@@ -1,0 +1,892 @@
+#!/usr/bin/env python3
+"""The quickest proof that ray_tpu still starts, compiles and answers on
+the chip.
+
+    python chip_smoke.py             # one TPU chip: train, serve, runtime
+    python chip_smoke.py --chips 4   # one four-chip host: the paths that
+                                     # exist only across chips
+
+It drives the device plane's main path once through the entry points a
+user calls, at the full width of GPT-2 124M (12 layers, d 768, 12 heads,
+padded vocabulary 50,304, bf16, sequence 1,024), with weights, batches
+and prompts made from a seed, and checks every result against a plain
+reference.  Any phase that fails makes the script exit non-zero; only
+when all passed is the last line of stdout
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+
+with the device as JAX reported it in the processes that held the chip.
+Without a TPU (``JAX_PLATFORMS=cpu``, or no accelerator) it exits non-zero
+and prints no such line.  It needs ``g++`` (the object store builds on
+first import), no network and no files besides the checkout.
+
+One process owns a chip at a time.  This process never imports JAX: it
+runs each phase as a child, one after another, and each child has exited
+— and freed the chip — before the next starts.  The ``runtime`` child
+does not import JAX either; there the ``num_tpus=1`` actor's worker owns
+the chip.  All of them share one persistent compilation cache
+(ray_tpu/_private/compile_cache.py).
+
+Phases, one chip:
+  train    JaxTrainer's build_train_step over gpt2_loss, batch 24, flash
+           attention by auto-dispatch, mlp_only remat, donated.
+  serve    the continuous engine with the paged KV cache under
+           shared-prefix traffic, against models.gpt2_decode.generate.
+  runtime  the README quick start: ray_tpu.init() finds the chip, a
+           num_tpus=1 actor trains on it, a num_tpus=0 task stays off it.
+Phases, --chips 4 (and no one-chip phase):
+  mesh_train    the train step over data=4 and data=2 x fsdp=2 against
+                the same step on device 0.
+  tensor_serve  the paged engine with mesh= tensor degree 4 against the
+                one-chip engine.
+  fleet         four one-chip replicas behind build_llm_fleet, on four
+                distinct devices.
+"""
+
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import json
+import os
+import signal
+import subprocess
+import sys
+import time
+from typing import Any, Dict, List
+
+ONE_CHIP = ("train", "serve", "runtime")
+FOUR_CHIPS = ("mesh_train", "tensor_serve", "fleet")
+#: the driver allows 1200 s; leave room for the parent's own exit
+DEADLINE_S = 1100.0
+_RESULT_TAG = "CHIP_SMOKE_PHASE "
+#: how a Pallas (Mosaic) kernel shows in a compiled program's text
+MOSAIC_CALL = 'custom_call_target="tpu_custom_call"'
+
+# Stated tolerances.  bf16 carries 8 bits of mantissa (2**-8 = 0.4%).
+#: bf16 flash step vs float32 XLA reference, relative, on a loss of ~10.8
+LOSS_REF_RTOL = 2e-3
+#: sharded vs one-chip losses over the first MESH_STEPS steps, relative:
+#: the same arithmetic reduced in another order (later steps are printed,
+#: not held: at this learning rate without warm-up the loss bounces, and
+#: a last-digit difference has room to grow)
+LOSS_MESH_RTOL = 5e-3
+MESH_STEPS = 3
+#: kernel output/gradient vs XLA reference, max error over max magnitude
+KERNEL_TOL = 3e-2
+#: where greedy tokens part from the oracle's, the oracle's own logit for
+#: the engine's token must be this close to its maximum: a bf16 near-tie,
+#: which seeded weights with nearly flat logits (std ~0.55, top-two gap
+#: ~0.1) make likely.  Twelve layers of bf16 rounding put ~5e-3 of noise
+#: on a logit, so two programs part by ~7e-3 and a third sees the gap of
+#: a flipped pair within about four such deviations.
+LOGIT_TIE_TOL = 3e-2
+
+
+@dataclasses.dataclass
+class Size:
+    """What a run exercises.  The defaults are the real thing; a test
+    passes a toy size to walk the control flow on the CPU."""
+
+    preset: str = "gpt2"
+    seq: int = 1024
+    batch: int = 24
+    steps: int = 6
+    #: GPT2Config overrides on top of the on-chip defaults
+    cfg: Dict[str, Any] = dataclasses.field(default_factory=dict)
+    seed: int = 0
+    # kernel checks: (B, T, H, D) attention, (N, D, V, valid V) fused CE
+    attn_shape: tuple = (2, 1024, 12, 64)
+    ce_shape: tuple = (2048, 768, 50304, 50257)
+    # serve: bench.py's on-chip TrafficSpec cut to a few dozen requests
+    requests: int = 32
+    #: warm-up requests of the serve phase (another seed's traffic), and
+    #: the whole load of each four-chip serve comparison
+    warm_requests: int = 12
+    prefix_groups: int = 4
+    prefix_len: int = 256
+    tail_mean: float = 32.0
+    tail_max: int = 128
+    vocab: int = 50000
+    rate_rps: float = 32.0
+    max_slots: int = 8
+    new_tokens: int = 64
+    prefill_bucket: int = 128
+    kv_block: int = 16
+
+
+def say(phase: str, **facts) -> None:
+    """One readable line per fact group; the last line is reserved."""
+    print(f"[{phase}] " + " ".join(
+        f"{k}={json.dumps(v, default=str)}" for k, v in facts.items()),
+        flush=True)
+
+
+def describe_devices() -> Dict[str, Any]:
+    """The device as JAX reports it: the last line's "device" block."""
+    import jax
+
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
+
+
+def device_block(platform: str) -> Dict[str, Any]:
+    """describe_devices(), after checking it is the platform this run is
+    for — anything else ends the run before it does work."""
+    device = describe_devices()
+    if device["platform"] != platform:
+        raise SystemExit(
+            f"chip_smoke: JAX found platform {device['platform']!r}, not "
+            f"{platform!r}: no accelerator, nothing to smoke")
+    return device
+
+
+def _peak_hbm(device) -> Any:
+    stats = device.memory_stats()
+    return None if stats is None else stats.get("peak_bytes_in_use")
+
+
+def _rel_err(got, want) -> float:
+    import numpy as np
+
+    got = np.asarray(got, np.float32)
+    want = np.asarray(want, np.float32)
+    return float(np.max(np.abs(got - want))
+                 / (np.max(np.abs(want)) + 1e-6))
+
+
+# ---------------------------------------------------------------------------
+# train
+# ---------------------------------------------------------------------------
+
+def check_kernels(size: Size, *, interpret: bool = False) -> None:
+    """The Pallas kernels the train step is built from — and the two
+    that wait for their A/B (resident-kv flash, fused lm-head+CE) — each
+    forward and backward against its XLA reference.  `interpret` is
+    False on the chip; only a CPU test asks for the interpreter."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.gpt2 import nll_from_logits
+    from ray_tpu.ops.attention import reference_attention
+    from ray_tpu.ops.flash_attention import flash_attention
+    from ray_tpu.ops.fused_ce import fused_lm_ce
+
+    B, T, H, D = size.attn_shape
+    kq, kk, kv, kw = jax.random.split(jax.random.PRNGKey(size.seed), 4)
+    q, k, v = (jax.random.normal(x, (B, T, H, D), jnp.bfloat16)
+               for x in (kq, kk, kv))
+    w = jax.random.normal(kw, (B, T, H, D), jnp.float32)
+
+    def run(attn):
+        def loss(q, k, v):
+            return jnp.sum(attn(q, k, v).astype(jnp.float32) * w)
+        return jax.jit(lambda q, k, v: (
+            attn(q, k, v), jax.grad(loss, argnums=(0, 1, 2))(q, k, v))
+        )(q, k, v)
+
+    with jax.default_matmul_precision("float32"):
+        want = run(lambda q, k, v: reference_attention(
+            q.astype(jnp.float32), k.astype(jnp.float32),
+            v.astype(jnp.float32)))
+    for name, resident in (("flash_classic", False),
+                           ("flash_resident", True)):
+        t0 = time.perf_counter()
+        got = run(lambda q, k, v: flash_attention(
+            q, k, v, resident_kv=resident, interpret=interpret))
+        jax.block_until_ready(got)
+        errs = [_rel_err(got[0], want[0])] + [
+            _rel_err(g, r) for g, r in zip(got[1], want[1])]
+        say("train", kernel=name, shape=[B, T, H, D],
+            err_o_dq_dk_dv=[round(e, 5) for e in errs],
+            seconds=round(time.perf_counter() - t0, 2))
+        assert max(errs) <= KERNEL_TOL, (name, errs)
+
+    N, Dm, V, valid = size.ce_shape
+    kh, kt, kg = jax.random.split(jax.random.PRNGKey(size.seed + 1), 3)
+    h = jax.random.normal(kh, (N, Dm), jnp.float32)
+    wte = jax.random.normal(kt, (V, Dm), jnp.float32) * 0.02
+    tgt = jax.random.randint(kg, (N,), 0, valid)
+
+    def dense(h, wte):
+        logits = jnp.einsum("nd,vd->nv", h.astype(jnp.bfloat16),
+                            wte.astype(jnp.bfloat16),
+                            preferred_element_type=jnp.float32)
+        return nll_from_logits(logits, tgt, valid, V)
+
+    def fused(h, wte):
+        return fused_lm_ce(h, wte, tgt, valid, interpret=interpret)
+
+    def both(fn):
+        return jax.jit(lambda h, wte: (
+            fn(h, wte),
+            jax.grad(lambda h, wte: jnp.mean(fn(h, wte)),
+                     argnums=(0, 1))(h, wte)))(h, wte)
+
+    t0 = time.perf_counter()
+    with jax.default_matmul_precision("float32"):
+        want = both(dense)
+    got = both(fused)
+    jax.block_until_ready(got)
+    errs = [_rel_err(got[0], want[0])] + [
+        _rel_err(g, r) for g, r in zip(got[1], want[1])]
+    say("train", kernel="fused_lm_ce", shape=[N, Dm, V],
+        err_nll_dh_dw=[round(e, 5) for e in errs],
+        seconds=round(time.perf_counter() - t0, 2))
+    assert max(errs) <= KERNEL_TOL, ("fused_lm_ce", errs)
+
+
+def train_setup(size: Size):
+    """(cfg, loss_fn, tx, tokens): the train program at its on-chip
+    defaults and one fixed seeded batch — shared by the train phase, the
+    runtime phase's actor and the four-chip comparison, so they compile
+    the same program and the cache can serve it."""
+    import numpy as np
+    import optax
+
+    from ray_tpu.models import gpt2_config, gpt2_loss
+
+    cfg = gpt2_config(size.preset, max_seq=size.seq,
+                      **{"remat_policy": "mlp_only", **size.cfg})
+    tokens = np.random.default_rng(size.seed).integers(
+        0, cfg.vocab_size, (size.batch, size.seq + 1)).astype(np.int32)
+
+    def loss_fn(params, batch):
+        return gpt2_loss(params, batch, cfg)
+
+    return cfg, loss_fn, optax.adamw(3e-4, weight_decay=0.1), tokens
+
+
+def run_steps(step, params, opt_state, batch, n: int,
+              phase: str = "train") -> List[float]:
+    """n fenced steps of a build_train_step step; the losses."""
+    losses = []
+    for i in range(n):
+        t0 = time.perf_counter()
+        params, opt_state, loss = step(params, opt_state, batch)
+        loss.block_until_ready()
+        losses.append(float(loss))
+        say(phase, step=i, loss=round(losses[-1], 5),
+            seconds=round(time.perf_counter() - t0, 3))
+    return losses
+
+
+def compile_step(step, params, opt_state, batch, watch, phase: str):
+    """AOT-compile the step once for its text and memory analysis and
+    print what it cost (the calls that follow reuse the executable).
+    Returns it and whether the persistent cache served it."""
+    hits, writes = watch.hits, watch.writes
+    t0 = time.perf_counter()
+    compiled = step.lower(params, opt_state, batch).compile()
+    seconds = time.perf_counter() - t0
+    ma = compiled.memory_analysis()
+    say(phase, compile_seconds=round(seconds, 2),
+        cache_hit=watch.hits > hits, cache_write=watch.writes > writes,
+        argument_bytes=ma.argument_size_in_bytes,
+        alias_bytes=ma.alias_size_in_bytes,
+        peak_memory_bytes=ma.peak_memory_in_bytes)
+    return compiled, watch.hits > hits
+
+
+def assert_losses_fall(losses: List[float]) -> None:
+    import math
+
+    assert all(math.isfinite(x) for x in losses), losses
+    assert losses[-1] < losses[0], losses
+
+
+def phase_train(size: Size, platform: str = "tpu") -> Dict[str, Any]:
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu._private.compile_cache import CompileWatch
+    from ray_tpu.models import gpt2_init, gpt2_loss
+    from ray_tpu.train.jax_trainer import jax_utils
+
+    device = device_block(platform)
+    watch = CompileWatch()
+    check_kernels(size)
+
+    cfg, loss_fn, tx, tokens = train_setup(size)
+    params = gpt2_init(jax.random.PRNGKey(size.seed), cfg)
+    batch = {"tokens": tokens}
+
+    # the plain reference: the initial parameters' loss on two
+    # sequences, flash + bf16 against XLA attention in float32
+    two = {"tokens": tokens[:2]}
+    ref_cfg = dataclasses.replace(cfg, use_flash=False,
+                                  dtype=jnp.float32)
+    loss_chip = float(jax.jit(loss_fn)(params, two))
+    with jax.default_matmul_precision("float32"):
+        loss_ref = float(jax.jit(
+            lambda p, b: gpt2_loss(p, b, ref_cfg))(params, two))
+    say("train", loss_two_rows=round(loss_chip, 5),
+        loss_two_rows_f32_reference=round(loss_ref, 5),
+        rel_diff=round(abs(loss_chip - loss_ref) / abs(loss_ref), 6))
+    assert abs(loss_chip - loss_ref) <= LOSS_REF_RTOL * abs(loss_ref)
+
+    opt_state = tx.init(params)
+    step = jax_utils.build_train_step(loss_fn, tx,
+                                      telemetry_name="chip_smoke")
+    compiled, _ = compile_step(step, params, opt_state, batch, watch,
+                               "train")
+    if platform == "tpu":
+        # the flash kernels are in the program: the XLA reference did
+        # not run in their place
+        n_kernels = compiled.as_text().count(MOSAIC_CALL)
+        say("train", tpu_custom_calls=n_kernels)
+        assert n_kernels >= 3, n_kernels
+    losses = run_steps(step, params, opt_state, batch, size.steps)
+    assert_losses_fall(losses)
+    say("train", peak_hbm_bytes=_peak_hbm(jax.devices()[0]),
+        compiles=watch.compiles, cache_hits=watch.hits,
+        cache_writes=watch.writes)
+    return {"device": device, "losses": losses}
+
+
+# ---------------------------------------------------------------------------
+# serve
+# ---------------------------------------------------------------------------
+
+def traffic(size: Size, seed: int, n: int):
+    from ray_tpu.serve.traffic import TrafficGenerator, TrafficSpec
+
+    return TrafficGenerator(TrafficSpec(
+        num_requests=n, seed=seed, rate_rps=size.rate_rps,
+        num_prefix_groups=size.prefix_groups, prefix_len=size.prefix_len,
+        p_shared=0.75, tail_len_mean=size.tail_mean,
+        tail_len_max=size.tail_max, vocab=size.vocab)).requests()
+
+
+def engine_kw(size: Size) -> Dict[str, Any]:
+    return dict(scheduler="continuous", kv_layout="paged",
+                kv_block_size=size.kv_block, max_slots=size.max_slots,
+                max_new_tokens=size.new_tokens, temperature=0.0,
+                prefill_bucket=size.prefill_bucket, seed=size.seed,
+                config_overrides=dict(size.cfg) or None)
+
+
+async def fire(target, requests, time_scale: float = 1.0):
+    """Send each request at its arrival time, as serve.traffic.drive
+    does, and keep the answers; any failure propagates."""
+    import asyncio
+
+    import numpy as np
+
+    t0 = time.perf_counter()
+
+    async def one(req):
+        delay = req.arrival_s * time_scale - (time.perf_counter() - t0)
+        if delay > 0:
+            await asyncio.sleep(delay)
+        return np.asarray(await target(req.prompt))
+
+    return await asyncio.gather(*[one(r) for r in requests])
+
+
+def assert_answered(requests, outs, new_tokens: int) -> None:
+    import numpy as np
+
+    assert len(outs) == len(requests)
+    for req, out in zip(requests, outs):
+        n = len(req.prompt)
+        assert out.shape == (n + new_tokens,), (out.shape, n)
+        assert np.array_equal(out[:n], req.prompt)
+
+
+def oracle_generate(params, cfg, prompts, new_tokens: int):
+    """models.gpt2_decode.generate on the dense cache, greedy: the
+    prompts left-padded into one ragged batch, so one program serves."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.models.gpt2_decode import generate
+
+    lens = [len(p) for p in prompts]
+    t0 = max(lens)
+    padded = np.zeros((len(prompts), t0), np.int32)
+    for i, p in enumerate(prompts):
+        padded[i, t0 - lens[i]:] = p
+    out = np.asarray(jax.jit(lambda p, toks, n: generate(
+        p, toks, cfg, lengths=n, max_new_tokens=new_tokens,
+        temperature=0.0))(params, jnp.asarray(padded),
+                          jnp.asarray(lens, jnp.int32)))
+    return [out[i, t0 - lens[i]:] for i in range(len(prompts))]
+
+
+def assert_same_greedy(phase: str, label: str, params, cfg, prompt,
+                       got, want) -> bool:
+    """Greedy tokens must be identical.  Where bf16 near-ties part them,
+    the rule is stated, not loosened: at the first diverging position
+    the oracle, teacher-forced on the engine's own tokens, must hold the
+    engine's token within LOGIT_TIE_TOL of its maximum logit (position 0
+    is the first-step logits).  Returns whether identity held."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.models import gpt2_forward
+
+    n = len(prompt)
+    if np.array_equal(got, want):
+        say(phase, request=label, prompt_len=n, token_identical=True)
+        return True
+    pos = int(np.argmax(got[n:] != want[n:]))
+    logits = np.asarray(jax.jit(
+        lambda p, t: gpt2_forward(p, t, cfg)[0, -1])(
+            params, jnp.asarray(got[None, :n + pos])))[:cfg.vocab_size]
+    gap = float(logits.max() - logits[got[n + pos]])
+    say(phase, request=label, prompt_len=n, token_identical=False,
+        first_diverging_position=pos, engine_token=int(got[n + pos]),
+        oracle_token=int(want[n + pos]), oracle_logit_gap=round(gap, 5),
+        tolerance=LOGIT_TIE_TOL)
+    assert gap <= LOGIT_TIE_TOL, (label, pos, gap)
+    return False
+
+
+def phase_serve(size: Size, platform: str = "tpu") -> Dict[str, Any]:
+    import asyncio
+
+    import jax
+
+    from ray_tpu._private.compile_cache import CompileWatch
+    from ray_tpu.serve.llm import build_llm_deployment
+
+    device = device_block(platform)
+    watch = CompileWatch()
+    t0 = time.perf_counter()
+    engine = build_llm_deployment("gpt2", size.preset,
+                                  **engine_kw(size)).func_or_class()
+    say("serve", engine_build_seconds=round(time.perf_counter() - t0, 2))
+    warm = traffic(size, size.seed + 1, size.warm_requests)
+    requests = traffic(size, size.seed, size.requests)
+
+    async def main():
+        try:
+            t0 = time.perf_counter()
+            assert_answered(warm, await fire(engine, warm, 0.0),
+                            size.new_tokens)
+            say("serve", warm_up_seconds=round(
+                time.perf_counter() - t0, 2), compiles=watch.compiles,
+                cache_hits=watch.hits, cache_writes=watch.writes)
+            seen = {r["id"] for r in engine.trace_records()}
+            compiles, t0 = watch.compiles, time.perf_counter()
+            outs = await fire(engine, requests)
+            seconds = time.perf_counter() - t0
+            stats = engine.engine_stats()
+            records = [r for r in engine.trace_records()
+                       if r["id"] not in seen]
+            return outs, seconds, watch.compiles - compiles, stats, \
+                records
+        finally:
+            engine.shutdown_engine()
+
+    outs, seconds, late_compiles, stats, records = asyncio.run(main())
+    assert_answered(requests, outs, size.new_tokens)
+    kv = stats["kv_cache"]
+    say("serve", requests=len(requests), seconds=round(seconds, 2),
+        finished=stats["requests"]["finished"],
+        tokens_generated=stats["tokens_generated"],
+        prefix_block_hits=kv["prefix_block_hits"],
+        prefix_hit_rate=kv["prefix_hit_rate"],
+        prefill_buckets=stats["prefill_buckets"],
+        compiles_after_warm_up=late_compiles,
+        peak_hbm_bytes=_peak_hbm(jax.devices()[0]))
+    assert kv["prefix_block_hits"] > 0, kv
+    assert late_compiles == 0, late_compiles
+
+    # one request that hit the prefix cache and one that met its prefix
+    # cold, told apart by the engine's own per-request records (matched
+    # to requests by prompt length, in order of arrival)
+    assert len(records) == len(requests), (len(records), len(requests))
+    waiting = sorted(range(len(requests)),
+                     key=lambda i: requests[i].arrival_s)
+    hit_blocks = {}
+    for rec in sorted(records, key=lambda r: r["id"]):
+        i = next(i for i in waiting
+                 if len(requests[i].prompt) == rec["prompt_len"])
+        waiting.remove(i)
+        hit_blocks[i] = rec["kv_reserve"][3]
+    shared = [i for i, r in enumerate(requests) if r.group >= 0]
+    picks = {"prefix_hit": [i for i in shared if hit_blocks[i] > 0],
+             "cold": [i for i in shared if hit_blocks[i] == 0]}
+    assert all(picks.values()), picks
+    picks = {label: found[0] for label, found in picks.items()}
+    want = oracle_generate(engine.params, engine.cfg,
+                           [requests[i].prompt for i in picks.values()],
+                           size.new_tokens)
+    identical = [assert_same_greedy(
+        "serve", f"{label}#{i}", engine.params, engine.cfg,
+        requests[i].prompt, outs[i], w)
+        for (label, i), w in zip(picks.items(), want)]
+    return {"device": device, "token_identical": all(identical)}
+
+
+# ---------------------------------------------------------------------------
+# runtime
+# ---------------------------------------------------------------------------
+
+def actor_train(size: Size, steps: int) -> Dict[str, Any]:
+    """Runs inside the num_tpus=1 actor: the train phase's program again,
+    in another process — so its compile must come from the cache."""
+    import jax
+
+    from ray_tpu._private.compile_cache import (CompileWatch,
+                                                compile_cache_dir)
+    from ray_tpu.models import gpt2_init
+    from ray_tpu.train.jax_trainer import jax_utils
+
+    watch = CompileWatch()
+    cfg, loss_fn, tx, tokens = train_setup(size)
+    params = gpt2_init(jax.random.PRNGKey(size.seed), cfg)
+    opt_state = tx.init(params)
+    batch = {"tokens": tokens}
+    step = jax_utils.build_train_step(loss_fn, tx,
+                                      telemetry_name="chip_smoke")
+    _, cache_hit = compile_step(step, params, opt_state, batch, watch,
+                                "runtime")
+    losses = run_steps(step, params, opt_state, batch, steps, "runtime")
+    return {"losses": losses, "cache_hit": cache_hit,
+            "cache_dir": compile_cache_dir(),
+            "device": describe_devices()}
+
+
+def phase_runtime(size: Size, platform: str = "tpu",
+                  num_tpus: Any = None) -> Dict[str, Any]:
+    """The README's quick start.  `num_tpus=None` lets init() count the
+    chips itself (node.py detect_num_tpus) — what a user's init() does;
+    a CPU test passes the count, as there is nothing to find."""
+    import ray_tpu
+
+    t0 = time.perf_counter()
+    ray_tpu.init(num_tpus=num_tpus)
+    try:
+        found = ray_tpu.cluster_resources().get("TPU", 0)
+        say("runtime", init_seconds=round(time.perf_counter() - t0, 2),
+            tpus_found=found)
+        assert found == 1, found
+
+        @ray_tpu.remote(num_tpus=1)
+        class Learner:
+            def train(self, size, steps):
+                return actor_train(size, steps)
+
+        @ray_tpu.remote(num_tpus=0)
+        def off_chip_platform():
+            import jax
+
+            return jax.devices()[0].platform
+
+        t0 = time.perf_counter()
+        out = ray_tpu.get(Learner.remote().train.remote(size, 2),
+                          timeout=900)
+        say("runtime", actor_seconds=round(time.perf_counter() - t0, 2),
+            **out)
+        off_chip = ray_tpu.get(off_chip_platform.remote(), timeout=300)
+        say("runtime", num_tpus_0_task_platform=off_chip)
+    finally:
+        ray_tpu.shutdown()
+    assert out["device"]["platform"] == platform, out["device"]
+    assert off_chip == "cpu", off_chip
+    assert_losses_fall(out["losses"])
+    if platform == "tpu":
+        # the cache path is the same in every process: the train phase
+        # compiled this program, the actor's worker must find it
+        assert out["cache_hit"], out
+    return {"device": out["device"], "losses": out["losses"]}
+
+
+# ---------------------------------------------------------------------------
+# four chips
+# ---------------------------------------------------------------------------
+
+def _bytes_on(tree, device) -> int:
+    import jax
+
+    return sum(s.data.nbytes for leaf in jax.tree.leaves(tree)
+               for s in leaf.addressable_shards if s.device == device)
+
+
+def phase_mesh_train(size: Size, platform: str = "tpu"
+                     ) -> Dict[str, Any]:
+    import jax
+
+    from ray_tpu._private.compile_cache import CompileWatch
+    from ray_tpu.models import gpt2_init, gpt2_logical_axes
+    from ray_tpu.parallel import MeshSpec, make_mesh
+    from ray_tpu.parallel.sharding import shard_params
+    from ray_tpu.train.jax_trainer import jax_utils
+
+    device = device_block(platform)
+    devices = jax.devices()
+    assert len(devices) == 4, devices
+    watch = CompileWatch()
+    cfg, loss_fn, tx, tokens = train_setup(size)
+    axes = gpt2_logical_axes(cfg)
+    batch = {"tokens": tokens}
+
+    def fresh():
+        return gpt2_init(jax.random.PRNGKey(size.seed), cfg)
+
+    # what it is compared with: the same step, batch and seed on device 0
+    params = jax.device_put(fresh(), devices[0])
+    total = _bytes_on(params, devices[0])
+    # committed whole: optax's step counter is born uncommitted, and a
+    # step whose inputs change from uncommitted to committed between
+    # its first and second call compiles twice
+    opt_state = jax.device_put(tx.init(params), devices[0])
+    step = jax_utils.build_train_step(loss_fn, tx,
+                                      telemetry_name="one_chip")
+    compile_step(step, params, opt_state, batch, watch, "mesh_train")
+    want = run_steps(step, params, opt_state, batch, size.steps,
+                     "mesh_train:one_chip")
+    del params, opt_state
+
+    all_losses = {"one_chip": want}
+    for name, spec, param_split in (
+            ("data=4", MeshSpec(data=4), 1),
+            ("data=2,fsdp=2", MeshSpec(data=2, fsdp=2), 2)):
+        mesh = make_mesh(spec, devices=devices)
+        with jax.set_mesh(mesh):
+            params = shard_params(fresh(), axes, mesh)
+            opt_state = tx.init(params)
+            per_dev = [_bytes_on((params, opt_state), d) for d in devices]
+            say("mesh_train", layout=name,
+                param_bytes_unsharded=total,
+                param_bytes_per_device=[_bytes_on(params, d)
+                                        for d in devices],
+                param_and_opt_bytes_per_device=per_dev)
+            # split as the layout says — every device an equal share,
+            # fsdp halving what each holds — not piled onto device 0
+            assert len(set(per_dev)) == 1, per_dev
+            held = _bytes_on(params, devices[0])
+            assert abs(held - total // param_split) <= total // 100, \
+                (held, total, param_split)
+            step = jax_utils.build_train_step(
+                loss_fn, tx, mesh=mesh, logical_axes=axes,
+                telemetry_name=name)
+            compiled, _ = compile_step(step, params, opt_state, batch,
+                                       watch, f"mesh_train:{name}")
+            text = compiled.as_text()
+            say("mesh_train", layout=name,
+                tpu_custom_calls=text.count(MOSAIC_CALL),
+                all_reduces=text.count("all-reduce("),
+                all_gathers=text.count("all-gather("))
+            if platform == "tpu":
+                assert text.count(MOSAIC_CALL) >= 3
+            got = run_steps(step, params, opt_state, batch, size.steps,
+                            f"mesh_train:{name}")
+        del params, opt_state
+        assert_losses_fall(got)
+        rel = [abs(g - w) / abs(w) for g, w in zip(got, want)]
+        say("mesh_train", layout=name,
+            rel_loss_diff=[round(r, 7) for r in rel],
+            held_steps=MESH_STEPS, tolerance=LOSS_MESH_RTOL)
+        assert max(rel[:MESH_STEPS]) <= LOSS_MESH_RTOL, (name, got, want)
+        all_losses[name] = got
+    return {"device": device, "losses": all_losses}
+
+
+def phase_tensor_serve(size: Size, platform: str = "tpu"
+                       ) -> Dict[str, Any]:
+    import asyncio
+
+    import jax
+
+    from ray_tpu.parallel import MeshSpec, make_mesh
+    from ray_tpu.serve.llm import build_llm_deployment
+
+    device = device_block(platform)
+    devices = jax.devices()
+    assert len(devices) == 4, devices
+    requests = traffic(size, size.seed, size.warm_requests)
+    mesh = make_mesh(MeshSpec(tensor=4), devices=devices)
+    engines = {
+        "one_chip": build_llm_deployment(
+            "gpt2", size.preset, **engine_kw(size)
+        ).func_or_class(device=devices[0]),
+        "tensor=4": build_llm_deployment(
+            "gpt2", size.preset, mesh=mesh, **engine_kw(size)
+        ).func_or_class(),
+    }
+    sharded = engines["tensor=4"]
+    say("tensor_serve",
+        wte_devices=len(sharded.params["wte"].devices()),
+        kv_pool_devices=len(sharded._cache["k"].devices()),
+        kv_pool_shard_shape=list(
+            sharded._cache["k"].addressable_shards[0].data.shape),
+        kv_pool_shape=list(sharded._cache["k"].shape))
+    assert len(sharded._cache["k"].devices()) == 4
+    assert engines["one_chip"]._cache["k"].devices() == {devices[0]}
+
+    async def main(engine):
+        try:
+            return await fire(engine, requests, 0.0)
+        finally:
+            engine.shutdown_engine()
+
+    outs = {}
+    for name, engine in engines.items():
+        t0 = time.perf_counter()
+        outs[name] = asyncio.run(main(engine))
+        assert_answered(requests, outs[name], size.new_tokens)
+        say("tensor_serve", engine=name, requests=len(requests),
+            seconds=round(time.perf_counter() - t0, 2))
+    one = engines["one_chip"]
+    identical = [assert_same_greedy(
+        "tensor_serve", f"request#{i}", one.params, one.cfg, r.prompt,
+        outs["tensor=4"][i], outs["one_chip"][i])
+        for i, r in enumerate(requests)]
+    say("tensor_serve", token_identical=sum(identical),
+        of=len(identical))
+    return {"device": device, "token_identical": all(identical)}
+
+
+def phase_fleet(size: Size, platform: str = "tpu") -> Dict[str, Any]:
+    import asyncio
+
+    import jax
+
+    from ray_tpu.serve.router import build_llm_fleet
+
+    device = device_block(platform)
+    assert len(jax.devices()) == 4, jax.devices()
+    requests = traffic(size, size.seed, size.warm_requests)
+    kw = engine_kw(size)
+    # the fleet forces scheduler and layout on, and `seed` is its own
+    for k in ("scheduler", "kv_layout", "seed"):
+        kw.pop(k)
+    fleet = build_llm_fleet("gpt2", size.preset, num_replicas=4,
+                            seed=size.seed, **kw)
+    replicas = fleet.router.live_replicas
+    placed = {r.name: (r.inst.params["wte"].devices(),
+                       r.inst._cache["k"].devices()) for r in replicas}
+    say("fleet", placement={n: [sorted(str(d) for d in p),
+                                sorted(str(d) for d in c)]
+                            for n, (p, c) in placed.items()})
+    for params_on, pool_on in placed.values():
+        assert params_on == pool_on and len(params_on) == 1
+    assert len({next(iter(p)) for p, _ in placed.values()}) == 4
+
+    async def main():
+        try:
+            # every replica answers (asked directly), then the router
+            # spreads a burst over them
+            direct = await asyncio.gather(*[
+                fire(r.inst, requests[i:i + 1], 0.0)
+                for i, r in enumerate(replicas)])
+            routed = await fire(fleet, requests, 0.0)
+            return [d[0] for d in direct], routed
+        finally:
+            fleet.shutdown()
+
+    t0 = time.perf_counter()
+    direct, routed = asyncio.run(main())
+    assert_answered(requests[:4], direct, size.new_tokens)
+    assert_answered(requests, routed, size.new_tokens)
+    finished = {r.name: r.inst.engine_stats()["requests"]["finished"]
+                for r in replicas}
+    say("fleet", seconds=round(time.perf_counter() - t0, 2),
+        finished_per_replica=finished)
+    assert all(n >= 1 for n in finished.values()), finished
+    return {"device": device}
+
+
+PHASES = {"train": phase_train, "serve": phase_serve,
+          "runtime": phase_runtime, "mesh_train": phase_mesh_train,
+          "tensor_serve": phase_tensor_serve, "fleet": phase_fleet}
+
+
+# ---------------------------------------------------------------------------
+# processes
+# ---------------------------------------------------------------------------
+
+def run_phase(name: str) -> int:
+    """Child process: one phase at full size, then its result line."""
+    t0 = time.perf_counter()
+    if name != "runtime":       # the runtime driver stays off JAX
+        from ray_tpu._private.compile_cache import enable_compile_cache
+
+        say(name, compile_cache_dir=enable_compile_cache())
+    result = PHASES[name](Size())
+    if name == "runtime":
+        assert "jax" not in sys.modules, "the driver imported JAX"
+    say(name, phase_seconds=round(time.perf_counter() - t0, 1))
+    print(_RESULT_TAG + json.dumps({"phase": name, **result}),
+          flush=True)
+    return 0
+
+
+def run_child(name: str, deadline: float) -> Dict[str, Any]:
+    """Run one phase in its own process group, echo what it prints, and
+    leave nothing of it running.  Raises SystemExit unless it passed."""
+    proc = subprocess.Popen(
+        [sys.executable, os.path.abspath(__file__), "--phase", name],
+        stdout=subprocess.PIPE, text=True, start_new_session=True)
+    result = None
+    try:
+        timer = _alarm(deadline - time.monotonic(), proc)
+        for line in proc.stdout:
+            if line.startswith(_RESULT_TAG):
+                result = json.loads(line[len(_RESULT_TAG):])
+            else:
+                sys.stdout.write(line)
+                sys.stdout.flush()
+        code = proc.wait()
+        timer.cancel()
+    finally:
+        _kill_group(proc)
+    if code != 0 or result is None:
+        raise SystemExit(f"chip_smoke: phase {name!r} failed "
+                         f"(exit {code})")
+    return result
+
+
+def _kill_group(proc) -> None:
+    try:
+        os.killpg(proc.pid, signal.SIGKILL)
+    except ProcessLookupError:
+        pass
+    proc.wait()
+
+
+def _alarm(seconds: float, proc):
+    import threading
+
+    timer = threading.Timer(max(seconds, 1.0), _kill_group, (proc,))
+    timer.daemon = True
+    timer.start()
+    return timer
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, default=1, choices=(1, 4),
+                    help="4 runs the across-chip phases and no other")
+    ap.add_argument("--phase", choices=sorted(PHASES),
+                    help="run one phase in this process (what the "
+                         "parent starts; also handy for debugging)")
+    args = ap.parse_args(argv)
+    if args.phase:
+        return run_phase(args.phase)
+    t0 = time.monotonic()
+    devices = []
+    for name in (FOUR_CHIPS if args.chips == 4 else ONE_CHIP):
+        t_phase = time.monotonic()
+        devices.append(run_child(name, t0 + DEADLINE_S)["device"])
+        print(f"[{name}] passed in {time.monotonic() - t_phase:.1f}s",
+              flush=True)
+    device = devices[0]
+    if any(d != device for d in devices) or device["platform"] != "tpu" \
+            or device["count"] != args.chips:
+        raise SystemExit(f"chip_smoke: phases disagree on the device or "
+                         f"it is not {args.chips} TPU chip(s): {devices}")
+    print(f"[all] passed in {time.monotonic() - t0:.1f}s", flush=True)
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

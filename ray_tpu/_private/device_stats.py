@@ -155,39 +155,35 @@ def _device_metrics() -> Dict[str, Any]:
         return _metrics
 
 
-def peak_flops_per_chip(device: Any = None) -> float:
-    """Dense peak FLOPs/s for one chip of the running backend (falls
-    back to the v5e figure for unknown TPU kinds, 1e12 for CPU)."""
-    try:
-        if device is None:
-            import jax
+def _peak(table: Dict[str, float], device: Any) -> float:
+    """Look one chip's peak up by ``device_kind``.  The "cpu" entry
+    answers only for a device whose platform IS cpu (tests); a device
+    kind the table does not know raises — a roofline against another
+    chip's peak is a wrong number, not a default."""
+    if device is None:
+        import jax
 
-            device = jax.devices()[0]
-        kind = device.device_kind.lower()
-    except Exception:  # noqa: BLE001 - no backend yet
-        return _PEAK_FLOPS_TABLE["cpu"]
-    for key, val in _PEAK_FLOPS_TABLE.items():
-        if key in kind:
+        device = jax.devices()[0]
+    if device.platform == "cpu":
+        return table["cpu"]
+    kind = device.device_kind.lower()
+    for key, val in table.items():
+        if key != "cpu" and key in kind:
             return val
-    return 197e12
+    raise ValueError(
+        f"no published peak for device kind {device.device_kind!r} "
+        f"(platform {device.platform!r}); add it to the tables in "
+        f"ray_tpu/_private/device_stats.py with its source")
+
+
+def peak_flops_per_chip(device: Any = None) -> float:
+    """Dense bf16 peak FLOPs/s for one chip of the running backend."""
+    return _peak(_PEAK_FLOPS_TABLE, device)
 
 
 def peak_hbm_bytes_per_sec(device: Any = None) -> float:
-    """HBM bandwidth bytes/s for one chip of the running backend
-    (same fallback policy as :func:`peak_flops_per_chip`: the v5e
-    figure for unknown TPU kinds, the CPU entry without a backend)."""
-    try:
-        if device is None:
-            import jax
-
-            device = jax.devices()[0]
-        kind = device.device_kind.lower()
-    except Exception:  # noqa: BLE001 - no backend yet
-        return _PEAK_HBM_BW_TABLE["cpu"]
-    for key, val in _PEAK_HBM_BW_TABLE.items():
-        if key in kind:
-            return val
-    return _PEAK_HBM_BW_TABLE["v5e"]
+    """HBM bandwidth bytes/s for one chip of the running backend."""
+    return _peak(_PEAK_HBM_BW_TABLE, device)
 
 
 def device_roofline(device: Any = None) -> Dict[str, Any]:
@@ -197,21 +193,15 @@ def device_roofline(device: Any = None) -> Dict[str, Any]:
     dashboard dump of a REMOTE engine carries the remote device's
     ridge, not the reader's) and used directly by
     ``ray_tpu.tools.autopilot``."""
-    backend = kind = None
-    try:
-        if device is None:
-            import jax
+    if device is None:
+        import jax
 
-            device = jax.devices()[0]
-        backend = getattr(device, "platform", None)
-        kind = getattr(device, "device_kind", None)
-    except Exception:  # noqa: BLE001 - no backend yet
-        device = None
+        device = jax.devices()[0]
     flops = peak_flops_per_chip(device)
     bw = peak_hbm_bytes_per_sec(device)
     return {
-        "backend": backend,
-        "device_kind": kind,
+        "backend": device.platform,
+        "device_kind": device.device_kind,
         "peak_flops_per_chip": flops,
         "peak_hbm_bytes_per_sec": bw,
         "ridge_flops_per_byte": round(flops / bw, 1),

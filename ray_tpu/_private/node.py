@@ -11,7 +11,6 @@ driver process; worker processes are real subprocesses.  ``ray_tpu start``
 
 from __future__ import annotations
 
-import logging
 import os
 import shutil
 import time
@@ -24,17 +23,20 @@ from ray_tpu._private.ids import NodeID
 from ray_tpu._private.node_manager import NodeManager
 from ray_tpu._private.object_store import ObjectStoreClient, default_shm_name
 
-logger = logging.getLogger(__name__)
-
 
 def detect_num_tpus(config: Config) -> int:
     """Count local TPU chips. ``num_tpus`` is a first-class predefined
     resource (the reference's GPU analog, scheduling_ids.h:34).
 
-    Probed in a BOUNDED subprocess: a flaky TPU plugin/tunnel can hang
-    jax.devices() indefinitely, and that must never hang init().  The
-    probe also keeps this process from initializing the TPU runtime
-    (libtpu locks chips per process; workers own them, not the driver).
+    A chip belongs to one process at a time and workers own it, not the
+    driver, so the count is taken by a short-lived child that has
+    released the chip again before any worker starts.  The child can
+    only succeed while no other process holds the chip: a node started
+    next to live TPU workers passes ``num_tpus`` (or sets
+    ``tpu_chips_per_host``) instead of probing.
+
+    A probe that fails or times out raises.  The only quiet zero is the
+    caller's own: ``JAX_PLATFORMS=cpu``.
     """
     if config.tpu_chips_per_host:
         return config.tpu_chips_per_host
@@ -45,19 +47,22 @@ def detect_num_tpus(config: Config) -> int:
 
     code = ("import jax; "
             "print(len([d for d in jax.devices() "
-            "if d.platform != 'cpu' "
-            "and 'tpu' in d.device_kind.lower()]))")
+            "if d.platform == 'tpu']))")
+    hint = ("pass num_tpus= to init(), set tpu_chips_per_host, or pin "
+            "JAX_PLATFORMS=cpu for a node without chips")
     try:
         r = subprocess.run([sys.executable, "-c", code],
                            capture_output=True, text=True,
                            timeout=config.tpu_detect_timeout_s)
-        if r.returncode == 0:
-            return int(r.stdout.strip().splitlines()[-1])
-    except Exception:  # noqa: BLE001 - no jax / probe timeout
-        pass
-    logger.warning("TPU detection failed or timed out; assuming 0 chips "
-                   "(set tpu_chips_per_host to override)")
-    return 0
+    except subprocess.TimeoutExpired as e:
+        raise RuntimeError(
+            f"TPU detection timed out after "
+            f"{config.tpu_detect_timeout_s:g}s; {hint}") from e
+    if r.returncode != 0:
+        raise RuntimeError(
+            f"TPU detection failed (exit {r.returncode}): "
+            f"{r.stderr.strip()[-400:]}; {hint}")
+    return int(r.stdout.strip().splitlines()[-1])
 
 
 def _gcs_is_local(gcs_address: str) -> bool:

@@ -553,9 +553,8 @@ class DashboardActor:
 
         # On-demand profiler capture (util/state.py profile_device):
         # POST {"logdir": ..., "seconds": 1.0} traces this process for
-        # the window and returns where the trace landed.  Degrades to
-        # {"ok": false} where jax.profiler is unavailable — same no-op
-        # contract as profile_device itself.
+        # the window and returns where the trace landed, or
+        # {"ok": false, "error": ...} when the profiler will not run.
         async def perf_profile(req):
             try:
                 body = await req.json()
@@ -570,13 +569,17 @@ class DashboardActor:
 
                 from ray_tpu.util.state import profile_device
 
-                with profile_device(logdir) as prof:
+                with profile_device(logdir):
                     _time.sleep(seconds)
-                return bool(prof._active)
 
-            ok = await loop.run_in_executor(None, _capture)
+            try:
+                await loop.run_in_executor(None, _capture)
+            except Exception as e:  # noqa: BLE001 - reported to caller
+                return web.json_response(
+                    {"ok": False, "logdir": logdir, "seconds": seconds,
+                     "error": repr(e)})
             return web.json_response(
-                {"ok": ok, "logdir": logdir, "seconds": seconds})
+                {"ok": True, "logdir": logdir, "seconds": seconds})
 
         app.router.add_post("/api/perf/profile", perf_profile)
 

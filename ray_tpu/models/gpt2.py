@@ -36,7 +36,7 @@ from ray_tpu.parallel.sharding import DEFAULT_RULES, with_logical_constraint
 #: "dense" materializes f32 (B,T,V) logits; "streaming_xla" is the
 #: lax.scan vocab-tile path (ops/vocab_ce.py); "pallas" is the fused
 #: MXU-streamed kernel (ops/fused_ce.py) — no (B,T,V) buffer in either
-#: pass.  See PERF_NOTES round 6 for when each wins.
+#: pass.  Which wins on the chip is ROADMAP.md A2 (measure once, keep one).
 CE_IMPLS = ("dense", "streaming_xla", "pallas")
 FLASH_RESIDENT_MODES = ("auto", "on", "off")
 
@@ -334,7 +334,7 @@ def _attention(x, p, cfg: GPT2Config, rules):
     if o is None:
         from ray_tpu.ops.attention import causal_attention
         o = causal_attention(q, kk, v, use_flash=cfg.use_flash,
-                             resident=cfg.flash_resident)
+                             resident=cfg.flash_resident, rules=rules)
     from jax.ad_checkpoint import checkpoint_name
     o = checkpoint_name(o, "attn_out")
     wo = p["o_w"].astype(cfg.dtype).reshape(h * hd, d)
@@ -592,7 +592,17 @@ def lm_head_nll(hidden, w_vocab_major, targets, cfg) -> jnp.ndarray:
     t1 = targets.reshape(-1).astype(jnp.int32)
     if cfg.ce_impl == "pallas":
         from ray_tpu.ops.fused_ce import fused_lm_ce
+        from ray_tpu.parallel.mesh import active_mesh
 
+        mesh = active_mesh()
+        if mesh is not None and mesh.size > 1:
+            # GSPMD cannot partition a Mosaic kernel, and the fused CE
+            # has no shard_map form (its vocab stream would have to
+            # cross the tensor axis): refuse rather than degrade.
+            raise NotImplementedError(
+                f"ce_impl='pallas' runs on one device; under a "
+                f"{mesh.size}-device mesh use ce_impl='dense' or "
+                f"'streaming_xla'")
         nll = fused_lm_ce(h2, w_vocab_major, t1, cfg.vocab_size,
                           block_n=cfg.ce_block_n,
                           block_v=min(cfg.ce_block_v, cfg.padded_vocab),

@@ -112,6 +112,7 @@ def prefill(params, tokens: jnp.ndarray, cfg: GPT2Config, *,
     is column T0-1 for every row — logits come from that one column,
     never the full (B, T0, V) tensor."""
     from ray_tpu.ops.attention import prefill_attention
+    from ray_tpu.parallel.sharding import DECODE_RULES
 
     B, T0 = tokens.shape
     d, h, hd = cfg.d_model, cfg.n_head, cfg.head_dim
@@ -137,7 +138,8 @@ def prefill(params, tokens: jnp.ndarray, cfg: GPT2Config, *,
         q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
         o = prefill_attention(q, k, v, start=attn_start,
                               use_flash=cfg.use_flash,
-                              resident=cfg.flash_resident)
+                              resident=cfg.flash_resident,
+                              rules=DECODE_RULES)
         wo = p["attn"]["o_w"].astype(cfg.dtype).reshape(h * hd, d)
         x = x + (o.reshape(B, T0, h * hd) @ wo
                  + p["attn"]["o_b"].astype(cfg.dtype))

@@ -223,7 +223,7 @@ def _attention(x, p, cos, sin, cfg: LlamaConfig, rules):
     from ray_tpu.ops.attention import causal_attention
 
     o = causal_attention(q, k, v, use_flash=cfg.use_flash,
-                         resident=cfg.flash_resident)
+                         resident=cfg.flash_resident, rules=rules)
     o = o.reshape(B, T, h * hd)
     wo = p["wo"].astype(cfg.dtype).reshape(h * hd, d)
     return (o @ wo).astype(x.dtype)

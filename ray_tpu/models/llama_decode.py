@@ -115,6 +115,7 @@ def llama_prefill(params, tokens: jnp.ndarray, cfg: LlamaConfig, *,
     LEFT-padded with `lengths` (B,); RoPE angles follow each row's
     logical positions, so pads never shift a real token's rotation."""
     from ray_tpu.ops.attention import prefill_attention
+    from ray_tpu.parallel.sharding import DECODE_RULES
 
     B, T0 = tokens.shape
     d, h, kv, hd = (cfg.d_model, cfg.n_head, cfg.n_kv_head,
@@ -151,7 +152,8 @@ def llama_prefill(params, tokens: jnp.ndarray, cfg: LlamaConfig, *,
             kr, vr = k, v
         o = prefill_attention(q, kr, vr, start=attn_start,
                               use_flash=cfg.use_flash,
-                              resident=cfg.flash_resident)
+                              resident=cfg.flash_resident,
+                              rules=DECODE_RULES)
         wo = p["attn"]["wo"].astype(cfg.dtype).reshape(h * hd, d)
         x = x + (o.reshape(B, T0, h * hd) @ wo).astype(x.dtype)
         xm = _rmsnorm(x, p["ln2"]["scale"], cfg.rms_eps)

@@ -19,7 +19,6 @@ from ray_tpu.parallel.mesh import (
     make_mesh,
     make_hybrid_mesh,
     active_mesh,
-    mesh_context,
     fake_mesh,
     local_mesh,
     AXIS_DATA,
@@ -45,7 +44,7 @@ from ray_tpu.parallel import collective
 __all__ = [
     "TpuGeneration", "SliceTopology", "parse_accelerator_type",
     "ici_domains", "MeshSpec", "make_mesh", "make_hybrid_mesh",
-    "active_mesh", "mesh_context", "fake_mesh", "local_mesh",
+    "active_mesh", "fake_mesh", "local_mesh",
     "LogicalAxisRules", "logical_to_mesh_axes",
     "mesh_axes_for_shape", "shard_by_shape", "shardings_by_shape",
     "shard_params", "with_logical_constraint", "DEFAULT_RULES",

@@ -155,44 +155,14 @@ def make_hybrid_mesh(spec: MeshSpec, *, num_slices: int,
 
 
 def active_mesh():
-    """The concrete Mesh made current by ``jax.set_mesh`` (or the legacy
-    ``with mesh:`` context manager), or None when no mesh is active.
+    """The concrete Mesh made current by ``jax.set_mesh``, or None when
+    no mesh is active.  All mesh-sensitive dispatch in this repo
+    (logical constraints, flash and ring attention, pipeline stages)
+    asks here, so there is one definition of "a mesh is active"."""
+    from jax._src.mesh import get_concrete_mesh
 
-    jax 0.9's ``jax.set_mesh`` populates the sharding config's
-    device_context but NOT the legacy ``thread_resources`` — code that
-    reads only ``thread_resources.env.physical_mesh`` silently sees "no
-    mesh" under ``set_mesh``.  All mesh-sensitive dispatch in this repo
-    (logical constraints, ring attention, pipeline stages) goes through
-    this helper so both entry APIs work."""
-    try:
-        from jax._src import mesh as _mesh_lib
-        m = _mesh_lib.get_concrete_mesh()
-        if m is not None and not m.empty:
-            return m
-    except Exception:  # noqa: BLE001 - older jax without get_concrete_mesh
-        pass
-    try:
-        from jax._src.mesh import thread_resources
-        m = thread_resources.env.physical_mesh
-        if not m.empty:
-            return m
-    except Exception:  # noqa: BLE001
-        pass
-    return None
-
-
-def mesh_context(mesh):
-    """Version-portable ``with mesh active:`` context manager.
-
-    ``jax.set_mesh`` appeared in newer jax; older versions use the
-    Mesh object itself as the context manager.  Callers only need the
-    mesh resource env active around their jitted steps, so either
-    spelling works — every ``with jax.set_mesh(mesh):`` site in the
-    repo (rllib algorithms, bench harness) routes through here so the
-    version shim has one home."""
-    jax, _ = _import_jax()
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
+    m = get_concrete_mesh()
+    return None if m is None or m.empty else m
 
 
 def local_mesh(spec: Optional[MeshSpec] = None):

@@ -444,8 +444,7 @@ class QPolicy:
             self.params = jax.device_put(self.params, repl)
             self.opt_state = jax.device_put(self.opt_state, repl)
             self.target_params = jax.device_put(self.target_params, repl)
-            from ray_tpu.parallel import mesh_context
-            with mesh_context(self.mesh):
+            with jax.set_mesh(self.mesh):
                 (self.params, self.opt_state, loss, tds,
                  self._train_rng) = self._update(
                     self.params, self.opt_state, self.target_params,

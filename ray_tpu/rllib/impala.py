@@ -198,8 +198,7 @@ class IMPALAPolicy:
             repl = NamedSharding(self.mesh, P())
             self.params = jax.device_put(self.params, repl)
             self.opt_state = jax.device_put(self.opt_state, repl)
-            from ray_tpu.parallel import mesh_context
-            with mesh_context(self.mesh):
+            with jax.set_mesh(self.mesh):
                 self.params, self.opt_state, stats = self._update(
                     self.params, self.opt_state, dev_batch)
             return stats

@@ -654,8 +654,7 @@ class JaxPolicy:
                    for k, v in batch.items()}
             self.params = jax.device_put(self.params, repl)
             self.opt_state = jax.device_put(self.opt_state, repl)
-            from ray_tpu.parallel import mesh_context
-            with mesh_context(self.mesh):
+            with jax.set_mesh(self.mesh):
                 (self.params, self.opt_state, stats,
                  self._rng) = self._update(self.params, self.opt_state,
                                            dev, self._rng)

@@ -224,8 +224,7 @@ class SACPolicy:
             self.params = jax.device_put(self.params, repl)
             self.opt_state = jax.device_put(self.opt_state, repl)
             self.target = jax.device_put(self.target, repl)
-            from ray_tpu.parallel import mesh_context
-            with mesh_context(self.mesh):
+            with jax.set_mesh(self.mesh):
                 (self.params, self.opt_state, self.target, stats,
                  self._rng) = self._update(self.params, self.opt_state,
                                            self.target, stacked,

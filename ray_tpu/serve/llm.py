@@ -563,9 +563,19 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
         raise ValueError("empty stop sequence")
 
     class LLM:
-        def __init__(self):
+        def __init__(self, device=None):
+            """device: the one ``jax.Device`` this engine lives on —
+            parameters, KV pool and (through their committed inputs)
+            every jitted program.  build_llm_fleet gives each replica
+            its own; None keeps JAX's default device.  A `mesh` engine
+            already has its placement."""
             import jax
             import jax.numpy as jnp
+
+            if device is not None and mesh is not None:
+                raise ValueError("an engine takes a mesh or one "
+                                 "device, not both")
+            self.device = device
 
             overrides = dict(config_overrides or {})
             (config_fn, init_fn, gen_fn, prefill_fn, step_fn,
@@ -589,6 +599,7 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
                 self.params = shard_by_shape(
                     self.params, logical_axes_fn(self.cfg), mesh,
                     DECODE_RULES)
+            self.params = self._to_engine(self.params)
             # per-call PRNG threading: without it every temperature>0
             # request would sample under the same default key and
             # return identical "random" continuations
@@ -629,6 +640,16 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
                 self._init_continuous(prefill_fn, step_fn,
                                       init_cache_fn, init_paged_fn,
                                       paged_prefill_fn)
+
+        def _to_engine(self, tree):
+            """Commit arrays made elsewhere (fresh inits, another
+            replica's handoff rows) to this engine's device; an
+            engine without one takes them as they are."""
+            import jax
+
+            if self.device is not None:
+                return jax.device_put(tree, self.device)
+            return tree
 
         # ------------------------------------------------------------
         # "batch" scheduler: @serve.batch over (possibly ragged) lists
@@ -760,6 +781,7 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
             else:
                 self._cache = init_cache_fn(cfg, max_slots,
                                             mesh=self.mesh)
+            self._cache = self._to_engine(self._cache)
             self._cur = np.zeros((max_slots,), np.int32)
             self._slots = [None] * max_slots
             self._queue = RequestQueue()
@@ -826,13 +848,13 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
                               if spec_decode.draft_seed is not None
                               else seed)
                     import jax as _jax
-                    self._draft_params = d_init_fn(
-                        _jax.random.PRNGKey(d_seed), d_cfg)
+                    self._draft_params = self._to_engine(d_init_fn(
+                        _jax.random.PRNGKey(d_seed), d_cfg))
                     # draft pool: always dense, never mesh-sharded —
                     # the draft is small by construction and a dense
                     # row pool keeps its pos arithmetic trivial
-                    self._draft_cache = d_init_cache_fn(d_cfg,
-                                                        max_slots)
+                    self._draft_cache = self._to_engine(
+                        d_init_cache_fn(d_cfg, max_slots))
                     self._draft_cfg = d_cfg
                     draft_fns = (d_prefill_fn, d_step_fn, d_cfg)
 
@@ -1374,9 +1396,10 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
             row_bt = np.zeros((self.cfg.max_seq // kv_block_size,),
                               np.int32)
             row_bt[:need] = alloc
+            k_rows, v_rows = self._to_engine(
+                (jnp.asarray(pkg.k_rows), jnp.asarray(pkg.v_rows)))
             self._cache = self._fns.kv_handoff_install(
-                self._cache, jnp.asarray(ids),
-                jnp.asarray(pkg.k_rows), jnp.asarray(pkg.v_rows),
+                self._cache, jnp.asarray(ids), k_rows, v_rows,
                 np.int32(slot), jnp.asarray(row_bt), np.int32(n))
             # fence: the handoff window must time the transfer+splice,
             # not the dispatch (the tier-restore h2d discipline)

@@ -1118,12 +1118,32 @@ def build_llm_fleet(family: str = "gpt2", preset: str = "nano", *,
     across roles — the handoff moves whole blocks.  `handoff_staged`
     forces the D2H→H2D host-staging hop (the cross-process path) even
     in-process.  `spec_decode` applies to decode replicas only
-    (drafting is decode-side work)."""
+    (drafting is decode-side work).
+
+    Placement: replica i lives on ``jax.local_devices()[i % n]`` —
+    its parameters, KV pool and programs are committed there, so four
+    replicas on a four-chip host use four chips (a handoff between
+    replicas is then a device-to-device copy).  Engines given a
+    `mesh` are placed by that mesh instead, and so is the whole fleet
+    they belong to."""
+    import jax
+
     from ray_tpu.serve.llm import build_llm_deployment
 
     engine_kw.setdefault("scheduler", "continuous")
     engine_kw.setdefault("kv_layout", "paged")
     name = fleet_name or f"fleet_{family}_{preset}"
+    devices = jax.local_devices()
+    n_placed = itertools.count()
+
+    def placed(engine_cls, *engine_kws):
+        """The replica factory: each call takes the next device, unless
+        a mesh among the fleet's engines places them already."""
+        if any(kw.get("mesh") is not None for kw in engine_kws):
+            return engine_cls
+        return lambda: engine_cls(
+            device=devices[next(n_placed) % len(devices)])
+
     disagg = (num_prefill_replicas is not None
               or num_decode_replicas is not None)
     if disagg:
@@ -1153,8 +1173,10 @@ def build_llm_fleet(family: str = "gpt2", preset: str = "nano", *,
         if max_inflight_per_replica is None:
             max_inflight_per_replica = int(dec_kw.get("max_slots", 4))
         return LLMFleet(
-            dec_dep.func_or_class, int(num_decode_replicas),
-            prefill_factory=pre_dep.func_or_class,
+            placed(dec_dep.func_or_class, pre_kw, dec_kw),
+            int(num_decode_replicas),
+            prefill_factory=placed(pre_dep.func_or_class, pre_kw,
+                                   dec_kw),
             num_prefill_replicas=int(num_prefill_replicas),
             name=name, block_size=bs_dec, tenants=tenants,
             policy=routing, wfq=wfq, autoscale=autoscale,
@@ -1165,7 +1187,7 @@ def build_llm_fleet(family: str = "gpt2", preset: str = "nano", *,
         max_inflight_per_replica = max_slots
     dep = build_llm_deployment(family, preset, **engine_kw)
     return LLMFleet(
-        dep.func_or_class, num_replicas,
+        placed(dep.func_or_class, engine_kw), num_replicas,
         name=name,
         block_size=int(engine_kw.get("kv_block_size", 16)),
         tenants=tenants, policy=routing, wfq=wfq,

@@ -27,7 +27,7 @@ roofline it sits on, and which knobs move it.
 Inputs are snapshot dicts — ``ProgramRegistry.snapshot()``,
 ``engine_stats()["programs"]``, or a dashboard ``/api/perf/programs``
 dump — so attribution runs equally on the live process and on a canned
-JSON file from a tunnel session.
+JSON file saved from a chip run.
 """
 
 from __future__ import annotations

@@ -1,8 +1,8 @@
 """Stage 2 of the autopilot loop: sweep planning.
 
 Turns "what do we not know yet" into a concrete, runnable grid.  The
-planner owns a static candidate catalog — every A/B the PERF_NOTES
-rounds queued (ce_impl, remat policy, flash residency, decode batch,
+planner owns a static candidate catalog — every A/B queued in
+ROADMAP.md A2/A3 (ce_impl, remat policy, flash residency, decode batch,
 tensor degree, spec_k, kv layout, block size, prefill buckets) as
 ``sweep_tpu.py`` ``[batch, {overrides}]`` entries — and grades each
 candidate against the ledger:
@@ -37,7 +37,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from ray_tpu.tools import perfledger
 
-#: grid entries the PERF_NOTES rounds queued, in catalog order (ties in
+#: grid entries queued in ROADMAP.md A2/A3, in catalog order (ties in
 #: priority resolve to this order).  ``programs`` names the observatory
 #: programs the knob moves — the hook that lets an attribution report
 #: re-rank the catalog around the measured bottleneck.

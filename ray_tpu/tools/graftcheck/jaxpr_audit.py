@@ -4,8 +4,8 @@
 hoping a review catches a host callback, a stray float64, or a
 rematerialized ``(B*T, V)`` logits buffer, we trace each canonical
 program (``programs.py``) and walk its equations.  The rules here grew
-out of real regressions measured on the live chip (PERF_NOTES rounds
-3-7) and out of the one-off jaxpr asserts the test suite carried
+out of real regressions measured on the chip by the builders' runs of
+2026-07-29…31 and out of the one-off jaxpr asserts the test suite carried
 before this module existed (``tests/test_fused_ce.py``,
 ``tests/test_decode_prefill.py`` — both now call the shared helpers
 below, so each invariant lives in exactly one place).
@@ -67,6 +67,7 @@ from ray_tpu.tools.graftcheck.core import Violation
 HOST_PRIMITIVES = frozenset({
     "pure_callback", "io_callback", "debug_callback", "python_callback",
     "callback", "host_callback_call", "infeed", "outfeed",
+    "debug_print",      # what jax.debug.print binds in jax 0.9
 })
 
 #: f32xf32 dot_generals at or above this many elements (largest

@@ -147,9 +147,14 @@ def extract_incidents(events: List[Dict[str, Any]]
             to = e.get("to")
             if to == "suspect" and inc["suspect_t"] is None:
                 inc["suspect_t"] = e["t"]
-            elif to == "dead" and inc["dead_t"] is None:
-                inc["dead_t"] = e["t"]
-                inc["time_to_detect_ms"] = e.get("time_to_detect_ms")
+            elif to == "dead":
+                if inc["dead_t"] is None:
+                    inc["dead_t"] = e["t"]
+                # a death before the fault (a cold replica stalled in
+                # its first compile) carries no detection latency; the
+                # one that followed the fault does
+                if inc["time_to_detect_ms"] is None:
+                    inc["time_to_detect_ms"] = e.get("time_to_detect_ms")
             elif to == "healthy":
                 inc["recover_t"] = e["t"]
         elif kind == "request_stall" and rep:

@@ -2,12 +2,12 @@
 
 Every bench.py run prints JSON metric lines and every sweep_tpu.py run
 prints ``SWEEPJSON`` records — and until now they evaporated with the
-terminal scrollback (PERF_NOTES: "everything since round 5 unmeasured").
+terminal scrollback.
 This module gives them a durable home, ``BENCH_HISTORY.jsonl`` at the
 repo root, and turns the accumulated trajectory into CI-style verdicts:
 
     python -m ray_tpu.tools.perfledger ingest bench_out.log
-    python -m ray_tpu.tools.perfledger ingest BENCH_r0*.json
+    python -m ray_tpu.tools.perfledger ingest driver_run.json
     python -m ray_tpu.tools.perfledger check            # exit 1 on regress
     python -m ray_tpu.tools.perfledger report           # markdown trends
     python -m ray_tpu.tools.perfledger publish latest   # arm the baseline

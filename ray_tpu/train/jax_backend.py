@@ -58,10 +58,7 @@ def _setup_virtual_devices(n: int):
             flags + f" --xla_force_host_platform_device_count={n}").strip()
     import jax
 
-    try:
-        jax.config.update("jax_platforms", "cpu")
-    except Exception:  # noqa: BLE001 - backend may be committed already
-        pass
+    jax.config.update("jax_platforms", "cpu")
 
 
 def _setup_jax_distributed(coordinator: str, num_processes: int,

@@ -95,34 +95,21 @@ class profile_device:
     ("tensorboard --logdir" or xprof to view); the task timeline stays
     chrome-trace.  The two share wall-clock timestamps, so aligning a
     slow task with its device activity is a same-axis comparison.
-    Degrades to a no-op (with a warning) where the backend has no
-    profiler support (e.g. some tunneled TPU plugins).
+    A profiler that will not start or stop raises: a run asked to trace
+    that returns without a trace has not done what it was asked.
     """
 
     def __init__(self, logdir: str):
         self.logdir = logdir
-        self._active = False
 
     def __enter__(self):
-        try:
-            import jax
+        import jax
 
-            jax.profiler.start_trace(self.logdir)
-            self._active = True
-        except Exception as e:  # noqa: BLE001 - no profiler support
-            import logging
-
-            logging.getLogger(__name__).warning(
-                "device profiler unavailable (%s); task timeline still "
-                "records", e)
+        jax.profiler.start_trace(self.logdir)
         return self
 
     def __exit__(self, *exc):
-        if self._active:
-            try:
-                import jax
+        import jax
 
-                jax.profiler.stop_trace()
-            except Exception:  # noqa: BLE001
-                pass
+        jax.profiler.stop_trace()
         return False

@@ -96,8 +96,10 @@ def run_one(test: dict, fast: bool) -> bool:
     if fast:
         env["RELEASE_FAST"] = "1"
     if not test.get("needs_tpu"):
-        # Control-plane workloads must not gamble on a flaky TPU plugin;
-        # only explicitly TPU-facing workloads probe for the chip.
+        # A chip belongs to one process at a time.  This runner never
+        # imports JAX and runs workloads one after another, so the one
+        # that declares needs_tpu owns the chip; every other workload
+        # is pinned to the CPU.
         env["JAX_PLATFORMS"] = "cpu"
     t0 = time.time()
     try:

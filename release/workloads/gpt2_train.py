@@ -1,11 +1,11 @@
-"""GPT-2 train smoke: loss must decrease over real optimizer steps
-(TPU when reachable, CPU-tiny otherwise)."""
+"""GPT-2 train smoke: loss must decrease over real optimizer steps (on
+the TPU; CPU-tiny only when the caller set JAX_PLATFORMS=cpu)."""
 import json
 import os
 
-import bench  # repo-root bench: bounded TPU probe + CPU pin fallback
+import bench  # repo-root bench: fails when neither holds
 
-bench.ensure_backend()
+bench.require_backend()
 import jax
 
 size = "tiny"

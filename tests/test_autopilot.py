@@ -283,19 +283,32 @@ def test_plan_biases_toward_attributed_bottleneck(tmp_path):
         in p["variants"][0]["rationale"]
 
 
-def test_plan_on_checked_in_history_is_nonempty_and_runnable(
-        monkeypatch, tmp_path):
-    """Acceptance: `autopilot plan` over the repo's BENCH_HISTORY.jsonl
-    emits a non-empty grid sweep_tpu accepts (stubbed harness), and the
-    measurement lands under the planner's predicted hash — after which
-    the candidate grades fresh."""
+@pytest.mark.parametrize("seeded", [True, False],
+                         ids=["bench-only-history", "absent-history"])
+def test_plan_on_thin_history_is_nonempty_and_runnable(
+        monkeypatch, tmp_path, seeded):
+    """Acceptance: `autopilot plan` over a history that holds bench
+    lines but no sweep variant yet — or over no history file at all,
+    which reads as an empty one — emits a non-empty grid sweep_tpu
+    accepts (stubbed harness), and the measurement lands under the
+    planner's predicted hash — after which the candidate grades
+    fresh."""
     import sweep_tpu
 
-    p = planner.plan(history=str(ROOT / "BENCH_HISTORY.jsonl"),
-                     budget=4)
+    first = str(tmp_path / "first.jsonl")
+    if seeded:
+        pl.append_records(
+            [{"metric": "gpt2_124m_train_tokens_per_sec_per_chip",
+              "value": 91965.0, "unit": "tokens/s/chip",
+              "vs_baseline": 0.872,
+              "detail": {"backend": "tpu", "batch": 24, "chips": 1,
+                         "seq": 1024, "remat_policy": "mlp_only"}},
+             _bench_rec(10.1, "gpt2_decode_spec_cpu_smoke_tokens_per_sec")],
+            "bench", path=first)
+    p = planner.plan(history=first, budget=4)
     assert p["grid"]
     train_entries = [g for g in p["grid"] if "mode" not in g[1]]
-    assert train_entries, "checked-in history leaves train A/Bs queued"
+    assert train_entries, "a thin history leaves train A/Bs queued"
     monkeypatch.setattr(sweep_tpu, "time_config", _stub_time_config)
     hist = str(tmp_path / "hist.jsonl")
     recs = sweep_tpu.run_sweep(train_entries[:1], n_chips=1,

@@ -32,6 +32,7 @@ from ray_tpu.tools.graftcheck.jaxpr_audit import ProgramSpec, audit_program
 from ray_tpu.tools.graftcheck.lint import (KERNEL_EXPORTS,
                                            _autopilot_attribution,
                                            _observatory_mapping,
+                                           _pallas_interpret_tests,
                                            lint_repo, lint_source,
                                            pallas_modules)
 
@@ -104,6 +105,26 @@ def test_pallas_module_detector_not_vacuous():
     stems = pallas_modules(ROOT)
     assert "flash_attention" in stems
     assert "fused_ce" in stems
+
+
+def test_new_pallas_kernel_needs_both_chipless_checks(tmp_path):
+    """A kernel needs an interpret-mode numerics test AND an AOT compile
+    for the described TPU topology (tests/test_tpu_compile.py)."""
+    ops = tmp_path / "ray_tpu" / "ops"
+    ops.mkdir(parents=True)
+    (ops / "newkern.py").write_text("pl.pallas_call(kernel)\n")
+    tests = tmp_path / "tests"
+    tests.mkdir()
+    found = _pallas_interpret_tests(tmp_path)
+    assert len(found) == 2
+    assert all(v.rule == "pallas-interpret-test" for v in found)
+    (tests / "test_newkern.py").write_text("run(interpret=True)\n")
+    (tests / "test_tpu_compile.py").write_text("# flash only\n")
+    found = _pallas_interpret_tests(tmp_path)
+    assert len(found) == 1 and "test_tpu_compile.py" in found[0].message
+    (tests / "test_tpu_compile.py").write_text(
+        "from ray_tpu.ops.newkern import k\n")
+    assert _pallas_interpret_tests(tmp_path) == []
 
 
 def test_kernel_exports_not_vacuous():
@@ -193,12 +214,10 @@ def test_planted_host_transfer_detected():
 
 
 def test_planted_f64_detected():
-    from jax.experimental import enable_x64
-
     def fn(x):
         return (x.astype(jnp.float64) * 2.0).astype(jnp.float32)
 
-    with enable_x64():
+    with jax.enable_x64(True):
         vs, _ = audit_program(_spec(fn, (jnp.zeros((8, 8)),)))
     assert "f64" in _rules(vs)
 

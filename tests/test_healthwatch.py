@@ -291,7 +291,13 @@ def _oracle(prompt, max_new=MAX_NEW):
 
 
 def test_chaos_freeze_detected_requeued_and_oracle_identical(
-        tmp_path, capsys):
+        tmp_path, capsys, monkeypatch):
+    # a one-chip host: both replicas share device 0 and its compiled
+    # programs.  On distinct devices each replica stalls in its own
+    # first compile, which the 30/90 ms thresholds below read as
+    # deaths of their own, before and between the injected one.
+    one_chip = jax.local_devices()[:1]
+    monkeypatch.setattr(jax, "local_devices", lambda: one_chip)
     rng = np.random.RandomState(7)
     # fixed-length prompts: the oracle's generate jit compiles once
     prompts = [rng.randint(2, 500, 24).astype(np.int32)

@@ -121,8 +121,9 @@ def test_profile_device_captures_xplane(tmp_path):
                      recursive=True)
 
 
-def test_profile_device_degrades_gracefully(tmp_path, monkeypatch):
-    """No profiler support -> warning + no-op, never an exception."""
+def test_profile_device_raises_when_profiler_fails(tmp_path, monkeypatch):
+    """A profiler that will not start is an error, not a silent run
+    without a trace."""
     import jax
 
     from ray_tpu.util.state import profile_device
@@ -131,5 +132,6 @@ def test_profile_device_degrades_gracefully(tmp_path, monkeypatch):
         raise RuntimeError("no profiler on this backend")
 
     monkeypatch.setattr(jax.profiler, "start_trace", boom)
-    with profile_device(str(tmp_path / "x")):
-        pass  # must not raise
+    with pytest.raises(RuntimeError, match="no profiler"):
+        with profile_device(str(tmp_path / "x")):
+            pass

@@ -1,0 +1,104 @@
+"""chip_smoke.py's control flow, walked on the CPU at toy size.
+
+The script proves the system on the chip; this proves the script: every
+phase runs to its end and checks what it says it checks, and off the
+chip the script exits non-zero without ever printing its ``"ok": true``
+line.  The steering a chip needs none of happens here, in the test: the
+Pallas kernels run in interpret mode, the four-chip phases get four of
+conftest's virtual devices, and the runtime phase is told its chip count
+(there is no chip for init() to find).
+"""
+
+import dataclasses
+import functools
+import importlib
+import os
+import pathlib
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(ROOT))
+
+import chip_smoke  # noqa: E402
+
+TOY = chip_smoke.Size(
+    preset="nano", seq=128, batch=4, steps=2,
+    cfg={"use_flash": True, "dtype": jnp.float32},
+    attn_shape=(1, 128, 2, 32), ce_shape=(64, 64, 512, 500),
+    requests=10, warm_requests=8, prefix_groups=2, prefix_len=32,
+    tail_mean=6.0, tail_max=16, vocab=500, rate_rps=200.0, max_slots=4,
+    new_tokens=8, prefill_bucket=16)
+
+
+@pytest.fixture
+def interpreted(monkeypatch):
+    """Interpret-mode kernels, chosen by the test: the model's flash
+    dispatch and the script's own kernel checks."""
+    flash = importlib.import_module("ray_tpu.ops.flash_attention")
+    monkeypatch.setattr(flash, "flash_attention", functools.partial(
+        flash.flash_attention, interpret=True))
+    monkeypatch.setattr(chip_smoke, "check_kernels", functools.partial(
+        chip_smoke.check_kernels, interpret=True))
+
+
+@pytest.fixture
+def four_devices(monkeypatch):
+    devices = jax.devices()[:4]
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: devices)
+
+
+def test_train_phase(interpreted):
+    out = chip_smoke.phase_train(TOY, "cpu")
+    assert out["device"]["platform"] == "cpu"
+    assert len(out["losses"]) == TOY.steps
+
+
+def test_serve_phase(interpreted):
+    out = chip_smoke.phase_serve(TOY, "cpu")
+    assert out["token_identical"]
+
+
+def test_runtime_phase(monkeypatch, tmp_path):
+    """The actor is another process, out of reach of an interpret-mode
+    patch, so its toy model takes XLA attention."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    size = dataclasses.replace(
+        TOY, cfg={"use_flash": False, "dtype": jnp.float32})
+    out = chip_smoke.phase_runtime(size, "cpu", num_tpus=1)
+    assert out["device"]["platform"] == "cpu"
+    assert len(out["losses"]) == 2
+
+
+@pytest.mark.parametrize("phase,preset", [
+    ("mesh_train", "nano"),
+    ("tensor_serve", "tiny"),    # four heads: tensor=4 really splits them
+    ("fleet", "nano"),
+])
+def test_four_chip_phase(interpreted, four_devices, phase, preset):
+    out = chip_smoke.PHASES[phase](
+        dataclasses.replace(TOY, preset=preset), "cpu")
+    assert out["device"]["count"] == 4
+
+
+def test_phase_refuses_another_platform():
+    with pytest.raises(SystemExit, match="no accelerator"):
+        chip_smoke.phase_train(TOY, "tpu")
+
+
+@pytest.mark.parametrize("argv", [[], ["--chips", "4"]],
+                         ids=["one-chip", "four-chips"])
+def test_script_fails_off_the_chip(argv):
+    """JAX_PLATFORMS=cpu (as in a sandbox without a chip): a non-zero
+    exit and no result line."""
+    proc = subprocess.run(
+        [sys.executable, str(ROOT / "chip_smoke.py"), *argv],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), cwd=ROOT,
+        capture_output=True, text=True, timeout=300)
+    assert proc.returncode != 0
+    assert '"ok": true' not in proc.stdout
+    assert "no accelerator" in proc.stderr
