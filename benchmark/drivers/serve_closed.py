@@ -1,0 +1,164 @@
+"""Driver ``serve_closed``: N clients, each sending its next prompt when
+its answer is whole.  A slow system receives less load, so what is
+judged is the tokens per second it completes: the tokens stamped inside
+the window over the window's length (benchmark/estimators.py
+emission_rate), so a stall that runs on to the window's end lowers it.
+The window is cut by the clock; requests in flight then count what they
+produced inside it and are not drained.  That the engine was still
+alive at the cut is checked apart: the clients keep the load on past
+it until the engine emits its next token (32 clients in lockstep finish
+a wave together, and would otherwise all see the clock pass and leave
+an idle engine), and an engine that emits nothing for ``drain_s``
+seconds after the cut has fallen silent: the run is not ``correct`` and
+its requests in flight count as failed.
+
+Set-up runs one request through each prefill shape the clients will
+use, and a repeat of the first (a prefix hit), to the end of their
+answers: a request cannot be cut short, so this costs one full answer's
+decode time (PERF.md, Open questions: an output length per request).
+"""
+
+from __future__ import annotations
+
+import asyncio
+import time
+import types
+
+from benchmark import correct, estimators
+from benchmark.harness import Ctx, Profiler, memory_peak_bytes, say
+from benchmark.serving import (Sender, all_token_stamps, build_engine,
+                               in_flight_spans, last_emission, padded,
+                               trace_between, trace_window, warm_up)
+from benchmark.traffic_gen import Request, TrafficGenerator
+
+
+def warmup_requests(gen: TrafficGenerator, clients, eng):
+    """One unshared request per padded prompt length in use, then the
+    longest of them again: its blocks are resident by then, so it is
+    the prefix-hit case."""
+    by_pad = {}
+    for row in clients:
+        for r in row:
+            by_pad.setdefault(padded(len(r.prompt), eng.bucket),
+                              r.tail_len)
+    reqs, labels = [], []
+    for i, (p, t) in enumerate(sorted(by_pad.items())):
+        reqs.append(Request(index=-1 - i, prompt=gen.prompt(-1, t),
+                            group=-1, tail_len=t))
+        labels.append(f"cold_pad{p}")
+    longest = reqs[-1]
+    reqs.append(Request(index=-1 - len(reqs), prompt=longest.prompt,
+                        group=-1, tail_len=longest.tail_len))
+    labels.append("repeat_hit")
+    return reqs, labels
+
+
+def run(ctx: Ctx):
+    import jax
+
+    from ray_tpu._private.compile_cache import CompileWatch
+
+    traffic = ctx.cell.traffic
+    split = {"import_s": time.perf_counter() - ctx.t_start}
+    watch = CompileWatch()
+    t_phase = time.perf_counter()
+    engine, eng = build_engine(ctx)
+    gen = TrafficGenerator(traffic, ctx.seed, engine.cfg.vocab_size)
+    clients = gen.closed_loop()
+    flat = [r for row in clients for r in row]
+    warm, labels = warmup_requests(gen, clients, eng)
+    split["engine_s"] = time.perf_counter() - t_phase
+    say("traffic", clients=len(clients), turns=len(clients[0]),
+        prompt_min=min(len(r.prompt) for r in flat),
+        prompt_max=max(len(r.prompt) for r in flat), warmup=labels)
+    trace_at = trace_window(ctx)
+    out = types.SimpleNamespace(trace=None)
+    prof = Profiler(ctx)
+    if trace_at:
+        prof.prime()
+
+    async def main():
+        checks = await warm_up(
+            ctx, engine, eng, warm, labels,
+            [(labels[-2], False), ("repeat_hit", True)], watch, split)
+
+        sender = Sender(engine)
+        compiles_before = watch.compiles
+        t0 = time.perf_counter()
+        setup_s = t0 - ctx.t_start
+        t1 = t0 + ctx.seconds
+
+        closed = asyncio.Event()
+
+        async def client(row) -> bool:
+            """True if it ran out of prompts before the engine was seen
+            alive past the cut (turns_per_client is then too small)."""
+            for req in row:
+                if closed.is_set():
+                    return False
+                await sender.send(req)
+            return not closed.is_set()
+
+        tasks = [asyncio.ensure_future(client(row)) for row in clients]
+        tracer = asyncio.ensure_future(
+            trace_between(prof, out, t0, trace_at)) if trace_at else None
+        await asyncio.wait(tasks, timeout=ctx.seconds)
+        # run on to the engine's next emission: alive at the cut
+        give_up = t1 + float(traffic["drain_s"])
+        while (last_emission(engine) < t1
+               and time.perf_counter() < give_up):
+            await asyncio.sleep(0.02)
+        alive = last_emission(engine) >= t1
+        closed.set()
+        t_end = time.perf_counter()
+        compiles_in_window = watch.compiles - compiles_before
+        if tracer is not None:
+            await tracer
+        rows = sender.rows(flat, eng.new_tokens)
+        stamps = all_token_stamps(engine)
+        engine.shutdown_engine()
+        done = [t for t in tasks if t.done()]
+        pending = [t for t in tasks if not t.done()]
+        for t in pending:
+            t.cancel()
+        await asyncio.gather(*pending, return_exceptions=True)
+        exhausted = sum(t.result() for t in done)
+        return types.SimpleNamespace(
+            setup_s=setup_s, t0=t0, t_end=t_end, rows=rows, checks=checks,
+            compiles_in_window=compiles_in_window, stamps=stamps,
+            exhausted=exhausted, alive=alive)
+
+    r = asyncio.run(main())
+    if trace_at:
+        out.trace = prof.reduce()
+    # a request whose answer the client holds, or that failed; the
+    # ones in flight at the cut are cut, not failed -- unless the
+    # engine never emitted again: then it fell silent with them inside
+    t1 = r.t0 + ctx.seconds
+    rate = estimators.emission_rate(r.stamps, r.t0, t1)
+    finished = [x for x in r.rows if x["collected"] or x["error"]]
+    failed = correct.count_failed(finished, eng.new_tokens)
+    if not r.alive:
+        failed += len(r.rows) - len(finished)
+    for c in r.checks:
+        say("correct", **c)
+    say("window", sent=len(r.rows), finished=len(finished), failed=failed,
+        clients_out_of_prompts=r.exhausted,
+        compiles_in_window=r.compiles_in_window,
+        tokens_in_window=rate and rate[1],
+        next_emission_after_cut_s=round(
+            min((t for t in r.stamps if t >= t1), default=t1) - t1, 4),
+        ran_on_s=round(r.t_end - t1, 3), alive_at_cut=r.alive)
+    say("setup_split", **{k: round(v, 3) for k, v in split.items()},
+        setup_s=round(r.setup_s, 3))
+    return types.SimpleNamespace(
+        ctx=ctx, setup_s=r.setup_s,
+        correct=all(c["ok"] for c in r.checks) and failed == 0
+        and r.exhausted == 0 and len(finished) > 0 and r.alive,
+        attempted=len(r.rows), failed=failed, rows=r.rows, t0=r.t0,
+        t1=t1, stamps=r.stamps, engine=eng,
+        compiles_in_window=r.compiles_in_window, trace=out.trace,
+        trace_t0=getattr(out, "trace_t0", None),
+        trace_t1=getattr(out, "trace_t1", None),
+        in_flight=in_flight_spans(r.rows, r.t_end),
+        memory_peak_bytes=memory_peak_bytes(jax.devices()[:1]))
