@@ -1,0 +1,7 @@
+"""Device self time of the train step's ops under scope ``mlp`` over the
+step's, %."""
+from benchmark.reduce import program
+
+
+def read(run):
+    return program.scope_share(run, "mlp")

@@ -17,14 +17,30 @@ import os
 ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
 _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-# A Mosaic kernel travels inside the program as bytecode with its debug
-# locations, which XLA's key hashes along with the rest; by default a
-# location is the whole Python call stack at trace time.  The same train
-# step reached from another call site — another script, an actor's
-# worker — then never hits (seen on the chip: the actor recompiled what
-# the driver's child had just cached).  Innermost frame only: the
-# kernel's own source line, the same wherever it is called from.
-_FULL_TRACEBACKS = "jax_include_full_tracebacks_in_locations"
+# Two settings go with the cache (PR 25 changed both; read off the chip's
+# compiled text and its cache):
+#
+# * A Mosaic kernel travels inside the program as bytecode with its debug
+#   locations, which XLA's key hashes along with the rest; by default a
+#   location is the Python call stack at trace time, ten frames deep.
+#   The same train step reached from another call site -- another
+#   script, an actor's worker -- then never hits (seen on the chip: the
+#   actor recompiled what the driver's child had just cached).  So no
+#   frames at all: a location is the name stack alone, the same wherever
+#   the program is traced from and in whichever directory the checkout
+#   lies.  (Until PR 25 this was jax_include_full_tracebacks_in_locations
+#   = False, which gives the same key but lowers every instruction's
+#   ``op_name`` to its bare primitive, ``mul``: the name stack, and with
+#   it every ``jax.named_scope`` of _private/scopes.py, never reached the
+#   compiled program.)
+# * By default the key leaves metadata out, so a hit may hand back an
+#   executable compiled from the same graph under other names -- by the
+#   commit before, say, whose text knows no scope: the scope map
+#   (device_stats.ProgramRegistry.scope_map) read 0 entries for a
+#   program the parent commit had compiled first.  The names are what
+#   that map reads, so they are part of the key.
+_SETTINGS = {"jax_traceback_in_locations_limit": 0,
+             "jax_compilation_cache_include_metadata_in_key": True}
 
 
 def compile_cache_dir() -> str:
@@ -40,16 +56,18 @@ def enable_compile_cache() -> str:
     path = compile_cache_dir()
     if not os.environ.get(ENV_VAR):
         jax.config.update("jax_compilation_cache_dir", path)
-    jax.config.update(_FULL_TRACEBACKS, False)
+    for name, value in _SETTINGS.items():
+        jax.config.update(name, value)
     return path
 
 
 def compile_cache_env() -> dict:
-    """The same two settings as environment variables, for a process
+    """The same settings as environment variables, for a process
     that is about to be started (a TPU worker) — JAX reads both at
     import, so the worker need not import JAX early to get them."""
     return {ENV_VAR: compile_cache_dir(),
-            _FULL_TRACEBACKS.upper(): "False"}
+            **{name.upper(): str(value)
+               for name, value in _SETTINGS.items()}}
 
 
 class CompileWatch:

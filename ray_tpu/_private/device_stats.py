@@ -19,7 +19,13 @@ of named jitted programs (``serve.prefill``, ``serve.decode``,
   dynamic shapes eating the serving hot path);
 * **live roofline MFU** — achieved FLOPs/s from the compiler's own
   FLOP count over the recent invoke-time window, divided by the
-  devices' peak (no hand-counted ``6*N*D`` formula involved).
+  devices' peak (no hand-counted ``6*N*D`` formula involved);
+* **scope map** — from a like harvest at every fresh signature,
+  ``{instruction name: {result type and opcode: innermost named
+  scope}}`` of the compiled text (``_private/scopes.py``): a profiler
+  trace names an executed op by its instruction, not by its metadata,
+  so this small map is what joins a device event to ``attn`` / ``mlp``
+  / ``kv_pool`` (``scope_map(program)``).
 
 Everything is surfaced three ways: Prometheus metrics
 (``device_program_compile_events_total`` / ``device_program_compile_seconds_total``
@@ -259,8 +265,29 @@ def _cost_summary(compiled: Any) -> Dict[str, Any]:
     return out
 
 
+def _scoped(compiled: Any) -> Optional[tuple]:
+    """(HloModule name, ``scopes.ScopeMap``) from a compiled program's
+    text; the text itself is not kept."""
+    try:
+        from ray_tpu._private import scopes
+
+        text = compiled.as_text()
+        return scopes.hlo_module_name(text), \
+            scopes.scope_map_from_hlo(text)
+    except Exception:  # noqa: BLE001 - backend without HLO text
+        return None
+
+
+#: fresh signatures of one program whose scope map is harvested: a
+#: program has a handful (the prefill buckets); one that keeps
+#: compiling (a recompile storm) must not pay a side compile each time
+_SCOPED_SIGNATURES = 16
+
+
 def cost_capture_enabled() -> bool:
-    """The AOT cost harvest doubles one compile per program; huge
+    """The AOT harvest (cost model at a program's first signature, scope
+    map at every fresh one) compiles a fresh signature a second time,
+    which the persistent compile cache answers where it is on; huge
     models can turn it off process-wide."""
     return os.environ.get("RAYTPU_DEVICE_STATS_COST", "1") != "0"
 
@@ -306,6 +333,11 @@ class ProgramRegistry:
                 "invoke_events": collections.deque(
                     maxlen=self._invoke_history),
                 "cost": {},
+                # compiled texts' {instruction: {key: scope}}, every
+                # signature's merged into one, and the HloModule name
+                # (what a trace calls the program)
+                "scope_map": None,
+                "module": None,
                 "storms": 0,
                 "storm_active": False,
             }
@@ -313,9 +345,11 @@ class ProgramRegistry:
 
     def record_compile(self, program: str, seconds: float,
                        cost: Optional[Dict[str, Any]] = None,
-                       now: Optional[float] = None) -> None:
+                       now: Optional[float] = None,
+                       scoped: Optional[tuple] = None) -> None:
         """One XLA compile of `program` taking `seconds` walltime;
-        `cost` is a ``_cost_summary`` dict when the harvest ran."""
+        `cost` is a ``_cost_summary`` dict and `scoped` a ``(module
+        name, scope map)`` pair when the harvest ran."""
         ts = self._now() if now is None else now
         with self._lock:
             rec = self._rec(program)
@@ -324,6 +358,12 @@ class ProgramRegistry:
             rec["compile_times"].append(ts)
             if cost:
                 rec["cost"] = dict(cost)
+            if scoped:
+                from ray_tpu._private.scopes import merge_scope_maps
+
+                rec["module"] = scoped[0]
+                rec["scope_map"] = merge_scope_maps(
+                    rec["scope_map"] or {}, scoped[1])
             recent = [t for t in rec["compile_times"]
                       if ts - t <= self.storm_window_s]
             storm = len(recent) >= self.storm_threshold
@@ -381,6 +421,26 @@ class ProgramRegistry:
             return {name: list(rec["invoke_events"])
                     for name, rec in self._programs.items()
                     if prefix is None or name.startswith(prefix)}
+
+    def scope_map(self, program: str
+                  ) -> Optional[Dict[str, Dict[str, str]]]:
+        """``{instruction name: {instruction key: innermost registered
+        scope}}`` of `program` over every signature it was compiled at
+        (the prefill buckets), or None where no harvest ran.  `program`
+        is the registry's name (``serve.decode``) or the HloModule's
+        (``jit_pool_step``, what a trace's ``XLA Modules`` line says).
+        XLA numbers a signature's ``fusion.N`` anew, so a name alone is
+        no identity: a reader takes an event's scope only where the
+        event's key (``scopes.instruction_key``: result type and
+        opcode) is the entry's too."""
+        with self._lock:
+            rec = self._programs.get(program)
+            if rec is None:
+                rec = next((r for r in self._programs.values()
+                            if r["module"] == program), None)
+            return None if rec is None or rec["scope_map"] is None \
+                else {name: dict(keyed)
+                      for name, keyed in rec["scope_map"].items()}
 
     def compile_windows(self, prefix: Optional[str] = None
                         ) -> Dict[str, List[tuple]]:
@@ -474,31 +534,35 @@ class ProgramRegistry:
             except Exception:  # noqa: BLE001
                 sig = None
             fresh = False
-            do_harvest = False
+            do_harvest = first = False
             if sig is not None:
                 with seen_lock:
                     fresh = sig not in seen
                     if fresh:
                         seen.add(sig)
-                    # claim the one-shot cost harvest under the same
-                    # lock: two threads compiling fresh signatures
-                    # concurrently must not both run the AOT side
-                    # compile (the unlocked check-then-act raced)
-                    if (fresh and not harvested[0]
+                    # claim the first signature's cost harvest under
+                    # the same lock: two threads compiling fresh
+                    # signatures concurrently must not both take it
+                    # (the unlocked check-then-act raced)
+                    if (fresh and len(seen) <= _SCOPED_SIGNATURES
                             and cost_capture_enabled()
                             and hasattr(fn, "lower")):
-                        harvested[0] = True
                         do_harvest = True
+                        first, harvested[0] = not harvested[0], True
             if fresh:
-                cost = None
+                cost = scoped = None
                 t0 = time.perf_counter()
                 if do_harvest:
                     try:
-                        # side AOT compile of the first signature, only
-                        # for its cost/memory analysis — the executing
-                        # call below still goes through fn's jit cache
-                        cost = _cost_summary(
-                            fn.lower(*args, **kwargs).compile())
+                        # side AOT compile of a fresh signature: the
+                        # first one's cost/memory analysis, and every
+                        # one's scope map (XLA names each signature's
+                        # instructions anew) — the executing call below
+                        # still goes through fn's jit cache
+                        compiled = fn.lower(*args, **kwargs).compile()
+                        if first:
+                            cost = _cost_summary(compiled)
+                        scoped = _scoped(compiled)
                     except Exception:  # noqa: BLE001
                         cost = None
                 # the first call with a fresh signature IS the compile:
@@ -507,7 +571,8 @@ class ProgramRegistry:
                 # invoke window so the live MFU is not diluted
                 out = fn(*args, **kwargs)
                 registry.record_compile(
-                    program, time.perf_counter() - t0, cost=cost)
+                    program, time.perf_counter() - t0, cost=cost,
+                    scoped=scoped)
                 return out
             t0 = time.perf_counter()
             out = fn(*args, **kwargs)

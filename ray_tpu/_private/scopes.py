@@ -1,0 +1,170 @@
+"""The names the program gives its own work on the profiler's timeline.
+
+Two kinds, one registry (``PERF.md`` §3 quotes these constants; only
+``benchmark/reduce/program.py`` spells the strings again, because it
+has to load against a checkout without this module, and a test holds
+the two equal):
+
+* **device scopes** -- ``jax.named_scope`` names inside the jitted
+  programs.  A scope is metadata only: it lands in every HLO
+  instruction's ``op_name`` (``jit(step)/loss_and_grad/jvp(attn)/...``)
+  and costs nothing at run time.  A trace event carries the
+  instruction's name, not its metadata, so the join is by instruction
+  name, checked by result type and opcode: :func:`scope_map_from_hlo`
+  reads a compiled program's text once into ``{instruction: {key:
+  innermost registered scope}}``
+  (``device_stats.ProgramRegistry.scope_map``).
+* **host phases** -- ``raytpu.<layer>.<phase>`` spans that
+  ``_private/telemetry.Phases`` opens as ``jax.profiler
+  .TraceAnnotation``, so they sit on the profiler's own clock beside the
+  device events.
+
+This module imports nothing heavy: the runtime's driver never imports
+JAX.
+"""
+
+from __future__ import annotations
+
+import re
+from typing import Dict, Optional
+
+# -- device scopes: models ---------------------------------------------------
+EMBED = "embed"              # token + position lookup
+ATTN = "attn"                # qkv projection, attention, output projection
+MLP = "mlp"
+LN = "ln"                    # every layernorm call site
+LM_HEAD_CE = "lm_head_ce"    # training: tied logits + cross-entropy
+LM_HEAD = "lm_head"          # decoding: final logits
+KV_POOL = "kv_pool"          # reads and writes of the K/V cache or pool
+SAMPLE = "sample"            # decode_common.sample_token
+#: the decode programs' scan over layers: what no inner scope claims is
+#: the scan's own plumbing (slicing the stacked weights, stacking the
+#: per-layer K/V it returns)
+LAYER_SCAN = "layer_scan"
+# -- device scopes: trainer --------------------------------------------------
+LOSS_AND_GRAD = "loss_and_grad"
+OPTIMIZER = "optimizer"
+
+DEVICE_SCOPES = frozenset((EMBED, ATTN, MLP, LN, LM_HEAD_CE, LM_HEAD,
+                           KV_POOL, SAMPLE, LAYER_SCAN, LOSS_AND_GRAD,
+                           OPTIMIZER))
+
+# -- Pallas kernel names (ops/flash_attention.py ``pallas_call(name=)``) -----
+FLASH_FWD, FLASH_DQ, FLASH_DKV = "flash_fwd", "flash_dq", "flash_dkv"
+FLASH_RES_FWD, FLASH_RES_DQ, FLASH_RES_DKV = (
+    "flash_res_fwd", "flash_res_dq", "flash_res_dkv")
+KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV,
+           FLASH_RES_FWD, FLASH_RES_DQ, FLASH_RES_DKV)
+
+# -- host phases -------------------------------------------------------------
+SPAN_PREFIX = "raytpu."
+ENGINE = "engine"            # layer of serve/llm.py's scheduler loop
+STEP = "step"                # one loop iteration with work in it
+LOOP = "loop"                # a step's own bookkeeping between two phases
+#: leaf phases of one ``raytpu.engine.step``; they partition it
+ENGINE_PHASES = (
+    LOOP, "admit", "kv.reserve", "prefill_dispatch", "prefill_fence",
+    "rng_split", "decode_dispatch", "decode_fence", "emit", "hooks",
+    "prefill_chunk", "spec_round", "yield")
+#: where the host only waits for the device
+ENGINE_FENCES = ("decode_fence", "prefill_fence")
+
+
+def span_name(layer: str, phase: str) -> str:
+    """``raytpu.<layer>.<phase>``: a host phase's name in a trace."""
+    return f"{SPAN_PREFIX}{layer}.{phase}"
+
+
+# ---------------------------------------------------------------------------
+# compiled text -> {instruction name: {instruction key: innermost scope}}
+# ---------------------------------------------------------------------------
+
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*)$", re.M)
+_OP_NAME = re.compile(r'metadata=\{[^}\n]*?op_name="([^"]*)"')
+_MODULE = re.compile(r"^HloModule ([\w.\-]+)", re.M)
+#: what a transformation wraps a scope's name in: ``transpose(jvp(attn))``
+_WRAPPED = re.compile(r"^(?:jvp|transpose|vmap)\((.*)\)$")
+#: the scopes that only hold other scopes: time whose innermost scope is
+#: one of these belongs to no part of the model (a layer that lost its
+#: scope would land here), so a reader counts it with the unscoped
+CONTAINER_SCOPES = frozenset((LOSS_AND_GRAD, LAYER_SCAN))
+#: one name and key, two scopes, in two signatures of one program
+AMBIGUOUS = "ambiguous"
+
+ScopeMap = Dict[str, Dict[str, str]]
+
+
+def innermost_scope(op_name: str) -> Optional[str]:
+    """The last registered scope on an instruction's ``op_name`` path,
+    or None: ``jit(step)/loss_and_grad/transpose(jvp(attn))/ln/mul`` is
+    ``ln``."""
+    for segment in reversed(op_name.split("/")):
+        while True:
+            m = _WRAPPED.match(segment)
+            if m is None:
+                break
+            segment = m.group(1)
+        if segment in DEVICE_SCOPES:
+            return segment
+    return None
+
+
+def hlo_module_name(hlo_text: str) -> Optional[str]:
+    """``HloModule jit_pool_step, ...`` -> ``jit_pool_step``: the name a
+    trace's ``XLA Modules`` line gives the program."""
+    m = _MODULE.search(hlo_text)
+    return m.group(1) if m else None
+
+
+def instruction_key(hlo_line: str) -> str:
+    """``%fusion.3 = bf16[8,64]{1,0:T(8,128)} fusion(bf16[...] %p), ...``
+    -> ``bf16[8,64]{1,0:T(8,128)} fusion``: an instruction's result type
+    (layout included) and opcode.  The compiled text and a trace's op
+    event print these alike (the event adds the operands' types), and an
+    instruction's name alone is not an identity: another signature of
+    the program (a prefill bucket) numbers its ``fusion.N`` anew."""
+    return _key_of_body(hlo_line.split(" = ", 1)[-1])
+
+
+def _key_of_body(body: str) -> str:
+    """`instruction_key` of what follows an instruction's ``name = ``
+    (the rest may hold `` = `` again, inside a ``backend_config``)."""
+    depth = 0
+    for i, ch in enumerate(body):
+        if ch in "({":
+            depth += 1
+        elif ch in ")}":
+            depth -= 1
+        elif ch == " " and depth == 0:      # the type ends here
+            j = body.find("(", i)
+            return body[:j] if j > 0 else body
+    return body
+
+
+def scope_map_from_hlo(hlo_text: str) -> ScopeMap:
+    """``{instruction name: {instruction key: innermost registered
+    scope}}`` over every instruction of a compiled program's text whose
+    metadata names a scope.  Instructions without one are left out, so
+    the map stays small and a reader counts what it does not find as
+    unscoped."""
+    out: ScopeMap = {}
+    for name, body in _INSTRUCTION.findall(hlo_text):
+        m = _OP_NAME.search(body)
+        scope = innermost_scope(m.group(1)) if m else None
+        if scope is not None:
+            out[name] = {_key_of_body(body): scope}
+    return out
+
+
+def merge_scope_maps(into: ScopeMap, other: ScopeMap) -> ScopeMap:
+    """Add another signature's map of the same program to `into`.  A
+    name may stand for different instructions in two signatures; the key
+    tells them apart.  Where name and key agree and the scope does not,
+    the entry becomes :data:`AMBIGUOUS` and a reader counts it as
+    unscoped."""
+    for name, keyed in other.items():
+        mine = into.setdefault(name, {})
+        for key, scope in keyed.items():
+            mine[key] = scope if mine.get(key, scope) == scope \
+                else AMBIGUOUS
+    return into

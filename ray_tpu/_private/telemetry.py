@@ -6,7 +6,7 @@ device buffer or forces a sync; producers time around syncs the hot
 path already performs (the np.asarray fence in the decode engine, the
 float(loss) fence in training loops).
 
-Two shared pieces live here:
+Three shared pieces live here:
 
 * percentile summaries over raw latency samples (the ``engine_stats()``
   p50/p95/p99 blocks), nearest-rank so a 3-sample TTFT series reports
@@ -14,14 +14,21 @@ Two shared pieces live here:
 * chrome-trace event builders emitting the exact shape
   ``ray_tpu.timeline()`` writes (name/cat/ph/ts/dur/pid/tid/args, ts in
   microseconds) so engine timelines and task timelines open in the same
-  chrome://tracing / Perfetto view.
+  chrome://tracing / Perfetto view;
+* host phases (:class:`Phases`): what a loop does between device
+  calls, as ``raytpu.<layer>.<phase>`` spans on the
+  profiler's own clock plus one ``{phase: [count, seconds]}`` table.
 """
 
 from __future__ import annotations
 
 import json
 import math
+import sys
+import time
 from typing import Any, Dict, List, Optional, Sequence
+
+from ray_tpu._private import scopes
 
 #: percentiles every summarize() block reports
 PERCENTILES = (50, 95, 99)
@@ -92,3 +99,150 @@ def write_chrome_trace(events: List[Dict[str, Any]],
         with open(filename, "w") as f:
             json.dump(events, f)
     return events
+
+
+# ---------------------------------------------------------------------------
+# host phases on the profiler's clock
+# ---------------------------------------------------------------------------
+
+def _annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` ``raytpu.<name>``, entered,
+    or None in a process that has not imported JAX (the runtime's
+    driver never does).  Without a profiler session the annotation
+    records nothing."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    ann = jax.profiler.TraceAnnotation(scopes.SPAN_PREFIX + name)
+    ann.__enter__()
+    return ann
+
+
+class _Phase:
+    """One ``with phases.phase(name)`` block; ``t0`` and ``t1`` are its
+    ``perf_counter`` stamps, for a caller that wants the duration it
+    would otherwise time a second time."""
+
+    __slots__ = ("_owner", "_name", "t0", "t1")
+
+    def __init__(self, owner: "Phases", name: str):
+        self._owner, self._name = owner, name
+        self.t0 = self.t1 = 0.0
+
+    def __enter__(self) -> "_Phase":
+        self.t0 = self._owner._push(self._name) * 1e-9
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.t1 = self._owner._pop() * 1e-9
+        return False
+
+
+class _Step:
+    __slots__ = ("_owner",)
+
+    def __init__(self, owner: "Phases"):
+        self._owner = owner
+
+    def __enter__(self) -> "_Step":
+        self._owner._begin_step()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self._owner._end_step()
+        return False
+
+
+class Phases:
+    """The host phases of one loop (one engine, one trainer).
+
+    ``with phases.phase("admit"):`` opens the span
+    ``raytpu.<layer>.admit`` and books its time under ``admit``.  Phases
+    nest, and only the innermost one runs: entering a child closes the
+    parent's span and books the parent's time so far, leaving the child
+    re-opens the parent.  So the spans are leaves -- they never overlap
+    -- and one stamp both ends a phase and starts the next, in whole
+    nanoseconds: inside ``with phases.step():`` the leaves sum to the
+    step's wall exactly (time between two phases of a step is the leaf
+    ``loop``, which is booked but opens no span).  A step is one more
+    span around its leaves, ``raytpu.<layer>.step``.
+
+    Cost with no profiler session: one ``perf_counter_ns`` and one empty
+    ``TraceAnnotation`` per switch (``tests/test_phases.py`` holds it to
+    a per-call budget).  One loop owns a ``Phases``: no lock.
+    """
+
+    def __init__(self, layer: str):
+        self._prefix = layer + "."
+        #: phase -> [times entered, nanoseconds run]
+        self._table: Dict[str, List[int]] = {}
+        self._stack: List[str] = []
+        self._since_ns = 0
+        self._ann = None
+        self._step_ann = None
+        self._step_t0_ns = 0
+
+    # -- the running leaf --------------------------------------------------
+
+    def _suspend(self, now_ns: int) -> None:
+        """Book and close the running leaf at `now_ns`."""
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        if self._stack:
+            self._table[self._stack[-1]][1] += now_ns - self._since_ns
+
+    def _resume(self, name: str, now_ns: int, entered: int) -> None:
+        cell = self._table.get(name)
+        if cell is None:
+            cell = self._table[name] = [0, 0]
+        cell[0] += entered
+        self._since_ns = now_ns
+        # no span of its own for the loop's fragments: inside a step's
+        # span, what no leaf's span covers is the loop
+        if name != scopes.LOOP:
+            self._ann = _annotation(self._prefix + name)
+
+    def _push(self, name: str) -> int:
+        now_ns = time.perf_counter_ns()
+        self._suspend(now_ns)
+        self._stack.append(name)
+        self._resume(name, now_ns, 1)
+        return now_ns
+
+    def _pop(self) -> int:
+        now_ns = time.perf_counter_ns()
+        self._suspend(now_ns)
+        self._stack.pop()
+        if self._stack:
+            self._resume(self._stack[-1], now_ns, 0)
+        return now_ns
+
+    def _begin_step(self) -> None:
+        self._step_ann = _annotation(self._prefix + scopes.STEP)
+        self._step_t0_ns = self._push(scopes.LOOP)
+
+    def _end_step(self) -> None:
+        now_ns = self._pop()
+        wall = now_ns - self._step_t0_ns
+        cell = self._table.setdefault(scopes.STEP, [0, 0])
+        cell[0] += 1
+        cell[1] += wall
+        if self._step_ann is not None:
+            self._step_ann.__exit__(None, None, None)
+            self._step_ann = None
+
+    # -- what callers use --------------------------------------------------
+
+    def phase(self, name: str) -> _Phase:
+        return _Phase(self, name)
+
+    def step(self) -> _Step:
+        """One loop iteration: ``raytpu.<layer>.step`` around leaves
+        that partition it."""
+        return _Step(self)
+
+    def snapshot(self) -> Dict[str, List[float]]:
+        """``{phase: [count, seconds]}``; ``step`` counts whole steps."""
+        return {name: [n, ns * 1e-9]
+                for name, (n, ns) in sorted(self._table.items())}

@@ -50,6 +50,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ray_tpu._private import scopes
+
 
 @dataclasses.dataclass(frozen=True)
 class SamplingParams:
@@ -93,6 +95,7 @@ def is_paged(cache) -> bool:
     return "block_tables" in cache
 
 
+@jax.named_scope(scopes.KV_POOL)
 def paged_update_and_view(layer, block_tables, pos, new):
     """One decode-step K (or V) update against a paged pool layer.
 
@@ -250,6 +253,7 @@ def filter_logits(logits, temperature: float,
     return scaled
 
 
+@jax.named_scope(scopes.SAMPLE)
 def sample_token(logits, key, temperature: float,
                  tail_mask: Optional[jnp.ndarray],
                  top_k: int = 0, top_p: float = 1.0) -> jnp.ndarray:

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ray_tpu._private import scopes
 from ray_tpu.parallel.sharding import DEFAULT_RULES, with_logical_constraint
 
 #: The three lm-head + cross-entropy implementations (GPT2Config.ce_impl):
@@ -277,6 +278,7 @@ def gpt2_init(key, cfg: GPT2Config) -> Dict[str, Any]:
 # Forward
 # ---------------------------------------------------------------------------
 
+@jax.named_scope(scopes.LN)
 def _layernorm(x, scale, bias, eps=1e-5):
     # LN in float32 for stability, cast back to compute dtype.
     xf = x.astype(jnp.float32)
@@ -309,6 +311,7 @@ def _heads_axis_sharded(rules) -> bool:
         return False
 
 
+@jax.named_scope(scopes.ATTN)
 def _attention(x, p, cfg: GPT2Config, rules):
     B, T, d = x.shape
     h, hd = cfg.n_head, cfg.head_dim
@@ -371,6 +374,7 @@ def _ring_attention_sharded(q, k, v, rules, sp_mode: str = "ring"):
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)(q, k, v)
 
 
+@jax.named_scope(scopes.MLP)
 def _mlp(x, p, cfg: GPT2Config, rules):
     h = jnp.einsum("btd,df->btf", x, p["fc_w"].astype(cfg.dtype))
     h = jax.nn.gelu(h + p["fc_b"].astype(cfg.dtype))
@@ -432,16 +436,17 @@ def gpt2_hidden(params, tokens, cfg: GPT2Config,
     # casted table FIRST (one all-gather — the partitioner emits the
     # same all-gather for a sharded-table gather anyway), then the local
     # gather inherits the token sharding (batch, seq) directly.
-    wte = with_logical_constraint(params["wte"].astype(cfg.dtype),
-                                  (None, None), rules)
-    x = wte[tokens]
-    # wpe slice: shard over seq to match x (T, d) + (B, T, d) broadcast;
-    # constraining to its param sharding (embed→fsdp) would force an
-    # fsdp→seq reshard of the activation instead.
-    pos = with_logical_constraint(params["wpe"].astype(cfg.dtype)[:T],
-                                  ("seq", None), rules)
-    x = x + pos
-    x = with_logical_constraint(x, ("batch", "seq", "embed"), rules)
+    with jax.named_scope(scopes.EMBED):
+        wte = with_logical_constraint(params["wte"].astype(cfg.dtype),
+                                      (None, None), rules)
+        x = wte[tokens]
+        # wpe slice: shard over seq to match x (T, d) + (B, T, d)
+        # broadcast; constraining to its param sharding (embed→fsdp)
+        # would force an fsdp→seq reshard of the activation instead.
+        pos = with_logical_constraint(
+            params["wpe"].astype(cfg.dtype)[:T], ("seq", None), rules)
+        x = x + pos
+        x = with_logical_constraint(x, ("batch", "seq", "embed"), rules)
 
     if cfg.remat and cfg.remat_policy == "mlp_only" and cfg.n_experts:
         raise NotImplementedError(
@@ -505,6 +510,7 @@ def gpt2_hidden(params, tokens, cfg: GPT2Config,
     return (out, jnp.sum(auxes)) if return_aux else out
 
 
+@jax.named_scope(scopes.LM_HEAD_CE)
 def _tied_logits(hidden, wte, cfg: GPT2Config, rules):
     """Tied-embedding projection — the ONE place defining the contract:
     bf16 operands with float32 accumulation (the MXU runs at bf16 rate
@@ -523,6 +529,7 @@ def gpt2_forward(params, tokens, cfg: GPT2Config,
     return _tied_logits(x, params["wte"], cfg, rules)
 
 
+@jax.named_scope(scopes.LM_HEAD_CE)
 def nll_from_logits(logits, targets, vocab_size: int,
                     padded_vocab: int):
     """Per-token negative log likelihood with the padded-vocab tail masked.
@@ -581,6 +588,7 @@ def _chunked_ce(hidden, wte, targets, mask, cfg: GPT2Config):
     return total / jnp.maximum(count, 1.0)
 
 
+@jax.named_scope(scopes.LM_HEAD_CE)
 def lm_head_nll(hidden, w_vocab_major, targets, cfg) -> jnp.ndarray:
     """Per-token nll via the non-dense CE impls, shared by gpt2 and
     llama.  hidden (B, T, D); w_vocab_major (V, D) — tied wte, or a
@@ -628,23 +636,28 @@ def gpt2_loss(params, batch, cfg: GPT2Config,
     hidden, aux = gpt2_hidden(params, inputs, cfg, rules,
                               return_aux=True)
     aux_term = cfg.moe_aux_weight * aux if cfg.n_experts else 0.0
+    return _ce(hidden, params["wte"], targets, mask, cfg, rules) + aux_term
+
+
+@jax.named_scope(scopes.LM_HEAD_CE)
+def _ce(hidden, wte, targets, mask, cfg: GPT2Config, rules):
+    """Mean next-token cross-entropy of `hidden` under the tied head, by
+    the configured implementation."""
     if cfg.ce_impl != "dense":
         # valid combinations were enforced at config construction
         # (__post_init__) — one coherent error, not scattered checks here
-        nll = lm_head_nll(hidden, params["wte"], targets, cfg)
+        nll = lm_head_nll(hidden, wte, targets, cfg)
         if mask is not None:
             m = mask.astype(jnp.float32)
-            return jnp.sum(nll * m) / jnp.maximum(jnp.sum(m),
-                                                  1.0) + aux_term
-        return jnp.mean(nll) + aux_term
+            return jnp.sum(nll * m) / jnp.maximum(jnp.sum(m), 1.0)
+        return jnp.mean(nll)
     if cfg.loss_chunks > 1:
         if mask is None:
             mask = jnp.ones(targets.shape, jnp.float32)
-        return _chunked_ce(hidden, params["wte"], targets,
-                           mask.astype(jnp.float32), cfg) + aux_term
-    logits = _tied_logits(hidden, params["wte"], cfg, rules)
+        return _chunked_ce(hidden, wte, targets,
+                           mask.astype(jnp.float32), cfg)
+    logits = _tied_logits(hidden, wte, cfg, rules)
     nll = _nll_from_logits(logits, targets, cfg)
     if mask is not None:
-        return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask),
-                                                 1.0) + aux_term
-    return jnp.mean(nll) + aux_term
+        return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+    return jnp.mean(nll)

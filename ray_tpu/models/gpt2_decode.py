@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ray_tpu._private import scopes
 from ray_tpu.models import decode_common
 from ray_tpu.models.decode_common import (generate_with, is_paged,
                                           paged_update_and_view,
@@ -98,6 +99,30 @@ def init_paged_cache(cfg: GPT2Config, batch: int, *, num_blocks: int,
     return decode_common.partitioned_cache_init(build, mesh)
 
 
+@jax.named_scope(scopes.MLP)
+def _mlp(xm, p, cfg: GPT2Config):
+    """The block's MLP on normalised activations of any rank."""
+    hmid = jax.nn.gelu(xm @ p["fc_w"].astype(cfg.dtype)
+                       + p["fc_b"].astype(cfg.dtype))
+    return (hmid @ p["proj_w"].astype(cfg.dtype)
+            + p["proj_b"].astype(cfg.dtype))
+
+
+@jax.named_scope(scopes.KV_POOL)
+def _layer_kv(cache, lidx):
+    """Layer `lidx`'s K and V out of the stacked cache (or pool)."""
+    return (lax.dynamic_index_in_dim(cache["k"], lidx, axis=0,
+                                     keepdims=False),
+            lax.dynamic_index_in_dim(cache["v"], lidx, axis=0,
+                                     keepdims=False))
+
+
+@jax.named_scope(scopes.LM_HEAD)
+def _lm_head(x, params, cfg: GPT2Config):
+    """Final layernorm'd activations -> float32 logits (tied head)."""
+    return (x @ params["wte"].astype(cfg.dtype).T).astype(jnp.float32)
+
+
 def prefill(params, tokens: jnp.ndarray, cfg: GPT2Config, *,
             lengths: Optional[jnp.ndarray] = None
             ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
@@ -125,42 +150,43 @@ def prefill(params, tokens: jnp.ndarray, cfg: GPT2Config, *,
         # pad columns clip to wpe row 0 — garbage the attention mask
         # keeps unread
         pos_ids = jnp.maximum(jnp.arange(T0)[None, :] - start[:, None], 0)
-    x = params["wte"].astype(cfg.dtype)[tokens]          # (B, T0, d)
-    x = x + params["wpe"].astype(cfg.dtype)[pos_ids]
+    with jax.named_scope(scopes.EMBED):
+        x = params["wte"].astype(cfg.dtype)[tokens]      # (B, T0, d)
+        x = x + params["wpe"].astype(cfg.dtype)[pos_ids]
     attn_start = None if lengths is None else start
 
     def body(x, layer):
         p, = layer
         xa = _layernorm(x, p["ln1"]["scale"], p["ln1"]["bias"])
-        w = p["attn"]["qkv_w"].astype(cfg.dtype).reshape(d, 3 * h * hd)
-        qkv = (xa @ w).reshape(B, T0, 3, h, hd) \
-            + p["attn"]["qkv_b"].astype(cfg.dtype)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        o = prefill_attention(q, k, v, start=attn_start,
-                              use_flash=cfg.use_flash,
-                              resident=cfg.flash_resident,
-                              rules=DECODE_RULES)
-        wo = p["attn"]["o_w"].astype(cfg.dtype).reshape(h * hd, d)
-        x = x + (o.reshape(B, T0, h * hd) @ wo
-                 + p["attn"]["o_b"].astype(cfg.dtype))
-        xm = _layernorm(x, p["ln2"]["scale"], p["ln2"]["bias"])
-        hmid = jax.nn.gelu(xm @ p["mlp"]["fc_w"].astype(cfg.dtype)
-                           + p["mlp"]["fc_b"].astype(cfg.dtype))
-        x = x + (hmid @ p["mlp"]["proj_w"].astype(cfg.dtype)
-                 + p["mlp"]["proj_b"].astype(cfg.dtype))
+        with jax.named_scope(scopes.ATTN):
+            w = p["attn"]["qkv_w"].astype(cfg.dtype).reshape(
+                d, 3 * h * hd)
+            qkv = (xa @ w).reshape(B, T0, 3, h, hd) \
+                + p["attn"]["qkv_b"].astype(cfg.dtype)
+            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+            o = prefill_attention(q, k, v, start=attn_start,
+                                  use_flash=cfg.use_flash,
+                                  resident=cfg.flash_resident,
+                                  rules=DECODE_RULES)
+            wo = p["attn"]["o_w"].astype(cfg.dtype).reshape(h * hd, d)
+            x = x + (o.reshape(B, T0, h * hd) @ wo
+                     + p["attn"]["o_b"].astype(cfg.dtype))
+        x = x + _mlp(_layernorm(x, p["ln2"]["scale"], p["ln2"]["bias"]),
+                     p["mlp"], cfg)
         return x, (k, v)
 
-    x, (ks, vs) = lax.scan(body, x, (params["blocks"],))
-    cache["k"] = lax.dynamic_update_slice(cache["k"], ks,
-                                          (0, 0, 0, 0, 0))
-    cache["v"] = lax.dynamic_update_slice(cache["v"], vs,
-                                          (0, 0, 0, 0, 0))
+    with jax.named_scope(scopes.LAYER_SCAN):
+        x, (ks, vs) = lax.scan(body, x, (params["blocks"],))
+    with jax.named_scope(scopes.KV_POOL):
+        cache["k"] = lax.dynamic_update_slice(cache["k"], ks,
+                                              (0, 0, 0, 0, 0))
+        cache["v"] = lax.dynamic_update_slice(cache["v"], vs,
+                                              (0, 0, 0, 0, 0))
     cache["pos"] = jnp.full((B,), T0, jnp.int32)
     cache["start"] = start
     x = _layernorm(x, params["ln_f"]["scale"], params["ln_f"]["bias"])
     last = x[:, -1]                 # left padding ⇒ last real token
-    logits = (last @ params["wte"].astype(cfg.dtype).T
-              ).astype(jnp.float32)
+    logits = _lm_head(last, params, cfg)
     return logits, cache
 
 
@@ -199,59 +225,61 @@ def paged_prefill(params, cache, tokens: jnp.ndarray, cfg: GPT2Config,
     pos_ids = jnp.maximum(logical, 0)          # pads clip to wpe row 0
     # scatter targets for tail K/V: pad columns MUST go to the null
     # block — their logical index can alias a live prefix slot
-    blk = jnp.where(real, row_bt[pos_ids // bs], 0)
-    off = jnp.where(real, logical % bs, 0)
+    with jax.named_scope(scopes.KV_POOL):
+        blk = jnp.where(real, row_bt[pos_ids // bs], 0)
+        off = jnp.where(real, logical % bs, 0)
     # key slot s attendable by query column c iff c is real and
     # s <= logical[c] (all-masked pad columns softmax to uniform —
     # finite garbage that never reaches the pool or the logits)
-    mask = real[:, None] & (
-        jnp.arange(cfg.max_seq)[None, :] <= logical[:, None])
+    with jax.named_scope(scopes.ATTN):
+        mask = real[:, None] & (
+            jnp.arange(cfg.max_seq)[None, :] <= logical[:, None])
     scale = 1.0 / math.sqrt(hd)
-    x = params["wte"].astype(cfg.dtype)[tokens[0]]       # (Tt, d)
-    x = x + params["wpe"].astype(cfg.dtype)[pos_ids]
+    with jax.named_scope(scopes.EMBED):
+        x = params["wte"].astype(cfg.dtype)[tokens[0]]   # (Tt, d)
+        x = x + params["wpe"].astype(cfg.dtype)[pos_ids]
 
     def body(carry, layer):
         x, lidx = carry
         p, = layer
-        lk = lax.dynamic_index_in_dim(cache["k"], lidx, axis=0,
-                                      keepdims=False)    # (nb,bs,H,hd)
-        lv = lax.dynamic_index_in_dim(cache["v"], lidx, axis=0,
-                                      keepdims=False)
+        lk, lv = _layer_kv(cache, lidx)                  # (nb,bs,H,hd)
         xa = _layernorm(x, p["ln1"]["scale"], p["ln1"]["bias"])
-        w = p["attn"]["qkv_w"].astype(cfg.dtype).reshape(d, 3 * h * hd)
-        qkv = (xa @ w).reshape(Tt, 3, h, hd) \
-            + p["attn"]["qkv_b"].astype(cfg.dtype)
-        q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]        # (Tt,h,hd)
-        lk = lk.at[blk, off].set(k)
-        lv = lv.at[blk, off].set(v)
-        kview = lk[row_bt].reshape(cfg.max_seq, h, hd)
-        vview = lv[row_bt].reshape(cfg.max_seq, h, hd)
-        scores = jnp.einsum("qhd,khd->hqk", q,
-                            kview).astype(jnp.float32) * scale
-        scores = jnp.where(mask[None], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-        o = jnp.einsum("hqk,khd->qhd", probs, vview)
-        wo = p["attn"]["o_w"].astype(cfg.dtype).reshape(h * hd, d)
-        x = x + (o.reshape(Tt, h * hd) @ wo
-                 + p["attn"]["o_b"].astype(cfg.dtype))
-        xm = _layernorm(x, p["ln2"]["scale"], p["ln2"]["bias"])
-        hmid = jax.nn.gelu(xm @ p["mlp"]["fc_w"].astype(cfg.dtype)
-                           + p["mlp"]["fc_b"].astype(cfg.dtype))
-        x = x + (hmid @ p["mlp"]["proj_w"].astype(cfg.dtype)
-                 + p["mlp"]["proj_b"].astype(cfg.dtype))
+        with jax.named_scope(scopes.ATTN):
+            w = p["attn"]["qkv_w"].astype(cfg.dtype).reshape(
+                d, 3 * h * hd)
+            qkv = (xa @ w).reshape(Tt, 3, h, hd) \
+                + p["attn"]["qkv_b"].astype(cfg.dtype)
+            q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]    # (Tt,h,hd)
+        with jax.named_scope(scopes.KV_POOL):
+            lk = lk.at[blk, off].set(k)
+            lv = lv.at[blk, off].set(v)
+            kview = lk[row_bt].reshape(cfg.max_seq, h, hd)
+            vview = lv[row_bt].reshape(cfg.max_seq, h, hd)
+        with jax.named_scope(scopes.ATTN):
+            scores = jnp.einsum("qhd,khd->hqk", q,
+                                kview).astype(jnp.float32) * scale
+            scores = jnp.where(mask[None], scores, -1e30)
+            probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+            o = jnp.einsum("hqk,khd->qhd", probs, vview)
+            wo = p["attn"]["o_w"].astype(cfg.dtype).reshape(h * hd, d)
+            x = x + (o.reshape(Tt, h * hd) @ wo
+                     + p["attn"]["o_b"].astype(cfg.dtype))
+        x = x + _mlp(_layernorm(x, p["ln2"]["scale"], p["ln2"]["bias"]),
+                     p["mlp"], cfg)
         return (x, lidx + 1), (lk, lv)
 
-    (x, _), (new_k, new_v) = lax.scan(body, (x, jnp.int32(0)),
-                                      (params["blocks"],))
+    with jax.named_scope(scopes.LAYER_SCAN):
+        (x, _), (new_k, new_v) = lax.scan(body, (x, jnp.int32(0)),
+                                          (params["blocks"],))
     x = _layernorm(x, params["ln_f"]["scale"], params["ln_f"]["bias"])
     last = x[-1]                    # right-aligned ⇒ last real token
-    logits = (last @ params["wte"].astype(cfg.dtype).T
-              ).astype(jnp.float32)
+    logits = _lm_head(last, params, cfg)
     out = dict(cache)
-    out["k"], out["v"] = new_k, new_v
-    out["block_tables"] = cache["block_tables"].at[slot].set(row_bt)
-    out["pos"] = cache["pos"].at[slot].set(prefix_len + n_tail)
-    out["start"] = cache["start"].at[slot].set(0)
+    with jax.named_scope(scopes.KV_POOL):
+        out["k"], out["v"] = new_k, new_v
+        out["block_tables"] = cache["block_tables"].at[slot].set(row_bt)
+        out["pos"] = cache["pos"].at[slot].set(prefix_len + n_tail)
+        out["start"] = cache["start"].at[slot].set(0)
     return logits, out
 
 
@@ -275,53 +303,56 @@ def decode_step(params, cache, tokens, cfg: GPT2Config
     pos = cache["pos"]                                   # (B,)
     start = cache["start"]                               # (B,)
     rows = jnp.arange(B)
-    x = params["wte"].astype(cfg.dtype)[tokens]          # (B, d)
-    x = x + params["wpe"].astype(cfg.dtype)[pos - start]
+    with jax.named_scope(scopes.EMBED):
+        x = params["wte"].astype(cfg.dtype)[tokens]      # (B, d)
+        x = x + params["wpe"].astype(cfg.dtype)[pos - start]
 
     # per-slot mask: start[b] <= s <= pos[b] (current token included)
-    attn_mask = slot_mask(start, pos + 1, cfg.max_seq)   # (B, S)
+    with jax.named_scope(scopes.ATTN):
+        attn_mask = slot_mask(start, pos + 1, cfg.max_seq)   # (B, S)
 
     def body(carry, layer):
         x, lidx = carry
         p, = layer
-        lk = lax.dynamic_index_in_dim(cache["k"], lidx, axis=0,
-                                      keepdims=False)
-        lv = lax.dynamic_index_in_dim(cache["v"], lidx, axis=0,
-                                      keepdims=False)
+        lk, lv = _layer_kv(cache, lidx)
         xa = _layernorm(x, p["ln1"]["scale"], p["ln1"]["bias"])
-        w = p["attn"]["qkv_w"].astype(cfg.dtype).reshape(d, 3 * h * hd)
-        qkv = (xa @ w).reshape(B, 3, h, hd) \
-            + p["attn"]["qkv_b"].astype(cfg.dtype)
-        q, k_new, v_new = qkv[:, 0], qkv[:, 1], qkv[:, 2]  # (B,h,hd)
+        with jax.named_scope(scopes.ATTN):
+            w = p["attn"]["qkv_w"].astype(cfg.dtype).reshape(
+                d, 3 * h * hd)
+            qkv = (xa @ w).reshape(B, 3, h, hd) \
+                + p["attn"]["qkv_b"].astype(cfg.dtype)
+            q, k_new, v_new = qkv[:, 0], qkv[:, 1], qkv[:, 2]  # (B,h,hd)
         if paged:
             bt = cache["block_tables"]
             lk, ck = paged_update_and_view(lk, bt, pos, k_new)
             lv, cv = paged_update_and_view(lv, bt, pos, v_new)
         else:
-            lk = ck = lk.at[rows, pos].set(k_new)  # row b → slot pos[b]
-            lv = cv = lv.at[rows, pos].set(v_new)
-        # attention of the single query against the cache
-        scores = jnp.einsum("bhd,bshd->bhs", q, ck).astype(jnp.float32)
-        scores = scores / jnp.sqrt(jnp.float32(hd))
-        scores = jnp.where(attn_mask[:, None, :], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-        o = jnp.einsum("bhs,bshd->bhd", probs, cv)       # (B,h,hd)
-        wo = p["attn"]["o_w"].astype(cfg.dtype).reshape(h * hd, d)
-        x = x + (o.reshape(B, h * hd) @ wo
-                 + p["attn"]["o_b"].astype(cfg.dtype))
-        xm = _layernorm(x, p["ln2"]["scale"], p["ln2"]["bias"])
-        hmid = jax.nn.gelu(xm @ p["mlp"]["fc_w"].astype(cfg.dtype)
-                           + p["mlp"]["fc_b"].astype(cfg.dtype))
-        x = x + (hmid @ p["mlp"]["proj_w"].astype(cfg.dtype)
-                 + p["mlp"]["proj_b"].astype(cfg.dtype))
+            with jax.named_scope(scopes.KV_POOL):
+                lk = ck = lk.at[rows, pos].set(k_new)  # row b → pos[b]
+                lv = cv = lv.at[rows, pos].set(v_new)
+        with jax.named_scope(scopes.ATTN):
+            # attention of the single query against the cache
+            scores = jnp.einsum("bhd,bshd->bhs", q,
+                                ck).astype(jnp.float32)
+            scores = scores / jnp.sqrt(jnp.float32(hd))
+            scores = jnp.where(attn_mask[:, None, :], scores, -1e30)
+            probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+            o = jnp.einsum("bhs,bshd->bhd", probs, cv)   # (B,h,hd)
+            wo = p["attn"]["o_w"].astype(cfg.dtype).reshape(h * hd, d)
+            x = x + (o.reshape(B, h * hd) @ wo
+                     + p["attn"]["o_b"].astype(cfg.dtype))
+        x = x + _mlp(_layernorm(x, p["ln2"]["scale"], p["ln2"]["bias"]),
+                     p["mlp"], cfg)
         return (x, lidx + 1), (lk, lv)
 
-    (x, _), (new_k, new_v) = lax.scan(body, (x, jnp.int32(0)),
-                                      (params["blocks"],))
+    with jax.named_scope(scopes.LAYER_SCAN):
+        (x, _), (new_k, new_v) = lax.scan(body, (x, jnp.int32(0)),
+                                          (params["blocks"],))
     x = _layernorm(x, params["ln_f"]["scale"], params["ln_f"]["bias"])
-    logits = (x @ params["wte"].astype(cfg.dtype).T).astype(jnp.float32)
+    logits = _lm_head(x, params, cfg)
     out = dict(cache)
-    out["k"], out["v"], out["pos"] = new_k, new_v, pos + 1
+    with jax.named_scope(scopes.KV_POOL):
+        out["k"], out["v"], out["pos"] = new_k, new_v, pos + 1
     return logits, out
 
 
@@ -350,69 +381,72 @@ def verify_step(params, cache, block, cfg: GPT2Config
     pos = cache["pos"]                                   # (B,)
     start = cache["start"]                               # (B,)
     rows = jnp.arange(B)
-    offs = jnp.arange(T, dtype=jnp.int32)
-    slot_ids = pos[:, None] + offs[None, :]              # (B, T)
-    in_range = slot_ids < cfg.max_seq
-    pos_ids = jnp.minimum(jnp.maximum(slot_ids - start[:, None], 0),
-                          cfg.max_seq - 1)
-    x = params["wte"].astype(cfg.dtype)[block]           # (B, T, d)
-    x = x + params["wpe"].astype(cfg.dtype)[pos_ids]
-    # (B, T, S): query t attends slots start[b] <= s <= pos[b] + t
-    s = jnp.arange(cfg.max_seq)
-    attn_mask = (s[None, None, :] >= start[:, None, None]) & \
-                (s[None, None, :] <= slot_ids[:, :, None])
-    if paged:
-        bt = cache["block_tables"]
-        bs = cache["k"].shape[2]
-        blk_col = jnp.minimum(slot_ids // bs, bt.shape[1] - 1)
-        blk = jnp.where(in_range, bt[rows[:, None], blk_col], 0)
-        off = jnp.where(in_range, slot_ids % bs, 0)
-    else:
-        # OOB rows dropped by the scatter (mode="drop")
-        write_idx = jnp.where(in_range, slot_ids, cfg.max_seq)
+    with jax.named_scope(scopes.KV_POOL):
+        offs = jnp.arange(T, dtype=jnp.int32)
+        slot_ids = pos[:, None] + offs[None, :]          # (B, T)
+        in_range = slot_ids < cfg.max_seq
+    with jax.named_scope(scopes.EMBED):
+        pos_ids = jnp.minimum(
+            jnp.maximum(slot_ids - start[:, None], 0), cfg.max_seq - 1)
+        x = params["wte"].astype(cfg.dtype)[block]       # (B, T, d)
+        x = x + params["wpe"].astype(cfg.dtype)[pos_ids]
+    with jax.named_scope(scopes.ATTN):
+        # (B, T, S): query t attends slots start[b] <= s <= pos[b] + t
+        s = jnp.arange(cfg.max_seq)
+        attn_mask = (s[None, None, :] >= start[:, None, None]) & \
+                    (s[None, None, :] <= slot_ids[:, :, None])
+    with jax.named_scope(scopes.KV_POOL):
+        if paged:
+            bt = cache["block_tables"]
+            bs = cache["k"].shape[2]
+            blk_col = jnp.minimum(slot_ids // bs, bt.shape[1] - 1)
+            blk = jnp.where(in_range, bt[rows[:, None], blk_col], 0)
+            off = jnp.where(in_range, slot_ids % bs, 0)
+        else:
+            # OOB rows dropped by the scatter (mode="drop")
+            write_idx = jnp.where(in_range, slot_ids, cfg.max_seq)
 
     def body(carry, layer):
         x, lidx = carry
         p, = layer
-        lk = lax.dynamic_index_in_dim(cache["k"], lidx, axis=0,
-                                      keepdims=False)
-        lv = lax.dynamic_index_in_dim(cache["v"], lidx, axis=0,
-                                      keepdims=False)
+        lk, lv = _layer_kv(cache, lidx)
         xa = _layernorm(x, p["ln1"]["scale"], p["ln1"]["bias"])
-        w = p["attn"]["qkv_w"].astype(cfg.dtype).reshape(d, 3 * h * hd)
-        qkv = (xa @ w).reshape(B, T, 3, h, hd) \
-            + p["attn"]["qkv_b"].astype(cfg.dtype)
-        q, k_new, v_new = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        if paged:
-            lk = lk.at[blk, off].set(k_new)
-            lv = lv.at[blk, off].set(v_new)
-            ck = lk[bt].reshape(B, cfg.max_seq, h, hd)
-            cv = lv[bt].reshape(B, cfg.max_seq, h, hd)
-        else:
-            lk = ck = lk.at[rows[:, None], write_idx].set(
-                k_new, mode="drop")
-            lv = cv = lv.at[rows[:, None], write_idx].set(
-                v_new, mode="drop")
-        scores = jnp.einsum("bthd,bshd->bhts", q,
-                            ck).astype(jnp.float32)
-        scores = scores / jnp.sqrt(jnp.float32(hd))
-        scores = jnp.where(attn_mask[:, None], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-        o = jnp.einsum("bhts,bshd->bthd", probs, cv)     # (B,T,h,hd)
-        wo = p["attn"]["o_w"].astype(cfg.dtype).reshape(h * hd, d)
-        x = x + (o.reshape(B, T, h * hd) @ wo
-                 + p["attn"]["o_b"].astype(cfg.dtype))
-        xm = _layernorm(x, p["ln2"]["scale"], p["ln2"]["bias"])
-        hmid = jax.nn.gelu(xm @ p["mlp"]["fc_w"].astype(cfg.dtype)
-                           + p["mlp"]["fc_b"].astype(cfg.dtype))
-        x = x + (hmid @ p["mlp"]["proj_w"].astype(cfg.dtype)
-                 + p["mlp"]["proj_b"].astype(cfg.dtype))
+        with jax.named_scope(scopes.ATTN):
+            w = p["attn"]["qkv_w"].astype(cfg.dtype).reshape(
+                d, 3 * h * hd)
+            qkv = (xa @ w).reshape(B, T, 3, h, hd) \
+                + p["attn"]["qkv_b"].astype(cfg.dtype)
+            q, k_new, v_new = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        with jax.named_scope(scopes.KV_POOL):
+            if paged:
+                lk = lk.at[blk, off].set(k_new)
+                lv = lv.at[blk, off].set(v_new)
+                ck = lk[bt].reshape(B, cfg.max_seq, h, hd)
+                cv = lv[bt].reshape(B, cfg.max_seq, h, hd)
+            else:
+                lk = ck = lk.at[rows[:, None], write_idx].set(
+                    k_new, mode="drop")
+                lv = cv = lv.at[rows[:, None], write_idx].set(
+                    v_new, mode="drop")
+        with jax.named_scope(scopes.ATTN):
+            scores = jnp.einsum("bthd,bshd->bhts", q,
+                                ck).astype(jnp.float32)
+            scores = scores / jnp.sqrt(jnp.float32(hd))
+            scores = jnp.where(attn_mask[:, None], scores, -1e30)
+            probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+            o = jnp.einsum("bhts,bshd->bthd", probs, cv)  # (B,T,h,hd)
+            wo = p["attn"]["o_w"].astype(cfg.dtype).reshape(h * hd, d)
+            x = x + (o.reshape(B, T, h * hd) @ wo
+                     + p["attn"]["o_b"].astype(cfg.dtype))
+        x = x + _mlp(_layernorm(x, p["ln2"]["scale"], p["ln2"]["bias"]),
+                     p["mlp"], cfg)
         return (x, lidx + 1), (lk, lv)
 
-    (x, _), (new_k, new_v) = lax.scan(body, (x, jnp.int32(0)),
-                                      (params["blocks"],))
+    with jax.named_scope(scopes.LAYER_SCAN):
+        (x, _), (new_k, new_v) = lax.scan(body, (x, jnp.int32(0)),
+                                          (params["blocks"],))
     x = _layernorm(x, params["ln_f"]["scale"], params["ln_f"]["bias"])
-    logits = (x @ params["wte"].astype(cfg.dtype).T).astype(jnp.float32)
+    logits = _lm_head(x, params, cfg)
     out = dict(cache)
     out["k"], out["v"] = new_k, new_v
     return logits, out

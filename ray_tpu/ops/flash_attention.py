@@ -32,6 +32,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from ray_tpu._private import scopes
+
 # Whole-1024 tiles measured fastest on v5e at GPT-2 shapes (T=1024,
 # D=64): one tile per (batch*head) avoids the online-softmax revisit
 # overhead and still fits VMEM (4 MiB f32 score tile).  _blocks() caps
@@ -132,6 +134,7 @@ def _fwd(q3, k3, v3, *, scale, block_q, block_k, causal, interpret):
         ],
         scratch_shapes=[_vmem((bq, 1)), _vmem((bq, 1)), _vmem((bq, D))],
         interpret=interpret,
+        name=scopes.FLASH_FWD,
     )(q3, k3, v3)
     return o, lse
 
@@ -252,6 +255,7 @@ def _bwd(res, do3, *, scale, block_q, block_k, causal, interpret):
         out_shape=jax.ShapeDtypeStruct((BH, T, D), q3.dtype),
         scratch_shapes=[_vmem((bq, D))],
         interpret=interpret,
+        name=scopes.FLASH_DQ,
     )(q3, k3, v3, do3, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -276,6 +280,7 @@ def _bwd(res, do3, *, scale, block_q, block_k, causal, interpret):
         ],
         scratch_shapes=[_vmem((bk, D)), _vmem((bk, D))],
         interpret=interpret,
+        name=scopes.FLASH_DKV,
     )(q3, k3, v3, do3, lse, delta)
     return dq, dk, dv
 
@@ -352,6 +357,7 @@ def _fwd_res(q3, k3, v3, *, scale, bq, chunk, causal, interpret):
             jax.ShapeDtypeStruct((BH, 1, T), jnp.float32),
         ],
         interpret=interpret,
+        name=scopes.FLASH_RES_FWD,
     )(q3, k3, v3)
 
 
@@ -454,6 +460,7 @@ def _bwd_res(res, do3, *, scale, bq, bk, chunk, causal, interpret):
         out_specs=pl.BlockSpec((None, bq, D), lambda bh, qi: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, T, D), q3.dtype),
         interpret=interpret,
+        name=scopes.FLASH_RES_DQ,
     )(q3, k3, v3, do3, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -477,6 +484,7 @@ def _bwd_res(res, do3, *, scale, bq, bk, chunk, causal, interpret):
             jax.ShapeDtypeStruct((BH, T, D), v3.dtype),
         ],
         interpret=interpret,
+        name=scopes.FLASH_RES_DKV,
     )(q3, k3, v3, do3, lse, delta)
     return dq, dk, dv
 

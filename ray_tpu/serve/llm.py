@@ -35,6 +35,8 @@ from typing import Any, Dict, Optional
 
 import numpy as np
 
+from ray_tpu._private import scopes
+from ray_tpu._private.telemetry import Phases
 from ray_tpu.models.decode_common import SamplingParams
 from ray_tpu.serve.api import deployment
 from ray_tpu.serve.batching import (ChunkCursor, HandoffCursor,
@@ -612,6 +614,10 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
                 max_slots=(max_slots if scheduler == "continuous"
                            else max_batch_size),
                 role=role)
+            #: what the scheduler loop does between device calls, as
+            #: raytpu.engine.* spans on the profiler's clock and the
+            #: engine_stats()["phases"] table (_private/telemetry.py)
+            self._phases = Phases(scopes.ENGINE)
             #: disaggregated serving role — the fleet router reads
             #: this to type replicas ("prefill" | "decode" | "both")
             self.role = role
@@ -1001,6 +1007,7 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
             import jax
             import jax.numpy as jnp
 
+            phase = self._phases.phase
             while len(self._queue):
                 free = [i for i, s in enumerate(self._slots)
                         if s is None]
@@ -1039,22 +1046,25 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
                 self._telemetry.record_admit(rec, slot, t_pad)
                 padded = np.zeros((1, t_pad), np.int32)
                 padded[0, t_pad - n:] = arr
-                self._rng, k = jax.random.split(self._rng)
-                if sp is not None:
-                    # override path: logits-returning twin + the
-                    # per-sp sampler (default requests keep the fused
-                    # single-dispatch program)
-                    logits, row = self._fns.prefill_raw(
-                        self.params, jnp.asarray(padded),
-                        jnp.asarray([n], jnp.int32))
-                    tok = self._sampler_for(sp)(logits, k)
-                else:
-                    tok, row = self._prefill(
-                        self.params, jnp.asarray(padded),
-                        jnp.asarray([n], jnp.int32), k)
+                with phase("rng_split"):
+                    self._rng, k = jax.random.split(self._rng)
+                with phase("prefill_dispatch"):
+                    if sp is not None:
+                        # override path: logits-returning twin + the
+                        # per-sp sampler (default requests keep the
+                        # fused single-dispatch program)
+                        logits, row = self._fns.prefill_raw(
+                            self.params, jnp.asarray(padded),
+                            jnp.asarray([n], jnp.int32))
+                        tok = self._sampler_for(sp)(logits, k)
+                    else:
+                        tok, row = self._prefill(
+                            self.params, jnp.asarray(padded),
+                            jnp.asarray([n], jnp.int32), k)
                 # int() is the engine's existing host fence for the
                 # prefill result; the timestamp behind it is the TTFT
-                first = int(np.asarray(tok)[0])
+                with phase("prefill_fence"):
+                    first = int(np.asarray(tok)[0])
                 self._telemetry.record_first_token(rec)
                 if max_new_tokens <= 1 or self._hit_stop([first]):
                     self._telemetry.record_finish(rec, n_tokens=1)
@@ -1068,13 +1078,12 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
                                      "fut": fut, "rec": rec, "sp": sp}
                 self._draft_admit(slot, arr)
 
-        def _admit_one_paged(self, arr, rec, sp, fut, slot) -> bool:
-            """Admit one request through the block pager: match the
-            longest resident prompt prefix, allocate the remaining
-            blocks up front (decode never allocates), COW-fork the
-            write-boundary block if it is shared, then prefill only
-            the unmatched tail.  Returns False when the pool cannot
-            hold the request yet (request requeued at the head)."""
+        def _reserve_blocks(self, arr, rec, sp, fut, tokens, ctx,
+                            t_kv0):
+            """The pager's half of a paged admission, from `t_kv0`:
+            prefix match, allocation, host-tier restore, COW fork.
+            Returns (blocks, prefix_len), or None when the pool cannot
+            hold the request yet (it is back at the queue's head)."""
             import jax
             import jax.numpy as jnp
 
@@ -1082,12 +1091,9 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
 
             pager = self._pager
             n = int(arr.shape[0])
-            tokens = arr.tolist()
-            ctx = rec.get("ctx")
             pager.set_request(rec["id"],
                               ctx.trace_id if ctx is not None else None,
                               tenant=rec.get("tenant"))
-            t_kv0 = _time.perf_counter()
             ev0 = pager.evictions
             # spec decode: reserve k blocks' worth of verify-overshoot
             # headroom so rejected draft K/V writes land in blocks this
@@ -1104,7 +1110,7 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
                 self._telemetry.record_requeue(
                     rec, need=need, reason="pool_exhausted")
                 self._queue.push_front((arr, rec, sp), fut)
-                return False
+                return None
             blocks = matched + alloc
             # tiered host-RAM KV cache: second-chance lookup — full
             # blocks the HBM prefix match missed may survive in the
@@ -1157,7 +1163,7 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
                     self._telemetry.record_requeue(
                         rec, need=need, reason="cow_exhausted")
                     self._queue.push_front((arr, rec, sp), fut)
-                    return False
+                    return None
                 if src is not None:
                     blocks[wb] = new_blk
                     self._cache = self._copy_block(
@@ -1174,6 +1180,29 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
             reused = len(matched) + len(pairs)
             self._telemetry.record_prefix_reuse(
                 reused, pager.blocks_needed(n, 0) - reused)
+            return blocks, prefix_len
+
+        def _admit_one_paged(self, arr, rec, sp, fut, slot) -> bool:
+            """Admit one request through the block pager: match the
+            longest resident prompt prefix, allocate the remaining
+            blocks up front (decode never allocates), COW-fork the
+            write-boundary block if it is shared, then prefill only
+            the unmatched tail.  Returns False when the pool cannot
+            hold the request yet (request requeued at the head)."""
+            import jax
+            import jax.numpy as jnp
+
+            pager = self._pager
+            phase = self._phases.phase
+            n = int(arr.shape[0])
+            tokens = arr.tolist()
+            ctx = rec.get("ctx")
+            with phase("kv.reserve") as reserve:
+                reserved = self._reserve_blocks(
+                    arr, rec, sp, fut, tokens, ctx, reserve.t0)
+            if reserved is None:
+                return False
+            blocks, prefix_len = reserved
             n_tail = n - prefix_len
             row_bt = np.zeros((self.cfg.max_seq // kv_block_size,),
                               np.int32)
@@ -1205,21 +1234,26 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
             self._telemetry.record_admit(rec, slot, t_pad)
             tail_toks = np.zeros((1, t_pad), np.int32)
             tail_toks[0, t_pad - n_tail:] = arr[prefix_len:]
-            self._rng, k = jax.random.split(self._rng)
-            if sp is not None:
-                logits, self._cache = self._fns.paged_prefill_raw(
-                    self.params, self._cache, jnp.asarray(tail_toks),
-                    jnp.asarray(row_bt), np.int32(prefix_len),
-                    np.int32(n_tail), np.int32(slot))
-                tok = self._sampler_for(sp)(logits, k)
-            else:
-                tok, self._cache = self._paged_prefill(
-                    self.params, self._cache, jnp.asarray(tail_toks),
-                    jnp.asarray(row_bt), np.int32(prefix_len),
-                    np.int32(n_tail), np.int32(slot), k)
+            with phase("rng_split"):
+                self._rng, k = jax.random.split(self._rng)
+            with phase("prefill_dispatch"):
+                if sp is not None:
+                    logits, self._cache = self._fns.paged_prefill_raw(
+                        self.params, self._cache,
+                        jnp.asarray(tail_toks), jnp.asarray(row_bt),
+                        np.int32(prefix_len), np.int32(n_tail),
+                        np.int32(slot))
+                    tok = self._sampler_for(sp)(logits, k)
+                else:
+                    tok, self._cache = self._paged_prefill(
+                        self.params, self._cache,
+                        jnp.asarray(tail_toks), jnp.asarray(row_bt),
+                        np.int32(prefix_len), np.int32(n_tail),
+                        np.int32(slot), k)
             # int() is the engine's existing host fence for the
             # prefill result; the timestamp behind it is the TTFT
-            first = int(np.asarray(tok)[0])
+            with phase("prefill_fence"):
+                first = int(np.asarray(tok)[0])
             self._telemetry.record_first_token(rec)
             # the prompt's full blocks now hold exactly its K/V —
             # index them so later prompts can skip this work.
@@ -1670,6 +1704,97 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
                     self._finish_slot(i, st)
             return total
 
+        async def _step(self) -> bool:
+            """One iteration's work, inside the open
+            ``raytpu.engine.step``: admit, one decode wave (or one
+            speculative round), the per-wave hooks, at most one chunk
+            of pending prefill.  Every device call and every host
+            chore sits in a leaf phase (_private/scopes.py
+            ENGINE_PHASES).  False when admission left nothing active
+            (every queued request was rejected or finished in its
+            prefill): the loop then goes round without yielding."""
+            import asyncio
+
+            import jax
+            import jax.numpy as jnp
+
+            phase = self._phases.phase
+            with phase("admit"):
+                self._admit_pending()
+            prefilling = [
+                i for i, s in enumerate(self._slots)
+                if s is not None and s.get("state") == "prefill"]
+            n_active = sum(s is not None for s in self._slots)
+            if not n_active:
+                return False
+            n_decode = n_active - len(prefilling)
+            if self._chaos is not None and n_decode:
+                delay_s = self._chaos.token_delay_s(self._replica_label)
+                if delay_s > 0:
+                    # chaos token delay: the loop still heartbeats but
+                    # its requests go token-silent — only the stall
+                    # sweep sees this
+                    await asyncio.sleep(delay_s)
+            # step walltime: dispatch + the np.asarray host fence the
+            # engine already performs, read off the phases' own stamps
+            # — no second perf_counter pair, no extra device sync
+            if n_decode and spec_decode is not None:
+                with phase("spec_round") as rnd:
+                    n_tokens = self._spec_round()
+                self._telemetry.record_step(
+                    n_decode, rnd.t1 - rnd.t0, n_tokens=n_tokens)
+            elif n_decode:
+                with phase("rng_split"):
+                    self._rng, k = jax.random.split(self._rng)
+                if any(st is not None
+                       and st.get("state") != "prefill"
+                       and st["sp"] is not None
+                       for st in self._slots):
+                    with phase("decode_dispatch") as wave:
+                        toks = self._mixed_step(k)   # fences inside
+                    t_wave = wave.t1
+                else:
+                    with phase("decode_dispatch") as wave:
+                        toks, self._cache = self._pool_step(
+                            self.params, self._cache,
+                            jnp.asarray(self._cur), k)
+                    with phase("decode_fence") as fence:
+                        # graftcheck: disable=blocking-call-in-async(the per-step host fence)
+                        toks = np.asarray(toks)
+                    t_wave = fence.t1
+                self._telemetry.record_step(
+                    n_decode, t_wave - wave.t0, now=t_wave)
+                with phase("emit"):
+                    for i, st in enumerate(self._slots):
+                        if st is None or st.get("state") == "prefill":
+                            continue
+                        st["out"].append(int(toks[i]))
+                        self._telemetry.record_token(st["rec"],
+                                                     now=t_wave)
+                        self._cur[i] = toks[i]
+                        if len(st["out"]) >= max_new_tokens \
+                                or self._hit_stop(st["out"]):
+                            self._finish_slot(i, st)
+            with phase("hooks"):
+                if self._telemetry.slo is not None:
+                    # throttled burn-rate watchdog: breach / storm
+                    # transitions postmortem-dump the flight record
+                    self._telemetry.slo.check()
+                if self._health is not None:
+                    # throttled liveness sweep: healthy replicas' waves
+                    # age their peers' heartbeats even while the
+                    # router is quiet
+                    self._health.maybe_probe()
+                if self._pager is not None:
+                    # kvscope occupancy ring: one pool snapshot per
+                    # wave (host counters only, no device sync) — the
+                    # timeline a postmortem replays
+                    self._pager.sample_occupancy()
+            if prefilling:
+                with phase("prefill_chunk"):
+                    self._prefill_chunk_step(prefilling)
+            return True
+
         async def _engine(self):
             """The scheduler loop: admit → one pooled decode step (or
             one speculative draft+verify round) over the decoding
@@ -1679,11 +1804,8 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
             chunked-prefill scheduler: a long prompt costs the other
             slots one chunk window per wave, never a full prefill."""
             import asyncio
-            import time as _time
 
-            import jax
-            import jax.numpy as jnp
-
+            phase = self._phases.phase
             while True:
                 try:
                     if self._chaos is not None and \
@@ -1696,86 +1818,25 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
                     if self._health is not None:
                         # one liveness stamp per wave (a dict store)
                         self._health.heartbeat(self._replica_label)
-                    self._admit_pending()
-                    prefilling = [
-                        i for i, s in enumerate(self._slots)
-                        if s is not None
-                        and s.get("state") == "prefill"]
-                    n_active = sum(s is not None for s in self._slots)
-                    if not n_active:
+                    if not len(self._queue) and all(
+                            s is None for s in self._slots):
+                        # nothing queued, nothing running: park
                         self._wake.clear()
-                        if not len(self._queue):
-                            if self._health is not None:
-                                # parked-idle is not a failure: the
-                                # probe skips idle replicas until the
-                                # next heartbeat re-arms the clock
-                                self._health.note_idle(
-                                    self._replica_label)
-                            await self._wake.wait()
+                        if self._health is not None:
+                            # parked-idle is not a failure: the probe
+                            # skips idle replicas until the next
+                            # heartbeat re-arms the clock
+                            self._health.note_idle(self._replica_label)
+                        await self._wake.wait()
                         continue
-                    n_decode = n_active - len(prefilling)
-                    if self._chaos is not None and n_decode:
-                        delay_s = self._chaos.token_delay_s(
-                            self._replica_label)
-                        if delay_s > 0:
-                            # chaos token delay: the loop still
-                            # heartbeats but its requests go token-
-                            # silent — only the stall sweep sees this
-                            await asyncio.sleep(delay_s)
-                    # step walltime: dispatch + the np.asarray host
-                    # fence the engine already performs — perf_counter
-                    # pairs only, no extra device sync
-                    if n_decode and spec_decode is not None:
-                        t_step = _time.perf_counter()
-                        n_tokens = self._spec_round()
-                        self._telemetry.record_step(
-                            n_decode,
-                            _time.perf_counter() - t_step,
-                            n_tokens=n_tokens)
-                    elif n_decode:
-                        t_step = _time.perf_counter()
-                        self._rng, k = jax.random.split(self._rng)
-                        if any(st is not None
-                               and st.get("state") != "prefill"
-                               and st["sp"] is not None
-                               for st in self._slots):
-                            toks = self._mixed_step(k)
-                        else:
-                            toks, self._cache = self._pool_step(
-                                self.params, self._cache,
-                                jnp.asarray(self._cur), k)
-                            # graftcheck: disable=blocking-call-in-async(the per-step host fence)
-                            toks = np.asarray(toks)
-                        t_wave = _time.perf_counter()
-                        self._telemetry.record_step(
-                            n_decode, t_wave - t_step, now=t_wave)
-                        for i, st in enumerate(self._slots):
-                            if st is None \
-                                    or st.get("state") == "prefill":
-                                continue
-                            st["out"].append(int(toks[i]))
-                            self._telemetry.record_token(st["rec"],
-                                                         now=t_wave)
-                            self._cur[i] = toks[i]
-                            if len(st["out"]) >= max_new_tokens \
-                                    or self._hit_stop(st["out"]):
-                                self._finish_slot(i, st)
-                    if self._telemetry.slo is not None:
-                        # throttled burn-rate watchdog: breach / storm
-                        # transitions postmortem-dump the flight record
-                        self._telemetry.slo.check()
-                    if self._health is not None:
-                        # throttled liveness sweep: healthy replicas'
-                        # waves age their peers' heartbeats even while
-                        # the router is quiet
-                        self._health.maybe_probe()
-                    if self._pager is not None:
-                        # kvscope occupancy ring: one pool snapshot
-                        # per wave (host counters only, no device
-                        # sync) — the timeline a postmortem replays
-                        self._pager.sample_occupancy()
-                    if prefilling:
-                        self._prefill_chunk_step(prefilling)
+                    # one raytpu.engine.step span per iteration with
+                    # work in it; its leaf phases partition it
+                    with self._phases.step():
+                        if await self._step():
+                            with phase("yield"):
+                                # callers enqueue mid-flight here
+                                await asyncio.sleep(0)
+                    continue
                 except Exception as e:  # noqa: BLE001 - fail loudly
                     # crash postmortem: the journal around the failure
                     # is exactly what the flight recorder exists for —
@@ -1803,7 +1864,7 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
                         self._telemetry.record_error(rec, error=repr(e))
                         if not fut.done():
                             fut.set_exception(e)
-                # yield the loop so callers can enqueue mid-flight
+                # after a crash: yield so callers see their exceptions
                 await asyncio.sleep(0)
 
         async def _call_continuous(self, prompt, sampling=None, *,
@@ -1968,6 +2029,9 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
                 self._telemetry.record_health(
                     self._health.replica_block(self._replica_label))
             stats = self._telemetry.engine_stats()
+            # {phase: [count, seconds]} of the scheduler loop; "step"
+            # counts iterations, the others are its leaves
+            stats["phases"] = self._phases.snapshot()
             if admission_policy is not None:
                 stats["admission_policy"] = admission_policy.describe()
             # perf observatory: compiled-cost / recompile / live-MFU

@@ -93,6 +93,7 @@ class jax_utils:
         import jax.numpy as jnp
         import optax
 
+        from ray_tpu._private import scopes
         from ray_tpu.parallel import sharding
 
         in_shardings = None
@@ -102,9 +103,13 @@ class jax_utils:
             in_shardings = (p_shard, None, None)
 
         def step(params, opt_state, batch):
-            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-            updates, opt_state = tx.update(grads, opt_state, params)
-            new_params = optax.apply_updates(params, updates)
+            # scopes are metadata on the program's instructions; the
+            # model's own (attn, mlp, ...) nest under loss_and_grad
+            with jax.named_scope(scopes.LOSS_AND_GRAD):
+                loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+            with jax.named_scope(scopes.OPTIMIZER):
+                updates, opt_state = tx.update(grads, opt_state, params)
+                new_params = optax.apply_updates(params, updates)
             if not health:
                 return new_params, opt_state, loss
             nonfinite = functools.reduce(

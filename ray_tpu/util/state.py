@@ -97,6 +97,12 @@ class profile_device:
     slow task with its device activity is a same-axis comparison.
     A profiler that will not start or stop raises: a run asked to trace
     that returns without a trace has not done what it was asked.
+
+    The Python tracer is off and the host tracer at level 2, as the
+    benchmark's profiler has them: the Python tracer alone wrote
+    300,000 events in two seconds of serving, and level 2 is what keeps
+    the program's own ``raytpu.*`` spans (docs/observability.md,
+    "Scopes and phases").
     """
 
     def __init__(self, logdir: str):
@@ -105,7 +111,10 @@ class profile_device:
     def __enter__(self):
         import jax
 
-        jax.profiler.start_trace(self.logdir)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 2
+        jax.profiler.start_trace(self.logdir, profiler_options=options)
         return self
 
     def __exit__(self, *exc):

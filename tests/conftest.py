@@ -58,3 +58,24 @@ def _tracing_isolation():
     from ray_tpu.util import tracing
 
     tracing.reset_tracing()
+
+
+@pytest.fixture
+def per_call_us():
+    """``per_call_us(fn)``: microseconds one call of `fn` costs, measured
+    in isolation -- ``timeit``, the minimum over repeats, so a loaded
+    box (six xdist workers on shared cores) slows the test down but
+    does not decide it.  The overhead guards hold a recorder's hot call
+    to an absolute budget with this, where they used to time two whole
+    decode loops against each other.  ``per_call_us.calls`` is how often
+    one measurement calls `fn`."""
+    import timeit
+
+    number, repeat = 2000, 7
+
+    def measure(fn) -> float:
+        return min(timeit.repeat(fn, number=number, repeat=repeat)) \
+            / number * 1e6
+
+    measure.calls = number * repeat
+    return measure

@@ -31,8 +31,13 @@ TOP_KEYS = {
     "prefill_compiles", "program_compiles", "rejections_by_reason",
     "kv_cache", "kv_scope", "kv_tier", "spec", "slo", "flightrec",
     "programs", "latency_anatomy", "prefill_chunks", "role", "handoff",
-    "health",
+    "health", "phases",
 }
+
+#: leaf phases every engine that ran a request has been through
+#: (_private/scopes.py ENGINE_PHASES holds the whole list)
+PHASES_ALWAYS = {"step", "loop", "admit", "rng_split",
+                 "prefill_dispatch", "prefill_fence", "hooks", "yield"}
 
 HEALTH_KEYS = {"enabled", "state", "suspect_ms", "dead_ms", "stall_ms",
                "heartbeats", "heartbeat_age_ms", "idle", "transitions",
@@ -94,6 +99,12 @@ def _mesh():
 
 
 def _stats(kv_layout, spec, mesh):
+    # the process-wide program registry counts compiles per program
+    # name over a minute: the engines other tests built on this worker
+    # must not trip its recompile-storm alarm (a postmortem dump) here
+    from ray_tpu._private.device_stats import reset_registry
+
+    reset_registry()
     # generous targets: the SLO block must take its well-behaved
     # (unbreached) shape, not just the breach shape test_flightrec pins
     slo = SLOConfig(ttft_ms=60_000.0, e2e_ms=120_000.0,
@@ -177,6 +188,23 @@ def test_engine_stats_schema(kv_layout, spec, sharded):
     assert kt["hits"] == 0 and kt["misses"] == 0
     assert kt["tokens_restored"] == 0
     assert kt["bytes_resident"] == 0 and kt["entries"] == 0
+
+    # phases: {phase: [count, seconds]} of the scheduler loop, the
+    # same names whatever the layout; "step" counts iterations and the
+    # leaves' seconds add up to its
+    from ray_tpu._private import scopes
+
+    ph = stats["phases"]
+    assert PHASES_ALWAYS <= set(ph) <= set(scopes.ENGINE_PHASES) | {
+        scopes.STEP}
+    for count, seconds in ph.values():
+        assert isinstance(count, int) and count > 0
+        assert isinstance(seconds, float) and seconds >= 0.0
+    assert sum(v[1] for k, v in ph.items() if k != "step") \
+        == pytest.approx(ph["step"][1], rel=1e-9)
+    assert ("kv.reserve" in ph) == (kv_layout == "paged")
+    assert ("spec_round" in ph) == (spec is not None)
+    assert ("decode_fence" in ph) == (spec is None)
 
     # spec block always present; counters move iff spec decoding ran
     assert set(stats["spec"]) == SPEC_KEYS

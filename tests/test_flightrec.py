@@ -14,7 +14,6 @@ import json
 import os
 import subprocess
 import sys
-import time
 
 import numpy as np
 import pytest
@@ -385,27 +384,30 @@ def test_kv_journal_events_carry_key_and_tenant():
 # hot-path overhead guard
 # ---------------------------------------------------------------------------
 
-def test_recorder_overhead_under_5pct(monkeypatch):
-    """The recorder must be cheap enough to leave on: min-of-repeats
-    decode-loop wall time with recording on stays within 5% of the
-    same loop with RAYTPU_FLIGHTREC=0 (record() early-returns)."""
-    dep = _build(max_new_tokens=8)
-    prompts = _prompts(4)
-
-    def run_once():
-        t0 = time.perf_counter()
-        _drive(dep, prompts)
-        return time.perf_counter() - t0
-
-    def best(n=5):
-        return min(run_once() for _ in range(n))
-
-    _drive(dep, prompts)               # compile warmup (shared cache)
-    monkeypatch.setenv("RAYTPU_FLIGHTREC", "0")
-    off = best()
+def test_recorder_overhead_under_5pct(monkeypatch, per_call_us):
+    """The recorder must be cheap enough to leave on.  The engine is
+    driven once with it on (its journal fills); the cost is then held
+    to a budget per ``record()`` call, measured in isolation
+    (``timeit``, min of repeats): the engine journals a handful of
+    events a step, so 5% of even a 1 ms step leaves 10 us each, and a
+    call must stay under 5 us (it is one counter increment and one
+    deque append).  (This used to be a wall-clock A/B of two whole
+    decode loops, which six xdist workers on shared cores decide, not
+    the recorder; the name is kept for the history.)"""
     monkeypatch.setenv("RAYTPU_FLIGHTREC", "1")
-    on = best()
-    assert on <= off * 1.05, (on, off)
+    _, stats = _drive(_build(max_new_tokens=8), _prompts(4))
+    assert stats["flightrec"]["enabled"]
+    assert stats["flightrec"]["recorded"] >= 4 * 3   # admit/first/finish
+
+    rec = FlightRecorder("budget", capacity=256)
+    on = per_call_us(lambda: rec.record("step", dur_ms=1.0, active=2))
+    assert rec.enabled and rec.recorded == per_call_us.calls
+    assert on < 5.0, f"record() costs {on:.2f} us a call"
+    monkeypatch.setenv("RAYTPU_FLIGHTREC", "0")
+    quiet = FlightRecorder("budget_off", capacity=256)
+    off = per_call_us(lambda: quiet.record("step", dur_ms=1.0, active=2))
+    assert not quiet.enabled and quiet.recorded == 0    # early return
+    assert off < 5.0
 
 
 # ---------------------------------------------------------------------------

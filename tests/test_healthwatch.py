@@ -468,7 +468,8 @@ def test_traffic_report_carries_detection_headlines():
 # overhead + inertness guards
 # ---------------------------------------------------------------------------
 
-def test_healthwatch_overhead_under_five_percent_and_chaos_inert():
+def test_healthwatch_overhead_under_five_percent_and_chaos_inert(
+        per_call_us):
     rng = np.random.RandomState(9)
     prompts = [rng.randint(2, 500, 24).astype(np.int32)
                for _ in range(6)]
@@ -494,23 +495,36 @@ def test_healthwatch_overhead_under_five_percent_and_chaos_inert():
         for rep in fleet._replicas:
             rep.inst._health = monitor if on else None
 
+    label = fleet._replicas[0].inst._replica_label
+
     async def main():
-        # compile + first-allocation warmup, outside the measurement
+        outs = await asyncio.gather(*[fleet(p) for p in prompts])
+        armed = monitor.replica_block(label)["heartbeats"]
+        _arm(False)
         await asyncio.gather(*[fleet(p) for p in prompts])
-        on, off = [], []
-        for _ in range(5):  # interleaved pairs: drift hits both arms
-            for armed, acc in ((True, on), (False, off)):
-                _arm(armed)
-                t0 = time.perf_counter()
-                await asyncio.gather(*[fleet(p) for p in prompts])
-                acc.append(time.perf_counter() - t0)
+        disarmed = monitor.replica_block(label)["heartbeats"]
         _arm(True)
-        return min(on), min(off)
+        return outs, armed, disarmed
 
     try:
-        t_on, t_off = asyncio.run(main())
+        outs, armed, disarmed = asyncio.run(main())
+        stats = fleet.fleet_stats()["health"]
     finally:
         fleet.shutdown()
-    # min-of-5 absorbs scheduler noise; the epsilon absorbs timer
-    # granularity on very fast hosts
-    assert t_on <= t_off * 1.05 + 0.002, (t_on, t_off)
+    # armed, the engine's loop heartbeats every wave and no transition
+    # fires under the generous thresholds; disarmed, the same waves
+    # make no call into the monitor at all
+    assert len(outs) == len(prompts)
+    assert armed > 0 and disarmed == armed
+    assert stats["replicas"][label]["state"] == "healthy"
+
+    # the pure hot-path cost, a budget per call measured in isolation
+    # (timeit, min of repeats): one heartbeat and one throttled probe a
+    # wave.  5% of even a 1 ms wave leaves 50 us; the two must stay
+    # under 5 and 20.  (This used to be a wall-clock A/B of armed and
+    # disarmed waves, which six xdist workers on shared cores decide,
+    # not the monitor: it failed under load in PR 25's runs.)
+    beat = per_call_us(lambda: monitor.heartbeat(label))
+    probe = per_call_us(monitor.maybe_probe)
+    assert beat < 5.0, f"heartbeat() costs {beat:.2f} us a call"
+    assert probe < 20.0, f"maybe_probe() costs {probe:.2f} us a call"

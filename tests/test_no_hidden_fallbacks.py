@@ -183,9 +183,14 @@ def test_compile_cache_dir_honours_the_environment(monkeypatch, tmp_path):
         assert cc.enable_compile_cache() == str(tmp_path)
         # JAX's own reading of the variable stands; no other is set
         assert jax.config.jax_compilation_cache_dir == before
+        # no frames, the name stack kept whole, and names in the key
+        assert jax.config.jax_traceback_in_locations_limit == 0
+        assert jax.config.jax_include_full_tracebacks_in_locations
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
     finally:
-        jax.config.update("jax_include_full_tracebacks_in_locations",
-                          True)
+        jax.config.update("jax_traceback_in_locations_limit", 10)
+        jax.config.update(
+            "jax_compilation_cache_include_metadata_in_key", False)
 
 
 def test_compile_cache_dir_is_one_fixed_path_otherwise(monkeypatch):
@@ -196,7 +201,9 @@ def test_compile_cache_dir_is_one_fixed_path_otherwise(monkeypatch):
     env = cc.compile_cache_env()
     assert env[cc.ENV_VAR] == str(ROOT / ".jax_cache")
     # what makes a kernel's key the same from every call site
-    assert env["JAX_INCLUDE_FULL_TRACEBACKS_IN_LOCATIONS"] == "False"
+    assert env["JAX_TRACEBACK_IN_LOCATIONS_LIMIT"] == "0"
+    # and what keeps a hit from handing back another commit's names
+    assert env["JAX_COMPILATION_CACHE_INCLUDE_METADATA_IN_KEY"] == "True"
 
 
 def test_compile_watch_counts_compiles():

@@ -114,11 +114,24 @@ def test_profile_device_captures_xplane(tmp_path):
 
     from ray_tpu.util.state import profile_device
 
+    from ray_tpu._private.telemetry import Phases
+
     d = str(tmp_path / "trace")
     with profile_device(d):
-        jnp.sum(jnp.arange(1000.0)).block_until_ready()
-    assert glob.glob(os.path.join(d, "**", "*.xplane.pb"),
-                     recursive=True)
+        with Phases("engine").phase("emit"):
+            jnp.sum(jnp.arange(1000.0)).block_until_ready()
+    path, = glob.glob(os.path.join(d, "**", "*.xplane.pb"),
+                      recursive=True)
+    # an operator's capture is readable: the Python tracer is off (it
+    # writes an event per call), and the program's own spans are there
+    from jax.profiler import ProfileData
+
+    host = [e.name for plane in ProfileData.from_file(path).planes
+            if plane.name == "/host:CPU"
+            for line in plane.lines for e in line.events]
+    assert "raytpu.engine.emit" in host
+    assert not any(name.startswith("$") for name in host), \
+        "python tracer events in the capture"
 
 
 def test_profile_device_raises_when_profiler_fails(tmp_path, monkeypatch):

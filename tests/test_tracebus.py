@@ -532,37 +532,41 @@ def test_unreadable_dump_exits_2(tmp_path, capsys):
 # hot-path overhead guard (mirrors the flightrec guard)
 # ---------------------------------------------------------------------------
 
-def test_tracebus_overhead_under_5pct(monkeypatch):
-    """Per-token stamping + context threading must be cheap enough to
-    leave on: min-of-repeats decode-loop wall time with tracebus on
-    stays within 5% of RAYTPU_TRACEBUS=0."""
+def test_tracebus_overhead_under_5pct(monkeypatch, per_call_us):
+    """Per-token stamping must be cheap enough to leave on.  The engine
+    is driven once with the tracebus on (every answer's tokens are
+    stamped); the cost is then held to a budget per call, measured in
+    isolation (``timeit``, min of repeats): 5% of even a 1 ms decode
+    step of 8 rows leaves 6 us a token, and a stamp must stay under 5.
+    (This used to be a wall-clock A/B of two whole decode loops, which
+    six xdist workers on shared cores decide, not the tracebus; the
+    name is kept for the history.)"""
+    monkeypatch.setenv("RAYTPU_TRACEBUS", "1")
     dep = build_llm_deployment(
         "gpt2", "nano", scheduler="continuous", kv_layout="paged",
         kv_block_size=16, prefill_bucket=16, max_slots=2,
         max_new_tokens=8, temperature=0.0, config_overrides=_OVR)
-    prompts = _prompts(4)
 
-    def drive():
-        async def main():
-            inst = dep.func_or_class()
-            try:
-                await asyncio.gather(*[inst(p) for p in prompts])
-            finally:
-                inst.shutdown_engine()
+    async def main():
+        inst = dep.func_or_class()
+        try:
+            await asyncio.gather(*[inst(p) for p in _prompts(4)])
+            return inst.trace_records()
+        finally:
+            inst.shutdown_engine()
 
-        asyncio.run(main())
+    records = asyncio.run(main())
+    # one stamp a token: the prefill's first, then one a decode wave
+    assert [len(r["token_ts"]) for r in records] == [8] * 4
 
-    def best(n=5):
-        def run_once():
-            t0 = time.perf_counter()
-            drive()
-            return time.perf_counter() - t0
-
-        return min(run_once() for _ in range(n))
-
-    drive()                            # compile warmup (shared cache)
+    tel = T.EngineTelemetry("tracebus_budget", max_slots=2)
+    rec = tel.record_enqueue(12)
+    assert rec["token_ts"] is not None      # the tracebus is on
+    on = per_call_us(lambda: tel.record_token(rec, now=1.0))
+    assert on < 5.0, f"record_token costs {on:.2f} us a call"
+    assert len(rec["token_ts"]) == per_call_us.calls  # every call stamped
     monkeypatch.setenv("RAYTPU_TRACEBUS", "0")
-    off = best()
-    monkeypatch.setenv("RAYTPU_TRACEBUS", "1")
-    on = best()
-    assert on <= off * 1.05, (on, off)
+    tel_off = T.EngineTelemetry("tracebus_budget_off", max_slots=2)
+    rec_off = tel_off.record_enqueue(12)
+    off = per_call_us(lambda: tel_off.record_token(rec_off, now=1.0))
+    assert rec_off["token_ts"] is None and off < 5.0
