@@ -107,6 +107,7 @@ STATIC_PROGRAM_MAP: Dict[str, str] = {
     # invoke per chunk), so the static spec maps to the same runtime
     # name — the observatory sees N invokes per chunked admission
     "gpt2_chunked_prefill": "serve.paged_prefill",
+    "gpt2_paged_prefill_bucket": "serve.paged_prefill",
     # disaggregated prefill/decode handoff: the export gather on the
     # prefill replica and the donated install splice on the decode
     # replica (serve/llm.py kv_handoff_* programs)
@@ -250,13 +251,18 @@ def _cost_summary(compiled: Any) -> Dict[str, Any]:
         arg_b = int(getattr(ma, "argument_size_in_bytes", 0) or 0)
         out_b = int(getattr(ma, "output_size_in_bytes", 0) or 0)
         tmp_b = int(getattr(ma, "temp_size_in_bytes", 0) or 0)
-        peak = getattr(ma, "peak_heap_usage_in_bytes", None)
+        # argument bytes the executable writes its results into (a
+        # donated KV pool updated in place shows up here whole)
+        alias_b = int(getattr(ma, "alias_size_in_bytes", 0) or 0)
+        peak = getattr(ma, "peak_memory_in_bytes", None)
         if peak is None:
-            # CPU's memory_analysis has no peak gauge: live args +
-            # temps + outputs bounds the executable's footprint
-            peak = arg_b + tmp_b + out_b
+            # a backend without the gauge: live args + temps + the
+            # outputs that are not written over an argument bounds the
+            # executable's footprint
+            peak = arg_b + tmp_b + out_b - alias_b
         out.update(argument_bytes=arg_b, output_bytes=out_b,
-                   temp_bytes=tmp_b, peak_hbm_bytes=int(peak))
+                   temp_bytes=tmp_b, alias_bytes=alias_b,
+                   peak_hbm_bytes=int(peak))
     except Exception:  # noqa: BLE001
         pass
     if out.get("xla_flops") and out.get("bytes_accessed"):
@@ -607,7 +613,12 @@ class ProgramRegistry:
         """Per-program observability block:
 
         ``{compile_events, compile_seconds, invokes, invoke_ms,
-        xla_flops, peak_hbm_bytes, ..., mfu, recompile_storm}``.
+        xla_flops, peak_hbm_bytes, alias_bytes, temp_bytes, ..., mfu,
+        recompile_storm}``.  ``alias_bytes`` is what the executable
+        updates in place of its (donated) arguments, ``temp_bytes``
+        what it allocates beside arguments and results: a serving
+        program that updates its KV pool where it lies reads the
+        pool's bytes in the first and well under them in the second.
 
         ``mfu`` is the live roofline: compiler FLOPs per invocation over
         the mean recent invoke walltime, against ``n_devices`` chips'
@@ -633,6 +644,8 @@ class ProgramRegistry:
                 "arithmetic_intensity": cost.get(
                     "arithmetic_intensity"),
                 "peak_hbm_bytes": cost.get("peak_hbm_bytes"),
+                "alias_bytes": cost.get("alias_bytes"),
+                "temp_bytes": cost.get("temp_bytes"),
                 "recompile_storm": rec["storm_active"],
                 "recompile_storms_total": rec["storms"],
                 "mfu": None,

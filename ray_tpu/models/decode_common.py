@@ -30,15 +30,21 @@ of fixed-size blocks
                   masked pad writes)
   pos, start    : unchanged
 
-The jitted decode step reads the cache through a gather by block id
-(one layer at a time inside the layer scan — never the whole dense
-cache at once) and writes the new token with a scatter at
-(block_tables[b, pos//bs], pos % bs).  Because table entries are kept
-in sequence order, the gathered view is value-identical to the dense
-layout, so attention numerics are bit-identical between layouts — the
-dense path stays the parity oracle (same pattern as
-prefill_impl="scan").  Host-side block allocation / refcounting /
-prefix hashing lives in ray_tpu/serve/kv_pager.py.
+The jitted programs read the cache through a gather by block id (one
+layer at a time inside the layer scan — never the whole dense cache at
+once) and write their new K/V at (block_tables[b, slot//bs],
+slot % bs), into the pool where it lies (`PagedKV` below: the layer
+scan never hands the pool back as its stacked output).  Because table
+entries are kept in sequence order, the gathered view is
+value-identical to the dense layout, so attention numerics are
+bit-identical between layouts — the dense path stays the parity oracle
+(same pattern as prefill_impl="scan").  Host-side block allocation /
+refcounting / prefix hashing lives in ray_tpu/serve/kv_pager.py.
+
+A cache handed to a jitted ENGINE program (serve/llm.py) is consumed:
+those programs donate it, the result is the same buffers updated, and
+the caller rebinds.  The functions here are pure; donation is the
+caller's choice, and what makes `PagedKV` write in place.
 """
 
 from __future__ import annotations
@@ -96,26 +102,128 @@ def is_paged(cache) -> bool:
 
 
 @jax.named_scope(scopes.KV_POOL)
-def paged_update_and_view(layer, block_tables, pos, new):
-    """One decode-step K (or V) update against a paged pool layer.
+def dense_layer_kv(cache, lidx):
+    """Layer `lidx`'s K and V out of the stacked DENSE cache (the
+    parity oracle's layout; a paged pool goes through PagedKV)."""
+    return (lax.dynamic_index_in_dim(cache["k"], lidx, axis=0,
+                                     keepdims=False),
+            lax.dynamic_index_in_dim(cache["v"], lidx, axis=0,
+                                     keepdims=False))
 
-    layer (num_blocks, bs, H, hd) is one layer's block pool;
-    block_tables (B, max_blk) int32; pos (B,) int32; new (B, H, hd).
-    Writes new[b] into block block_tables[b, pos[b]//bs] at offset
-    pos[b] % bs (every active row's tail block is private, so the
-    scatter is conflict-free), then gathers each row's blocks into the
-    dense-equivalent (B, max_blk*bs, H, hd) attention view.  Table
-    entries are sequence-ordered, so view[b, s] holds exactly what the
-    dense cache would hold at slot s — unattended slots carry other
-    sequences' bytes, but the slot mask replaces them with the same
-    -1e30 the dense path writes, keeping logits bit-identical."""
-    bs = layer.shape[1]
-    rows = jnp.arange(block_tables.shape[0])
-    blk = block_tables[rows, pos // bs]
-    layer = layer.at[blk, pos % bs].set(new)
-    view = layer[block_tables]            # (B, max_blk, bs, H, hd)
-    b, nb = block_tables.shape
-    return layer, view.reshape(b, nb * bs, *layer.shape[2:])
+
+class PagedKV:
+    """One program's use of the paged pool, for every decoder family.
+
+    cache["k"] / cache["v"] are the WHOLE stacked pools
+    (L, num_blocks, bs, H, hd); block_tables (B, max_blk) int32 names
+    the rows the program attends over; slots (B, T) int32 is the cache
+    slot each of the program's new K/V rows lands at (one decode token:
+    pos[:, None]; a prefill's tail: one row of Tt columns).  A slot
+    >= max_blk*bs is a masked write (a prefill's pad column, a verify
+    position past max_seq): it goes to the null block 0 and is dropped
+    from the view, so it can never land on a live slot.  Use:
+
+        kv = PagedKV(cache, block_tables, slots)
+        def body((x, lidx, pools), layer):
+            ...
+            pools, (ck, cv) = kv.attend(lidx, pools, k_new, v_new)
+            ...
+            return (x, lidx + 1, pools), (k_new, v_new)
+        (x, _, pools), (ks, vs) = lax.scan(body, (x, 0, kv.pools), ...)
+        cache = kv.commit(pools, ks, vs)
+
+    `attend` slices layer lidx out of the pool and gathers the rows'
+    blocks into the dense-equivalent (B, max_blk*bs, H, hd) views, with
+    the new (B, T, H, hd) rows in them at their slots.  Table entries
+    are sequence-ordered, so every attendable view[b, s] holds exactly
+    what the dense cache holds at slot s (each active row's tail block
+    is private, so only its own write can be in it); unattended slots
+    carry other sequences' bytes, which the slot mask replaces with the
+    same -1e30 the dense path writes: logits stay bit-identical.
+
+    How the rows reach the pool follows from the program's shape, the
+    static T.  One column a row (T == 1, a decode step): the pool is
+    READ-ONLY in the scan, neither written nor stacked; the row goes
+    into the gathered view, and `commit` lands the scan's stacked new
+    rows with one dynamic_update_slice a row, every layer at once.  A
+    block of columns a row (a prefill's tail, a verify block): `attend`
+    writes them into its copy of the layer, gathers the views from that
+    and writes the layer back into the carried pool; `commit` has
+    nothing left to do.  Either way the pool is updated where it lies:
+    with the cache donated to the jitted program no second pool exists,
+    and the layer scan never stacks one as its `ys`.  (Why two ways,
+    and what each costs on the chip: PERF.md, PR 26.)
+
+    (The layer is sliced out before the gather on purpose.  A TPU
+    stores the pool with the block axis minor-most, the only order of
+    this shape its tiles do not pad, and a gather or a scatter by block
+    id needs the block axis major: given the whole pool, either makes
+    the compiler re-lay ALL of it, padded, and the program no longer
+    fits the chip.  Sliced, it re-lays one layer at a time;
+    dynamic_update_slice works in any layout.)"""
+
+    def __init__(self, cache, block_tables, slots):
+        self.cache = cache
+        self.block_tables = block_tables
+        self.slots = slots
+        self.L, _, self.bs, *self.tail = cache["k"].shape
+        self.B, self.nb = block_tables.shape
+        self.T = slots.shape[1]
+        #: one column a row: the pool is read-only in the scan and
+        #: `commit` writes the rows; more: `attend` writes layers back
+        self.by_rows = self.T == 1
+        self.rows = jnp.arange(self.B)[:, None]
+        with jax.named_scope(scopes.KV_POOL):
+            live = slots < self.nb * self.bs
+            self.blk = jnp.where(
+                live, block_tables[self.rows,
+                                   jnp.minimum(slots // self.bs,
+                                               self.nb - 1)], 0)
+            self.off = jnp.where(live, slots % self.bs, 0)
+
+    @property
+    def pools(self):
+        """What the layer scan carries: (K pool, V pool)."""
+        return self.cache["k"], self.cache["v"]
+
+    @jax.named_scope(scopes.KV_POOL)
+    def attend(self, lidx, pools, k_new, v_new):
+        """Inside the scan body: (pools, (ck, cv)) for layer `lidx`."""
+        out, views = [], []
+        for pool, new in zip(pools, (k_new, v_new)):
+            layer = lax.dynamic_index_in_dim(pool, lidx, 0,
+                                             keepdims=False)
+            if not self.by_rows:
+                layer = layer.at[self.blk, self.off].set(new)
+                pool = lax.dynamic_update_index_in_dim(pool, layer,
+                                                       lidx, 0)
+            view = layer[self.block_tables]  # (B, max_blk, bs, H, hd)
+            view = view.reshape(self.B, self.nb * self.bs, *self.tail)
+            if self.by_rows:
+                # into the gathered copy, not the layer's
+                view = view.at[self.rows, self.slots].set(new,
+                                                         mode="drop")
+            out.append(pool)
+            views.append(view)
+        return tuple(out), tuple(views)
+
+    @jax.named_scope(scopes.KV_POOL)
+    def commit(self, pools, ks, vs):
+        """After the scan: the cache with the program's new rows in
+        the pool (ks, vs: the scan's stacked (L, B, T, H, hd))."""
+        if self.by_rows:
+            shape = (self.L, 1, 1, *self.tail)
+
+            def one(b, pools):
+                at = (0, self.blk[b, 0], self.off[b, 0], 0, 0)
+                return tuple(
+                    lax.dynamic_update_slice(
+                        pool, lax.dynamic_slice(new, (0, b, 0, 0, 0),
+                                                shape), at)
+                    for pool, new in zip(pools, (ks, vs)))
+
+            pools = lax.fori_loop(0, self.B, one, pools)
+        return dict(self.cache, k=pools[0], v=pools[1])
 
 
 def cache_logical_axes(cache):

@@ -35,8 +35,8 @@ from jax import lax
 
 from ray_tpu._private import scopes
 from ray_tpu.models import decode_common
-from ray_tpu.models.decode_common import (generate_with, is_paged,
-                                          paged_update_and_view,
+from ray_tpu.models.decode_common import (PagedKV, dense_layer_kv,
+                                          generate_with, is_paged,
                                           scan_prefill, slot_mask)
 from ray_tpu.models.gpt2 import GPT2Config, _layernorm
 
@@ -106,15 +106,6 @@ def _mlp(xm, p, cfg: GPT2Config):
                        + p["fc_b"].astype(cfg.dtype))
     return (hmid @ p["proj_w"].astype(cfg.dtype)
             + p["proj_b"].astype(cfg.dtype))
-
-
-@jax.named_scope(scopes.KV_POOL)
-def _layer_kv(cache, lidx):
-    """Layer `lidx`'s K and V out of the stacked cache (or pool)."""
-    return (lax.dynamic_index_in_dim(cache["k"], lidx, axis=0,
-                                     keepdims=False),
-            lax.dynamic_index_in_dim(cache["v"], lidx, axis=0,
-                                     keepdims=False))
 
 
 @jax.named_scope(scopes.LM_HEAD)
@@ -203,11 +194,13 @@ def paged_prefill(params, cache, tokens: jnp.ndarray, cfg: GPT2Config,
     row_bt (max_seq // block_size,) int32 is the row's full block
     table: entries < prefix_len//bs name already-resident prefix blocks
     whose K/V are read, not recomputed — that is the entire point.
-    Tail K/V are scattered into the pool (pad columns route to the
-    reserved null block 0); attention for the Tt queries runs against
-    the row's gathered pool view with a causal-by-logical-position
-    mask.  prefix_len / n_tail / slot are dynamic scalars — one
-    compiled program per (Tt bucket, pool shape) serves every request.
+    Tail K/V are written into the pool where it lies (pad columns
+    are masked writes: dropped, or routed to the reserved null block
+    0); attention for the Tt queries runs against the row's gathered
+    pool view, the tail in it, with a causal-by-logical-position mask
+    (decode_common.PagedKV owns both).  prefix_len / n_tail / slot
+    are dynamic scalars — one compiled program per (Tt bucket, pool
+    shape) serves every request.
 
     Returns (last-token logits (padded_vocab,) float32, cache with
     pool K/V updated and row `slot`'s table/pos/start set).  Paged
@@ -215,7 +208,6 @@ def paged_prefill(params, cache, tokens: jnp.ndarray, cfg: GPT2Config,
     that makes blocks shareable across sequences)."""
     _, Tt = tokens.shape
     d, h, hd = cfg.d_model, cfg.n_head, cfg.head_dim
-    bs = cache["k"].shape[2]
     prefix_len = jnp.asarray(prefix_len, jnp.int32)
     n_tail = jnp.asarray(n_tail, jnp.int32)
     pad = Tt - n_tail
@@ -223,11 +215,10 @@ def paged_prefill(params, cache, tokens: jnp.ndarray, cfg: GPT2Config,
     real = col >= pad                          # (Tt,), False on pads
     logical = prefix_len + col - pad           # position iff real
     pos_ids = jnp.maximum(logical, 0)          # pads clip to wpe row 0
-    # scatter targets for tail K/V: pad columns MUST go to the null
-    # block — their logical index can alias a live prefix slot
-    with jax.named_scope(scopes.KV_POOL):
-        blk = jnp.where(real, row_bt[pos_ids // bs], 0)
-        off = jnp.where(real, logical % bs, 0)
+    # write slots for tail K/V: pad columns MUST be masked writes
+    # (slot max_seq) — their logical index can alias a live prefix slot
+    pkv = PagedKV(cache, row_bt[None],
+                  jnp.where(real, logical, cfg.max_seq)[None])
     # key slot s attendable by query column c iff c is real and
     # s <= logical[c] (all-masked pad columns softmax to uniform —
     # finite garbage that never reaches the pool or the logits)
@@ -240,9 +231,8 @@ def paged_prefill(params, cache, tokens: jnp.ndarray, cfg: GPT2Config,
         x = x + params["wpe"].astype(cfg.dtype)[pos_ids]
 
     def body(carry, layer):
-        x, lidx = carry
+        x, lidx, pools = carry
         p, = layer
-        lk, lv = _layer_kv(cache, lidx)                  # (nb,bs,H,hd)
         xa = _layernorm(x, p["ln1"]["scale"], p["ln1"]["bias"])
         with jax.named_scope(scopes.ATTN):
             w = p["attn"]["qkv_w"].astype(cfg.dtype).reshape(
@@ -250,11 +240,9 @@ def paged_prefill(params, cache, tokens: jnp.ndarray, cfg: GPT2Config,
             qkv = (xa @ w).reshape(Tt, 3, h, hd) \
                 + p["attn"]["qkv_b"].astype(cfg.dtype)
             q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]    # (Tt,h,hd)
-        with jax.named_scope(scopes.KV_POOL):
-            lk = lk.at[blk, off].set(k)
-            lv = lv.at[blk, off].set(v)
-            kview = lk[row_bt].reshape(cfg.max_seq, h, hd)
-            vview = lv[row_bt].reshape(cfg.max_seq, h, hd)
+        pools, (kview, vview) = pkv.attend(lidx, pools, k[None],
+                                          v[None])
+        kview, vview = kview[0], vview[0]                # (S,h,hd)
         with jax.named_scope(scopes.ATTN):
             scores = jnp.einsum("qhd,khd->hqk", q,
                                 kview).astype(jnp.float32) * scale
@@ -266,17 +254,17 @@ def paged_prefill(params, cache, tokens: jnp.ndarray, cfg: GPT2Config,
                      + p["attn"]["o_b"].astype(cfg.dtype))
         x = x + _mlp(_layernorm(x, p["ln2"]["scale"], p["ln2"]["bias"]),
                      p["mlp"], cfg)
-        return (x, lidx + 1), (lk, lv)
+        return (x, lidx + 1, pools), (k[None], v[None])
 
     with jax.named_scope(scopes.LAYER_SCAN):
-        (x, _), (new_k, new_v) = lax.scan(body, (x, jnp.int32(0)),
-                                          (params["blocks"],))
+        (x, _, pools), (new_k, new_v) = lax.scan(
+            body, (x, jnp.int32(0), pkv.pools),
+            (params["blocks"],))
     x = _layernorm(x, params["ln_f"]["scale"], params["ln_f"]["bias"])
     last = x[-1]                    # right-aligned ⇒ last real token
     logits = _lm_head(last, params, cfg)
-    out = dict(cache)
+    out = pkv.commit(pools, new_k, new_v)
     with jax.named_scope(scopes.KV_POOL):
-        out["k"], out["v"] = new_k, new_v
         out["block_tables"] = cache["block_tables"].at[slot].set(row_bt)
         out["pos"] = cache["pos"].at[slot].set(prefix_len + n_tail)
         out["start"] = cache["start"].at[slot].set(0)
@@ -291,10 +279,12 @@ def decode_step(params, cache, tokens, cfg: GPT2Config
 
     Works on both cache layouts (the pytree structure is the knob —
     decode_common.is_paged): dense caches index a (B, S, ...) layer and
-    write slot pos[b]; paged caches scatter into the row's pool block
-    and attend over the gathered block-table view, which is
-    value-identical to the dense layer, so everything downstream of the
-    K/V update is shared verbatim between layouts.
+    write slot pos[b]; paged caches attend over the block-table view
+    gathered from the pool with the new token in it — value-identical
+    to the dense layer, so everything downstream of the K/V update is
+    shared verbatim between layouts — and write the step's K/V into
+    the pool where it lies (decode_common.PagedKV: the pool is
+    read-only inside the layer scan, the rows land after it).
 
     Returns (logits (B, padded_vocab) float32, updated cache)."""
     B = tokens.shape[0]
@@ -310,11 +300,12 @@ def decode_step(params, cache, tokens, cfg: GPT2Config
     # per-slot mask: start[b] <= s <= pos[b] (current token included)
     with jax.named_scope(scopes.ATTN):
         attn_mask = slot_mask(start, pos + 1, cfg.max_seq)   # (B, S)
+    pkv = PagedKV(cache, cache["block_tables"],
+                  pos[:, None]) if paged else None
 
     def body(carry, layer):
-        x, lidx = carry
+        x, lidx, pools = carry
         p, = layer
-        lk, lv = _layer_kv(cache, lidx)
         xa = _layernorm(x, p["ln1"]["scale"], p["ln1"]["bias"])
         with jax.named_scope(scopes.ATTN):
             w = p["attn"]["qkv_w"].astype(cfg.dtype).reshape(
@@ -323,13 +314,14 @@ def decode_step(params, cache, tokens, cfg: GPT2Config
                 + p["attn"]["qkv_b"].astype(cfg.dtype)
             q, k_new, v_new = qkv[:, 0], qkv[:, 1], qkv[:, 2]  # (B,h,hd)
         if paged:
-            bt = cache["block_tables"]
-            lk, ck = paged_update_and_view(lk, bt, pos, k_new)
-            lv, cv = paged_update_and_view(lv, bt, pos, v_new)
+            new = (k_new[:, None], v_new[:, None])       # (B,1,h,hd)
+            pools, (ck, cv) = pkv.attend(lidx, pools, *new)
         else:
+            lk, lv = dense_layer_kv(cache, lidx)
             with jax.named_scope(scopes.KV_POOL):
-                lk = ck = lk.at[rows, pos].set(k_new)  # row b → pos[b]
-                lv = cv = lv.at[rows, pos].set(v_new)
+                ck = lk.at[rows, pos].set(k_new)   # row b → pos[b]
+                cv = lv.at[rows, pos].set(v_new)
+            new = (ck, cv)
         with jax.named_scope(scopes.ATTN):
             # attention of the single query against the cache
             scores = jnp.einsum("bhd,bshd->bhs", q,
@@ -343,16 +335,20 @@ def decode_step(params, cache, tokens, cfg: GPT2Config
                      + p["attn"]["o_b"].astype(cfg.dtype))
         x = x + _mlp(_layernorm(x, p["ln2"]["scale"], p["ln2"]["bias"]),
                      p["mlp"], cfg)
-        return (x, lidx + 1), (lk, lv)
+        return (x, lidx + 1, pools), new
 
     with jax.named_scope(scopes.LAYER_SCAN):
-        (x, _), (new_k, new_v) = lax.scan(body, (x, jnp.int32(0)),
-                                          (params["blocks"],))
+        (x, _, pools), (new_k, new_v) = lax.scan(
+            body, (x, jnp.int32(0), pkv.pools if pkv else ()),
+            (params["blocks"],))
     x = _layernorm(x, params["ln_f"]["scale"], params["ln_f"]["bias"])
     logits = _lm_head(x, params, cfg)
-    out = dict(cache)
+    if paged:
+        out = pkv.commit(pools, new_k, new_v)
+    else:
+        out = dict(cache, k=new_k, v=new_v)
     with jax.named_scope(scopes.KV_POOL):
-        out["k"], out["v"], out["pos"] = new_k, new_v, pos + 1
+        out["pos"] = pos + 1
     return logits, out
 
 
@@ -384,7 +380,6 @@ def verify_step(params, cache, block, cfg: GPT2Config
     with jax.named_scope(scopes.KV_POOL):
         offs = jnp.arange(T, dtype=jnp.int32)
         slot_ids = pos[:, None] + offs[None, :]          # (B, T)
-        in_range = slot_ids < cfg.max_seq
     with jax.named_scope(scopes.EMBED):
         pos_ids = jnp.minimum(
             jnp.maximum(slot_ids - start[:, None], 0), cfg.max_seq - 1)
@@ -395,21 +390,19 @@ def verify_step(params, cache, block, cfg: GPT2Config
         s = jnp.arange(cfg.max_seq)
         attn_mask = (s[None, None, :] >= start[:, None, None]) & \
                     (s[None, None, :] <= slot_ids[:, :, None])
-    with jax.named_scope(scopes.KV_POOL):
-        if paged:
-            bt = cache["block_tables"]
-            bs = cache["k"].shape[2]
-            blk_col = jnp.minimum(slot_ids // bs, bt.shape[1] - 1)
-            blk = jnp.where(in_range, bt[rows[:, None], blk_col], 0)
-            off = jnp.where(in_range, slot_ids % bs, 0)
-        else:
+    pkv = None
+    if paged:
+        # slots past max_seq are PagedKV's masked writes
+        pkv = PagedKV(cache, cache["block_tables"], slot_ids)
+    else:
+        with jax.named_scope(scopes.KV_POOL):
             # OOB rows dropped by the scatter (mode="drop")
-            write_idx = jnp.where(in_range, slot_ids, cfg.max_seq)
+            write_idx = jnp.where(slot_ids < cfg.max_seq, slot_ids,
+                                  cfg.max_seq)
 
     def body(carry, layer):
-        x, lidx = carry
+        x, lidx, pools = carry
         p, = layer
-        lk, lv = _layer_kv(cache, lidx)
         xa = _layernorm(x, p["ln1"]["scale"], p["ln1"]["bias"])
         with jax.named_scope(scopes.ATTN):
             w = p["attn"]["qkv_w"].astype(cfg.dtype).reshape(
@@ -417,17 +410,17 @@ def verify_step(params, cache, block, cfg: GPT2Config
             qkv = (xa @ w).reshape(B, T, 3, h, hd) \
                 + p["attn"]["qkv_b"].astype(cfg.dtype)
             q, k_new, v_new = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        with jax.named_scope(scopes.KV_POOL):
-            if paged:
-                lk = lk.at[blk, off].set(k_new)
-                lv = lv.at[blk, off].set(v_new)
-                ck = lk[bt].reshape(B, cfg.max_seq, h, hd)
-                cv = lv[bt].reshape(B, cfg.max_seq, h, hd)
-            else:
-                lk = ck = lk.at[rows[:, None], write_idx].set(
+        if paged:
+            new = (k_new, v_new)
+            pools, (ck, cv) = pkv.attend(lidx, pools, *new)
+        else:
+            lk, lv = dense_layer_kv(cache, lidx)
+            with jax.named_scope(scopes.KV_POOL):
+                ck = lk.at[rows[:, None], write_idx].set(
                     k_new, mode="drop")
-                lv = cv = lv.at[rows[:, None], write_idx].set(
+                cv = lv.at[rows[:, None], write_idx].set(
                     v_new, mode="drop")
+            new = (ck, cv)
         with jax.named_scope(scopes.ATTN):
             scores = jnp.einsum("bthd,bshd->bhts", q,
                                 ck).astype(jnp.float32)
@@ -440,16 +433,17 @@ def verify_step(params, cache, block, cfg: GPT2Config
                      + p["attn"]["o_b"].astype(cfg.dtype))
         x = x + _mlp(_layernorm(x, p["ln2"]["scale"], p["ln2"]["bias"]),
                      p["mlp"], cfg)
-        return (x, lidx + 1), (lk, lv)
+        return (x, lidx + 1, pools), new
 
     with jax.named_scope(scopes.LAYER_SCAN):
-        (x, _), (new_k, new_v) = lax.scan(body, (x, jnp.int32(0)),
-                                          (params["blocks"],))
+        (x, _, pools), (new_k, new_v) = lax.scan(
+            body, (x, jnp.int32(0), pkv.pools if pkv else ()),
+            (params["blocks"],))
     x = _layernorm(x, params["ln_f"]["scale"], params["ln_f"]["bias"])
     logits = _lm_head(x, params, cfg)
-    out = dict(cache)
-    out["k"], out["v"] = new_k, new_v
-    return logits, out
+    if paged:
+        return logits, pkv.commit(pools, new_k, new_v)
+    return logits, dict(cache, k=new_k, v=new_v)
 
 
 def _scan_prefill(params, tokens, cfg, *, lengths=None):

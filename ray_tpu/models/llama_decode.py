@@ -19,8 +19,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.models import decode_common
-from ray_tpu.models.decode_common import (generate_with, is_paged,
-                                          paged_update_and_view,
+from ray_tpu.models.decode_common import (PagedKV, dense_layer_kv,
+                                          generate_with, is_paged,
                                           scan_prefill, slot_mask)
 from ray_tpu.models.llama import (LlamaConfig, _rmsnorm,
                                   rope_frequencies)
@@ -187,14 +187,14 @@ def llama_paged_prefill(params, cache, tokens: jnp.ndarray,
     """Prompt-tail ingestion for ONE sequence against the block pool
     (see gpt2_decode.paged_prefill for the full contract): tokens
     (1, Tt) RIGHT-aligned tail, prefix K/V read from resident pool
-    blocks via row_bt, tail K/V (post-RoPE, kv heads only) scattered in
-    (pads → null block 0).  RoPE follows logical positions, and the
+    blocks via row_bt, tail K/V (post-RoPE, kv heads only) written into
+    the pool where it lies (pads are masked writes;
+    decode_common.PagedKV).  RoPE follows logical positions, and the
     kv heads are repeated to n_head for attention exactly as in
     llama_prefill so the hidden states match the dense path."""
     _, Tt = tokens.shape
     d, h, kv, hd = (cfg.d_model, cfg.n_head, cfg.n_kv_head,
                     cfg.head_dim)
-    bs = cache["k"].shape[2]
     prefix_len = jnp.asarray(prefix_len, jnp.int32)
     n_tail = jnp.asarray(n_tail, jnp.int32)
     pad = Tt - n_tail
@@ -202,10 +202,10 @@ def llama_paged_prefill(params, cache, tokens: jnp.ndarray,
     real = col >= pad                          # (Tt,), False on pads
     logical = prefix_len + col - pad           # position iff real
     pos_ids = jnp.maximum(logical, 0)          # pads clip to position 0
-    # pad columns MUST scatter to the null block — their logical index
-    # can alias a live prefix slot
-    blk = jnp.where(real, row_bt[pos_ids // bs], 0)
-    off = jnp.where(real, logical % bs, 0)
+    # pad columns MUST be masked writes (slot max_seq) — their logical
+    # index can alias a live prefix slot
+    pkv = PagedKV(cache, row_bt[None],
+                  jnp.where(real, logical, cfg.max_seq)[None])
     mask = real[:, None] & (
         jnp.arange(cfg.max_seq)[None, :] <= logical[:, None])
     scale = 1.0 / math.sqrt(hd)
@@ -214,12 +214,8 @@ def llama_paged_prefill(params, cache, tokens: jnp.ndarray,
     cos_p, sin_p = cos[pos_ids], sin[pos_ids]            # (Tt, hd/2)
 
     def body(carry, layer):
-        x, lidx = carry
+        x, lidx, pools = carry
         p, = layer
-        lk = lax.dynamic_index_in_dim(cache["k"], lidx, axis=0,
-                                      keepdims=False)    # (nb,bs,kv,hd)
-        lv = lax.dynamic_index_in_dim(cache["v"], lidx, axis=0,
-                                      keepdims=False)
         xa = _rmsnorm(x, p["ln1"]["scale"], cfg.rms_eps)
         xa = xa.astype(cfg.dtype)
         q = (xa @ p["attn"]["wq"].astype(cfg.dtype).reshape(d, h * hd)
@@ -230,10 +226,9 @@ def llama_paged_prefill(params, cache, tokens: jnp.ndarray,
              ).reshape(Tt, kv, hd)
         q = _rope_at(q, cos_p, sin_p)
         k = _rope_at(k, cos_p, sin_p)
-        lk = lk.at[blk, off].set(k)
-        lv = lv.at[blk, off].set(v)
-        kview = lk[row_bt].reshape(cfg.max_seq, kv, hd)
-        vview = lv[row_bt].reshape(cfg.max_seq, kv, hd)
+        pools, (kview, vview) = pkv.attend(lidx, pools, k[None],
+                                          v[None])
+        kview, vview = kview[0], vview[0]                # (S,kv,hd)
         if kv != h:
             rep = h // kv
             kview = jnp.repeat(kview, rep, axis=1)
@@ -252,17 +247,17 @@ def llama_paged_prefill(params, cache, tokens: jnp.ndarray,
         hmid = jax.nn.silu(gate) * up
         x = x + (hmid @ p["mlp"]["w_down"].astype(cfg.dtype)
                  ).astype(x.dtype)
-        return (x, lidx + 1), (lk, lv)
+        return (x, lidx + 1, pools), (k[None], v[None])
 
-    (x, _), (new_k, new_v) = lax.scan(body, (x, jnp.int32(0)),
-                                      (params["blocks"],))
+    (x, _, pools), (new_k, new_v) = lax.scan(
+        body, (x, jnp.int32(0), pkv.pools),
+        (params["blocks"],))
     x = _rmsnorm(x, params["ln_f"]["scale"], cfg.rms_eps)
     last = x[-1]                    # right-aligned ⇒ last real token
     logits = (last.astype(cfg.dtype)
               @ params["lm_head"].astype(cfg.dtype)
               ).astype(jnp.float32)
-    out = dict(cache)
-    out["k"], out["v"] = new_k, new_v
+    out = pkv.commit(pools, new_k, new_v)
     out["block_tables"] = cache["block_tables"].at[slot].set(row_bt)
     out["pos"] = cache["pos"].at[slot].set(prefix_len + n_tail)
     out["start"] = cache["start"].at[slot].set(0)
@@ -275,9 +270,12 @@ def llama_decode_step(params, cache, tokens, cfg: LlamaConfig
     cache["pos"][b]; RoPE at each row's LOGICAL position pos - start.
 
     Works on both cache layouts (decode_common.is_paged): dense caches
-    write slot pos[b] in a (B, S, ...) layer; paged caches scatter into
-    the row's pool block and attend over the gathered block-table view
-    (value-identical to dense, so the attention math is shared).
+    write slot pos[b] in a (B, S, ...) layer; paged caches attend over
+    the block-table view gathered from the pool with the new token in
+    it (value-identical to dense, so the attention math is shared) and
+    write the step's K/V into the pool where it lies
+    (decode_common.PagedKV: read-only inside the layer scan, the rows
+    land after it).
 
     Returns (logits (B, padded_vocab) float32, updated cache)."""
     B = tokens.shape[0]
@@ -292,14 +290,12 @@ def llama_decode_step(params, cache, tokens, cfg: LlamaConfig
     cos, sin = rope_frequencies(cfg.max_seq, hd, cfg.rope_theta)
     cos_t, sin_t = cos[pos - start], sin[pos - start]    # (B, hd/2)
     attn_mask = slot_mask(start, pos + 1, cfg.max_seq)   # (B, S)
+    pkv = PagedKV(cache, cache["block_tables"],
+                  pos[:, None]) if paged else None
 
     def body(carry, layer):
-        x, lidx = carry
+        x, lidx, pools = carry
         p, = layer
-        lk = lax.dynamic_index_in_dim(cache["k"], lidx, axis=0,
-                                      keepdims=False)
-        lv = lax.dynamic_index_in_dim(cache["v"], lidx, axis=0,
-                                      keepdims=False)
         xa = _rmsnorm(x, p["ln1"]["scale"], cfg.rms_eps)
         xa = xa.astype(cfg.dtype)
         q = (xa @ p["attn"]["wq"].astype(cfg.dtype).reshape(d, h * hd)
@@ -311,12 +307,13 @@ def llama_decode_step(params, cache, tokens, cfg: LlamaConfig
         q = _rope_at(q, cos_t, sin_t)
         k_new = _rope_at(k_new, cos_t, sin_t)
         if paged:
-            bt = cache["block_tables"]
-            lk, ck = paged_update_and_view(lk, bt, pos, k_new)
-            lv, cv = paged_update_and_view(lv, bt, pos, v_new)
+            new = (k_new[:, None], v_new[:, None])       # (B,1,kv,hd)
+            pools, (ck, cv) = pkv.attend(lidx, pools, *new)
         else:
-            lk = ck = lk.at[rows, pos].set(k_new)  # row b → slot pos[b]
-            lv = cv = lv.at[rows, pos].set(v_new)
+            lk, lv = dense_layer_kv(cache, lidx)
+            ck = lk.at[rows, pos].set(k_new)   # row b → slot pos[b]
+            cv = lv.at[rows, pos].set(v_new)
+            new = (ck, cv)
         # grouped-query attention against the kv-head cache: query
         # heads reshape to (kv, group) — no head repetition needed
         qg = q.reshape(B, kv, g, hd)
@@ -336,16 +333,20 @@ def llama_decode_step(params, cache, tokens, cfg: LlamaConfig
         hmid = jax.nn.silu(gate) * up
         x = x + (hmid @ p["mlp"]["w_down"].astype(cfg.dtype)
                  ).astype(x.dtype)
-        return (x, lidx + 1), (lk, lv)
+        return (x, lidx + 1, pools), new
 
-    (x, _), (new_k, new_v) = lax.scan(body, (x, jnp.int32(0)),
-                                      (params["blocks"],))
+    (x, _, pools), (new_k, new_v) = lax.scan(
+        body, (x, jnp.int32(0), pkv.pools if pkv else ()),
+        (params["blocks"],))
     x = _rmsnorm(x, params["ln_f"]["scale"], cfg.rms_eps)
     logits = (x.astype(cfg.dtype)
               @ params["lm_head"].astype(cfg.dtype)
               ).astype(jnp.float32)
-    out = dict(cache)
-    out["k"], out["v"], out["pos"] = new_k, new_v, pos + 1
+    if paged:
+        out = pkv.commit(pools, new_k, new_v)
+    else:
+        out = dict(cache, k=new_k, v=new_v)
+    out["pos"] = pos + 1
     return logits, out
 
 
@@ -370,7 +371,6 @@ def llama_verify_step(params, cache, block, cfg: LlamaConfig
     rows = jnp.arange(B)
     offs = jnp.arange(T, dtype=jnp.int32)
     slot_ids = pos[:, None] + offs[None, :]              # (B, T)
-    in_range = slot_ids < cfg.max_seq
     pos_ids = jnp.minimum(jnp.maximum(slot_ids - start[:, None], 0),
                           cfg.max_seq - 1)
     x = params["wte"].astype(cfg.dtype)[block]           # (B, T, d)
@@ -379,22 +379,17 @@ def llama_verify_step(params, cache, block, cfg: LlamaConfig
     s = jnp.arange(cfg.max_seq)
     attn_mask = (s[None, None, :] >= start[:, None, None]) & \
                 (s[None, None, :] <= slot_ids[:, :, None])
+    pkv = None
     if paged:
-        bt = cache["block_tables"]
-        bs = cache["k"].shape[2]
-        blk_col = jnp.minimum(slot_ids // bs, bt.shape[1] - 1)
-        blk = jnp.where(in_range, bt[rows[:, None], blk_col], 0)
-        off = jnp.where(in_range, slot_ids % bs, 0)
+        # slots past max_seq are PagedKV's masked writes
+        pkv = PagedKV(cache, cache["block_tables"], slot_ids)
     else:
-        write_idx = jnp.where(in_range, slot_ids, cfg.max_seq)
+        write_idx = jnp.where(slot_ids < cfg.max_seq, slot_ids,
+                              cfg.max_seq)
 
     def body(carry, layer):
-        x, lidx = carry
+        x, lidx, pools = carry
         p, = layer
-        lk = lax.dynamic_index_in_dim(cache["k"], lidx, axis=0,
-                                      keepdims=False)
-        lv = lax.dynamic_index_in_dim(cache["v"], lidx, axis=0,
-                                      keepdims=False)
         xa = _rmsnorm(x, p["ln1"]["scale"], cfg.rms_eps)
         xa = xa.astype(cfg.dtype)
         q = (xa @ p["attn"]["wq"].astype(cfg.dtype).reshape(d, h * hd)
@@ -406,15 +401,15 @@ def llama_verify_step(params, cache, block, cfg: LlamaConfig
         q = _rope_bt(q, cos_p, sin_p)
         k_new = _rope_bt(k_new, cos_p, sin_p)
         if paged:
-            lk = lk.at[blk, off].set(k_new)
-            lv = lv.at[blk, off].set(v_new)
-            ck = lk[bt].reshape(B, cfg.max_seq, kv, hd)
-            cv = lv[bt].reshape(B, cfg.max_seq, kv, hd)
+            new = (k_new, v_new)
+            pools, (ck, cv) = pkv.attend(lidx, pools, *new)
         else:
-            lk = ck = lk.at[rows[:, None], write_idx].set(
+            lk, lv = dense_layer_kv(cache, lidx)
+            ck = lk.at[rows[:, None], write_idx].set(
                 k_new, mode="drop")
-            lv = cv = lv.at[rows[:, None], write_idx].set(
+            cv = lv.at[rows[:, None], write_idx].set(
                 v_new, mode="drop")
+            new = (ck, cv)
         qg = q.reshape(B, T, kv, g, hd)
         scores = jnp.einsum("btkgd,bskd->bkgts", qg,
                             ck).astype(jnp.float32)
@@ -431,17 +426,18 @@ def llama_verify_step(params, cache, block, cfg: LlamaConfig
         hmid = jax.nn.silu(gate) * up
         x = x + (hmid @ p["mlp"]["w_down"].astype(cfg.dtype)
                  ).astype(x.dtype)
-        return (x, lidx + 1), (lk, lv)
+        return (x, lidx + 1, pools), new
 
-    (x, _), (new_k, new_v) = lax.scan(body, (x, jnp.int32(0)),
-                                      (params["blocks"],))
+    (x, _, pools), (new_k, new_v) = lax.scan(
+        body, (x, jnp.int32(0), pkv.pools if pkv else ()),
+        (params["blocks"],))
     x = _rmsnorm(x, params["ln_f"]["scale"], cfg.rms_eps)
     logits = (x.astype(cfg.dtype)
               @ params["lm_head"].astype(cfg.dtype)
               ).astype(jnp.float32)
-    out = dict(cache)
-    out["k"], out["v"] = new_k, new_v
-    return logits, out
+    if paged:
+        return logits, pkv.commit(pools, new_k, new_v)
+    return logits, dict(cache, k=new_k, v=new_v)
 
 
 def _scan_prefill(params, tokens, cfg, *, lengths=None):

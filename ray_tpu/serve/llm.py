@@ -142,6 +142,15 @@ def _jitted_engine_fns(prefill_fn, step_fn, paged_prefill_fn, cfg,
           twins for requests overriding SamplingParams (compiled only
           if such a request arrives)
       admit / copy_block / clear_row       — pool bookkeeping
+
+    Every program that takes the engine's cache and returns it
+    CONSUMES it (donate_argnums): the result is the same buffers
+    updated in place, the argument is dead once the call is made, and
+    the caller rebinds (`self._cache = ...`).  Only `admit` (dense
+    rows) and the read-only `save_block` / `kv_handoff_export` leave
+    their cache argument alive.  Under a mesh the returned cache is
+    pinned to the committed cache shardings, so the alias holds shard
+    for shard.
       spec_verify                          — (spec only) ONE target
           dispatch verifying a (B, k+1) draft block, KV donated
       draft_propose                        — (model draft only) the
@@ -160,7 +169,8 @@ def _jitted_engine_fns(prefill_fn, step_fn, paged_prefill_fn, cfg,
     import jax
     from jax import lax
 
-    from ray_tpu.models.decode_common import (copy_block,
+    from ray_tpu.models.decode_common import (cache_shardings,
+                                              copy_block,
                                               make_draft_propose,
                                               make_spec_verify,
                                               make_vocab_tail_mask,
@@ -169,6 +179,15 @@ def _jitted_engine_fns(prefill_fn, step_fn, paged_prefill_fn, cfg,
     tail = make_vocab_tail_mask(cfg)
     temperature = sampling.temperature
     top_k, top_p = sampling.top_k, sampling.top_p
+
+    def pinned(cache):
+        # a donated cache aliases its result only where both have one
+        # sharding: hold the result to the shardings the engine
+        # committed its cache to (partitioned_cache_init)
+        if mesh is None:
+            return cache
+        return lax.with_sharding_constraint(
+            cache, cache_shardings(cache, mesh))
 
     def prefill_sample(p, toks, lens, k):
         logits, cache = prefill_fn(p, toks, cfg, lengths=lens)
@@ -184,22 +203,26 @@ def _jitted_engine_fns(prefill_fn, step_fn, paged_prefill_fn, cfg,
             p, cache, toks, cfg, row_bt=row_bt,
             prefix_len=prefix_len, n_tail=n_tail, slot=slot)
         return sample_token(logits[None], k, temperature, tail,
-                            top_k, top_p), cache
+                            top_k, top_p), pinned(cache)
 
     def paged_prefill_raw(p, cache, toks, row_bt, prefix_len, n_tail,
                           slot):
         logits, cache = paged_prefill_fn(
             p, cache, toks, cfg, row_bt=row_bt,
             prefix_len=prefix_len, n_tail=n_tail, slot=slot)
-        return logits[None], cache
+        return logits[None], pinned(cache)
 
     def pool_step(p, cache, toks, k):
         logits, cache = step_fn(p, cache, toks, cfg)
         return sample_token(logits, k, temperature, tail, top_k,
-                            top_p), cache
+                            top_p), pinned(cache)
 
     def pool_logits(p, cache, toks):
-        return step_fn(p, cache, toks, cfg)
+        logits, cache = step_fn(p, cache, toks, cfg)
+        return logits, pinned(cache)
+
+    def fork_block(cache, src, dst):
+        return pinned(copy_block(cache, src, dst))
 
     def admit(pool, row, slot):
         out = dict(pool)
@@ -218,7 +241,7 @@ def _jitted_engine_fns(prefill_fn, step_fn, paged_prefill_fn, cfg,
         out = dict(cache)
         out["block_tables"] = cache["block_tables"].at[slot].set(0)
         out["pos"] = cache["pos"].at[slot].set(0)
-        return out
+        return pinned(out)
 
     def install_blocks(cache, blk_ids, k_stack, v_stack):
         # tiered host-RAM KV cache (serve/kv_tier.py): splice a whole
@@ -238,7 +261,7 @@ def _jitted_engine_fns(prefill_fn, step_fn, paged_prefill_fn, cfg,
             k_stack.swapaxes(0, 1))
         out["v"] = cache["v"].at[:, blk_ids].set(
             v_stack.swapaxes(0, 1))
-        return out
+        return pinned(out)
 
     def save_block(cache, blk):
         # spill companion to install_blocks: one fused program slices
@@ -281,7 +304,7 @@ def _jitted_engine_fns(prefill_fn, step_fn, paged_prefill_fn, cfg,
             row_bt)
         out["pos"] = cache["pos"].at[slot].set(pos)
         out["start"] = cache["start"].at[slot].set(0)
-        return out
+        return pinned(out)
 
     # perf observatory: the heavy programs report compiles / compiler
     # cost model / invoke walltimes to the process-wide registry under
@@ -295,9 +318,14 @@ def _jitted_engine_fns(prefill_fn, step_fn, paged_prefill_fn, cfg,
         if mesh is not None else 1
     spec_verify = draft_propose = draft_prefill = None
     if spec is not None:
-        verify = make_spec_verify(verify_fn, cfg,
-                                  temperature=temperature,
-                                  top_k=top_k, top_p=top_p)
+        verify_accept = make_spec_verify(verify_fn, cfg,
+                                         temperature=temperature,
+                                         top_k=top_k, top_p=top_p)
+
+        def verify(*args):
+            out, n_acc, cache = verify_accept(*args)
+            return out, n_acc, pinned(cache)
+
         # the target KV pool (arg 1) is donated: the verify round is
         # the engine's steady-state hot program and the old pool is
         # dead the moment the new one lands
@@ -325,15 +353,18 @@ def _jitted_engine_fns(prefill_fn, step_fn, paged_prefill_fn, cfg,
         prefill=registry.instrument(shard + "prefill",
                                     jax.jit(prefill_sample), n_dev),
         paged_prefill=registry.instrument(
-            shard + "paged_prefill", jax.jit(paged_prefill_sample),
+            shard + "paged_prefill",
+            jax.jit(paged_prefill_sample, donate_argnums=(1,)), n_dev),
+        pool_step=registry.instrument(
+            shard + "decode", jax.jit(pool_step, donate_argnums=(1,)),
             n_dev),
-        pool_step=registry.instrument(shard + "decode",
-                                      jax.jit(pool_step), n_dev),
         prefill_raw=jax.jit(prefill_raw),
-        paged_prefill_raw=jax.jit(paged_prefill_raw),
-        pool_logits=jax.jit(pool_logits),
-        admit=jax.jit(admit), copy_block=jax.jit(copy_block),
-        clear_row=jax.jit(clear_row),
+        paged_prefill_raw=jax.jit(paged_prefill_raw,
+                                  donate_argnums=(1,)),
+        pool_logits=jax.jit(pool_logits, donate_argnums=(1,)),
+        admit=jax.jit(admit),
+        copy_block=jax.jit(fork_block, donate_argnums=(0,)),
+        clear_row=jax.jit(clear_row, donate_argnums=(0,)),
         install_blocks=jax.jit(install_blocks, donate_argnums=(0,)),
         save_block=jax.jit(save_block),
         kv_handoff_export=registry.instrument(

@@ -44,6 +44,21 @@ Rules (ids as reported / suppressed):
   post-partitioning sizes, so a pool that stopped sharding trips it
   even if the traced (global) program is unchanged.
 
+* ``pool-inplace`` — a serving program that takes the paged KV pool
+  (``inplace_pool`` names the cache argument, which the spec also
+  donates) must update it where it lies: the COMPILED program aliases
+  the pool's K and V to its results (``alias_size_in_bytes``), and no
+  ``copy`` and no ``dynamic-slice`` in it has the pool's shape, nor a
+  ``copy`` one layer's.  A ``dynamic-update-slice`` whose update is one
+  layer is a layer written or stacked back: a program that writes one
+  column a row (a decode step) may hold none, its pool is read-only in
+  the layer scan and ``decode_common.PagedKV`` lands the rows after
+  it; one that writes a block of columns (a prefill, a verify block)
+  declares ``pool_layer_writes=2``, each tensor's layer written back
+  into the carried pool, and a third is the pool stacked as the scan's
+  ``ys`` beside it.  The one layer-sized ``dynamic-slice`` the gather
+  reads is allowed.
+
 Sharded specs declare ``min_devices``; on hosts with fewer devices the
 spec is skipped with an info note instead of failing (tier-1 forces 8
 virtual CPU devices via tests/conftest.py, so CI always runs them).
@@ -59,6 +74,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import re
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from ray_tpu.tools.graftcheck.core import Violation
@@ -228,6 +244,12 @@ class ProgramSpec:
     forbid_hlo_shapes: Tuple[str, ...] = ()
     #: compiled per-partition arg+temp byte ceiling
     per_chip_hbm_budget_bytes: Optional[int] = None
+    #: argnum of a paged cache ({"k", "v", ...}, also in
+    #: ``donate_argnums``) the compiled program must update in place
+    inplace_pool: Optional[int] = None
+    #: layer-sized ``dynamic-update-slice`` updates that program may
+    #: hold: 0 (a decode step) or 2 (K's and V's layer written back)
+    pool_layer_writes: int = 0
 
 
 def _check_host_transfer(jaxpr, spec) -> List[Violation]:
@@ -375,6 +397,82 @@ def _check_compiled(fn, args, spec) -> Tuple[List[Violation],
     return out, info
 
 
+#: ``%name = type[dims]{layout} opcode(operands...`` in compiled text
+_HLO_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = \w+\[([\d,]*)\][^ ]* "
+    r"([\w\-]+)\((.*)$")
+
+
+def pool_moves(hlo: str, pool: Tuple[int, ...]):
+    """(opcode, instruction, dims moved) for every instruction of a
+    compiled module's text (fused computations included) that
+    materialises the K/V pool of shape `pool`, or one layer of it,
+    beside the pool: a ``copy`` of either, a ``dynamic-slice`` of the
+    pool, a ``dynamic-update-slice`` whose UPDATE is either (a layer
+    written or stacked back).  The layer-sized ``dynamic-slice`` a
+    gather reads and row-sized updates are not moves."""
+    big = (pool, pool[1:], (1,) + pool[1:])
+    found = []
+    for line in hlo.splitlines():
+        m = _HLO_INSTRUCTION.match(line)
+        if m:
+            dims = tuple(int(d) for d in m.group(2).split(",") if d)
+            # operands print bare (``%a, %b``) or typed
+            # (``f32[2,3]{1,0} %a``): keep the names
+            found.append((m.group(1), dims, m.group(3),
+                          re.findall(r"%([\w.\-]+)", m.group(4))))
+    dims_of = {name: dims for name, dims, _op, _ops in found}
+    for name, dims, op, operands in found:
+        if op == "copy" and dims in big:
+            yield op, name, dims
+        elif op == "dynamic-slice" and dims == pool:
+            yield op, name, dims
+        elif op == "dynamic-update-slice" and len(operands) > 1 \
+                and dims_of.get(operands[1]) in big:
+            yield op, name, dims_of[operands[1]]
+
+
+def _check_pool_inplace(fn, args, spec) -> Tuple[List[Violation],
+                                                 Dict[str, Any]]:
+    """Compile with the spec's donation and hold the program to
+    "no pool-sized or layer-sized K/V buffer other than the pool"."""
+    import jax
+
+    cache = args[spec.inplace_pool]
+    pool = tuple(cache["k"].shape)
+    pool_bytes = sum(cache[n].size * cache[n].dtype.itemsize
+                     for n in ("k", "v"))
+    compiled = jax.jit(
+        fn, donate_argnums=spec.donate_argnums).lower(*args).compile()
+    out: List[Violation] = []
+    ma = compiled.memory_analysis()
+    info = {"alias_bytes": int(ma.alias_size_in_bytes),
+            "temp_bytes": int(ma.temp_size_in_bytes),
+            "pool_bytes": int(pool_bytes)}
+    if info["alias_bytes"] < pool_bytes:
+        out.append(Violation(
+            "pool-inplace",
+            f"the compiled program aliases {info['alias_bytes']} bytes "
+            f"of its arguments to its results, the K/V pool has "
+            f"{pool_bytes}: the pool is copied, not updated in place",
+            program=spec.name))
+    moves = list(pool_moves(compiled.as_text(), pool))
+    layer_writes = [m for m in moves
+                    if m[0] == "dynamic-update-slice" and m[2] != pool]
+    info["pool_layer_writes"] = len(layer_writes)
+    if len(layer_writes) <= spec.pool_layer_writes:
+        moves = [m for m in moves if m not in layer_writes]
+    for op, name, moved in moves:
+        out.append(Violation(
+            "pool-inplace",
+            f"compiled `{op}` {name} moves a {list(moved)} buffer "
+            f"(the K/V pool is {list(pool)}; "
+            f"{spec.pool_layer_writes} layers written back allowed): "
+            f"the pool, or a layer of it, is materialised beside the "
+            f"pool", program=spec.name))
+    return out, info
+
+
 def audit_program(spec: ProgramSpec
                   ) -> Tuple[List[Violation], Dict[str, Any]]:
     """Trace one program and run every rule it doesn't skip.  Returns
@@ -425,6 +523,11 @@ def audit_program(spec: ProgramSpec
         vs, compiled_info = _check_compiled(fn, args, spec)
         violations.extend(vs)
         info.update(compiled_info)
+    if spec.inplace_pool is not None \
+            and "pool-inplace" not in spec.skip_rules:
+        vs, pool_info = _check_pool_inplace(fn, args, spec)
+        violations.extend(vs)
+        info.update(pool_info)
     return violations, info
 
 

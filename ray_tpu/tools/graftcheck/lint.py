@@ -131,7 +131,7 @@ KNOWN_RULES = frozenset({
     # jaxpr auditor rules
     "host-transfer", "f64", "f32-matmul", "logits-buffer", "t0-scan",
     "donation", "collectives", "per-chip-hbm", "hbm-budget",
-    "audit-error",
+    "pool-inplace", "audit-error",
     "all",
 })
 

@@ -37,6 +37,7 @@ _PB, _PT0 = 4, 64        # prefill batch: T0 != n_layer so no aliasing
 _CE_N, _CE_D, _CE_V, _CE_VALID = 128, 64, 512, 500
 _NANO_VOCAB = 512        # padded_vocab of the nano presets
 _CHUNK_T = 32            # chunked-prefill tail bucket (< max_seq=128)
+_BUCKET_T = 64           # a one-shot paged prefill's bucket
 
 _MiB = 2 ** 20
 
@@ -232,7 +233,7 @@ def _build_gpt2_spec_verify_step():
             (params, cache, block, key))
 
 
-def _build_gpt2_chunked_prefill():
+def _build_gpt2_chunked_prefill(t_pad=_CHUNK_T, n_tail=_CHUNK_T):
     """One chunk of streaming prefill (round 14): the serve engine's
     chunked admission runs the SAME ``paged_prefill`` program once per
     chunk with ``prefix_len`` = tokens already filled, so the audited
@@ -260,13 +261,21 @@ def _build_gpt2_chunked_prefill():
     cache["block_tables"] = 1 + jnp.arange(
         _PB * per_row, dtype=jnp.int32).reshape(_PB, per_row)
     row_bt = 1 + jnp.arange(per_row, dtype=jnp.int32)
-    toks = jnp.zeros((1, _CHUNK_T), jnp.int32)
+    toks = jnp.zeros((1, t_pad), jnp.int32)
     # prefix_len=16: one already-resident block (the previous chunk);
     # n_tail == bucket (full chunk); dynamic scalars as in the engine
     return (lambda p, c, t, bt, pl, nt, s: paged_prefill(
         p, c, t, cfg, row_bt=bt, prefix_len=pl, n_tail=nt, slot=s),
         (params, cache, toks, row_bt, jnp.int32(16),
-         jnp.int32(_CHUNK_T), jnp.int32(0)))
+         jnp.int32(n_tail), jnp.int32(0)))
+
+
+def _build_gpt2_paged_prefill_bucket():
+    """The engine's one-shot paged prefill at a bucket the serving
+    cells run (Tt=64 of nano's 128), a prefix hit of one block and
+    three pad columns: the same program as a chunk, at the size where
+    the tail's K/V are most of what the pool receives."""
+    return _build_gpt2_chunked_prefill(_BUCKET_T, _BUCKET_T - 3)
 
 
 def _paged_nano_pool():
@@ -423,7 +432,10 @@ def default_programs() -> List[ProgramSpec]:
             # per-layer gathered (B, max_seq) views inside the scan; a
             # hidden dense re-materialization of the WHOLE pool per
             # layer would blow straight through it
-            hbm_budget_bytes=6 * _MiB),
+            hbm_budget_bytes=6 * _MiB,
+            # the engine donates the pool to its decode program and
+            # the step writes 2 * L * B rows into it where it lies
+            donate_argnums=(1,), inplace_pool=1),
         ProgramSpec(
             name="gpt2_sharded_decode_step",
             build=_build_gpt2_sharded_decode_step,
@@ -449,7 +461,8 @@ def default_programs() -> List[ProgramSpec]:
             build=_build_gpt2_spec_verify_step,
             forbid_logits=(_PB * 128, _NANO_VOCAB),  # B * max_seq rows
             allow_f32_matmul=True,
-            donate_argnums=(1,),
+            # a verify block writes each tensor's layer back
+            donate_argnums=(1,), inplace_pool=1, pool_layer_writes=2,
             # same pool sizing as the paged decode step plus the tiny
             # (B, k+1, V) verify logits and accept-fold temps
             hbm_budget_bytes=6 * _MiB),
@@ -467,7 +480,19 @@ def default_programs() -> List[ProgramSpec]:
             # pool (same sizing as the paged decode step) + (Tt, ...)
             # chunk temps; a dense pool re-materialization per chunk
             # blows through this
-            hbm_budget_bytes=6 * _MiB),
+            hbm_budget_bytes=6 * _MiB,
+            # a chunk writes each tensor's layer back, like a prefill
+            donate_argnums=(1,), inplace_pool=1, pool_layer_writes=2),
+        ProgramSpec(
+            name="gpt2_paged_prefill_bucket",
+            build=_build_gpt2_paged_prefill_bucket,
+            forbid_logits=(128, _NANO_VOCAB),        # max_seq rows
+            forbid_scan_lengths=(128,),
+            allow_f32_matmul=True,
+            hbm_budget_bytes=6 * _MiB,
+            # K's and V's layer written back into the carried pool,
+            # and no third: the pool is never the scan's stacked ys
+            donate_argnums=(1,), inplace_pool=1, pool_layer_writes=2),
         ProgramSpec(
             name="gpt2_kv_handoff_export",
             build=_build_gpt2_kv_handoff_export,

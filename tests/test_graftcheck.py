@@ -417,6 +417,95 @@ def test_peak_estimate_counts_live_buffers():
     assert info["peak_hbm_bytes"] >= 2 * 2**20
 
 
+@pytest.mark.parametrize("name", ["gpt2_paged_decode_step",
+                                  "gpt2_chunked_prefill",
+                                  "gpt2_paged_prefill_bucket",
+                                  "gpt2_spec_verify_step"])
+def test_paged_programs_update_the_pool_in_place(name):
+    """The engine's decode step, paged prefill (a chunk, and a bucket
+    the serving cells run) and verify take the KV pool donated and
+    update it where it lies: compiled, K and V alias their results, no
+    copy / dynamic-slice of the pool and no copy of a layer is left,
+    and a layer is written back exactly as often as the program's
+    route says: never by a decode step (its pool is read-only in the
+    layer scan), once a tensor by a prefill or a verify block (into
+    the carried pool, with no stacked `ys` beside it)."""
+    from ray_tpu.tools.graftcheck.programs import default_programs
+
+    spec = next(s for s in default_programs() if s.name == name)
+    assert spec.donate_argnums == (1,) and spec.inplace_pool == 1
+    assert spec.pool_layer_writes == (
+        0 if name == "gpt2_paged_decode_step" else 2)
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")
+        vs, info = audit_program(spec)
+    assert not _rules(vs), [v.message for v in vs]
+    assert info["alias_bytes"] >= info["pool_bytes"] > 0
+    assert info["pool_layer_writes"] == spec.pool_layer_writes
+
+
+def _stacked_back(fn):
+    """`fn` with its result's K pool handed back as a layer scan's
+    stacked `ys` (every layer sliced out, written, stacked)."""
+    def through_the_scan(p, c, *rest):
+        logits, out = fn(p, c, *rest)
+
+        def body(carry, lidx):
+            lk = jax.lax.dynamic_index_in_dim(out["k"], lidx, 0,
+                                              keepdims=False)
+            return carry, lk.at[0, 0].add(1.0)
+
+        _, ks = jax.lax.scan(body, 0, jnp.arange(out["k"].shape[0]))
+        return logits, dict(out, k=ks)
+    return through_the_scan
+
+
+@pytest.mark.parametrize("name,allowed", [
+    ("gpt2_paged_decode_step", 0), ("gpt2_paged_prefill_bucket", 2)])
+def test_planted_pool_through_the_scan_detected(name, allowed):
+    """The regression the rule exists for, on both routes: a program
+    that hands the pool back as a layer scan's stacked `ys` moves a
+    layer-sized buffer per layer: one more than a prefill's two
+    write-backs, one where a decode step has none."""
+    from ray_tpu.tools.graftcheck.programs import default_programs
+
+    spec = next(s for s in default_programs() if s.name == name)
+    fn, args = spec.build()
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")
+        vs, info = audit_program(ProgramSpec(
+            name="planted", build=lambda: (_stacked_back(fn), args),
+            donate_argnums=(1,), inplace_pool=1,
+            pool_layer_writes=allowed, allow_f32_matmul=True))
+    assert "pool-inplace" in _rules(vs)
+    assert info["pool_layer_writes"] > allowed
+    assert any("dynamic-update-slice" in v.message for v in vs)
+
+
+def test_planted_dropped_pool_loses_the_alias():
+    """A program that never writes its donated pool's buffers drops
+    the alias, and the rule says so."""
+    from ray_tpu.tools.graftcheck.programs import default_programs
+
+    spec = next(s for s in default_programs()
+                if s.name == "gpt2_paged_decode_step")
+    fn, args = spec.build()
+
+    def pool_dropped(p, c, t):
+        logits, out = fn(p, c, t)
+        return logits, {k: v for k, v in out.items()
+                        if k not in ("k", "v")}
+
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")   # cpu donation warning
+        vs, info = audit_program(
+            ProgramSpec(name="planted",
+                        build=lambda: (pool_dropped, args),
+                        inplace_pool=1, allow_f32_matmul=True))
+    assert "pool-inplace" in _rules(vs)
+    assert info["alias_bytes"] < info["pool_bytes"]
+
+
 def test_skip_rules_waives_a_jaxpr_rule():
     def fn(x):
         jax.debug.print("leak {}", x[0])
