@@ -10,6 +10,12 @@ family (``program.family``); what depends on the architecture is in
 ``benchmark/reference/<name>.py``.  A later PR adds a cell, a mix, a
 metric or a family by adding files and entries; nothing here lists
 them.
+
+What a configuration brings (its file, its family and reference, the
+family's rehearsal file, its traffic files) is looked for under the
+tree the cell was loaded from, ``load_cell(..., root=)``: the checkout
+for the command, a tree of fixture files for a test.  What the harness
+is (drivers, metric readers) is this package's own.
 """
 
 from __future__ import annotations
@@ -23,6 +29,12 @@ from typing import Any, Callable, Dict, List
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
+
+
+def tree(root: str, *parts: str) -> str:
+    """``<root>/benchmark/<parts>``: where a tree keeps what this
+    package keeps under its own directory."""
+    return os.path.join(root, os.path.basename(HERE), *parts)
 
 
 def load_json(*parts: str) -> Any:
@@ -45,16 +57,26 @@ class Cell:
     #: the metric entries of BENCHMARK.json this cell reports
     end_to_end: List[Dict[str, Any]]
     per_layer: List[Dict[str, Any]]
+    #: the tree its files were found under
+    root: str = ROOT
 
     @property
     def family(self):
         """``benchmark/families/<program.family>.py``."""
-        return load_family(self.config["program"]["family"])
+        return load_family(self.config["program"]["family"], self.root)
 
     @property
     def reference(self):
         """The family's plain reference, ``benchmark/reference/*.py``."""
-        return _load_module("reference", self.family.REFERENCE)
+        return _load_module("reference", self.family.REFERENCE, self.root)
+
+    @property
+    def reference_kwargs(self) -> Dict[str, Any]:
+        """What the reference needs beyond the parameter tree, as the
+        family reads it from the configuration; nothing where the
+        family states none."""
+        stated = getattr(self.family, "reference_kwargs", None)
+        return dict(stated(self.config)) if stated else {}
 
 
 def _reported(metrics: List[Dict[str, Any]], cell: str
@@ -78,14 +100,20 @@ def load_cell(name: str, bench: Dict[str, Any] | None = None,
         name=name, chips=int(w["chips"]), config_name=w["config"],
         traffic_name=w["traffic"],
         config=load_json(root, cfg_entry["file"]),
-        traffic=load_json(HERE, "traffic", w["traffic"] + ".json"),
+        traffic=load_json(tree(root, "traffic", w["traffic"] + ".json")),
         end_to_end=_reported(bench["end_to_end"], name),
-        per_layer=_reported(bench["per_layer"], name))
+        per_layer=_reported(bench["per_layer"], name), root=root)
+
+
+def _load_module(kind: str, name: str, root: str = ROOT):
+    """``<root>/benchmark/<kind>/<name>.py``, loaded once (the cache
+    below sees `root` whether or not the caller gave it)."""
+    return _load(kind, name, root)
 
 
 @functools.lru_cache(maxsize=None)
-def _load_module(kind: str, name: str):
-    path = os.path.join(HERE, kind, name + ".py")
+def _load(kind: str, name: str, root: str):
+    path = tree(root, kind, name + ".py")
     if not os.path.isfile(path):
         raise SystemExit(f"benchmark: no {kind} file {path}")
     spec = importlib.util.spec_from_file_location(
@@ -100,10 +128,10 @@ def load_driver(name: str):
     return _load_module("drivers", name)
 
 
-def load_family(name: str):
-    """The module ``benchmark/families/<name>.py`` (its docstring lists
-    what a family file holds)."""
-    return _load_module("families", name)
+def load_family(name: str, root: str = ROOT):
+    """The module ``benchmark/families/<name>.py`` (the docstring of
+    ``families/gpt2.py`` lists what a family file holds)."""
+    return _load_module("families", name, root)
 
 
 def load_reader(metric: str) -> Callable[[Any], Any]:

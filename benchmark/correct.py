@@ -72,15 +72,17 @@ def check_greedy(ref_logits: np.ndarray, engine_tokens: np.ndarray,
 
 def reference_generated_logits(reference, params, out_tokens: np.ndarray,
                                prompt_len: int, *, vocab_size: int,
-                               max_seq: int) -> np.ndarray:
+                               max_seq: int, **stated) -> np.ndarray:
     """The reference's logits for each generated position of one
     answered request (prompt + continuation), in one forward pass.  The
     sequence is right-padded to max_seq: under the causal mask padding
-    cannot reach an earlier position, and one shape compiles once."""
+    cannot reach an earlier position, and one shape compiles once.
+    `stated` is what the family reads from the configuration for its
+    reference (``Cell.reference_kwargs``)."""
     n = len(out_tokens)
     padded = np.zeros((1, max_seq), np.int32)
     padded[0, :n] = out_tokens
-    lg = reference.logits(params, padded, vocab_size=vocab_size)
+    lg = reference.logits(params, padded, vocab_size=vocab_size, **stated)
     # logits at position i predict token i+1
     return np.asarray(lg[0, prompt_len - 1:n - 1])
 

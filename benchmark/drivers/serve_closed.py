@@ -16,6 +16,18 @@ Set-up runs one request through each prefill shape the clients will
 use, and a repeat of the first (a prefix hit), to the end of their
 answers: a request cannot be cut short, so this costs one full answer's
 decode time (PERF.md, Open questions: an output length per request).
+
+``first_send_spread_s`` in the traffic file staggers the clients: client
+c (in the file's order, the same for every ``--seed``) sends its first
+prompt ``c / clients * first_send_spread_s`` after the load starts, and
+the measured window opens when the last client has sent.  The ramp is
+set-up (``setup_s`` holds it, ``[setup_split]`` names it ``ramp_s``).
+Clients that start together stay together: every wave ends at once, the
+next wave's prefills run back to back, and the window holds a whole
+number of waves and a piece, so its rate hears a shorter decode step
+only through that piece.  Spread over one wave's length, a prefill
+falls between decode steps all the time and the rate is the engine's.
+Absent or 0, all clients send at once, as the driver always did.
 """
 
 from __future__ import annotations
@@ -53,6 +65,75 @@ def warmup_requests(gen: TrafficGenerator, clients, eng):
     return reqs, labels
 
 
+def first_send_offsets(traffic) -> list:
+    """Seconds after the load starts at which each client first sends:
+    ``c / clients * first_send_spread_s`` for client c, all 0 without
+    the key.  From the traffic file alone, so the same for every seed."""
+    n = int(traffic["clients"])
+    spread = float(traffic.get("first_send_spread_s") or 0.0)
+    return [c * spread / n for c in range(n)]
+
+
+async def closed_window(engine, clients, offsets, seconds: float,
+                        drain_s: float, trace=None):
+    """Keep `clients` sending from now on, client c first after
+    ``offsets[c]``, for `seconds` from when the last of them has first
+    sent (at once where every offset is 0), and on to the engine's next
+    emission, at most `drain_s` longer.  `trace` is called with the
+    window's start and gives the tracer's coroutine.  Leaves the engine
+    running: the caller reads its records, then shuts it down and calls
+    ``finish()`` of what is returned."""
+    sender = Sender(engine)
+    load_t0 = time.perf_counter()
+    closed = asyncio.Event()
+    opened = asyncio.get_running_loop().create_future()
+    last = len(clients) - 1              # offsets rise with c
+
+    async def client(c, row) -> bool:
+        """True if it ran out of prompts before the engine was seen
+        alive past the cut (turns_per_client is then too small)."""
+        if offsets[c] > 0:
+            await asyncio.sleep(max(
+                0.0, load_t0 + offsets[c] - time.perf_counter()))
+            if c == last:
+                opened.set_result(time.perf_counter())
+        for req in row:
+            if closed.is_set():
+                return False
+            await sender.send(req)
+        return not closed.is_set()
+
+    tasks = [asyncio.ensure_future(client(c, row))
+             for c, row in enumerate(clients)]
+    t0 = await opened if offsets[last] > 0 else load_t0
+    t1 = t0 + seconds
+    tracer = asyncio.ensure_future(trace(t0)) if trace else None
+    await asyncio.wait(tasks, timeout=max(0.0, t1 - time.perf_counter()))
+    # run on to the engine's next emission: alive at the cut
+    give_up = t1 + drain_s
+    while (last_emission(engine) < t1
+           and time.perf_counter() < give_up):
+        await asyncio.sleep(0.02)
+    alive = last_emission(engine) >= t1
+    closed.set()
+    t_end = time.perf_counter()
+    if tracer is not None:
+        await tracer
+
+    async def finish() -> int:
+        """Cancel the clients still waiting for an answer; how many
+        ran out of prompts."""
+        done = [t for t in tasks if t.done()]
+        pending = [t for t in tasks if not t.done()]
+        for t in pending:
+            t.cancel()
+        await asyncio.gather(*pending, return_exceptions=True)
+        return sum(t.result() for t in done)
+
+    return types.SimpleNamespace(sender=sender, load_t0=load_t0, t0=t0,
+                                 t_end=t_end, alive=alive, finish=finish)
+
+
 def run(ctx: Ctx):
     import jax
 
@@ -68,9 +149,12 @@ def run(ctx: Ctx):
     flat = [r for row in clients for r in row]
     warm, labels = warmup_requests(gen, clients, eng)
     split["engine_s"] = time.perf_counter() - t_phase
+    offsets = first_send_offsets(traffic)
     say("traffic", clients=len(clients), turns=len(clients[0]),
         prompt_min=min(len(r.prompt) for r in flat),
-        prompt_max=max(len(r.prompt) for r in flat), warmup=labels)
+        prompt_max=max(len(r.prompt) for r in flat), warmup=labels,
+        **({"first_send_spread_s": traffic["first_send_spread_s"]}
+           if any(offsets) else {}))
     trace_at = trace_window(ctx)
     out = types.SimpleNamespace(trace=None)
     prof = Profiler(ctx)
@@ -82,51 +166,23 @@ def run(ctx: Ctx):
             ctx, engine, eng, warm, labels,
             [(labels[-2], False), ("repeat_hit", True)], watch, split)
 
-        sender = Sender(engine)
         compiles_before = watch.compiles
-        t0 = time.perf_counter()
-        setup_s = t0 - ctx.t_start
-        t1 = t0 + ctx.seconds
-
-        closed = asyncio.Event()
-
-        async def client(row) -> bool:
-            """True if it ran out of prompts before the engine was seen
-            alive past the cut (turns_per_client is then too small)."""
-            for req in row:
-                if closed.is_set():
-                    return False
-                await sender.send(req)
-            return not closed.is_set()
-
-        tasks = [asyncio.ensure_future(client(row)) for row in clients]
-        tracer = asyncio.ensure_future(
-            trace_between(prof, out, t0, trace_at)) if trace_at else None
-        await asyncio.wait(tasks, timeout=ctx.seconds)
-        # run on to the engine's next emission: alive at the cut
-        give_up = t1 + float(traffic["drain_s"])
-        while (last_emission(engine) < t1
-               and time.perf_counter() < give_up):
-            await asyncio.sleep(0.02)
-        alive = last_emission(engine) >= t1
-        closed.set()
-        t_end = time.perf_counter()
+        w = await closed_window(
+            engine, clients, offsets, ctx.seconds,
+            float(traffic["drain_s"]),
+            (lambda t0: trace_between(prof, out, t0, trace_at))
+            if trace_at else None)
+        if w.t0 > w.load_t0:
+            split["ramp_s"] = w.t0 - w.load_t0
         compiles_in_window = watch.compiles - compiles_before
-        if tracer is not None:
-            await tracer
-        rows = sender.rows(flat, eng.new_tokens)
+        rows = w.sender.rows(flat, eng.new_tokens)
         stamps = all_token_stamps(engine)
         engine.shutdown_engine()
-        done = [t for t in tasks if t.done()]
-        pending = [t for t in tasks if not t.done()]
-        for t in pending:
-            t.cancel()
-        await asyncio.gather(*pending, return_exceptions=True)
-        exhausted = sum(t.result() for t in done)
+        exhausted = await w.finish()
         return types.SimpleNamespace(
-            setup_s=setup_s, t0=t0, t_end=t_end, rows=rows, checks=checks,
-            compiles_in_window=compiles_in_window, stamps=stamps,
-            exhausted=exhausted, alive=alive)
+            setup_s=w.t0 - ctx.t_start, t0=w.t0, t_end=w.t_end, rows=rows,
+            checks=checks, compiles_in_window=compiles_in_window,
+            stamps=stamps, exhausted=exhausted, alive=w.alive)
 
     r = asyncio.run(main())
     if trace_at:

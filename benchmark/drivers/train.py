@@ -78,7 +78,7 @@ def run(ctx: Ctx):
     devices = jax.devices()[:ctx.cell.chips]
     family, reference = ctx.cell.family, ctx.cell.reference
     model = family.program(config, program_overrides(ctx.cell))
-    loss_fn, vocab = model.loss, int(config["vocab_size"])
+    loss_fn, vocab = model.loss, int(family.sizes(config)["vocab_size"])
     B, T = int(traffic["batch"]), int(traffic["seq"])
     cycle = int(traffic["batch_cycle"])
     tx = _optimizer(traffic["optimizer"])
@@ -120,7 +120,8 @@ def run(ctx: Ctx):
         few = {"tokens": batches[0]["tokens"][:rows]}
         loss_sys = float(jax.jit(loss_fn)(params, few))
         loss_ref = float(reference.loss(params, few["tokens"],
-                                        vocab_size=vocab))
+                                        vocab_size=vocab,
+                                        **ctx.cell.reference_kwargs))
         now = time.perf_counter()
         split["reference_s"], t_phase = now - t_phase, now
 

@@ -3,15 +3,20 @@ in one file.  A configuration names its family under
 ``program.family``; the drivers, the readers and the rehearsal tools
 find this file by that name (``cells.load_family``) and ask it for
 everything that depends on the architecture.  A later PR adds a family
-(llama, a mixture of experts) by adding ``families/<name>.py`` with
-these names and ``reference/<REFERENCE>.py`` beside it, and edits no
-file that is there:
+(a mixture of experts, another attention) by adding
+``families/<name>.py`` with these names, ``reference/<REFERENCE>.py``
+and ``rehearsal/<name>.json`` beside it, and edits no file that is
+there (``tests/benchmark/data/second_family`` is one, whole):
 
 ``REFERENCE``
     name of the plain reference, ``benchmark/reference/<name>.py``, with
-    ``logits(params, tokens, vocab_size=)`` and ``loss(...)``.
+    ``logits(params, tokens, vocab_size=, **kw)`` and ``loss(params,
+    tokens, vocab_size=, **kw)``; ``kw`` is ``reference_kwargs`` below.
 ``sizes(config)``
-    the published sizes as the program's configuration overrides.
+    the published sizes, under whatever keys the source's
+    ``config.json`` spells them with, as the program's configuration
+    overrides.  The harness reads ``d_model``, ``n_head`` and
+    ``vocab_size`` out of it and hands the rest to ``program``.
 ``program(config, overrides)``
     the program's own model: ``cfg``, ``init(key)``, ``loss(params,
     batch)``, ``logical_axes()``, imported from ``ray_tpu.models`` here
@@ -20,10 +25,28 @@ file that is there:
 ``kv_bytes_per_token``, ``attention_shape``
     the yardstick's arithmetic from the published sizes alone: nothing
     here asks the program or the compiler what it did.  Recomputed
-    operations (remat) are not counted.
+    operations (remat) are not counted.  ``attention_shape`` gives
+    ``n_head``, ``head_dim`` (the configuration's own where it states
+    one, whatever ``d_model / n_head`` is), ``n_layer``, ``d_model``
+    and, where K and V have fewer heads than Q, ``n_kv_head``: the K/V
+    pool's shape follows from it.
 ``aot_serve_programs``
     for ``benchmark/aot_fit.py`` only: the engine's decode and prefill
     programs over abstract arguments.
+
+A family may also state, and this one states neither:
+
+``reference_kwargs(config)``
+    what its reference needs that the parameter tree does not show (a
+    rotary base, a norm's epsilon, the experts a token takes), read
+    from the configuration.  GPT-2's one such number, the LayerNorm
+    epsilon, is the reference's default.
+``logit_tie_tol(config)``
+    the near-tie tolerance its served answers are held to
+    (``serving.tie_tol``), with its reason and the readings behind it
+    written beside it.  Without it ``correct.logit_tie_tol(n_layer)``
+    stands, which was derived for dense pre-norm blocks in bf16: 0.03
+    for this family's twelve layers, 0.06 for the XL's 48.
 """
 
 from __future__ import annotations

@@ -216,14 +216,16 @@ def _registry_maps() -> Dict[str, ScopeMap]:
 
 
 def _pool_dims(run) -> Optional[Tuple[int, ...]]:
-    """(L, blocks, block, H, hd) of a serving run's K/V pool."""
+    """(L, blocks, block, H, hd) of a serving run's K/V pool; H is the
+    number of K/V heads, which under grouped-query attention the family
+    states apart (``n_kv_head``) from the query heads'."""
     eng = getattr(run, "engine", None)
     if eng is None or not hasattr(eng, "n_blocks"):
         return None
     cell = run.ctx.cell
     shape = cell.family.attention_shape(cell.config)
-    return (shape["n_layer"], eng.n_blocks, eng.block, shape["n_head"],
-            shape["head_dim"])
+    return (shape["n_layer"], eng.n_blocks, eng.block,
+            shape.get("n_kv_head", shape["n_head"]), shape["head_dim"])
 
 
 def _cached(run, key: str, make):

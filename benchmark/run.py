@@ -91,7 +91,8 @@ def run_cell(cell, args: argparse.Namespace, device: Dict[str, Any],
     from ray_tpu._private.compile_cache import enable_compile_cache
 
     # the program's own fixed path: JAX_COMPILATION_CACHE_DIR if set,
-    # else .jax_cache/ in this checkout; full tracebacks off
+    # else .jax_cache/ in this checkout; no frames in locations and the
+    # names in the cache's key, so the named scopes reach the trace
     cache_dir = enable_compile_cache()
     harness.say("run", workload=cell.name, seed=args.seed,
                 seconds=args.seconds, trace=args.trace, device=device,

@@ -146,6 +146,17 @@ def last_emission(engine) -> float:
                 if r["token_ts"]), default=float("-inf"))
 
 
+def tie_tol(cell, n_layer: int) -> float:
+    """The near-tie tolerance an answer is held to: the family's own
+    where it states one (``logit_tie_tol(config)``, with its reason
+    beside it: a router over many near-flat logits does not round as a
+    dense block does), else the one derived for dense pre-norm blocks
+    in bf16, which grows with the depth the engine runs."""
+    stated = getattr(cell.family, "logit_tie_tol", None)
+    return float(stated(cell.config)) if stated \
+        else correct.logit_tie_tol(n_layer)
+
+
 def check_answer(ctx: Ctx, engine, label: str, req: Request, out,
                  hit_blocks: int, want_hit: bool, new_tokens: int
                  ) -> Dict[str, Any]:
@@ -156,9 +167,8 @@ def check_answer(ctx: Ctx, engine, label: str, req: Request, out,
         return {"ok": False, "request": label, "reason": "not answered"}
     lg = correct.reference_generated_logits(
         ctx.cell.reference, engine.params, out, n, vocab_size=cfg.vocab_size,
-        max_seq=cfg.max_seq)
-    res = correct.check_greedy(lg, out[n:],
-                               correct.logit_tie_tol(cfg.n_layer))
+        max_seq=cfg.max_seq, **ctx.cell.reference_kwargs)
+    res = correct.check_greedy(lg, out[n:], tie_tol(ctx.cell, cfg.n_layer))
     res.update(request=label, prompt_len=n, hit_blocks=hit_blocks)
     res["ok"] = bool(res["ok"] and (hit_blocks > 0) == want_hit)
     return res

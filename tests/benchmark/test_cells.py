@@ -18,19 +18,42 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 
 
+def head_dim(cell) -> int:
+    """The configuration's own head dimension: its ``head_dim`` where
+    it has the key, else what its family's sizes give."""
+    sizes = cell.family.sizes(cell.config)
+    return cell.config.get("head_dim",
+                           sizes["d_model"] // sizes["n_head"])
+
+
 @pytest.mark.parametrize("name", CELLS)
 def test_every_cell_finds_its_files_by_name(name):
     cell = cells.load_cell(name, BENCH)
     assert name == f"{cell.config_name}.{cell.traffic_name}"
     assert cell.config["name"] == cell.config_name
-    assert cell.config["reduced"] == []
+    # what was cut from the source is said twice and agrees: the keys
+    # in BENCHMARK.json (names, as the contract has them) and in the
+    # file, which also keeps what the source had and the deployment
+    # the cut stands for.  Nothing cut is as good: GPT-2's are whole.
+    entry = next(c for c in BENCH["configs"]
+                 if c["name"] == cell.config_name)
+    reduced = cell.config["reduced"]
+    assert reduced == entry["reduced"] and len(reduced) <= 16
+    for key in reduced:
+        assert NAME.match(key), key
+        assert cell.config["reduced_from"][key] != cell.config[key]
+    if reduced:
+        assert cell.config["deployment"].strip()
     driver = cells.load_driver(cell.traffic["driver"])
     assert callable(driver.run)
     sizes = cell.family.sizes(cell.config)
-    assert sizes["d_model"] % sizes["n_head"] == 0
-    assert sizes["d_model"] // sizes["n_head"] == 64
+    assert sizes["d_model"] > 0 and sizes["n_head"] > 0
+    assert cell.family.attention_shape(cell.config)["head_dim"] \
+        == head_dim(cell) > 0
 
 
+#: what a family file must have; ``logit_tie_tol`` and
+#: ``reference_kwargs`` it may have (``benchmark/families/gpt2.py``)
 FAMILY_NAMES = ("REFERENCE", "sizes", "program", "param_count",
                 "train_flops_per_token", "decode_step_bytes",
                 "kv_bytes_per_token", "attention_shape",
@@ -50,7 +73,12 @@ def test_every_configuration_finds_its_family_and_reference(config):
         assert hasattr(cell.family, attr), attr
     assert callable(cell.reference.logits) and callable(
         cell.reference.loss)
-    assert cell.family.attention_shape(cell.config)["head_dim"] == 64
+    shape = cell.family.attention_shape(cell.config)
+    assert shape["head_dim"] == head_dim(cell) > 0
+    assert 0 < shape.get("n_kv_head", shape["n_head"]) <= shape["n_head"]
+    assert cell.family.param_count(cell.config) > 0
+    assert cell.family.kv_bytes_per_token(cell.config) > 0
+    assert isinstance(cell.reference_kwargs, dict)
 
 
 def test_no_driver_or_reader_names_a_family():
@@ -89,7 +117,10 @@ def test_the_rehearsal_lays_tiny_files_over_the_cell(name):
 
     cell, tiny = cells.load_cell(name, BENCH), rehearse.tiny_cell(name)
     assert tiny.name == cell.name and tiny.chips == cell.chips
-    assert tiny.config["n_embd"] == 64 < cell.config["n_embd"]
+    family = cell.family
+    assert family.sizes(tiny.config)["d_model"] \
+        < family.sizes(cell.config)["d_model"]
+    assert family.param_count(tiny.config) < 1_000_000
     assert tiny.config["program"]["family"] == \
         cell.config["program"]["family"]
     assert tiny.traffic["driver"] == cell.traffic["driver"]
