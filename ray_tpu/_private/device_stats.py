@@ -101,6 +101,8 @@ STATIC_PROGRAM_MAP: Dict[str, str] = {
     "llama_prefill_ragged": "serve.prefill",
     "gpt2_decode_step": "serve.decode",
     "gpt2_paged_decode_step": "serve.decode",
+    "jamba_paged_decode_step": "serve.decode",
+    "jamba_paged_prefill_bucket": "serve.paged_prefill",
     "gpt2_sharded_decode_step": "serve.sharded_decode",
     "gpt2_spec_verify_step": "serve.spec_verify",
     # chunked streaming prefill reuses the paged_prefill program (one

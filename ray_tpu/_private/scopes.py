@@ -37,6 +37,11 @@ LM_HEAD_CE = "lm_head_ce"    # training: tied logits + cross-entropy
 LM_HEAD = "lm_head"          # decoding: final logits
 KV_POOL = "kv_pool"          # reads and writes of the K/V cache or pool
 SAMPLE = "sample"            # decode_common.sample_token
+#: a Mamba mixer's projections, convolution, scan and gate (models/jamba.py)
+SSM = "ssm"
+#: reads and writes of the recurrent state (convolution window and SSM
+#: state per sequence) and of its snapshot pool: ``kv_pool``'s twin
+SSM_STATE = "ssm_state"
 #: the decode programs' scan over layers: what no inner scope claims is
 #: the scan's own plumbing (slicing the stacked weights, stacking the
 #: per-layer K/V it returns)
@@ -46,8 +51,8 @@ LOSS_AND_GRAD = "loss_and_grad"
 OPTIMIZER = "optimizer"
 
 DEVICE_SCOPES = frozenset((EMBED, ATTN, MLP, LN, LM_HEAD_CE, LM_HEAD,
-                           KV_POOL, SAMPLE, LAYER_SCAN, LOSS_AND_GRAD,
-                           OPTIMIZER))
+                           KV_POOL, SAMPLE, SSM, SSM_STATE, LAYER_SCAN,
+                           LOSS_AND_GRAD, OPTIMIZER))
 
 # -- Pallas kernel names (ops/flash_attention.py ``pallas_call(name=)``) -----
 FLASH_FWD, FLASH_DQ, FLASH_DKV = "flash_fwd", "flash_dq", "flash_dkv"

@@ -11,6 +11,14 @@ from ray_tpu.models.gpt2 import (GPT2Config, gpt2_config, gpt2_forward,
                                  gpt2_param_count)
 from ray_tpu.models.gpt2_decode import (decode_step, generate,
                                         init_cache, prefill)
+from ray_tpu.models.jamba import (JambaConfig, jamba_config,
+                                  jamba_forward, jamba_init,
+                                  jamba_logical_axes, jamba_loss,
+                                  jamba_param_count)
+from ray_tpu.models.jamba_decode import (jamba_decode_step,
+                                         jamba_generate,
+                                         jamba_init_cache,
+                                         jamba_prefill)
 from ray_tpu.models.llama import (LlamaConfig, llama_config,
                                   llama_forward, llama_init,
                                   llama_logical_axes, llama_loss,
@@ -44,4 +52,8 @@ __all__ = [
     "llama_loss", "llama_logical_axes", "llama_param_count",
     "llama_init_cache", "llama_decode_step", "llama_generate",
     "llama_prefill",
+    "JambaConfig", "jamba_config", "jamba_init", "jamba_forward",
+    "jamba_loss", "jamba_logical_axes", "jamba_param_count",
+    "jamba_init_cache", "jamba_decode_step", "jamba_generate",
+    "jamba_prefill",
 ]

@@ -41,6 +41,13 @@ bit-identical between layouts — the dense path stays the parity oracle
 (same pattern as prefill_impl="scan").  Host-side block allocation /
 refcounting / prefix hashing lives in ray_tpu/serve/kv_pager.py.
 
+A second kind of state (models/jamba_decode.py): a family with recurrent
+layers keeps, beside K/V and per SEQUENCE, not per token, a state per
+such layer (``conv``, ``ssm``) and in the paged layout a snapshot pool of
+it (``snap_conv``, ``snap_ssm``).  Its paged prefill takes one more
+argument, ``state`` int32 (3,) = [source, snapshot entry, snapshot
+boundary]; the constants below are its vocabulary, and the engine's.
+
 A cache handed to a jitted ENGINE program (serve/llm.py) is consumed:
 those programs donate it, the result is the same buffers updated, and
 the caller rebinds.  The functions here are pure; donation is the
@@ -57,6 +64,13 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu._private import scopes
+
+
+#: a recurrent family's paged prefill, ``state[0]``: where the slot's
+#: recurrent state starts (a value >= 0 is a snapshot entry)
+STATE_FROM_ZERO, STATE_FROM_SLOT = -1, -2
+#: ``state[1]``: this prefill leaves no snapshot
+NO_SNAPSHOT = -1
 
 
 @dataclasses.dataclass(frozen=True)

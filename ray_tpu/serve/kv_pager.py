@@ -36,12 +36,90 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 from ray_tpu.serve.kv_tier import HostKVTier
 from ray_tpu.serve.kvscope import KVScope
 
-__all__ = ["BlockPager"]
+__all__ = ["BlockPager", "StateSnapshots"]
 
 #: journal events tag evicted/re-registered keys by their first few
 #: tokens (enough to eyeball which prefix churned) plus the full
 #: length — full keys would bloat the bounded flightrec ring
 _KEY_PREFIX_TOKENS = 8
+
+
+class StateSnapshots:
+    """Host-side index of a recurrent family's snapshot pool: which
+    entry of the device pool holds the recurrent state after which
+    prompt prefix.
+
+    A K/V block holds the keys and values OF its tokens, so a resident
+    block can be skipped by anyone whose prompt starts with them.  A
+    recurrent layer's past is one state per sequence: to skip the first
+    ``i`` blocks of a prompt the engine needs the state after EXACTLY
+    those ``i * block_size`` tokens.  Each prefill leaves one such state
+    behind, at its deepest block boundary, in entry ``reserve(key)`` of
+    the device pool, keyed as the pager keys that boundary's block: by
+    the exact token tuple.  `deepest` answers an admission's question:
+    of the blocks `match_prefix` found, how many can really be skipped.
+
+    `entries` is fixed (one a slot); the least recently used goes when
+    all are held, and an entry goes with its block when the pager evicts
+    that (`BlockPager.set_snapshots` wires `drop` to eviction).  Nothing
+    here touches device memory."""
+
+    def __init__(self, entries: int):
+        self.entries = int(entries)
+        #: boundary key -> entry, insertion order == LRU order
+        self._held: "collections.OrderedDict[Tuple[int, ...], int]" = \
+            collections.OrderedDict()
+        self._free: List[int] = list(range(self.entries - 1, -1, -1))
+        self.hits = 0          # admissions that started from a snapshot
+        self.misses = 0        # blocks matched, no state for any of them
+        self.evictions = 0     # entries dropped (LRU, or with their block)
+
+    def deepest(self, tokens: Tuple[int, ...], blocks: int,
+                block_size: int) -> int:
+        """The largest ``i <= blocks`` whose boundary ``tokens[:i *
+        block_size]`` has a snapshot (`entry_of` says where), 0 if none
+        has.  A hit is touched; `blocks > 0` without one counts as a
+        miss."""
+        for i in range(blocks, 0, -1):
+            key = tokens[:i * block_size]
+            if key in self._held:
+                self._held.move_to_end(key)
+                self.hits += 1
+                return i
+        if blocks:
+            self.misses += 1
+        return 0
+
+    def entry_of(self, key: Tuple[int, ...]) -> Optional[int]:
+        """The entry that holds the state after `key`, if any."""
+        return self._held.get(key)
+
+    def reserve(self, key: Tuple[int, ...]) -> int:
+        """The entry the state after `key` is to be written into: the
+        one it has, a free one, or the least recently used."""
+        entry = self._held.pop(key, None)
+        if entry is None:
+            if not self._free:
+                _, lru = self._held.popitem(last=False)
+                self._free.append(lru)
+                self.evictions += 1
+            entry = self._free.pop()
+        self._held[key] = entry
+        return entry
+
+    def drop(self, key: Optional[Tuple[int, ...]]) -> None:
+        """The pager evicted the block keyed `key`."""
+        entry = self._held.pop(key, None) if key is not None else None
+        if entry is not None:
+            self._free.append(entry)
+            self.evictions += 1
+
+    def stats(self, state_bytes: int = 0) -> Dict[str, int]:
+        return {"state_bytes": int(state_bytes),
+                "snapshots_resident": len(self._held),
+                "snapshot_hits": self.hits,
+                "snapshot_misses": self.misses,
+                "snapshot_evictions": self.evictions}
 
 
 class BlockPager:
@@ -78,6 +156,9 @@ class BlockPager:
         # LIFO free list: recently-freed blocks are re-used first
         # (warmer HBM pages on real hardware, denser tests)
         self._free: List[int] = list(range(num_blocks - 1, 0, -1))
+        #: bumped whenever `_free` changes: kvscope's per-wave sample
+        #: reuses its last fragmentation while this stands still
+        self._free_version = 0
         self._ref: Dict[int, int] = {}
         #: exact prompt-token prefix -> resident block id.  Keys are
         #: token tuples (content-addressed), so a block evicted and
@@ -121,6 +202,15 @@ class BlockPager:
         #: gathers a block's K/V rows to host at spill time.
         self.tier = host_tier
         self._block_saver: Optional[Callable[[int], Tuple]] = None
+        #: a recurrent family's snapshot index (`set_snapshots`)
+        self.snapshots: Optional[StateSnapshots] = None
+
+    def set_snapshots(self, snapshots: StateSnapshots) -> None:
+        """Tie a recurrent family's snapshot index to this pool: an
+        evicted block takes the snapshot at its boundary with it, and
+        `match_prefix` keeps only what a snapshot lets the caller
+        skip."""
+        self.snapshots = snapshots
 
     def set_block_saver(self, fn: Callable[[int], Tuple]) -> None:
         """Register the engine's D2H gather: ``fn(block_id) ->
@@ -207,6 +297,7 @@ class BlockPager:
             return None
         out: List[int] = []
         evicted = 0
+        self._free_version += 1 if count else 0
         for _ in range(count):
             if not self._free:
                 blk, _ = self._cached.popitem(last=False)  # LRU
@@ -274,6 +365,7 @@ class BlockPager:
                 self._cached.move_to_end(blk)
             else:
                 self._free.append(blk)
+                self._free_version += 1
             freed += 1
         if self._recorder is not None and freed:
             self._recorder.record("kv_free", blocks=freed,
@@ -328,6 +420,16 @@ class BlockPager:
                 self._ref[blk] = 1
             else:
                 self._ref[blk] += 1
+        if self.snapshots is not None:
+            # a recurrent family skips only as far as a snapshot of its
+            # state reaches: the deepest matched boundary that has one
+            # (block-aligned and <= n - 1, so the tail is never empty
+            # and never lands in a shared block); the rest is a miss
+            keep = self.snapshots.deepest(
+                tokens, min(len(matched), max(n - 1, 0) // self.block_size),
+                self.block_size)
+            self.release(matched[keep:])
+            matched, prefix_len = matched[:keep], keep * self.block_size
         self.scope.note_alloc(matched, self._req_ctx[2])
         self.prefix_hits += len(matched)
         self.prefix_misses += self.blocks_needed(n, 0) - len(matched)
@@ -502,6 +604,8 @@ class BlockPager:
         key = self._block_key.pop(block_id, None)
         if key is not None:
             self._index.pop(key, None)
+            if self.snapshots is not None:
+                self.snapshots.drop(key)
 
     # -- introspection -------------------------------------------------
 
@@ -509,7 +613,8 @@ class BlockPager:
         """Append one kvscope occupancy snapshot — the engine calls
         this once per wave, so the ring replays pool pressure at
         scheduling granularity without journaling every allocation."""
-        self.scope.sample(self._free, len(self._cached))
+        self.scope.sample(self._free, len(self._cached),
+                          self._free_version)
 
     def kv_scope_stats(self) -> Dict[str, object]:
         """The occupancy/forensics half of ``engine_stats()``'s

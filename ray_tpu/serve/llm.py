@@ -28,6 +28,7 @@ Two schedulers:
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import pickle
 import types
@@ -73,7 +74,8 @@ class SpecConfig:
             raise ValueError(f"spec k must be >= 1, got {self.k}")
         if self.draft != "ngram":
             parts = self.draft.split(":")
-            if len(parts) != 2 or parts[0] not in ("gpt2", "llama"):
+            if len(parts) != 2 or \
+                    _FAMILIES.get(parts[0], ("",))[0] != "kv":
                 raise ValueError(
                     f"spec draft must be 'ngram' or "
                     f"'<family>:<preset>' with family gpt2|llama, "
@@ -83,34 +85,97 @@ class SpecConfig:
                 f"ngram_order must be >= 1, got {self.ngram_order}")
 
 
-def _family_fns(family: str):
-    """(config_fn, init_fn, generate_fn, prefill_fn, step_fn,
-    init_cache_fn, init_paged_cache_fn, paged_prefill_fn,
-    logical_axes_fn) for a decoder family."""
-    if family == "gpt2":
-        from ray_tpu.models import (gpt2_config, gpt2_init,
-                                    gpt2_logical_axes)
-        from ray_tpu.models.gpt2_decode import (decode_step, generate,
-                                                init_cache,
-                                                init_paged_cache,
-                                                paged_prefill, prefill)
+def _gpt2_fns():
+    from ray_tpu.models import (gpt2_config, gpt2_init,
+                                gpt2_logical_axes)
+    from ray_tpu.models import gpt2_decode as m
 
-        return (gpt2_config, gpt2_init, generate, prefill, decode_step,
-                init_cache, init_paged_cache, paged_prefill,
-                gpt2_logical_axes)
+    return types.SimpleNamespace(
+        config=gpt2_config, init=gpt2_init, generate=m.generate,
+        prefill=m.prefill, step=m.decode_step, init_cache=m.init_cache,
+        init_paged_cache=m.init_paged_cache,
+        paged_prefill=m.paged_prefill, logical_axes=gpt2_logical_axes,
+        verify=m.verify_step)
+
+
+def _llama_fns():
     from ray_tpu.models import (llama_config, llama_init,
                                 llama_logical_axes)
-    from ray_tpu.models.llama_decode import (llama_decode_step,
-                                             llama_generate,
-                                             llama_init_cache,
-                                             llama_init_paged_cache,
-                                             llama_paged_prefill,
-                                             llama_prefill)
+    from ray_tpu.models import llama_decode as m
 
-    return (llama_config, llama_init, llama_generate, llama_prefill,
-            llama_decode_step, llama_init_cache,
-            llama_init_paged_cache, llama_paged_prefill,
-            llama_logical_axes)
+    return types.SimpleNamespace(
+        config=llama_config, init=llama_init, generate=m.llama_generate,
+        prefill=m.llama_prefill, step=m.llama_decode_step,
+        init_cache=m.llama_init_cache,
+        init_paged_cache=m.llama_init_paged_cache,
+        paged_prefill=m.llama_paged_prefill,
+        logical_axes=llama_logical_axes, verify=m.llama_verify_step)
+
+
+def _jamba_fns():
+    from ray_tpu.models import jamba_decode as m
+    from ray_tpu.models.jamba import (jamba_config, jamba_init,
+                                      jamba_logical_axes)
+
+    return types.SimpleNamespace(
+        config=jamba_config, init=jamba_init, generate=m.jamba_generate,
+        prefill=m.jamba_prefill, step=m.jamba_decode_step,
+        init_cache=m.jamba_init_cache,
+        init_paged_cache=m.jamba_init_paged_cache,
+        paged_prefill=m.jamba_paged_prefill,
+        logical_axes=jamba_logical_axes, verify=None)
+
+
+#: family -> (what its cache holds, loader of its programs).  A family
+#: is one row here: the engine asks `_family_fns` for the programs and
+#: this table for the cache's kind, and names no family anywhere else.
+#: "kv": a slot's past is its K/V rows, which every engine feature can
+#: move (rewind by position, spill and restore by block, hand off).
+#: "kv+recurrent": some layers keep one state per sequence beside the
+#: K/V (models/jamba_decode.py); what cannot carry that state yet is
+#: refused at construction (`_refuse_for_recurrent`).
+RECURRENT = "kv+recurrent"
+
+#: how much work the continuous engine may queue on the chip ahead of
+#: the host, in seconds of decode waves and at most so many waves
+#: (`LLM._depth`): what a host that is held up for a tenth of a second
+#: (a collector's pause, a neighbour, a frozen sandbox) can be late by
+#: before the chip runs dry.  A request admitted meanwhile starts
+#: behind that work, which is the price and why it is bounded: a wave
+#: longer than this is never queued behind another.
+_AHEAD_S = 0.13
+_AHEAD_MAX = 16
+_FAMILIES = {"gpt2": ("kv", _gpt2_fns), "llama": ("kv", _llama_fns),
+             "jamba": (RECURRENT, _jamba_fns)}
+
+
+def _family_fns(family: str):
+    """A family's programs by name: config, init, generate, prefill,
+    step, init_cache, init_paged_cache, paged_prefill, logical_axes,
+    verify (None where the family has no verify program), and
+    `cache_kind`."""
+    kind, load = _FAMILIES[family]
+    fns = load()
+    fns.cache_kind = kind
+    return fns
+
+
+def _refuse_for_recurrent(family, *, spec_decode, kv_host_tier_bytes,
+                          role, mesh) -> None:
+    """What cannot carry a recurrent state yet refuses, loudly, before
+    anything is built: a spec-decode rewind moves `pos` back and a
+    recurrent state has no earlier value to go back to; the host tier
+    and the handoff move K/V blocks and would leave the state behind;
+    the state has no sharding rule."""
+    asked = {"spec_decode": spec_decode is not None,
+             "kv_host_tier_bytes": kv_host_tier_bytes is not None,
+             f"role={role!r}": role != "both", "mesh": mesh is not None}
+    for option, on in asked.items():
+        if on:
+            raise ValueError(
+                f"family {family!r} keeps a {RECURRENT} cache (one "
+                f"recurrent state per slot beside the K/V pool), which "
+                f"{option} cannot carry yet: refused")
 
 
 # jax's compile cache is keyed by the jitted function OBJECT, so a
@@ -142,6 +207,8 @@ def _jitted_engine_fns(prefill_fn, step_fn, paged_prefill_fn, cfg,
           twins for requests overriding SamplingParams (compiled only
           if such a request arrives)
       admit / copy_block / clear_row       — pool bookkeeping
+      join_token                           — a prefill's first token
+          into the tokens of the wave queued behind it
 
     Every program that takes the engine's cache and returns it
     CONSUMES it (donate_argnums): the result is the same buffers
@@ -197,19 +264,27 @@ def _jitted_engine_fns(prefill_fn, step_fn, paged_prefill_fn, cfg,
     def prefill_raw(p, toks, lens):
         return prefill_fn(p, toks, cfg, lengths=lens)
 
+    def stated(state):
+        # a recurrent family's prefill is also told where the slot's
+        # state starts and which snapshot it leaves (LLM._state_args);
+        # the other families' prefills take no such argument
+        return {"state": state[0]} if state else {}
+
     def paged_prefill_sample(p, cache, toks, row_bt, prefix_len,
-                             n_tail, slot, k):
+                             n_tail, slot, k, *state):
         logits, cache = paged_prefill_fn(
             p, cache, toks, cfg, row_bt=row_bt,
-            prefix_len=prefix_len, n_tail=n_tail, slot=slot)
+            prefix_len=prefix_len, n_tail=n_tail, slot=slot,
+            **stated(state))
         return sample_token(logits[None], k, temperature, tail,
                             top_k, top_p), pinned(cache)
 
     def paged_prefill_raw(p, cache, toks, row_bt, prefix_len, n_tail,
-                          slot):
+                          slot, *state):
         logits, cache = paged_prefill_fn(
             p, cache, toks, cfg, row_bt=row_bt,
-            prefix_len=prefix_len, n_tail=n_tail, slot=slot)
+            prefix_len=prefix_len, n_tail=n_tail, slot=slot,
+            **stated(state))
         return logits[None], pinned(cache)
 
     def pool_step(p, cache, toks, k):
@@ -221,14 +296,22 @@ def _jitted_engine_fns(prefill_fn, step_fn, paged_prefill_fn, cfg,
         logits, cache = step_fn(p, cache, toks, cfg)
         return logits, pinned(cache)
 
+    def join_token(toks, slot, tok):
+        # a prefill's first token into a wave's tokens, on the device
+        return lax.dynamic_update_slice(toks, tok.astype(toks.dtype),
+                                        (slot,))
+
     def fork_block(cache, src, dst):
         return pinned(copy_block(cache, src, dst))
 
     def admit(pool, row, slot):
         out = dict(pool)
-        for name in ("k", "v"):   # (L, B, S, ...): row b=slot
-            out[name] = lax.dynamic_update_slice_in_dim(
-                pool[name], row[name], slot, axis=1)
+        # (L, B, S, ...): row b=slot; a recurrent family's state rows
+        # beside them, its window with the batch on axis 2
+        for name, axis in (("k", 1), ("v", 1), ("ssm", 1), ("conv", 2)):
+            if name in pool:
+                out[name] = lax.dynamic_update_slice_in_dim(
+                    pool[name], row[name], slot, axis=axis)
         for name in ("pos", "start"):
             out[name] = lax.dynamic_update_slice_in_dim(
                 pool[name], row[name], slot, axis=0)
@@ -241,6 +324,19 @@ def _jitted_engine_fns(prefill_fn, step_fn, paged_prefill_fn, cfg,
         out = dict(cache)
         out["block_tables"] = cache["block_tables"].at[slot].set(0)
         out["pos"] = cache["pos"].at[slot].set(0)
+        return pinned(out)
+
+    def restore_state(cache, entry, slot):
+        # a recurrent family's chunked admission that hit a snapshot:
+        # the slot's state becomes the snapshot's NOW, in one small
+        # donated program, because the chunks run later and the entry
+        # may be another prefix's by then
+        out = dict(cache)
+        for name, axis in (("ssm", 1), ("conv", 2)):
+            out[name] = lax.dynamic_update_slice_in_dim(
+                cache[name], lax.dynamic_slice_in_dim(
+                    cache["snap_" + name], entry, 1, axis=axis),
+                slot, axis=axis)
         return pinned(out)
 
     def install_blocks(cache, blk_ids, k_stack, v_stack):
@@ -363,8 +459,10 @@ def _jitted_engine_fns(prefill_fn, step_fn, paged_prefill_fn, cfg,
                                   donate_argnums=(1,)),
         pool_logits=jax.jit(pool_logits, donate_argnums=(1,)),
         admit=jax.jit(admit),
+        join_token=jax.jit(join_token),
         copy_block=jax.jit(fork_block, donate_argnums=(0,)),
         clear_row=jax.jit(clear_row, donate_argnums=(0,)),
+        restore_state=jax.jit(restore_state, donate_argnums=(0,)),
         install_blocks=jax.jit(install_blocks, donate_argnums=(0,)),
         save_block=jax.jit(save_block),
         kv_handoff_export=registry.instrument(
@@ -410,7 +508,14 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
     each caller gets back its own prompt + continuation, pads
     trimmed).
 
-    family: "gpt2" | "llama"; preset: a model-zoo preset name.
+    family: "gpt2" | "llama" | "jamba" (a row of `_FAMILIES`); preset:
+    a model-zoo preset name.  A family whose cache is
+    "kv+recurrent" (jamba: Mamba layers keep one state per slot beside
+    the K/V pool of its attention layers) is served by the same engine;
+    with the paged layout a prompt skips a resident prefix only as far
+    as a snapshot of that state reaches (kv_pager.StateSnapshots), and
+    spec_decode, kv_host_tier_bytes, a split role and mesh are refused
+    for it, since none can carry the state yet.
     scheduler: "batch" (@serve.batch fixed micro-batches) or
     "continuous" (slot pool of `max_slots` KV rows with mid-flight
     admission; `prefill_bucket` bounds prefill recompiles).
@@ -507,8 +612,12 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
     where the exported rows stay device-resident end to end.
     checkpoint_path: pickled param pytree (matching the family's init
     layout); absent → fresh init from `seed` (tests/demos)."""
-    if family not in ("gpt2", "llama"):
+    if family not in _FAMILIES:
         raise ValueError(f"unknown LM family {family!r}")
+    if _FAMILIES[family][0] == RECURRENT:
+        _refuse_for_recurrent(family, spec_decode=spec_decode,
+                              kv_host_tier_bytes=kv_host_tier_bytes,
+                              role=role, mesh=mesh)
     if scheduler not in ("batch", "continuous"):
         raise ValueError(f"unknown scheduler {scheduler!r} "
                          f"(expected 'batch' or 'continuous')")
@@ -611,10 +720,11 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
             self.device = device
 
             overrides = dict(config_overrides or {})
-            (config_fn, init_fn, gen_fn, prefill_fn, step_fn,
-             init_cache_fn, init_paged_fn, paged_prefill_fn,
-             logical_axes_fn) = _family_fns(family)
-            self.cfg = config_fn(preset, **overrides)
+            fam = _family_fns(family)
+            init_fn, gen_fn, logical_axes_fn = (
+                fam.init, fam.generate, fam.logical_axes)
+            self._recurrent = fam.cache_kind == RECURRENT
+            self.cfg = fam.config(preset, **overrides)
             if checkpoint_path:
                 with open(checkpoint_path, "rb") as f:
                     self.params = jax.tree.map(jnp.asarray,
@@ -674,9 +784,7 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
                         temperature=temperature, top_k=top_k,
                         top_p=top_p, key=k))
             else:
-                self._init_continuous(prefill_fn, step_fn,
-                                      init_cache_fn, init_paged_fn,
-                                      paged_prefill_fn)
+                self._init_continuous(fam)
 
         def _to_engine(self, tree):
             """Commit arrays made elsewhere (fresh inits, another
@@ -775,12 +883,15 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
             return t if t > 1 and self._kv_heads(self.cfg) % t == 0 \
                 else 1
 
-        def _init_continuous(self, prefill_fn, step_fn, init_cache_fn,
-                             init_paged_fn, paged_prefill_fn):
+        def _init_continuous(self, fam):
             import jax
             import jax.numpy as jnp
 
             cfg = self.cfg
+            prefill_fn, step_fn, paged_prefill_fn = (
+                fam.prefill, fam.step, fam.paged_prefill)
+            init_cache_fn, init_paged_fn = (fam.init_cache,
+                                            fam.init_paged_cache)
             self._pager = None
             if kv_layout == "paged":
                 from ray_tpu.serve.kv_pager import BlockPager
@@ -791,7 +902,10 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
                 # COW forks survive a fully-occupied pool
                 n_blocks = (kv_num_blocks if kv_num_blocks is not None
                             else 1 + (max_slots + 1) * max_blk)
-                bytes_per_block = (2 * cfg.n_layer * kv_block_size
+                # a hybrid's pool holds its attention layers only
+                bytes_per_block = (2 * getattr(cfg, "n_kv_layer",
+                                               cfg.n_layer)
+                                   * kv_block_size
                                    * self._kv_heads(cfg)
                                    * cfg.head_dim
                                    * jnp.dtype(cfg.dtype).itemsize)
@@ -815,12 +929,29 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
                                             mesh=self.mesh)
                 if host_tier is not None:
                     self._pager.set_block_saver(self._tier_save)
+                if self._recurrent:
+                    # prefix reuse for a recurrent family: one snapshot
+                    # of the state a slot, keyed as the pager keys the
+                    # block at its boundary (kv_pager.StateSnapshots)
+                    from ray_tpu.serve.kv_pager import StateSnapshots
+
+                    self._pager.set_snapshots(StateSnapshots(max_slots))
             else:
                 self._cache = init_cache_fn(cfg, max_slots,
                                             mesh=self.mesh)
             self._cache = self._to_engine(self._cache)
             self._cur = np.zeros((max_slots,), np.int32)
             self._slots = [None] * max_slots
+            # what the chip has been given and the host has not fenced
+            # yet, oldest first: decode waves, and the prefills admitted
+            # between them (_wave, _land); when the last wave landed and
+            # what the last waves took, fence to fence
+            self._flight = collections.deque()
+            # slot -> first token, still on the device, of each prefill
+            # in flight that the next wave takes up (join_token)
+            self._joins = {}
+            self._t_landed = 0.0
+            self._wave_s = collections.deque(maxlen=33)
             self._queue = RequestQueue()
             self._wake = None           # asyncio.Event, made on-loop
             self._engine_task = None
@@ -832,10 +963,10 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
             # splits once per admission, at the FINAL chunk — the same
             # stream a one-shot admission sees)
             self._chunk_rr = 0
-            self._dummy_key = None
-            if prefill_chunk_tokens is not None:
-                import jax as _jax
-                self._dummy_key = _jax.random.PRNGKey(0)
+            # the same constant key rides with a greedy decode wave:
+            # argmax reads no key, and the eager split it replaces was
+            # 1 ms of idle device a step (`_step`)
+            self._dummy_key = jax.random.PRNGKey(0)
 
             # spec decode: resolve the verify program and (model
             # drafts) the draft family's fns/config/params/cache pool
@@ -845,13 +976,7 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
             self._spec_sampled = (spec_decode is not None
                                   and temperature > 0.0)
             if spec_decode is not None:
-                if family == "gpt2":
-                    from ray_tpu.models.gpt2_decode import verify_step
-                    verify_fn = verify_step
-                else:
-                    from ray_tpu.models.llama_decode import \
-                        llama_verify_step
-                    verify_fn = llama_verify_step
+                verify_fn = fam.verify
                 # draft rewind bookkeeping: per slot, how many of last
                 # round's drafted tokens the target rejected (the
                 # draft cache rolls back exactly this many positions
@@ -859,9 +984,11 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
                 self._spec_rej = np.zeros((max_slots,), np.int32)
                 if spec_decode.draft != "ngram":
                     d_family, d_preset = spec_decode.draft.split(":")
-                    (d_config_fn, d_init_fn, _g, d_prefill_fn,
-                     d_step_fn, d_init_cache_fn, *_rest) = \
-                        _family_fns(d_family)
+                    d_fam = _family_fns(d_family)
+                    d_config_fn, d_init_fn, d_prefill_fn = (
+                        d_fam.config, d_fam.init, d_fam.prefill)
+                    d_step_fn, d_init_cache_fn = (d_fam.step,
+                                                  d_fam.init_cache)
                     # overrides describe THIS family's config fields;
                     # a cross-family draft takes its preset verbatim
                     d_over = (dict(config_overrides or {})
@@ -905,6 +1032,9 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
              self._admit, self._copy_block, self._clear_row) = (
                 fns.prefill, fns.paged_prefill, fns.pool_step,
                 fns.admit, fns.copy_block, fns.clear_row)
+            # compiled here, not at the first admission that meets a
+            # decode wave in flight
+            fns.join_token(self._cur, np.int32(0), self._cur[:1])
             if self._pager is not None and self._pager.tier is not None:
                 # pre-compile the H2D splice program with an all-pad
                 # call (every id 0 → zero rows into the null write
@@ -1069,6 +1199,9 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
                                                  slot):
                         return          # pool exhausted — retry later
                     continue
+                # this prefill is fenced at once: not with waves queued
+                # before it, whose tokens would wait for it
+                self._drain()
                 # pad up to the bucket so the prefill program compiles
                 # once per bucket; never past the decode headroom
                 t_pad = -(-n // prefill_bucket) * prefill_bucket
@@ -1258,6 +1391,11 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
                         filled=prefix_len)}
                 if spec_decode is not None:
                     self._spec_rej[slot] = 0
+                if self._recurrent and prefix_len:
+                    self._cache = self._fns.restore_state(
+                        self._cache,
+                        np.int32(pager.snapshots.entry_of(
+                            tuple(tokens[:prefix_len]))), np.int32(slot))
                 self._telemetry.record_kv_stats(pager.stats())
                 return True
             t_pad = -(-n_tail // prefill_bucket) * prefill_bucket
@@ -1268,23 +1406,53 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
             with phase("rng_split"):
                 self._rng, k = jax.random.split(self._rng)
             with phase("prefill_dispatch"):
+                state = self._state_args(tokens, prefix_len, n_tail)
                 if sp is not None:
                     logits, self._cache = self._fns.paged_prefill_raw(
                         self.params, self._cache,
                         jnp.asarray(tail_toks), jnp.asarray(row_bt),
                         np.int32(prefix_len), np.int32(n_tail),
-                        np.int32(slot))
+                        np.int32(slot), *state)
                     tok = self._sampler_for(sp)(logits, k)
                 else:
                     tok, self._cache = self._paged_prefill(
                         self.params, self._cache,
                         jnp.asarray(tail_toks), jnp.asarray(row_bt),
                         np.int32(prefix_len), np.int32(n_tail),
-                        np.int32(slot), k)
+                        np.int32(slot), k, *state)
+            st = {"prompt": arr, "out": [], "due": 1, "fut": fut,
+                  "rec": rec, "sp": sp, "blocks": blocks}
+            first = {"tok": tok, "slot": slot, "st": st,
+                     "tokens": tokens}
+            if sp is None and self._flight and self._chains():
+                # decode waves are in flight: this prefill is queued
+                # behind them and fenced in its turn (_land); the next
+                # wave, behind it and the prefills admitted with it,
+                # finds the row's first token on the device, so the
+                # chip does not wait for the host to fence a prefill
+                # and come back
+                self._slots[slot] = st
+                self._flight.append(first)
+                self._joins[slot] = tok
+            else:
+                self._drain()
+                self._land_first(first)
+            return True
+
+        def _land_first(self, item) -> None:
+            """Fence a paged prefill and book its first token: the
+            second half of `_admit_one_paged`, at once where nothing
+            was in flight, else in the prefill's turn."""
+            pager = self._pager
+            slot, st, tokens = item["slot"], item["st"], item["tokens"]
+            arr, rec, fut, blocks = (st["prompt"], st["rec"], st["fut"],
+                                     st["blocks"])
+            ctx = rec.get("ctx")
             # int() is the engine's existing host fence for the
             # prefill result; the timestamp behind it is the TTFT
-            with phase("prefill_fence"):
-                first = int(np.asarray(tok)[0])
+            with self._phases.phase("prefill_fence"):
+                first = int(np.asarray(item["tok"])[0])
+            st["due"] -= 1
             self._telemetry.record_first_token(rec)
             # the prompt's full blocks now hold exactly its K/V —
             # index them so later prompts can skip this work.
@@ -1304,24 +1472,60 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
                 if not fut.done():
                     fut.set_result(np.concatenate(
                         [arr, np.asarray([first], np.int32)]))
+                self._slots[slot] = None
                 self._retire_paged_row(slot, blocks)
-                return True
+                return
             if role == "prefill":
                 # disaggregated serving: the request's decode belongs
                 # to a decode replica — export the filled block rows,
                 # resolve the future with a HandoffCursor package, and
                 # free this replica's row/blocks (registered full
                 # blocks park in the LRU, keeping the prefix warm)
-                self._handoff_out(slot, arr, rec, sp, fut, blocks,
+                self._handoff_out(slot, arr, rec, st["sp"], fut, blocks,
                                   first)
-                return True
+                return
             self._cur[slot] = first
-            self._slots[slot] = {"prompt": arr, "out": [first],
-                                 "fut": fut, "rec": rec, "sp": sp,
-                                 "blocks": blocks}
+            st["out"].append(first)
+            self._slots[slot] = st
+            self._draft_admit(slot, arr)
+            self._telemetry.record_kv_stats(pager.stats())
             self._draft_admit(slot, arr)
             self._telemetry.record_kv_stats(pager.stats())
             return True
+
+        def _state_args(self, tokens, prefix_len, n_tail,
+                        chunk=False) -> tuple:
+            """What a recurrent family's paged prefill is told beside
+            the K/V arguments (models/jamba_decode.py
+            jamba_paged_prefill `state`), () for the other families:
+            where the slot's state starts, and the snapshot this
+            prefill leaves.  The state starts from zeros, from the
+            snapshot `match_prefix` trimmed the match to (it holds the
+            state after exactly `prefix_len` tokens), or, for a chunk
+            of a streamed prompt, from the slot's own rows (the
+            previous chunk's, or the snapshot `restore_state` put
+            there at admission).  The snapshot is of the prompt's
+            deepest block boundary that leaves a token to prefill; the
+            prefill (or chunk) that walks over it writes it."""
+            if not self._recurrent:
+                return ()
+            from ray_tpu.models.decode_common import (NO_SNAPSHOT,
+                                                      STATE_FROM_SLOT,
+                                                      STATE_FROM_ZERO)
+
+            snaps = self._pager.snapshots
+            tokens = tuple(tokens)
+            if not prefix_len:
+                source = STATE_FROM_ZERO
+            elif chunk:
+                source = STATE_FROM_SLOT
+            else:
+                source = snaps.entry_of(tokens[:prefix_len])
+            boundary = (len(tokens) - 1) // kv_block_size * kv_block_size
+            entry = NO_SNAPSHOT
+            if prefix_len < boundary <= prefix_len + n_tail:
+                entry = snaps.reserve(tokens[:boundary])
+            return (np.asarray([source, entry, boundary], np.int32),)
 
         def _tier_save(self, blk) -> tuple:
             """The pager's block-saver callback (serve/kv_tier.py):
@@ -1537,11 +1741,13 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
                 # admission (exactly one split, at the final chunk)
                 k = self._dummy_key
             first = None
+            state = self._state_args(arr.tolist(), filled, c,
+                                     chunk=True)
             if st["sp"] is not None:
                 logits, self._cache = self._fns.paged_prefill_raw(
                     self.params, self._cache, jnp.asarray(chunk_toks),
                     jnp.asarray(st["row_bt"]), np.int32(filled),
-                    np.int32(c), np.int32(i))
+                    np.int32(c), np.int32(i), *state)
                 if last:
                     tok = self._sampler_for(st["sp"])(logits, k)
                     first = int(np.asarray(tok)[0])
@@ -1553,7 +1759,7 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
                 tok, self._cache = self._paged_prefill(
                     self.params, self._cache, jnp.asarray(chunk_toks),
                     jnp.asarray(st["row_bt"]), np.int32(filled),
-                    np.int32(c), np.int32(i), k)
+                    np.int32(c), np.int32(i), k, *state)
                 # the chunk's host fence (the one-shot path's int());
                 # intermediate chunks discard the value
                 first = int(np.asarray(tok)[0])
@@ -1735,6 +1941,138 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
                     self._finish_slot(i, st)
             return total
 
+        def _decoding(self) -> dict:
+            """The rows a decode wave samples for: slot -> its state."""
+            return {i: st for i, st in enumerate(self._slots)
+                    if st is not None and st.get("state") != "prefill"}
+
+        def _mixed(self) -> bool:
+            """Whether a decoding row overrides the engine's
+            SamplingParams (the wave then samples by groups)."""
+            return any(st["sp"] is not None
+                       for st in self._decoding().values())
+
+        def _chains(self) -> bool:
+            """Whether the next decode wave can be queued behind what
+            is in flight and read the newest wave's tokens on the
+            device: every row decoding now was decoding in that wave or
+            has its first token waiting on the device (`_joins`; any
+            other row that joined since has it on the host, in `_cur`),
+            the wave is the plain one, and some row outlives
+            what is in flight by its count (a wave for rows that all
+            end there would be a step for nothing).  A row that ends in
+            a wave in flight, by its count or by a stop token the host
+            sees only when that wave lands, is stepped by the waves
+            behind it: those writes land in blocks it reserved or in
+            the null block, before `clear_row` and before any later
+            tenant's prefill, and the tokens are dropped (`_land`)."""
+            waves = [w for w in self._flight if "rows" in w]
+            if not waves or spec_decode is not None or self._mixed():
+                return False
+            rows, before = self._decoding(), waves[-1]["rows"]
+            return (all(before.get(i) is st or i in self._joins
+                        for i, st in rows.items())
+                    and any(len(st["out"]) + st.get("due", 0)
+                            < max_new_tokens for st in rows.values()))
+
+        def _depth(self) -> int:
+            """How many decode waves to leave in flight when the host
+            goes to fence the oldest: as many as fit in `_AHEAD_S` on
+            the chip by what the last waves took.  None where one wave
+            is longer than that (the chip is then fenced after every
+            wave, and a request admitted next starts at once: a long
+            step hides the host by itself), none before a wave has been
+            timed, and none while a chunked prompt streams in (its
+            chunk is fenced between two waves)."""
+            if not self._wave_s or any(
+                    st is not None and st.get("state") == "prefill"
+                    for st in self._slots):
+                return 0
+            step_s = sorted(self._wave_s)[len(self._wave_s) // 2]
+            return min(_AHEAD_MAX, int(_AHEAD_S / max(step_s, 1e-4)))
+
+        def _wave(self) -> None:
+            """Dispatch one plain decode wave and leave it in flight.
+            Where waves before it still are, this one reads the
+            newest one's tokens where they are, on the device, and is
+            queued behind it BEFORE the host fences and emits any of
+            them: the chip goes from one step to the next while the
+            host works, or is held up (`_chains` says when that is
+            sound, `_depth` how far ahead).  The first tokens of the
+            prefills admitted since, not yet fenced, are put into
+            their slots' places on the device (`_joins`)."""
+            import jax
+            import jax.numpy as jnp
+
+            phase = self._phases.phase
+            if temperature > 0.0:
+                with phase("rng_split"):
+                    self._rng, k = jax.random.split(self._rng)
+            else:
+                # every decoding row is greedy: the wave's sampler is
+                # an argmax and reads no key, so none is drawn (the
+                # engine RNG advances on the waves that sample and at
+                # each admission, as before)
+                k = self._dummy_key
+            waves = [w for w in self._flight if "rows" in w]
+            with phase("decode_dispatch") as wave:
+                toks = waves[-1]["toks"] if waves \
+                    else jnp.asarray(self._cur)
+                for slot, tok in self._joins.items():
+                    toks = self._fns.join_token(toks, np.int32(slot),
+                                                tok)
+                self._joins.clear()
+                toks, self._cache = self._pool_step(
+                    self.params, self._cache, toks, k)
+            rows = self._decoding()
+            for st in rows.values():
+                st["due"] = st.get("due", 0) + 1
+            self._flight.append({"toks": toks, "t0": wave.t0,
+                                 "rows": rows})
+
+        def _land(self) -> None:
+            """Fence what has been in flight longest.  A decode wave:
+            emit its tokens to the rows it sampled for that are still
+            there.  A prefill: book its first token."""
+            item = self._flight.popleft()
+            if "rows" not in item:
+                self._land_first(item)
+                return
+            with self._phases.phase("decode_fence") as fence:
+                # the wave's one host fence
+                toks = np.asarray(item["toks"])
+            rows = {i: st for i, st in item["rows"].items()
+                    if self._slots[i] is st}
+            if not rows:
+                return      # every row it stepped has ended since
+            for st in rows.values():
+                st["due"] -= 1
+            # a step's walltime: from its dispatch, or from the wave
+            # before it landing where it was queued behind that one
+            took = fence.t1 - max(item["t0"], self._t_landed)
+            self._telemetry.record_step(len(rows), took, now=fence.t1)
+            self._wave_s.append(took)
+            self._t_landed = fence.t1
+            self._emit(rows, toks, fence.t1)
+
+        def _drain(self) -> None:
+            """Land everything in flight, oldest first."""
+            while self._flight:
+                self._land()
+            self._joins.clear()     # their tokens are in `_cur` now
+
+        def _emit(self, rows, toks, t_wave) -> None:
+            """One wave's tokens to their rows; a row that is done is
+            retired now."""
+            with self._phases.phase("emit"):
+                for i, st in rows.items():
+                    st["out"].append(int(toks[i]))
+                    self._telemetry.record_token(st["rec"], now=t_wave)
+                    self._cur[i] = toks[i]
+                    if len(st["out"]) >= max_new_tokens \
+                            or self._hit_stop(st["out"]):
+                        self._finish_slot(i, st)
+
         async def _step(self) -> bool:
             """One iteration's work, inside the open
             ``raytpu.engine.step``: admit, one decode wave (or one
@@ -1747,16 +2085,19 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
             import asyncio
 
             import jax
-            import jax.numpy as jnp
 
             phase = self._phases.phase
             with phase("admit"):
                 self._admit_pending()
+            if self._flight and not self._chains():
+                self._drain()
             prefilling = [
                 i for i, s in enumerate(self._slots)
                 if s is not None and s.get("state") == "prefill"]
             n_active = sum(s is not None for s in self._slots)
             if not n_active:
+                self._flight.clear()    # waves whose rows all ended
+                self._joins.clear()
                 return False
             n_decode = n_active - len(prefilling)
             if self._chaos is not None and n_decode:
@@ -1775,37 +2116,19 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
                 self._telemetry.record_step(
                     n_decode, rnd.t1 - rnd.t0, n_tokens=n_tokens)
             elif n_decode:
-                with phase("rng_split"):
-                    self._rng, k = jax.random.split(self._rng)
-                if any(st is not None
-                       and st.get("state") != "prefill"
-                       and st["sp"] is not None
-                       for st in self._slots):
+                if self._mixed():
+                    with phase("rng_split"):
+                        self._rng, k = jax.random.split(self._rng)
                     with phase("decode_dispatch") as wave:
                         toks = self._mixed_step(k)   # fences inside
-                    t_wave = wave.t1
+                    self._telemetry.record_step(
+                        n_decode, wave.t1 - wave.t0, now=wave.t1)
+                    self._emit(self._decoding(), toks, wave.t1)
                 else:
-                    with phase("decode_dispatch") as wave:
-                        toks, self._cache = self._pool_step(
-                            self.params, self._cache,
-                            jnp.asarray(self._cur), k)
-                    with phase("decode_fence") as fence:
-                        # graftcheck: disable=blocking-call-in-async(the per-step host fence)
-                        toks = np.asarray(toks)
-                    t_wave = fence.t1
-                self._telemetry.record_step(
-                    n_decode, t_wave - wave.t0, now=t_wave)
-                with phase("emit"):
-                    for i, st in enumerate(self._slots):
-                        if st is None or st.get("state") == "prefill":
-                            continue
-                        st["out"].append(int(toks[i]))
-                        self._telemetry.record_token(st["rec"],
-                                                     now=t_wave)
-                        self._cur[i] = toks[i]
-                        if len(st["out"]) >= max_new_tokens \
-                                or self._hit_stop(st["out"]):
-                            self._finish_slot(i, st)
+                    self._wave()
+                    depth = self._depth()
+                    while sum("rows" in w for w in self._flight) > depth:
+                        self._land()
             with phase("hooks"):
                 if self._telemetry.slo is not None:
                     # throttled burn-rate watchdog: breach / storm
@@ -1852,6 +2175,7 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
                     if not len(self._queue) and all(
                             s is None for s in self._slots):
                         # nothing queued, nothing running: park
+                        self._flight.clear()
                         self._wake.clear()
                         if self._health is not None:
                             # parked-idle is not a failure: the probe
@@ -1880,6 +2204,8 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
                             context={"error": repr(e)[:500]})
                     except Exception:  # noqa: BLE001 - dump best-effort
                         pass
+                    self._flight.clear()
+                    self._joins.clear()
                     for i, st in enumerate(self._slots):
                         if st is not None:
                             self._telemetry.record_error(
@@ -2056,6 +2382,12 @@ def build_llm_deployment(family: str = "gpt2", preset: str = "nano",
                 if pager.tier is not None:
                     self._telemetry.record_kv_tier(
                         pager.tier.stats())
+                if pager.snapshots is not None:
+                    self._telemetry.record_recurrent(
+                        pager.snapshots.stats(sum(
+                            self._cache[n].nbytes for n in (
+                                "conv", "ssm", "snap_conv",
+                                "snap_ssm"))))
             if self._health is not None:
                 self._telemetry.record_health(
                     self._health.replica_block(self._replica_label))

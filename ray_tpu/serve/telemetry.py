@@ -198,8 +198,35 @@ def _engine_metrics() -> Dict[str, Any]:
                     "prompt tokens re-admitted from the host tier "
                     "via H2D copy instead of re-prefill",
                     tag_keys=tags),
+                "recurrent_state_bytes": Gauge(
+                    "serve_recurrent_state_bytes",
+                    "device bytes of a recurrent family's per-slot "
+                    "state and of its snapshot pool", tag_keys=tags),
+                "recurrent_snapshots": Gauge(
+                    "serve_recurrent_snapshots_resident",
+                    "snapshot entries that hold the state after some "
+                    "resident prompt prefix", tag_keys=tags),
+                "recurrent_snapshot_hits": Counter(
+                    "serve_recurrent_snapshot_hits_total",
+                    "admissions whose recurrent state started from a "
+                    "snapshot", tag_keys=tags),
+                "recurrent_snapshot_misses": Counter(
+                    "serve_recurrent_snapshot_misses_total",
+                    "admissions that matched resident K/V blocks but "
+                    "no snapshot of the state: prefilled in full",
+                    tag_keys=tags),
+                "recurrent_snapshot_evictions": Counter(
+                    "serve_recurrent_snapshot_evictions_total",
+                    "snapshot entries dropped, least recently used or "
+                    "with their block", tag_keys=tags),
             }
         return _metrics
+
+
+#: ``engine_stats()["recurrent"]`` of an engine without recurrent state
+EMPTY_RECURRENT = {"state_bytes": 0, "snapshots_resident": 0,
+                   "snapshot_hits": 0, "snapshot_misses": 0,
+                   "snapshot_evictions": 0}
 
 
 def _tracebus_enabled() -> bool:
@@ -505,6 +532,9 @@ class EngineTelemetry:
         #: pushes; same delta-tracking idiom for its restored counter
         self._kv_tier: Optional[Dict[str, Any]] = None
         self._kv_tier_restored_reported = 0
+        #: a recurrent family's state and snapshot counters
+        #: (kv_pager.StateSnapshots.stats); None for the other families
+        self._recurrent: Optional[Dict[str, int]] = None
         #: round-19 healthwatch block (serve/health.py) the deployment
         #: refreshes from its fleet HealthMonitor — zero-shaped when
         #: no monitor watches this engine (standalone / disabled)
@@ -1042,6 +1072,24 @@ class EngineTelemetry:
         if delta > 0:
             self._m["kv_tier_restored"].inc(delta, tags=self._tags)
 
+    def record_recurrent(self, block: Dict[str, int]) -> None:
+        """Latest ``StateSnapshots.stats()`` block of a recurrent
+        family's engine, mirrored into ``engine_stats()["recurrent"]``
+        and the ``serve_recurrent_*`` metrics (the counters advance by
+        the delta since the last push)."""
+        with self._lock:
+            before = self._recurrent or EMPTY_RECURRENT
+            self._recurrent = dict(block)
+        self._m["recurrent_state_bytes"].set(
+            int(block["state_bytes"]), tags=self._tags)
+        self._m["recurrent_snapshots"].set(
+            int(block["snapshots_resident"]), tags=self._tags)
+        for name in ("hits", "misses", "evictions"):
+            delta = block[f"snapshot_{name}"] - before[f"snapshot_{name}"]
+            if delta > 0:
+                self._m[f"recurrent_snapshot_{name}"].inc(
+                    delta, tags=self._tags)
+
     def record_health(self, block: Dict[str, Any]) -> None:
         """Latest healthwatch block (serve/health.py
         ``HealthMonitor.replica_block``) — mirrored into
@@ -1265,6 +1313,7 @@ class EngineTelemetry:
                         if self._kv_stats is not None else None)
             kv_scope = self._kv_scope
             kv_tier = self._kv_tier
+            recurrent = self._recurrent
             health = self._health_block
             spec = dict(self._spec)
             chunks = dict(self._chunks)
@@ -1329,6 +1378,10 @@ class EngineTelemetry:
             # block when no tier is configured, dense included)
             "kv_tier": (kv_tier if kv_tier is not None
                         else _empty_kv_tier()),
+            # a recurrent family's per-slot state and snapshot pool
+            # (zero-shaped for the families that keep K/V alone)
+            "recurrent": dict(recurrent if recurrent is not None
+                              else EMPTY_RECURRENT),
             # round-19: healthwatch — liveness state machine counters
             # (stable zero-shaped block when no HealthMonitor watches
             # this engine: standalone, dense, or RAYTPU_HEALTHWATCH=0)

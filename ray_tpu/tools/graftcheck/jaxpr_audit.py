@@ -57,7 +57,14 @@ Rules (ids as reported / suppressed):
   declares ``pool_layer_writes=2``, each tensor's layer written back
   into the carried pool, and a third is the pool stacked as the scan's
   ``ys`` beside it.  The one layer-sized ``dynamic-slice`` the gather
-  reads is allowed.
+  reads is allowed.  Where the cache also holds a recurrent family's
+  per-slot state and snapshot pool (``RECURRENT_STATE``), their bytes
+  must alias too, and no ``dynamic-slice`` or update of a whole
+  state's shape may be left: the state stacked as a scan's ``ys`` (a
+  second state beside the donated one) loses the alias or shows as one
+  of these.  Whole-state ``copy`` instructions are counted
+  (``state_copies``), not refused: the CPU compiler this audit uses
+  leaves some at a nested loop's edge that the TPU's does not.
 
 Sharded specs declare ``min_devices``; on hosts with fewer devices the
 spec is skipped with an info note instead of failing (tier-1 forces 8
@@ -403,7 +410,11 @@ _HLO_INSTRUCTION = re.compile(
     r"([\w\-]+)\((.*)$")
 
 
-def pool_moves(hlo: str, pool: Tuple[int, ...]):
+#: the cache entries of a recurrent family's state and snapshot pool
+RECURRENT_STATE = ("conv", "ssm", "snap_conv", "snap_ssm")
+
+
+def pool_moves(hlo: str, pool: Tuple[int, ...], whole_only: bool = False):
     """(opcode, instruction, dims moved) for every instruction of a
     compiled module's text (fused computations included) that
     materialises the K/V pool of shape `pool`, or one layer of it,
@@ -411,7 +422,7 @@ def pool_moves(hlo: str, pool: Tuple[int, ...]):
     pool, a ``dynamic-update-slice`` whose UPDATE is either (a layer
     written or stacked back).  The layer-sized ``dynamic-slice`` a
     gather reads and row-sized updates are not moves."""
-    big = (pool, pool[1:], (1,) + pool[1:])
+    big = (pool,) if whole_only else (pool, pool[1:], (1,) + pool[1:])
     found = []
     for line in hlo.splitlines():
         m = _HLO_INSTRUCTION.match(line)
@@ -442,21 +453,46 @@ def _check_pool_inplace(fn, args, spec) -> Tuple[List[Violation],
     pool = tuple(cache["k"].shape)
     pool_bytes = sum(cache[n].size * cache[n].dtype.itemsize
                      for n in ("k", "v"))
+    # a recurrent family's per-slot state and snapshot pool
+    # (models/jamba_decode.py): donated with the pool, held to the same
+    state = [cache[n] for n in RECURRENT_STATE if n in cache]
+    state_bytes = sum(a.size * a.dtype.itemsize for a in state)
     compiled = jax.jit(
         fn, donate_argnums=spec.donate_argnums).lower(*args).compile()
     out: List[Violation] = []
     ma = compiled.memory_analysis()
     info = {"alias_bytes": int(ma.alias_size_in_bytes),
             "temp_bytes": int(ma.temp_size_in_bytes),
-            "pool_bytes": int(pool_bytes)}
-    if info["alias_bytes"] < pool_bytes:
+            "pool_bytes": int(pool_bytes),
+            "state_bytes": int(state_bytes)}
+    if info["alias_bytes"] < pool_bytes + state_bytes:
         out.append(Violation(
             "pool-inplace",
             f"the compiled program aliases {info['alias_bytes']} bytes "
             f"of its arguments to its results, the K/V pool has "
-            f"{pool_bytes}: the pool is copied, not updated in place",
+            f"{pool_bytes} and the recurrent state {state_bytes}: one "
+            f"of them is copied, not updated in place",
             program=spec.name))
-    moves = list(pool_moves(compiled.as_text(), pool))
+    hlo = compiled.as_text()
+    info["state_copies"] = 0
+    for dims in sorted({tuple(a.shape) for a in state}):
+        for op, name, moved in pool_moves(hlo, dims, whole_only=True):
+            if op == "copy":
+                # not held against the program: this audit compiles for
+                # the CPU, whose compiler copies a buffer carried into a
+                # nested loop at the loop's edge, where the TPU's does
+                # not (the walk over layers of two kinds nests two
+                # scans; the TPU text holds no such copy: PERF.md, PR
+                # 28).  A state stacked as `ys` loses the alias above.
+                info["state_copies"] += 1
+                continue
+            out.append(Violation(
+                "pool-inplace",
+                f"compiled `{op}` {name} moves a {list(moved)} buffer, "
+                f"the shape of the recurrent state: it is materialised "
+                f"beside the state (a scan's stacked `ys`)",
+                program=spec.name))
+    moves = list(pool_moves(hlo, pool))
     layer_writes = [m for m in moves
                     if m[0] == "dynamic-update-slice" and m[2] != pool]
     info["pool_layer_writes"] = len(layer_writes)

@@ -278,6 +278,66 @@ def _build_gpt2_paged_prefill_bucket():
     return _build_gpt2_chunked_prefill(_BUCKET_T, _BUCKET_T - 3)
 
 
+def _jamba_paged_nano():
+    """A nano jamba of two periods (eight layers, the third of each four
+    attention: the pool has two layers, so a layer of it is not the
+    pool), its parameters and the serve engine's paged cache for it: the K/V
+    pool of the attention layers, the rows' recurrent state and the
+    snapshot pool (models/jamba_decode.py)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.jamba import jamba_config, jamba_init
+    from ray_tpu.models.jamba_decode import jamba_init_paged_cache
+
+    cfg = jamba_config("nano", n_layer=8, dtype=jnp.float32,
+                       use_flash=False, remat=False)
+    params = jamba_init(jax.random.PRNGKey(0), cfg)
+    bs = 16
+    per_row = cfg.max_seq // bs
+    cache = jamba_init_paged_cache(cfg, _PB,
+                                   num_blocks=1 + _PB * per_row,
+                                   block_size=bs)
+    cache["block_tables"] = 1 + jnp.arange(
+        _PB * per_row, dtype=jnp.int32).reshape(_PB, per_row)
+    # rows that hold a sequence: an empty row's state is not advanced
+    cache["pos"] = jnp.full((_PB,), 20, jnp.int32)
+    return cfg, params, cache, per_row
+
+
+def _build_jamba_paged_decode_step():
+    """The engine's decode step for the hybrid family: the K/V pool
+    read-only in the walk over layers (PagedKV, as for GPT-2), every
+    row's recurrent state read, advanced and written back one Mamba
+    layer at a time into the carried state, never stacked."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models.jamba_decode import jamba_decode_step
+
+    cfg, params, cache, _ = _jamba_paged_nano()
+    return (lambda p, c, t: jamba_decode_step(p, c, t, cfg),
+            (params, cache, jnp.zeros((_PB,), jnp.int32)))
+
+
+def _build_jamba_paged_prefill_bucket():
+    """Its one-shot paged prefill at bucket 64 with three pad columns,
+    one block resident, started from snapshot entry 0 and leaving the
+    state after 48 tokens in entry 1: the slot's state rows and one
+    snapshot entry are written where they lie."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models.jamba_decode import jamba_paged_prefill
+
+    cfg, params, cache, per_row = _jamba_paged_nano()
+    row_bt = 1 + jnp.arange(per_row, dtype=jnp.int32)
+    return (lambda p, c, t, bt, pl, nt, s, st: jamba_paged_prefill(
+        p, c, t, cfg, row_bt=bt, prefix_len=pl, n_tail=nt, slot=s,
+        state=st),
+        (params, cache, jnp.zeros((1, _BUCKET_T), jnp.int32), row_bt,
+         jnp.int32(16), jnp.int32(_BUCKET_T - 3), jnp.int32(0),
+         jnp.asarray([0, 1, 48], jnp.int32)))
+
+
 def _paged_nano_pool():
     """The serve engine's default nano paged pool (null block + one
     full chain per pooled row) with identity tables — shared by the
@@ -492,6 +552,26 @@ def default_programs() -> List[ProgramSpec]:
             hbm_budget_bytes=6 * _MiB,
             # K's and V's layer written back into the carried pool,
             # and no third: the pool is never the scan's stacked ys
+            donate_argnums=(1,), inplace_pool=1, pool_layer_writes=2),
+        ProgramSpec(
+            name="jamba_paged_decode_step",
+            build=_build_jamba_paged_decode_step,
+            forbid_logits=(_PB * 128, _NANO_VOCAB),  # B * max_seq rows
+            allow_f32_matmul=True,
+            # pool (2 attention layers' worth: a quarter of GPT-2
+            # nano's) + state and snapshots + per-layer views
+            hbm_budget_bytes=6 * _MiB,
+            # pool AND recurrent state donated and updated in place:
+            # the rule holds the state to it too (jaxpr_audit)
+            donate_argnums=(1,), inplace_pool=1),
+        ProgramSpec(
+            name="jamba_paged_prefill_bucket",
+            build=_build_jamba_paged_prefill_bucket,
+            forbid_logits=(128, _NANO_VOCAB),        # max_seq rows
+            # the scan over time is chunked: never a step a column
+            forbid_scan_lengths=(128, _BUCKET_T),
+            allow_f32_matmul=True,
+            hbm_budget_bytes=6 * _MiB,
             donate_argnums=(1,), inplace_pool=1, pool_layer_writes=2),
         ProgramSpec(
             name="gpt2_kv_handoff_export",

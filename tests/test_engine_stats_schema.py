@@ -29,7 +29,8 @@ TOP_KEYS = {
     "tokens_generated", "tokens_per_sec", "slot_utilization",
     "max_active_slots", "max_slots", "prefill_buckets",
     "prefill_compiles", "program_compiles", "rejections_by_reason",
-    "kv_cache", "kv_scope", "kv_tier", "spec", "slo", "flightrec",
+    "kv_cache", "kv_scope", "kv_tier", "recurrent", "spec", "slo",
+    "flightrec",
     "programs", "latency_anatomy", "prefill_chunks", "role", "handoff",
     "health", "phases",
 }
