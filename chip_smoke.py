@@ -96,7 +96,9 @@ class Size:
     cfg: Dict[str, Any] = dataclasses.field(default_factory=dict)
     seed: int = 0
     # kernel checks: (B, T, H, D) attention, (N, D, V, valid V) fused CE
-    attn_shape: tuple = (2, 1024, 12, 64)
+    #: the 124M step's heads, and one fsdp4 chip's share of the XL's
+    #: (3 rows x 25 heads: an odd number of heads a chip)
+    attn_shapes: tuple = ((2, 1024, 12, 64), (3, 1024, 25, 64))
     ce_shape: tuple = (2048, 768, 50304, 50257)
     # serve: bench.py's on-chip TrafficSpec cut to a few dozen requests
     requests: int = 32
@@ -160,21 +162,18 @@ def _rel_err(got, want) -> float:
 # train
 # ---------------------------------------------------------------------------
 
-def check_kernels(size: Size, *, interpret: bool = False) -> None:
-    """The Pallas kernels the train step is built from — and the two
-    that wait for their A/B (resident-kv flash, fused lm-head+CE) — each
-    forward and backward against its XLA reference.  `interpret` is
-    False on the chip; only a CPU test asks for the interpreter."""
+def _check_flash(shape, seed: int, interpret: bool) -> None:
+    """The default causal path (the triangle kernels at these shapes)
+    beside the classic and the resident-kv kernels, forward and
+    gradients against the XLA reference in float32."""
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.models.gpt2 import nll_from_logits
     from ray_tpu.ops.attention import reference_attention
     from ray_tpu.ops.flash_attention import flash_attention
-    from ray_tpu.ops.fused_ce import fused_lm_ce
 
-    B, T, H, D = size.attn_shape
-    kq, kk, kv, kw = jax.random.split(jax.random.PRNGKey(size.seed), 4)
+    B, T, H, D = shape
+    kq, kk, kv, kw = jax.random.split(jax.random.PRNGKey(seed), 4)
     q, k, v = (jax.random.normal(x, (B, T, H, D), jnp.bfloat16)
                for x in (kq, kk, kv))
     w = jax.random.normal(kw, (B, T, H, D), jnp.float32)
@@ -190,7 +189,8 @@ def check_kernels(size: Size, *, interpret: bool = False) -> None:
         want = run(lambda q, k, v: reference_attention(
             q.astype(jnp.float32), k.astype(jnp.float32),
             v.astype(jnp.float32)))
-    for name, resident in (("flash_classic", False),
+    for name, resident in (("flash_default", None),
+                           ("flash_classic", False),
                            ("flash_resident", True)):
         t0 = time.perf_counter()
         got = run(lambda q, k, v: flash_attention(
@@ -202,6 +202,22 @@ def check_kernels(size: Size, *, interpret: bool = False) -> None:
             err_o_dq_dk_dv=[round(e, 5) for e in errs],
             seconds=round(time.perf_counter() - t0, 2))
         assert max(errs) <= KERNEL_TOL, (name, errs)
+
+
+def check_kernels(size: Size, *, interpret: bool = False) -> None:
+    """The Pallas kernels the train step is built from — and those it
+    does not take by default (classic and resident-kv flash, fused
+    lm-head+CE) — each forward and backward against its XLA reference.
+    `interpret` is False on the chip; only a CPU test asks for the
+    interpreter."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.gpt2 import nll_from_logits
+    from ray_tpu.ops.fused_ce import fused_lm_ce
+
+    for shape in size.attn_shapes:
+        _check_flash(shape, size.seed, interpret)
 
     N, Dm, V, valid = size.ce_shape
     kh, kt, kg = jax.random.split(jax.random.PRNGKey(size.seed + 1), 3)
@@ -332,11 +348,12 @@ def phase_train(size: Size, platform: str = "tpu") -> Dict[str, Any]:
     compiled, _ = compile_step(step, params, opt_state, batch, watch,
                                "train")
     if platform == "tpu":
-        # the flash kernels are in the program: the XLA reference did
-        # not run in their place
+        # the flash kernels are in the program (the triangle forward
+        # and its one-pass backward): the XLA reference did not run in
+        # their place
         n_kernels = compiled.as_text().count(MOSAIC_CALL)
         say("train", tpu_custom_calls=n_kernels)
-        assert n_kernels >= 3, n_kernels
+        assert n_kernels >= 2, n_kernels
     losses = run_steps(step, params, opt_state, batch, size.steps)
     assert_losses_fall(losses)
     say("train", peak_hbm_bytes=_peak_hbm(jax.devices()[0]),
@@ -675,7 +692,7 @@ def phase_mesh_train(size: Size, platform: str = "tpu"
                 all_reduces=text.count("all-reduce("),
                 all_gathers=text.count("all-gather("))
             if platform == "tpu":
-                assert text.count(MOSAIC_CALL) >= 3
+                assert text.count(MOSAIC_CALL) >= 2
             got = run_steps(step, params, opt_state, batch, size.steps,
                             f"mesh_train:{name}")
         del params, opt_state

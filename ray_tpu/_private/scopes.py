@@ -58,8 +58,10 @@ DEVICE_SCOPES = frozenset((EMBED, ATTN, MLP, LN, LM_HEAD_CE, LM_HEAD,
 FLASH_FWD, FLASH_DQ, FLASH_DKV = "flash_fwd", "flash_dq", "flash_dkv"
 FLASH_RES_FWD, FLASH_RES_DQ, FLASH_RES_DKV = (
     "flash_res_fwd", "flash_res_dq", "flash_res_dkv")
+FLASH_TRI_FWD, FLASH_TRI_BWD = "flash_tri_fwd", "flash_tri_bwd"
 KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV,
-           FLASH_RES_FWD, FLASH_RES_DQ, FLASH_RES_DKV)
+           FLASH_RES_FWD, FLASH_RES_DQ, FLASH_RES_DKV,
+           FLASH_TRI_FWD, FLASH_TRI_BWD)
 
 # -- host phases -------------------------------------------------------------
 SPAN_PREFIX = "raytpu."

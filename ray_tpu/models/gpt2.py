@@ -112,8 +112,10 @@ class GPT2Config:
     #: (ops/fused_ce.py).
     ce_block_n: int = 256
     ce_block_v: int = 1024
-    #: resident-kv flash attention dispatch: "auto" = the measured
-    #: policy (ops/flash_attention._resident_plan), "on"/"off" force it.
+    #: flash attention kernel family: "auto" = the measured policy of
+    #: ops/flash_attention.flash_attention (triangle kernels at causal
+    #: T <= 2048, resident-kv past it), "on" forces the resident-kv
+    #: kernels, "off" the classic grid kernels.
     #: RAYTPU_FLASH_RESIDENT=1/0 in the env overrides the config — the
     #: process-wide A/B workflow keeps working.
     flash_resident: str = "auto"

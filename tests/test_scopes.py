@@ -374,9 +374,16 @@ def test_flash_kernels_carry_their_own_names():
                                    jnp.float32).sum()
 
     for resident, names in ((False, scopes.KERNELS[:3]),
-                            (True, scopes.KERNELS[3:])):
+                            (True, scopes.KERNELS[3:6])):
         jaxpr = str(jax.make_jaxpr(jax.grad(
             lambda q, k, v: loss(q, k, v, resident), argnums=(0, 1, 2)))(
                 q, q, q))
         for name in names:
             assert name in jaxpr, (resident, name)
+    # the default causal path at a T its tile divides: the triangle
+    # kernels, which say in the trace that they ran
+    q = jnp.ones((1, 256, 2, 64), jnp.bfloat16)
+    jaxpr = str(jax.make_jaxpr(jax.grad(
+        lambda q, k, v: loss(q, k, v, None), argnums=(0, 1, 2)))(q, q, q))
+    for name in scopes.KERNELS[6:]:
+        assert name in jaxpr, name

@@ -79,13 +79,17 @@ def _kernels_in(fn, *args, mesh=None) -> int:
         'custom_call_target="tpu_custom_call"')
 
 
-@pytest.mark.parametrize("resident", [False, True],
-                         ids=["classic", "resident_kv"])
-def test_flash_attention_fwd_bwd_compiles(resident):
-    """ray_tpu.ops.flash_attention at the train step's shape."""
+@pytest.mark.parametrize("shape", [(24, 1024, 12, 64), (3, 1024, 25, 64)],
+                         ids=["124m_b24_h12", "xl_fsdp4_chip_b3_h25"])
+@pytest.mark.parametrize("resident", [None, False, True],
+                         ids=["default_triangle", "classic", "resident_kv"])
+def test_flash_attention_fwd_bwd_compiles(resident, shape):
+    """ray_tpu.ops.flash_attention at the train cells' shapes: what a
+    call takes by default (the triangle kernels) and the two families
+    `flash_resident` still reaches."""
     from ray_tpu.ops.flash_attention import flash_attention
 
-    x = _one_chip()((24, 1024, 12, 64), jnp.bfloat16)
+    x = _one_chip()(shape, jnp.bfloat16)
 
     def fwd_bwd(q, k, v):
         def loss(q, k, v):
@@ -93,8 +97,29 @@ def test_flash_attention_fwd_bwd_compiles(resident):
                                    ).astype(jnp.float32).sum()
         return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
 
-    # forward + the dq and dk/dv backward kernels
-    assert _kernels_in(fwd_bwd, x, x, x) >= 3
+    # forward + backward (one pass, or the dq and dk/dv kernels)
+    assert _kernels_in(fwd_bwd, x, x, x) >= 2
+
+
+@pytest.mark.parametrize("T,D,dtype", [(2048, 64, jnp.bfloat16),
+                                       (2048, 128, jnp.float32),
+                                       (256, 64, jnp.bfloat16)],
+                         ids=["T2048_D64_bf16", "T2048_D128_f32",
+                              "T256_D64_bf16"])
+def test_triangle_kernels_compile_at_the_edges_of_their_gate(T, D, dtype):
+    """The largest head `_triangle_plan` lets through (a head whole in
+    VMEM, float32 accumulators beside it) and the smallest."""
+    from ray_tpu.ops.flash_attention import _triangle_plan, flash_attention
+
+    assert _triangle_plan(T, D, dtype, True) is not None
+    x = _one_chip()((2, T, 4, D), dtype)
+
+    def fwd_bwd(q, k, v):
+        def loss(q, k, v):
+            return flash_attention(q, k, v).astype(jnp.float32).sum()
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    assert _kernels_in(fwd_bwd, x, x, x) >= 2
 
 
 def test_fused_lm_ce_fwd_bwd_compiles():
@@ -174,4 +199,5 @@ def test_sharded_attention_compiles_on_four_devices():
                                     ).astype(jnp.float32).sum()
         return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
 
-    assert _kernels_in(fwd_bwd, x, x, x, mesh=mesh) >= 3
+    # the triangle forward and its one-pass backward
+    assert _kernels_in(fwd_bwd, x, x, x, mesh=mesh) >= 2
