@@ -112,7 +112,7 @@ STATIC_PROGRAM_MAP: Dict[str, str] = {
     "gpt2_paged_prefill_bucket": "serve.paged_prefill",
     # disaggregated prefill/decode handoff: the export gather on the
     # prefill replica and the donated install splice on the decode
-    # replica (serve/llm.py kv_handoff_* programs)
+    # replica (serve/engine.py kv_handoff_* programs)
     "gpt2_kv_handoff_export": "serve.kv_handoff_export",
     "gpt2_kv_handoff_install": "serve.kv_handoff_install",
 }

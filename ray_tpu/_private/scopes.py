@@ -65,7 +65,7 @@ KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV,
 
 # -- host phases -------------------------------------------------------------
 SPAN_PREFIX = "raytpu."
-ENGINE = "engine"            # layer of serve/llm.py's scheduler loop
+ENGINE = "engine"            # layer of serve/engine.py's scheduler loop
 STEP = "step"                # one loop iteration with work in it
 LOOP = "loop"                # a step's own bookkeeping between two phases
 #: leaf phases of one ``raytpu.engine.step``; they partition it

@@ -48,10 +48,16 @@ it (``snap_conv``, ``snap_ssm``).  Its paged prefill takes one more
 argument, ``state`` int32 (3,) = [source, snapshot entry, snapshot
 boundary]; the constants below are its vocabulary, and the engine's.
 
-A cache handed to a jitted ENGINE program (serve/llm.py) is consumed:
-those programs donate it, the result is the same buffers updated, and
-the caller rebinds.  The functions here are pure; donation is the
-caller's choice, and what makes `PagedKV` write in place.
+What a cache is made of (its keys, which axis of each tensor is the
+slot or the block, what a block weighs) is written in this module and
+nowhere else: the serving engine moves rows, blocks and state through
+the cache operations below (`admit` ... `state_bytes`) and never
+indexes the pytree itself.
+
+A cache handed to a jitted ENGINE program (serve/engine_programs.py) is
+consumed: those programs donate it, the result is the same buffers
+updated, and the caller rebinds.  The functions here are pure; donation
+is the caller's choice, and what makes `PagedKV` write in place.
 """
 
 from __future__ import annotations
@@ -240,20 +246,32 @@ class PagedKV:
         return dict(self.cache, k=pools[0], v=pools[1])
 
 
+_HEADS = (None, None, None, "heads", "head_dim")
+#: every tensor a cache may hold beside its position vectors and block
+#: tables: the axis its slots lie on (dense K/V rows and state rows; a
+#: paged pool has its BLOCKS there), and the logical axes it shards by
+#: (None: replicated, a recurrent state has no sharding rule yet).  The
+#: heads axis sits at index 3 in BOTH K/V layouts, dense
+#: (L, B, S, H, hd) and paged (L, num_blocks, bs, H, hd), so one
+#: annotation serves both, and under DECODE_RULES only that dim splits
+#: (over `tensor`).  A state tensor's snapshot pool is "snap_" + name,
+#: same axes, one entry a slot.
+_TENSORS = {"k": (1, _HEADS), "v": (1, _HEADS),
+            "ssm": (1, None), "conv": (2, None)}
+_KV = ("k", "v")
+_STATE = tuple(n for n in _TENSORS if n not in _KV)
+_SNAP = "snap_"
+
+
 def cache_logical_axes(cache):
     """Logical-axis pytree matching a decode cache, dense or paged.
-    The heads axis sits at index 3 in BOTH layouts — dense K/V is
-    (L, B, S, H, hd), the paged pool is (L, num_blocks, bs, H, hd) —
-    so one annotation serves both, and under DECODE_RULES only that
-    dim splits (over `tensor`).  pos/start/block_tables stay
-    replicated: they are the host scheduler's view of the pool and
-    must be readable without collectives."""
-    axes = {"k": (None, None, None, "heads", "head_dim"),
-            "v": (None, None, None, "heads", "head_dim"),
-            "pos": (None,), "start": (None,)}
-    if "block_tables" in cache:
-        axes["block_tables"] = (None, None)
-    return axes
+    pos/start/block_tables stay replicated: they are the host
+    scheduler's view of the pool and must be readable without
+    collectives."""
+    def axes(name):
+        _, logical = _TENSORS.get(name.removeprefix(_SNAP), (None, None))
+        return logical or (None,) * cache[name].ndim
+    return {name: axes(name) for name in cache}
 
 
 def cache_shardings(cache, mesh, rules=None):
@@ -299,7 +317,7 @@ def dense_to_paged(cache, block_size: int):
                          f"block_size={block_size}")
     nb = S // block_size
     out = dict(cache)
-    for name in ("k", "v"):
+    for name in _KV:
         pool = cache[name].reshape(L, B * nb, block_size, *tail)
         null = jnp.zeros((L, 1, block_size, *tail), pool.dtype)
         out[name] = jnp.concatenate([null, pool], axis=1)
@@ -316,10 +334,124 @@ def copy_block(cache, src, dst):
     src = jnp.asarray(src, jnp.int32)
     dst = jnp.asarray(dst, jnp.int32)
     out = dict(cache)
-    for name in ("k", "v"):
+    for name in _KV:
         pool = cache[name]                 # (L, num_blocks, bs, ...)
         out[name] = pool.at[:, dst].set(pool[:, src])
     return out
+
+
+def admit(pool, row, slot):
+    """A prefilled one-sequence cache `row` into row `slot` of a DENSE
+    pool (the engine's, or a draft model's): its K/V rows, a recurrent
+    family's state rows beside them, its positions."""
+    out = dict(pool)
+    for name, (axis, _) in _TENSORS.items():
+        if name in pool:
+            out[name] = lax.dynamic_update_slice_in_dim(
+                pool[name], row[name], slot, axis=axis)
+    for name in ("pos", "start"):
+        out[name] = lax.dynamic_update_slice_in_dim(
+            pool[name], row[name], slot, axis=0)
+    return out
+
+
+def clear_row(cache, slot):
+    """Retire a paged row: its table points at the null block, so the
+    (masked, unread) writes of an idle row can never land in a block
+    the pager has handed to someone else."""
+    out = dict(cache)
+    out["block_tables"] = cache["block_tables"].at[slot].set(0)
+    out["pos"] = cache["pos"].at[slot].set(0)
+    return out
+
+
+def restore_state(cache, entry, slot):
+    """Row `slot`'s recurrent state becomes snapshot `entry`'s, NOW: a
+    chunked admission that hit a snapshot runs its chunks later, and
+    the entry may be another prefix's by then."""
+    out = dict(cache)
+    for name in _STATE:
+        axis = _TENSORS[name][0]
+        out[name] = lax.dynamic_update_slice_in_dim(
+            cache[name], lax.dynamic_slice_in_dim(
+                cache[_SNAP + name], entry, 1, axis=axis),
+            slot, axis=axis)
+    return out
+
+
+def install_blocks(cache, blk_ids, k_stack, v_stack):
+    """Splice block rows into the pool in ONE dispatch (a host-tier
+    restore, serve/kv_tier.py; a handoff's arrival).  blk_ids is a
+    fixed-length (max_seq // block_size) id vector and the stacks are
+    (N, L, block_size, H, head_dim) rows (`block_rows`), so every
+    splice shares one compiled program whatever the chain's length.
+    Padding entries target the null block (id 0): block 0 is the masked
+    write-sink idle rows already scribble into, so the pad write is
+    harmless by the same contract.  On a sharded pool the committed
+    cache shardings re-distribute the replicated rows."""
+    out = dict(cache)
+    for name, stack in zip(_KV, (k_stack, v_stack)):
+        out[name] = cache[name].at[:, blk_ids].set(stack.swapaxes(0, 1))
+    return out
+
+
+def save_block(cache, blk):
+    """One block's (K rows, V rows) out of the pool together: an
+    eviction's spill costs one dispatch and one D2H transfer pair."""
+    return tuple(cache[name][:, blk] for name in _KV)
+
+
+def kv_handoff_export(cache, blk_ids):
+    """The read twin of `install_blocks`: gather a finished prefill's
+    block rows, (K stack, V stack) as `install_blocks` takes them, by
+    the same fixed-length id vector.  Pad entries (id 0) gather the
+    null block's garbage rows; they install back into the null block
+    on the other side."""
+    return tuple(cache[name][:, blk_ids].swapaxes(0, 1) for name in _KV)
+
+
+def kv_handoff_install(cache, blk_ids, k_stack, v_stack, slot, row_bt, pos):
+    """`install_blocks`, and row `slot` pointed at the rows in the same
+    dispatch: its block table, `pos` (the prompt's length, what
+    paged_prefill leaves behind: prefix_len + n_tail) and start 0 like
+    every paged admission.  The row is decode-ready the moment the
+    program retires, and its first decode step reads exactly the rows
+    the prefill replica wrote."""
+    out = install_blocks(cache, blk_ids, k_stack, v_stack)
+    out["block_tables"] = cache["block_tables"].at[slot].set(row_bt)
+    out["pos"] = cache["pos"].at[slot].set(pos)
+    out["start"] = cache["start"].at[slot].set(0)
+    return out
+
+
+def block_bytes(cache) -> int:
+    """K+V bytes of one block of a paged cache, over all the layers the
+    pool holds (a hybrid's pool holds its attention layers only)."""
+    return sum(cache[name].nbytes // cache[name].shape[1] for name in _KV)
+
+
+def block_rows(cache, n: int) -> jax.ShapeDtypeStruct:
+    """Shape and dtype of `n` blocks' rows of K (V's are the same), as
+    `kv_handoff_export` returns and `install_blocks` takes them."""
+    L, _, *row = cache["k"].shape
+    return jax.ShapeDtypeStruct((n, L, *row), cache["k"].dtype)
+
+
+def kv_shards(cache) -> int:
+    """How many ways the K/V tensors' heads axis is split over the
+    devices the cache lives on: 1 on one device, and where the head
+    count does not divide the mesh's tensor degree (`cache_shardings`
+    replicates it then)."""
+    k = cache["k"]
+    return k.shape[3] // k.sharding.shard_shape(k.shape)[3]
+
+
+def state_bytes(cache) -> int:
+    """Bytes of the recurrent state a cache holds beside its K/V: every
+    slot's, and the snapshot pool's (0 for a family that keeps none)."""
+    return sum(cache[name].nbytes
+               for state in _STATE for name in (state, _SNAP + state)
+               if name in cache)
 
 
 def make_vocab_tail_mask(cfg) -> Optional[jnp.ndarray]:

@@ -1,0 +1,103 @@
+"""The decoder families a serving engine can be built over: one row each.
+
+A family is `models/<family>.py` (config, init, forward), its decode
+programs in `models/<family>_decode.py`, and a row of `FAMILIES` below.
+The serving layer (serve/llm.py, serve/engine.py) asks `family(name)`
+for the programs and `cache_kind(name)` for what the cache holds, and
+names no family itself.
+
+A row's loader imports its modules when it is called, not when this
+module is: building a GPT-2 engine never imports the Jamba decoder.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Callable, Dict, Optional, Tuple
+
+#: what a family's cache holds.  KV: a slot's past is its K/V rows,
+#: which every engine feature can move (rewind by position, spill and
+#: restore by block, hand off).  RECURRENT: some layers keep one state
+#: per sequence beside the K/V (models/jamba_decode.py), and the paged
+#: prefill takes one more argument, `state` (decode_common.py: where the
+#: slot's state starts and which snapshot it leaves); what cannot carry
+#: that state yet is refused when the engine's options are checked.
+KV = "kv"
+RECURRENT = "kv+recurrent"
+
+
+@dataclasses.dataclass(frozen=True)
+class Family:
+    """One family's programs, as the engine calls them.  `verify` is
+    None where the family has no spec-decode verify program."""
+    name: str
+    cache_kind: str
+    config: Callable[..., Any]
+    init: Callable[..., Any]
+    logical_axes: Callable[..., Any]
+    generate: Callable[..., Any]
+    prefill: Callable[..., Any]
+    paged_prefill: Callable[..., Any]
+    step: Callable[..., Any]
+    verify: Optional[Callable[..., Any]]
+    init_cache: Callable[..., Any]
+    init_paged_cache: Callable[..., Any]
+
+
+def _gpt2() -> Dict[str, Any]:
+    from ray_tpu.models import gpt2_decode as m
+    from ray_tpu.models.gpt2 import (gpt2_config, gpt2_init,
+                                     gpt2_logical_axes)
+
+    return dict(
+        config=gpt2_config, init=gpt2_init,
+        logical_axes=gpt2_logical_axes, generate=m.generate,
+        prefill=m.prefill, paged_prefill=m.paged_prefill,
+        step=m.decode_step, verify=m.verify_step,
+        init_cache=m.init_cache, init_paged_cache=m.init_paged_cache)
+
+
+def _llama() -> Dict[str, Any]:
+    from ray_tpu.models import llama_decode as m
+    from ray_tpu.models.llama import (llama_config, llama_init,
+                                      llama_logical_axes)
+
+    return dict(
+        config=llama_config, init=llama_init,
+        logical_axes=llama_logical_axes, generate=m.llama_generate,
+        prefill=m.llama_prefill, paged_prefill=m.llama_paged_prefill,
+        step=m.llama_decode_step, verify=m.llama_verify_step,
+        init_cache=m.llama_init_cache,
+        init_paged_cache=m.llama_init_paged_cache)
+
+
+def _jamba() -> Dict[str, Any]:
+    from ray_tpu.models import jamba_decode as m
+    from ray_tpu.models.jamba import (jamba_config, jamba_init,
+                                      jamba_logical_axes)
+
+    return dict(
+        config=jamba_config, init=jamba_init,
+        logical_axes=jamba_logical_axes, generate=m.jamba_generate,
+        prefill=m.jamba_prefill, paged_prefill=m.jamba_paged_prefill,
+        step=m.jamba_decode_step, verify=None,
+        init_cache=m.jamba_init_cache,
+        init_paged_cache=m.jamba_init_paged_cache)
+
+
+#: family -> (what its cache holds, loader of its programs)
+FAMILIES: Dict[str, Tuple[str, Callable[[], Dict[str, Any]]]] = {
+    "gpt2": (KV, _gpt2), "llama": (KV, _llama),
+    "jamba": (RECURRENT, _jamba)}
+
+
+def cache_kind(name: str) -> Optional[str]:
+    """What the named family's cache holds, without loading it; None
+    for a name that is no family."""
+    return FAMILIES.get(name, (None,))[0]
+
+
+def family(name: str) -> Family:
+    """The named family's adapter (its modules are imported now)."""
+    kind, load = FAMILIES[name]
+    return Family(name=name, cache_kind=kind, **load())

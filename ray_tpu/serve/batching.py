@@ -28,7 +28,7 @@ from typing import Any, Callable, List, Optional
 
 @dataclass
 class ChunkCursor:
-    """Progress cursor for chunked streaming prefill (serve/llm.py):
+    """Progress cursor for chunked streaming prefill (serve/engine.py):
     a queued long prompt is admitted once but filled over several
     block-aligned ``paged_prefill`` calls interleaved with decode
     waves, and the engine's slot record carries this cursor between
@@ -62,7 +62,7 @@ class ChunkCursor:
 @dataclass
 class HandoffCursor:
     """State of one disaggregated prefill→decode KV handoff
-    (serve/llm.py + serve/router.py two-stage dispatch): a prefill
+    (serve/engine.py + serve/router.py two-stage dispatch): a prefill
     replica that finishes a request's last chunk resolves its future
     with this cursor instead of generated tokens, and the router
     forwards it to the chosen decode replica, whose admission path
@@ -155,7 +155,7 @@ class _BatchQueue:
 
 class RequestQueue:
     """FIFO admission queue for slot-based continuous batching
-    (serve/llm.py): callers enqueue one request and await its future;
+    (serve/engine.py): callers enqueue one request and await its future;
     the scheduler pops up to n pending requests whenever cache slots
     free up.  The complement of @serve.batch — that collects FIXED
     batches and runs them to completion, this hands out work as

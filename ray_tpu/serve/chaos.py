@@ -135,7 +135,7 @@ class ChaosInjector:
         if self._monitor is not None:
             self._monitor.note_fault(replica, kind=kind)
 
-    # -- engine-loop hooks (serve/llm.py _engine) ----------------------
+    # -- engine-loop hooks (serve/engine.py _engine) ----------------------
 
     def frozen(self, replica: str) -> bool:
         """Is this wave frozen for `replica`?  True for

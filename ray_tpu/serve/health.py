@@ -11,7 +11,7 @@ machine over engine-loop heartbeats:
     any     --heartbeat resumes----------------> HEALTHY (recovered)
 
 * **Heartbeats** — every wave of the continuous engine loop
-  (serve/llm.py ``_engine``) stamps a ``perf_counter`` heartbeat; an
+  (serve/engine.py ``_engine``) stamps a ``perf_counter`` heartbeat; an
   idle-parked loop declares itself idle instead (an idle replica has
   no outstanding work, so a stale heartbeat there is not a failure).
 * **Stall detection** — a request that was admitted but has been

@@ -1,5 +1,5 @@
 """Host-side block manager for the paged KV cache (the data plane
-under serve/llm.py's continuous scheduler).
+under serve/engine.py's continuous scheduler).
 
 The jitted decode programs see only a preallocated block pool and
 per-row block tables (decode_common paged contract); everything that
@@ -376,7 +376,7 @@ class BlockPager:
     def note_fill(self, tokens: int, partial: bool = False) -> None:
         """Journal one prefill chunk writing `tokens` token slots into
         this pager's reserved blocks (chunked streaming prefill —
-        serve/llm.py calls this per chunk).  `partial=True` marks an
+        serve/engine.py calls this per chunk).  `partial=True` marks an
         intermediate chunk: the row still has unfilled tail blocks and
         is parked until its next chunk window.  Pure accounting — the
         blocks were allocated at admission and ownership is unchanged;

@@ -57,7 +57,7 @@ def staging_buffers(maxn: int, row_shape: Tuple[int, ...],
                                     np.ndarray]:
     """Persistent host staging triple ``(ids, k_rows, v_rows)`` for
     fixed-shape block-splice dispatches: the tier restore path and the
-    disaggregated handoff's staged D2H→H2D hop (serve/llm.py) both
+    disaggregated handoff's staged D2H→H2D hop (serve/engine.py) both
     refill these in place per transfer instead of re-allocating pad
     arrays.  ``maxn`` is the id-vector length (max_seq // block_size)
     and ``row_shape`` the stacked per-block row shape the engine's

@@ -1,7 +1,7 @@
 """Fleet control plane: prefix-aware routing, per-tenant weighted
 fair queueing, and SLO-driven autoscaling over N continuous engines.
 
-One continuous-batching engine (serve/llm.py) cannot serve heavy
+One continuous-batching engine (serve/engine.py) cannot serve heavy
 traffic alone; this module composes N of them into a horizontally
 scalable fleet behind one router, in the shape of Ray Serve's
 controller/router split (reference: serve controller.py ServeController

@@ -1,6 +1,6 @@
 """Engine telemetry for the LM serving hot path.
 
-Every request through ``serve/llm.py`` carries a lifecycle record —
+Every request through ``serve/engine.py`` carries a lifecycle record —
 enqueue → admit → prefill-done (first token) → per-decode-step →
 finish / reject — and the continuous-batching engine reports each
 transition here.  Three sinks hang off those records:

@@ -153,7 +153,7 @@ def _build_gpt2_paged_decode_step():
                       remat=False)
     params = gpt2_init(jax.random.PRNGKey(0), cfg)
     # null block + one full sequence of blocks per pooled row — the
-    # serve engine's default sizing (llm.py _init_continuous)
+    # serve engine's default sizing (LLMEngine._init_scheduler)
     bs = 16
     per_row = cfg.max_seq // bs
     cache = init_paged_cache(cfg, _PB, num_blocks=1 + _PB * per_row,
@@ -368,14 +368,11 @@ def _build_gpt2_kv_handoff_export():
     replica's steady-state footprint on every handoff."""
     import jax.numpy as jnp
 
+    from ray_tpu.models.decode_common import kv_handoff_export
+
     cache, per_row = _paged_nano_pool()
     ids = jnp.zeros((per_row,), jnp.int32)
-
-    def export(c, blk_ids):
-        return (c["k"][:, blk_ids].swapaxes(0, 1),
-                c["v"][:, blk_ids].swapaxes(0, 1))
-
-    return export, (cache, ids)
+    return kv_handoff_export, (cache, ids)
 
 
 def _build_gpt2_kv_handoff_install():
@@ -388,24 +385,16 @@ def _build_gpt2_kv_handoff_install():
     afford on the decode fleet."""
     import jax.numpy as jnp
 
+    from ray_tpu.models.decode_common import (block_rows,
+                                              kv_handoff_install)
+
     cache, per_row = _paged_nano_pool()
     ids = jnp.zeros((per_row,), jnp.int32)
-    row_shape = (per_row,) + cache["k"][:, 0].shape
-    k_stack = jnp.zeros(row_shape, cache["k"].dtype)
-    v_stack = jnp.zeros(row_shape, cache["v"].dtype)
+    rows = block_rows(cache, per_row)
+    stack = jnp.zeros(rows.shape, rows.dtype)
     row_bt = jnp.zeros((per_row,), jnp.int32)
-
-    def install(c, blk_ids, ks, vs, slot, bt, pos):
-        out = dict(c)
-        out["k"] = c["k"].at[:, blk_ids].set(ks.swapaxes(0, 1))
-        out["v"] = c["v"].at[:, blk_ids].set(vs.swapaxes(0, 1))
-        out["block_tables"] = c["block_tables"].at[slot].set(bt)
-        out["pos"] = c["pos"].at[slot].set(pos)
-        out["start"] = c["start"].at[slot].set(0)
-        return out
-
-    return install, (cache, ids, k_stack, v_stack, jnp.int32(0),
-                     row_bt, jnp.int32(48))
+    return kv_handoff_install, (cache, ids, stack, stack, jnp.int32(0),
+                                row_bt, jnp.int32(48))
 
 
 def _ce_inputs():

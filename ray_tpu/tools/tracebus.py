@@ -259,7 +259,7 @@ def build_request_spans(req: Dict[str, Any]) -> List[Dict[str, Any]]:
         else:
             emit("engine.prefill", admit, first,
                  bucket=req.get("bucket"), slot=req.get("slot"))
-    # disaggregated handoff (serve/llm.py role-split fleets): the
+    # disaggregated handoff (serve/engine.py role-split fleets): the
     # block move from prefill replica to decode replica — export
     # start through install fence, between the prefill and decode
     # legs, matching the handoff_ms critical-path component

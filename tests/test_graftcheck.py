@@ -81,8 +81,8 @@ def test_repo_sharded_spec_ran_compiled_rules(repo_report):
 
 
 def test_repo_suppressions_are_visible(repo_report):
-    # serve/llm.py carries deliberate host fences behind disable
-    # comments; the report must surface (not hide) that they exist
+    # serve/llm.py and serve/engine.py carry deliberate host fences behind
+    # disable comments; the report must surface (not hide) that they exist
     # (round 11 moved the finish-path fence into a sync helper, so
     # the count dropped from 7 to 6; PR 28 moved the decode wave's
     # fence into one, `_land_wave`: 5)

@@ -294,8 +294,8 @@ def test_chaos_freeze_detected_requeued_and_oracle_identical(
         tmp_path, capsys, monkeypatch):
     # a one-chip host: both replicas share device 0 and its compiled
     # programs.  On distinct devices each replica stalls in its own
-    # first compile, which the 30/90 ms thresholds below read as
-    # deaths of their own, before and between the injected one.
+    # first compile, which the thresholds below read as deaths of
+    # their own, before and between the injected one.
     one_chip = jax.local_devices()[:1]
     monkeypatch.setattr(jax, "local_devices", lambda: one_chip)
     rng = np.random.RandomState(7)
@@ -303,10 +303,18 @@ def test_chaos_freeze_detected_requeued_and_oracle_identical(
     prompts = [rng.randint(2, 500, 24).astype(np.int32)
                for _ in range(12)]
 
-    health = HealthConfig(suspect_ms=30.0, dead_ms=90.0,
+    # A stale replica is SUSPECT from 30 ms and DEAD from a second.
+    # The window between the two is what must not depend on the box: a
+    # sweep (one a ping below, 20 ms apart on an idle box) that finds
+    # the frozen replica's beat already older than dead_ms takes it
+    # from HEALTHY straight to DEAD.  With dead_ms at 90 the window
+    # was 60 ms, and beside a busy neighbour two sweeps lie further
+    # apart than that: SUSPECT was skipped in every run.  The freeze
+    # outlasts dead_ms whatever the box does (500 polls of >= 5 ms).
+    health = HealthConfig(suspect_ms=30.0, dead_ms=1000.0,
                           stall_ms=60_000.0, probe_ms=1.0)
     chaos = ChaosConfig(seed=0, freeze_replica=1, freeze_after_waves=2,
-                        freeze_waves=150, freeze_poll_ms=5.0)
+                        freeze_waves=500, freeze_poll_ms=5.0)
     # unreachable-fast TTFT target: the freeze window burns the SLO,
     # giving the incident report a burn window to name
     slo = SLOConfig(ttft_ms=5.0, e2e_ms=600_000.0, objective=0.5,

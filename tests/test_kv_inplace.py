@@ -371,7 +371,7 @@ def _session_engine(**kw):
     from ray_tpu.serve.llm import build_llm_deployment
 
     # a temperature no other test uses: this engine's programs are its
-    # own entry in serve/llm.py's _JIT_CACHE
+    # own entry in serve/engine_programs.py's _JIT_CACHE
     return build_llm_deployment(
         "gpt2", "nano", max_new_tokens=3, temperature=0.0,
         top_k=0, top_p=1.0, scheduler="continuous", kv_layout="paged",

@@ -285,21 +285,20 @@ def test_continuous_engine_two_waves_under_mesh(family, mesh):
 def test_jit_cache_keyed_by_layout_and_mesh(mesh):
     """Regression (round-9 satellite): equal-config engines differing
     only in kv_layout or mesh must NOT share jitted programs."""
+    from ray_tpu.models.families import family
     from ray_tpu.serve.llm import _jitted_engine_fns
 
-    from ray_tpu.models.gpt2_decode import (decode_step, paged_prefill,
-                                            prefill)
-
+    gpt2 = family("gpt2")
     cfg = gpt2_config("nano", **_OVR)
-    base = _jitted_engine_fns(prefill, decode_step, paged_prefill,
-                              cfg, 0.0, kv_layout="dense", mesh=None)
-    paged = _jitted_engine_fns(prefill, decode_step, paged_prefill,
-                               cfg, 0.0, kv_layout="paged", mesh=None)
-    meshed = _jitted_engine_fns(prefill, decode_step, paged_prefill,
-                                cfg, 0.0, kv_layout="paged", mesh=mesh)
+    base = _jitted_engine_fns(gpt2, cfg, 0.0, kv_layout="dense",
+                              mesh=None)
+    paged = _jitted_engine_fns(gpt2, cfg, 0.0, kv_layout="paged",
+                               mesh=None)
+    meshed = _jitted_engine_fns(gpt2, cfg, 0.0, kv_layout="paged",
+                                mesh=mesh)
     assert base is not paged
     assert paged is not meshed
     # same identity -> same cached tuple (the cache still works)
-    again = _jitted_engine_fns(prefill, decode_step, paged_prefill,
-                               cfg, 0.0, kv_layout="paged", mesh=mesh)
+    again = _jitted_engine_fns(family("gpt2"), cfg, 0.0,
+                               kv_layout="paged", mesh=mesh)
     assert again is meshed

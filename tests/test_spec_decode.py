@@ -315,15 +315,14 @@ def test_jitted_fns_cache_keyed_by_sampling_and_spec():
     bare float temperature (the pre-round-11 call shape) still hits
     the same cache entry as its SamplingParams equivalent."""
     from ray_tpu.models import gpt2_config
-    from ray_tpu.models.gpt2_decode import (decode_step, paged_prefill,
-                                            prefill, verify_step)
+    from ray_tpu.models.families import family
     from ray_tpu.serve.llm import _jitted_engine_fns
 
     cfg = gpt2_config("nano", **_OVR)
 
     def fns(sampling, **kw):
-        return _jitted_engine_fns(prefill, decode_step, paged_prefill,
-                                  cfg, sampling, **kw)
+        # a fresh adapter each call: the key is its programs, not it
+        return _jitted_engine_fns(family("gpt2"), cfg, sampling, **kw)
 
     base = fns(0.0)
     assert fns(0.0) is base                     # cache hit
@@ -333,13 +332,13 @@ def test_jitted_fns_cache_keyed_by_sampling_and_spec():
     assert fns(SamplingParams(temperature=0.7, top_p=0.9)) \
         is not fns(SamplingParams(temperature=0.7))
 
-    k2 = fns(0.0, spec=SpecConfig(k=2), verify_fn=verify_step)
-    k4 = fns(0.0, spec=SpecConfig(k=4), verify_fn=verify_step)
+    k2 = fns(0.0, spec=SpecConfig(k=2))
+    k4 = fns(0.0, spec=SpecConfig(k=4))
     assert k2 is not base and k4 is not base and k2 is not k4
     assert k2.spec_verify is not None
     assert base.spec_verify is None
     # same spec -> same entry (SpecConfig is hashable by value)
-    assert fns(0.0, spec=SpecConfig(k=2), verify_fn=verify_step) is k2
+    assert fns(0.0, spec=SpecConfig(k=2)) is k2
 
 
 # ---------------------------------------------------------------------------
