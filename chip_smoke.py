@@ -47,6 +47,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import json
 import os
 import signal
@@ -74,6 +75,12 @@ LOSS_MESH_RTOL = 5e-3
 MESH_STEPS = 3
 #: kernel output/gradient vs XLA reference, max error over max magnitude
 KERNEL_TOL = 3e-2
+#: selective-scan kernel vs the XLA chain, float32 both, max error over
+#: max magnitude of outputs and states: the same operations on the
+#: state, one sum over d_state associated otherwise (the chip read
+#: 4.8e-8 on outputs and 0 on both states at T = 1,024, PR 31;
+#: tests/test_ssm_scan.py holds the CPU's 2.4e-7)
+SCAN_TOL = 1e-5
 #: where greedy tokens part from the oracle's, the oracle's own logit for
 #: the engine's token must be this close to its maximum: a bf16 near-tie,
 #: which seeded weights with nearly flat logits (std ~0.55, top-two gap
@@ -100,6 +107,9 @@ class Size:
     #: (3 rows x 25 heads: an odd number of heads a chip)
     attn_shapes: tuple = ((2, 1024, 12, 64), (3, 1024, 25, 64))
     ce_shape: tuple = (2048, 768, 50304, 50257)
+    #: (B, T, d_inner, d_state) of the selective scan: Jamba2-3B's
+    #: published widths at a middle and at the largest prefill bucket
+    scan_shapes: tuple = ((1, 640, 5120, 16), (1, 1024, 5120, 16))
     # serve: bench.py's on-chip TrafficSpec cut to a few dozen requests
     requests: int = 32
     #: warm-up requests of the serve phase (another seed's traffic), and
@@ -204,12 +214,77 @@ def _check_flash(shape, seed: int, interpret: bool) -> None:
         assert max(errs) <= KERNEL_TOL, (name, errs)
 
 
+def _check_ssm_scan(shape, seed: int, interpret: bool) -> None:
+    """ops/ssm_scan.py against the XLA chain it replaces in a prefill:
+    a left-padded row from a non-zero state with a captured column, the
+    outputs and both states held to SCAN_TOL, the pads to the bit
+    (the same row without them gives the same states), and what a call
+    of each takes on the device."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.ops.ssm_scan import (selective_scan,
+                                      selective_scan_reference)
+
+    B, T, di, N = shape
+    pads, capture = T // 5, jnp.int32(T - T // 3)
+    ks = jax.random.split(jax.random.PRNGKey(seed + 2), 6)
+    real = (jnp.arange(T) >= pads)[None, :, None]
+    dt = jnp.where(real, jax.random.uniform(
+        ks[0], (B, T, di), jnp.float32, 1e-3, 1e-1), 0.0)
+    x = jnp.where(real, jax.random.normal(ks[1], (B, T, di)), 0.0)
+    Bm, Cm = (jax.random.normal(k, (B, T, N)) for k in ks[2:4])
+    A = -jnp.broadcast_to(
+        jnp.arange(1, N + 1, dtype=jnp.float32)[:, None], (N, di))
+    s0 = jax.random.normal(ks[5], (B, N, di))
+    kernel = functools.partial(selective_scan, interpret=interpret)
+
+    def chain(*a):                 # 32 columns a chunk, as the 3B has it
+        return selective_scan_reference(*a[:6], 32, a[6])
+
+    args = (dt, x, A, Bm, Cm, s0, capture)
+
+    def layer_ms(fn, layers=2 if interpret else 26):
+        """Device time a call: `layers` dependent calls in one program,
+        as a prefill's walk over its Mamba layers has them (a single
+        call is shorter than its dispatch)."""
+        def walk(x):
+            def layer(x, _):
+                y, s, snap = fn(dt, x, *args[2:])
+                return x + 1e-2 * y, (s[0, 0, 0], snap[0, 0, 0])
+            return jax.lax.scan(layer, x, None, length=layers)
+
+        walk = jax.jit(walk)
+        jax.block_until_ready(walk(x))              # compiles
+        t0 = time.perf_counter()
+        jax.block_until_ready(walk(x))
+        return (time.perf_counter() - t0) / layers * 1e3
+
+    got, want = jax.jit(kernel)(*args), jax.jit(chain)(*args)
+    errs = [_rel_err(g[:, pads:] if i == 0 else g,
+                     w[:, pads:] if i == 0 else w)
+            for i, (g, w) in enumerate(zip(got, want))]
+    cut = lambda a: a[:, pads:]  # noqa: E731
+    bare = kernel(cut(dt), cut(x), A, cut(Bm), cut(Cm), s0,
+                  capture - pads)
+    pads_exact = all(np.array_equal(np.asarray(g), np.asarray(b))
+                     for g, b in zip(got[1:], bare[1:]))
+    say("train", kernel="ssm_scan", shape=[B, T, di, N], pads=pads,
+        err_y_state_snapshot=[float(f"{e:.3g}") for e in errs],
+        pads_exact=pads_exact,
+        kernel_ms_a_layer=round(layer_ms(kernel), 4),
+        xla_chain_ms_a_layer=round(layer_ms(chain), 4))
+    assert max(errs) <= SCAN_TOL and pads_exact, ("ssm_scan", errs)
+
+
 def check_kernels(size: Size, *, interpret: bool = False) -> None:
     """The Pallas kernels the train step is built from — and those it
     does not take by default (classic and resident-kv flash, fused
-    lm-head+CE) — each forward and backward against its XLA reference.
-    `interpret` is False on the chip; only a CPU test asks for the
-    interpreter."""
+    lm-head+CE) — each forward and backward against its XLA reference,
+    and the selective-scan kernel a Jamba prefill takes against the XLA
+    chain.  `interpret` is False on the chip; only a CPU test asks for
+    the interpreter."""
     import jax
     import jax.numpy as jnp
 
@@ -251,6 +326,9 @@ def check_kernels(size: Size, *, interpret: bool = False) -> None:
         err_nll_dh_dw=[round(e, 5) for e in errs],
         seconds=round(time.perf_counter() - t0, 2))
     assert max(errs) <= KERNEL_TOL, ("fused_lm_ce", errs)
+
+    for shape in size.scan_shapes:
+        _check_ssm_scan(shape, size.seed, interpret)
 
 
 def train_setup(size: Size):

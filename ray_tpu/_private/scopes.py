@@ -54,14 +54,16 @@ DEVICE_SCOPES = frozenset((EMBED, ATTN, MLP, LN, LM_HEAD_CE, LM_HEAD,
                            KV_POOL, SAMPLE, SSM, SSM_STATE, LAYER_SCAN,
                            LOSS_AND_GRAD, OPTIMIZER))
 
-# -- Pallas kernel names (ops/flash_attention.py ``pallas_call(name=)``) -----
+# -- Pallas kernel names (``pallas_call(name=)`` in ops/*.py) ----------------
 FLASH_FWD, FLASH_DQ, FLASH_DKV = "flash_fwd", "flash_dq", "flash_dkv"
 FLASH_RES_FWD, FLASH_RES_DQ, FLASH_RES_DKV = (
     "flash_res_fwd", "flash_res_dq", "flash_res_dkv")
 FLASH_TRI_FWD, FLASH_TRI_BWD = "flash_tri_fwd", "flash_tri_bwd"
+#: a Mamba mixer's recurrence over a program's columns (ops/ssm_scan.py)
+SSM_SCAN = "ssm_scan"
 KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV,
            FLASH_RES_FWD, FLASH_RES_DQ, FLASH_RES_DKV,
-           FLASH_TRI_FWD, FLASH_TRI_BWD)
+           FLASH_TRI_FWD, FLASH_TRI_BWD, SSM_SCAN)
 
 # -- host phases -------------------------------------------------------------
 SPAN_PREFIX = "raytpu."

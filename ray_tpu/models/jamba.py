@@ -45,10 +45,12 @@ both untouched: its ``D`` is 0, so ``exp(0 A) = 1`` and nothing is
 added, exactly, and the window is laid directly before the first real
 column.  The recurrence runs in the order of the tokens whatever the
 padding, so a prompt gives bit-equal state at every bucket size.  The
-scan over time is chunked (`ssm_scan`): the exponentials and outer
-products of ``scan_chunk`` columns at once, then the chain of
-multiply-adds through them, each column's output reduced as its state
-passes; ``T / scan_chunk`` sequential steps, not T.
+scan over time (`ssm_scan`) is one Pallas kernel on a TPU wherever a
+program holds more than one column (ops/ssm_scan.py: the state in VMEM,
+the columns walked inside); a decode step, and every program off the
+TPU, runs the ``jnp`` chain: the exponentials and outer products of
+``scan_chunk`` columns at once, then the chain of multiply-adds through
+them, each column's output reduced as its state passes.
 """
 
 from __future__ import annotations
@@ -93,7 +95,8 @@ class JambaConfig:
     remat: bool = True
     use_flash: Optional[bool] = None    # None = auto (flash on TPU)
     vocab_pad_to: int = 128
-    #: columns of the SSM scan computed at once (`ssm_scan`)
+    #: columns of the SSM scan's ``jnp`` chain computed at once
+    #: (`ssm_scan`; the Pallas kernel sizes itself from the shape)
     scan_chunk: int = 32
 
     def __post_init__(self):
@@ -386,47 +389,20 @@ def ssm_scan(dt, x, A, Bm, Cm, s0, chunk: int, capture=None):
     or None).  `capture` is a traced column index; a column with
     ``dt = 0`` is the identity, exactly.
 
-    `chunk` columns at a time: their ``exp`` and outer products in one
-    elementwise pass, then the multiply-add chain through them unrolled,
-    each column's output reduced over N as its state passes.  The chain is the same
-    sequence of operations on ``s`` wherever the chunks' edges fall."""
-    B, T, di = x.shape
-    chunk = min(chunk, T)          # a decode step is one column
-    n_chunks = -(-T // chunk)
-    tail = n_chunks * chunk - T
-    if tail:                       # identity columns at the end
-        pad = lambda a: jnp.pad(a, ((0, 0), (0, tail), (0, 0)))  # noqa: E731
-        dt, x, Bm, Cm = pad(dt), pad(x), pad(Bm), pad(Cm)
+    Two bodies (ops/ssm_scan.py), chosen by what the shape says of the
+    need.  One column (a decode step) is one elementwise expression
+    over every row's state, bound by the state's bytes.  Several columns
+    on a TPU are bound by the launches of the chain through them: there
+    the Pallas kernel walks the columns with the state in VMEM.
+    Elsewhere the ``jnp`` chain, `chunk` columns at a time, which is
+    also what the kernel's backward pass differentiates."""
+    from ray_tpu.ops.ssm_scan import (selective_scan,
+                                      selective_scan_reference)
 
-    def chunks(a):                 # (B, T, w) -> (n_chunks, B, chunk, w)
-        return a.reshape(B, n_chunks, chunk, a.shape[-1]).swapaxes(0, 1)
-
-    def one(carry, xs):
-        s, snap = carry
-        dt_c, dtx_c, b_c, c_c, first = xs
-        a = jnp.exp(dt_c[:, :, None, :] * A)            # (B, Q, N, di)
-        bx = dtx_c[:, :, None, :] * b_c[..., None]
-        ys = []
-        for q in range(chunk):
-            s = a[:, q] * s + bx[:, q]
-            # the column's output at once: the chain's states are never
-            # stacked (32 of them are 10 MB a chunk at the 3B's width)
-            ys.append(jnp.sum(s * c_c[:, q, :, None], axis=1))
-            if capture is not None:
-                snap = jnp.where(capture == first + q, s, snap)
-        y = jnp.stack(ys, axis=1)                       # (B, Q, di)
-        return (s, snap), y
-
-    firsts = jnp.arange(n_chunks, dtype=jnp.int32) * chunk
-    xs = (chunks(dt), chunks(dt * x), chunks(Bm), chunks(Cm), firsts)
-    init = (s0, s0 if capture is not None else ())
-    if n_chunks == 1:
-        (s, snap), y = one(init, jax.tree.map(lambda a: a[0], xs))
-        y = y[None]
-    else:
-        (s, snap), y = lax.scan(one, init, xs)
-    y = y.swapaxes(0, 1).reshape(B, n_chunks * chunk, di)[:, :T]
-    return y, s, (snap if capture is not None else None)
+    if x.shape[1] > 1 and jax.default_backend() == "tpu":
+        return selective_scan(dt, x, A, Bm, Cm, s0, capture,
+                              ref_chunk=chunk)
+    return selective_scan_reference(dt, x, A, Bm, Cm, s0, chunk, capture)
 
 
 def mamba_mix(p, u, cfg: JambaConfig, window, state, real=None,

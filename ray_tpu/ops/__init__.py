@@ -13,6 +13,7 @@ from ray_tpu.ops.flash_attention import flash_attention
 from ray_tpu.ops.fused_ce import fused_lm_ce
 from ray_tpu.ops.pipeline import pipeline_apply, stack_stage_params
 from ray_tpu.ops.ring_attention import ring_attention, ulysses_attention
+from ray_tpu.ops.ssm_scan import selective_scan
 from ray_tpu.ops.vocab_ce import streaming_ce
 
 __all__ = [
@@ -22,6 +23,7 @@ __all__ = [
     "pipeline_apply",
     "reference_attention",
     "ring_attention",
+    "selective_scan",
     "stack_stage_params",
     "streaming_ce",
     "ulysses_attention",

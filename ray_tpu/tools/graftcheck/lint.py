@@ -112,7 +112,8 @@ _MUTABLE_FACTORIES = {"list", "dict", "set", "defaultdict", "deque",
                       "OrderedDict", "Counter"}
 #: entry points that must stay exported from ray_tpu.ops
 KERNEL_EXPORTS = ("causal_attention", "flash_attention", "fused_lm_ce",
-                  "streaming_ce", "ring_attention", "ulysses_attention")
+                  "streaming_ce", "ring_attention", "ulysses_attention",
+                  "selective_scan")
 
 #: every rule id a disable comment may legitimately name — a waiver
 #: for anything else is a typo or a removed rule (stale-suppression)

@@ -169,7 +169,7 @@ def test_default_causal_path_matches_reference(T, dtype):
     if T == 384:
         assert names == set(scopes.KERNELS[:3]), names
     else:
-        assert names == set(scopes.KERNELS[6:]), names
+        assert names == set(scopes.KERNELS[6:8]), names
 
     (_, got), gf = jax.value_and_grad(loss_flash, argnums=(0, 1, 2),
                                       has_aux=True)(q, k, v)
@@ -281,7 +281,7 @@ def test_dispatch_by_what_the_call_can_see(case, T, kw, want):
     names = _kernels_of(grad, x, x, x)
     families = {_CLASSIC: set(scopes.KERNELS[:3]),
                 _RESIDENT: set(scopes.KERNELS[3:6]),
-                _TRIANGLE: set(scopes.KERNELS[6:])}
+                _TRIANGLE: set(scopes.KERNELS[6:8])}
     assert names and names <= families[want], (case, names)
 
 

@@ -30,7 +30,7 @@ TOY = chip_smoke.Size(
     preset="nano", seq=128, batch=4, steps=2,
     cfg={"use_flash": True, "dtype": jnp.float32},
     attn_shapes=((1, 128, 2, 32), (1, 256, 3, 64)),
-    ce_shape=(64, 64, 512, 500),
+    ce_shape=(64, 64, 512, 500), scan_shapes=((1, 40, 256, 16),),
     requests=10, warm_requests=8, prefix_groups=2, prefix_len=32,
     tail_mean=6.0, tail_max=16, vocab=500, rate_rps=200.0, max_slots=4,
     new_tokens=8, prefill_bucket=16)

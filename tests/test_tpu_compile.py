@@ -57,6 +57,9 @@ def _no_compile_cache():
     compilation_cache.reset_cache()
 
 
+MOSAIC_CALL = 'custom_call_target="tpu_custom_call"'
+
+
 def _on(sharding):
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
@@ -75,8 +78,7 @@ def _kernels_in(fn, *args, mesh=None) -> int:
     else:
         with jax.set_mesh(mesh):
             compiled = jax.jit(fn).lower(*args).compile()
-    return compiled.as_text().count(
-        'custom_call_target="tpu_custom_call"')
+    return compiled.as_text().count(MOSAIC_CALL)
 
 
 @pytest.mark.parametrize("shape", [(24, 1024, 12, 64), (3, 1024, 25, 64)],
@@ -178,6 +180,76 @@ def test_paged_prefill_compiles():
                           slot=slot)).lower(
         params, cache, i32(1, 384), i32(64), i32(), i32(), i32()
     ).compile()
+
+
+@pytest.mark.parametrize("T", [640, 1024])
+def test_ssm_scan_kernel_compiles_at_the_published_widths(T):
+    """ray_tpu.ops.ssm_scan at Jamba2-3B's widths (d_inner 5,120,
+    d_state 16), one row at a middle and at the largest prefill bucket,
+    with a captured column: one Mosaic call, the state's blocks, the
+    B/C columns and the double-buffered tiles inside the default VMEM
+    budget."""
+    from ray_tpu.ops.ssm_scan import selective_scan
+
+    spec = _one_chip()
+    f32 = lambda *shape: spec(shape, jnp.float32)   # noqa: E731
+    assert _kernels_in(
+        selective_scan, f32(1, T, 5120), f32(1, T, 5120), f32(16, 5120),
+        f32(1, T, 16), f32(1, T, 16), f32(1, 16, 5120),
+        spec((), jnp.int32)) == 1
+
+
+def test_jamba_prefill_holds_the_scan_kernel_and_decode_does_not(
+        monkeypatch):
+    """The Jamba2-3B serving programs as the cell builds them (64 slots,
+    16,384 blocks of 16, bf16 weights): the paged prefill of the 640
+    bucket compiles with the scan kernel in each of its two walks over
+    Mamba layers, the decode step without any.  The program asks
+    ``jax.default_backend()``, which says "cpu" on this host: steered
+    here, in the test."""
+    from ray_tpu._private import scopes
+    from ray_tpu.models.jamba import jamba_config, jamba_init
+    from ray_tpu.models.jamba_decode import (jamba_decode_step,
+                                             jamba_init_paged_cache,
+                                             jamba_paged_prefill)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    spec = _one_chip()
+    with_spec = lambda x: spec(x.shape, x.dtype)   # noqa: E731
+    i32 = lambda *shape: spec(shape, jnp.int32)   # noqa: E731
+    cfg = jamba_config("jamba2-3b", param_dtype=jnp.bfloat16)
+    params = jax.tree.map(with_spec, jax.eval_shape(
+        lambda: jamba_init(jax.random.PRNGKey(0), cfg)))
+    cache = jax.tree.map(with_spec, jax.eval_shape(
+        lambda: jamba_init_paged_cache(cfg, 64, num_blocks=16384,
+                                       block_size=16)))
+
+    def prefill(p, c, toks, row_bt, prefix_len, n_tail, slot, state):
+        return jamba_paged_prefill(p, c, toks, cfg, row_bt=row_bt,
+                                   prefix_len=prefix_len, n_tail=n_tail,
+                                   slot=slot, state=state)
+
+    def kernels_by_scope(fn, *args):
+        """{instruction: scope} of the program's Mosaic calls, as the
+        program registry's scope map and a trace's op events name
+        them."""
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        assert text.count(MOSAIC_CALL) == sum(
+            MOSAIC_CALL in line and "ssm_scan" in line.split(" = ")[0]
+            for line in text.splitlines())
+        return {name: scope for name, keyed in
+                scopes.scope_map_from_hlo(text).items()
+                for key, scope in keyed.items()
+                if scopes.SSM_SCAN in name and "custom-call" in key}
+
+    found = kernels_by_scope(prefill, params, cache, i32(1, 640),
+                             i32(cfg.max_seq // 16), i32(), i32(), i32(),
+                             i32(3))
+    assert len(found) == 2 and set(found.values()) == {scopes.SSM}, found
+    assert all(name.startswith(scopes.SSM_SCAN) for name in found)
+    assert kernels_by_scope(
+        lambda p, c, t: jamba_decode_step(p, c, t, cfg),
+        params, cache, i32(64)) == {}
 
 
 def test_sharded_attention_compiles_on_four_devices():
