@@ -42,6 +42,15 @@ SSM = "ssm"
 #: reads and writes of the recurrent state (convolution window and SSM
 #: state per sequence) and of its snapshot pool: ``kv_pool``'s twin
 SSM_STATE = "ssm_state"
+#: latent attention (models/kimi_k2.py): the down and up projections of
+#: queries and of the cached latent, RoPE, and both attention paths
+#: (expanded for a prefill, absorbed for a decode step)
+MLA = "mla"
+#: a sparse expert layer (models/experts.py): scores and top-k ...
+MOE_ROUTER = "moe_router"
+#: ... and the held experts' sort, grouped matmuls and combine; the
+#: shared expert and a dense layer's MLP keep ``mlp``
+MOE_EXPERTS = "moe_experts"
 #: the decode programs' scan over layers: what no inner scope claims is
 #: the scan's own plumbing (slicing the stacked weights, stacking the
 #: per-layer K/V it returns)
@@ -50,8 +59,15 @@ LAYER_SCAN = "layer_scan"
 LOSS_AND_GRAD = "loss_and_grad"
 OPTIMIZER = "optimizer"
 
+#: instructions the compiler writes anew, whose ``op_name`` is its own
+#: and carries no name stack: the stem says what they were made from.
+#: ``lax.ragged_dot`` becomes a grouped-matmul kernel named
+#: ``ragged-dot-*``, and the only ragged_dot here is the experts'
+REWRITTEN = {"ragged-dot": MOE_EXPERTS}
+
 DEVICE_SCOPES = frozenset((EMBED, ATTN, MLP, LN, LM_HEAD_CE, LM_HEAD,
-                           KV_POOL, SAMPLE, SSM, SSM_STATE, LAYER_SCAN,
+                           KV_POOL, SAMPLE, SSM, SSM_STATE, MLA,
+                           MOE_ROUTER, MOE_EXPERTS, LAYER_SCAN,
                            LOSS_AND_GRAD, OPTIMIZER))
 
 # -- Pallas kernel names (``pallas_call(name=)`` in ops/*.py) ----------------
@@ -106,7 +122,10 @@ ScopeMap = Dict[str, Dict[str, str]]
 def innermost_scope(op_name: str) -> Optional[str]:
     """The last registered scope on an instruction's ``op_name`` path,
     or None: ``jit(step)/loss_and_grad/transpose(jvp(attn))/ln/mul`` is
-    ``ln``."""
+    ``ln``; a name of `REWRITTEN` is what it was made from."""
+    for stem, scope in REWRITTEN.items():
+        if op_name.startswith(stem):
+            return scope
     for segment in reversed(op_name.split("/")):
         while True:
             m = _WRAPPED.match(segment)

@@ -48,6 +48,18 @@ it (``snap_conv``, ``snap_ssm``).  Its paged prefill takes one more
 argument, ``state`` int32 (3,) = [source, snapshot entry, snapshot
 boundary]; the constants below are its vocabulary, and the engine's.
 
+A third (models/kimi_k2_decode.py): latent attention caches per token
+and layer no K and V per head but ONE latent ``ckv`` (kv_lora_rank
+wide, after its norm) and ONE rotary key ``kpe`` shared by every head,
+(L, B, S, width) dense and (L, num_blocks, block_size, width) paged.
+They are positional as K and V are, so everything below that walks
+"the K/V" walks `positional(cache)`: whichever of the two pairs the
+cache holds.  Two tensors and not one 576 wide: compiled for the chip,
+the 512-wide one keeps its rows whole in the tiles and is gathered and
+scattered where it lies, where one of 576 (4.5 lane tiles) is stored
+block-minor and re-laid a layer at a time, as the 64-wide one still is
+(PERF.md, PR 32).
+
 What a cache is made of (its keys, which axis of each tensor is the
 slot or the block, what a block weighs) is written in this module and
 nowhere else: the serving engine moves rows, blocks and state through
@@ -121,21 +133,37 @@ def is_paged(cache) -> bool:
     return "block_tables" in cache
 
 
+#: what a cache keeps PER POSITION: K and V per head, or latent
+#: attention's latent and rotary key (one of each a token, no heads)
+_KV = ("k", "v")
+_LATENT = ("ckv", "kpe")
+
+
+def positional(cache):
+    """The names of the cache's per-position tensors, in the order the
+    family's programs hand their new rows over: ``("k", "v")`` or
+    ``("ckv", "kpe")``."""
+    return _LATENT if _LATENT[0] in cache else _KV
+
+
 @jax.named_scope(scopes.KV_POOL)
 def dense_layer_kv(cache, lidx):
-    """Layer `lidx`'s K and V out of the stacked DENSE cache (the
-    parity oracle's layout; a paged pool goes through PagedKV)."""
-    return (lax.dynamic_index_in_dim(cache["k"], lidx, axis=0,
-                                     keepdims=False),
-            lax.dynamic_index_in_dim(cache["v"], lidx, axis=0,
-                                     keepdims=False))
+    """Layer `lidx`'s per-position tensors (K and V, or latent and
+    rotary key) out of the stacked DENSE cache (the parity oracle's
+    layout; a paged pool goes through PagedKV)."""
+    return tuple(lax.dynamic_index_in_dim(cache[name], lidx, axis=0,
+                                          keepdims=False)
+                 for name in positional(cache))
 
 
 class PagedKV:
     """One program's use of the paged pool, for every decoder family.
 
     cache["k"] / cache["v"] are the WHOLE stacked pools
-    (L, num_blocks, bs, H, hd); block_tables (B, max_blk) int32 names
+    (L, num_blocks, bs, H, hd) -- or, for a latent cache, cache["ckv"] /
+    cache["kpe"], (L, num_blocks, bs, width): the class walks
+    `positional(cache)` and every pool keeps its own trailing dims --;
+    block_tables (B, max_blk) int32 names
     the rows the program attends over; slots (B, T) int32 is the cache
     slot each of the program's new K/V rows lands at (one decode token:
     pos[:, None]; a prefill's tail: one row of Tt columns).  A slot
@@ -174,7 +202,17 @@ class PagedKV:
     and the layer scan never stacks one as its `ys`.  (Why two ways,
     and what each costs on the chip: PERF.md, PR 26.)
 
-    (The layer is sliced out before the gather on purpose.  A TPU
+    ``whole=True`` (the latent pool's programs) reads and writes the
+    pool itself instead: a block of columns is scattered at (layer,
+    block, offset) into the carried pool and the views are gathered at
+    (layer, block table) out of it, so no copy of a layer is ever made;
+    and one column a row is NOT put into the gathered view: the caller
+    attends its new rows beside the view (kimi_k2.attend_absorbed
+    ``fresh``), which saves the view's copy.  Only a pool whose rows
+    are whole lane tiles (512 wide) is taken where it lies
+    (``in_place``); a narrower one still goes by the layer.
+
+    (Otherwise the layer is sliced out before the gather on purpose.  A TPU
     stores the pool with the block axis minor-most, the only order of
     this shape its tiles do not pad, and a gather or a scatter by block
     id needs the block axis major: given the whole pool, either makes
@@ -182,11 +220,21 @@ class PagedKV:
     fits the chip.  Sliced, it re-lays one layer at a time;
     dynamic_update_slice works in any layout.)"""
 
-    def __init__(self, cache, block_tables, slots):
+    def __init__(self, cache, block_tables, slots, whole=False):
         self.cache = cache
+        self.whole = whole
         self.block_tables = block_tables
         self.slots = slots
-        self.L, _, self.bs, *self.tail = cache["k"].shape
+        self.names = positional(cache)
+        self.L, _, self.bs = cache[self.names[0]].shape[:3]
+        #: each pool's dims after (L, blocks, block): (H, hd) or (width,)
+        self.tails = tuple(cache[n].shape[3:] for n in self.names)
+        #: the pools read and written where they lie (`whole`): those
+        #: whose rows are whole lane tiles.  A narrower one (a 64-wide
+        #: rotary key) is stored block-minor, and a gather or scatter
+        #: on all of it would re-lay all of it: it goes by the layer
+        self.in_place = tuple(whole and t[-1] % 128 == 0
+                              for t in self.tails)
         self.B, self.nb = block_tables.shape
         self.T = slots.shape[1]
         #: one column a row: the pool is read-only in the scan and
@@ -204,22 +252,31 @@ class PagedKV:
     @property
     def pools(self):
         """What the layer scan carries: (K pool, V pool)."""
-        return self.cache["k"], self.cache["v"]
+        return tuple(self.cache[name] for name in self.names)
 
     @jax.named_scope(scopes.KV_POOL)
-    def attend(self, lidx, pools, k_new, v_new):
+    def attend(self, lidx, pools, *new_rows):
         """Inside the scan body: (pools, (ck, cv)) for layer `lidx`."""
         out, views = [], []
-        for pool, new in zip(pools, (k_new, v_new)):
-            layer = lax.dynamic_index_in_dim(pool, lidx, 0,
-                                             keepdims=False)
-            if not self.by_rows:
-                layer = layer.at[self.blk, self.off].set(new)
-                pool = lax.dynamic_update_index_in_dim(pool, layer,
-                                                       lidx, 0)
-            view = layer[self.block_tables]  # (B, max_blk, bs, H, hd)
-            view = view.reshape(self.B, self.nb * self.bs, *self.tail)
-            if self.by_rows:
+        for pool, new, tail, in_place in zip(pools, new_rows, self.tails,
+                                             self.in_place):
+            if in_place:
+                # scatter and gather on the pool itself: no copy of a
+                # layer is made (a 512-wide latent pool keeps its rows
+                # whole in the chip's tiles, so neither re-lays it)
+                if not self.by_rows:
+                    pool = pool.at[lidx, self.blk, self.off].set(new)
+                view = pool[lidx, self.block_tables]
+            else:
+                layer = lax.dynamic_index_in_dim(pool, lidx, 0,
+                                                 keepdims=False)
+                if not self.by_rows:
+                    layer = layer.at[self.blk, self.off].set(new)
+                    pool = lax.dynamic_update_index_in_dim(pool, layer,
+                                                           lidx, 0)
+                view = layer[self.block_tables]  # (B,max_blk,bs,H,hd)
+            view = view.reshape(self.B, self.nb * self.bs, *tail)
+            if self.by_rows and not self.whole:
                 # into the gathered copy, not the layer's
                 view = view.at[self.rows, self.slots].set(new,
                                                          mode="drop")
@@ -228,22 +285,24 @@ class PagedKV:
         return tuple(out), tuple(views)
 
     @jax.named_scope(scopes.KV_POOL)
-    def commit(self, pools, ks, vs):
+    def commit(self, pools, *stacked):
         """After the scan: the cache with the program's new rows in
-        the pool (ks, vs: the scan's stacked (L, B, T, H, hd))."""
+        the pool (stacked: the scan's (L, B, T, H, hd) of K and of V,
+        or (L, B, T, width) of latent and rotary key)."""
         if self.by_rows:
-            shape = (self.L, 1, 1, *self.tail)
-
             def one(b, pools):
-                at = (0, self.blk[b, 0], self.off[b, 0], 0, 0)
-                return tuple(
-                    lax.dynamic_update_slice(
-                        pool, lax.dynamic_slice(new, (0, b, 0, 0, 0),
-                                                shape), at)
-                    for pool, new in zip(pools, (ks, vs)))
+                out = []
+                for pool, new, tail in zip(pools, stacked, self.tails):
+                    zeros = (0,) * len(tail)
+                    row = lax.dynamic_slice(
+                        new, (0, b, 0, *zeros), (self.L, 1, 1, *tail))
+                    out.append(lax.dynamic_update_slice(
+                        pool, row,
+                        (0, self.blk[b, 0], self.off[b, 0], *zeros)))
+                return tuple(out)
 
             pools = lax.fori_loop(0, self.B, one, pools)
-        return dict(self.cache, k=pools[0], v=pools[1])
+        return dict(self.cache, **dict(zip(self.names, pools)))
 
 
 _HEADS = (None, None, None, "heads", "head_dim")
@@ -257,9 +316,9 @@ _HEADS = (None, None, None, "heads", "head_dim")
 #: (over `tensor`).  A state tensor's snapshot pool is "snap_" + name,
 #: same axes, one entry a slot.
 _TENSORS = {"k": (1, _HEADS), "v": (1, _HEADS),
+            "ckv": (1, None), "kpe": (1, None),
             "ssm": (1, None), "conv": (2, None)}
-_KV = ("k", "v")
-_STATE = tuple(n for n in _TENSORS if n not in _KV)
+_STATE = ("ssm", "conv")
 _SNAP = "snap_"
 
 
@@ -310,14 +369,15 @@ def dense_to_paged(cache, block_size: int):
     continues a dense prefill exactly.  Used by generate_with's
     kv_layout="paged" path and the parity tests; the serve engine
     builds its pool through kv_pager instead."""
-    k = cache["k"]
-    L, B, S, *tail = k.shape
+    names = positional(cache)
+    L, B, S = cache[names[0]].shape[:3]
     if S % block_size:
         raise ValueError(f"max_seq={S} must be a multiple of "
                          f"block_size={block_size}")
     nb = S // block_size
     out = dict(cache)
-    for name in _KV:
+    for name in names:
+        tail = cache[name].shape[3:]
         pool = cache[name].reshape(L, B * nb, block_size, *tail)
         null = jnp.zeros((L, 1, block_size, *tail), pool.dtype)
         out[name] = jnp.concatenate([null, pool], axis=1)
@@ -334,7 +394,7 @@ def copy_block(cache, src, dst):
     src = jnp.asarray(src, jnp.int32)
     dst = jnp.asarray(dst, jnp.int32)
     out = dict(cache)
-    for name in _KV:
+    for name in positional(cache):
         pool = cache[name]                 # (L, num_blocks, bs, ...)
         out[name] = pool.at[:, dst].set(pool[:, src])
     return out
@@ -390,7 +450,7 @@ def install_blocks(cache, blk_ids, k_stack, v_stack):
     harmless by the same contract.  On a sharded pool the committed
     cache shardings re-distribute the replicated rows."""
     out = dict(cache)
-    for name, stack in zip(_KV, (k_stack, v_stack)):
+    for name, stack in zip(positional(cache), (k_stack, v_stack)):
         out[name] = cache[name].at[:, blk_ids].set(stack.swapaxes(0, 1))
     return out
 
@@ -398,7 +458,7 @@ def install_blocks(cache, blk_ids, k_stack, v_stack):
 def save_block(cache, blk):
     """One block's (K rows, V rows) out of the pool together: an
     eviction's spill costs one dispatch and one D2H transfer pair."""
-    return tuple(cache[name][:, blk] for name in _KV)
+    return tuple(cache[name][:, blk] for name in positional(cache))
 
 
 def kv_handoff_export(cache, blk_ids):
@@ -407,7 +467,8 @@ def kv_handoff_export(cache, blk_ids):
     the same fixed-length id vector.  Pad entries (id 0) gather the
     null block's garbage rows; they install back into the null block
     on the other side."""
-    return tuple(cache[name][:, blk_ids].swapaxes(0, 1) for name in _KV)
+    return tuple(cache[name][:, blk_ids].swapaxes(0, 1)
+                 for name in positional(cache))
 
 
 def kv_handoff_install(cache, blk_ids, k_stack, v_stack, slot, row_bt, pos):
@@ -425,14 +486,19 @@ def kv_handoff_install(cache, blk_ids, k_stack, v_stack, slot, row_bt, pos):
 
 
 def block_bytes(cache) -> int:
-    """K+V bytes of one block of a paged cache, over all the layers the
-    pool holds (a hybrid's pool holds its attention layers only)."""
-    return sum(cache[name].nbytes // cache[name].shape[1] for name in _KV)
+    """Bytes of one block of a paged cache (K and V, or latent and
+    rotary key), over all the layers the pool holds (a hybrid's pool
+    holds its attention layers only)."""
+    return sum(cache[name].nbytes // cache[name].shape[1]
+               for name in positional(cache))
 
 
 def block_rows(cache, n: int) -> jax.ShapeDtypeStruct:
     """Shape and dtype of `n` blocks' rows of K (V's are the same), as
-    `kv_handoff_export` returns and `install_blocks` takes them."""
+    `kv_handoff_export` returns and `install_blocks` takes them.  A
+    latent cache's two tensors differ in width, so what moves rows of
+    ONE shape (the host tier, the handoff) is refused for it at the
+    options check (serve/llm.py)."""
     L, _, *row = cache["k"].shape
     return jax.ShapeDtypeStruct((n, L, *row), cache["k"].dtype)
 
@@ -441,7 +507,9 @@ def kv_shards(cache) -> int:
     """How many ways the K/V tensors' heads axis is split over the
     devices the cache lives on: 1 on one device, and where the head
     count does not divide the mesh's tensor degree (`cache_shardings`
-    replicates it then)."""
+    replicates it then); 1 for a latent cache, which has no heads."""
+    if positional(cache) is _LATENT:
+        return 1
     k = cache["k"]
     return k.shape[3] // k.sharding.shard_shape(k.shape)[3]
 
@@ -452,6 +520,27 @@ def state_bytes(cache) -> int:
     return sum(cache[name].nbytes
                for state in _STATE for name in (state, _SNAP + state)
                if name in cache)
+
+
+#: a family with a sparse expert layer (models/experts.py) keeps under
+#: this key what the routing of its LAST program did on this chip, a
+#: float32 vector of `EXPERT_COUNTERS`: every program overwrites it, and
+#: the engine's fused programs hand it out beside their tokens
+#: (`expert_counters`), so it lands at the fence the tokens land at
+EXPERTS = "experts"
+#: experts held by this chip, of how many routed; (token, expert)
+#: assignments that fell on held experts, summed over the layers; held
+#: experts with at least one token over the held, mean over layers;
+#: the fullest held expert's tokens over the held experts' mean, worst
+#: layer
+EXPERT_COUNTERS = ("held", "of", "assignments_local",
+                   "experts_touched_share", "load_max_over_mean")
+
+
+def expert_counters(cache):
+    """The last program's `EXPERT_COUNTERS`, or None for a family
+    without expert layers."""
+    return cache.get(EXPERTS)
 
 
 def make_vocab_tail_mask(cfg) -> Optional[jnp.ndarray]:

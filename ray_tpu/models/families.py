@@ -22,8 +22,13 @@ from typing import Any, Callable, Dict, Optional, Tuple
 #: prefill takes one more argument, `state` (decode_common.py: where the
 #: slot's state starts and which snapshot it leaves); what cannot carry
 #: that state yet is refused when the engine's options are checked.
+#: LATENT: positional as KV is (a slot's past is its rows, a prefix is
+#: its blocks), but a row is one latent a token, not K and V per head
+#: (models/kimi_k2_decode.py): what moves K/V rows of one shape, rewinds
+#: through a verify program, or splits a heads axis is refused for it.
 KV = "kv"
 RECURRENT = "kv+recurrent"
+LATENT = "latent"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,10 +90,24 @@ def _jamba() -> Dict[str, Any]:
         init_paged_cache=m.jamba_init_paged_cache)
 
 
+def _kimi_k2() -> Dict[str, Any]:
+    from ray_tpu.models import kimi_k2_decode as m
+    from ray_tpu.models.kimi_k2 import (kimi_k2_config, kimi_k2_init,
+                                        kimi_k2_logical_axes)
+
+    return dict(
+        config=kimi_k2_config, init=kimi_k2_init,
+        logical_axes=kimi_k2_logical_axes, generate=m.kimi_k2_generate,
+        prefill=m.kimi_k2_prefill, paged_prefill=m.kimi_k2_paged_prefill,
+        step=m.kimi_k2_decode_step, verify=None,
+        init_cache=m.kimi_k2_init_cache,
+        init_paged_cache=m.kimi_k2_init_paged_cache)
+
+
 #: family -> (what its cache holds, loader of its programs)
 FAMILIES: Dict[str, Tuple[str, Callable[[], Dict[str, Any]]]] = {
     "gpt2": (KV, _gpt2), "llama": (KV, _llama),
-    "jamba": (RECURRENT, _jamba)}
+    "jamba": (RECURRENT, _jamba), "kimi_k2": (LATENT, _kimi_k2)}
 
 
 def cache_kind(name: str) -> Optional[str]:

@@ -1,0 +1,336 @@
+"""Autoregressive decoding for the Kimi-K2 family: latent attention over
+a cache of latents, dense or paged.
+
+The cache contract of decode_common with the latent pair in K/V's
+place: per token and layer ONE latent ``ckv`` (kv_lora_rank wide, after
+its norm) and ONE rotary key ``kpe`` (after RoPE), shared by all heads:
+
+  ckv : (L, B, S, kv_lora_rank)   dense   (L, blocks, bs, kv_lora_rank)
+  kpe : (L, B, S, qk_rope_dim)            (L, blocks, bs, qk_rope_dim)
+
+(1,152 B a token a layer in bf16 at the published widths, where K and V
+of 64 heads would be 40,960), through `PagedKV` as every family's K/V
+go.  Two attention paths read them (models/kimi_k2.py): a decode step
+attends ABSORBED, over the latents themselves; a prefill up-projects
+the latents it needs once and attends EXPANDED, blockwise over queries
+and keys with a running softmax (`attend_blockwise`), as far as the
+causal mask reaches and no further.
+
+``cache["experts"]`` holds what the expert layers' routing did on this
+chip in the LAST program (decode_common.EXPERT_COUNTERS).
+"""
+
+from __future__ import annotations
+
+from typing import Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ray_tpu._private import scopes
+from ray_tpu.models.decode_common import (EXPERT_COUNTERS, EXPERTS,
+                                          PagedKV, dense_layer_kv,
+                                          generate_with, is_paged,
+                                          slot_mask)
+from ray_tpu.models.kimi_k2 import (KimiK2Config, attend_absorbed,
+                                    attend_expanded, block, embed,
+                                    expand_keys, expert_counters,
+                                    lm_logits, softmax_scale, walk_layers)
+
+__all__ = ["kimi_k2_init_cache", "kimi_k2_init_paged_cache",
+           "kimi_k2_prefill", "kimi_k2_paged_prefill",
+           "kimi_k2_decode_step", "kimi_k2_generate"]
+
+
+def _latent_tensors(cfg: KimiK2Config, *lead: int):
+    return {"ckv": jnp.zeros((cfg.n_layer, *lead, cfg.kv_lora_rank),
+                             cfg.dtype),
+            "kpe": jnp.zeros((cfg.n_layer, *lead, cfg.qk_rope_dim),
+                             cfg.dtype)}
+
+
+def _positions(batch: int):
+    return {"pos": jnp.zeros((batch,), jnp.int32),
+            "start": jnp.zeros((batch,), jnp.int32),
+            EXPERTS: jnp.zeros((len(EXPERT_COUNTERS),), jnp.float32)}
+
+
+def _refuse_mesh(mesh) -> None:
+    if mesh is not None:
+        raise ValueError(
+            "family kimi_k2 keeps a latent pool, one latent a token "
+            "with no heads axis to split, and has no sharding for it "
+            "yet: mesh-sharded caches are refused")
+
+
+def kimi_k2_init_cache(cfg: KimiK2Config, batch: int,
+                       mesh=None) -> Dict[str, jnp.ndarray]:
+    """Dense cache: (L, B, S, width) latents and rotary keys, position
+    vectors, the last program's expert counters."""
+    _refuse_mesh(mesh)
+    return dict(_latent_tensors(cfg, batch, cfg.max_seq),
+                **_positions(batch))
+
+
+def kimi_k2_init_paged_cache(cfg: KimiK2Config, batch: int, *,
+                             num_blocks: int, block_size: int,
+                             mesh=None) -> Dict[str, jnp.ndarray]:
+    """Block-pool cache: (L, num_blocks, block_size, width) pools and
+    per-row block tables."""
+    _refuse_mesh(mesh)
+    if cfg.max_seq % block_size:
+        raise ValueError(f"max_seq={cfg.max_seq} must be a multiple of "
+                         f"block_size={block_size}")
+    return dict(_latent_tensors(cfg, num_blocks, block_size),
+                block_tables=jnp.zeros(
+                    (batch, cfg.max_seq // block_size), jnp.int32),
+                **_positions(batch))
+
+
+def _block_of(cfg: KimiK2Config, n: int) -> int:
+    """``cfg.attn_block`` where it divides `n`, else `n` whole."""
+    return cfg.attn_block if n % cfg.attn_block == 0 else n
+
+
+@jax.named_scope(scopes.MLA)
+def attend_blockwise(q, ckv, kpe, p, logical, real, cfg: KimiK2Config):
+    """One sequence's expanded attention without its score matrix.
+
+    q (T, H, qk) at positions `logical` (T,), `real` (T,) False on pad
+    columns; ckv (S, c), kpe (S, r) the sequence's cached latents, its
+    own new rows among them; position t attends slots <= logical[t].
+    The latents are up-projected a block at a time as far as the last
+    real query reaches; each block of queries then walks the key blocks
+    up to its own diagonal with a running maximum and sum.  Returns
+    (T, H, v); a pad's row is zeros."""
+    T, H, _ = q.shape
+    S = ckv.shape[0]
+    dt = cfg.dtype
+    kb, qb = _block_of(cfg, S), _block_of(cfg, T)
+    reach = jnp.where(real, logical, -1)
+
+    def blocks_to(top):                 # key blocks covering [0, top]
+        return (top + kb) // kb
+
+    # (the loops' bodies name their scope again: a nested loop is
+    # lowered as a function of its own, whose operations would carry
+    # the loop's name and not the stack around it)
+    def up(j, kv):
+        k, v = expand_keys(lax.dynamic_slice_in_dim(ckv, j * kb, kb),
+                           lax.dynamic_slice_in_dim(kpe, j * kb, kb),
+                           p, cfg)
+        return (lax.dynamic_update_slice_in_dim(kv[0], k, j * kb, 0),
+                lax.dynamic_update_slice_in_dim(kv[1], v, j * kb, 0))
+
+    keys, values = lax.fori_loop(
+        0, blocks_to(jnp.max(reach)), up,
+        (jnp.zeros((S, H, cfg.qk_head_dim), dt),
+         jnp.zeros((S, H, cfg.v_head_dim), dt)))
+    scale = softmax_scale(cfg)
+
+    def queries(i):
+        qi = lax.dynamic_slice_in_dim(q, i * qb, qb)
+        at = lax.dynamic_slice_in_dim(reach, i * qb, qb)
+
+        @jax.named_scope(scopes.MLA)
+        def over(j, carry):
+            m, l, acc = carry
+            kj = lax.dynamic_slice_in_dim(keys, j * kb, kb)
+            vj = lax.dynamic_slice_in_dim(values, j * kb, kb)
+            s = jnp.einsum("qhd,khd->hqk", qi, kj).astype(jnp.float32)
+            ok = (j * kb + jnp.arange(kb))[None, :] <= at[:, None]
+            s = jnp.where(ok[None], s * scale, -1e30)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            # a row with nothing to attend yet has m_new == -1e30 and
+            # exp(0) == 1 on every masked key: zero them
+            e = jnp.where(ok[None], jnp.exp(s - m_new[..., None]), 0.0)
+            shrink = jnp.exp(m - m_new)
+            return (m_new, l * shrink + jnp.sum(e, axis=-1),
+                    acc * shrink[..., None] + jnp.einsum(
+                        "hqk,khv->hqv", e.astype(dt), vj
+                    ).astype(jnp.float32))
+
+        _, l, acc = lax.fori_loop(
+            0, blocks_to(jnp.max(at)), over,
+            (jnp.full((H, qb), -1e30, jnp.float32),
+             jnp.zeros((H, qb), jnp.float32),
+             jnp.zeros((H, qb, cfg.v_head_dim), jnp.float32)))
+        out = acc / jnp.maximum(l, 1e-30)[..., None]
+        return out.transpose(1, 0, 2).astype(dt)
+
+    return lax.map(queries, jnp.arange(T // qb)).reshape(
+        T, H, cfg.v_head_dim)
+
+
+def _with_counters(cache, cfg: KimiK2Config, stats):
+    with jax.named_scope(scopes.MOE_EXPERTS):
+        cache[EXPERTS] = expert_counters(cfg, stats)
+    return cache
+
+
+def kimi_k2_prefill(params, tokens: jnp.ndarray, cfg: KimiK2Config, *,
+                    lengths: Optional[jnp.ndarray] = None
+                    ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """Single-dispatch prompt ingestion into a fresh DENSE cache: tokens
+    (B, T0) int32 -> (last_logits (B, padded_vocab) float32, cache).
+    Ragged rows are LEFT-padded with `lengths` (B,): a pad's key is
+    masked, its row is routed to no expert, and a token's rotary
+    position counts from its row's first real column."""
+    B, T0 = tokens.shape
+    cache = kimi_k2_init_cache(cfg, B)
+    col = jnp.arange(T0, dtype=jnp.int32)
+    if lengths is None:
+        start = jnp.zeros((B,), jnp.int32)
+    else:
+        start = (T0 - jnp.asarray(lengths, jnp.int32)).astype(jnp.int32)
+    real = col[None, :] >= start[:, None]                    # (B, T0)
+    positions = jnp.maximum(col[None, :] - start[:, None], 0)
+    mask = (col[None, :, None] >= col[None, None, :]) \
+        & real[:, None, :]                                   # (B, T, S=T)
+    x = embed(params, tokens, cfg)
+
+    def layer(x, carry, p, lidx):
+        new = []
+
+        def attend(q, ckv, kpe):
+            new.extend((ckv, kpe))
+            return attend_expanded(q, ckv, kpe, p["attn"], mask, cfg)
+
+        x, stats = block(x, p, cfg, positions, attend, valid=real)
+        return x, carry, tuple(new), stats
+
+    x, _, (ckv, kpe), stats = walk_layers(cfg, params, x, (), layer)
+    with jax.named_scope(scopes.KV_POOL):
+        cache["ckv"] = lax.dynamic_update_slice(cache["ckv"], ckv,
+                                                (0, 0, 0, 0))
+        cache["kpe"] = lax.dynamic_update_slice(cache["kpe"], kpe,
+                                                (0, 0, 0, 0))
+    cache.update(start=start, pos=jnp.full((B,), T0, jnp.int32))
+    return lm_logits(x[:, -1], params, cfg), \
+        _with_counters(cache, cfg, stats)
+
+
+def kimi_k2_paged_prefill(params, cache, tokens: jnp.ndarray,
+                          cfg: KimiK2Config, *, row_bt: jnp.ndarray,
+                          prefix_len, n_tail, slot
+                          ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """Prompt-tail ingestion for ONE sequence against the block pool
+    (gpt2_decode.paged_prefill has the contract): tokens (1, Tt)
+    RIGHT-aligned tail of `n_tail` real columns after `prefix_len`
+    tokens whose latents are resident in `row_bt`'s blocks.  The new
+    latents land in the row's blocks, the pad columns' in the null
+    block; the row becomes `slot`'s.  Returns (logits (padded_vocab,)
+    of the last real column, cache)."""
+    _, Tt = tokens.shape
+    prefix_len = jnp.asarray(prefix_len, jnp.int32)
+    n_tail = jnp.asarray(n_tail, jnp.int32)
+    slot = jnp.asarray(slot, jnp.int32)
+    pad = Tt - n_tail
+    col = jnp.arange(Tt, dtype=jnp.int32)
+    real = col >= pad
+    logical = prefix_len + col - pad               # position iff real
+    # pad columns MUST be masked writes (slot max_seq): their logical
+    # index can alias a live prefix slot
+    pkv = PagedKV(cache, row_bt[None],
+                  jnp.where(real, logical, cfg.max_seq)[None], whole=True)
+    positions = jnp.maximum(logical, 0)[None]
+    x = embed(params, tokens, cfg)
+
+    def layer(x, pools, p, lidx):
+        def attend(q, ckv, kpe):
+            nonlocal pools
+            pools, (cview, rview) = pkv.attend(lidx, pools, ckv, kpe)
+            return attend_blockwise(q[0], cview[0], rview[0], p["attn"],
+                                    logical, real, cfg)[None]
+
+        x, stats = block(x, p, cfg, positions, attend, valid=real[None])
+        return x, pools, (), stats
+
+    x, pools, _, stats = walk_layers(cfg, params, x, pkv.pools, layer)
+    # right-aligned: the last column is the last real one.  As eight
+    # equal rows: the product of one row is compiled as a float32
+    # multiply and sum over the whole head upcast (0.6 GB of it)
+    logits = lm_logits(jnp.broadcast_to(x[0, -1], (8, cfg.d_model)),
+                       params, cfg)[0]
+    out = pkv.commit(pools)
+    out["block_tables"] = cache["block_tables"].at[slot].set(row_bt)
+    out["pos"] = cache["pos"].at[slot].set(prefix_len + n_tail)
+    out["start"] = cache["start"].at[slot].set(0)
+    return logits, _with_counters(out, cfg, stats)
+
+
+def kimi_k2_decode_step(params, cache, tokens, cfg: KimiK2Config
+                        ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """One token per sequence: tokens (B,) int32, row b at cache slot
+    ``cache["pos"][b]``.  Both cache layouts (decode_common.is_paged).
+    A row with ``pos == 0`` holds no sequence (an engine's idle row):
+    it is routed to no expert, stays at ``pos == 0``, and what it
+    computes is masked garbage as every family's idle rows produce.
+
+    Returns (logits (B, padded_vocab) float32, updated cache)."""
+    B = tokens.shape[0]
+    paged = is_paged(cache)
+    pos, start = cache["pos"], cache["start"]
+    rows = jnp.arange(B)
+    with jax.named_scope(scopes.MLA):
+        # dense: the row's new latent is set into the layer at slot
+        # pos; paged: it is attended beside the gathered view, whose
+        # slot pos is left out (PagedKV(whole=True) inserts nothing)
+        mask = slot_mask(start, pos + (0 if paged else 1),
+                         cfg.max_seq)[:, None]                  # (B,1,S)
+    pkv = PagedKV(cache, cache["block_tables"], pos[:, None],
+                  whole=True) if paged else None
+    x = embed(params, tokens, cfg)[:, None]                     # (B,1,d)
+
+    def layer(x, pools, p, lidx):
+        new = []
+
+        def attend(q, ckv, kpe):
+            nonlocal pools
+            if paged:
+                new.extend((ckv, kpe))                   # (B, 1, width)
+                pools, views = pkv.attend(lidx, pools, ckv, kpe)
+                return attend_absorbed(q, *views, p["attn"], mask, cfg,
+                                       fresh=(ckv, kpe))
+            else:
+                with jax.named_scope(scopes.KV_POOL):
+                    views = tuple(
+                        held.at[rows, pos].set(row[:, 0])
+                        for held, row in zip(
+                            dense_layer_kv(cache, lidx), (ckv, kpe)))
+                new.extend(views)
+                return attend_absorbed(q, *views, p["attn"], mask, cfg)
+
+        x, stats = block(x, p, cfg, (pos - start)[:, None], attend,
+                         valid=(pos > 0)[:, None])
+        return x, pools, tuple(new), stats
+
+    x, pools, new, stats = walk_layers(
+        cfg, params, x, pkv.pools if paged else (), layer)
+    logits = lm_logits(x[:, 0], params, cfg)
+    out = pkv.commit(pools, *new) if paged \
+        else dict(cache, ckv=new[0], kpe=new[1])
+    with jax.named_scope(scopes.KV_POOL):
+        # a row without a sequence stays one: were its pos to count the
+        # steps it idled through, the next step would route it
+        out["pos"] = jnp.where(pos > 0, pos + 1, 0)
+    return logits, _with_counters(out, cfg, stats)
+
+
+def kimi_k2_generate(params, prompt: jnp.ndarray, cfg: KimiK2Config, *,
+                     max_new_tokens: int, temperature: float = 1.0,
+                     top_k: int = 0, top_p: float = 1.0,
+                     lengths: Optional[jnp.ndarray] = None,
+                     key: Optional[jax.Array] = None,
+                     kv_layout: str = "dense",
+                     kv_block_size: int = 16) -> jnp.ndarray:
+    """Generation via the shared loop (decode_common.generate_with): one
+    dense prefill, then the decode step scanned; the serve engine's
+    parity oracle."""
+    return generate_with(kimi_k2_prefill, kimi_k2_decode_step, params,
+                         prompt, cfg, max_new_tokens=max_new_tokens,
+                         lengths=lengths, temperature=temperature,
+                         top_k=top_k, top_p=top_p, key=key,
+                         kv_layout=kv_layout,
+                         kv_block_size=kv_block_size)

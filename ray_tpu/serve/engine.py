@@ -273,6 +273,9 @@ class LLMEngine(EngineBase):
         else:
             self._cache = fam.init_cache(cfg, opt.max_slots, mesh=self.mesh)
         self._cache = self._to_engine(self._cache)
+        # a family with a sparse expert layer leaves its routing
+        # counters in the cache, program by program (_counters)
+        self._counted = dc.expert_counters(self._cache) is not None
         self._cur = np.zeros((opt.max_slots,), np.int32)
         self._slots = [None] * opt.max_slots
         # what the chip has been given and the host has not fenced
@@ -421,6 +424,19 @@ class LLMEngine(EngineBase):
             self._telemetry.slo = SLOTracker(
                 opt.slo, self._telemetry,
                 recorder=self._telemetry.flightrec)
+
+    def _counters(self):
+        """The expert counters the program just dispatched leaves,
+        still on the device (None for a family without expert
+        layers): a copy queued behind the program, which its fence
+        reads beside its tokens (`_book_counters`)."""
+        if not self._counted:
+            return None
+        return self._fns.take_counters(dc.expert_counters(self._cache))
+
+    def _book_counters(self, program, counters) -> None:
+        if counters is not None:
+            self._telemetry.record_experts(program, np.asarray(counters))
 
     def _sampler_for(self, sp):
         """Per-SamplingParams jitted full-batch sampler for
@@ -738,9 +754,11 @@ class LLMEngine(EngineBase):
                     jnp.asarray(tail_toks), jnp.asarray(row_bt),
                     np.int32(prefix_len), np.int32(n_tail),
                     np.int32(slot), k, state)
+            counters = self._counters()
         st = {"prompt": arr, "out": [], "due": 1, "fut": fut,
               "rec": rec, "sp": sp, "blocks": blocks}
-        first = {"tok": tok, "slot": slot, "st": st, "tokens": tokens}
+        first = {"tok": tok, "slot": slot, "st": st, "tokens": tokens,
+                 "experts": counters}
         if sp is None and self._flight and self._chains():
             # decode waves are in flight: this prefill is queued
             # behind them and fenced in its turn (_land); the next
@@ -770,6 +788,7 @@ class LLMEngine(EngineBase):
         # prefill result; the timestamp behind it is the TTFT
         with self._phases.phase("prefill_fence"):
             first = int(np.asarray(item["tok"])[0])
+            self._book_counters("prefill", item["experts"])
         st["due"] -= 1
         self._telemetry.record_first_token(rec)
         # the prompt's full blocks now hold exactly its K/V —
@@ -1070,9 +1089,11 @@ class LLMEngine(EngineBase):
                 self.params, self._cache, jnp.asarray(chunk_toks),
                 jnp.asarray(st["row_bt"]), np.int32(filled),
                 np.int32(c), np.int32(i), k, state)
+            counters = self._counters()
             # the chunk's host fence (the one-shot path's int());
             # intermediate chunks discard the value
             first = int(np.asarray(tok)[0])
+            self._book_counters("prefill", counters)
         t1 = _time.perf_counter()
         cur.advance(c)
         self._telemetry.record_prefill_chunk(
@@ -1330,10 +1351,12 @@ class LLMEngine(EngineBase):
             self._joins.clear()
             toks, self._cache = self._pool_step(
                 self.params, self._cache, toks, k)
+            counters = self._counters()
         rows = self._decoding()
         for st in rows.values():
             st["due"] = st.get("due", 0) + 1
-        self._flight.append({"toks": toks, "t0": wave.t0, "rows": rows})
+        self._flight.append({"toks": toks, "t0": wave.t0, "rows": rows,
+                             "experts": counters})
 
     def _land(self) -> None:
         """Fence what has been in flight longest.  A decode wave:
@@ -1346,6 +1369,7 @@ class LLMEngine(EngineBase):
         with self._phases.phase("decode_fence") as fence:
             # the wave's one host fence
             toks = np.asarray(item["toks"])
+            self._book_counters("decode", item["experts"])
         rows = {i: st for i, st in item["rows"].items()
                 if self._slots[i] is st}
         if not rows:

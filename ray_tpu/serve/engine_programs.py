@@ -49,6 +49,11 @@ def _jitted_engine_fns(family, cfg, sampling, kv_layout="dense",
       prefill_raw / paged_prefill_raw / pool_logits — logits-returning
           twins for requests overriding SamplingParams (compiled only
           if such a request arrives)
+      take_counters                        — a copy of the expert
+          counters a program left in the cache
+          (decode_common.expert_counters), queued behind that program:
+          the copy outlives the cache's next donation and is read at
+          the fence the program's tokens are read at
       admit / copy_block / clear_row / restore_state / install_blocks
       / save_block / kv_handoff_export / kv_handoff_install — pool
           bookkeeping: the cache operations of models/decode_common.py
@@ -206,6 +211,7 @@ def _jitted_engine_fns(family, cfg, sampling, kv_layout="dense",
         pool_logits=jax.jit(pool_logits, donate_argnums=(1,)),
         admit=jax.jit(dc.admit),
         join_token=jax.jit(join_token),
+        take_counters=jax.jit(lambda counters: counters + 0),
         copy_block=jax.jit(fork_block, donate_argnums=(0,)),
         clear_row=jax.jit(consuming(dc.clear_row), donate_argnums=(0,)),
         restore_state=jax.jit(consuming(dc.restore_state),

@@ -87,21 +87,33 @@ class SpecConfig:
                 f"ngram_order must be >= 1, got {self.ngram_order}")
 
 
+#: what a cache that is not plain K/V keeps, as a refusal words it
+_CACHE_HOLDS = {
+    families.RECURRENT: "one recurrent state per slot beside the K/V pool",
+    families.LATENT: "a latent pool: one latent and one rotary key a "
+                     "token, no K or V per head",
+}
+
+
 def _refuse_for_recurrent(family, *, spec_decode, kv_host_tier_bytes,
                           role, mesh) -> None:
-    """What cannot carry a recurrent state yet refuses, loudly, before
-    anything is built: a spec-decode rewind moves `pos` back and a
-    recurrent state has no earlier value to go back to; the host tier
-    and the handoff move K/V blocks and would leave the state behind;
-    the state has no sharding rule."""
+    """What cannot carry a cache that is not plain K/V yet refuses,
+    loudly, before anything is built.  A recurrent state: a spec-decode
+    rewind moves `pos` back and the state has no earlier value to go
+    back to; the host tier and the handoff move K/V blocks and would
+    leave the state behind; the state has no sharding rule.  A latent
+    pool: the family has no verify program; the host tier and the
+    handoff move rows of ONE shape where the pool's two tensors differ;
+    a latent has no heads axis to shard."""
+    kind = families.cache_kind(family)
     asked = {"spec_decode": spec_decode is not None,
              "kv_host_tier_bytes": kv_host_tier_bytes is not None,
              f"role={role!r}": role != "both", "mesh": mesh is not None}
     for option, on in asked.items():
         if on:
             raise ValueError(
-                f"family {family!r} keeps a {families.RECURRENT} cache "
-                f"(one recurrent state per slot beside the K/V pool), "
+                f"family {family!r} keeps a {kind} cache "
+                f"({_CACHE_HOLDS[kind]}), "
                 f"which {option} cannot carry yet: refused")
 
 
@@ -142,7 +154,7 @@ class EngineOptions:
     def __post_init__(self):
         if self.family not in families.FAMILIES:
             raise ValueError(f"unknown LM family {self.family!r}")
-        if families.cache_kind(self.family) == families.RECURRENT:
+        if families.cache_kind(self.family) in _CACHE_HOLDS:
             _refuse_for_recurrent(
                 self.family, spec_decode=self.spec_decode,
                 kv_host_tier_bytes=self.kv_host_tier_bytes,

@@ -215,6 +215,35 @@ def _engine_metrics() -> Dict[str, Any]:
                     "admissions that matched resident K/V blocks but "
                     "no snapshot of the state: prefilled in full",
                     tag_keys=tags),
+                # a sparse expert layer's routing on this chip, summed
+                # over the fused programs of one kind (decode, prefill);
+                # a mean is a sum over serve_expert_programs_total
+                "expert_programs": Counter(
+                    "serve_expert_programs_total",
+                    "fused programs whose expert counters landed",
+                    tag_keys=tags + ("program",)),
+                "expert_assignments_local": Counter(
+                    "serve_expert_assignments_local_total",
+                    "(token, expert) assignments that fell on experts "
+                    "this chip holds, over all layers",
+                    tag_keys=tags + ("program",)),
+                "expert_touched_share": Counter(
+                    "serve_expert_touched_share_sum",
+                    "per program: held experts with at least one "
+                    "token over the held, mean over layers; summed",
+                    tag_keys=tags + ("program",)),
+                "expert_load_max_over_mean": Counter(
+                    "serve_expert_load_max_over_mean_sum",
+                    "per program: the fullest held expert's tokens "
+                    "over the held experts' mean, worst layer; summed",
+                    tag_keys=tags + ("program",)),
+                "expert_held": Gauge(
+                    "serve_expert_held",
+                    "routed experts this chip holds", tag_keys=tags),
+                "expert_of": Gauge(
+                    "serve_expert_of",
+                    "routed experts a token is scored over",
+                    tag_keys=tags),
                 "recurrent_snapshot_evictions": Counter(
                     "serve_recurrent_snapshot_evictions_total",
                     "snapshot entries dropped, least recently used or "
@@ -535,6 +564,9 @@ class EngineTelemetry:
         #: a recurrent family's state and snapshot counters
         #: (kv_pager.StateSnapshots.stats); None for the other families
         self._recurrent: Optional[Dict[str, int]] = None
+        #: {program kind: sums of decode_common.EXPERT_COUNTERS} of a
+        #: family with a sparse expert layer; empty for the others
+        self._experts: Dict[str, Dict[str, float]] = {}
         #: round-19 healthwatch block (serve/health.py) the deployment
         #: refreshes from its fleet HealthMonitor — zero-shaped when
         #: no monitor watches this engine (standalone / disabled)
@@ -1090,6 +1122,30 @@ class EngineTelemetry:
                 self._m[f"recurrent_snapshot_{name}"].inc(
                     delta, tags=self._tags)
 
+    def record_experts(self, program: str, counters) -> None:
+        """One fused program's expert counters (decode_common
+        .EXPERT_COUNTERS, in that order), landed with its tokens:
+        summed under `program` ("decode" or "prefill") into
+        ``engine_stats()["experts"]`` and the ``serve_expert_*``
+        metrics."""
+        held, of, local, touched, worst = (float(v) for v in counters)
+        with self._lock:
+            acc = self._experts.setdefault(program, {
+                "programs": 0, "assignments_local": 0.0,
+                "touched_share": 0.0, "load_max_over_mean": 0.0})
+            acc.update(held=int(held), of=int(of))
+            acc["programs"] += 1
+            acc["assignments_local"] += local
+            acc["touched_share"] += touched
+            acc["load_max_over_mean"] += worst
+        tags = dict(self._tags, program=program)
+        self._m["expert_programs"].inc(tags=tags)
+        self._m["expert_assignments_local"].inc(local, tags=tags)
+        self._m["expert_touched_share"].inc(touched, tags=tags)
+        self._m["expert_load_max_over_mean"].inc(worst, tags=tags)
+        self._m["expert_held"].set(held, tags=self._tags)
+        self._m["expert_of"].set(of, tags=self._tags)
+
     def record_health(self, block: Dict[str, Any]) -> None:
         """Latest healthwatch block (serve/health.py
         ``HealthMonitor.replica_block``) — mirrored into
@@ -1314,6 +1370,7 @@ class EngineTelemetry:
             kv_scope = self._kv_scope
             kv_tier = self._kv_tier
             recurrent = self._recurrent
+            experts = {k: dict(v) for k, v in self._experts.items()}
             health = self._health_block
             spec = dict(self._spec)
             chunks = dict(self._chunks)
@@ -1382,6 +1439,18 @@ class EngineTelemetry:
             # (zero-shaped for the families that keep K/V alone)
             "recurrent": dict(recurrent if recurrent is not None
                               else EMPTY_RECURRENT),
+            # a sparse expert layer's routing on this chip, by program
+            # kind (empty for a family without one): means over the
+            # programs whose counters landed
+            "experts": {
+                kind: {"held": acc["held"], "of": acc["of"],
+                       "programs": acc["programs"],
+                       "assignments_local": int(acc["assignments_local"]),
+                       "experts_touched_share": round(
+                           acc["touched_share"] / acc["programs"], 4),
+                       "load_max_over_mean": round(
+                           acc["load_max_over_mean"] / acc["programs"], 4)}
+                for kind, acc in sorted(experts.items())},
             # round-19: healthwatch — liveness state machine counters
             # (stable zero-shaped block when no HealthMonitor watches
             # this engine: standalone, dense, or RAYTPU_HEALTHWATCH=0)

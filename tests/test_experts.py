@@ -1,0 +1,202 @@
+"""The dropless expert layer that is told which experts it holds
+(ray_tpu/models/experts.py), on the CPU at small sizes: the router's
+arithmetic, nothing dropped under the worst imbalance, the shares of a
+partition of the experts adding up to the uncut layer, the counters."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import experts as ex
+
+D, F, E, K = 32, 16, 16, 4
+
+
+def _cfg(**kw):
+    kw = {"d_model": D, "d_expert": F, "n_routed": E, "top_k": K,
+          "route_scale": 2.827, "dtype": jnp.float32, **kw}
+    return ex.ExpertsConfig(**kw)
+
+
+def _x(n, seed=1):
+    return jax.random.normal(jax.random.PRNGKey(seed), (n, D), jnp.float32)
+
+
+def _dense_reference(p, x, cfg, held=None):
+    """Every expert applied to every token, masked: no sort, no groups."""
+    chosen, w = ex.route(p["router"], x, cfg)
+    held = cfg.held_ids if held is None else held
+    y = jnp.zeros_like(x)
+    for place, e in enumerate(cfg.held_ids):
+        if e not in held:
+            continue
+        mine = jnp.sum(jnp.where(chosen == e, w, 0.0), axis=-1)
+        one = {k: v[place] for k, v in p["experts"].items()}
+        y = y + mine[:, None] * ex._swiglu(x, one, jnp.float32)
+    return y
+
+
+@pytest.fixture(scope="module")
+def whole():
+    cfg = _cfg()
+    return cfg, ex.experts_init(jax.random.PRNGKey(0), cfg, std=0.3)
+
+
+def test_the_bias_selects_and_does_not_weigh(whole):
+    cfg, p = whole
+    x = _x(24)
+    router = dict(p["router"], bias=jnp.zeros((E,)).at[5].set(10.0))
+    chosen, w = ex.route(router, x, cfg)
+    assert bool(jnp.all(jnp.any(chosen == 5, axis=-1)))
+    sigma = jax.nn.sigmoid(x @ p["router"]["w"])
+    picked = jnp.take_along_axis(sigma, chosen, axis=-1)
+    want = picked / picked.sum(-1, keepdims=True) * 2.827
+    np.testing.assert_allclose(np.asarray(w), np.asarray(want), rtol=1e-5)
+
+
+@pytest.mark.parametrize("norm,scale", [(True, 2.827), (True, 1.0),
+                                        (False, 1.0)])
+def test_weights_are_normalised_and_scaled(whole, norm, scale):
+    cfg, p = whole
+    cfg = _cfg(norm_topk=norm, route_scale=scale)
+    _, w = ex.route(p["router"], _x(10), cfg)
+    total = np.asarray(w.sum(-1))
+    if norm:
+        np.testing.assert_allclose(total, scale, rtol=1e-5)
+    else:
+        assert np.all(total < K) and np.all(total > 0)
+
+
+def test_softmax_scoring_takes_the_top_of_a_distribution(whole):
+    cfg, p = whole
+    cfg = _cfg(scoring="softmax", norm_topk=False, route_scale=1.0)
+    chosen, w = ex.route(p["router"], _x(10), cfg)
+    probs = jax.nn.softmax(_x(10) @ p["router"]["w"], axis=-1)
+    top = jnp.sort(probs, axis=-1)[:, -K:].sum(-1)
+    np.testing.assert_allclose(np.asarray(w.sum(-1)), np.asarray(top),
+                               rtol=1e-5)
+
+
+@pytest.mark.parametrize("tiled", [True, False])
+@pytest.mark.parametrize("n", [7, 64, 300])
+def test_routed_part_equals_every_expert_under_a_mask(whole, n, tiled):
+    cfg, p = whole
+    x = _x(n, seed=n)
+    chosen, w = ex.route(p["router"], x, cfg)
+    y, _ = ex.routed_experts(p["experts"], x, chosen, w, cfg, tiled=tiled)
+    np.testing.assert_allclose(np.asarray(y),
+                               np.asarray(_dense_reference(p, x, cfg)),
+                               atol=2e-5)
+
+
+def test_no_drop_when_every_token_chooses_one_expert(whole):
+    """The worst imbalance: the selection bias sends all 200 tokens to
+    expert 3 (and to three more); every one of them is computed, through
+    several tiles of the sorted rows."""
+    cfg, p = whole
+    cfg = _cfg(tile_rows=128)
+    x = _x(200, seed=9)
+    router = dict(p["router"], bias=jnp.zeros((E,)).at[3].set(10.0))
+    chosen, w = ex.route(router, x, cfg)
+    y, stats = ex.routed_experts(p["experts"], x, chosen, w, cfg)
+    want = jnp.zeros_like(x)
+    for e in range(E):
+        mine = jnp.sum(jnp.where(chosen == e, w, 0.0), axis=-1)
+        one = {k: v[e] for k, v in p["experts"].items()}
+        want = want + mine[:, None] * ex._swiglu(x, one, jnp.float32)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=2e-5)
+    assert float(stats[0]) == 200 * K            # every assignment local
+    assert float(stats[2]) >= 200 / (200 * K / E) - 1e-6
+
+
+@pytest.mark.parametrize("shares", [4, 2])
+def test_the_shares_add_up_to_the_uncut_layer(whole, shares):
+    """16 experts over `shares` chips: the routed parts of all shares
+    plus the shared expert ONCE equal the uncut layer."""
+    cfg, p = whole
+    x = _x(96, seed=5)
+    uncut, _ = ex.moe_layer(p, x, cfg)
+    per = E // shares
+    total = ex.shared_expert(p["shared"], x, cfg)
+    touched = 0.0
+    for c in range(shares):
+        held = ex.held_range(c * per, per)
+        part_cfg = _cfg(held=held)
+        part = dict(p, experts={k: v[c * per:(c + 1) * per]
+                                for k, v in p["experts"].items()})
+        chosen, w = ex.route(p["router"], x, part_cfg)
+        y, stats = ex.routed_experts(part["experts"], x, chosen, w,
+                                     part_cfg)
+        total = total + y
+        touched += float(stats[0])
+    np.testing.assert_allclose(np.asarray(total), np.asarray(uncut),
+                               atol=3e-5)
+    assert touched == 96 * K          # every assignment fell on one share
+
+
+def test_a_stack_of_layers_is_taken_whole(whole):
+    """`layer` picks one layer's experts out of a stack without slicing
+    it: the other layers' groups have no rows."""
+    cfg, p = whole
+    other = ex.experts_init(jax.random.PRNGKey(7), cfg, std=0.3)
+    stack = jax.tree.map(lambda a, b: jnp.stack([a, b]), other["experts"],
+                         p["experts"])
+    x = _x(40)
+    chosen, w = ex.route(p["router"], x, cfg)
+    alone, _ = ex.routed_experts(p["experts"], x, chosen, w, cfg)
+    picked, _ = ex.routed_experts(stack, x, chosen, w, cfg,
+                                  layer=jnp.int32(1))
+    np.testing.assert_allclose(np.asarray(picked), np.asarray(alone),
+                               atol=1e-6)
+
+
+def test_rows_without_a_token_are_routed_nowhere(whole):
+    cfg, p = whole
+    x = _x(32)
+    chosen, w = ex.route(p["router"], x, cfg)
+    valid = jnp.arange(32) < 20
+    y, stats = ex.routed_experts(p["experts"], x, chosen, w, cfg, valid)
+    assert float(jnp.abs(y[20:]).max()) == 0.0
+    assert float(stats[0]) == 20 * K
+    full, _ = ex.routed_experts(p["experts"], x, chosen, w, cfg)
+    np.testing.assert_allclose(np.asarray(y[:20]), np.asarray(full[:20]),
+                               atol=1e-6)
+
+
+def test_the_counters_count_the_held_experts_load():
+    cfg = _cfg(held=(0, 1, 2, 3))
+    p = ex.experts_init(jax.random.PRNGKey(0), cfg)
+    x = _x(50)
+    chosen, w = ex.route(p["router"], x, cfg)
+    _, stats = ex.routed_experts(p["experts"], x, chosen, w, cfg)
+    counts = np.asarray([(np.asarray(chosen) == e).sum() for e in range(4)])
+    assert float(stats[0]) == counts.sum()
+    assert float(stats[1]) == (counts > 0).sum()
+    np.testing.assert_allclose(float(stats[2]),
+                               counts.max() / counts.mean(), rtol=1e-6)
+
+
+@pytest.mark.parametrize("n_assign,want", [(512, 128), (65536, 2048),
+                                           (8192, 512), (64, 64)])
+def test_tile_rows_follow_the_chips_share(n_assign, want):
+    cfg = ex.ExpertsConfig(d_model=8, d_expert=8, n_routed=384, top_k=8,
+                           held=ex.held_range(0, 12))
+    assert ex.tile_rows(n_assign, cfg) == want
+
+
+@pytest.mark.parametrize("bad", [{"held": (0, 0)}, {"held": (99,)},
+                                 {"top_k": 0}, {"scoring": "tanh"}])
+def test_a_wrong_configuration_is_refused(bad):
+    with pytest.raises(ValueError):
+        _cfg(**bad)
+
+
+def test_the_layer_differentiates_untiled(whole):
+    cfg, p = whole
+    x = _x(16)
+    g = jax.grad(lambda q: jnp.sum(ex.moe_layer(q, x, cfg,
+                                                tiled=False)[0] ** 2))(p)
+    assert float(jnp.abs(g["experts"]["w_down"]).max()) > 0
+    assert ex.experts_param_count(cfg) == sum(
+        a.size for a in jax.tree.leaves(p))

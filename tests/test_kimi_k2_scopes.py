@@ -1,0 +1,92 @@
+"""What the Kimi-K2 programs call their own parts on the profiler's
+timeline (ray_tpu/_private/scopes.py): ``mla``, ``moe_router``,
+``moe_experts`` beside the scopes every family has, at most a tenth of
+the operations outside any, and the compiler's own grouped-matmul
+kernel named after what it was made from."""
+
+import collections
+
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+
+from ray_tpu._private import scopes  # noqa: E402
+from ray_tpu.models.decode_common import sample_token  # noqa: E402
+from ray_tpu.models.kimi_k2 import (kimi_k2_config,  # noqa: E402
+                                    kimi_k2_init)
+from ray_tpu.models.kimi_k2_decode import (  # noqa: E402
+    kimi_k2_decode_step, kimi_k2_init_cache, kimi_k2_init_paged_cache,
+    kimi_k2_paged_prefill)
+from tests.test_scopes import _op_scopes  # noqa: E402
+
+CFG = kimi_k2_config("nano", held=(0, 1, 2, 3, 4, 5))
+EVERY = {"embed", "ln", "mla", "kv_pool", "mlp", "moe_router",
+         "moe_experts", "lm_head", "sample", "layer_scan"}
+
+
+@pytest.fixture(scope="module")
+def params():
+    return kimi_k2_init(jax.random.PRNGKey(0), CFG)
+
+
+def _lowered(name, params):
+    key = jax.random.PRNGKey(1)
+    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
+    paged = kimi_k2_init_paged_cache(CFG, 2, num_blocks=20, block_size=16)
+
+    def pool_step(p, cache, toks, key):
+        logits, cache = kimi_k2_decode_step(p, cache, toks, CFG)
+        return sample_token(logits, key, 0.0, None), cache
+
+    def prefill_sample(p, cache, toks, row_bt, key):
+        logits, cache = kimi_k2_paged_prefill(
+            p, cache, toks, CFG, row_bt=row_bt, prefix_len=0, n_tail=5,
+            slot=0)
+        return sample_token(logits[None], key, 0.0, None), cache
+
+    if name == "decode_step":
+        return jax.jit(pool_step).lower(params, paged, i32(2), key)
+    if name == "decode_step_dense":
+        return jax.jit(pool_step).lower(
+            params, kimi_k2_init_cache(CFG, 2), i32(2), key)
+    return jax.jit(prefill_sample).lower(params, paged, i32(1, 16), i32(8),
+                                         key)
+
+
+@pytest.mark.parametrize("program", ["decode_step", "decode_step_dense",
+                                     "paged_prefill"])
+def test_at_most_a_tenth_of_a_program_is_unscoped(program, params):
+    ops = _op_scopes(_lowered(program, params))
+    assert len(ops) > 100
+    found = collections.Counter(s for _, s in ops)
+    assert set(found) - {None} == EVERY
+    loose = [op for op, s in ops if s is None]
+    assert len(loose) <= 0.10 * len(ops), collections.Counter(loose)
+    alone = collections.Counter(
+        op for op, s in ops if s in scopes.CONTAINER_SCOPES)
+    assert not {"stablehlo.dot_general", "stablehlo.exponential",
+                "stablehlo.gather", "stablehlo.scatter",
+                "chlo.ragged_dot"} & set(alone), alone
+
+
+@pytest.mark.parametrize("op_name,scope", [
+    ("ragged-dot-none", "moe_experts"),
+    ("ragged-dot-metadata", "moe_experts"),
+    ("jit(pool_step)/layer_scan/while/body/closed_call/moe_experts/"
+     "while/body/ragged_dot_general", "moe_experts"),
+    ("jit(pool_step)/layer_scan/while/body/closed_call/moe_router/"
+     "dot_general", "moe_router"),
+    ("jit(pool_step)/layer_scan/while/body/closed_call/mla/kv_pool/"
+     "gather", "kv_pool"),
+    ("jit(pool_step)/layer_scan/while/body/closed_call/mla/exp", "mla"),
+])
+def test_innermost_scope_of_the_new_names(op_name, scope):
+    assert scopes.innermost_scope(op_name) == scope
+
+
+def test_the_new_scopes_are_registered_and_hold_no_other():
+    new = {scopes.MLA, scopes.MOE_ROUTER, scopes.MOE_EXPERTS}
+    assert new <= scopes.DEVICE_SCOPES
+    assert not new & scopes.CONTAINER_SCOPES
+    assert set(scopes.REWRITTEN.values()) <= scopes.DEVICE_SCOPES

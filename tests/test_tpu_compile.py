@@ -273,3 +273,60 @@ def test_sharded_attention_compiles_on_four_devices():
 
     # the triangle forward and its one-pass backward
     assert _kernels_in(fwd_bwd, x, x, x, mesh=mesh) >= 2
+
+
+@pytest.mark.parametrize("program,t_pad", [("decode", 0), ("prefill", 8192)])
+def test_kimi_k2_programs_fit_the_chip_at_the_published_widths(program,
+                                                               t_pad):
+    """The cell kimi-k2-code.serve-offline-codegen's two programs as the
+    engine builds them (benchmark/families/kimi_k2.py
+    aot_serve_programs): published widths, 1 + 5 layers, 12 of 384
+    experts held, 20,480 rows of the vocabulary, bf16 weights, 64 slots
+    over a 4 GiB latent pool, the 8,192-token prefill bucket.  The
+    compiled peak (weights and pool among it) stays under 15 GB of the
+    chip's 16: the room left is the reference's at warm-up.  The
+    experts' grouped matmuls are kernels (XLA's own lowering of
+    ragged_dot), scoped ``moe_experts`` by their name; the 512-wide
+    latent pool is neither copied nor re-laid whole."""
+    from benchmark.cells import load_cell
+    from ray_tpu._private import scopes
+
+    cell = load_cell("kimi-k2-code.serve-offline-codegen")
+    family, spec = cell.family, cell.traffic["engine"]
+    cfg = family.program(cell.config, {
+        "max_seq": cell.traffic["config_overrides"]["max_seq"],
+        "param_dtype": jnp.bfloat16}).cfg
+    place = _one_chip()
+    cache_shapes, programs = family.aot_serve_programs(
+        cfg, spec["max_slots"], spec["kv_block_size"], t_pad or 1024,
+        place)
+    n_blocks = spec["kv_pool_bytes"] // (
+        family.kv_bytes_per_token(cell.config) * spec["kv_block_size"])
+    abstract = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: place(a.shape, a.dtype), tree)
+    from ray_tpu.models.kimi_k2 import kimi_k2_init
+
+    params = abstract(jax.eval_shape(
+        lambda: kimi_k2_init(jax.random.PRNGKey(0), cfg)))
+    cache = abstract(cache_shapes(n_blocks))
+    assert sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params)
+               ) < 8.4e9
+    fn, args = next((f, a) for name, f, a in programs if name == program)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args).compile()
+    memory = compiled.memory_analysis()
+    assert memory.peak_memory_in_bytes < 15e9, memory
+    assert memory.alias_size_in_bytes >= 4.29e9      # the pool, in place
+    text = compiled.as_text()
+    kernels = {name: scope for name, keyed in
+               scopes.scope_map_from_hlo(text).items()
+               for key, scope in keyed.items() if "ragged-dot" in name
+               and "custom-call" in key}
+    assert kernels and set(kernels.values()) == {scopes.MOE_EXPERTS}
+    # no copy of the whole 3.8 GB latent pool, nor of a layer of it
+    pool = f"bf16[{cfg.n_layer},{n_blocks},16,512]"
+    layer = f"bf16[{n_blocks},16,512]"
+    for line in text.splitlines():
+        body = line.split(" = ", 1)[-1]
+        if body.startswith((pool, layer)):
+            assert " copy(" not in body and " transpose(" not in body, line
