@@ -77,9 +77,15 @@ FLASH_RES_FWD, FLASH_RES_DQ, FLASH_RES_DKV = (
 FLASH_TRI_FWD, FLASH_TRI_BWD = "flash_tri_fwd", "flash_tri_bwd"
 #: a Mamba mixer's recurrence over a program's columns (ops/ssm_scan.py)
 SSM_SCAN = "ssm_scan"
+#: a decode column's latent attention over the paged latent pool where
+#: it lies (ops/mla_paged_decode.py)
+MLA_PAGED_DECODE = "mla_paged_decode"
+#: ... and the rotary pool's re-lay for it, once a decode step
+MLA_ROTARY_LANES = "mla_rotary_lanes"
 KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV,
            FLASH_RES_FWD, FLASH_RES_DQ, FLASH_RES_DKV,
-           FLASH_TRI_FWD, FLASH_TRI_BWD, SSM_SCAN)
+           FLASH_TRI_FWD, FLASH_TRI_BWD, SSM_SCAN, MLA_PAGED_DECODE,
+           MLA_ROTARY_LANES)
 
 # -- host phases -------------------------------------------------------------
 SPAN_PREFIX = "raytpu."

@@ -212,6 +212,14 @@ class PagedKV:
     are whole lane tiles (512 wide) is taken where it lies
     (``in_place``); a narrower one still goes by the layer.
 
+    A third way to read the pool makes no view at all: on the chip the
+    latent family's decode step does not call `attend` but hands the
+    whole pool to a kernel that walks each row's block table over it,
+    as far as the row's length reaches (kimi_k2_decode.attend_paged,
+    ops/mla_paged_decode.py; PERF.md, PR 33).  The class is then the
+    step's index arithmetic and its `commit`: the pool stays read-only
+    in the scan and the rows land after it, as above.
+
     (Otherwise the layer is sliced out before the gather on purpose.  A TPU
     stores the pool with the block axis minor-most, the only order of
     this shape its tiles do not pad, and a gather or a scatter by block

@@ -37,6 +37,7 @@ from ray_tpu.models.kimi_k2 import (KimiK2Config, attend_absorbed,
                                     attend_expanded, block, embed,
                                     expand_keys, expert_counters,
                                     lm_logits, softmax_scale, walk_layers)
+from ray_tpu.ops.mla_paged_decode import mla_paged_decode, rotary_lanes
 
 __all__ = ["kimi_k2_init_cache", "kimi_k2_init_paged_cache",
            "kimi_k2_prefill", "kimi_k2_paged_prefill",
@@ -163,6 +164,27 @@ def attend_blockwise(q, ckv, kpe, p, logical, real, cfg: KimiK2Config):
         T, H, cfg.v_head_dim)
 
 
+@jax.named_scope(scopes.MLA)
+def attend_paged(q, ckv_pool, rope_lanes, cache, lidx, p, fresh,
+                 cfg: KimiK2Config):
+    """`attend_absorbed` for one decode column of every row of a paged
+    cache, over the latent pool where it lies: q (B, 1, H, qk); the
+    whole latent pool and ``rotary_lanes`` of the rotary pool; `fresh`
+    = this column's (ckv (B, 1, c), kpe (B, 1, r)).  The walk over each
+    row's blocks, the running softmax and the weighted sum are one
+    kernel (ops/mla_paged_decode.py); ``W_uk`` and ``W_uv`` stay the
+    einsums they are."""
+    dt, n = cfg.dtype, cfg.qk_nope_dim
+    q_lat = jnp.einsum("bthn,chn->bthc", q[..., :n], p["wk_b"].astype(dt))
+    o_lat = mla_paged_decode(
+        q_lat[:, 0], q[:, 0, :, n:], ckv_pool, rope_lanes,
+        cache["block_tables"], cache["pos"], lidx,
+        (fresh[0][:, 0], fresh[1][:, 0]), scale=softmax_scale(cfg),
+        start=cache["start"])
+    return jnp.einsum("bthc,chv->bthv", o_lat[:, None],
+                      p["wv_b"].astype(dt))
+
+
 def _with_counters(cache, cfg: KimiK2Config, stats):
     with jax.named_scope(scopes.MOE_EXPERTS):
         cache[EXPERTS] = expert_counters(cfg, stats)
@@ -271,6 +293,11 @@ def kimi_k2_decode_step(params, cache, tokens, cfg: KimiK2Config
     Returns (logits (B, padded_vocab) float32, updated cache)."""
     B = tokens.shape[0]
     paged = is_paged(cache)
+    # what the program can see of its input picks the path (a paged
+    # cache, one column a row, the chip): the kernel walks the pool's
+    # blocks where they lie; the CPU gathers the views and keeps the
+    # jnp path, the parity oracle
+    in_place = paged and jax.default_backend() == "tpu"
     pos, start = cache["pos"], cache["start"]
     rows = jnp.arange(B)
     with jax.named_scope(scopes.MLA):
@@ -281,6 +308,10 @@ def kimi_k2_decode_step(params, cache, tokens, cfg: KimiK2Config
                          cfg.max_seq)[:, None]                  # (B,1,S)
     pkv = PagedKV(cache, cache["block_tables"], pos[:, None],
                   whole=True) if paged else None
+    if in_place:
+        with jax.named_scope(scopes.KV_POOL):
+            # once for all layers: the pools are read-only in the scan
+            rope = rotary_lanes(cache["kpe"])
     x = embed(params, tokens, cfg)[:, None]                     # (B,1,d)
 
     def layer(x, pools, p, lidx):
@@ -290,6 +321,10 @@ def kimi_k2_decode_step(params, cache, tokens, cfg: KimiK2Config
             nonlocal pools
             if paged:
                 new.extend((ckv, kpe))                   # (B, 1, width)
+            if in_place:
+                return attend_paged(q, pools[0], rope, cache, lidx,
+                                    p["attn"], (ckv, kpe), cfg)
+            elif paged:
                 pools, views = pkv.attend(lidx, pools, ckv, kpe)
                 return attend_absorbed(q, *views, p["attn"], mask, cfg,
                                        fresh=(ckv, kpe))

@@ -1353,10 +1353,21 @@ class LLMEngine(EngineBase):
                 self.params, self._cache, toks, k)
             counters = self._counters()
         rows = self._decoding()
+        walked = 0
         for st in rows.values():
-            st["due"] = st.get("due", 0) + 1
-        self._flight.append({"toks": toks, "t0": wave.t0, "rows": rows,
-                             "experts": counters})
+            due = st.get("due", 0)
+            # the row's position in this wave, from the lengths the
+            # host holds: its prompt, its tokens landed and in flight
+            # (the first of them came out of the prefill)
+            walked += -(-(len(st["prompt"]) + len(st["out"]) + due - 1)
+                        // self.opt.kv_block_size)
+            st["due"] = due + 1
+        item = {"toks": toks, "t0": wave.t0, "rows": rows,
+                "experts": counters}
+        if self._pager is not None:
+            item["walk"] = (walked, len(rows) * (
+                self.cfg.max_seq // self.opt.kv_block_size))
+        self._flight.append(item)
 
     def _land(self) -> None:
         """Fence what has been in flight longest.  A decode wave:
@@ -1370,6 +1381,8 @@ class LLMEngine(EngineBase):
             # the wave's one host fence
             toks = np.asarray(item["toks"])
             self._book_counters("decode", item["experts"])
+            if "walk" in item:
+                self._telemetry.record_kv_walk(*item["walk"])
         rows = {i: st for i, st in item["rows"].items()
                 if self._slots[i] is st}
         if not rows:

@@ -244,6 +244,17 @@ def _engine_metrics() -> Dict[str, Any]:
                     "serve_expert_of",
                     "routed experts a token is scored over",
                     tag_keys=tags),
+                # a paged decode wave's rows: the blocks that hold their
+                # positions over the blocks their tables have room for
+                "kv_walk_blocks_walked": Counter(
+                    "serve_kv_walk_blocks_walked_total",
+                    "blocks that hold the positions of a decode "
+                    "wave's rows, summed over the waves landed",
+                    tag_keys=tags),
+                "kv_walk_blocks_tabled": Counter(
+                    "serve_kv_walk_blocks_tabled_total",
+                    "entries of those rows' block tables, summed "
+                    "over the waves landed", tag_keys=tags),
                 "recurrent_snapshot_evictions": Counter(
                     "serve_recurrent_snapshot_evictions_total",
                     "snapshot entries dropped, least recently used or "
@@ -567,6 +578,9 @@ class EngineTelemetry:
         #: {program kind: sums of decode_common.EXPERT_COUNTERS} of a
         #: family with a sparse expert layer; empty for the others
         self._experts: Dict[str, Dict[str, float]] = {}
+        #: paged decode waves landed; the blocks their rows' positions
+        #: fill; the entries of those rows' tables
+        self._kv_walk = [0, 0, 0]
         #: round-19 healthwatch block (serve/health.py) the deployment
         #: refreshes from its fleet HealthMonitor — zero-shaped when
         #: no monitor watches this engine (standalone / disabled)
@@ -1146,6 +1160,21 @@ class EngineTelemetry:
         self._m["expert_held"].set(held, tags=self._tags)
         self._m["expert_of"].set(of, tags=self._tags)
 
+    def record_kv_walk(self, walked: int, tabled: int) -> None:
+        """One paged decode wave, landed with its tokens: `walked`
+        blocks hold its rows' positions (the sum of ceil(pos /
+        block) over the rows the wave stepped) of the `tabled`
+        entries their block tables have (rows x max_seq / block).
+        What a program that reads the pool by each row's length
+        touches of what one that gathers whole tables does:
+        ``engine_stats()["kv_walk"]``, ``serve_kv_walk_*``."""
+        with self._lock:
+            self._kv_walk[0] += 1
+            self._kv_walk[1] += walked
+            self._kv_walk[2] += tabled
+        self._m["kv_walk_blocks_walked"].inc(walked, tags=self._tags)
+        self._m["kv_walk_blocks_tabled"].inc(tabled, tags=self._tags)
+
     def record_health(self, block: Dict[str, Any]) -> None:
         """Latest healthwatch block (serve/health.py
         ``HealthMonitor.replica_block``) — mirrored into
@@ -1371,6 +1400,7 @@ class EngineTelemetry:
             kv_tier = self._kv_tier
             recurrent = self._recurrent
             experts = {k: dict(v) for k, v in self._experts.items()}
+            walk_waves, walked, tabled = self._kv_walk
             health = self._health_block
             spec = dict(self._spec)
             chunks = dict(self._chunks)
@@ -1451,6 +1481,13 @@ class EngineTelemetry:
                        "load_max_over_mean": round(
                            acc["load_max_over_mean"] / acc["programs"], 4)}
                 for kind, acc in sorted(experts.items())},
+            # paged decode waves: the blocks that hold their rows'
+            # positions over the entries of those rows' block tables
+            # (zeros for a dense cache)
+            "kv_walk": {"waves": walk_waves, "blocks_walked": walked,
+                        "blocks_tabled": tabled,
+                        "walked_share": round(walked / tabled, 4)
+                        if tabled else 0.0},
             # round-19: healthwatch — liveness state machine counters
             # (stable zero-shaped block when no HealthMonitor watches
             # this engine: standalone, dense, or RAYTPU_HEALTHWATCH=0)

@@ -80,6 +80,8 @@ def test_at_most_a_tenth_of_a_program_is_unscoped(program, params):
     ("jit(pool_step)/layer_scan/while/body/closed_call/mla/kv_pool/"
      "gather", "kv_pool"),
     ("jit(pool_step)/layer_scan/while/body/closed_call/mla/exp", "mla"),
+    ("jit(pool_step)/layer_scan/while/body/closed_call/mla/"
+     "jit(mla_paged_decode)/pallas_call", "mla"),
 ])
 def test_innermost_scope_of_the_new_names(op_name, scope):
     assert scopes.innermost_scope(op_name) == scope
@@ -90,3 +92,33 @@ def test_the_new_scopes_are_registered_and_hold_no_other():
     assert new <= scopes.DEVICE_SCOPES
     assert not new & scopes.CONTAINER_SCOPES
     assert set(scopes.REWRITTEN.values()) <= scopes.DEVICE_SCOPES
+
+
+def test_the_paged_decode_kernel_is_named_and_scoped_mla(params,
+                                                         monkeypatch):
+    """On the chip the paged decode step holds the kernel
+    ``mla_paged_decode`` (ray_tpu/ops/mla_paged_decode.py), one call in
+    each scan over layers, under ``mla``: not unscoped, not ``kv_pool``
+    (a reader divides the kernel's bytes by the time under ``mla``).
+    Traced only: tests/test_tpu_compile.py reads the compiled
+    program's own scope map."""
+    assert scopes.MLA_PAGED_DECODE in scopes.KERNELS
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    paged = kimi_k2_init_paged_cache(CFG, 2, num_blocks=20, block_size=16)
+    jaxpr = jax.make_jaxpr(
+        lambda p, c, t: kimi_k2_decode_step(p, c, t, CFG))(
+            params, paged, jnp.zeros((2,), jnp.int32))
+    found = []
+
+    def walk(inner, stack):
+        for eqn in inner.eqns:
+            here = f"{stack}/{eqn.source_info.name_stack}"
+            if eqn.primitive.name == "pallas_call":
+                found.append((eqn.params["name"], here))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, here)
+
+    walk(jaxpr.jaxpr, "jit(pool_step)")
+    assert [name for name, _ in found] == [scopes.MLA_PAGED_DECODE] * 2
+    assert {scopes.innermost_scope(stack) for _, stack in found} \
+        == {scopes.MLA}

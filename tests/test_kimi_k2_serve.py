@@ -121,6 +121,35 @@ def test_the_expert_counters_land_with_the_tokens():
                for tags, _ in dump["values"])
 
 
+def test_the_blocks_walked_land_with_the_tokens():
+    """Two requests one after the other, 40 and 21 prompt tokens, 6 new
+    each: the first comes out of the prefill, five decode waves step
+    the row at positions n .. n + 4, which fill ceil(pos / 16) blocks
+    (3 each for 40 .. 44, 2 each for 21 .. 25) of the table's
+    max_seq / 16 entries."""
+    _, stats, _ = _serve(_build(), [A, C])
+    walk = stats["kv_walk"]
+    tabled = kimi_k2_config("nano", **_OVR).max_seq // 16
+    assert walk["waves"] == 2 * (MAX_NEW - 1)
+    assert walk["blocks_walked"] == 5 * 3 + 5 * 2
+    assert walk["blocks_tabled"] == walk["waves"] * tabled
+    assert walk["walked_share"] == round(25 / (10 * tabled), 4)
+    # two rows in one wave: both rows' blocks, both rows' tables
+    _, stats, _ = _serve(_build(), [A, C], together=True)
+    walk = stats["kv_walk"]
+    assert walk["blocks_walked"] == 5 * 3 + 5 * 2
+    assert walk["blocks_tabled"] == 10 * tabled
+    from ray_tpu.util.metrics import _registry
+
+    assert "serve_kv_walk_blocks_walked_total" in _registry.snapshot()
+
+
+def test_a_dense_cache_walks_no_blocks():
+    _, stats, _ = _serve(_build(kv_layout="dense"), [C])
+    assert stats["kv_walk"] == {"waves": 0, "blocks_walked": 0,
+                                "blocks_tabled": 0, "walked_share": 0.0}
+
+
 def test_a_family_without_experts_counts_none():
     dep = build_llm_deployment(
         "gpt2", "nano", temperature=0.0, scheduler="continuous",

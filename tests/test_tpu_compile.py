@@ -275,9 +275,40 @@ def test_sharded_attention_compiles_on_four_devices():
     assert _kernels_in(fwd_bwd, x, x, x, mesh=mesh) >= 2
 
 
+def test_mla_paged_decode_kernel_compiles_at_the_cells_shapes():
+    """ray_tpu.ops.mla_paged_decode at the Kimi-K2 cell's shapes: 64
+    rows, tables of 544 blocks of 16, 64 heads, a 512-wide latent pool
+    of 6 layers and 38,836 blocks, 64-wide rotary keys.  Two Mosaic
+    calls, the walk and the rotary keys' re-lay before it; both pools
+    go in as they are stored (no copy, no re-lay by the compiler: the
+    only temporary is the re-laid rotary keys, 0.48 GB)."""
+    from ray_tpu.ops.mla_paged_decode import mla_paged_decode, rotary_lanes
+
+    spec = _one_chip()
+    bf16 = lambda *shape: spec(shape, jnp.bfloat16)   # noqa: E731
+    i32 = lambda *shape: spec(shape, jnp.int32)   # noqa: E731
+    B, H, c, r, L, blocks = 64, 64, 512, 64, 6, 38836
+
+    def attend(q_lat, q_rope, ckv, kpe, tables, pos, lidx, cn, rn):
+        return mla_paged_decode(q_lat, q_rope, ckv, rotary_lanes(kpe),
+                                tables, pos, lidx, (cn, rn), scale=0.14)
+
+    compiled = jax.jit(attend).lower(
+        bf16(B, H, c), bf16(B, H, r), bf16(L, blocks, 16, c),
+        bf16(L, blocks, 16, r), i32(B, 544), i32(B), i32(), bf16(B, c),
+        bf16(B, r)).compile()
+    text = compiled.as_text()
+    calls = [line.split(" = ")[0].split("%")[-1]
+             for line in text.splitlines() if MOSAIC_CALL in line]
+    assert sorted(name.split(".")[0] for name in calls) == [
+        "mla_paged_decode", "mla_rotary_lanes"], calls
+    # room for the re-laid rotary keys (0.477 GB), not for a layer more
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.49e9
+
+
 @pytest.mark.parametrize("program,t_pad", [("decode", 0), ("prefill", 8192)])
-def test_kimi_k2_programs_fit_the_chip_at_the_published_widths(program,
-                                                               t_pad):
+def test_kimi_k2_programs_fit_the_chip_at_the_published_widths(
+        program, t_pad, monkeypatch):
     """The cell kimi-k2-code.serve-offline-codegen's two programs as the
     engine builds them (benchmark/families/kimi_k2.py
     aot_serve_programs): published widths, 1 + 5 layers, 12 of 384
@@ -287,9 +318,16 @@ def test_kimi_k2_programs_fit_the_chip_at_the_published_widths(program,
     chip's 16: the room left is the reference's at warm-up.  The
     experts' grouped matmuls are kernels (XLA's own lowering of
     ragged_dot), scoped ``moe_experts`` by their name; the 512-wide
-    latent pool is neither copied nor re-laid whole."""
+    latent pool is neither copied nor re-laid whole.  The decode step
+    is the chip's (the program asks ``jax.default_backend()``, steered
+    here): its attention is the kernel ``mla_paged_decode`` under
+    ``mla``, one call in each scan over layers, the rotary keys'
+    re-lay ``mla_rotary_lanes`` once before them under ``kv_pool``, and
+    no view of the rows' tables is gathered."""
     from benchmark.cells import load_cell
     from ray_tpu._private import scopes
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     cell = load_cell("kimi-k2-code.serve-offline-codegen")
     family, spec = cell.family, cell.traffic["engine"]
@@ -330,3 +368,26 @@ def test_kimi_k2_programs_fit_the_chip_at_the_published_widths(program,
         body = line.split(" = ", 1)[-1]
         if body.startswith((pool, layer)):
             assert " copy(" not in body and " transpose(" not in body, line
+    if program != "decode":
+        return
+    walks = {name: scope for name, keyed in
+             scopes.scope_map_from_hlo(text).items()
+             for key, scope in keyed.items()
+             if name.startswith(scopes.MLA_PAGED_DECODE)
+             and "custom-call" in key}
+    assert len(walks) == 2 and set(walks.values()) == {scopes.MLA}, walks
+    lanes = [scope for name, keyed in
+             scopes.scope_map_from_hlo(text).items()
+             for scope in keyed.values()
+             if name.startswith(scopes.MLA_ROTARY_LANES)]
+    assert lanes == [scopes.KV_POOL], lanes
+    # no temporary of the gathered view's size (64 rows x 8,704 slots x
+    # 512 x 2 B = 570 MB: the parent's program holds it under four
+    # shapes), and the temporaries together under the parent's 0.933 GB
+    # (0.491: the re-laid rotary keys and little else)
+    slots = 64 * cfg.max_seq
+    for view in (f"bf16[64,{cfg.max_seq},512]", f"bf16[{slots},512]",
+                 f"bf16[64,{cfg.max_seq // 16},16,512]",
+                 f"bf16[{slots // 16},16,512]"):
+        assert view not in text, view
+    assert memory.temp_size_in_bytes < 0.55e9, memory
