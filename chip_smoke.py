@@ -34,6 +34,10 @@ Phases, one chip:
            shared-prefix traffic, against models.gpt2_decode.generate.
   runtime  the README quick start: ray_tpu.init() finds the chip, a
            num_tpus=1 actor trains on it, a num_tpus=0 task stays off it.
+  mla      the latent-attention kernels of the Kimi-K2 cell at its
+           shapes, each against its jnp reference: the prefill's flash
+           kernel, the decode step's walk over the paged latent pool
+           and the rotary pool's re-lay.
 Phases, --chips 4 (and no one-chip phase):
   mesh_train    the train step over data=4 and data=2 x fsdp=2 against
                 the same step on device 0.
@@ -56,7 +60,7 @@ import sys
 import time
 from typing import Any, Dict, List
 
-ONE_CHIP = ("train", "serve", "runtime")
+ONE_CHIP = ("train", "serve", "runtime", "mla")
 FOUR_CHIPS = ("mesh_train", "tensor_serve", "fleet")
 #: the driver allows 1200 s; leave room for the parent's own exit
 DEADLINE_S = 1100.0
@@ -110,6 +114,15 @@ class Size:
     #: (B, T, d_inner, d_state) of the selective scan: Jamba2-3B's
     #: published widths at a middle and at the largest prefill bucket
     scan_shapes: tuple = ((1, 640, 5120, 16), (1, 1024, 5120, 16))
+    # mla: Kimi-K2's published widths; (T, prefix_len, real columns) of
+    #: a prefill's tail over `mla_max_seq` slots: the largest bucket
+    #: whole, and a short tail behind a long resident prefix
+    mla_preset: str = "kimi-k2-code"
+    mla_max_seq: int = 8704
+    mla_tile: int = 512
+    mla_prefills: tuple = ((8192, 0, 8155), (1024, 7173, 1019))
+    #: (rows, pool blocks, layers) of a decode wave over the paged pool
+    mla_wave: tuple = (64, 8193, 6)
     # serve: bench.py's on-chip TrafficSpec cut to a few dozen requests
     requests: int = 32
     #: warm-up requests of the serve phase (another seed's traffic), and
@@ -889,8 +902,137 @@ def phase_fleet(size: Size, platform: str = "tpu") -> Dict[str, Any]:
     return {"device": device}
 
 
+# ---------------------------------------------------------------------------
+# mla
+# ---------------------------------------------------------------------------
+
+def _check_mla_prefill(size: Size, interpret: bool) -> None:
+    """ops/mla_flash_prefill.py against the jnp walk it replaces in a
+    Kimi-K2 prefill on the chip (kimi_k2_decode.attend_blockwise), and
+    what a layer's call of each takes."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.kimi_k2 import (expand_latents, kimi_k2_config,
+                                        softmax_scale)
+    from ray_tpu.models.kimi_k2_decode import attend_blockwise
+    from ray_tpu.ops.mla_flash_prefill import mla_flash_prefill
+
+    S = size.mla_max_seq
+    cfg = kimi_k2_config(size.mla_preset, max_seq=S)
+    H, c = cfg.n_head, cfg.kv_lora_rank
+    ks = jax.random.split(jax.random.PRNGKey(size.seed + 3), 5)
+    p = {"wk_b": jax.random.normal(ks[0], (c, H, cfg.qk_nope_dim),
+                                   cfg.dtype) * c ** -0.5,
+         "wv_b": jax.random.normal(ks[1], (c, H, cfg.v_head_dim),
+                                   cfg.dtype) * c ** -0.5}
+    ckv = jax.random.normal(ks[2], (S, c), cfg.dtype)
+    kpe = jax.random.normal(ks[3], (S, cfg.qk_rope_dim), cfg.dtype)
+
+    @jax.jit
+    def kernel(q, prefix_len, pad):
+        k_nope, v = expand_latents(ckv, p, cfg)
+        return mla_flash_prefill(
+            q, k_nope, kpe, v, prefix_len, pad, scale=softmax_scale(cfg),
+            block_q=2 * size.mla_tile, block_k=size.mla_tile,
+            strip=size.mla_tile // 2, interpret=interpret)
+
+    @jax.jit
+    def walk(q, prefix_len, pad):
+        col = jnp.arange(q.shape[0])
+        return attend_blockwise(q, ckv, kpe, p, prefix_len + col - pad,
+                                col >= pad, cfg)
+
+    def timed(fn, *args):
+        jax.block_until_ready(fn(*args))            # compiles
+        t0 = time.perf_counter()
+        out = jax.block_until_ready(fn(*args))
+        return out, round((time.perf_counter() - t0) * 1e3, 3)
+
+    for T, prefix_len, n_tail in size.mla_prefills:
+        q = jax.random.normal(ks[4], (T, H, cfg.qk_head_dim), cfg.dtype)
+        args = (q, jnp.int32(prefix_len), jnp.int32(T - n_tail))
+        got, kernel_ms = timed(kernel, *args)
+        want, walk_ms = timed(walk, *args)
+        took = {"kernel_ms_a_layer": kernel_ms,
+                "jnp_walk_ms_a_layer": walk_ms}
+        err = _rel_err(got, want)
+        pads_zero = not bool(jnp.any(got[:T - n_tail]))
+        say("mla", kernel="mla_flash_prefill",
+            shape=[T, S, H, cfg.qk_head_dim, cfg.v_head_dim],
+            prefix_len=prefix_len, real_columns=n_tail,
+            err=round(err, 5), pads_zero=pads_zero, **took)
+        assert err <= KERNEL_TOL and pads_zero, ("mla_flash_prefill", err)
+
+
+def _check_mla_decode(size: Size, interpret: bool) -> None:
+    """ops/mla_paged_decode.py: the rotary pool's re-lay against its
+    transpose, to the bit, and one decode column of every row over the
+    pool where it lies against the gathered views."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.models.kimi_k2 import kimi_k2_config, softmax_scale
+    from ray_tpu.ops.mla_paged_decode import (
+        mla_paged_decode, mla_paged_decode_reference, rotary_lanes,
+        rotary_lanes_reference)
+
+    B, blocks, L = size.mla_wave
+    cfg = kimi_k2_config(size.mla_preset, max_seq=size.mla_max_seq)
+    H, c, r, bs = (cfg.n_head, cfg.kv_lora_rank, cfg.qk_rope_dim,
+                   size.kv_block)
+    nb = cfg.max_seq // bs
+    ks = jax.random.split(jax.random.PRNGKey(size.seed + 4), 6)
+    rng = np.random.default_rng(size.seed)
+    ckv = jax.random.normal(ks[0], (L, blocks, bs, c), cfg.dtype)
+    kpe = jax.random.normal(ks[1], (L, blocks, bs, r), cfg.dtype)
+    # rows of every length from idle to the whole table, over blocks
+    # out of order (block 0 is the null block and is no row's)
+    tables = jnp.asarray(rng.integers(1, blocks, (B, nb)), jnp.int32)
+    pos = jnp.asarray(np.linspace(0, nb * bs, B).astype(np.int32))
+    q_lat = jax.random.normal(ks[2], (B, H, c), cfg.dtype) * c ** -0.5
+    q_rope = jax.random.normal(ks[3], (B, H, r), cfg.dtype)
+    fresh = (jax.random.normal(ks[4], (B, c), cfg.dtype),
+             jax.random.normal(ks[5], (B, r), cfg.dtype))
+
+    t0 = time.perf_counter()
+    lanes = rotary_lanes(kpe, interpret=interpret)
+    same = bool(jnp.array_equal(lanes, rotary_lanes_reference(kpe)))
+    say("mla", kernel="mla_rotary_lanes", shape=list(kpe.shape),
+        identical=same, seconds=round(time.perf_counter() - t0, 2))
+    assert same, "mla_rotary_lanes"
+    for lidx in sorted({0, L - 1}):
+        t0 = time.perf_counter()
+        args = (q_lat, q_rope, ckv)
+        rest = (tables, pos, jnp.int32(lidx), fresh)
+        got = mla_paged_decode(*args, lanes, *rest,
+                               scale=softmax_scale(cfg),
+                               interpret=interpret)
+        want = jax.jit(functools.partial(
+            mla_paged_decode_reference, scale=softmax_scale(cfg)))(
+                *args, kpe, *rest)
+        err = _rel_err(got, want)
+        say("mla", kernel="mla_paged_decode", layer=lidx,
+            shape=[B, nb, H, c, r], err=round(err, 5),
+            seconds=round(time.perf_counter() - t0, 2))
+        assert err <= KERNEL_TOL, ("mla_paged_decode", lidx, err)
+
+
+def check_mla_kernels(size: Size, *, interpret: bool = False) -> None:
+    _check_mla_prefill(size, interpret)
+    _check_mla_decode(size, interpret)
+
+
+def phase_mla(size: Size, platform: str = "tpu") -> Dict[str, Any]:
+    device = device_block(platform)
+    check_mla_kernels(size)
+    return {"device": device}
+
+
 PHASES = {"train": phase_train, "serve": phase_serve,
-          "runtime": phase_runtime, "mesh_train": phase_mesh_train,
+          "runtime": phase_runtime, "mla": phase_mla,
+          "mesh_train": phase_mesh_train,
           "tensor_serve": phase_tensor_serve, "fleet": phase_fleet}
 
 

@@ -82,10 +82,13 @@ SSM_SCAN = "ssm_scan"
 MLA_PAGED_DECODE = "mla_paged_decode"
 #: ... and the rotary pool's re-lay for it, once a decode step
 MLA_ROTARY_LANES = "mla_rotary_lanes"
+#: a prefill's expanded causal latent attention, one sequence
+#: (ops/mla_flash_prefill.py)
+MLA_FLASH_PREFILL = "mla_flash_prefill"
 KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV,
            FLASH_RES_FWD, FLASH_RES_DQ, FLASH_RES_DKV,
            FLASH_TRI_FWD, FLASH_TRI_BWD, SSM_SCAN, MLA_PAGED_DECODE,
-           MLA_ROTARY_LANES)
+           MLA_ROTARY_LANES, MLA_FLASH_PREFILL)
 
 # -- host phases -------------------------------------------------------------
 SPAN_PREFIX = "raytpu."

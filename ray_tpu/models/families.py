@@ -47,6 +47,10 @@ class Family:
     verify: Optional[Callable[..., Any]]
     init_cache: Callable[..., Any]
     init_paged_cache: Callable[..., Any]
+    #: ``(cfg, t_pad, prefix_len, n_tail) -> (a kernel attended, tile
+    #: pairs walked, pairs without the diagonal)`` of a paged prefill,
+    #: for the host's count; None where the family has one path
+    prefill_attention: Optional[Callable[..., Any]] = None
 
 
 def _gpt2() -> Dict[str, Any]:
@@ -101,7 +105,8 @@ def _kimi_k2() -> Dict[str, Any]:
         prefill=m.kimi_k2_prefill, paged_prefill=m.kimi_k2_paged_prefill,
         step=m.kimi_k2_decode_step, verify=None,
         init_cache=m.kimi_k2_init_cache,
-        init_paged_cache=m.kimi_k2_init_paged_cache)
+        init_paged_cache=m.kimi_k2_init_paged_cache,
+        prefill_attention=m.kimi_k2_prefill_attention)
 
 
 #: family -> (what its cache holds, loader of its programs)

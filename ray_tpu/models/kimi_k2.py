@@ -355,14 +355,21 @@ def mla_project(u, p, cfg: KimiK2Config, cos, sin):
 
 
 @jax.named_scope(scopes.MLA)
+def expand_latents(ckv, p, cfg: KimiK2Config):
+    """Latents (..., S, kv_lora_rank) up to the per-head keys' position
+    free part (..., S, H, nope) and V (..., S, H, v)."""
+    dt = cfg.dtype
+    ckv = ckv.astype(dt)
+    return (jnp.einsum("...sc,chn->...shn", ckv, p["wk_b"].astype(dt)),
+            jnp.einsum("...sc,chv->...shv", ckv, p["wv_b"].astype(dt)))
+
+
+@jax.named_scope(scopes.MLA)
 def expand_keys(ckv, kpe, p, cfg: KimiK2Config):
     """Latents (..., S, kv_lora_rank) and rotary keys (..., S, rope) up
     to per-head K (..., S, H, nope + rope) and V (..., S, H, v)."""
-    dt = cfg.dtype
-    ckv = ckv.astype(dt)
-    kn = jnp.einsum("...sc,chn->...shn", ckv, p["wk_b"].astype(dt))
-    v = jnp.einsum("...sc,chv->...shv", ckv, p["wv_b"].astype(dt))
-    kr = jnp.broadcast_to(kpe.astype(dt)[..., None, :],
+    kn, v = expand_latents(ckv, p, cfg)
+    kr = jnp.broadcast_to(kpe.astype(cfg.dtype)[..., None, :],
                           (*kn.shape[:-1], cfg.qk_rope_dim))
     return jnp.concatenate([kn, kr], axis=-1), v
 

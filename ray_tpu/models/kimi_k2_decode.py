@@ -35,12 +35,15 @@ from ray_tpu.models.decode_common import (EXPERT_COUNTERS, EXPERTS,
                                           slot_mask)
 from ray_tpu.models.kimi_k2 import (KimiK2Config, attend_absorbed,
                                     attend_expanded, block, embed,
-                                    expand_keys, expert_counters,
-                                    lm_logits, softmax_scale, walk_layers)
+                                    expand_keys, expand_latents,
+                                    expert_counters, lm_logits,
+                                    softmax_scale, walk_layers)
+from ray_tpu.ops import mla_flash_prefill as flash
 from ray_tpu.ops.mla_paged_decode import mla_paged_decode, rotary_lanes
 
 __all__ = ["kimi_k2_init_cache", "kimi_k2_init_paged_cache",
            "kimi_k2_prefill", "kimi_k2_paged_prefill",
+           "kimi_k2_prefill_attention",
            "kimi_k2_decode_step", "kimi_k2_generate"]
 
 
@@ -165,6 +168,42 @@ def attend_blockwise(q, ckv, kpe, p, logical, real, cfg: KimiK2Config):
 
 
 @jax.named_scope(scopes.MLA)
+def attend_flash(q, ckv, kpe, p, prefix_len, pad, cfg: KimiK2Config):
+    """`attend_blockwise` for a tail of ``T - pad`` real columns behind
+    `prefix_len` slots, as one kernel (ops/mla_flash_prefill.py): the
+    view's latents are up-projected once, all S of them (two einsums,
+    under a millisecond a layer at the published widths), and the
+    rotary key stays the one row a slot it is."""
+    k_nope, v = expand_latents(ckv, p, cfg)
+    return flash.mla_flash_prefill(q, k_nope, kpe, v, prefix_len, pad,
+                                   scale=softmax_scale(cfg))
+
+
+def _takes_kernel(cfg: KimiK2Config, t_pad: int) -> bool:
+    """What a paged prefill can see of its input picks its attention:
+    on the chip a tail the kernel's tiles divide takes `attend_flash`;
+    the CPU and any other tail keep `attend_blockwise`, the parity
+    oracle."""
+    return jax.default_backend() == "tpu" \
+        and flash.fits(t_pad, cfg.max_seq)
+
+
+def kimi_k2_prefill_attention(cfg: KimiK2Config, t_pad: int,
+                              prefix_len: int, n_tail: int
+                              ) -> Tuple[bool, int, int]:
+    """For the host's count of what `kimi_k2_paged_prefill` ran for a
+    `t_pad`-column tail: (whether the kernel attended, the (query tile,
+    key tile) pairs it walked, the pairs a walk without the diagonal
+    would have: every query tile over the sequence's key tiles).  A
+    `jnp` prefill counts no pairs."""
+    if not _takes_kernel(cfg, t_pad):
+        return False, 0, 0
+    walked = flash.walk(t_pad, cfg.max_seq, prefix_len, t_pad - n_tail)
+    return True, int(walked.sum()), len(walked) * -(
+        -(prefix_len + n_tail) // flash.BLOCK_K)
+
+
+@jax.named_scope(scopes.MLA)
 def attend_paged(q, ckv_pool, rope_lanes, cache, lidx, p, fresh,
                  cfg: KimiK2Config):
     """`attend_absorbed` for one decode column of every row of a paged
@@ -263,6 +302,9 @@ def kimi_k2_paged_prefill(params, cache, tokens: jnp.ndarray,
         def attend(q, ckv, kpe):
             nonlocal pools
             pools, (cview, rview) = pkv.attend(lidx, pools, ckv, kpe)
+            if _takes_kernel(cfg, Tt):
+                return attend_flash(q[0], cview[0], rview[0], p["attn"],
+                                    prefix_len, pad, cfg)[None]
             return attend_blockwise(q[0], cview[0], rview[0], p["attn"],
                                     logical, real, cfg)[None]
 

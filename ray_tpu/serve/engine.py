@@ -70,6 +70,7 @@ class EngineBase:
 
         overrides = dict(opt.config_overrides or {})
         fam = _family(opt.family)
+        self._prefill_attention = fam.prefill_attention
         self.cfg = fam.config(opt.preset, **overrides)
         if opt.checkpoint_path:
             with open(opt.checkpoint_path, "rb") as f:
@@ -759,6 +760,9 @@ class LLMEngine(EngineBase):
               "rec": rec, "sp": sp, "blocks": blocks}
         first = {"tok": tok, "slot": slot, "st": st, "tokens": tokens,
                  "experts": counters}
+        if self._prefill_attention is not None:
+            first["attn"] = self._prefill_attention(
+                self.cfg, t_pad, prefix_len, n_tail)
         if sp is None and self._flight and self._chains():
             # decode waves are in flight: this prefill is queued
             # behind them and fenced in its turn (_land); the next
@@ -789,6 +793,8 @@ class LLMEngine(EngineBase):
         with self._phases.phase("prefill_fence"):
             first = int(np.asarray(item["tok"])[0])
             self._book_counters("prefill", item["experts"])
+            if "attn" in item:
+                self._telemetry.record_prefill_attn(*item["attn"])
         st["due"] -= 1
         self._telemetry.record_first_token(rec)
         # the prompt's full blocks now hold exactly its K/V —
@@ -1094,6 +1100,9 @@ class LLMEngine(EngineBase):
             # intermediate chunks discard the value
             first = int(np.asarray(tok)[0])
             self._book_counters("prefill", counters)
+        if self._prefill_attention is not None:
+            self._telemetry.record_prefill_attn(*self._prefill_attention(
+                self.cfg, t_pad, filled, c))
         t1 = _time.perf_counter()
         cur.advance(c)
         self._telemetry.record_prefill_chunk(

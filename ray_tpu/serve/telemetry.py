@@ -255,6 +255,16 @@ def _engine_metrics() -> Dict[str, Any]:
                     "serve_kv_walk_blocks_tabled_total",
                     "entries of those rows' block tables, summed "
                     "over the waves landed", tag_keys=tags),
+                # a paged prefill's attention, where the family has a
+                # kernel for it and a jnp walk beside it
+                "prefill_attn_kernel": Counter(
+                    "serve_prefill_attn_kernel_total",
+                    "paged prefills landed whose attention a kernel "
+                    "ran", tag_keys=tags),
+                "prefill_attn_jnp": Counter(
+                    "serve_prefill_attn_jnp_total",
+                    "paged prefills landed whose attention the jnp "
+                    "walk ran", tag_keys=tags),
                 "recurrent_snapshot_evictions": Counter(
                     "serve_recurrent_snapshot_evictions_total",
                     "snapshot entries dropped, least recently used or "
@@ -581,6 +591,10 @@ class EngineTelemetry:
         #: paged decode waves landed; the blocks their rows' positions
         #: fill; the entries of those rows' tables
         self._kv_walk = [0, 0, 0]
+        #: paged prefills landed that a kernel attended and that the
+        #: jnp walk did; the kernel's (query tile, key tile) pairs
+        #: walked, and the pairs without the diagonal
+        self._prefill_attn = [0, 0, 0, 0]
         #: round-19 healthwatch block (serve/health.py) the deployment
         #: refreshes from its fleet HealthMonitor — zero-shaped when
         #: no monitor watches this engine (standalone / disabled)
@@ -1175,6 +1189,21 @@ class EngineTelemetry:
         self._m["kv_walk_blocks_walked"].inc(walked, tags=self._tags)
         self._m["kv_walk_blocks_tabled"].inc(tabled, tags=self._tags)
 
+    def record_prefill_attn(self, kernel: bool, walked: int,
+                            square: int) -> None:
+        """One paged prefill of a family with two attention paths
+        (`Family.prefill_attention`), landed with its first token:
+        whether the kernel attended it, the (query tile, key tile)
+        pairs its causal walk visited and the pairs every query tile
+        over every key tile of the sequence would be:
+        ``engine_stats()["prefill_attn"]``."""
+        with self._lock:
+            self._prefill_attn[0 if kernel else 1] += 1
+            self._prefill_attn[2] += walked
+            self._prefill_attn[3] += square
+        self._m["prefill_attn_kernel" if kernel else "prefill_attn_jnp"
+                ].inc(1, tags=self._tags)
+
     def record_health(self, block: Dict[str, Any]) -> None:
         """Latest healthwatch block (serve/health.py
         ``HealthMonitor.replica_block``) — mirrored into
@@ -1401,6 +1430,7 @@ class EngineTelemetry:
             recurrent = self._recurrent
             experts = {k: dict(v) for k, v in self._experts.items()}
             walk_waves, walked, tabled = self._kv_walk
+            attn_kernel, attn_jnp, pairs, square = self._prefill_attn
             health = self._health_block
             spec = dict(self._spec)
             chunks = dict(self._chunks)
@@ -1488,6 +1518,13 @@ class EngineTelemetry:
                         "blocks_tabled": tabled,
                         "walked_share": round(walked / tabled, 4)
                         if tabled else 0.0},
+            # paged prefills by what attended them, and what the
+            # kernel's causal walk visited of the full rectangle of
+            # tile pairs (zeros for a family with one path)
+            "prefill_attn": {"kernel": attn_kernel, "jnp": attn_jnp,
+                             "pairs_walked": pairs, "pairs_square": square,
+                             "walked_share": round(pairs / square, 4)
+                             if square else 0.0},
             # round-19: healthwatch — liveness state machine counters
             # (stable zero-shaped block when no HealthMonitor watches
             # this engine: standalone, dense, or RAYTPU_HEALTHWATCH=0)

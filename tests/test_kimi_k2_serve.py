@@ -144,6 +144,22 @@ def test_the_blocks_walked_land_with_the_tokens():
     assert "serve_kv_walk_blocks_walked_total" in _registry.snapshot()
 
 
+@pytest.mark.parametrize("kw,prefills", [({}, 2),
+                                         ({"prefill_chunk_tokens": 16}, 5)],
+                         ids=["whole", "chunked"])
+def test_what_attended_the_prefills_lands_with_their_tokens(kw, prefills):
+    """Off the chip every paged prefill (a chunk is one) takes the jnp
+    walk, and the counter says so: 40 and 21 tokens whole, or in chunks
+    of 16 (3 + 2)."""
+    _, stats, _ = _serve(_build(**kw), [A, C])
+    assert stats["prefill_attn"] == {
+        "kernel": 0, "jnp": prefills, "pairs_walked": 0, "pairs_square": 0,
+        "walked_share": 0.0}
+    from ray_tpu.util.metrics import _registry
+
+    assert "serve_prefill_attn_jnp_total" in _registry.snapshot()
+
+
 def test_a_dense_cache_walks_no_blocks():
     _, stats, _ = _serve(_build(kv_layout="dense"), [C])
     assert stats["kv_walk"] == {"waves": 0, "blocks_walked": 0,
@@ -156,6 +172,10 @@ def test_a_family_without_experts_counts_none():
         kv_layout="paged", max_slots=2, max_new_tokens=3)
     _, stats, _ = _serve(dep, [C % 256])
     assert stats["experts"] == {}
+    # ... and, with one attention path, counts no prefill by its path
+    assert stats["prefill_attn"] == {
+        "kernel": 0, "jnp": 0, "pairs_walked": 0, "pairs_square": 0,
+        "walked_share": 0.0}
 
 
 @pytest.mark.parametrize("option,kw", [

@@ -33,7 +33,9 @@ TOY = chip_smoke.Size(
     ce_shape=(64, 64, 512, 500), scan_shapes=((1, 40, 256, 16),),
     requests=10, warm_requests=8, prefix_groups=2, prefix_len=32,
     tail_mean=6.0, tail_max=16, vocab=500, rate_rps=200.0, max_slots=4,
-    new_tokens=8, prefill_bucket=16)
+    new_tokens=8, prefill_bucket=16, mla_preset="nano", mla_max_seq=128,
+    mla_tile=16, mla_prefills=((64, 0, 51), (64, 60, 14)),
+    mla_wave=(5, 24, 3))
 
 
 @pytest.fixture
@@ -45,6 +47,8 @@ def interpreted(monkeypatch):
         flash.flash_attention, interpret=True))
     monkeypatch.setattr(chip_smoke, "check_kernels", functools.partial(
         chip_smoke.check_kernels, interpret=True))
+    monkeypatch.setattr(chip_smoke, "check_mla_kernels", functools.partial(
+        chip_smoke.check_mla_kernels, interpret=True))
 
 
 @pytest.fixture
@@ -62,6 +66,11 @@ def test_train_phase(interpreted):
 def test_serve_phase(interpreted):
     out = chip_smoke.phase_serve(TOY, "cpu")
     assert out["token_identical"]
+
+
+def test_mla_phase(interpreted):
+    out = chip_smoke.phase_mla(TOY, "cpu")
+    assert out["device"]["platform"] == "cpu"
 
 
 def test_runtime_phase(monkeypatch, tmp_path):

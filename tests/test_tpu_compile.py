@@ -306,6 +306,34 @@ def test_mla_paged_decode_kernel_compiles_at_the_cells_shapes():
     assert compiled.memory_analysis().temp_size_in_bytes < 0.49e9
 
 
+@pytest.mark.parametrize("T", range(2048, 8193, 1024))
+def test_mla_flash_prefill_kernel_compiles_at_the_cells_shapes(T):
+    """ray_tpu.ops.mla_flash_prefill at the Kimi-K2 cell's prefill
+    buckets: T queries of 64 heads, 128 + 64 wide, over the 8,704 slots
+    of the view, 128-wide values.  One Mosaic call; beside it only the
+    operands' re-lay (standing alone the keys and values come row-major;
+    in the prefill the products that make them write the kernel's
+    order, held below)."""
+    from ray_tpu._private import scopes
+    from ray_tpu.ops.mla_flash_prefill import mla_flash_prefill
+
+    spec = _one_chip()
+    bf16 = lambda *shape: spec(shape, jnp.bfloat16)   # noqa: E731
+    H, S = 64, 8704
+
+    def attend(q, k_nope, k_rope, v, prefix_len, pad):
+        return mla_flash_prefill(q, k_nope, k_rope, v, prefix_len, pad,
+                                 scale=0.1447)
+
+    text = jax.jit(attend).lower(
+        bf16(T, H, 192), bf16(S, H, 128), bf16(S, 64), bf16(S, H, 128),
+        spec((), jnp.int32), spec((), jnp.int32)).compile().as_text()
+    calls = [line.split(" = ")[0].split("%")[-1]
+             for line in text.splitlines() if MOSAIC_CALL in line]
+    assert [name.split(".")[0] for name in calls] == [
+        scopes.MLA_FLASH_PREFILL], calls
+
+
 @pytest.mark.parametrize("program,t_pad", [("decode", 0), ("prefill", 8192)])
 def test_kimi_k2_programs_fit_the_chip_at_the_published_widths(
         program, t_pad, monkeypatch):
@@ -323,7 +351,9 @@ def test_kimi_k2_programs_fit_the_chip_at_the_published_widths(
     here): its attention is the kernel ``mla_paged_decode`` under
     ``mla``, one call in each scan over layers, the rotary keys'
     re-lay ``mla_rotary_lanes`` once before them under ``kv_pool``, and
-    no view of the rows' tables is gathered."""
+    no view of the rows' tables is gathered.  The prefill's is the
+    kernel ``mla_flash_prefill`` under ``mla``, its keys and values as
+    the up-projections write them."""
     from benchmark.cells import load_cell
     from ray_tpu._private import scopes
 
@@ -369,6 +399,26 @@ def test_kimi_k2_programs_fit_the_chip_at_the_published_widths(
         if body.startswith((pool, layer)):
             assert " copy(" not in body and " transpose(" not in body, line
     if program != "decode":
+        # the prefill's attention is the kernel `mla_flash_prefill`
+        # under ``mla``, one call in each scan over layers
+        flashes = {name: scope for name, keyed in
+                   scopes.scope_map_from_hlo(text).items()
+                   for key, scope in keyed.items()
+                   if name.startswith(scopes.MLA_FLASH_PREFILL)
+                   and "custom-call" in key}
+        assert len(flashes) == 2 and set(flashes.values()) == {scopes.MLA}, \
+            flashes
+        # the keys and values reach it as the products write them: no
+        # re-lay of a head-major (64, 8,704, 128) tensor before it
+        for line in text.splitlines():
+            body = line.split(" = ", 1)[-1]
+            if body.startswith((f"bf16[64,{cfg.max_seq},128]",
+                                f"bf16[64,128,{cfg.max_seq}]")):
+                assert " copy(" not in body and " transpose(" not in body, \
+                    line
+        # no higher than the parent's, whose jnp walk carried the
+        # expanded keys and three accumulators (PR 33: 14,513,561,088)
+        assert memory.peak_memory_in_bytes <= 14_513_561_088, memory
         return
     walks = {name: scope for name, keyed in
              scopes.scope_map_from_hlo(text).items()
