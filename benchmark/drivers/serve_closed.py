@@ -12,6 +12,20 @@ an idle engine), and an engine that emits nothing for ``drain_s``
 seconds after the cut has fallen silent: the run is not ``correct`` and
 its requests in flight count as failed.
 
+``window_requests`` in the traffic file (``measured_window``) ends the
+MEASURED window on the stamp of the n-th request finished after it
+opened, where that falls inside ``--seconds``: fixed work over the time
+it took, so that no seed's window holds a prefill more or less than
+another's by where the clock's cut falls.  The load, the clock's cut,
+the check that the engine is alive past it, ``setup_s`` and the traced
+sub-window are what they are without the key; only the rate's window,
+and with it the result's ``t1`` that the readers cut rows by, is
+shorter.  Fewer than n finished inside ``--seconds`` (a slower program,
+a machine that stood still): the window is the clock's and the
+``[window]`` line says ``cut="clock"``.  The count is the file's, never
+chosen from the run: "as many whole turns as fit" would drop a stall
+late in the window out of it.
+
 Set-up runs one request through each prefill shape the clients will
 use, and a repeat of the first (a prefix hit), to the end of their
 answers: a request cannot be cut short, so this costs one full answer's
@@ -134,6 +148,30 @@ async def closed_window(engine, clients, offsets, seconds: float,
                                  t_end=t_end, alive=alive, finish=finish)
 
 
+def measured_window(rows, stamps, t0: float, t_clock: float,
+                    new_tokens: int, window_requests=None):
+    """What the rate is taken over and what that window held.  Without
+    `window_requests` (absent or 0) it is ``(t0, t_clock]``.  With it,
+    it ends on the stamp of the n-th answer made whole after `t0` (its
+    last token's), if n were by `t_clock`; else the clock's window
+    stands, ``cut == "clock"``.  A request is `finished` by its
+    `new_tokens` stamps, a prefill is counted where its request was
+    SENT (a client sends when its last answer is whole, so the sends of
+    a window are the prefills its finished requests made room for)."""
+    ends = sorted(r["token_ts"][-1] for r in rows
+                  if len(r.get("token_ts") or ()) >= new_tokens
+                  and r["token_ts"][-1] > t0)
+    n = int(window_requests or 0)
+    by_requests = 0 < n <= len(ends) and ends[n - 1] <= t_clock
+    t1 = ends[n - 1] if by_requests else t_clock
+    sent = [r for r in rows if t0 < r["sent"] <= t1]
+    return types.SimpleNamespace(
+        cut="requests" if by_requests else "clock", t1=t1,
+        rate=estimators.emission_rate(stamps, t0, t1),
+        finished=sum(e <= t1 for e in ends), prefills=len(sent),
+        prompt_tokens=sum(r["prompt_len"] for r in sent))
+
+
 def run(ctx: Ctx):
     import jax
 
@@ -190,8 +228,10 @@ def run(ctx: Ctx):
     # a request whose answer the client holds, or that failed; the
     # ones in flight at the cut are cut, not failed -- unless the
     # engine never emitted again: then it fell silent with them inside
-    t1 = r.t0 + ctx.seconds
-    rate = estimators.emission_rate(r.stamps, r.t0, t1)
+    t_clock = r.t0 + ctx.seconds
+    held = measured_window(r.rows, r.stamps, r.t0, t_clock,
+                           eng.new_tokens, traffic.get("window_requests"))
+    rate, t1 = held.rate, held.t1
     finished = [x for x in r.rows if x["collected"] or x["error"]]
     failed = correct.count_failed(finished, eng.new_tokens)
     if not r.alive:
@@ -203,8 +243,13 @@ def run(ctx: Ctx):
         compiles_in_window=r.compiles_in_window,
         tokens_in_window=rate and rate[1],
         next_emission_after_cut_s=round(
-            min((t for t in r.stamps if t >= t1), default=t1) - t1, 4),
-        ran_on_s=round(r.t_end - t1, 3), alive_at_cut=r.alive)
+            min((t for t in r.stamps if t >= t_clock), default=t_clock)
+            - t_clock, 4),
+        ran_on_s=round(r.t_end - t_clock, 3), alive_at_cut=r.alive,
+        cut=held.cut, window_s=round(t1 - r.t0, 4),
+        finished_in_window=held.finished,
+        prefills_in_window=held.prefills,
+        prompt_tokens_in_window=held.prompt_tokens)
     say("setup_split", **{k: round(v, 3) for k, v in split.items()},
         setup_s=round(r.setup_s, 3))
     return types.SimpleNamespace(

@@ -2,14 +2,19 @@
 
     python3 -m benchmark.measure_sets --workload <cell> [--sets 2]
         [--runs 6] [--seconds <run_seconds>] [--traced 1] [--out <dir>]
+        [--seeds 5,7,...]
 
 Runs BENCHMARK.json's command once per run, each a process of its own
 (this one never touches JAX, so the chip is free for each child), the
 same seeds in every set, and prints per set and metric the median and
 the spread the contract defines: the distance between the first and
 third quartile (``statistics.quantiles(values, n=4)``) as a share of
-the median.  ``--traced 1`` adds one ``--trace 1`` run at the end.
-Every result line is kept in ``<out>/<cell>.jsonl``.
+the median.  ``--seeds`` gives the seeds instead, ``sets x runs`` of
+them dealt to the sets in order: a seed a run, where the question is
+how far seeds lie apart and not whether a seed repeats.  ``--traced n``
+adds ``--trace 1`` runs at the end, on the first n seeds.  Every result
+line is kept in ``<out>/<cell>.jsonl``, and a serving run's ``[window]``
+line (how its window was cut and what it held) is shown under its own.
 """
 
 from __future__ import annotations
@@ -39,8 +44,10 @@ def one_run(command, workload, seed, seconds, trace, log):
     if proc.returncode != 0:
         return {"rc": proc.returncode, "wall_s": wall,
                 "stderr": proc.stderr[-800:]}
-    line = json.loads(proc.stdout.strip().splitlines()[-1])
-    line.update(rc=0, wall_s=wall, seed=seed, trace=trace)
+    said = proc.stdout.strip().splitlines()
+    line = json.loads(said[-1])
+    line.update(rc=0, wall_s=wall, seed=seed, trace=trace, window=next(
+        (ln for ln in said if ln.startswith("[window]")), None))
     return line
 
 
@@ -57,7 +64,15 @@ def main(argv=None) -> int:
     p.add_argument("--seconds", type=float, default=None)
     p.add_argument("--traced", type=int, default=0)
     p.add_argument("--out", default="chiprun_out/sets")
+    p.add_argument("--seeds", default=None)
     args = p.parse_args(argv)
+    seeds = [list(SEEDS[:args.runs])] * args.sets
+    if args.seeds:
+        given = [int(x) for x in args.seeds.split(",")]
+        if len(given) != args.sets * args.runs:
+            p.error(f"--seeds wants {args.sets * args.runs} seeds")
+        seeds = [given[s * args.runs:(s + 1) * args.runs]
+                 for s in range(args.sets)]
     with open("BENCHMARK.json") as f:
         bench = json.load(f)
     seconds = args.seconds or bench["run_seconds"]
@@ -67,7 +82,7 @@ def main(argv=None) -> int:
     with open(path + ".log", "a") as log, \
             open(path + ".jsonl", "a") as keep:
         for s in range(args.sets):
-            for seed in SEEDS[:args.runs]:
+            for seed in seeds[s]:
                 r = one_run(bench["command"], args.workload, seed,
                             seconds, 0, log)
                 r["set"] = s
@@ -80,12 +95,14 @@ def main(argv=None) -> int:
                       f"correct {r.get('correct')} failed "
                       f"{r.get('failed')} wall {r['wall_s']:.0f}s {shown}",
                       flush=True)
+                if r.get("window"):
+                    print("   ", r["window"], flush=True)
                 if r["rc"] != 0:
                     # a cell that does not run burns no more chip time
                     print(r.get("stderr", ""), flush=True)
                     return 1
-        if args.traced:
-            r = one_run(bench["command"], args.workload, SEEDS[0],
+        for seed in seeds[0][:args.traced]:
+            r = one_run(bench["command"], args.workload, seed,
                         seconds, 1, log)
             keep.write(json.dumps(r) + "\n")
             print("traced", json.dumps(r), flush=True)

@@ -7,7 +7,8 @@ model's own vocabulary.  Two things differ on purpose:
 * The SHAPE of the traffic -- arrival times, and each request's prefix
   group and tail length -- comes from seeds written in the traffic file
   and is the same in every run.  ``--seed`` permutes which body meets
-  which arrival (or which client), and draws every token.  Runs of
+  which arrival (or which client; not even that where a closed-loop
+  file says ``"client_lists": "file"``), and draws every token.  Runs of
   different seeds then do the same work in another order, and their
   spread is the system's, not the dice's.
 * Nothing is timed here: the drivers clock requests from their due
@@ -110,13 +111,23 @@ class TrafficGenerator:
                 for i in range(len(due))]
 
     def closed_loop(self) -> List[List[Request]]:
-        """Per client, the prompts it sends one after another.  Client
-        c's list of lengths is fixed by the file; the seed decides which
-        client gets which list, so every wave holds the same lengths."""
+        """Per client, the prompts it sends one after another.  Each
+        list of lengths is fixed by the file; the seed decides which
+        client gets which list, so every wave holds the same lengths.
+        ``"client_lists": "file"`` in the traffic file gives client c
+        list c under every seed: where the clients are out of step (a
+        window over a piece of a cycle holds some clients' turns and not
+        others'), the seed must not deal the lists, or each seed's
+        window holds other prefills.  The seed still draws every token."""
         n_clients = int(self.traffic["clients"])
         turns = int(self.traffic["turns_per_client"])
         bodies = _bodies(self.prompts, n_clients * turns)
-        order = self._rng.permutation(n_clients)
+        lists = self.traffic.get("client_lists", "seed")
+        if lists not in ("seed", "file"):
+            raise ValueError(f"client_lists is 'seed' or 'file', not "
+                             f"{lists!r}")
+        order = self._rng.permutation(n_clients) if lists == "seed" \
+            else range(n_clients)
         out, index = [], 0
         for c in range(n_clients):
             mine = bodies[order[c] * turns:(order[c] + 1) * turns]
