@@ -105,32 +105,37 @@ def write_chrome_trace(events: List[Dict[str, Any]],
 # host phases on the profiler's clock
 # ---------------------------------------------------------------------------
 
-def _annotation(name: str):
+def _annotation(name: str, attrs: Optional[Dict[str, Any]] = None):
     """A ``jax.profiler.TraceAnnotation`` ``raytpu.<name>``, entered,
     or None in a process that has not imported JAX (the runtime's
-    driver never does).  Without a profiler session the annotation
-    records nothing."""
+    driver never does).  `attrs` become the event's stats (the
+    profiler's host tracer reads them off the name it is handed,
+    ``name#k=v,...#``, and keeps the bare name); they are encoded only
+    while a session is active.  Without a profiler session the
+    annotation records nothing."""
     jax = sys.modules.get("jax")
     if jax is None:
         return None
-    ann = jax.profiler.TraceAnnotation(scopes.SPAN_PREFIX + name)
+    ann = jax.profiler.TraceAnnotation(scopes.SPAN_PREFIX + name,
+                                       **(attrs or {}))
     ann.__enter__()
     return ann
 
 
 class _Phase:
-    """One ``with phases.phase(name)`` block; ``t0`` and ``t1`` are its
-    ``perf_counter`` stamps, for a caller that wants the duration it
-    would otherwise time a second time."""
+    """One ``with phases.phase(name, **attrs)`` block; ``t0`` and ``t1``
+    are its ``perf_counter`` stamps, for a caller that wants the
+    duration it would otherwise time a second time."""
 
-    __slots__ = ("_owner", "_name", "t0", "t1")
+    __slots__ = ("_owner", "_name", "_attrs", "t0", "t1")
 
-    def __init__(self, owner: "Phases", name: str):
-        self._owner, self._name = owner, name
+    def __init__(self, owner: "Phases", name: str,
+                 attrs: Optional[Dict[str, Any]] = None):
+        self._owner, self._name, self._attrs = owner, name, attrs
         self.t0 = self.t1 = 0.0
 
     def __enter__(self) -> "_Phase":
-        self.t0 = self._owner._push(self._name) * 1e-9
+        self.t0 = self._owner._push(self._name, self._attrs) * 1e-9
         return self
 
     def __exit__(self, *exc) -> bool:
@@ -139,13 +144,18 @@ class _Phase:
 
 
 class _Step:
-    __slots__ = ("_owner",)
+    """One ``with phases.step(**attrs)`` block; ``t0`` is the
+    ``perf_counter`` stamp its first leaf starts on."""
 
-    def __init__(self, owner: "Phases"):
-        self._owner = owner
+    __slots__ = ("_owner", "_attrs", "t0")
+
+    def __init__(self, owner: "Phases",
+                 attrs: Optional[Dict[str, Any]] = None):
+        self._owner, self._attrs = owner, attrs
+        self.t0 = 0.0
 
     def __enter__(self) -> "_Step":
-        self._owner._begin_step()
+        self.t0 = self._owner._begin_step(self._attrs) * 1e-9
         return self
 
     def __exit__(self, *exc) -> bool:
@@ -167,6 +177,12 @@ class Phases:
     ``loop``, which is booked but opens no span).  A step is one more
     span around its leaves, ``raytpu.<layer>.step``.
 
+    A phase or a step may carry attributes (``phase("decode_dispatch",
+    seq=7, rows=32)``): small host facts that say WHICH piece of work
+    the span is, so a reader can join it to what caused it.  They are
+    the span's stats in a trace, under its bare name, on every fragment
+    a child splits it into; the table knows nothing of them.
+
     Cost with no profiler session: one ``perf_counter_ns`` and one empty
     ``TraceAnnotation`` per switch (``tests/test_phases.py`` holds it to
     a per-call budget).  One loop owns a ``Phases``: no lock.
@@ -176,7 +192,8 @@ class Phases:
         self._prefix = layer + "."
         #: phase -> [times entered, nanoseconds run]
         self._table: Dict[str, List[int]] = {}
-        self._stack: List[str] = []
+        #: (phase, its attributes or None), innermost last
+        self._stack: List[tuple] = []
         self._since_ns = 0
         self._ann = None
         self._step_ann = None
@@ -190,9 +207,10 @@ class Phases:
             self._ann.__exit__(None, None, None)
             self._ann = None
         if self._stack:
-            self._table[self._stack[-1]][1] += now_ns - self._since_ns
+            self._table[self._stack[-1][0]][1] += now_ns - self._since_ns
 
-    def _resume(self, name: str, now_ns: int, entered: int) -> None:
+    def _resume(self, name: str, attrs, now_ns: int,
+                entered: int) -> None:
         cell = self._table.get(name)
         if cell is None:
             cell = self._table[name] = [0, 0]
@@ -201,13 +219,13 @@ class Phases:
         # no span of its own for the loop's fragments: inside a step's
         # span, what no leaf's span covers is the loop
         if name != scopes.LOOP:
-            self._ann = _annotation(self._prefix + name)
+            self._ann = _annotation(self._prefix + name, attrs)
 
-    def _push(self, name: str) -> int:
+    def _push(self, name: str, attrs=None) -> int:
         now_ns = time.perf_counter_ns()
         self._suspend(now_ns)
-        self._stack.append(name)
-        self._resume(name, now_ns, 1)
+        self._stack.append((name, attrs))
+        self._resume(name, attrs, now_ns, 1)
         return now_ns
 
     def _pop(self) -> int:
@@ -215,12 +233,13 @@ class Phases:
         self._suspend(now_ns)
         self._stack.pop()
         if self._stack:
-            self._resume(self._stack[-1], now_ns, 0)
+            self._resume(*self._stack[-1], now_ns, 0)
         return now_ns
 
-    def _begin_step(self) -> None:
-        self._step_ann = _annotation(self._prefix + scopes.STEP)
+    def _begin_step(self, attrs=None) -> int:
+        self._step_ann = _annotation(self._prefix + scopes.STEP, attrs)
         self._step_t0_ns = self._push(scopes.LOOP)
+        return self._step_t0_ns
 
     def _end_step(self) -> None:
         now_ns = self._pop()
@@ -234,13 +253,13 @@ class Phases:
 
     # -- what callers use --------------------------------------------------
 
-    def phase(self, name: str) -> _Phase:
-        return _Phase(self, name)
+    def phase(self, name: str, **attrs: Any) -> _Phase:
+        return _Phase(self, name, attrs or None)
 
-    def step(self) -> _Step:
+    def step(self, **attrs: Any) -> _Step:
         """One loop iteration: ``raytpu.<layer>.step`` around leaves
         that partition it."""
-        return _Step(self)
+        return _Step(self, attrs or None)
 
     def snapshot(self) -> Dict[str, List[float]]:
         """``{phase: [count, seconds]}``; ``step`` counts whole steps."""

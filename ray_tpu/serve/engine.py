@@ -45,6 +45,17 @@ from ray_tpu.serve.telemetry import EngineTelemetry
 _AHEAD_S = 0.13
 _AHEAD_MAX = 16
 
+#: what a launch's dict holds of the device and of its requests while
+#: it is in flight (`LLMEngine._launch`); dropped when it lands, so the
+#: ring of landed records pins neither
+_IN_FLIGHT = ("toks", "tok", "stepped", "st", "tokens", "experts")
+#: the fields of a launch that its dispatch span carries as attributes
+_SPAN_ATTRS = ("seq", "kind", "rows", "req", "bucket", "n_tail", "ahead")
+
+
+def _span_attrs(launch) -> dict:
+    return {k: launch[k] for k in _SPAN_ATTRS if k in launch}
+
 
 class EngineBase:
     """Parameters, telemetry and the stats surface of either scheduler.
@@ -284,6 +295,12 @@ class LLMEngine(EngineBase):
         # between them (_wave, _land); when the last wave landed and
         # what the last waves took, fence to fence
         self._flight = collections.deque()
+        # launches made so far (a launch's `seq`), loop iterations
+        # with work in them, and when the loop last let its callers
+        # run (None: parked, or not started)
+        self._seq = 0
+        self._iteration = 0
+        self._held_from = None
         # slot -> first token, still on the device, of each prefill
         # in flight that the next wave takes up (join_token)
         self._joins = {}
@@ -426,6 +443,50 @@ class LLMEngine(EngineBase):
                 opt.slo, self._telemetry,
                 recorder=self._telemetry.flightrec)
 
+    def _launch(self, kind, program, rows, **facts) -> dict:
+        """The record of the launch about to be made: the one dict
+        the engine keeps of a program it hands the device, from
+        before its dispatch (the span carries these fields:
+        `_span_attrs`) through its time in `_flight` (where it also
+        holds what the fence will read: `_IN_FLIGHT`) to the ring of
+        landed records (`_landed`).  `program` is the jitted
+        function, named here as a trace names its executions;
+        `rows` the decoding rows a wave steps, or that stand behind
+        a prefill; `facts` what the site has in hand beside
+        (`EngineTelemetry.record_launch` lists the fields)."""
+        self._seq += 1
+        return dict(facts, seq=self._seq, kind=kind,
+                    program="jit_" + program.__name__, rows=rows,
+                    ahead=len(self._flight))
+
+    def _landed(self, launch, fence=None) -> None:
+        """A launch is fenced (`fence`: the fence phase's stamps) or
+        given up unfenced: it lets go of the device and joins the
+        landed records.  A launch whose one phase holds dispatch and
+        fence (``fused``) has that phase's stamps as both."""
+        launch["fence"] = fence
+        if launch.get("fused"):
+            launch["dispatch"] = fence
+        for key in _IN_FLIGHT:
+            launch.pop(key, None)
+        self._telemetry.record_launch(launch)
+
+    def _give_up(self) -> None:
+        """Forget what is in flight (its rows have all ended, or the
+        loop failed): the launches were made, so each is recorded as
+        it stands, unfenced."""
+        while self._flight:
+            self._landed(self._flight.popleft())
+        self._joins.clear()
+
+    def launch_records(self):
+        """The landed launch records, oldest first, at most
+        `serve.telemetry.LAUNCH_HISTORY`
+        (`EngineTelemetry.record_launch` says what one holds);
+        `serve.telemetry.recent_launches()` reads the same ring once
+        the engine is gone."""
+        return self._telemetry.launch_records()
+
     def _counters(self):
         """The expert counters the program just dispatched leaves,
         still on the device (None for a family without expert
@@ -452,9 +513,12 @@ class LLMEngine(EngineBase):
                 make_vocab_tail_mask, sample_token)
 
             tail = make_vocab_tail_mask(self.cfg)
-            fn = jax.jit(lambda lg, kk: sample_token(
-                lg, kk, sp.temperature, tail, sp.top_k, sp.top_p))
-            self._samplers[sp] = fn
+
+            def sampler(lg, kk):
+                return sample_token(lg, kk, sp.temperature, tail,
+                                    sp.top_k, sp.top_p)
+
+            fn = self._samplers[sp] = jax.jit(sampler)
         return fn
 
     def _hit_stop(self, out) -> bool:
@@ -545,9 +609,15 @@ class LLMEngine(EngineBase):
             self._telemetry.record_admit(rec, slot, t_pad)
             padded = np.zeros((1, t_pad), np.int32)
             padded[0, t_pad - n:] = arr
+            launch = self._launch(
+                "prefill",
+                self._prefill if sp is None else self._fns.prefill_raw,
+                len(self._decoding()), req=rec["id"], bucket=t_pad,
+                prefix_len=0, n_tail=n)
             with phase("rng_split"):
                 self._rng, k = jax.random.split(self._rng)
-            with phase("prefill_dispatch"):
+            with phase("prefill_dispatch",
+                       **_span_attrs(launch)) as dispatch:
                 if sp is not None:
                     # override path: logits-returning twin + the
                     # per-sp sampler (default requests keep the
@@ -562,8 +632,10 @@ class LLMEngine(EngineBase):
                         jnp.asarray([n], jnp.int32), k)
             # int() is the engine's existing host fence for the
             # prefill result; the timestamp behind it is the TTFT
-            with phase("prefill_fence"):
+            with phase("prefill_fence", seq=launch["seq"]) as fence:
                 first = int(np.asarray(tok)[0])
+            launch["dispatch"] = (dispatch.t0, dispatch.t1)
+            self._landed(launch, (fence.t0, fence.t1))
             self._telemetry.record_first_token(rec)
             if opt.max_new_tokens <= 1 or self._hit_stop([first]):
                 self._telemetry.record_finish(rec, n_tokens=1)
@@ -738,9 +810,15 @@ class LLMEngine(EngineBase):
         self._telemetry.record_admit(rec, slot, t_pad)
         tail_toks = np.zeros((1, t_pad), np.int32)
         tail_toks[0, t_pad - n_tail:] = arr[prefix_len:]
+        first = self._launch(
+            "prefill",
+            self._paged_prefill if sp is None
+            else self._fns.paged_prefill_raw,
+            len(self._decoding()), req=rec["id"], bucket=t_pad,
+            prefix_len=prefix_len, n_tail=n_tail, slot=slot)
         with phase("rng_split"):
             self._rng, k = jax.random.split(self._rng)
-        with phase("prefill_dispatch"):
+        with phase("prefill_dispatch", **_span_attrs(first)) as dispatch:
             state = self._state_arg(tokens, prefix_len, n_tail)
             if sp is not None:
                 logits, self._cache = self._fns.paged_prefill_raw(
@@ -758,8 +836,8 @@ class LLMEngine(EngineBase):
             counters = self._counters()
         st = {"prompt": arr, "out": [], "due": 1, "fut": fut,
               "rec": rec, "sp": sp, "blocks": blocks}
-        first = {"tok": tok, "slot": slot, "st": st, "tokens": tokens,
-                 "experts": counters}
+        first.update(dispatch=(dispatch.t0, dispatch.t1), tok=tok, st=st,
+                     tokens=tokens, experts=counters)
         if self._prefill_attention is not None:
             first["attn"] = self._prefill_attention(
                 self.cfg, t_pad, prefix_len, n_tail)
@@ -790,11 +868,13 @@ class LLMEngine(EngineBase):
         ctx = rec.get("ctx")
         # int() is the engine's existing host fence for the
         # prefill result; the timestamp behind it is the TTFT
-        with self._phases.phase("prefill_fence"):
+        with self._phases.phase("prefill_fence",
+                                seq=item["seq"]) as fence:
             first = int(np.asarray(item["tok"])[0])
             self._book_counters("prefill", item["experts"])
             if "attn" in item:
                 self._telemetry.record_prefill_attn(*item["attn"])
+        self._landed(item, (fence.t0, fence.t1))
         st["due"] -= 1
         self._telemetry.record_first_token(rec)
         # the prompt's full blocks now hold exactly its K/V —
@@ -1002,6 +1082,10 @@ class LLMEngine(EngineBase):
         row_bt[:need] = alloc
         k_rows, v_rows = self._to_engine(
             (jnp.asarray(pkg.k_rows), jnp.asarray(pkg.v_rows)))
+        launch = self._launch(
+            "handoff", self._fns.kv_handoff_install,
+            len(self._decoding()), req=rec["id"], fused=True)
+        t_splice = _time.perf_counter()
         self._cache = self._fns.kv_handoff_install(
             self._cache, jnp.asarray(ids), k_rows, v_rows,
             np.int32(slot), jnp.asarray(row_bt), np.int32(n))
@@ -1009,6 +1093,7 @@ class LLMEngine(EngineBase):
         # not the dispatch (the tier-restore h2d discipline)
         jax.block_until_ready(self._cache)
         t_done = _time.perf_counter()
+        self._landed(launch, (t_splice, t_done))
         pkg.installed = True
         # index the imported full blocks so later prompts sharing
         # the prefix hit HERE — the router's prefix-affinity stage
@@ -1044,12 +1129,10 @@ class LLMEngine(EngineBase):
         table): decode waves scatter-write masked garbage into
         every row at its pos, and those writes must land in the
         null block, never in this row's half-filled real blocks;
-        the next chunk re-installs row_bt/pos/start absolutely."""
-        import time as _time
+        the next chunk re-installs row_bt/pos/start absolutely.
 
-        import jax
-        import jax.numpy as jnp
-
+        The chunk is one leaf phase, ``prefill_chunk``, dispatch and
+        fence together: its launch is ``fused``."""
         opt = self.opt
         # next candidate strictly after the cursor, cyclically
         i = min(candidates,
@@ -1057,14 +1140,40 @@ class LLMEngine(EngineBase):
                 or opt.max_slots)
         self._chunk_rr = i
         st = self._slots[i]
-        arr = st["prompt"]
-        n = int(arr.shape[0])
         cur = st["cursor"]
         filled = cur.filled
         c = cur.next_chunk()
-        last = filled + c >= n
         t_pad = -(-c // opt.prefill_bucket) * opt.prefill_bucket
         t_pad = max(c, min(t_pad, self.cfg.max_seq))
+        launch = self._launch(
+            "chunk",
+            self._paged_prefill if st["sp"] is None
+            else self._fns.paged_prefill_raw,
+            len(self._decoding()), req=st["rec"]["id"], bucket=t_pad,
+            prefix_len=filled, n_tail=c, slot=i, fused=True)
+        with self._phases.phase("prefill_chunk",
+                                **_span_attrs(launch)) as leaf:
+            self._run_chunk(launch)
+        self._landed(launch, (leaf.t0, leaf.t1))
+
+    def _run_chunk(self, launch) -> None:
+        """The chunk `launch` describes (its slot's next ``n_tail``
+        prompt tokens behind the ``prefix_len`` it holds, padded to
+        ``bucket``): dispatch, fence, and what follows a prompt's
+        last chunk."""
+        import time as _time
+
+        import jax
+        import jax.numpy as jnp
+
+        opt = self.opt
+        i, filled, c, t_pad = (launch["slot"], launch["prefix_len"],
+                               launch["n_tail"], launch["bucket"])
+        st = self._slots[i]
+        arr = st["prompt"]
+        n = int(arr.shape[0])
+        cur = st["cursor"]
+        last = filled + c >= n
         chunk_toks = np.zeros((1, t_pad), np.int32)
         chunk_toks[0, t_pad - c:] = arr[filled:filled + c]
         t0 = _time.perf_counter()
@@ -1101,8 +1210,9 @@ class LLMEngine(EngineBase):
             first = int(np.asarray(tok)[0])
             self._book_counters("prefill", counters)
         if self._prefill_attention is not None:
-            self._telemetry.record_prefill_attn(*self._prefill_attention(
-                self.cfg, t_pad, filled, c))
+            launch["attn"] = self._prefill_attention(
+                self.cfg, t_pad, filled, c)
+            self._telemetry.record_prefill_attn(*launch["attn"])
         t1 = _time.perf_counter()
         cur.advance(c)
         self._telemetry.record_prefill_chunk(
@@ -1287,6 +1397,10 @@ class LLMEngine(EngineBase):
         SamplingParams (the wave then samples by groups)."""
         return any(st["sp"] is not None for st in self._decoding().values())
 
+    def _waves(self) -> list:
+        """The decode waves in flight, oldest first."""
+        return [w for w in self._flight if w["kind"] == "decode"]
+
     def _chains(self) -> bool:
         """Whether the next decode wave can be queued behind what
         is in flight and read the newest wave's tokens on the
@@ -1302,11 +1416,11 @@ class LLMEngine(EngineBase):
         the null block, before `clear_row` and before any later
         tenant's prefill, and the tokens are dropped (`_land`)."""
         max_new = self.opt.max_new_tokens
-        waves = [w for w in self._flight if "rows" in w]
+        waves = self._waves()
         if not waves or self.opt.spec_decode is not None \
                 or self._mixed():
             return False
-        rows, before = self._decoding(), waves[-1]["rows"]
+        rows, before = self._decoding(), waves[-1]["stepped"]
         return (all(before.get(i) is st or i in self._joins
                     for i, st in rows.items())
                 and any(len(st["out"]) + st.get("due", 0) < max_new
@@ -1351,8 +1465,10 @@ class LLMEngine(EngineBase):
             # engine RNG advances on the waves that sample and at
             # each admission, as before)
             k = self._dummy_key
-        waves = [w for w in self._flight if "rows" in w]
-        with phase("decode_dispatch") as wave:
+        waves = self._waves()
+        rows = self._decoding()
+        item = self._launch("decode", self._pool_step, len(rows))
+        with phase("decode_dispatch", **_span_attrs(item)) as wave:
             toks = waves[-1]["toks"] if waves \
                 else jnp.asarray(self._cur)
             for slot, tok in self._joins.items():
@@ -1361,7 +1477,6 @@ class LLMEngine(EngineBase):
             toks, self._cache = self._pool_step(
                 self.params, self._cache, toks, k)
             counters = self._counters()
-        rows = self._decoding()
         walked = 0
         for st in rows.values():
             due = st.get("due", 0)
@@ -1371,8 +1486,8 @@ class LLMEngine(EngineBase):
             walked += -(-(len(st["prompt"]) + len(st["out"]) + due - 1)
                         // self.opt.kv_block_size)
             st["due"] = due + 1
-        item = {"toks": toks, "t0": wave.t0, "rows": rows,
-                "experts": counters}
+        item.update(dispatch=(wave.t0, wave.t1), toks=toks, stepped=rows,
+                    experts=counters)
         if self._pager is not None:
             item["walk"] = (walked, len(rows) * (
                 self.cfg.max_seq // self.opt.kv_block_size))
@@ -1383,16 +1498,18 @@ class LLMEngine(EngineBase):
         emit its tokens to the rows it sampled for that are still
         there.  A prefill: book its first token."""
         item = self._flight.popleft()
-        if "rows" not in item:
+        if item["kind"] != "decode":
             self._land_first(item)
             return
-        with self._phases.phase("decode_fence") as fence:
+        stepped = item["stepped"]
+        with self._phases.phase("decode_fence", seq=item["seq"]) as fence:
             # the wave's one host fence
             toks = np.asarray(item["toks"])
             self._book_counters("decode", item["experts"])
             if "walk" in item:
                 self._telemetry.record_kv_walk(*item["walk"])
-        rows = {i: st for i, st in item["rows"].items()
+        self._landed(item, (fence.t0, fence.t1))
+        rows = {i: st for i, st in stepped.items()
                 if self._slots[i] is st}
         if not rows:
             return      # every row it stepped has ended since
@@ -1400,7 +1517,7 @@ class LLMEngine(EngineBase):
             st["due"] -= 1
         # a step's walltime: from its dispatch, or from the wave
         # before it landing where it was queued behind that one
-        took = fence.t1 - max(item["t0"], self._t_landed)
+        took = fence.t1 - max(item["dispatch"][0], self._t_landed)
         self._telemetry.record_step(len(rows), took, now=fence.t1)
         self._wave_s.append(took)
         self._t_landed = fence.t1
@@ -1449,8 +1566,7 @@ class LLMEngine(EngineBase):
             if s is not None and s.get("state") == "prefill"]
         n_active = sum(s is not None for s in self._slots)
         if not n_active:
-            self._flight.clear()    # waves whose rows all ended
-            self._joins.clear()
+            self._give_up()         # waves whose rows all ended
             return False
         n_decode = n_active - len(prefilling)
         if self._chaos is not None and n_decode:
@@ -1464,23 +1580,30 @@ class LLMEngine(EngineBase):
         # engine already performs, read off the phases' own stamps
         # — no second perf_counter pair, no extra device sync
         if n_decode and opt.spec_decode is not None:
-            with phase("spec_round") as rnd:
+            launch = self._launch("spec", self._fns.spec_verify,
+                                  n_decode, fused=True)
+            with phase("spec_round", **_span_attrs(launch)) as rnd:
                 n_tokens = self._spec_round()
+            self._landed(launch, (rnd.t0, rnd.t1))
             self._telemetry.record_step(
                 n_decode, rnd.t1 - rnd.t0, n_tokens=n_tokens)
         elif n_decode:
             if self._mixed():
+                launch = self._launch("mixed", self._fns.pool_logits,
+                                      n_decode, fused=True)
                 with phase("rng_split"):
                     self._rng, k = jax.random.split(self._rng)
-                with phase("decode_dispatch") as wave:
+                with phase("decode_dispatch",
+                           **_span_attrs(launch)) as wave:
                     toks = self._mixed_step(k)   # fences inside
+                self._landed(launch, (wave.t0, wave.t1))
                 self._telemetry.record_step(
                     n_decode, wave.t1 - wave.t0, now=wave.t1)
                 self._emit(self._decoding(), toks, wave.t1)
             else:
                 self._wave()
                 depth = self._depth()
-                while sum("rows" in w for w in self._flight) > depth:
+                while len(self._waves()) > depth:
                     self._land()
         with phase("hooks"):
             if self._telemetry.slo is not None:
@@ -1498,8 +1621,7 @@ class LLMEngine(EngineBase):
                 # timeline a postmortem replays
                 self._pager.sample_occupancy()
         if prefilling:
-            with phase("prefill_chunk"):
-                self._prefill_chunk_step(prefilling)
+            self._prefill_chunk_step(prefilling)
         return True
 
     async def _engine(self):
@@ -1520,6 +1642,7 @@ class LLMEngine(EngineBase):
                     # chaos freeze: poll without processing and —
                     # crucially — without heartbeating, exactly
                     # what a wedged host looks like to healthwatch
+                    self._held_from = None
                     await asyncio.sleep(self._chaos.freeze_poll_s)
                     continue
                 if self._health is not None:
@@ -1528,7 +1651,8 @@ class LLMEngine(EngineBase):
                 if not len(self._queue) and all(
                         s is None for s in self._slots):
                     # nothing queued, nothing running: park
-                    self._flight.clear()
+                    self._give_up()
+                    self._held_from = None
                     self._wake.clear()
                     if self._health is not None:
                         # parked-idle is not a failure: the probe
@@ -1539,11 +1663,19 @@ class LLMEngine(EngineBase):
                     continue
                 # one raytpu.engine.step span per iteration with
                 # work in it; its leaf phases partition it
-                with self._phases.step():
+                self._iteration += 1
+                with self._phases.step(n=self._iteration) as step:
+                    if self._held_from is None:
+                        self._held_from = step.t0
                     if await self._step():
-                        with phase("yield"):
+                        with phase("yield") as let_go:
                             # callers enqueue mid-flight here
                             await asyncio.sleep(0)
+                        # how long they waited for it: the loop ran
+                        # from the last yield (or its waking) to this
+                        self._telemetry.record_hold(
+                            let_go.t0 - self._held_from)
+                        self._held_from = let_go.t1
                 continue
             except Exception as e:  # noqa: BLE001 - fail loudly
                 # crash postmortem: the journal around the failure
@@ -1557,8 +1689,8 @@ class LLMEngine(EngineBase):
                         context={"error": repr(e)[:500]})
                 except Exception:  # noqa: BLE001 - dump best-effort
                     pass
-                self._flight.clear()
-                self._joins.clear()
+                self._give_up()
+                self._held_from = None
                 for i, st in enumerate(self._slots):
                     if st is not None:
                         self._telemetry.record_error(st["rec"], error=repr(e))

@@ -151,6 +151,10 @@ def _jitted_engine_fns(family, cfg, sampling, kv_layout="dense",
         # a prefill's first token into a wave's tokens, on the device
         return lax.dynamic_update_slice(toks, tok.astype(toks.dtype), (slot,))
 
+    def take_counters(counters):
+        # a copy that outlives the cache's next donation
+        return counters + 0
+
     def fork_block(cache, src, dst):
         return pinned(dc.copy_block(cache, src, dst))
 
@@ -211,7 +215,7 @@ def _jitted_engine_fns(family, cfg, sampling, kv_layout="dense",
         pool_logits=jax.jit(pool_logits, donate_argnums=(1,)),
         admit=jax.jit(dc.admit),
         join_token=jax.jit(join_token),
-        take_counters=jax.jit(lambda counters: counters + 0),
+        take_counters=jax.jit(take_counters),
         copy_block=jax.jit(fork_block, donate_argnums=(0,)),
         clear_row=jax.jit(consuming(dc.clear_row), donate_argnums=(0,)),
         restore_state=jax.jit(consuming(dc.restore_state),

@@ -279,6 +279,27 @@ EMPTY_RECURRENT = {"state_bytes": 0, "snapshots_resident": 0,
                    "snapshot_evictions": 0}
 
 
+#: launch records and loop holds an engine keeps (`EngineTelemetry
+#: .record_launch`, `.record_hold`): a record is a small dict and the
+#: fastest engine lands under a hundred a second
+LAUNCH_HISTORY = 4096
+#: the kinds of launch the continuous engine stamps (serve/engine.py
+#: `LLMEngine._launch`); a decode replica's handoff splice is the sixth
+LAUNCH_KINDS = ("prefill", "chunk", "decode", "mixed", "spec", "handoff")
+#: the newest engine's ring of landed launch records: what
+#: `recent_launches` reads after the engine itself is gone
+_newest_launches: Deque[Dict[str, Any]] = collections.deque(maxlen=0)
+
+
+def recent_launches() -> List[Dict[str, Any]]:
+    """The launch records of the newest engine of this process, oldest
+    first (`EngineTelemetry.record_launch` says what one holds), at
+    most `LAUNCH_HISTORY` of them.  They outlive `shutdown_engine()`
+    and the engine: a benchmark's reader, or an operator's postmortem,
+    asks the process."""
+    return list(_newest_launches)
+
+
 def _tracebus_enabled() -> bool:
     """Tracebus bookkeeping (TraceContext + per-token timestamps) is
     always-on unless ``RAYTPU_TRACEBUS=0`` — same opt-out contract as
@@ -595,6 +616,17 @@ class EngineTelemetry:
         #: jnp walk did; the kernel's (query tile, key tile) pairs
         #: walked, and the pairs without the diagonal
         self._prefill_attn = [0, 0, 0, 0]
+        #: landed launch records, oldest first (`record_launch`), and
+        #: their sums by kind, keyed as ``engine_stats()["launches"]``
+        #: gives them (``by_bucket`` stays empty for a wave)
+        global _newest_launches
+        self._launches: Deque[Dict[str, Any]] = collections.deque(
+            maxlen=LAUNCH_HISTORY)
+        _newest_launches = self._launches
+        self._launch_sums: Dict[str, Dict[str, Any]] = {}
+        #: milliseconds the engine's loop ran between two yields
+        self._holds: Deque[float] = collections.deque(
+            maxlen=LAUNCH_HISTORY)
         #: round-19 healthwatch block (serve/health.py) the deployment
         #: refreshes from its fleet HealthMonitor — zero-shaped when
         #: no monitor watches this engine (standalone / disabled)
@@ -1204,6 +1236,57 @@ class EngineTelemetry:
         self._m["prefill_attn_kernel" if kernel else "prefill_attn_jnp"
                 ].inc(1, tags=self._tags)
 
+    def record_launch(self, launch: Dict[str, Any]) -> None:
+        """One program the engine handed the device, landed (fenced,
+        or given up unfenced: ``fence`` is then None).  The engine's
+        own dict of the launch (serve/engine.py `LLMEngine._launch`),
+        less what it held of the device: ``seq`` (the engine's launch
+        counter), ``kind`` (`LAUNCH_KINDS`), ``program`` (the name a
+        trace's ``XLA Modules`` line gives it), ``rows`` (decoding rows
+        a wave steps; for a prefill the rows decoding when it was
+        dispatched), ``ahead`` (launches in flight at dispatch),
+        ``dispatch`` and ``fence`` ((t0, t1), the ``perf_counter``
+        stamps of the dispatch and fence phases), ``fused`` where one
+        phase holds both; for a prefill or a chunk ``req`` (the request
+        record's id), ``bucket``, ``prefix_len``, ``n_tail``; ``walk``
+        (`record_kv_walk`'s arguments) and ``attn``
+        (`record_prefill_attn`'s) where the launch has them.  Kept in a
+        ring of `LAUNCH_HISTORY` (`launch_records`, `recent_launches`)
+        and summed into ``engine_stats()["launches"]``."""
+        fence = launch.get("fence")
+        took = fence[1] - launch["dispatch"][0] if fence else 0.0
+        n_tail = launch.get("n_tail", 0)
+        with self._lock:
+            self._launches.append(launch)
+            acc = self._launch_sums.get(launch["kind"])
+            if acc is None:
+                acc = self._launch_sums[launch["kind"]] = {
+                    "count": 0, "rows": 0, "tail_tokens": 0, "ahead": 0,
+                    "turnaround_s": 0.0, "by_bucket": {}}
+            acc["count"] += 1
+            acc["rows"] += launch["rows"]
+            acc["tail_tokens"] += n_tail
+            acc["ahead"] += launch["ahead"]
+            acc["turnaround_s"] += took
+            if "bucket" in launch:
+                per = acc["by_bucket"].setdefault(
+                    launch["bucket"], [0, 0, 0.0])
+                per[0] += 1
+                per[1] += n_tail
+                per[2] += took
+
+    def launch_records(self) -> List[Dict[str, Any]]:
+        """The ring `record_launch` fills, oldest first."""
+        with self._lock:
+            return list(self._launches)
+
+    def record_hold(self, seconds: float) -> None:
+        """The engine's loop ran `seconds` between two points at which
+        it let its callers run (a ``yield`` phase, or parking): what a
+        caller of the engine on the same loop waits for before it is
+        heard.  ``engine_stats()["hold"]``."""
+        self._holds.append(seconds * 1e3)
+
     def record_health(self, block: Dict[str, Any]) -> None:
         """Latest healthwatch block (serve/health.py
         ``HealthMonitor.replica_block``) — mirrored into
@@ -1431,6 +1514,16 @@ class EngineTelemetry:
             experts = {k: dict(v) for k, v in self._experts.items()}
             walk_waves, walked, tabled = self._kv_walk
             attn_kernel, attn_jnp, pairs, square = self._prefill_attn
+            launches = {}
+            for kind, acc in sorted(self._launch_sums.items()):
+                block = launches[kind] = dict(
+                    acc, turnaround_s=round(acc["turnaround_s"], 6))
+                by_bucket = block.pop("by_bucket")
+                if by_bucket:
+                    block["by_bucket"] = {
+                        str(b): [n, tail, round(took, 6)]
+                        for b, (n, tail, took) in sorted(by_bucket.items())}
+            holds = list(self._holds)
             health = self._health_block
             spec = dict(self._spec)
             chunks = dict(self._chunks)
@@ -1525,6 +1618,19 @@ class EngineTelemetry:
                              "pairs_walked": pairs, "pairs_square": square,
                              "walked_share": round(pairs / square, 4)
                              if square else 0.0},
+            # the programs the engine handed the device, by kind: how
+            # many landed, the rows they stepped (a prefill: the rows
+            # that stood behind it), the prompt tokens they prefilled,
+            # the launches in flight ahead of them and the seconds from
+            # dispatch to fence, summed; prefills and chunks also by
+            # bucket, as [count, tail tokens, turnaround seconds].
+            # Empty until a launch lands (the batch scheduler stamps
+            # none)
+            "launches": launches,
+            # milliseconds the loop ran between two yields (the last
+            # LAUNCH_HISTORY of them): what a caller on the engine's
+            # loop waits before its request is heard
+            "hold": _core.summarize(holds),
             # round-19: healthwatch — liveness state machine counters
             # (stable zero-shaped block when no HealthMonitor watches
             # this engine: standalone, dense, or RAYTPU_HEALTHWATCH=0)

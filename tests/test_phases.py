@@ -113,15 +113,19 @@ def test_a_phase_is_a_host_span_only_while_a_trace_is_taken(tmp_path):
     assert ph.snapshot()["before"][0] == ph.snapshot()["during"][0] == 1
 
 
-def test_phase_costs_microseconds_without_a_trace(per_call_us):
+@pytest.mark.parametrize("attrs", [
+    {}, {"seq": 7, "kind": "decode", "rows": 32, "ahead": 3}],
+    ids=["bare", "with_attributes"])
+def test_phase_costs_microseconds_without_a_trace(per_call_us, attrs):
     """The budget per ``phase()`` call when the instrumentation is off
     (no profiler session): measured in isolation, min of repeats.  An
     engine step opens about a dozen, so 15 us each is under 0.2 ms a
-    step; the measured cost is 1-2 us."""
+    step; the measured cost is 1-2 us, with a launch's attributes
+    passed as without (they are encoded only under a session)."""
     ph = Phases("budget")
 
     def one():
-        with ph.phase("emit"):
+        with ph.phase("emit", **attrs):
             pass
 
     us = per_call_us(one)
