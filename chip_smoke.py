@@ -34,10 +34,10 @@ Phases, one chip:
            shared-prefix traffic, against models.gpt2_decode.generate.
   runtime  the README quick start: ray_tpu.init() finds the chip, a
            num_tpus=1 actor trains on it, a num_tpus=0 task stays off it.
-  mla      the latent-attention kernels of the Kimi-K2 cell at its
-           shapes, each against its jnp reference: the prefill's flash
-           kernel, the decode step's walk over the paged latent pool
-           and the rotary pool's re-lay.
+  mla      the kernels of the Kimi-K2 cell at its shapes, each against
+           its jnp reference: the prefill's flash kernel, the decode
+           step's walk over the paged latent pool, the rotary pool's
+           re-lay, and the expert layer's dispatch and combine.
 Phases, --chips 4 (and no one-chip phase):
   mesh_train    the train step over data=4 and data=2 x fsdp=2 against
                 the same step on device 0.
@@ -123,6 +123,11 @@ class Size:
     mla_prefills: tuple = ((8192, 0, 8155), (1024, 7173, 1019))
     #: (rows, pool blocks, layers) of a decode wave over the paged pool
     mla_wave: tuple = (64, 8193, 6)
+    #: (tokens, rows of grouped order a pass takes) of the expert
+    #: layer's dispatch and combine: the largest prefill bucket, a
+    #: wave; with the first `moe_held` experts held
+    moe_rows: tuple = ((8192, 2304), (64, 32))
+    moe_held: int = 12
     # serve: bench.py's on-chip TrafficSpec cut to a few dozen requests
     requests: int = 32
     #: warm-up requests of the serve phase (another seed's traffic), and
@@ -1019,9 +1024,58 @@ def _check_mla_decode(size: Size, interpret: bool) -> None:
         assert err <= KERNEL_TOL, ("mla_paged_decode", lidx, err)
 
 
+def _check_moe_rows(size: Size, interpret: bool) -> None:
+    """ops/moe_dispatch.py: the local assignments' rows into grouped
+    order, to the bit, and the grouped results back onto their tokens,
+    against the sort / gather / scatter-add they replace; a first pass
+    and, with half the rows a pass, the second."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.models.kimi_k2 import kimi_k2_config
+    from ray_tpu.ops import moe_dispatch as md
+
+    cfg = kimi_k2_config(size.mla_preset,
+                         held=range(size.moe_held)).experts
+    K, g, d = cfg.top_k, cfg.n_held, cfg.d_model
+    for n, rows in size.moe_rows:
+        ks = jax.random.split(jax.random.PRNGKey(size.seed + 5 + n), 5)
+        _, chosen = jax.lax.top_k(
+            jax.random.uniform(ks[0], (n, cfg.n_routed)), K)
+        place = np.full((cfg.n_routed,), g, np.int32)
+        place[list(cfg.held_ids)] = np.arange(g)
+        loc = jnp.where((jnp.arange(n) >= n // 50)[:, None],
+                        jnp.asarray(place)[chosen], g)  # a few pad rows
+        counts = jnp.sum(loc[..., None] == jnp.arange(g), axis=(0, 1))
+        starts = (jnp.cumsum(counts) - counts).astype(jnp.int32)
+        n_local = int(counts.sum())
+        x = jax.random.normal(ks[1], (n, d), jnp.float32)
+        base = jax.random.normal(ks[2], (n, d), jnp.float32)
+        w = jax.random.uniform(ks[3], (n, K), jnp.float32)
+        for lo, take in ((0, rows), (rows // 2, rows // 2)):
+            ys = md.slabs(jax.random.normal(ks[4], (take, d), jnp.float32))
+            t0 = time.perf_counter()
+            xs = md.moe_dispatch(x, loc, starts, lo, rows=take,
+                                 interpret=interpret)
+            held = min(max(n_local - lo, 0), take)
+            same = bool(jnp.array_equal(xs[:held], md.dispatch_reference(
+                x, loc, starts, lo, rows=take)[:held]))
+            got = md.moe_combine(base, ys, loc, w, starts, lo,
+                                 interpret=interpret)
+            err = float(jnp.max(jnp.abs(got - md.combine_reference(
+                base, ys, loc, w, starts, lo))))
+            say("mla", kernel="moe_dispatch+moe_combine",
+                shape=[n, take, d, K, g], first_row=lo, local_rows=n_local,
+                dispatch_identical=same, combine_err=round(err, 7),
+                seconds=round(time.perf_counter() - t0, 2))
+            assert same and err <= 1e-4, ("moe_dispatch", n, lo, same, err)
+
+
 def check_mla_kernels(size: Size, *, interpret: bool = False) -> None:
     _check_mla_prefill(size, interpret)
     _check_mla_decode(size, interpret)
+    _check_moe_rows(size, interpret)
 
 
 def phase_mla(size: Size, platform: str = "tpu") -> Dict[str, Any]:

@@ -48,8 +48,10 @@ SSM_STATE = "ssm_state"
 MLA = "mla"
 #: a sparse expert layer (models/experts.py): scores and top-k ...
 MOE_ROUTER = "moe_router"
-#: ... and the held experts' sort, grouped matmuls and combine; the
-#: shared expert and a dense layer's MLP keep ``mlp``
+#: ... and the held experts' part: which choices are local and their
+#: counts, the rows' dispatch into grouped order, the grouped matmuls
+#: and the weighted combine onto the tokens' rows; the shared expert
+#: and a dense layer's MLP keep ``mlp``
 MOE_EXPERTS = "moe_experts"
 #: the decode programs' scan over layers: what no inner scope claims is
 #: the scan's own plumbing (slicing the stacked weights, stacking the
@@ -63,6 +65,7 @@ OPTIMIZER = "optimizer"
 #: and carries no name stack: the stem says what they were made from.
 #: ``lax.ragged_dot`` becomes a grouped-matmul kernel named
 #: ``ragged-dot-*``, and the only ragged_dot here is the experts'
+#: (both paths of experts.routed_experts keep it)
 REWRITTEN = {"ragged-dot": MOE_EXPERTS}
 
 DEVICE_SCOPES = frozenset((EMBED, ATTN, MLP, LN, LM_HEAD_CE, LM_HEAD,
@@ -85,10 +88,14 @@ MLA_ROTARY_LANES = "mla_rotary_lanes"
 #: a prefill's expanded causal latent attention, one sequence
 #: (ops/mla_flash_prefill.py)
 MLA_FLASH_PREFILL = "mla_flash_prefill"
+#: the held experts' rows out of the tokens' and back onto them
+#: (ops/moe_dispatch.py)
+MOE_DISPATCH, MOE_COMBINE = "moe_dispatch", "moe_combine"
 KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV,
            FLASH_RES_FWD, FLASH_RES_DQ, FLASH_RES_DKV,
            FLASH_TRI_FWD, FLASH_TRI_BWD, SSM_SCAN, MLA_PAGED_DECODE,
-           MLA_ROTARY_LANES, MLA_FLASH_PREFILL)
+           MLA_ROTARY_LANES, MLA_FLASH_PREFILL, MOE_DISPATCH,
+           MOE_COMBINE)
 
 # -- host phases -------------------------------------------------------------
 SPAN_PREFIX = "raytpu."

@@ -18,16 +18,21 @@ tokens: with ``held`` = all experts the layer is the whole published
 layer, and the routed parts of a partition of the experts add up to it
 (tests/test_experts.py holds both).
 
-Dropless, with static shapes.  The ``N * top_k`` (token, expert)
-assignments are sorted so that those on held experts come first,
-grouped by expert; the groups go through `jax.lax.ragged_dot` (on a TPU
-XLA lowers it to a grouped-matmul kernel that visits only the tiles a
-group has rows in, so an expert no token chose is never read).  The
-sorted rows are walked in tiles of a static size, as many tiles as the
-held assignments fill: the bound is the ``N * top_k`` assignments
-themselves, never a capacity, so imbalance costs time and drops
-nothing.  `moe_layer` hands back, beside the result, what the routing
-did on this chip (`STATS`), computed where the counts already are.
+Dropless, with static shapes.  The held experts' rows are put in
+GROUPED order (expert 0's first, each expert's by ascending token) and
+the groups go through `jax.lax.ragged_dot` (on a TPU XLA lowers it to a
+grouped-matmul kernel that visits only the tiles a group has rows in,
+so an expert no token chose is never read).  The serving path finds a
+choice's place among the held by comparison and moves the rows with two
+Pallas kernels that walk the tokens (ops/moe_dispatch.py): no sort over
+the ``N * top_k`` assignments, no gather, no scatter.  A pass takes a
+static number of grouped rows (`tile_rows`: what a prefill's local rows
+fit in), as many passes as the local rows fill: the bound is the
+assignments themselves, never a capacity, so imbalance costs time and
+drops nothing.  The path that differentiates sorts all ``N * top_k``
+assignments and takes them in one grouped matmul.  `moe_layer` hands
+back, beside the result, what the routing did on this chip (`STATS`),
+computed where the counts already are.
 
 `models/moe.py` is another layer (GShard: softmax, a capacity, drops,
 GELU, biases) wired into GPT-2's training path; it is left as it is.
@@ -36,6 +41,7 @@ GELU, biases) wired into GPT-2's training path; it is left as it is.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -44,6 +50,9 @@ import numpy as np
 from jax import lax
 
 from ray_tpu._private import scopes
+from ray_tpu.ops.moe_dispatch import (combine_reference,
+                                      dispatch_reference, moe_combine,
+                                      moe_dispatch, rows_of, slabs)
 
 #: what `moe_layer` reports of one layer's routing on this chip:
 #: assignments that fell on held experts, held experts with at least
@@ -69,8 +78,8 @@ class ExpertsConfig:
     n_shared: int = 1
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
-    #: most rows of sorted assignments one grouped matmul takes
-    tile_rows: int = 2048
+    #: most rows of grouped assignments one pass takes (`tile_rows`)
+    tile_rows: int = 4096
 
     def __post_init__(self):
         if self.scoring not in ("sigmoid", "softmax"):
@@ -213,80 +222,125 @@ def _grouped(xs, p, sizes, dtype, layer=None):
     return dot(h.astype(dtype), p["w_down"])
 
 
-def tile_rows(n_assign: int, cfg: ExpertsConfig) -> int:
-    """Rows of sorted assignments one grouped matmul takes: twice this
-    chip's even share of them, in whole lane tiles of 128, at most
-    ``cfg.tile_rows`` (and never more than there are).  A decode wave's
-    handful of local rows must not be padded to a prefill's tile: the
-    kernel's row tile is the matmul's M."""
-    share = 2 * n_assign * cfg.n_held // cfg.n_routed
-    rows = min(cfg.tile_rows, max(128, -(-share // 128) * 128))
-    return min(rows, n_assign)
+def tile_rows(n_tokens: int, cfg: ExpertsConfig) -> int:
+    """Rows of grouped order one pass of `routed_experts` takes: this
+    chip's even share of the tokens' assignments and four of its
+    standard deviations more (a choice is local one time in
+    n_routed / n_held: the share's variance is the share), so that a
+    prefill's local rows fit one pass and the held weights are read
+    once a layer; at most ``cfg.tile_rows``, never more than can be
+    local.
+
+    The count is then rounded to what the grouped matmul does well
+    with: the compiler's kernel takes its row tile, its M, from the
+    rows it is handed, and multiplies a whole tile a group whatever
+    rows the group has (PERF.md, PR 40: a call over 12 groups of ~107
+    rows takes 1.36 ms handed 1,536 rows, a multiple of 512, 1.00 ms
+    handed 1,792, and 1.06 handed 1,408 or 1,664).  Up to a tile of
+    128 the rows are whole sublane tiles of 16 (a decode wave's 16
+    expected rows take 32: 0.41 ms a call where 128 take 0.47); up to
+    512 whole tiles of 128, never 512 itself; beyond, an odd multiple
+    of 256."""
+    share = n_tokens * cfg.top_k * cfg.n_held // cfg.n_routed
+    rows = share + 4 * math.isqrt(share)
+    if rows <= 128:
+        rows = max(16, -(-rows // 16) * 16)
+    elif rows < 512:
+        rows = -(-rows // 128) * 128
+    else:
+        rows = (rows + 255) // 512 * 512 + 256
+    return min(rows, cfg.tile_rows,
+               n_tokens * min(cfg.top_k, cfg.n_held))
 
 
-@jax.named_scope(scopes.MOE_EXPERTS)
-def routed_experts(p, x, chosen, w, cfg: ExpertsConfig, valid=None,
-                   tiled: bool = True, layer=None):
-    """The held experts' part of the routed sum.
-
-    x (N, d) compute dtype; chosen, w (N, top_k) as `route` gives them;
-    valid (N,) bool or None: rows that hold no token (a prefill's pads,
-    a decode pool's idle rows) are routed nowhere.  `tiled` False takes
-    all N*top_k sorted rows in one grouped matmul (static all through,
-    so it differentiates; the training forward uses it), True walks
-    them in `tile_rows` as far as the held assignments reach.  `layer`
-    says that p is a stack over layers and which of them this is
-    (`_grouped`).
-
-    Returns (y (N, d) float32, stats (len(STATS),) float32)."""
+def _sorted_whole(p, x, local, w, counts, cfg: ExpertsConfig, layer):
+    """The routed sum through XLA alone, static all through, so it
+    differentiates: the N * top_k assignments sorted so that those on
+    held experts come first by expert, their tokens' rows gathered, ONE
+    grouped matmul over all of them, a scatter-add back."""
     N, d = x.shape
     K, g = cfg.top_k, cfg.n_held
-    A = N * K
-    # expert id -> its place among the held, g for an expert elsewhere
-    place = np.full((cfg.n_routed,), g, np.int32)
-    place[list(cfg.held_ids)] = np.arange(g, dtype=np.int32)
-    local = jnp.asarray(place)[chosen]                      # (N, K)
-    if valid is not None:
-        local = jnp.where(valid[:, None], local, g)
-    local = local.reshape(A)
-    order = jnp.argsort(local, stable=True)     # held first, by expert
+    flat = local.reshape(N * K)
+    order = jnp.argsort(flat, stable=True)      # held first, by expert
     tok = (order // K).astype(jnp.int32)        # the row each came from
-    wt = jnp.where(local[order] < g, w.reshape(A)[order], 0.0)
-    counts = jnp.sum(local[:, None] == jnp.arange(g)[None, :], axis=0,
-                     dtype=jnp.int32)                       # (g,)
-    n_local = jnp.sum(counts)
+    wt = jnp.where(flat[order] < g, w.reshape(N * K)[order], 0.0)
+    out = _grouped(x.astype(cfg.dtype)[tok], p, counts, cfg.dtype, layer)
+    return jnp.zeros((N, d), jnp.float32).at[tok].add(out * wt[:, None])
+
+
+def _walked(p, x, local, w, counts, cfg: ExpertsConfig, layer, base):
+    """The routed sum onto `base` with no sort, gather or scatter
+    (ops/moe_dispatch.py): the local assignments' rows are copied into
+    grouped order by a walk over the tokens, `tile_rows` of them go
+    through the grouped matmuls, and the same walk adds each result
+    row, times its weight, onto its token's.  As many passes as the
+    local rows fill: one, but under an imbalance `tile_rows`' margin
+    does not cover; the bound is the assignments themselves."""
+    N, _ = x.shape
+    R = tile_rows(N, cfg)
     ends = jnp.cumsum(counts)
     starts = ends - counts
-    x = x.astype(cfg.dtype)
-    R = tile_rows(A, cfg) if tiled else A
+    # the chip runs the kernels; elsewhere their `jnp` references, the
+    # parity oracle (tests/test_experts.py steers the kernels in, in the
+    # Pallas interpreter)
+    dispatch, combine = (moe_dispatch, moe_combine) \
+        if jax.default_backend() == "tpu" \
+        else (dispatch_reference, combine_reference)
+    x = x.astype(jnp.float32)
 
     # (a loop's body names its scope again: it is lowered as a function
     # of its own, kimi_k2_decode.attend_blockwise)
     @jax.named_scope(scopes.MOE_EXPERTS)
     def tile(i, y):
-        s = i * R
-        t = lax.dynamic_slice_in_dim(tok, s, R)
-        sizes = jnp.clip(ends - s, 0, R) - jnp.clip(starts - s, 0, R)
-        out = _grouped(x[t], p, sizes, cfg.dtype, layer)
-        weight = lax.dynamic_slice_in_dim(wt, s, R)
-        return y.at[t].add(out * weight[:, None])
+        lo = i * R
+        xs = dispatch(x, local, starts, lo, rows=R)
+        sizes = jnp.clip(ends - lo, 0, R) - jnp.clip(starts - lo, 0, R)
+        out = _grouped(rows_of(xs).astype(cfg.dtype), p, sizes, cfg.dtype,
+                       layer)
+        return combine(y, slabs(out), local, w, starts, lo)
 
-    y = jnp.zeros((N, d), jnp.float32)
-    if R == A:
-        y = tile(0, y)
+    return lax.fori_loop(0, (ends[-1] + R - 1) // R, tile, base)
+
+
+@jax.named_scope(scopes.MOE_EXPERTS)
+def routed_experts(p, x, chosen, w, cfg: ExpertsConfig, valid=None,
+                   tiled: bool = True, layer=None, base=None):
+    """The held experts' part of the routed sum, added to `base`.
+
+    x (N, d), the compute dtype or the float32 it is rounded from;
+    chosen, w (N, top_k) as `route` gives them; valid (N,) bool or
+    None: rows that hold no token (a prefill's pads, a decode pool's
+    idle rows) are routed nowhere; base (N, d) float32 or None (zeros):
+    what the sum starts from, the shared experts' part.  `tiled` True
+    is the serving path (`_walked`: Pallas kernels around the grouped
+    matmuls), False the one that differentiates (`_sorted_whole`; the
+    training forward, and what the other is tested against).  `layer`
+    says that p is a stack over layers and which of them this is
+    (`_grouped`).
+
+    Returns (y (N, d) float32, stats (len(STATS),) float32)."""
+    N, d = x.shape
+    g = cfg.n_held
+    # a choice's place among the held, g for an expert elsewhere: by
+    # comparison with the g held ids, not by a table looked up
+    # N * top_k times
+    hit = chosen[:, :, None] == np.asarray(cfg.held_ids, np.int32)
+    if valid is not None:
+        hit = hit & valid[:, None, None]
+    local = g + jnp.sum(jnp.where(hit, np.arange(g, dtype=np.int32) - g, 0),
+                        axis=-1, dtype=jnp.int32)               # (N, K)
+    counts = jnp.sum(hit, axis=(0, 1), dtype=jnp.int32)         # (g,)
+    if tiled:
+        y = _walked(p, x, local, w, counts, cfg, layer,
+                    jnp.zeros((N, d), jnp.float32) if base is None
+                    else base)
     else:
-        # the sorted rows padded to whole tiles (a dynamic_slice
-        # clamps a last tile that would run over, and would take rows
-        # twice); sorted rows past n_local carry weight 0
-        pad = -A % R
-        if pad:
-            tok = jnp.concatenate([tok, jnp.zeros((pad,), tok.dtype)])
-            wt = jnp.concatenate([wt, jnp.zeros((pad,), wt.dtype)])
-        y = lax.fori_loop(0, (n_local + R - 1) // R, tile, y)
+        y = _sorted_whole(p, x, local, w, counts, cfg, layer)
+        y = y if base is None else y + base
     load = counts.astype(jnp.float32)
     mean = jnp.sum(load) / g
     stats = jnp.stack([
-        n_local.astype(jnp.float32),
+        jnp.sum(load),
         jnp.sum(counts > 0).astype(jnp.float32),
         jnp.where(mean > 0, jnp.max(load) / jnp.maximum(mean, 1e-9), 0.0)])
     return y, stats
@@ -299,9 +353,9 @@ def moe_layer(p, x32, cfg: ExpertsConfig, valid=None, tiled: bool = True):
     int32 scalar) the stack of every layer's, left whole (`_grouped`).
     Returns (y (N, d) in ``cfg.dtype``, stats as `routed_experts`)."""
     chosen, w = route(p["router"], x32, cfg)
-    x = x32.astype(cfg.dtype)
-    y, stats = routed_experts(p["experts"], x, chosen, w, cfg, valid,
-                              tiled, p.get("layer"))
-    if cfg.n_shared:
-        y = y + shared_expert(p["shared"], x, cfg).astype(jnp.float32)
-    return y.astype(cfg.dtype), stats
+    base = shared_expert(p["shared"], x32.astype(cfg.dtype),
+                         cfg).astype(jnp.float32) if cfg.n_shared else None
+    y, stats = routed_experts(p["experts"], x32, chosen, w, cfg, valid,
+                              tiled, p.get("layer"), base)
+    with jax.named_scope(scopes.MOE_EXPERTS):       # the combine's cast
+        return y.astype(cfg.dtype), stats

@@ -3,6 +3,8 @@
 arithmetic, nothing dropped under the worst imbalance, the shares of a
 partition of the experts adding up to the uncut layer, the counters."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -177,12 +179,105 @@ def test_the_counters_count_the_held_experts_load():
                                counts.max() / counts.mean(), rtol=1e-6)
 
 
-@pytest.mark.parametrize("n_assign,want", [(512, 128), (65536, 2048),
-                                           (8192, 512), (64, 64)])
-def test_tile_rows_follow_the_chips_share(n_assign, want):
+@pytest.mark.parametrize("n_tokens,want", [(64, 32), (8192, 2304),
+                                           (1024, 384), (5120, 1792),
+                                           (2, 16)])
+def test_tile_rows_follow_the_chips_share(n_tokens, want):
+    """A decode wave's 16 expected rows take 32, an 8k prefill's 2,048
+    take 2,304 in one pass, and a count past 512 is an odd multiple of
+    256, never a multiple of 512 (the grouped matmul's row tile would
+    be 512); never more rows than the tokens can send (2 tokens x 8
+    choices)."""
     cfg = ex.ExpertsConfig(d_model=8, d_expert=8, n_routed=384, top_k=8,
                            held=ex.held_range(0, 12))
-    assert ex.tile_rows(n_assign, cfg) == want
+    assert ex.tile_rows(n_tokens, cfg) == want
+
+
+#: the serving path (`tiled=True`: the kernels of ops/moe_dispatch.py
+#: around the grouped matmuls) against the path that differentiates, at
+#: the shapes the cells run (cut to toy widths) and their edges.
+#: name -> (N, rows that hold a token or None, held or None, tile_rows,
+#: the router's bias or None, stacked)
+SERVED = {
+    "a_decode_wave": (64, None, (0, 1, 2, 3, 4, 5), 4096, None, False),
+    "a_decode_wave_with_idle_rows": (64, "every_third_idle", (2, 3, 5, 11),
+                                     4096, None, False),
+    "a_prefill_bucket_with_pads": (1024, 1000, (0, 1, 2, 3, 4, 5), 4096,
+                                   None, False),
+    "3000_real_of_3072": (3072, 3000, (4, 5, 6, 7), 4096, None, False),
+    "every_expert_held": (64, None, None, 4096, None, False),
+    "every_expert_held_and_pads": (96, 80, None, 4096, None, False),
+    "a_stack_of_layers": (64, 50, (0, 1, 2, 3, 4, 5), 4096, None, True),
+    "one_held_expert_takes_every_local_row": (
+        128, None, (3, 12, 13, 14), 4096, {3: 10.0, 12: -10.0, 13: -10.0,
+                                           14: -10.0}, False),
+    "no_row_is_local": (64, None, (0, 1), 4096, {0: -10.0, 1: -10.0},
+                        False),
+    "local_rows_just_under_one_tile": (40, 31, None, 128, None, False),
+    "local_rows_fill_one_tile": (40, 32, None, 128, None, False),
+    "local_rows_just_over_one_tile": (40, 33, None, 128, None, False),
+    "local_rows_over_many_tiles": (200, None, None, 128, {3: 10.0}, False),
+}
+
+
+@pytest.fixture(params=["kernels_interpreted", "as_the_cpu_runs_it"])
+def runs(request, monkeypatch):
+    """What `routed_experts(tiled=True)` moves rows with: off the chip
+    the kernels' `jnp` references; steered here to the kernels
+    themselves, in the Pallas interpreter."""
+    if request.param == "kernels_interpreted":
+        monkeypatch.setattr(ex, "dispatch_reference", functools.partial(
+            ex.moe_dispatch, interpret=True))
+        monkeypatch.setattr(ex, "combine_reference", functools.partial(
+            ex.moe_combine, interpret=True))
+    return request.param
+
+
+@pytest.mark.parametrize("name", SERVED)
+def test_the_served_path_is_the_one_that_differentiates(whole, name, runs):
+    n, real, held, tile, bias, stacked = SERVED[name]
+    _, p = whole
+    cfg = _cfg(held=held, tile_rows=tile)
+    x = _x(n, seed=n + len(name))
+    router = p["router"]
+    if bias:
+        router = dict(router, bias=jnp.zeros((E,)).at[
+            jnp.asarray(list(bias))].set(jnp.asarray(list(bias.values()))))
+    chosen, w = ex.route(router, x, cfg)
+    if real == "every_third_idle":
+        valid = jnp.arange(n) % 3 != 0
+    else:
+        valid = None if real is None else jnp.arange(n) >= n - real
+    mine = {k: v[jnp.asarray(cfg.held_ids)] for k, v in p["experts"].items()}
+    layer = None
+    if stacked:
+        other = jax.tree.map(lambda a: a[::-1] * 0.5, mine)
+        mine = jax.tree.map(lambda a, b: jnp.stack([a, b]), other, mine)
+        layer = jnp.int32(1)
+    base = _x(n, seed=99)
+    want, want_stats = ex.routed_experts(mine, x, chosen, w, cfg, valid,
+                                         tiled=False, layer=layer,
+                                         base=base)
+    got, stats = ex.routed_experts(mine, x, chosen, w, cfg, valid,
+                                   tiled=True, layer=layer, base=base)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(stats), np.asarray(want_stats))
+    # the counter is the integer the choices give
+    local = np.isin(np.asarray(chosen), cfg.held_ids)
+    if valid is not None:
+        local &= np.asarray(valid)[:, None]
+    assert float(stats[0]) == local.sum()
+    if name == "no_row_is_local":
+        assert local.sum() == 0
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(base))
+    if name == "one_held_expert_takes_every_local_row":
+        assert float(stats[1]) == 1 and local.sum() == n
+    if name.startswith("local_rows_"):
+        rows = ex.tile_rows(n, cfg)
+        passes = -(-int(local.sum()) // rows)
+        assert passes == {"just_under_one_tile": 1, "fill_one_tile": 1,
+                          "just_over_one_tile": 2,
+                          "over_many_tiles": 7}[name[len("local_rows_"):]]
 
 
 @pytest.mark.parametrize("bad", [{"held": (0, 0)}, {"held": (99,)},

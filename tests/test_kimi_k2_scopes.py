@@ -94,31 +94,78 @@ def test_the_new_scopes_are_registered_and_hold_no_other():
     assert set(scopes.REWRITTEN.values()) <= scopes.DEVICE_SCOPES
 
 
-def test_the_paged_decode_kernel_is_named_and_scoped_mla(params,
-                                                         monkeypatch):
-    """On the chip the paged decode step holds the kernel
-    ``mla_paged_decode`` (ray_tpu/ops/mla_paged_decode.py), one call in
-    each scan over layers, under ``mla``: not unscoped, not ``kv_pool``
-    (a reader divides the kernel's bytes by the time under ``mla``).
-    Traced only: tests/test_tpu_compile.py reads the compiled
-    program's own scope map."""
-    assert scopes.MLA_PAGED_DECODE in scopes.KERNELS
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+def _traced(program, params):
+    """(primitive, its params, the name stack with the scans' and
+    loops' around it joined) of every equation `program` traces to,
+    kernels' own bodies apart.  Traced anew at every call (a trace is
+    kept by the function's identity, and a test that steers
+    ``jax.default_backend`` needs the program to ask again)."""
     paged = kimi_k2_init_paged_cache(CFG, 2, num_blocks=20, block_size=16)
-    jaxpr = jax.make_jaxpr(
-        lambda p, c, t: kimi_k2_decode_step(p, c, t, CFG))(
-            params, paged, jnp.zeros((2,), jnp.int32))
+    if program == "decode_step":
+        fn = lambda p, c, t: kimi_k2_decode_step(p, c, t, CFG)  # noqa: E731
+        args = (params, paged, jnp.zeros((2,), jnp.int32))
+    else:
+        fn = lambda p, c, t, bt: kimi_k2_paged_prefill(  # noqa: E731
+            p, c, t, CFG, row_bt=bt, prefix_len=0, n_tail=5, slot=0)
+        args = (params, paged, jnp.zeros((1, 16), jnp.int32),
+                jnp.zeros((8,), jnp.int32))
     found = []
 
     def walk(inner, stack):
         for eqn in inner.eqns:
             here = f"{stack}/{eqn.source_info.name_stack}"
-            if eqn.primitive.name == "pallas_call":
-                found.append((eqn.params["name"], here))
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                walk(sub, here)
+            found.append((eqn.primitive.name, eqn.params, here))
+            if eqn.primitive.name != "pallas_call":
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub, here)
 
-    walk(jaxpr.jaxpr, "jit(pool_step)")
-    assert [name for name, _ in found] == [scopes.MLA_PAGED_DECODE] * 2
-    assert {scopes.innermost_scope(stack) for _, stack in found} \
-        == {scopes.MLA}
+    walk(jax.make_jaxpr(fn)(*args).jaxpr, "jit(program)")
+    return found
+
+
+@pytest.mark.parametrize("program", ["decode_step", "paged_prefill"])
+def test_the_chips_kernels_are_named_and_scoped(program, params,
+                                                monkeypatch):
+    """On the chip the paged decode step holds the kernel
+    ``mla_paged_decode`` (ray_tpu/ops/mla_paged_decode.py), one call in
+    each scan over layers, under ``mla``: not unscoped, not ``kv_pool``
+    (a reader divides the kernel's bytes by the time under ``mla``);
+    and both programs move the held experts' rows with ``moe_dispatch``
+    and ``moe_combine`` (ray_tpu/ops/moe_dispatch.py), one call each in
+    the expert layers' scan, inside the loop over row tiles, under
+    ``moe_experts``.  Traced only: tests/test_tpu_compile.py reads the
+    compiled programs' own scope maps."""
+    assert {scopes.MLA_PAGED_DECODE, scopes.MOE_DISPATCH,
+            scopes.MOE_COMBINE} <= set(scopes.KERNELS)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    by_scope = collections.defaultdict(list)
+    for name, eqn_params, stack in _traced(program, params):
+        if name == "pallas_call":
+            by_scope[scopes.innermost_scope(stack)].append(
+                eqn_params["name"])
+    assert by_scope.pop(scopes.MOE_EXPERTS) == [scopes.MOE_DISPATCH,
+                                               scopes.MOE_COMBINE]
+    # (a toy tail takes the prefill's jnp walk, not its flash kernel)
+    assert dict(by_scope) == ({scopes.MLA: [scopes.MLA_PAGED_DECODE] * 2}
+                              if program == "decode_step" else {})
+
+
+@pytest.mark.parametrize("program", ["decode_step", "paged_prefill"])
+def test_the_expert_layer_sorts_and_scatters_nothing(program, params,
+                                                     monkeypatch):
+    """What the serving programs hold under ``moe_experts`` off the
+    chip is the kernels' `jnp` references, a sort and a scatter among
+    them; on the chip (steered) no sort, gather or scatter is traced
+    there at all: the rows are moved by the kernels."""
+    def under_experts():
+        return collections.Counter(
+            name for name, _, stack in _traced(program, params)
+            if scopes.innermost_scope(stack) == scopes.MOE_EXPERTS)
+
+    moved = {"sort", "gather", "scatter", "scatter-add", "scatter_add"}
+    assert moved & set(under_experts())
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    on_chip = under_experts()
+    assert not moved & set(on_chip), on_chip
+    assert on_chip["pallas_call"] == 2 and on_chip["ragged_dot_general"] \
+        + on_chip["ragged_dot"] == 3, on_chip

@@ -9,7 +9,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from ray_tpu._private import scopes
 from ray_tpu.models import decode_common as dc
+from ray_tpu.models import experts
 from ray_tpu.models import kimi_k2 as K
 from ray_tpu.models import kimi_k2_decode as D
 from ray_tpu.ops.mla_paged_decode import (mla_paged_decode,
@@ -219,6 +221,16 @@ def tiny():
     return cfg, K.kimi_k2_init(jax.random.PRNGKey(0), cfg)
 
 
+def _named(jaxpr, kernel):
+    """``pallas_call``s named `kernel` in a jaxpr, its sub-jaxprs'
+    too."""
+    return sum((eqn.primitive.name == "pallas_call"
+                and eqn.params["name"] == kernel)
+               + sum(_named(sub, kernel)
+                     for sub in jax.core.jaxprs_in_params(eqn.params))
+               for eqn in jaxpr.eqns)
+
+
 @pytest.mark.parametrize("backend,program,kernels", [
     ("cpu", "paged_decode", 0), ("tpu", "paged_decode", 2),
     ("tpu", "dense_decode", 0), ("tpu", "paged_prefill", 0)])
@@ -226,10 +238,15 @@ def test_only_the_paged_decode_step_on_the_chip_holds_the_kernel(
         tiny, monkeypatch, backend, program, kernels):
     """A paged cache, one column a row and the TPU backend take the
     kernel, one ``pallas_call`` in each of the two scans over layers;
-    the CPU, the dense cache and a prefill keep the ``jnp`` paths."""
+    the CPU, the dense cache and a prefill keep the ``jnp`` paths (the
+    expert layer's own kernels, which every program on the chip holds,
+    are tests/test_kimi_k2_scopes.py's)."""
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     fn, args = _programs(*tiny)[program]
-    assert _count(jax.make_jaxpr(fn)(*args).jaxpr, "pallas_call") == kernels
+    jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+    assert _named(jaxpr, scopes.MLA_PAGED_DECODE) == kernels
+    assert _count(jaxpr, "pallas_call") == (
+        kernels + 2 if backend == "tpu" else 0)
 
 
 def _shapes(jaxpr, found=None):
@@ -281,6 +298,10 @@ def test_the_decode_step_through_the_kernel_is_the_jnp_step(tiny,
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(D, "mla_paged_decode", functools.partial(
         mla_paged_decode, interpret=True))
+    # the chip's step moves the experts' rows by kernels too
+    for kernel in ("moe_dispatch", "moe_combine"):
+        monkeypatch.setattr(experts, kernel, functools.partial(
+            getattr(experts, kernel), interpret=True))
     got_logits, got = step(cache)
     got_logits2, got2 = step(got)
     live = np.asarray([0, 2])

@@ -35,7 +35,7 @@ TOY = chip_smoke.Size(
     tail_mean=6.0, tail_max=16, vocab=500, rate_rps=200.0, max_slots=4,
     new_tokens=8, prefill_bucket=16, mla_preset="nano", mla_max_seq=128,
     mla_tile=16, mla_prefills=((64, 0, 51), (64, 60, 14)),
-    mla_wave=(5, 24, 3))
+    mla_wave=(5, 24, 3), moe_rows=((64, 32), (8, 8)), moe_held=6)
 
 
 @pytest.fixture
