@@ -53,6 +53,12 @@ MOE_ROUTER = "moe_router"
 #: and the weighted combine onto the tokens' rows; the shared expert
 #: and a dense layer's MLP keep ``mlp``
 MOE_EXPERTS = "moe_experts"
+#: a layer that attends the whole context, and one that attends a
+#: bounded window of it (models/laguna.py: the two kinds differ in
+#: head count, rotary and reach): each kind's projections, rotary,
+#: scores, per-head gate and output projection
+ATTN_FULL = "attn_full"
+ATTN_WINDOW = "attn_window"
 #: the decode programs' scan over layers: what no inner scope claims is
 #: the scan's own plumbing (slicing the stacked weights, stacking the
 #: per-layer K/V it returns)
@@ -70,8 +76,8 @@ REWRITTEN = {"ragged-dot": MOE_EXPERTS}
 
 DEVICE_SCOPES = frozenset((EMBED, ATTN, MLP, LN, LM_HEAD_CE, LM_HEAD,
                            KV_POOL, SAMPLE, SSM, SSM_STATE, MLA,
-                           MOE_ROUTER, MOE_EXPERTS, LAYER_SCAN,
-                           LOSS_AND_GRAD, OPTIMIZER))
+                           MOE_ROUTER, MOE_EXPERTS, ATTN_FULL, ATTN_WINDOW,
+                           LAYER_SCAN, LOSS_AND_GRAD, OPTIMIZER))
 
 # -- Pallas kernel names (``pallas_call(name=)`` in ops/*.py) ----------------
 FLASH_FWD, FLASH_DQ, FLASH_DKV = "flash_fwd", "flash_dq", "flash_dkv"

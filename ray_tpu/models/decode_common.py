@@ -60,6 +60,19 @@ scattered where it lies, where one of 576 (4.5 lane tiles) is stored
 block-minor and re-laid a layer at a time, as the 64-wide one still is
 (PERF.md, PR 32).
 
+A fourth (models/laguna_decode.py): layers of two REACHES in one
+cache.  A layer that attends the whole context keeps its K/V in the
+paged pool, whose leading axis counts those layers alone (as a hybrid's
+pool counts its attention layers); a layer that attends a bounded
+window keeps, per SLOT and not per token, a ring of its last ``window``
+K/V rows (``wk``, ``wv``: (window layers, B, window, width), row ``slot
+mod window``, keys after rotary) and in the paged layout a snapshot
+pool of it (``snap_wk``, ``snap_wv``): per-slot state as ``conv`` and
+``ssm`` are, told to the paged prefill by the same ``state`` argument,
+so a block reserves full-reach rows for the full layers alone.  K and V
+of such a cache are FOLDED, (L, ..., n_kv_head * head_dim): a row is
+whole lane tiles.  `cache_reach` says what a cache reserves by reach.
+
 What a cache is made of (its keys, which axis of each tensor is the
 slot or the block, what a block weighs) is written in this module and
 nowhere else: the serving engine moves rows, blocks and state through
@@ -325,8 +338,13 @@ _HEADS = (None, None, None, "heads", "head_dim")
 #: same axes, one entry a slot.
 _TENSORS = {"k": (1, _HEADS), "v": (1, _HEADS),
             "ckv": (1, None), "kpe": (1, None),
-            "ssm": (1, None), "conv": (2, None)}
-_STATE = ("ssm", "conv")
+            "ssm": (1, None), "conv": (2, None),
+            "wk": (1, None), "wv": (1, None)}
+#: what a cache may keep per SLOT beside its per-position tensors: a
+#: recurrent layer's state, a window layer's ring of K/V rows
+_STATE = ("ssm", "conv", "wk", "wv")
+#: the rings among them: (window layers, slots, window, width)
+_WINDOW = ("wk", "wv")
 _SNAP = "snap_"
 
 
@@ -337,7 +355,9 @@ def cache_logical_axes(cache):
     collectives."""
     def axes(name):
         _, logical = _TENSORS.get(name.removeprefix(_SNAP), (None, None))
-        return logical or (None,) * cache[name].ndim
+        if logical is None or len(logical) != cache[name].ndim:
+            return (None,) * cache[name].ndim   # folded K/V: no heads axis
+        return logical
     return {name: axes(name) for name in cache}
 
 
@@ -439,6 +459,8 @@ def restore_state(cache, entry, slot):
     the entry may be another prefix's by then."""
     out = dict(cache)
     for name in _STATE:
+        if name not in cache:
+            continue
         axis = _TENSORS[name][0]
         out[name] = lax.dynamic_update_slice_in_dim(
             cache[name], lax.dynamic_slice_in_dim(
@@ -523,11 +545,32 @@ def kv_shards(cache) -> int:
 
 
 def state_bytes(cache) -> int:
-    """Bytes of the recurrent state a cache holds beside its K/V: every
-    slot's, and the snapshot pool's (0 for a family that keeps none)."""
+    """Bytes of the per-slot state a cache holds beside its K/V pool (a
+    recurrent layer's, a window layer's ring): every slot's, and the
+    snapshot pool's (0 for a family that keeps none)."""
     return sum(cache[name].nbytes
                for state in _STATE for name in (state, _SNAP + state)
                if name in cache)
+
+
+def cache_reach(cache) -> dict:
+    """What a paged cache reserves, by its layers' reach.  The pool's
+    layers keep every position of a sequence: ``pool_bytes_per_token``,
+    over all of them.  A window layer keeps ``window_rows`` rows a slot
+    whatever the sequence's length: ``window_bytes_per_slot``, over all
+    of them (0 rows and bytes for a cache without such layers).
+    ``full_reach_bytes_per_token`` is what a token would weigh were
+    every layer, the window layers too, kept at full reach."""
+    per_token = sum(
+        cache[n].nbytes // (cache[n].shape[1] * cache[n].shape[2])
+        for n in positional(cache))
+    rings = [cache[n] for n in _WINDOW if n in cache]
+    per_slot = sum(r.nbytes // r.shape[1] for r in rings)
+    rows = rings[0].shape[2] if rings else 0
+    return {"pool_bytes_per_token": per_token,
+            "window_bytes_per_slot": per_slot, "window_rows": rows,
+            "full_reach_bytes_per_token":
+                per_token + (per_slot // rows if rows else 0)}
 
 
 #: a family with a sparse expert layer (models/experts.py) keeps under

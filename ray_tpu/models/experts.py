@@ -228,8 +228,8 @@ def tile_rows(n_tokens: int, cfg: ExpertsConfig) -> int:
     standard deviations more (a choice is local one time in
     n_routed / n_held: the share's variance is the share), so that a
     prefill's local rows fit one pass and the held weights are read
-    once a layer; at most ``cfg.tile_rows``, never more than can be
-    local.
+    once a layer; never more than can be local (before the rounding
+    below), at most ``cfg.tile_rows``.
 
     The count is then rounded to what the grouped matmul does well
     with: the compiler's kernel takes its row tile, its M, from the
@@ -240,17 +240,22 @@ def tile_rows(n_tokens: int, cfg: ExpertsConfig) -> int:
     128 the rows are whole sublane tiles of 16 (a decode wave's 16
     expected rows take 32: 0.41 ms a call where 128 take 0.47); up to
     512 whole tiles of 128, never 512 itself; beyond, an odd multiple
-    of 256."""
+    of 256, or, where a group expects fewer than 16 rows (a decode
+    wave over many small experts all held: 512 rows over 256 groups),
+    an odd multiple of 128: a tile's height is then all waste, and the
+    lowest is taken (PERF.md, PR 42)."""
     share = n_tokens * cfg.top_k * cfg.n_held // cfg.n_routed
-    rows = share + 4 * math.isqrt(share)
+    rows = min(share + 4 * math.isqrt(share),
+               n_tokens * min(cfg.top_k, cfg.n_held))
     if rows <= 128:
         rows = max(16, -(-rows // 16) * 16)
     elif rows < 512:
         rows = -(-rows // 128) * 128
+    elif rows < 16 * cfg.n_held:
+        rows = (rows + 127) // 256 * 256 + 128
     else:
         rows = (rows + 255) // 512 * 512 + 256
-    return min(rows, cfg.tile_rows,
-               n_tokens * min(cfg.top_k, cfg.n_held))
+    return min(rows, cfg.tile_rows)
 
 
 def _sorted_whole(p, x, local, w, counts, cfg: ExpertsConfig, layer):

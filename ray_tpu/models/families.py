@@ -26,9 +26,18 @@ from typing import Any, Callable, Dict, Optional, Tuple
 #: its blocks), but a row is one latent a token, not K and V per head
 #: (models/kimi_k2_decode.py): what moves K/V rows of one shape, rewinds
 #: through a verify program, or splits a heads axis is refused for it.
+#: WINDOWED: some layers attend a bounded window and keep, per slot, a
+#: ring of their last K/V rows beside the pool of the layers that
+#: attend everything (models/laguna_decode.py): per-slot state as a
+#: recurrent layer's is, carried by the same `state` argument and the
+#: same snapshots, and refused where that is.
 KV = "kv"
 RECURRENT = "kv+recurrent"
 LATENT = "latent"
+WINDOWED = "kv+window"
+#: the kinds that keep state per SLOT beside the pool: their paged
+#: prefill takes `state`, and a prefix is reused from a snapshot of it
+PER_SLOT_STATE = frozenset((RECURRENT, WINDOWED))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -109,10 +118,25 @@ def _kimi_k2() -> Dict[str, Any]:
         prefill_attention=m.kimi_k2_prefill_attention)
 
 
+def _laguna() -> Dict[str, Any]:
+    from ray_tpu.models import laguna_decode as m
+    from ray_tpu.models.laguna import (laguna_config, laguna_init,
+                                       laguna_logical_axes)
+
+    return dict(
+        config=laguna_config, init=laguna_init,
+        logical_axes=laguna_logical_axes, generate=m.laguna_generate,
+        prefill=m.laguna_prefill, paged_prefill=m.laguna_paged_prefill,
+        step=m.laguna_decode_step, verify=None,
+        init_cache=m.laguna_init_cache,
+        init_paged_cache=m.laguna_init_paged_cache)
+
+
 #: family -> (what its cache holds, loader of its programs)
 FAMILIES: Dict[str, Tuple[str, Callable[[], Dict[str, Any]]]] = {
     "gpt2": (KV, _gpt2), "llama": (KV, _llama),
-    "jamba": (RECURRENT, _jamba), "kimi_k2": (LATENT, _kimi_k2)}
+    "jamba": (RECURRENT, _jamba), "kimi_k2": (LATENT, _kimi_k2),
+    "laguna": (WINDOWED, _laguna)}
 
 
 def cache_kind(name: str) -> Optional[str]:

@@ -29,7 +29,7 @@ from ray_tpu._private import scopes
 from ray_tpu._private.telemetry import Phases
 from ray_tpu.models import decode_common as dc
 from ray_tpu.models.decode_common import SamplingParams
-from ray_tpu.models.families import RECURRENT, family as _family
+from ray_tpu.models.families import PER_SLOT_STATE, family as _family
 from ray_tpu.serve.batching import (ChunkCursor, HandoffCursor,
                                     OverloadedError, RequestQueue)
 from ray_tpu.serve.engine_programs import _jitted_engine_fns
@@ -243,7 +243,7 @@ class LLMEngine(EngineBase):
 
         opt = self.opt
         cfg = self.cfg
-        self._recurrent = fam.cache_kind == RECURRENT
+        self._recurrent = fam.cache_kind in PER_SLOT_STATE
         self._pager = None
         self._kvscope_budget = None     # _compose_kv_scope's, cached
         if opt.kv_layout == "paged":
@@ -275,6 +275,9 @@ class LLMEngine(EngineBase):
                 host_tier=host_tier)
             if host_tier is not None:
                 self._pager.set_block_saver(self._tier_save)
+            # what the cache reserves by its layers' reach (a token in
+            # the pool, a slot's windows): `_reserved_by_reach`
+            self._reach = dc.cache_reach(self._cache)
             if self._recurrent:
                 # prefix reuse for a recurrent family: one snapshot
                 # of the state a slot, keyed as the pager keys the
@@ -1491,7 +1494,20 @@ class LLMEngine(EngineBase):
         if self._pager is not None:
             item["walk"] = (walked, len(rows) * (
                 self.cfg.max_seq // self.opt.kv_block_size))
+            item["reach"] = self._reserved_by_reach()
         self._flight.append(item)
+
+    def _reserved_by_reach(self):
+        """(bytes the resident requests hold reserved in pool blocks,
+        in per-slot windows, and what every layer at full reach would
+        reserve for them), now: `Telemetry.record_kv_reach`'s
+        arguments."""
+        reach = self._reach
+        tokens = self._pager.blocks_in_use * self.opt.kv_block_size
+        resident = sum(st is not None for st in self._slots)
+        return (tokens * reach["pool_bytes_per_token"],
+                resident * reach["window_bytes_per_slot"],
+                tokens * reach["full_reach_bytes_per_token"])
 
     def _land(self) -> None:
         """Fence what has been in flight longest.  A decode wave:
@@ -1508,6 +1524,7 @@ class LLMEngine(EngineBase):
             self._book_counters("decode", item["experts"])
             if "walk" in item:
                 self._telemetry.record_kv_walk(*item["walk"])
+                self._telemetry.record_kv_reach(*item["reach"])
         self._landed(item, (fence.t0, fence.t1))
         rows = {i: st for i, st in stepped.items()
                 if self._slots[i] is st}

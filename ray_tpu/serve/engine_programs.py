@@ -15,7 +15,7 @@ import types
 from typing import Any, Dict
 
 from ray_tpu.models.decode_common import SamplingParams
-from ray_tpu.models.families import RECURRENT
+from ray_tpu.models.families import PER_SLOT_STATE
 
 # jax's compile cache is keyed by the jitted function OBJECT, so a
 # fresh `jax.jit(closure)` per engine instance recompiles every
@@ -43,7 +43,7 @@ def _jitted_engine_fns(family, cfg, sampling, kv_layout="dense",
       prefill / paged_prefill / pool_step  — fused sample-included
           programs (engine-default sampling baked in; the hot path
           stays one dispatch).  The paged prefills' last argument is
-          the `state` a RECURRENT family's prefill is told (where the
+          the `state` a family with per-slot state is told (where the
           slot's state starts, the snapshot it leaves:
           LLMEngine._state_arg); a KV family is given None there
       prefill_raw / paged_prefill_raw / pool_logits — logits-returning
@@ -79,7 +79,7 @@ def _jitted_engine_fns(family, cfg, sampling, kv_layout="dense",
     identity."""
     if not isinstance(sampling, SamplingParams):
         sampling = SamplingParams(temperature=float(sampling))
-    recurrent = family.cache_kind == RECURRENT
+    recurrent = family.cache_kind in PER_SLOT_STATE
     verify_fn = family.verify if spec is not None else None
     draft_fns = None if draft is None else (draft.prefill, draft.step,
                                             draft_cfg)

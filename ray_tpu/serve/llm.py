@@ -92,6 +92,8 @@ _CACHE_HOLDS = {
     families.RECURRENT: "one recurrent state per slot beside the K/V pool",
     families.LATENT: "a latent pool: one latent and one rotary key a "
                      "token, no K or V per head",
+    families.WINDOWED: "a ring of each window layer's last K/V rows per "
+                       "slot beside the full layers' K/V pool",
 }
 
 
@@ -104,7 +106,8 @@ def _refuse_for_recurrent(family, *, spec_decode, kv_host_tier_bytes,
     leave the state behind; the state has no sharding rule.  A latent
     pool: the family has no verify program; the host tier and the
     handoff move rows of ONE shape where the pool's two tensors differ;
-    a latent has no heads axis to shard."""
+    a latent has no heads axis to shard.  A window layer's ring is
+    per-slot state as the recurrent one is, and is refused alike."""
     kind = families.cache_kind(family)
     asked = {"spec_decode": spec_decode is not None,
              "kv_host_tier_bytes": kv_host_tier_bytes is not None,

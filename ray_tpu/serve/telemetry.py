@@ -255,6 +255,24 @@ def _engine_metrics() -> Dict[str, Any]:
                     "serve_kv_walk_blocks_tabled_total",
                     "entries of those rows' block tables, summed "
                     "over the waves landed", tag_keys=tags),
+                # what the resident requests hold reserved, by the
+                # reach of the cache's layers, summed over the paged
+                # decode waves landed
+                "kv_reach_pool_bytes": Counter(
+                    "serve_kv_reach_pool_bytes_total",
+                    "bytes reserved in pool blocks (the layers kept "
+                    "at full reach), summed over the waves landed",
+                    tag_keys=tags),
+                "kv_reach_window_bytes": Counter(
+                    "serve_kv_reach_window_bytes_total",
+                    "bytes reserved in per-slot windows (the layers "
+                    "that keep a bounded window), summed over the "
+                    "waves landed", tag_keys=tags),
+                "kv_reach_full_bytes": Counter(
+                    "serve_kv_reach_full_bytes_total",
+                    "bytes the same requests would reserve were every "
+                    "layer kept at full reach, summed over the waves "
+                    "landed", tag_keys=tags),
                 # a paged prefill's attention, where the family has a
                 # kernel for it and a jnp walk beside it
                 "prefill_attn_kernel": Counter(
@@ -612,6 +630,10 @@ class EngineTelemetry:
         #: paged decode waves landed; the blocks their rows' positions
         #: fill; the entries of those rows' tables
         self._kv_walk = [0, 0, 0]
+        #: paged decode waves landed; bytes their resident requests hold
+        #: reserved in the pool and in per-slot windows; what every
+        #: layer at full reach would reserve for them
+        self._kv_reach = [0, 0, 0, 0]
         #: paged prefills landed that a kernel attended and that the
         #: jnp walk did; the kernel's (query tile, key tile) pairs
         #: walked, and the pairs without the diagonal
@@ -1221,6 +1243,23 @@ class EngineTelemetry:
         self._m["kv_walk_blocks_walked"].inc(walked, tags=self._tags)
         self._m["kv_walk_blocks_tabled"].inc(tabled, tags=self._tags)
 
+    def record_kv_reach(self, pool: int, window: int, full: int) -> None:
+        """One paged decode wave, landed with its tokens: the bytes the
+        resident requests hold reserved then, `pool` in blocks of the
+        layers kept at full reach and `window` in the per-slot rings of
+        the layers that keep a bounded window, and `full`, what the
+        same requests would reserve were every layer kept at full reach
+        (models/decode_common.cache_reach):
+        ``engine_stats()["kv_reach"]``, ``serve_kv_reach_*``."""
+        with self._lock:
+            self._kv_reach[0] += 1
+            self._kv_reach[1] += pool
+            self._kv_reach[2] += window
+            self._kv_reach[3] += full
+        self._m["kv_reach_pool_bytes"].inc(pool, tags=self._tags)
+        self._m["kv_reach_window_bytes"].inc(window, tags=self._tags)
+        self._m["kv_reach_full_bytes"].inc(full, tags=self._tags)
+
     def record_prefill_attn(self, kernel: bool, walked: int,
                             square: int) -> None:
         """One paged prefill of a family with two attention paths
@@ -1513,6 +1552,7 @@ class EngineTelemetry:
             recurrent = self._recurrent
             experts = {k: dict(v) for k, v in self._experts.items()}
             walk_waves, walked, tabled = self._kv_walk
+            reach_waves, in_pool, in_window, at_full = self._kv_reach
             attn_kernel, attn_jnp, pairs, square = self._prefill_attn
             launches = {}
             for kind, acc in sorted(self._launch_sums.items()):
@@ -1611,6 +1651,17 @@ class EngineTelemetry:
                         "blocks_tabled": tabled,
                         "walked_share": round(walked / tabled, 4)
                         if tabled else 0.0},
+            # the cache's bytes reserved by layer reach, means over the
+            # paged decode waves landed: pool blocks of the layers kept
+            # at full reach, per-slot rings of the window layers, and
+            # what every layer at full reach would have reserved
+            "kv_reach": {"waves": reach_waves,
+                         "pool_bytes": in_pool // max(reach_waves, 1),
+                         "window_bytes": in_window // max(reach_waves, 1),
+                         "full_reach_bytes": at_full // max(reach_waves, 1),
+                         "reserved_share": round(
+                             (in_pool + in_window) / at_full, 4)
+                         if at_full else 0.0},
             # paged prefills by what attended them, and what the
             # kernel's causal walk visited of the full rectangle of
             # tile pairs (zeros for a family with one path)

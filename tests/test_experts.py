@@ -280,6 +280,51 @@ def test_the_served_path_is_the_one_that_differentiates(whole, name, runs):
                           "over_many_tiles": 7}[name[len("local_rows_"):]]
 
 
+#: every expert held, many and small, softmax scores (the Laguna cell's
+#: layer: 256 experts of width 512, 8 a token, cut here to 64 of width
+#: 16): a decode wave gives most experts 0 to 3 rows and some none.
+#: name -> (N, rows that hold a token or None)
+MANY_SMALL = {
+    "a_decode_wave": (16, None),
+    "a_decode_wave_with_idle_rows": (16, "every_third_idle"),
+    "one_row": (8, 1),
+    "a_prefill_bucket_with_pads": (256, 200),
+}
+
+
+@pytest.mark.parametrize("name", MANY_SMALL)
+def test_many_small_experts_all_held_with_empty_groups(name, runs):
+    n, real = MANY_SMALL[name]
+    many = 64
+    cfg = _cfg(n_routed=many, top_k=8, scoring="softmax", route_scale=2.5)
+    assert cfg.n_held == many and cfg.held is None
+    p = ex.experts_init(jax.random.PRNGKey(2), cfg, std=0.3)
+    x = _x(n, seed=n + len(name))
+    chosen, w = ex.route(p["router"], x, cfg)
+    np.testing.assert_allclose(np.asarray(w.sum(-1)), 2.5, rtol=1e-5)
+    if real == "every_third_idle":
+        valid = jnp.arange(n) % 3 != 0
+    else:
+        valid = None if real is None else jnp.arange(n) >= n - real
+    base = _x(n, seed=98)
+    want, want_stats = ex.routed_experts(p["experts"], x, chosen, w, cfg,
+                                         valid, tiled=False, base=base)
+    got, stats = ex.routed_experts(p["experts"], x, chosen, w, cfg, valid,
+                                   tiled=True, base=base)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(stats), np.asarray(want_stats))
+    rows = n if valid is None else int(np.asarray(valid).sum())
+    assert float(stats[0]) == rows * 8            # every choice is local
+    if n <= 16:
+        assert float(stats[1]) < many             # groups of 0 rows
+        # one pass takes every assignment the wave can make
+        assert ex.tile_rows(n, cfg) == n * 8
+    if valid is None:
+        dense = _dense_reference(p, x, cfg) + base
+        np.testing.assert_allclose(np.asarray(got), np.asarray(dense),
+                                   atol=3e-5)
+
+
 @pytest.mark.parametrize("bad", [{"held": (0, 0)}, {"held": (99,)},
                                  {"top_k": 0}, {"scoring": "tanh"}])
 def test_a_wrong_configuration_is_refused(bad):
