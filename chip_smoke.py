@@ -38,6 +38,9 @@ Phases, one chip:
            its jnp reference: the prefill's flash kernel, the decode
            step's walk over the paged latent pool, the rotary pool's
            re-lay, and the expert layer's dispatch and combine.
+  gqa      the Laguna-XS.2 cell's decode kernel at its shapes against
+           its jnp reference: the full layers' walk over the paged
+           grouped-query K/V pools.
 Phases, --chips 4 (and no one-chip phase):
   mesh_train    the train step over data=4 and data=2 x fsdp=2 against
                 the same step on device 0.
@@ -60,7 +63,7 @@ import sys
 import time
 from typing import Any, Dict, List
 
-ONE_CHIP = ("train", "serve", "runtime", "mla")
+ONE_CHIP = ("train", "serve", "runtime", "mla", "gqa")
 FOUR_CHIPS = ("mesh_train", "tensor_serve", "fleet")
 #: the driver allows 1200 s; leave room for the parent's own exit
 DEADLINE_S = 1100.0
@@ -128,6 +131,12 @@ class Size:
     #: wave; with the first `moe_held` experts held
     moe_rows: tuple = ((8192, 2304), (64, 32))
     moe_held: int = 12
+    # gqa: Laguna-XS.2's published widths; (rows, pool blocks) of a
+    #: decode wave over the full layers' paged K/V pools (the cell's:
+    #: 4 GiB of K and V) under tables of `gqa_max_seq` slots
+    gqa_preset: str = "laguna-xs2"
+    gqa_max_seq: int = 8704
+    gqa_wave: tuple = (64, 32768)
     # serve: bench.py's on-chip TrafficSpec cut to a few dozen requests
     requests: int = 32
     #: warm-up requests of the serve phase (another seed's traffic), and
@@ -1084,8 +1093,55 @@ def phase_mla(size: Size, platform: str = "tpu") -> Dict[str, Any]:
     return {"device": device}
 
 
+def check_gqa_kernels(size: Size, *, interpret: bool = False) -> None:
+    """ops/gqa_paged_decode.py: one decode column of every row over the
+    K/V pools where they lie against the gathered views, in each full
+    layer of the pools."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.models.laguna import FULL, laguna_config
+    from ray_tpu.ops.gqa_paged_decode import (gqa_paged_decode,
+                                              gqa_paged_decode_reference)
+
+    B, blocks = size.gqa_wave
+    cfg = laguna_config(size.gqa_preset, max_seq=size.gqa_max_seq)
+    n_full, bs = len(cfg.layers_of(FULL)), size.kv_block
+    nb = cfg.max_seq // bs
+    ks = jax.random.split(jax.random.PRNGKey(size.seed + 5), 5)
+    rng = np.random.default_rng(size.seed)
+    pools = [jax.random.normal(k, (n_full, blocks, bs, cfg.kv_width),
+                               cfg.dtype) for k in ks[:2]]
+    # rows of every length from idle to the whole table, over blocks
+    # out of order (block 0 is the null block and is no row's)
+    tables = jnp.asarray(rng.integers(1, blocks, (B, nb)), jnp.int32)
+    pos = jnp.asarray(np.linspace(0, nb * bs, B).astype(np.int32))
+    q = jax.random.normal(ks[2], (B, cfg.n_head, cfg.head_dim), cfg.dtype)
+    fresh = tuple(jax.random.normal(k, (B, cfg.kv_width), cfg.dtype)
+                  for k in ks[3:])
+    kw = dict(n_kv_head=cfg.n_kv_head, scale=cfg.head_dim ** -0.5)
+    for f in range(n_full):
+        t0 = time.perf_counter()
+        args = (q, *pools, tables, pos, jnp.int32(f), fresh)
+        got = gqa_paged_decode(*args, interpret=interpret, **kw)
+        want = jax.jit(functools.partial(gqa_paged_decode_reference,
+                                         **kw))(*args)
+        err = _rel_err(got, want)
+        say("gqa", kernel="gqa_paged_decode", layer=f,
+            shape=[B, nb, cfg.n_head, cfg.n_kv_head, cfg.head_dim],
+            err=round(err, 5), seconds=round(time.perf_counter() - t0, 2))
+        assert err <= KERNEL_TOL, ("gqa_paged_decode", f, err)
+
+
+def phase_gqa(size: Size, platform: str = "tpu") -> Dict[str, Any]:
+    device = device_block(platform)
+    check_gqa_kernels(size)
+    return {"device": device}
+
+
 PHASES = {"train": phase_train, "serve": phase_serve,
-          "runtime": phase_runtime, "mla": phase_mla,
+          "runtime": phase_runtime, "mla": phase_mla, "gqa": phase_gqa,
           "mesh_train": phase_mesh_train,
           "tensor_serve": phase_tensor_serve, "fleet": phase_fleet}
 

@@ -97,11 +97,14 @@ MLA_FLASH_PREFILL = "mla_flash_prefill"
 #: the held experts' rows out of the tokens' and back onto them
 #: (ops/moe_dispatch.py)
 MOE_DISPATCH, MOE_COMBINE = "moe_dispatch", "moe_combine"
+#: a decode column's grouped-query attention over the paged K/V pool
+#: where it lies (ops/gqa_paged_decode.py)
+GQA_PAGED_DECODE = "gqa_paged_decode"
 KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV,
            FLASH_RES_FWD, FLASH_RES_DQ, FLASH_RES_DKV,
            FLASH_TRI_FWD, FLASH_TRI_BWD, SSM_SCAN, MLA_PAGED_DECODE,
            MLA_ROTARY_LANES, MLA_FLASH_PREFILL, MOE_DISPATCH,
-           MOE_COMBINE)
+           MOE_COMBINE, GQA_PAGED_DECODE)
 
 # -- host phases -------------------------------------------------------------
 SPAN_PREFIX = "raytpu."

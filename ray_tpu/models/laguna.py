@@ -104,8 +104,7 @@ class LagunaConfig:
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     vocab_pad_to: int = 128
-    #: queries and keys a tile of the prefill's banded attention, and
-    #: positions a chunk of the decode step's walk over a row's blocks
+    #: queries and keys a tile of the prefill's banded attention
     attn_block: int = 512
     #: most rows of grouped assignments one pass of the experts takes:
     #: a 4k prefill's 32,768 in one pass, an 8k prefill's in two (every

@@ -33,9 +33,11 @@ rows it has not written derive to negative slots.
   * a decode step writes every ACTIVE row's new K/V at ``pos mod
     window`` of each ring and attends the ring; a row with ``pos == 0``
     (empty, retired, or parked between two chunks of its prompt) is
-    left exactly as it is.  A full layer reads the row's blocks a chunk
-    at a time, as far as the wave's longest context reaches, with a
-    running softmax: no view of ``max_seq`` columns is gathered.
+    left exactly as it is.  A full layer of a paged cache on the chip
+    is one kernel (ops/gqa_paged_decode.py): each row's own blocks are
+    read where they lie, as far as its own context reaches, under a
+    running softmax; off the chip the ``jnp`` reference gathers the
+    rows' views.
   * a prefill attends BANDED (`attend_banded`): a tile of queries walks
     the key tiles between its first query's window edge (or 0) and its
     own diagonal.  A window layer's keys are the slot's ring (from
@@ -60,6 +62,8 @@ from ray_tpu.models.decode_common import (EXPERT_COUNTERS, EXPERTS,
                                           NO_SNAPSHOT, STATE_FROM_ZERO,
                                           PagedKV, generate_with,
                                           is_paged, slot_mask)
+from ray_tpu.ops.gqa_paged_decode import (gqa_paged_decode,
+                                          gqa_paged_decode_reference)
 from ray_tpu.models.laguna import (ATTN_SCOPE, FULL, WINDOW, LagunaConfig,
                                    attend_masked, block, causal_mask,
                                    embed, expert_counters, lm_logits,
@@ -248,54 +252,18 @@ def _attend_ring(q, ring_k, ring_v, mask, cfg: LagunaConfig):
 # -- a full layer's decode column over the pool -------------------------------
 
 @jax.named_scope(scopes.ATTN_FULL)
-def _attend_walked(q, pools, f: int, cache, fresh, cfg: LagunaConfig):
-    """One decode column of every row over the paged pool where it
-    lies: q (B, H, hd); pools = the whole (K, V) pools; `f` the full
-    layer's place in them; `fresh` = this column's (k, v) (B,
-    kv_width), attended beside the slots ``start <= s < pos``.  Each
-    row's blocks are gathered ``attn_block`` positions at a time, as
-    far as the wave's longest context reaches, under a running softmax:
-    what is read follows the contexts, not ``max_seq``."""
-    B, H, hd = q.shape
-    dt = cfg.dtype
-    kpool, vpool = pools
-    bs = kpool.shape[2]
-    tables, pos, start = cache["block_tables"], cache["pos"], cache["start"]
-    per = max(cfg.attn_block // bs, 1)              # blocks a chunk
-    chunk = per * bs
-    short = -tables.shape[1] % per
-    if short:
-        tables = jnp.pad(tables, ((0, 0), (0, short)))
-
-    @jax.named_scope(scopes.ATTN_FULL)
-    def over(j, carry):
-        m, l, acc = carry
-        ids = lax.dynamic_slice_in_dim(tables, j * per, per, axis=1)
-        with jax.named_scope(scopes.KV_POOL):
-            kc = kpool[f, ids].reshape(B, chunk, cfg.kv_width)
-            vc = vpool[f, ids].reshape(B, chunk, cfg.kv_width)
-        at = j * chunk + jnp.arange(chunk)
-        ok = ((at[None, :] >= start[:, None])
-              & (at[None, :] < pos[:, None]))[:, None]      # (B, 1, chunk)
-        s = jnp.where(ok, _rows_scores(q, kc, cfg), -1e30)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        e = jnp.where(ok, jnp.exp(s - m_new[..., None]), 0.0)
-        shrink = jnp.exp(m - m_new)
-        return (m_new, l * shrink + jnp.sum(e, axis=-1),
-                acc * shrink[..., None] + _rows_values(e, vc, cfg))
-
-    m, l, acc = lax.fori_loop(
-        0, (jnp.max(pos) + chunk - 1) // chunk, over,
-        (jnp.full((B, H), -1e30, jnp.float32),
-         jnp.zeros((B, H), jnp.float32),
-         jnp.zeros((B, H, hd), jnp.float32)))
-    # the row's own new key and value, one more column
-    s = _rows_scores(q, fresh[0][:, None], cfg)[..., 0]         # (B, H)
-    m_new = jnp.maximum(m, s)
-    e, shrink = jnp.exp(s - m_new), jnp.exp(m - m_new)
-    acc = acc * shrink[..., None] + _rows_values(
-        e[..., None], fresh[1][:, None], cfg)
-    return (acc / (l * shrink + e)[..., None]).astype(dt)
+def _attend_paged(q, pools, f: int, cache, fresh, cfg: LagunaConfig,
+                  in_place: bool):
+    """One decode column of every row over the paged pool: q (B, H,
+    hd); pools = the whole (K, V) pools; `f` the full layer's place in
+    them; `fresh` = this column's (k, v) (B, kv_width), attended beside
+    the slots ``start <= s < pos``.  `in_place`: the kernel walks each
+    row's own blocks where they lie (ops/gqa_paged_decode.py); else the
+    ``jnp`` reference over the gathered views, the parity oracle."""
+    walk = gqa_paged_decode if in_place else gqa_paged_decode_reference
+    return walk(q, *pools, cache["block_tables"], cache["pos"], f, fresh,
+                n_kv_head=cfg.n_kv_head,
+                scale=1.0 / math.sqrt(cfg.head_dim), start=cache["start"])
 
 
 def _with_counters(cache, cfg: LagunaConfig, stats):
@@ -486,6 +454,11 @@ def laguna_decode_step(params, cache, tokens, cfg: LagunaConfig
     B = tokens.shape[0]
     W = cfg.window
     paged = is_paged(cache)
+    # what the program can see of its input picks the path (a paged
+    # cache, one column a row, the chip): the kernel walks the pool's
+    # blocks where they lie; the CPU gathers the views and keeps the
+    # jnp path, the parity oracle (kimi_k2_decode.kimi_k2_decode_step)
+    in_place = paged and jax.default_backend() == "tpu"
     pos, start = cache["pos"], cache["start"]
     active = pos > 0
     rows = jnp.arange(B)
@@ -514,8 +487,8 @@ def laguna_decode_step(params, cache, tokens, cfg: LagunaConfig
                 return _attend_ring(q, *ring, ring_mask, cfg)
             if paged:
                 fresh.append((k, v))
-                return _attend_walked(q, (held["k"], held["v"]), j, cache,
-                                      (k, v), cfg)
+                return _attend_paged(q, (held["k"], held["v"]), j, cache,
+                                     (k, v), cfg, in_place)
             with jax.named_scope(scopes.KV_POOL):
                 for n, new in (("k", k), ("v", v)):
                     held[n] = held[n].at[j, rows, pos].set(new)

@@ -91,6 +91,8 @@ def test_each_kinds_scores_are_under_its_own_scope(program, params):
      "kv_pool"),
     ("jit(pool_step)/attn_full/while/body/attn_full/dot_general",
      "attn_full"),
+    ("jit(pool_step)/attn_full/jit(gqa_paged_decode)/gqa_paged_decode",
+     "attn_full"),
     ("jit(prefill)/attn_window/while/body/closed_call/attn_window/"
      "while/body/attn_window/exp", "attn_window"),
     ("jit(prefill)/moe_experts/while/body/moe_experts/ragged_dot_general",
@@ -98,6 +100,34 @@ def test_each_kinds_scores_are_under_its_own_scope(program, params):
 ])
 def test_innermost_scope_of_the_new_names(op_name, scope):
     assert scopes.innermost_scope(op_name) == scope
+
+
+def test_the_chips_decode_step_walks_the_pool_under_attn_full(
+        params, monkeypatch):
+    """On the chip (the backend steered) a paged decode step's full
+    layers are the kernel ``gqa_paged_decode``, one call a layer, each
+    under ``attn_full`` and not under ``kv_pool``: the time of the walk
+    reads as the layer's attention (`full_attn_time_share.offline`),
+    and `attn_decode_roofline.offline` divides the three scopes' sum."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    paged = laguna_init_paged_cache(CFG, 2, num_blocks=20, block_size=16)
+    jaxpr = jax.make_jaxpr(
+        lambda c, t: laguna_decode_step(params, c, t, CFG))(
+            paged, jnp.zeros((2,), jnp.int32)).jaxpr
+
+    def kernels(jaxpr, stack=""):
+        for eqn in jaxpr.eqns:
+            path = f"{stack}/{eqn.source_info.name_stack}"
+            if eqn.primitive.name == "pallas_call":
+                yield eqn.params["name"], scopes.innermost_scope(path)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from kernels(sub, path)
+
+    walks = [scope for name, scope in kernels(jaxpr)
+             if name == scopes.GQA_PAGED_DECODE]
+    assert walks == [scopes.ATTN_FULL] * len(CFG.layers_of("full")), walks
+    assert scopes.GQA_PAGED_DECODE in scopes.KERNELS
+    assert scopes.GQA_PAGED_DECODE not in scopes.DEVICE_SCOPES
 
 
 def test_the_new_scopes_are_registered_and_hold_no_other():

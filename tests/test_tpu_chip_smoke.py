@@ -35,7 +35,8 @@ TOY = chip_smoke.Size(
     tail_mean=6.0, tail_max=16, vocab=500, rate_rps=200.0, max_slots=4,
     new_tokens=8, prefill_bucket=16, mla_preset="nano", mla_max_seq=128,
     mla_tile=16, mla_prefills=((64, 0, 51), (64, 60, 14)),
-    mla_wave=(5, 24, 3), moe_rows=((64, 32), (8, 8)), moe_held=6)
+    mla_wave=(5, 24, 3), moe_rows=((64, 32), (8, 8)), moe_held=6,
+    gqa_preset="nano", gqa_max_seq=128, gqa_wave=(5, 24))
 
 
 @pytest.fixture
@@ -49,6 +50,8 @@ def interpreted(monkeypatch):
         chip_smoke.check_kernels, interpret=True))
     monkeypatch.setattr(chip_smoke, "check_mla_kernels", functools.partial(
         chip_smoke.check_mla_kernels, interpret=True))
+    monkeypatch.setattr(chip_smoke, "check_gqa_kernels", functools.partial(
+        chip_smoke.check_gqa_kernels, interpret=True))
 
 
 @pytest.fixture
@@ -70,6 +73,11 @@ def test_serve_phase(interpreted):
 
 def test_mla_phase(interpreted):
     out = chip_smoke.phase_mla(TOY, "cpu")
+    assert out["device"]["platform"] == "cpu"
+
+
+def test_gqa_phase(interpreted):
+    out = chip_smoke.phase_gqa(TOY, "cpu")
     assert out["device"]["platform"] == "cpu"
 
 

@@ -61,6 +61,45 @@ def _no_compile_cache():
 MOSAIC_CALL = 'custom_call_target="tpu_custom_call"'
 
 
+def _mosaic_modules(hlo_text: str, kernel: str):
+    """sha256 of the Mosaic module of each call of `kernel` in a
+    compiled program's text, printed WITHOUT its locations: the payload
+    itself carries its source's path and lines, so it differs between
+    two checkouts of the same kernel; the operations do not."""
+    import base64
+    import hashlib
+    import json
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    found = []
+    for line in hlo_text.splitlines():
+        if MOSAIC_CALL not in line \
+                or not line.split(" = ")[0].split("%")[-1].startswith(kernel):
+            continue
+        config, _ = json.JSONDecoder().raw_decode(
+            line[line.index("backend_config=") + len("backend_config="):])
+        ctx = mlir.make_ir_context()
+        # the payload names its dialect ``stable_mosaic``, which only
+        # the chip's compiler registers: read it as it is written
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            module = ir.Module.parse(base64.b64decode(
+                config["custom_call_config"]["body"]))
+            text = module.operation.get_asm(enable_debug_info=False)
+        found.append(hashlib.sha256(text.encode()).hexdigest())
+    return found
+
+
+#: `mla_paged_decode` in the Kimi-K2 cell's decode step, as PR 33 wrote
+#: it and every PR since left it (the cell's second-longest device
+#: operation under a 1% bound): whoever changes the kernel on purpose
+#: states the new module here, with the cell's numbers beside it
+MLA_PAGED_DECODE_MODULE = (
+    "cd1c6ff8e23a570e4c641f494b781e916c786236b02b6b174366774eb0e91eae")
+
+
 def _on(sharding):
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
@@ -307,6 +346,108 @@ def test_mla_paged_decode_kernel_compiles_at_the_cells_shapes():
     assert compiled.memory_analysis().temp_size_in_bytes < 0.49e9
 
 
+def _serving_cell(name: str, init, t_pad: int):
+    """The serving cell `name`'s programs as its engine builds them
+    (the family's ``aot_serve_programs``: published widths, the cell's
+    slots, pool and `max_seq`, bf16 weights) over abstract parameters
+    (`init`: the model's own) and cache, placed on the described chip:
+    (cfg, params, cache, {program: (fn, args)}, blocks of the pool)."""
+    from benchmark.cells import load_cell
+
+    cell = load_cell(name)
+    family, spec = cell.family, cell.traffic["engine"]
+    cfg = family.program(cell.config, {
+        "max_seq": cell.traffic["config_overrides"]["max_seq"],
+        "param_dtype": jnp.bfloat16}).cfg
+    place = _one_chip()
+    cache_shapes, programs = family.aot_serve_programs(
+        cfg, spec["max_slots"], spec["kv_block_size"], t_pad, place)
+    n_blocks = spec["kv_pool_bytes"] // (
+        family.kv_bytes_per_token(cell.config) * spec["kv_block_size"])
+    abstract = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: place(a.shape, a.dtype), tree)
+    params = abstract(jax.eval_shape(
+        lambda: init(jax.random.PRNGKey(0), cfg)))
+    return (cfg, params, abstract(cache_shapes(n_blocks)),
+            {name: (fn, args) for name, fn, args in programs}, n_blocks)
+
+
+def test_gqa_paged_decode_kernel_compiles_at_the_cells_shapes():
+    """ray_tpu.ops.gqa_paged_decode at the Laguna cell's shapes: 64
+    rows, tables of 544 blocks of 16 (`max_seq` 8,704), 48 query heads
+    over 8 K/V heads of 128, K and V pools of 2 layers and 32,768
+    blocks of 1,024 lanes.  One Mosaic call; both pools go in as they
+    are stored (no copy, no slice of a layer: no temporary at all)."""
+    from ray_tpu._private import scopes
+    from ray_tpu.ops.gqa_paged_decode import gqa_paged_decode
+
+    spec = _one_chip()
+    bf16 = lambda *shape: spec(shape, jnp.bfloat16)   # noqa: E731
+    i32 = lambda *shape: spec(shape, jnp.int32)   # noqa: E731
+    B, H, hd, n_kv, blocks = 64, 48, 128, 8, 32768
+
+    def attend(q, kpool, vpool, tables, pos, f, kn, vn):
+        return gqa_paged_decode(q, kpool, vpool, tables, pos, f, (kn, vn),
+                                n_kv_head=n_kv, scale=hd ** -0.5)
+
+    pool = bf16(2, blocks, 16, n_kv * hd)
+    compiled = jax.jit(attend).lower(
+        bf16(B, H, hd), pool, pool, i32(B, 544), i32(B), i32(),
+        bf16(B, n_kv * hd), bf16(B, n_kv * hd)).compile()
+    text = compiled.as_text()
+    calls = [line.split(" = ")[0].split("%")[-1]
+             for line in text.splitlines() if MOSAIC_CALL in line]
+    assert [name.split(".")[0] for name in calls] == [
+        scopes.GQA_PAGED_DECODE], calls
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e6
+
+
+def test_laguna_decode_step_walks_the_pool_once_a_full_layer(monkeypatch):
+    """The cell laguna-xs2.serve-offline-mixed's decode step as the
+    engine builds it (benchmark/families/laguna.py aot_serve_programs:
+    published widths, 5 layers, 64 slots over the 4 GiB pool, bf16
+    weights), the chip's (the program asks ``jax.default_backend()``,
+    steered here): exactly one Mosaic call named ``gqa_paged_decode`` a
+    full layer, each under ``attn_full``; nothing gathered under
+    ``kv_pool`` (the chunked gather is gone: what stays there are the
+    rings' row writes and the commit's scatter); the pools neither
+    copied nor sliced by layer; donated, they are updated in place."""
+    from ray_tpu._private import scopes
+    from ray_tpu.models.laguna import laguna_init
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, params, cache, programs, n_blocks = _serving_cell(
+        "laguna-xs2.serve-offline-mixed", laguna_init, 1024)
+    n_full = len(cfg.layers_of("full"))
+    assert cache["k"].shape == (n_full, 32768, 16, 1024)
+    fn, args = programs["decode"]
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args).compile()
+    text = compiled.as_text()
+    scoped = scopes.scope_map_from_hlo(text)
+    calls = [line.split(" = ")[0].split("%")[-1]
+             for line in text.splitlines() if MOSAIC_CALL in line]
+    walks = [name for name in calls
+             if name.startswith(scopes.GQA_PAGED_DECODE)]
+    assert len(walks) == n_full == 2, calls
+    assert all(set(scoped[name].values()) == {scopes.ATTN_FULL}
+               for name in walks), {n: scoped.get(n) for n in walks}
+    # (the commit still looks each row's block up in its table: int32)
+    gathered = [line for line in text.splitlines()
+                if re.search(r"= bf16\[[^ ]* gather\(", line)
+                and "/kv_pool/" in line]
+    assert not gathered, gathered[:3]
+    pool = f"bf16[{n_full},{n_blocks},16,1024]"
+    layer = f"bf16[{n_blocks},16,1024]"
+    for line in text.splitlines():
+        body = line.split(" = ", 1)[-1]
+        if body.startswith((pool, layer)):
+            assert " copy(" not in body and " transpose(" not in body, line
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 4.29e9      # the pools, in place
+    assert memory.peak_memory_in_bytes < 15e9, memory
+
+
 @pytest.mark.parametrize("T", range(2048, 8193, 1024))
 def test_mla_flash_prefill_kernel_compiles_at_the_cells_shapes(T):
     """ray_tpu.ops.mla_flash_prefill at the Kimi-K2 cell's prefill
@@ -386,32 +527,15 @@ def test_kimi_k2_programs_fit_the_chip_at_the_published_widths(
     no view of the rows' tables is gathered.  The prefill's is the
     kernel ``mla_flash_prefill`` under ``mla``, its keys and values as
     the up-projections write them."""
-    from benchmark.cells import load_cell
     from ray_tpu._private import scopes
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-
-    cell = load_cell("kimi-k2-code.serve-offline-codegen")
-    family, spec = cell.family, cell.traffic["engine"]
-    cfg = family.program(cell.config, {
-        "max_seq": cell.traffic["config_overrides"]["max_seq"],
-        "param_dtype": jnp.bfloat16}).cfg
-    place = _one_chip()
-    cache_shapes, programs = family.aot_serve_programs(
-        cfg, spec["max_slots"], spec["kv_block_size"], t_pad or 1024,
-        place)
-    n_blocks = spec["kv_pool_bytes"] // (
-        family.kv_bytes_per_token(cell.config) * spec["kv_block_size"])
-    abstract = lambda tree: jax.tree.map(  # noqa: E731
-        lambda a: place(a.shape, a.dtype), tree)
     from ray_tpu.models.kimi_k2 import kimi_k2_init
 
-    params = abstract(jax.eval_shape(
-        lambda: kimi_k2_init(jax.random.PRNGKey(0), cfg)))
-    cache = abstract(cache_shapes(n_blocks))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, params, cache, programs, n_blocks = _serving_cell(
+        "kimi-k2-code.serve-offline-codegen", kimi_k2_init, t_pad or 1024)
     assert sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params)
                ) < 8.4e9
-    fn, args = next((f, a) for name, f, a in programs if name == program)
+    fn, args = programs[program]
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(
         params, cache, *args).compile()
     memory = compiled.memory_analysis()
@@ -468,6 +592,10 @@ def test_kimi_k2_programs_fit_the_chip_at_the_published_widths(
              if name.startswith(scopes.MLA_PAGED_DECODE)
              and "custom-call" in key}
     assert len(walks) == 2 and set(walks.values()) == {scopes.MLA}, walks
+    # ... and the walk is the module it was before the grouped-query
+    # pool got a walk of its own (ops/gqa_paged_decode.py, PR 43)
+    assert _mosaic_modules(text, scopes.MLA_PAGED_DECODE) == [
+        MLA_PAGED_DECODE_MODULE] * 2
     lanes = [scope for name, keyed in
              scopes.scope_map_from_hlo(text).items()
              for scope in keyed.values()
