@@ -100,11 +100,15 @@ MOE_DISPATCH, MOE_COMBINE = "moe_dispatch", "moe_combine"
 #: a decode column's grouped-query attention over the paged K/V pool
 #: where it lies (ops/gqa_paged_decode.py)
 GQA_PAGED_DECODE = "gqa_paged_decode"
+#: the held experts' gate, up, SwiGLU and down on rows that are few a
+#: group, every touched expert's weights streamed once
+#: (ops/grouped_swiglu.py)
+GROUPED_SWIGLU = "grouped_swiglu"
 KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV,
            FLASH_RES_FWD, FLASH_RES_DQ, FLASH_RES_DKV,
            FLASH_TRI_FWD, FLASH_TRI_BWD, SSM_SCAN, MLA_PAGED_DECODE,
            MLA_ROTARY_LANES, MLA_FLASH_PREFILL, MOE_DISPATCH,
-           MOE_COMBINE, GQA_PAGED_DECODE)
+           MOE_COMBINE, GQA_PAGED_DECODE, GROUPED_SWIGLU)
 
 # -- host phases -------------------------------------------------------------
 SPAN_PREFIX = "raytpu."

@@ -583,9 +583,12 @@ EXPERTS = "experts"
 #: assignments that fell on held experts, summed over the layers; held
 #: experts with at least one token over the held, mean over layers;
 #: the fullest held expert's tokens over the held experts' mean, worst
-#: layer
+#: layer; row tiles the experts' rows fill over the experts touched,
+#: summed over the layers (the visits `ops/grouped_swiglu.py` makes an
+#: expert it fetches: 1.0 unless an expert's rows overflow a row tile)
 EXPERT_COUNTERS = ("held", "of", "assignments_local",
-                   "experts_touched_share", "load_max_over_mean")
+                   "experts_touched_share", "load_max_over_mean",
+                   "row_tiles_per_touched")
 
 
 def expert_counters(cache):

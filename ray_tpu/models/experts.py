@@ -19,20 +19,24 @@ layer, and the routed parts of a partition of the experts add up to it
 (tests/test_experts.py holds both).
 
 Dropless, with static shapes.  The held experts' rows are put in
-GROUPED order (expert 0's first, each expert's by ascending token) and
-the groups go through `jax.lax.ragged_dot` (on a TPU XLA lowers it to a
-grouped-matmul kernel that visits only the tiles a group has rows in,
-so an expert no token chose is never read).  The serving path finds a
-choice's place among the held by comparison and moves the rows with two
-Pallas kernels that walk the tokens (ops/moe_dispatch.py): no sort over
-the ``N * top_k`` assignments, no gather, no scatter.  A pass takes a
-static number of grouped rows (`tile_rows`: what a prefill's local rows
-fit in), as many passes as the local rows fill: the bound is the
-assignments themselves, never a capacity, so imbalance costs time and
-drops nothing.  The path that differentiates sorts all ``N * top_k``
-assignments and takes them in one grouped matmul.  `moe_layer` hands
-back, beside the result, what the routing did on this chip (`STATS`),
-computed where the counts already are.
+GROUPED order (expert 0's first, each expert's by ascending token).
+The serving path finds a choice's place among the held by comparison
+and moves the rows with two Pallas kernels that walk the tokens
+(ops/moe_dispatch.py): no sort over the ``N * top_k`` assignments, no
+gather, no scatter.  A pass takes a static number of grouped rows
+(`tile_rows`: what a prefill's local rows fit in), as many passes as
+the local rows fill: the bound is the assignments themselves, never a
+capacity, so imbalance costs time and drops nothing.  What multiplies
+the groups follows the rows a group expects (`few_a_group`): few (a
+decode wave), and one Pallas kernel streams each touched expert's
+weights once and does gate, up, SwiGLU and down on its rows
+(ops/grouped_swiglu.py); many (a long prefill), and the groups go
+through `jax.lax.ragged_dot` (on a TPU XLA lowers it to a grouped-matmul
+kernel that visits only the tiles a group has rows in, so an expert no
+token chose is never read).  The path that differentiates sorts all
+``N * top_k`` assignments and takes them in one grouped matmul.
+`moe_layer` hands back, beside the result, what the routing did on this
+chip (`STATS`), computed where the counts already are.
 
 `models/moe.py` is another layer (GShard: softmax, a capacity, drops,
 GELU, biases) wired into GPT-2's training path; it is left as it is.
@@ -50,14 +54,19 @@ import numpy as np
 from jax import lax
 
 from ray_tpu._private import scopes
+from ray_tpu.ops.grouped_swiglu import (ROW_TILE, grouped_swiglu,
+                                        row_tiles)
 from ray_tpu.ops.moe_dispatch import (combine_reference,
                                       dispatch_reference, moe_combine,
                                       moe_dispatch, rows_of, slabs)
 
 #: what `moe_layer` reports of one layer's routing on this chip:
 #: assignments that fell on held experts, held experts with at least
-#: one token, and the fullest held expert's tokens over their mean
-STATS = ("assignments_local", "experts_touched", "load_max_over_mean")
+#: one token, the fullest held expert's tokens over their mean, and the
+#: row tiles the experts' rows fill (`grouped_swiglu`'s visits: as many
+#: as experts touched unless one's rows overflow a tile)
+STATS = ("assignments_local", "experts_touched", "load_max_over_mean",
+         "row_tiles_visited")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -222,37 +231,66 @@ def _grouped(xs, p, sizes, dtype, layer=None):
     return dot(h.astype(dtype), p["w_down"])
 
 
-def tile_rows(n_tokens: int, cfg: ExpertsConfig) -> int:
-    """Rows of grouped order one pass of `routed_experts` takes: this
-    chip's even share of the tokens' assignments and four of its
-    standard deviations more (a choice is local one time in
-    n_routed / n_held: the share's variance is the share), so that a
-    prefill's local rows fit one pass and the held weights are read
-    once a layer; never more than can be local (before the rounding
-    below), at most ``cfg.tile_rows``.
+def _fused(xs, p, sizes, dtype, layer=None, interpret=False):
+    """`_grouped` on rows that are few a group (`few_a_group`), as one
+    kernel that fetches every touched expert's weights once
+    (ops/grouped_swiglu.py).  xs (R, s, l) float32 slabs as
+    `moe_dispatch` writes them, every group begun on a whole row tile
+    of `ROW_TILE`; slabs back, as `moe_combine` reads them.  Rows
+    nobody owns come back as whatever they make, and `moe_combine`
+    reads owned rows only."""
+    return grouped_swiglu(xs, p["w_gate"], p["w_up"], p["w_down"], sizes,
+                          layer, dtype=dtype, interpret=interpret)
 
-    The count is then rounded to what the grouped matmul does well
-    with: the compiler's kernel takes its row tile, its M, from the
-    rows it is handed, and multiplies a whole tile a group whatever
-    rows the group has (PERF.md, PR 40: a call over 12 groups of ~107
-    rows takes 1.36 ms handed 1,536 rows, a multiple of 512, 1.00 ms
-    handed 1,792, and 1.06 handed 1,408 or 1,664).  Up to a tile of
-    128 the rows are whole sublane tiles of 16 (a decode wave's 16
-    expected rows take 32: 0.41 ms a call where 128 take 0.47); up to
-    512 whole tiles of 128, never 512 itself; beyond, an odd multiple
-    of 256, or, where a group expects fewer than 16 rows (a decode
-    wave over many small experts all held: 512 rows over 256 groups),
-    an odd multiple of 128: a tile's height is then all waste, and the
-    lowest is taken (PERF.md, PR 42)."""
+
+def fused_reference(xs, p, sizes, dtype, layer=None):
+    """`_fused`'s contract through `_grouped`: every group's rows
+    rounded up to whole row tiles, so that a group begins where the
+    last one's tile ends.  What runs off the chip."""
+    return slabs(_grouped(rows_of(xs).astype(dtype), p,
+                          row_tiles(sizes) * ROW_TILE, dtype, layer))
+
+
+def _local_rows(n_tokens: int, cfg: ExpertsConfig) -> int:
+    """This chip's even share of the tokens' assignments and four of
+    its standard deviations more (a choice is local one time in
+    n_routed / n_held: the share's variance is the share), never more
+    than can be local."""
     share = n_tokens * cfg.top_k * cfg.n_held // cfg.n_routed
-    rows = min(share + 4 * math.isqrt(share),
+    return min(share + 4 * math.isqrt(share),
                n_tokens * min(cfg.top_k, cfg.n_held))
-    if rows <= 128:
-        rows = max(16, -(-rows // 16) * 16)
-    elif rows < 512:
+
+
+def few_a_group(n_tokens: int, cfg: ExpertsConfig) -> bool:
+    """A held expert expects fewer than 16 of a pass's rows (a decode
+    wave, a short prefill over many small experts): the layer is bound
+    by the touched experts' bytes and takes `_fused`; else by the
+    arithmetic, and takes `_grouped`'s tall row tiles."""
+    return _local_rows(n_tokens, cfg) < 16 * cfg.n_held
+
+
+def tile_rows(n_tokens: int, cfg: ExpertsConfig) -> int:
+    """Rows of grouped order one pass of `routed_experts` takes:
+    `_local_rows`, so that a prefill's local rows fit one pass and the
+    held weights are read once a layer, at most ``cfg.tile_rows``.
+
+    Where the rows are `few_a_group`, every touched group begins on a
+    row tile of its own: the pass holds `ROW_TILE` - 1 rows more for
+    each group that can have a row.  Else the count is rounded to what
+    the compiler's grouped matmul does well with: it takes its row
+    tile, its M, from the rows it is handed, and multiplies a whole
+    tile a group whatever rows the group has (PERF.md, PR 40: a call
+    over 12 groups of ~107 rows takes 1.36 ms handed 1,536 rows, a
+    multiple of 512, 1.00 ms handed 1,792, and 1.06 handed 1,408 or
+    1,664): up to 512 whole tiles of 128, never 512 itself; beyond, an
+    odd multiple of 256."""
+    rows = _local_rows(n_tokens, cfg)
+    if few_a_group(n_tokens, cfg):
+        rows += (ROW_TILE - 1) * min(cfg.n_held, rows)
+        tiles = min(-(-rows // ROW_TILE), cfg.tile_rows // ROW_TILE)
+        return max(tiles, 1) * ROW_TILE
+    if rows < 512:
         rows = -(-rows // 128) * 128
-    elif rows < 16 * cfg.n_held:
-        rows = (rows + 127) // 256 * 256 + 128
     else:
         rows = (rows + 255) // 512 * 512 + 256
     return min(rows, cfg.tile_rows)
@@ -277,20 +315,27 @@ def _walked(p, x, local, w, counts, cfg: ExpertsConfig, layer, base):
     """The routed sum onto `base` with no sort, gather or scatter
     (ops/moe_dispatch.py): the local assignments' rows are copied into
     grouped order by a walk over the tokens, `tile_rows` of them go
-    through the grouped matmuls, and the same walk adds each result
-    row, times its weight, onto its token's.  As many passes as the
-    local rows fill: one, but under an imbalance `tile_rows`' margin
-    does not cover; the bound is the assignments themselves."""
+    through the experts, and the same walk adds each result row, times
+    its weight, onto its token's.  As many passes as the local rows
+    fill: one, but under an imbalance `tile_rows`' margin does not
+    cover; the bound is the assignments themselves.
+
+    Where the rows are `few_a_group`, grouped order leaves room: every
+    group begins on a whole row tile (the walks take the groups'
+    `starts` as they are given), and the rows between belong to nobody
+    (`_fused`)."""
     N, _ = x.shape
     R = tile_rows(N, cfg)
-    ends = jnp.cumsum(counts)
-    starts = ends - counts
+    few = few_a_group(N, cfg)
+    room = row_tiles(counts) * ROW_TILE if few else counts
+    starts = jnp.cumsum(room) - room
+    ends = starts + counts
     # the chip runs the kernels; elsewhere their `jnp` references, the
     # parity oracle (tests/test_experts.py steers the kernels in, in the
     # Pallas interpreter)
-    dispatch, combine = (moe_dispatch, moe_combine) \
+    dispatch, combine, fused = (moe_dispatch, moe_combine, _fused) \
         if jax.default_backend() == "tpu" \
-        else (dispatch_reference, combine_reference)
+        else (dispatch_reference, combine_reference, fused_reference)
     x = x.astype(jnp.float32)
 
     # (a loop's body names its scope again: it is lowered as a function
@@ -300,11 +345,15 @@ def _walked(p, x, local, w, counts, cfg: ExpertsConfig, layer, base):
         lo = i * R
         xs = dispatch(x, local, starts, lo, rows=R)
         sizes = jnp.clip(ends - lo, 0, R) - jnp.clip(starts - lo, 0, R)
-        out = _grouped(rows_of(xs).astype(cfg.dtype), p, sizes, cfg.dtype,
-                       layer)
-        return combine(y, slabs(out), local, w, starts, lo)
+        if few:
+            out = fused(xs, p, sizes, cfg.dtype, layer)
+        else:
+            out = slabs(_grouped(rows_of(xs).astype(cfg.dtype), p, sizes,
+                                 cfg.dtype, layer))
+        return combine(y, out, local, w, starts, lo)
 
-    return lax.fori_loop(0, (ends[-1] + R - 1) // R, tile, base)
+    last = jnp.max(jnp.where(counts > 0, ends, 0))
+    return lax.fori_loop(0, (last + R - 1) // R, tile, base)
 
 
 @jax.named_scope(scopes.MOE_EXPERTS)
@@ -347,8 +396,23 @@ def routed_experts(p, x, chosen, w, cfg: ExpertsConfig, valid=None,
     stats = jnp.stack([
         jnp.sum(load),
         jnp.sum(counts > 0).astype(jnp.float32),
-        jnp.where(mean > 0, jnp.max(load) / jnp.maximum(mean, 1e-9), 0.0)])
+        jnp.where(mean > 0, jnp.max(load) / jnp.maximum(mean, 1e-9), 0.0),
+        jnp.sum(row_tiles(counts)).astype(jnp.float32)])
     return y, stats
+
+
+def program_counters(cfg: ExpertsConfig, stats):
+    """One program's `decode_common.EXPERT_COUNTERS` from its expert
+    layers' stats (layers, len(STATS)), or None for a program without
+    an expert layer."""
+    if stats is None:
+        return jnp.asarray([cfg.n_held, cfg.n_routed, 0, 0, 0, 0],
+                           jnp.float32)
+    return jnp.stack([
+        jnp.float32(cfg.n_held), jnp.float32(cfg.n_routed),
+        jnp.sum(stats[:, 0]), jnp.mean(stats[:, 1]) / cfg.n_held,
+        jnp.max(stats[:, 2]),
+        jnp.sum(stats[:, 3]) / jnp.maximum(jnp.sum(stats[:, 1]), 1.0)])
 
 
 def moe_layer(p, x32, cfg: ExpertsConfig, valid=None, tiled: bool = True):

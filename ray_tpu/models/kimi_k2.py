@@ -504,13 +504,7 @@ def walk_layers(cfg: KimiK2Config, params, x, carry, layer: Callable):
 def expert_counters(cfg: KimiK2Config, stats):
     """One program's `decode_common.EXPERT_COUNTERS` from its expert
     layers' stats (n_moe, len(experts.STATS))."""
-    e = cfg.experts
-    if not cfg.n_moe:
-        return jnp.asarray([e.n_held, e.n_routed, 0, 0, 0], jnp.float32)
-    return jnp.stack([
-        jnp.float32(e.n_held), jnp.float32(e.n_routed),
-        jnp.sum(stats[:, 0]), jnp.mean(stats[:, 1]) / e.n_held,
-        jnp.max(stats[:, 2])])
+    return ex.program_counters(cfg.experts, stats if cfg.n_moe else None)
 
 
 @jax.named_scope(scopes.EMBED)

@@ -412,13 +412,7 @@ def walk_layers(cfg: LagunaConfig, params, x, layer: Callable):
 def expert_counters(cfg: LagunaConfig, stats):
     """One program's `decode_common.EXPERT_COUNTERS` from its expert
     layers' stats (n_sparse, len(experts.STATS))."""
-    e = cfg.experts
-    if stats is None:
-        return jnp.asarray([e.n_held, e.n_routed, 0, 0, 0], jnp.float32)
-    return jnp.stack([
-        jnp.float32(e.n_held), jnp.float32(e.n_routed),
-        jnp.sum(stats[:, 0]), jnp.mean(stats[:, 1]) / e.n_held,
-        jnp.max(stats[:, 2])])
+    return ex.program_counters(cfg.experts, stats)
 
 
 def causal_mask(T: int, kind: str, cfg: LagunaConfig):

@@ -237,6 +237,11 @@ def _engine_metrics() -> Dict[str, Any]:
                     "per program: the fullest held expert's tokens "
                     "over the held experts' mean, worst layer; summed",
                     tag_keys=tags + ("program",)),
+                "expert_row_tiles_per_touched": Counter(
+                    "serve_expert_row_tiles_per_touched_sum",
+                    "per program: row tiles the experts' rows fill "
+                    "over the experts touched, over all layers; summed",
+                    tag_keys=tags + ("program",)),
                 "expert_held": Gauge(
                     "serve_expert_held",
                     "routed experts this chip holds", tag_keys=tags),
@@ -1210,21 +1215,25 @@ class EngineTelemetry:
         summed under `program` ("decode" or "prefill") into
         ``engine_stats()["experts"]`` and the ``serve_expert_*``
         metrics."""
-        held, of, local, touched, worst = (float(v) for v in counters)
+        held, of, local, touched, worst, tiles = (
+            float(v) for v in counters)
         with self._lock:
             acc = self._experts.setdefault(program, {
                 "programs": 0, "assignments_local": 0.0,
-                "touched_share": 0.0, "load_max_over_mean": 0.0})
+                "touched_share": 0.0, "load_max_over_mean": 0.0,
+                "row_tiles_per_touched": 0.0})
             acc.update(held=int(held), of=int(of))
             acc["programs"] += 1
             acc["assignments_local"] += local
             acc["touched_share"] += touched
             acc["load_max_over_mean"] += worst
+            acc["row_tiles_per_touched"] += tiles
         tags = dict(self._tags, program=program)
         self._m["expert_programs"].inc(tags=tags)
         self._m["expert_assignments_local"].inc(local, tags=tags)
         self._m["expert_touched_share"].inc(touched, tags=tags)
         self._m["expert_load_max_over_mean"].inc(worst, tags=tags)
+        self._m["expert_row_tiles_per_touched"].inc(tiles, tags=tags)
         self._m["expert_held"].set(held, tags=self._tags)
         self._m["expert_of"].set(of, tags=self._tags)
 
@@ -1642,7 +1651,10 @@ class EngineTelemetry:
                        "experts_touched_share": round(
                            acc["touched_share"] / acc["programs"], 4),
                        "load_max_over_mean": round(
-                           acc["load_max_over_mean"] / acc["programs"], 4)}
+                           acc["load_max_over_mean"] / acc["programs"], 4),
+                       "row_tiles_per_touched": round(
+                           acc["row_tiles_per_touched"]
+                           / acc["programs"], 4)}
                 for kind, acc in sorted(experts.items())},
             # paged decode waves: the blocks that hold their rows'
             # positions over the entries of those rows' block tables

@@ -33,7 +33,8 @@ PARENT = {
                "start": ((3,), I32)}, 2048, 2 * (6912 + 73728)),
     "kimi_k2": ({"ckv": ((3, 24, 16, 32), BF16), "kpe": ((3, 24, 16, 8), BF16),
                  "block_tables": ((3, 8), I32), "pos": ((3,), I32),
-                 "start": ((3,), I32), "experts": ((5,), F32)}, 3840, 0),
+                 # (six counters since PR 46: `row_tiles_per_touched`)
+                 "start": ((3,), I32), "experts": ((6,), F32)}, 3840, 0),
 }
 
 

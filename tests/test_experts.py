@@ -179,18 +179,21 @@ def test_the_counters_count_the_held_experts_load():
                                counts.max() / counts.mean(), rtol=1e-6)
 
 
-@pytest.mark.parametrize("n_tokens,want", [(64, 32), (8192, 2304),
+@pytest.mark.parametrize("n_tokens,want", [(64, 120), (8192, 2304),
                                            (1024, 384), (5120, 1792),
-                                           (2, 16)])
+                                           (2, 8)])
 def test_tile_rows_follow_the_chips_share(n_tokens, want):
-    """A decode wave's 16 expected rows take 32, an 8k prefill's 2,048
-    take 2,304 in one pass, and a count past 512 is an odd multiple of
-    256, never a multiple of 512 (the grouped matmul's row tile would
-    be 512); never more rows than the tokens can send (2 tokens x 8
-    choices)."""
+    """A decode wave's 16 expected rows are 32 with their margin, few a
+    group: each of the 12 experts begins on a row tile of its own, 7
+    rows more apiece, in whole tiles (120).  A prefill's rows go
+    through the compiler's grouped matmul: an 8k prefill's 2,048 take
+    2,304 in one pass, and a count past 512 is an odd multiple of 256,
+    never a multiple of 512 (its row tile would be 512); never more
+    rows than the tokens can send (2 tokens x 8 choices: one tile)."""
     cfg = ex.ExpertsConfig(d_model=8, d_expert=8, n_routed=384, top_k=8,
                            held=ex.held_range(0, 12))
     assert ex.tile_rows(n_tokens, cfg) == want
+    assert ex.few_a_group(n_tokens, cfg) == (n_tokens <= 64)
 
 
 #: the serving path (`tiled=True`: the kernels of ops/moe_dispatch.py
@@ -213,6 +216,14 @@ SERVED = {
                                            14: -10.0}, False),
     "no_row_is_local": (64, None, (0, 1), 4096, {0: -10.0, 1: -10.0},
                         False),
+    # few rows a group: the fused kernel's regime (`few_a_group`)
+    "a_short_wave_over_a_stack": (8, None, (0, 1, 2, 3, 4, 5), 4096, None,
+                                  True),
+    "a_short_wave_with_idle_rows": (16, "every_third_idle", None, 4096,
+                                    None, False),
+    "a_short_wave_on_one_expert": (20, None, (3, 12, 13, 14), 4096,
+                                   {3: 10.0, 12: -10.0, 13: -10.0,
+                                    14: -10.0}, True),
     "local_rows_just_under_one_tile": (40, 31, None, 128, None, False),
     "local_rows_fill_one_tile": (40, 32, None, 128, None, False),
     "local_rows_just_over_one_tile": (40, 33, None, 128, None, False),
@@ -220,16 +231,21 @@ SERVED = {
 }
 
 
-@pytest.fixture(params=["kernels_interpreted", "as_the_cpu_runs_it"])
+@pytest.fixture(params=["kernels_interpreted", "fused_interpreted",
+                        "as_the_cpu_runs_it"])
 def runs(request, monkeypatch):
-    """What `routed_experts(tiled=True)` moves rows with: off the chip
-    the kernels' `jnp` references; steered here to the kernels
-    themselves, in the Pallas interpreter."""
-    if request.param == "kernels_interpreted":
+    """What `routed_experts(tiled=True)` moves rows with, and what
+    multiplies rows that are few a group: off the chip the kernels'
+    `jnp` references; steered here to the kernels themselves, in the
+    Pallas interpreter (the walks; the walks and `grouped_swiglu`)."""
+    if request.param != "as_the_cpu_runs_it":
         monkeypatch.setattr(ex, "dispatch_reference", functools.partial(
             ex.moe_dispatch, interpret=True))
         monkeypatch.setattr(ex, "combine_reference", functools.partial(
             ex.moe_combine, interpret=True))
+    if request.param == "fused_interpreted":
+        monkeypatch.setattr(ex, "fused_reference", functools.partial(
+            ex._fused, interpret=True))
     return request.param
 
 
@@ -272,6 +288,13 @@ def test_the_served_path_is_the_one_that_differentiates(whole, name, runs):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(base))
     if name == "one_held_expert_takes_every_local_row":
         assert float(stats[1]) == 1 and local.sum() == n
+    assert ex.few_a_group(n, cfg) == (
+        name.startswith(("a_short_wave", "local_rows_"))
+        and name != "local_rows_over_many_tiles")
+    # the row tiles the experts' rows fill
+    per = np.asarray([(np.asarray(chosen)[local] == e).sum()
+                      for e in cfg.held_ids])
+    assert float(stats[3]) == (-(-per // ex.ROW_TILE)).sum()
     if name.startswith("local_rows_"):
         rows = ex.tile_rows(n, cfg)
         passes = -(-int(local.sum()) // rows)
@@ -317,8 +340,10 @@ def test_many_small_experts_all_held_with_empty_groups(name, runs):
     assert float(stats[0]) == rows * 8            # every choice is local
     if n <= 16:
         assert float(stats[1]) < many             # groups of 0 rows
-        # one pass takes every assignment the wave can make
-        assert ex.tile_rows(n, cfg) == n * 8
+        # one pass takes every assignment the wave can make, each
+        # expert's on a row tile of its own
+        assert ex.few_a_group(n, cfg)
+        assert ex.tile_rows(n, cfg) == n * 8 + (ex.ROW_TILE - 1) * many
     if valid is None:
         dense = _dense_reference(p, x, cfg) + base
         np.testing.assert_allclose(np.asarray(got), np.asarray(dense),

@@ -29,8 +29,9 @@ def _steer(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(D, "gqa_paged_decode", functools.partial(
         gqa_paged_decode, interpret=True))
-    # the chip's step moves the experts' rows by kernels too
-    for kernel in ("moe_dispatch", "moe_combine"):
+    # the chip's step moves the experts' rows by kernels too, and
+    # multiplies them by one where they are few an expert
+    for kernel in ("moe_dispatch", "moe_combine", "_fused"):
         monkeypatch.setattr(experts, kernel, functools.partial(
             getattr(experts, kernel), interpret=True))
 
@@ -214,7 +215,7 @@ def test_only_the_paged_decode_step_on_the_chip_holds_the_kernel(
     kernel, one ``pallas_call`` a full layer (nano has two); the CPU,
     the dense cache and a prefill keep the ``jnp`` paths (the expert
     layers' own kernels, which every program on the chip holds, are
-    tests/test_moe_dispatch.py's)."""
+    tests/test_moe_dispatch.py's and tests/test_grouped_swiglu.py's)."""
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     cfg, _ = tiny
     fn, args = _programs(*tiny)[program]
@@ -222,7 +223,8 @@ def test_only_the_paged_decode_step_on_the_chip_holds_the_kernel(
     assert _named(jaxpr, scopes.GQA_PAGED_DECODE) == kernels
     assert kernels in (0, len(cfg.layers_of("full")))
     others = sum(_named(jaxpr, k)
-                 for k in (scopes.MOE_DISPATCH, scopes.MOE_COMBINE))
+                 for k in (scopes.MOE_DISPATCH, scopes.MOE_COMBINE,
+                           scopes.GROUPED_SWIGLU))
     assert _count(jaxpr, "pallas_call") == kernels + others
 
 

@@ -18,6 +18,7 @@ from ray_tpu.models.kimi_k2 import (kimi_k2_config,  # noqa: E402
 from ray_tpu.models.kimi_k2_decode import (  # noqa: E402
     kimi_k2_decode_step, kimi_k2_init_cache, kimi_k2_init_paged_cache,
     kimi_k2_paged_prefill)
+from ray_tpu.models import experts as ex  # noqa: E402
 from tests.test_scopes import _op_scopes  # noqa: E402
 
 CFG = kimi_k2_config("nano", held=(0, 1, 2, 3, 4, 5))
@@ -105,9 +106,12 @@ def _traced(program, params):
         fn = lambda p, c, t: kimi_k2_decode_step(p, c, t, CFG)  # noqa: E731
         args = (params, paged, jnp.zeros((2,), jnp.int32))
     else:
+        # 16 columns: rows few an expert, as a decode wave's; 64: many
+        t_pad = 64 if program == "long_prefill" else 16
         fn = lambda p, c, t, bt: kimi_k2_paged_prefill(  # noqa: E731
-            p, c, t, CFG, row_bt=bt, prefix_len=0, n_tail=5, slot=0)
-        args = (params, paged, jnp.zeros((1, 16), jnp.int32),
+            p, c, t, CFG, row_bt=bt, prefix_len=0, n_tail=t_pad - 11,
+            slot=0)
+        args = (params, paged, jnp.zeros((1, t_pad), jnp.int32),
                 jnp.zeros((8,), jnp.int32))
     found = []
 
@@ -123,7 +127,11 @@ def _traced(program, params):
     return found
 
 
-@pytest.mark.parametrize("program", ["decode_step", "paged_prefill"])
+#: which programs hand an expert few rows (`experts.few_a_group`)
+FEW = {"decode_step": True, "paged_prefill": True, "long_prefill": False}
+
+
+@pytest.mark.parametrize("program", FEW)
 def test_the_chips_kernels_are_named_and_scoped(program, params,
                                                 monkeypatch):
     """On the chip the paged decode step holds the kernel
@@ -133,30 +141,40 @@ def test_the_chips_kernels_are_named_and_scoped(program, params,
     and both programs move the held experts' rows with ``moe_dispatch``
     and ``moe_combine`` (ray_tpu/ops/moe_dispatch.py), one call each in
     the expert layers' scan, inside the loop over row tiles, under
-    ``moe_experts``.  Traced only: tests/test_tpu_compile.py reads the
+    ``moe_experts``; between them, where an expert expects few rows (a
+    decode wave, a short prefill), ONE ``grouped_swiglu``
+    (ray_tpu/ops/grouped_swiglu.py) under ``moe_experts`` too, so that
+    its time reads as the experts' (`moe_time_share.offline`,
+    `moe_expert_roofline.offline`) and nothing new reads unscoped.
+    Traced only: tests/test_tpu_compile.py reads the
     compiled programs' own scope maps."""
     assert {scopes.MLA_PAGED_DECODE, scopes.MOE_DISPATCH,
-            scopes.MOE_COMBINE} <= set(scopes.KERNELS)
+            scopes.MOE_COMBINE, scopes.GROUPED_SWIGLU} <= set(scopes.KERNELS)
+    assert ex.few_a_group({"decode_step": 2, "paged_prefill": 16,
+                           "long_prefill": 64}[program],
+                          CFG.experts) == FEW[program]
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     by_scope = collections.defaultdict(list)
     for name, eqn_params, stack in _traced(program, params):
         if name == "pallas_call":
             by_scope[scopes.innermost_scope(stack)].append(
                 eqn_params["name"])
-    assert by_scope.pop(scopes.MOE_EXPERTS) == [scopes.MOE_DISPATCH,
-                                               scopes.MOE_COMBINE]
+    assert by_scope.pop(scopes.MOE_EXPERTS) == [scopes.MOE_DISPATCH] + [
+        scopes.GROUPED_SWIGLU] * FEW[program] + [scopes.MOE_COMBINE]
     # (a toy tail takes the prefill's jnp walk, not its flash kernel)
     assert dict(by_scope) == ({scopes.MLA: [scopes.MLA_PAGED_DECODE] * 2}
                               if program == "decode_step" else {})
 
 
-@pytest.mark.parametrize("program", ["decode_step", "paged_prefill"])
+@pytest.mark.parametrize("program", FEW)
 def test_the_expert_layer_sorts_and_scatters_nothing(program, params,
                                                      monkeypatch):
     """What the serving programs hold under ``moe_experts`` off the
     chip is the kernels' `jnp` references, a sort and a scatter among
     them; on the chip (steered) no sort, gather or scatter is traced
-    there at all: the rows are moved by the kernels."""
+    there at all: the rows are moved by the kernels, and multiplied
+    by one kernel where they are few an expert, by the compiler's three
+    grouped matmuls where they are many."""
     def under_experts():
         return collections.Counter(
             name for name, _, stack in _traced(program, params)
@@ -167,5 +185,6 @@ def test_the_expert_layer_sorts_and_scatters_nothing(program, params,
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     on_chip = under_experts()
     assert not moved & set(on_chip), on_chip
-    assert on_chip["pallas_call"] == 2 and on_chip["ragged_dot_general"] \
-        + on_chip["ragged_dot"] == 3, on_chip
+    ragged = on_chip["ragged_dot_general"] + on_chip["ragged_dot"]
+    assert (on_chip["pallas_call"], ragged) == (
+        (3, 0) if FEW[program] else (2, 3)), on_chip

@@ -109,6 +109,10 @@ def test_the_expert_counters_land_with_the_tokens():
         assert block["held"] == 6 and block["of"] == 16
         assert 0 < block["experts_touched_share"] <= 1
         assert block["load_max_over_mean"] >= 1
+        # row tiles the experts' rows fill over the experts touched:
+        # one apiece unless an expert's rows overflow a tile of 8
+        assert 1.0 <= block["row_tiles_per_touched"] < 3.0
+    assert experts["decode"]["row_tiles_per_touched"] == 1.0
     assert experts["prefill"]["programs"] == 2
     # a prefill of 40 and one of 21 tokens, 2 expert layers, 4 of 16
     # experts a token, 6 held: about 61 * 2 * 4 * 6 / 16 assignments

@@ -466,6 +466,10 @@ def test_the_bytes_reserved_by_layer_type_land_with_the_tokens():
     experts = stats["experts"]
     assert set(experts) == {"decode", "prefill"}
     assert experts["decode"]["held"] == experts["decode"]["of"] == 16
+    # one row a wave: every touched expert's rows fill one row tile; a
+    # prefill of 40 tokens over 16 experts overflows some experts' tiles
+    assert experts["decode"]["row_tiles_per_touched"] == 1.0
+    assert 1.0 <= experts["prefill"]["row_tiles_per_touched"] < 3.0
     from ray_tpu.util.metrics import _registry
 
     assert _registry.snapshot()["serve_kv_reach_full_bytes_total"]["values"]

@@ -240,13 +240,15 @@ def test_only_the_paged_decode_step_on_the_chip_holds_the_kernel(
     kernel, one ``pallas_call`` in each of the two scans over layers;
     the CPU, the dense cache and a prefill keep the ``jnp`` paths (the
     expert layer's own kernels, which every program on the chip holds,
-    are tests/test_kimi_k2_scopes.py's)."""
+    are tests/test_kimi_k2_scopes.py's: the two that move rows and,
+    these programs' rows being few an expert, ``grouped_swiglu``)."""
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     fn, args = _programs(*tiny)[program]
     jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
     assert _named(jaxpr, scopes.MLA_PAGED_DECODE) == kernels
     assert _count(jaxpr, "pallas_call") == (
-        kernels + 2 if backend == "tpu" else 0)
+        kernels + 3 if backend == "tpu" else 0)
+    assert _named(jaxpr, scopes.GROUPED_SWIGLU) == (backend == "tpu")
 
 
 def _shapes(jaxpr, found=None):
@@ -298,8 +300,9 @@ def test_the_decode_step_through_the_kernel_is_the_jnp_step(tiny,
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(D, "mla_paged_decode", functools.partial(
         mla_paged_decode, interpret=True))
-    # the chip's step moves the experts' rows by kernels too
-    for kernel in ("moe_dispatch", "moe_combine"):
+    # the chip's step moves the experts' rows by kernels too, and
+    # multiplies them by one
+    for kernel in ("moe_dispatch", "moe_combine", "_fused"):
         monkeypatch.setattr(experts, kernel, functools.partial(
             getattr(experts, kernel), interpret=True))
     got_logits, got = step(cache)

@@ -411,7 +411,9 @@ def test_laguna_decode_step_walks_the_pool_once_a_full_layer(monkeypatch):
     full layer, each under ``attn_full``; nothing gathered under
     ``kv_pool`` (the chunked gather is gone: what stays there are the
     rings' row writes and the commit's scatter); the pools neither
-    copied nor sliced by layer; donated, they are updated in place."""
+    copied nor sliced by layer; donated, they are updated in place.
+    Its four expert layers are one ``grouped_swiglu`` each under
+    ``moe_experts``, and no ``ragged-dot`` is compiled."""
     from ray_tpu._private import scopes
     from ray_tpu.models.laguna import laguna_init
 
@@ -432,6 +434,9 @@ def test_laguna_decode_step_walks_the_pool_once_a_full_layer(monkeypatch):
     assert len(walks) == n_full == 2, calls
     assert all(set(scoped[name].values()) == {scopes.ATTN_FULL}
                for name in walks), {n: scoped.get(n) for n in walks}
+    fused, ragged = _experts_kernels(text)
+    assert not ragged and "ragged-dot" not in text, ragged
+    assert [s for _, s in fused] == [scopes.MOE_EXPERTS] * 4, fused
     # (the commit still looks each row's block up in its table: int32)
     gathered = [line for line in text.splitlines()
                 if re.search(r"= bf16\[[^ ]* gather\(", line)
@@ -504,6 +509,69 @@ def test_moe_dispatch_and_combine_compile_at_the_cells_shapes(n, rows):
     assert compiled.memory_analysis().alias_size_in_bytes == n * 7168 * 4
 
 
+@pytest.mark.parametrize("stack,rows,tf", [
+    ((4, 256, 2048, 512), 2304, 512), ((5, 12, 7168, 2048), 120, 128)],
+    ids=["laguna_wave", "kimi_k2_wave"])
+def test_grouped_swiglu_compiles_at_the_cells_wave_shapes(stack, rows, tf):
+    """ops/grouped_swiglu.py at the two cells' decode waves: Laguna's
+    1,024 experts of (2,048, 512) stacked over 4 layers, an expert
+    whole a step; Kimi-K2's 60 of (7,168, 2,048) over 5, in chunks of
+    128 columns; the rows a wave's pass holds (`experts.tile_rows`).
+    One Mosaic call of the kernel's name within the VMEM it asks for
+    (the chip's compiler refuses one that needs more); the stacks go
+    in whole (no copy, no slice of a layer: no temporary)."""
+    from ray_tpu._private import scopes
+    from ray_tpu.ops.grouped_swiglu import chunk, grouped_swiglu
+
+    spec = _one_chip()
+    L, g, d, f = stack
+    assert chunk(d, f) == tf
+    bf16 = lambda *shape: spec(shape, jnp.bfloat16)   # noqa: E731
+    compiled = jax.jit(grouped_swiglu).lower(
+        spec((rows, d // 128, 128), jnp.float32), bf16(L, g, d, f),
+        bf16(L, g, d, f),
+        bf16(L, g, f, d), spec((g,), jnp.int32),
+        spec((), jnp.int32)).compile()
+    calls = [line.split(" = ")[0].split("%")[-1]
+             for line in compiled.as_text().splitlines()
+             if MOSAIC_CALL in line]
+    assert [name.split(".")[0] for name in calls] == [
+        scopes.GROUPED_SWIGLU], calls
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e6
+
+
+def _experts_kernels(text: str):
+    """(calls of `grouped_swiglu` with their scopes, the compiler's
+    ``ragged-dot-*`` kernels with theirs) in a compiled program."""
+    from ray_tpu._private import scopes
+
+    keyed = [(name, scope)
+             for name, by_key in scopes.scope_map_from_hlo(text).items()
+             for key, scope in by_key.items() if "custom-call" in key]
+    return ([(n, s) for n, s in keyed
+             if n.startswith(scopes.GROUPED_SWIGLU)],
+            [(n, s) for n, s in keyed if "ragged-dot" in n])
+
+
+def test_laguna_prefill_keeps_the_compilers_grouped_matmuls(monkeypatch):
+    """A 1,024-token prefill of the Laguna cell hands each of the 256
+    experts 32 rows or so (`experts.few_a_group` is False): its four
+    expert layers go through `lax.ragged_dot`, and no `grouped_swiglu`
+    is compiled there (PERF.md, PR 46: the timing that kept it)."""
+    from ray_tpu._private import scopes
+    from ray_tpu.models.laguna import laguna_init
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    _, params, cache, programs, _ = _serving_cell(
+        "laguna-xs2.serve-offline-mixed", laguna_init, 1024)
+    fn, args = programs["prefill"]
+    text = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args).compile().as_text()
+    fused, ragged = _experts_kernels(text)
+    assert not fused, fused
+    assert ragged and {s for _, s in ragged} == {scopes.MOE_EXPERTS}
+
+
 @pytest.mark.parametrize("program,t_pad", [("decode", 0), ("prefill", 8192)])
 def test_kimi_k2_programs_fit_the_chip_at_the_published_widths(
         program, t_pad, monkeypatch):
@@ -513,9 +581,13 @@ def test_kimi_k2_programs_fit_the_chip_at_the_published_widths(
     experts held, 20,480 rows of the vocabulary, bf16 weights, 64 slots
     over a 4 GiB latent pool, the 8,192-token prefill bucket.  The
     compiled peak (weights and pool among it) stays under 15 GB of the
-    chip's 16: the room left is the reference's at warm-up.  The
-    experts' grouped matmuls are kernels (XLA's own lowering of
-    ragged_dot), scoped ``moe_experts`` by their name, and the rows
+    chip's 16: the room left is the reference's at warm-up.  A decode
+    wave's experts are ONE kernel, ``grouped_swiglu``
+    (ops/grouped_swiglu.py), once in the scan over the expert layers
+    and under ``moe_experts``, and no ``ragged-dot`` is left there; the
+    8,192 prefill's grouped matmuls are the compiler's kernels (XLA's
+    own lowering of ragged_dot), scoped ``moe_experts`` by their name.
+    The rows
     reach them and return through ``moe_dispatch`` and ``moe_combine``
     (ops/moe_dispatch.py), one call each under ``moe_experts``: no
     sort, gather or scatter is compiled there; the 512-wide
@@ -542,11 +614,13 @@ def test_kimi_k2_programs_fit_the_chip_at_the_published_widths(
     assert memory.peak_memory_in_bytes < 15e9, memory
     assert memory.alias_size_in_bytes >= 4.29e9      # the pool, in place
     text = compiled.as_text()
-    kernels = {name: scope for name, keyed in
-               scopes.scope_map_from_hlo(text).items()
-               for key, scope in keyed.items() if "ragged-dot" in name
-               and "custom-call" in key}
-    assert kernels and set(kernels.values()) == {scopes.MOE_EXPERTS}
+    fused, ragged = _experts_kernels(text)
+    if program == "decode":
+        assert not ragged and "ragged-dot" not in text, ragged
+        assert [s for _, s in fused] == [scopes.MOE_EXPERTS], fused
+    else:
+        assert not fused, fused
+        assert ragged and {s for _, s in ragged} == {scopes.MOE_EXPERTS}
     rows = [(name.rsplit(".", 1)[0], scope) for name, keyed in
             scopes.scope_map_from_hlo(text).items()
             for key, scope in keyed.items() if "custom-call" in key
