@@ -1082,16 +1082,19 @@ def _check_moe_rows(size: Size, interpret: bool) -> None:
 
 
 def _check_grouped_swiglu(size: Size, interpret: bool) -> None:
-    """ops/grouped_swiglu.py: a decode wave's rows, few an expert and
-    some experts none, over a stack of two layers' held experts, against
-    the three grouped matmuls it replaces (`experts.fused_reference`)."""
+    """ops/grouped_swiglu.py over a stack of two layers' held experts,
+    against the three grouped matmuls it replaces: a decode wave's
+    rows, few an expert and some experts none, every group begun on a
+    row tile of 8 (`experts.fused_reference`); and a prefill pass's,
+    groups of uneven heights end to end under 256-row tiles, one group
+    empty (`experts._grouped` on the sizes as they are)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from ray_tpu.models import experts as ex
     from ray_tpu.models.kimi_k2 import kimi_k2_config
-    from ray_tpu.ops.grouped_swiglu import ROW_TILE, grouped_swiglu
+    from ray_tpu.ops.grouped_swiglu import ROW_TILE, TALL, grouped_swiglu
     from ray_tpu.ops.moe_dispatch import rows_of, slabs
 
     cfg = kimi_k2_config(size.mla_preset,
@@ -1101,26 +1104,34 @@ def _check_grouped_swiglu(size: Size, interpret: bool) -> None:
     p = {"w_gate": jax.random.normal(ks[0], (2, g, d, f), cfg.dtype) * .02,
          "w_up": jax.random.normal(ks[1], (2, g, d, f), cfg.dtype) * .02,
          "w_down": jax.random.normal(ks[2], (2, g, f, d), cfg.dtype) * .02}
-    counts = np.random.default_rng(size.seed).integers(0, 4, g)
-    counts[0], counts[-1] = 0, 2 * ROW_TILE + 1       # one empty, one tall
-    tiles = -(-counts // ROW_TILE)
-    first = (np.cumsum(tiles) - tiles) * ROW_TILE
-    owned = np.zeros((int(tiles.sum()) + 2) * ROW_TILE, bool)
-    for at, n in zip(first, counts):
-        owned[at:at + n] = True
-    xs = slabs(jnp.where(owned[:, None], jax.random.normal(
-        ks[3], (owned.size, d), jnp.float32), 0.0))
-    sizes, layer = jnp.asarray(counts, jnp.int32), jnp.int32(1)
-    t0 = time.perf_counter()
-    got = grouped_swiglu(xs, p["w_gate"], p["w_up"], p["w_down"], sizes,
-                         layer, dtype=cfg.dtype, interpret=interpret)
-    want = jax.jit(ex.fused_reference, static_argnums=(3,))(
-        xs, p, sizes, cfg.dtype, layer)
-    err = _rel_err(rows_of(got)[owned], rows_of(want)[owned])
-    say("mla", kernel="grouped_swiglu", shape=[owned.size, g, d, f],
-        touched=int((counts > 0).sum()), err=round(err, 5),
-        seconds=round(time.perf_counter() - t0, 2))
-    assert err <= KERNEL_TOL, ("grouped_swiglu", err)
+    rng = np.random.default_rng(size.seed)
+    wave = rng.integers(0, 4, g)
+    wave[0], wave[-1] = 0, 2 * ROW_TILE + 1           # one empty, one tall
+    tall = rng.integers(40, 300, g)
+    tall[1] = 0
+    layer = jnp.int32(1)
+    for counts, tm, aligned in ((wave, ROW_TILE, True),
+                                (tall, 2 * TALL, False)):
+        room = -(-counts // tm) * tm if aligned else counts
+        first = np.cumsum(room) - room
+        owned = np.zeros((-(-int(room.sum()) // tm) + 2) * tm, bool)
+        for at, n in zip(first, counts):
+            owned[at:at + n] = True
+        xs = slabs(jnp.where(owned[:, None], jax.random.normal(
+            ks[3], (owned.size, d), jnp.float32), 0.0))
+        sizes = jnp.asarray(counts, jnp.int32)
+        t0 = time.perf_counter()
+        got = grouped_swiglu(xs, p["w_gate"], p["w_up"], p["w_down"], sizes,
+                             layer, dtype=cfg.dtype, tm=tm, aligned=aligned,
+                             interpret=interpret)
+        want = jax.jit(ex.fused_reference, static_argnums=(3, 5, 6))(
+            xs, p, sizes, cfg.dtype, layer, tm, aligned)
+        err = _rel_err(rows_of(got)[owned], rows_of(want)[owned])
+        say("mla", kernel="grouped_swiglu", aligned=aligned,
+            shape=[owned.size, g, d, f], row_tile=tm,
+            touched=int((counts > 0).sum()), err=round(err, 5),
+            seconds=round(time.perf_counter() - t0, 2))
+        assert err <= KERNEL_TOL, ("grouped_swiglu", aligned, err)
 
 
 def check_mla_kernels(size: Size, *, interpret: bool = False) -> None:

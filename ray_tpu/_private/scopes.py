@@ -71,7 +71,7 @@ OPTIMIZER = "optimizer"
 #: and carries no name stack: the stem says what they were made from.
 #: ``lax.ragged_dot`` becomes a grouped-matmul kernel named
 #: ``ragged-dot-*``, and the only ragged_dot here is the experts'
-#: (both paths of experts.routed_experts keep it)
+#: (the path of experts.routed_experts that differentiates keeps it)
 REWRITTEN = {"ragged-dot": MOE_EXPERTS}
 
 DEVICE_SCOPES = frozenset((EMBED, ATTN, MLP, LN, LM_HEAD_CE, LM_HEAD,

@@ -583,9 +583,10 @@ EXPERTS = "experts"
 #: assignments that fell on held experts, summed over the layers; held
 #: experts with at least one token over the held, mean over layers;
 #: the fullest held expert's tokens over the held experts' mean, worst
-#: layer; row tiles the experts' rows fill over the experts touched,
-#: summed over the layers (the visits `ops/grouped_swiglu.py` makes an
-#: expert it fetches: 1.0 unless an expert's rows overflow a row tile)
+#: layer; the visits `ops/grouped_swiglu.py` makes the experts' rows
+#: over the experts touched, summed over the layers (a decode wave: the
+#: row tiles the rows fill, 1.0 unless an expert's overflow one; a
+#: prefill: the tall tiles a group lies in, 1 + the edges it straddles)
 EXPERT_COUNTERS = ("held", "of", "assignments_local",
                    "experts_touched_share", "load_max_over_mean",
                    "row_tiles_per_touched")

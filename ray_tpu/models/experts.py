@@ -27,14 +27,15 @@ gather, no scatter.  A pass takes a static number of grouped rows
 (`tile_rows`: what a prefill's local rows fit in), as many passes as
 the local rows fill: the bound is the assignments themselves, never a
 capacity, so imbalance costs time and drops nothing.  What multiplies
-the groups follows the rows a group expects (`few_a_group`): few (a
-decode wave), and one Pallas kernel streams each touched expert's
-weights once and does gate, up, SwiGLU and down on its rows
-(ops/grouped_swiglu.py); many (a long prefill), and the groups go
-through `jax.lax.ragged_dot` (on a TPU XLA lowers it to a grouped-matmul
-kernel that visits only the tiles a group has rows in, so an expert no
-token chose is never read).  The path that differentiates sorts all
-``N * top_k`` assignments and takes them in one grouped matmul.
+the groups is one Pallas kernel that streams each touched expert's
+weights and does gate, up, SwiGLU and down on its rows
+(ops/grouped_swiglu.py), so an expert no token chose is never read; how
+it is asked follows the rows a group expects (`few_a_group`): few (a
+decode wave), and every group begins on a row tile of 8 of its own;
+many (a prefill), and the groups lie end to end under tall row tiles,
+one that two groups share visited once for each.  The path that
+differentiates sorts all ``N * top_k`` assignments and takes them in
+one grouped matmul (`jax.lax.ragged_dot`).
 `moe_layer` hands back, beside the result, what the routing did on this
 chip (`STATS`), computed where the counts already are.
 
@@ -55,7 +56,7 @@ from jax import lax
 
 from ray_tpu._private import scopes
 from ray_tpu.ops.grouped_swiglu import (ROW_TILE, grouped_swiglu,
-                                        row_tiles)
+                                        row_tiles, visit_rows, visits)
 from ray_tpu.ops.moe_dispatch import (combine_reference,
                                       dispatch_reference, moe_combine,
                                       moe_dispatch, rows_of, slabs)
@@ -63,8 +64,10 @@ from ray_tpu.ops.moe_dispatch import (combine_reference,
 #: what `moe_layer` reports of one layer's routing on this chip:
 #: assignments that fell on held experts, held experts with at least
 #: one token, the fullest held expert's tokens over their mean, and the
-#: row tiles the experts' rows fill (`grouped_swiglu`'s visits: as many
-#: as experts touched unless one's rows overflow a tile)
+#: visits `grouped_swiglu` makes the experts' rows: where they are
+#: `few_a_group` the row tiles they fill (as many as experts touched
+#: unless one's rows overflow a tile), else the tall tiles they lie in
+#: (one more than they fill for every tile's edge a group straddles)
 STATS = ("assignments_local", "experts_touched", "load_max_over_mean",
          "row_tiles_visited")
 
@@ -231,24 +234,28 @@ def _grouped(xs, p, sizes, dtype, layer=None):
     return dot(h.astype(dtype), p["w_down"])
 
 
-def _fused(xs, p, sizes, dtype, layer=None, interpret=False):
-    """`_grouped` on rows that are few a group (`few_a_group`), as one
-    kernel that fetches every touched expert's weights once
-    (ops/grouped_swiglu.py).  xs (R, s, l) float32 slabs as
-    `moe_dispatch` writes them, every group begun on a whole row tile
-    of `ROW_TILE`; slabs back, as `moe_combine` reads them.  Rows
-    nobody owns come back as whatever they make, and `moe_combine`
-    reads owned rows only."""
+def _fused(xs, p, sizes, dtype, layer=None, tm=ROW_TILE, aligned=True,
+           interpret=False):
+    """`_grouped` as one kernel that fetches a touched expert's weights
+    once a visit (ops/grouped_swiglu.py).  xs (R, s, l) float32 slabs
+    as `moe_dispatch` writes them, in row tiles of `tm`; with `aligned`
+    every group begun on a whole row tile, else where the last one
+    ended; slabs back, as `moe_combine` reads them.  Rows nobody owns
+    come back as whatever they make, and `moe_combine` reads owned rows
+    only."""
     return grouped_swiglu(xs, p["w_gate"], p["w_up"], p["w_down"], sizes,
-                          layer, dtype=dtype, interpret=interpret)
+                          layer, dtype=dtype, tm=tm, aligned=aligned,
+                          interpret=interpret)
 
 
-def fused_reference(xs, p, sizes, dtype, layer=None):
-    """`_fused`'s contract through `_grouped`: every group's rows
-    rounded up to whole row tiles, so that a group begins where the
-    last one's tile ends.  What runs off the chip."""
-    return slabs(_grouped(rows_of(xs).astype(dtype), p,
-                          row_tiles(sizes) * ROW_TILE, dtype, layer))
+def fused_reference(xs, p, sizes, dtype, layer=None, tm=ROW_TILE,
+                    aligned=True):
+    """`_fused`'s contract through `_grouped`: with `aligned` every
+    group's rows rounded up to whole row tiles, so that a group begins
+    where the last one's tile ends.  What runs off the chip."""
+    return slabs(_grouped(
+        rows_of(xs).astype(dtype), p,
+        row_tiles(sizes, tm) * tm if aligned else sizes, dtype, layer))
 
 
 def _local_rows(n_tokens: int, cfg: ExpertsConfig) -> int:
@@ -263,37 +270,35 @@ def _local_rows(n_tokens: int, cfg: ExpertsConfig) -> int:
 
 def few_a_group(n_tokens: int, cfg: ExpertsConfig) -> bool:
     """A held expert expects fewer than 16 of a pass's rows (a decode
-    wave, a short prefill over many small experts): the layer is bound
-    by the touched experts' bytes and takes `_fused`; else by the
-    arithmetic, and takes `_grouped`'s tall row tiles."""
+    wave, a short prefill over many small experts): a group of two or
+    three rows that straddled a row tile would be visited twice and
+    fetch nothing less, so every group begins on a short row tile of
+    its own; else the groups lie end to end under tall ones."""
     return _local_rows(n_tokens, cfg) < 16 * cfg.n_held
+
+
+def row_tile(n_tokens: int, cfg: ExpertsConfig) -> int:
+    """Rows of one visit of the experts' kernel: `ROW_TILE` where the
+    rows are `few_a_group`, else what the pass's rows call for
+    (ops/grouped_swiglu.py `visit_rows`)."""
+    return ROW_TILE if few_a_group(n_tokens, cfg) \
+        else visit_rows(min(_local_rows(n_tokens, cfg), cfg.tile_rows))
 
 
 def tile_rows(n_tokens: int, cfg: ExpertsConfig) -> int:
     """Rows of grouped order one pass of `routed_experts` takes:
     `_local_rows`, so that a prefill's local rows fit one pass and the
-    held weights are read once a layer, at most ``cfg.tile_rows``.
+    held weights are read once a layer, in whole row tiles (`row_tile`),
+    at most ``cfg.tile_rows`` (one tile at the least).
 
     Where the rows are `few_a_group`, every touched group begins on a
     row tile of its own: the pass holds `ROW_TILE` - 1 rows more for
-    each group that can have a row.  Else the count is rounded to what
-    the compiler's grouped matmul does well with: it takes its row
-    tile, its M, from the rows it is handed, and multiplies a whole
-    tile a group whatever rows the group has (PERF.md, PR 40: a call
-    over 12 groups of ~107 rows takes 1.36 ms handed 1,536 rows, a
-    multiple of 512, 1.00 ms handed 1,792, and 1.06 handed 1,408 or
-    1,664): up to 512 whole tiles of 128, never 512 itself; beyond, an
-    odd multiple of 256."""
-    rows = _local_rows(n_tokens, cfg)
+    each group that can have a row.  Else no row is added: the groups
+    begin where they begin."""
+    rows, tm = _local_rows(n_tokens, cfg), row_tile(n_tokens, cfg)
     if few_a_group(n_tokens, cfg):
         rows += (ROW_TILE - 1) * min(cfg.n_held, rows)
-        tiles = min(-(-rows // ROW_TILE), cfg.tile_rows // ROW_TILE)
-        return max(tiles, 1) * ROW_TILE
-    if rows < 512:
-        rows = -(-rows // 128) * 128
-    else:
-        rows = (rows + 255) // 512 * 512 + 256
-    return min(rows, cfg.tile_rows)
+    return max(min(-(-rows // tm), cfg.tile_rows // tm), 1) * tm
 
 
 def _sorted_whole(p, x, local, w, counts, cfg: ExpertsConfig, layer):
@@ -323,9 +328,9 @@ def _walked(p, x, local, w, counts, cfg: ExpertsConfig, layer, base):
     Where the rows are `few_a_group`, grouped order leaves room: every
     group begins on a whole row tile (the walks take the groups'
     `starts` as they are given), and the rows between belong to nobody
-    (`_fused`)."""
+    (`_fused`).  `R` is whole row tiles, so a pass begins on one."""
     N, _ = x.shape
-    R = tile_rows(N, cfg)
+    R, tm = tile_rows(N, cfg), row_tile(N, cfg)
     few = few_a_group(N, cfg)
     room = row_tiles(counts) * ROW_TILE if few else counts
     starts = jnp.cumsum(room) - room
@@ -345,11 +350,7 @@ def _walked(p, x, local, w, counts, cfg: ExpertsConfig, layer, base):
         lo = i * R
         xs = dispatch(x, local, starts, lo, rows=R)
         sizes = jnp.clip(ends - lo, 0, R) - jnp.clip(starts - lo, 0, R)
-        if few:
-            out = fused(xs, p, sizes, cfg.dtype, layer)
-        else:
-            out = slabs(_grouped(rows_of(xs).astype(cfg.dtype), p, sizes,
-                                 cfg.dtype, layer))
+        out = fused(xs, p, sizes, cfg.dtype, layer, tm, few)
         return combine(y, out, local, w, starts, lo)
 
     last = jnp.max(jnp.where(counts > 0, ends, 0))
@@ -397,7 +398,8 @@ def routed_experts(p, x, chosen, w, cfg: ExpertsConfig, valid=None,
         jnp.sum(load),
         jnp.sum(counts > 0).astype(jnp.float32),
         jnp.where(mean > 0, jnp.max(load) / jnp.maximum(mean, 1e-9), 0.0),
-        jnp.sum(row_tiles(counts)).astype(jnp.float32)])
+        jnp.sum(visits(counts, row_tile(N, cfg),
+                       few_a_group(N, cfg))).astype(jnp.float32)])
     return y, stats
 
 

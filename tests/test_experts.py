@@ -179,21 +179,53 @@ def test_the_counters_count_the_held_experts_load():
                                counts.max() / counts.mean(), rtol=1e-6)
 
 
-@pytest.mark.parametrize("n_tokens,want", [(64, 120), (8192, 2304),
-                                           (1024, 384), (5120, 1792),
-                                           (2, 8)])
-def test_tile_rows_follow_the_chips_share(n_tokens, want):
+def _old_rounding(rows: int) -> int:
+    """What a prefill's pass held while the compiler's grouped matmul
+    took it (PR 40 to PR 46): up to 512 whole tiles of 128; beyond, an
+    odd multiple of 256."""
+    return -(-rows // 128) * 128 if rows < 512 \
+        else (rows + 255) // 512 * 512 + 256
+
+
+@pytest.mark.parametrize("n_tokens,want,tall", [
+    (64, 120, 8), (8192, 2304, 256), (1024, 384, 128), (5120, 1536, 256),
+    (3072, 1024, 256), (2048, 768, 256), (2, 8, 8)])
+def test_tile_rows_follow_the_chips_share(n_tokens, want, tall):
     """A decode wave's 16 expected rows are 32 with their margin, few a
     group: each of the 12 experts begins on a row tile of its own, 7
-    rows more apiece, in whole tiles (120).  A prefill's rows go
-    through the compiler's grouped matmul: an 8k prefill's 2,048 take
-    2,304 in one pass, and a count past 512 is an odd multiple of 256,
-    never a multiple of 512 (its row tile would be 512); never more
-    rows than the tokens can send (2 tokens x 8 choices: one tile)."""
+    rows more apiece, in whole tiles of 8 (120).  A prefill's rows lie
+    end to end in whole tall tiles and no row is added for a group: an
+    8k prefill's 2,228 take 2,304 in one pass, tiles of 256, of 128
+    where the pass has fewer than four of those; never more rows than
+    while the compiler's grouped matmul took them, and never more than
+    the tokens can send (2 tokens x 8 choices: one tile)."""
     cfg = ex.ExpertsConfig(d_model=8, d_expert=8, n_routed=384, top_k=8,
                            held=ex.held_range(0, 12))
     assert ex.tile_rows(n_tokens, cfg) == want
+    assert ex.row_tile(n_tokens, cfg) == tall and want % tall == 0
     assert ex.few_a_group(n_tokens, cfg) == (n_tokens <= 64)
+    if n_tokens > 64:
+        assert ex._local_rows(n_tokens, cfg) <= want <= _old_rounding(
+            ex._local_rows(n_tokens, cfg))
+
+
+@pytest.mark.parametrize("n_tokens,want", [
+    (64, 2304), (512, 4096), (1024, 8192), (2048, 16384), (4096, 32768),
+    (8192, 33024)])
+def test_lagunas_pass_holds_no_more_rows_than_it_did(n_tokens, want):
+    """Laguna-XS.2's layer (256 experts of (2,048, 512), all held, 8 a
+    token; `LagunaConfig.moe_tile_rows` 33,024): a prefill bucket's
+    pass is its assignments in tiles of 256, one pass up to 4,096
+    tokens and two at 8,192, the first of 33,024 rows as before."""
+    cfg = ex.ExpertsConfig(d_model=2048, d_expert=512, n_routed=256,
+                           top_k=8, held=None, param_dtype=jnp.bfloat16,
+                           tile_rows=33_024)
+    assert ex.tile_rows(n_tokens, cfg) == want
+    few = n_tokens == 64
+    assert ex.few_a_group(n_tokens, cfg) == few
+    assert ex.row_tile(n_tokens, cfg) == (8 if few else 256)
+    if not few:
+        assert want <= min(_old_rounding(n_tokens * 8), 33_024)
 
 
 #: the serving path (`tiled=True`: the kernels of ops/moe_dispatch.py
@@ -291,10 +323,18 @@ def test_the_served_path_is_the_one_that_differentiates(whole, name, runs):
     assert ex.few_a_group(n, cfg) == (
         name.startswith(("a_short_wave", "local_rows_"))
         and name != "local_rows_over_many_tiles")
-    # the row tiles the experts' rows fill
+    # the visits the kernel makes the experts' rows: the row tiles they
+    # fill where every group begins on its own, else the tall tiles
+    # they lie in, end to end
     per = np.asarray([(np.asarray(chosen)[local] == e).sum()
                       for e in cfg.held_ids])
-    assert float(stats[3]) == (-(-per // ex.ROW_TILE)).sum()
+    if ex.few_a_group(n, cfg):
+        assert float(stats[3]) == (-(-per // ex.ROW_TILE)).sum()
+    else:
+        tall = ex.row_tile(n, cfg)
+        ends = np.cumsum(per)
+        lain = (ends - 1) // tall - (ends - per) // tall + 1
+        assert float(stats[3]) == lain[per > 0].sum()
     if name.startswith("local_rows_"):
         rows = ex.tile_rows(n, cfg)
         passes = -(-int(local.sum()) // rows)

@@ -130,23 +130,24 @@ def test_the_chips_decode_step_walks_the_pool_under_attn_full(
     assert scopes.GQA_PAGED_DECODE not in scopes.DEVICE_SCOPES
 
 
-@pytest.mark.parametrize("rows,fused", [(2, True), (64, False)],
+@pytest.mark.parametrize("rows,few", [(2, True), (64, False)],
                          ids=["a_decode_wave", "a_long_prefill"])
 def test_the_experts_kernel_is_under_moe_experts(params, monkeypatch,
-                                                 rows, fused):
+                                                 rows, few):
     """On the chip (steered) a decode wave's expert layers hold ONE
     ``grouped_swiglu`` each between ``moe_dispatch`` and
     ``moe_combine``, all three under ``moe_experts`` (nothing new reads
     unscoped, and `moe_expert_roofline.offline` divides the time the
-    kernel takes); a prefill that hands an expert many rows keeps the
-    compiler's grouped matmuls."""
+    kernel takes); a prefill that hands an expert many rows holds the
+    same three (its groups end to end under tall row tiles) and no
+    `ragged_dot`."""
     from ray_tpu.models import experts as ex
     from ray_tpu.models.laguna_decode import laguna_paged_prefill
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert ex.few_a_group(rows, CFG.experts) == fused
+    assert ex.few_a_group(rows, CFG.experts) == few
     paged = laguna_init_paged_cache(CFG, 2, num_blocks=20, block_size=16)
-    if fused:
+    if few:
         fn = lambda c, t: laguna_decode_step(params, c, t, CFG)  # noqa: E731
         args = (paged, jnp.zeros((rows,), jnp.int32))
     else:
@@ -173,9 +174,7 @@ def test_the_experts_kernel_is_under_moe_experts(params, monkeypatch,
     experts = [name for name, scope in found
                if scope == scopes.MOE_EXPERTS]
     assert all(scope is not None for _, scope in found), found
-    layer = [scopes.MOE_DISPATCH] + (
-        [scopes.GROUPED_SWIGLU] if fused else ["ragged_dot_general"] * 3
-    ) + [scopes.MOE_COMBINE]
+    layer = [scopes.MOE_DISPATCH, scopes.GROUPED_SWIGLU, scopes.MOE_COMBINE]
     n_sparse = sum(kind == "sparse" for kind in CFG.mlp_types)
     assert n_sparse and experts == layer * n_sparse, experts
     assert scopes.GROUPED_SWIGLU in scopes.KERNELS

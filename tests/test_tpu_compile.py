@@ -540,6 +540,39 @@ def test_grouped_swiglu_compiles_at_the_cells_wave_shapes(stack, rows, tf):
     assert compiled.memory_analysis().temp_size_in_bytes < 1e6
 
 
+@pytest.mark.parametrize("stack,rows,tm", [
+    ((4, 256, 2048, 512), 33024, 256), ((4, 256, 2048, 512), 8192, 256),
+    ((5, 12, 7168, 2048), 2304, 256), ((5, 12, 7168, 2048), 384, 128)],
+    ids=["laguna_8k", "laguna_1k", "kimi_k2_8k", "kimi_k2_1k"])
+def test_grouped_swiglu_compiles_at_the_cells_prefill_shapes(stack, rows, tm):
+    """ops/grouped_swiglu.py with the groups end to end
+    (``aligned=False``) at the two cells' prefill passes, the rows and
+    the tall tile `experts.tile_rows` / `row_tile` give them: a tile's
+    slabs are read and written by strided sublanes (the chip's compiler
+    takes both), the scratch of a 256-row tile of 7,168 columns fits
+    the VMEM asked for.  One Mosaic call; the slabs go in and come out
+    as they lie (the reshape round the call is no copy: no
+    temporary)."""
+    from ray_tpu._private import scopes
+    from ray_tpu.ops.grouped_swiglu import grouped_swiglu, visit_rows
+
+    spec = _one_chip()
+    L, g, d, f = stack
+    assert visit_rows(rows) == tm and rows % tm == 0
+    bf16 = lambda *shape: spec(shape, jnp.bfloat16)   # noqa: E731
+    compiled = jax.jit(
+        lambda *a: grouped_swiglu(*a, tm=tm, aligned=False)).lower(
+        spec((rows, d // 128, 128), jnp.float32), bf16(L, g, d, f),
+        bf16(L, g, d, f), bf16(L, g, f, d), spec((g,), jnp.int32),
+        spec((), jnp.int32)).compile()
+    calls = [line.split(" = ")[0].split("%")[-1]
+             for line in compiled.as_text().splitlines()
+             if MOSAIC_CALL in line]
+    assert [name.split(".")[0] for name in calls] == [
+        scopes.GROUPED_SWIGLU], calls
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e6
+
+
 def _experts_kernels(text: str):
     """(calls of `grouped_swiglu` with their scopes, the compiler's
     ``ragged-dot-*`` kernels with theirs) in a compiled program."""
@@ -553,23 +586,76 @@ def _experts_kernels(text: str):
             [(n, s) for n, s in keyed if "ragged-dot" in n])
 
 
-def test_laguna_prefill_keeps_the_compilers_grouped_matmuls(monkeypatch):
-    """A 1,024-token prefill of the Laguna cell hands each of the 256
-    experts 32 rows or so (`experts.few_a_group` is False): its four
-    expert layers go through `lax.ragged_dot`, and no `grouped_swiglu`
-    is compiled there (PERF.md, PR 46: the timing that kept it)."""
+#: compiled peaks of the cells' 8,192 prefills at 6291e8d (PR 46), whose
+#: passes went through three `lax.ragged_dot` and two re-lays: no
+#: higher since (bytes; my compile for the described v5e, PR 47)
+PEAK_8K_AT_PR46 = {"laguna-xs2.serve-offline-mixed": 13_667_644_928,
+                   "kimi-k2-code.serve-offline-codegen": 14_454_839_808}
+
+
+@pytest.mark.parametrize("t_pad", [1024, 8192])
+def test_laguna_prefill_is_one_grouped_swiglu_a_layer(t_pad, monkeypatch):
+    """A prefill of the Laguna cell hands each of the 256 experts 32
+    rows or so at the 1,024 bucket and ~260 of half of them a pass at
+    8,192 (`experts.few_a_group` is False): its four expert layers are
+    ONE ``grouped_swiglu`` each under ``moe_experts`` (the groups end
+    to end under tall row tiles), and no ``ragged-dot`` is left; the
+    8,192 program's compiled peak is no higher than the parent's, the
+    1,024's far under it."""
     from ray_tpu._private import scopes
     from ray_tpu.models.laguna import laguna_init
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    _, params, cache, programs, _ = _serving_cell(
-        "laguna-xs2.serve-offline-mixed", laguna_init, 1024)
+    cell = "laguna-xs2.serve-offline-mixed"
+    _, params, cache, programs, _ = _serving_cell(cell, laguna_init, t_pad)
     fn, args = programs["prefill"]
-    text = jax.jit(fn, donate_argnums=(1,)).lower(
-        params, cache, *args).compile().as_text()
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args).compile()
+    text = compiled.as_text()
     fused, ragged = _experts_kernels(text)
-    assert not fused, fused
-    assert ragged and {s for _, s in ragged} == {scopes.MOE_EXPERTS}
+    assert not ragged and "ragged-dot" not in text, ragged
+    assert [s for _, s in fused] == [scopes.MOE_EXPERTS] * 4, fused
+    peak = compiled.memory_analysis().peak_memory_in_bytes
+    assert peak <= PEAK_8K_AT_PR46[cell] - (0 if t_pad == 8192 else 5e8)
+
+
+#: the cells' decode steps lowered for the chip, as text without
+#: locations and with each Mosaic call's payload (which holds its
+#: source's lines) taken out, and the payloads' modules without theirs
+#: (`_mosaic_modules`), at 6291e8d (PR 46): a decode wave's experts keep
+#: what PR 46 gave them whatever the prefill's regime is taught
+DECODE_AT_PR46 = {
+    "laguna-xs2.serve-offline-mixed": (
+        "ad6dcf978b31f2ba",
+        ["bbc5fa541589b72ff754835f9938efd788e587c7c997597a754c0e7b6f02af66"]
+        * 4),
+    "kimi-k2-code.serve-offline-codegen": (
+        "2c3a75603c5375b3",
+        ["77f499141965a4f46e3257ef19b01781f61bd3545b3bf02443528d2e59101ca1"]),
+}
+
+
+@pytest.mark.parametrize("cell", DECODE_AT_PR46)
+def test_decode_steps_lower_to_the_text_they_lowered_to(cell, monkeypatch):
+    import hashlib
+
+    from ray_tpu._private import scopes
+    from ray_tpu.models.kimi_k2 import kimi_k2_init
+    from ray_tpu.models.laguna import laguna_init
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    init = laguna_init if cell.startswith("laguna") else kimi_k2_init
+    _, params, cache, programs, _ = _serving_cell(cell, init, 1024)
+    fn, args = programs["decode"]
+    lowered = jax.jit(fn, donate_argnums=(1,)).lower(params, cache, *args)
+    text = lowered.compiler_ir(dialect="stablehlo").operation.get_asm(
+        enable_debug_info=False)
+    text = re.sub(r'backend_config = "(?:[^"\\]|\\.)*"',
+                  'backend_config = ""', text)
+    want_text, want_modules = DECODE_AT_PR46[cell]
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == want_text
+    assert _mosaic_modules(lowered.compile().as_text(),
+                           scopes.GROUPED_SWIGLU) == want_modules
 
 
 @pytest.mark.parametrize("program,t_pad", [("decode", 0), ("prefill", 8192)])
@@ -584,9 +670,9 @@ def test_kimi_k2_programs_fit_the_chip_at_the_published_widths(
     chip's 16: the room left is the reference's at warm-up.  A decode
     wave's experts are ONE kernel, ``grouped_swiglu``
     (ops/grouped_swiglu.py), once in the scan over the expert layers
-    and under ``moe_experts``, and no ``ragged-dot`` is left there; the
-    8,192 prefill's grouped matmuls are the compiler's kernels (XLA's
-    own lowering of ragged_dot), scoped ``moe_experts`` by their name.
+    and under ``moe_experts``, and no ``ragged-dot`` is left there nor
+    in the 8,192 prefill, whose pass is the same kernel with its groups
+    end to end under tall row tiles.
     The rows
     reach them and return through ``moe_dispatch`` and ``moe_combine``
     (ops/moe_dispatch.py), one call each under ``moe_experts``: no
@@ -615,12 +701,8 @@ def test_kimi_k2_programs_fit_the_chip_at_the_published_widths(
     assert memory.alias_size_in_bytes >= 4.29e9      # the pool, in place
     text = compiled.as_text()
     fused, ragged = _experts_kernels(text)
-    if program == "decode":
-        assert not ragged and "ragged-dot" not in text, ragged
-        assert [s for _, s in fused] == [scopes.MOE_EXPERTS], fused
-    else:
-        assert not fused, fused
-        assert ragged and {s for _, s in ragged} == {scopes.MOE_EXPERTS}
+    assert not ragged and "ragged-dot" not in text, ragged
+    assert [s for _, s in fused] == [scopes.MOE_EXPERTS], fused
     rows = [(name.rsplit(".", 1)[0], scope) for name, keyed in
             scopes.scope_map_from_hlo(text).items()
             for key, scope in keyed.items() if "custom-call" in key
@@ -656,9 +738,10 @@ def test_kimi_k2_programs_fit_the_chip_at_the_published_widths(
                                 f"bf16[64,128,{cfg.max_seq}]")):
                 assert " copy(" not in body and " transpose(" not in body, \
                     line
-        # no higher than the parent's, whose jnp walk carried the
-        # expanded keys and three accumulators (PR 33: 14,513,561,088)
-        assert memory.peak_memory_in_bytes <= 14_513_561_088, memory
+        # no higher than PR 46's, whose pass went through three
+        # ragged_dot and two re-lays (PR 33's jnp walk: 14,513,561,088)
+        assert memory.peak_memory_in_bytes <= PEAK_8K_AT_PR46[
+            "kimi-k2-code.serve-offline-codegen"], memory
         return
     walks = {name: scope for name, keyed in
              scopes.scope_map_from_hlo(text).items()
