@@ -1,8 +1,11 @@
 """The generation machinery shared by every decoder family.
 
-Family modules (gpt2_decode, llama_decode) supply their
+Every family's decode module (models/<family>_decode.py) supplies its
 (init_cache_fn, prefill_fn, decode_step_fn) triple; this module owns
-the family-neutral prefill dispatch + sampling scan so fixes land once.
+the family-neutral prefill dispatch + sampling scan (`generator`) and
+what the cache contract below makes common to them, so fixes land once.
+The families whose cache is plain K/V share their programs too
+(kv_decode.py).
 
 Cache contract (vector positions, round 7 — ragged batches decode
 together):
@@ -95,6 +98,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu._private import scopes
+from ray_tpu.models import families
 
 
 #: a recurrent family's paged prefill, ``state[0]``: where the slot's
@@ -598,6 +602,31 @@ def expert_counters(cache):
     return cache.get(EXPERTS)
 
 
+def _positions(batch: int):
+    """What a fresh cache of a family with expert layers holds beside
+    its tensors: the position vectors and the counters, all zeros."""
+    return {"pos": jnp.zeros((batch,), jnp.int32),
+            "start": jnp.zeros((batch,), jnp.int32),
+            EXPERTS: jnp.zeros((len(EXPERT_COUNTERS),), jnp.float32)}
+
+
+def _refuse_mesh(family: str, mesh) -> None:
+    """A cache that is more than K/V has no sharding rule for what it
+    holds beside them: its family's init functions refuse a mesh."""
+    if mesh is not None:
+        kind = families.cache_kind(family)
+        raise ValueError(
+            f"family {family!r} keeps a {kind} cache "
+            f"({families.CACHE_HOLDS[kind]}) and has no sharding for it "
+            f"yet: mesh-sharded caches are refused")
+
+
+def _block_of(cfg, n: int) -> int:
+    """The tile a prefill's blockwise attention walks `n` queries or
+    keys by: ``cfg.attn_block`` where it divides `n`, else `n` whole."""
+    return cfg.attn_block if n % cfg.attn_block == 0 else n
+
+
 def make_vocab_tail_mask(cfg) -> Optional[jnp.ndarray]:
     """Static (padded_vocab,) bool mask, True on the real vocab — built
     ONCE per generation (or jitted serve program) so sampling is a
@@ -868,22 +897,6 @@ def ngram_propose(tokens, k: int, order: int = 2):
     return fallback
 
 
-def scan_prefill(init_cache_fn, decode_step_fn, params, prompt, cfg):
-    """Per-token reference prefill: T0 sequential decode_step dispatches
-    (the pre-round-7 path).  Kept as the numerics oracle for the
-    batched prefill parity tests; equal-length prompts only.  Returns
-    (last_logits (B, padded_vocab), cache)."""
-    B = prompt.shape[0]
-    cache = init_cache_fn(cfg, B)
-
-    def prefill_step(cache, tok):
-        logits, cache = decode_step_fn(params, cache, tok, cfg)
-        return cache, logits
-
-    cache, logits_seq = lax.scan(prefill_step, cache, prompt.T)
-    return logits_seq[-1], cache
-
-
 def generate_with(prefill_fn, decode_step_fn, params,
                   prompt: jnp.ndarray, cfg, *, max_new_tokens: int,
                   lengths: Optional[jnp.ndarray] = None,
@@ -892,10 +905,10 @@ def generate_with(prefill_fn, decode_step_fn, params,
                   key: Optional[jax.Array] = None,
                   kv_layout: str = "dense",
                   kv_block_size: int = 16) -> jnp.ndarray:
-    """The generation loop shared by every decoder family (gpt2,
-    llama): ONE batched prefill dispatch + a sampling scan over the
-    family's decode_step.  prompt (B, T0) int32 → (B, T0 +
-    max_new_tokens) int32; `lengths` (B,) marks ragged LEFT-padded
+    """The generation loop shared by every decoder family (a family's
+    `generate` is `generator`'s): ONE batched prefill dispatch + a
+    sampling scan over the family's decode_step.  prompt (B, T0) int32
+    → (B, T0 + max_new_tokens) int32; `lengths` (B,) marks ragged LEFT-padded
     prompts (row b's real tokens occupy columns [T0 - lengths[b], T0));
     temperature 0 = greedy; top_k/top_p are jit-static sampling
     filters (see sample_token); the whole program jits (static cfg /
@@ -932,3 +945,36 @@ def generate_with(prefill_fn, decode_step_fn, params,
     (_, _), new_tokens = lax.scan(gen_step, (cache, last_logits), keys)
     return jnp.concatenate([prompt, new_tokens.T.astype(prompt.dtype)],
                            axis=1)
+
+
+def generator(prefill_fn, decode_step_fn, init_cache_fn=None):
+    """A family's public `generate`: `generate_with` over its
+    `prefill_fn` and `decode_step_fn`, taking `generate_with`'s
+    keywords.  A family that hands its `init_cache_fn` too can ingest
+    an equal-length prompt by ``prefill_impl="scan"``, the per-token
+    reference prefill: T0 sequential decode_step dispatches (the
+    pre-round-7 path), kept as the numerics oracle for the batched
+    prefill's parity tests (which it is not for a family whose step
+    leaves an empty row alone: jamba_decode.py)."""
+    def scan_prefill(params, prompt, cfg, *, lengths=None):
+        if lengths is not None or init_cache_fn is None:
+            raise ValueError("prefill_impl='scan' is the equal-length "
+                             "reference path of a family that has one; "
+                             "ragged prompts need the batched prefill")
+
+        def prefill_step(cache, tok):
+            logits, cache = decode_step_fn(params, cache, tok, cfg)
+            return cache, logits
+
+        cache, logits_seq = lax.scan(
+            prefill_step, init_cache_fn(cfg, prompt.shape[0]), prompt.T)
+        return logits_seq[-1], cache
+
+    def generate(params, prompt, cfg, *, prefill_impl: str = "batched",
+                 **how) -> jnp.ndarray:
+        return generate_with(
+            prefill_fn if prefill_impl == "batched" else scan_prefill,
+            decode_step_fn, params, prompt, cfg, **how)
+
+    generate.__doc__ = generate_with.__doc__
+    return generate

@@ -55,6 +55,7 @@ import numpy as np
 from jax import lax
 
 from ray_tpu._private import scopes
+from ray_tpu.models.decode_common import EXPERTS
 from ray_tpu.ops.grouped_swiglu import (ROW_TILE, grouped_swiglu,
                                         row_tiles, visit_rows, visits)
 from ray_tpu.ops.moe_dispatch import (combine_reference,
@@ -415,6 +416,18 @@ def program_counters(cfg: ExpertsConfig, stats):
         jnp.sum(stats[:, 0]), jnp.mean(stats[:, 1]) / cfg.n_held,
         jnp.max(stats[:, 2]),
         jnp.sum(stats[:, 3]) / jnp.maximum(jnp.sum(stats[:, 1]), 1.0)])
+
+
+def _with_counters(cache, cfg, stats):
+    """`cache` with what this program's expert layers did, under
+    `decode_common.EXPERTS`: `program_counters` of a family config's
+    ``cfg.experts`` and the layers' `stats` (None, or no row, for a
+    program without an expert layer), in the experts' scope."""
+    if stats is not None and not stats.shape[0]:
+        stats = None
+    with jax.named_scope(scopes.MOE_EXPERTS):
+        cache[EXPERTS] = program_counters(cfg.experts, stats)
+    return cache
 
 
 def moe_layer(p, x32, cfg: ExpertsConfig, valid=None, tiled: bool = True):

@@ -1,7 +1,9 @@
 """The decoder families a serving engine can be built over: one row each.
 
 A family is `models/<family>.py` (config, init, forward), its decode
-programs in `models/<family>_decode.py`, and a row of `FAMILIES` below.
+programs in `models/<family>_decode.py` (for a family whose cache is
+plain K/V: its `kv_decode.Block` and kv_decode.py's programs bound to
+it), and a row of `FAMILIES` below.
 The serving layer (serve/llm.py, serve/engine.py) asks `family(name)`
 for the programs and `cache_kind(name)` for what the cache holds, and
 names no family itself.
@@ -35,6 +37,15 @@ KV = "kv"
 RECURRENT = "kv+recurrent"
 LATENT = "latent"
 WINDOWED = "kv+window"
+#: what a cache that is not plain K/V keeps, as a refusal words it
+#: (of an engine option: serve/llm.py; of a mesh: decode_common.py)
+CACHE_HOLDS = {
+    RECURRENT: "one recurrent state per slot beside the K/V pool",
+    LATENT: "a latent pool: one latent and one rotary key a token, no K "
+            "or V per head",
+    WINDOWED: "a ring of each window layer's last K/V rows per slot "
+              "beside the full layers' K/V pool",
+}
 #: the kinds that keep state per SLOT beside the pool: their paged
 #: prefill takes `state`, and a prefix is reused from a snapshot of it
 PER_SLOT_STATE = frozenset((RECURRENT, WINDOWED))

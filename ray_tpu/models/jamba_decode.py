@@ -44,8 +44,8 @@ from jax import lax
 from ray_tpu._private import scopes
 from ray_tpu.models.decode_common import (NO_SNAPSHOT, STATE_FROM_SLOT,
                                           STATE_FROM_ZERO, PagedKV,
-                                          dense_layer_kv, generate_with,
-                                          is_paged, slot_mask)
+                                          _refuse_mesh, dense_layer_kv,
+                                          generator, is_paged, slot_mask)
 from ray_tpu.models.jamba import (JambaConfig, attn_out, embed, layer_at,
                                   lm_logits, mamba_mix, mlp_residual, qkv,
                                   rmsnorm, walk_layers, zero_recurrent)
@@ -64,7 +64,7 @@ def jamba_init_cache(cfg: JambaConfig, batch: int,
                      mesh=None) -> Dict[str, jnp.ndarray]:
     """Dense cache: (n_attn, B, S, n_kv_head, hd) K/V, the recurrent
     state of `batch` sequences, position vectors."""
-    _refuse_mesh(mesh)
+    _refuse_mesh("jamba", mesh)
     conv, ssm = zero_recurrent(cfg, batch)
     return dict(_kv_tensors(cfg, batch, cfg.max_seq), conv=conv, ssm=ssm,
                 pos=jnp.zeros((batch,), jnp.int32),
@@ -77,7 +77,7 @@ def jamba_init_paged_cache(cfg: JambaConfig, batch: int, *,
     """Block-pool cache: K/V pools of the attention layers, per-row
     block tables, the rows' recurrent state and a snapshot pool of one
     entry a row."""
-    _refuse_mesh(mesh)
+    _refuse_mesh("jamba", mesh)
     if cfg.max_seq % block_size:
         raise ValueError(f"max_seq={cfg.max_seq} must be a multiple of "
                          f"block_size={block_size}")
@@ -89,13 +89,6 @@ def jamba_init_paged_cache(cfg: JambaConfig, batch: int, *,
                     (batch, cfg.max_seq // block_size), jnp.int32),
                 pos=jnp.zeros((batch,), jnp.int32),
                 start=jnp.zeros((batch,), jnp.int32))
-
-
-def _refuse_mesh(mesh) -> None:
-    if mesh is not None:
-        raise ValueError(
-            "family jamba keeps recurrent state beside its K/V pool and "
-            "has no sharding for it yet: mesh-sharded caches are refused")
 
 
 # -- the recurrent state, one layer of it at a time --------------------------
@@ -348,22 +341,8 @@ def jamba_decode_step(params, cache, tokens, cfg: JambaConfig
     return logits, out
 
 
-def jamba_generate(params, prompt: jnp.ndarray, cfg: JambaConfig, *,
-                   max_new_tokens: int, temperature: float = 1.0,
-                   top_k: int = 0, top_p: float = 1.0,
-                   lengths: Optional[jnp.ndarray] = None,
-                   key: Optional[jax.Array] = None,
-                   kv_layout: str = "dense",
-                   kv_block_size: int = 16) -> jnp.ndarray:
-    """Generation via the shared loop (decode_common.generate_with): one
-    dense prefill, then the decode step scanned.  `lengths` marks
-    LEFT-padded ragged prompts; kv_layout="paged" re-lays the K/V into
-    blocks after the prefill (the recurrent state is per row in both
-    layouts); dense is the paged path's parity oracle."""
-    return generate_with(jamba_prefill, jamba_decode_step, params, prompt,
-                         cfg, max_new_tokens=max_new_tokens,
-                         lengths=lengths, temperature=temperature,
-                         top_k=top_k, top_p=top_p, key=key,
-                         kv_layout=kv_layout,
-                         kv_block_size=kv_block_size)
-
+#: generation via the shared loop (decode_common.generate_with): one
+#: dense prefill, then the decode step scanned.  kv_layout="paged"
+#: re-lays the K/V into blocks after the prefill (the recurrent state is
+#: per row in both layouts); dense is the paged path's parity oracle
+jamba_generate = generator(jamba_prefill, jamba_decode_step)

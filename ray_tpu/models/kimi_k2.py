@@ -501,12 +501,6 @@ def walk_layers(cfg: KimiK2Config, params, x, carry, layer: Callable):
     return x, carry, ys, stats
 
 
-def expert_counters(cfg: KimiK2Config, stats):
-    """One program's `decode_common.EXPERT_COUNTERS` from its expert
-    layers' stats (n_moe, len(experts.STATS))."""
-    return ex.program_counters(cfg.experts, stats if cfg.n_moe else None)
-
-
 @jax.named_scope(scopes.EMBED)
 def embed(params, tokens, cfg: KimiK2Config):
     return params["wte"].astype(cfg.dtype)[tokens]

@@ -29,14 +29,13 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu._private import scopes
-from ray_tpu.models.decode_common import (EXPERT_COUNTERS, EXPERTS,
-                                          PagedKV, dense_layer_kv,
-                                          generate_with, is_paged,
-                                          slot_mask)
+from ray_tpu.models.decode_common import (PagedKV, _block_of, _positions,
+                                          _refuse_mesh, dense_layer_kv,
+                                          generator, is_paged, slot_mask)
+from ray_tpu.models.experts import _with_counters
 from ray_tpu.models.kimi_k2 import (KimiK2Config, attend_absorbed,
                                     attend_expanded, block, embed,
-                                    expand_keys, expand_latents,
-                                    expert_counters, lm_logits,
+                                    expand_keys, expand_latents, lm_logits,
                                     softmax_scale, walk_layers)
 from ray_tpu.ops import mla_flash_prefill as flash
 from ray_tpu.ops.mla_paged_decode import mla_paged_decode, rotary_lanes
@@ -54,25 +53,11 @@ def _latent_tensors(cfg: KimiK2Config, *lead: int):
                              cfg.dtype)}
 
 
-def _positions(batch: int):
-    return {"pos": jnp.zeros((batch,), jnp.int32),
-            "start": jnp.zeros((batch,), jnp.int32),
-            EXPERTS: jnp.zeros((len(EXPERT_COUNTERS),), jnp.float32)}
-
-
-def _refuse_mesh(mesh) -> None:
-    if mesh is not None:
-        raise ValueError(
-            "family kimi_k2 keeps a latent pool, one latent a token "
-            "with no heads axis to split, and has no sharding for it "
-            "yet: mesh-sharded caches are refused")
-
-
 def kimi_k2_init_cache(cfg: KimiK2Config, batch: int,
                        mesh=None) -> Dict[str, jnp.ndarray]:
     """Dense cache: (L, B, S, width) latents and rotary keys, position
     vectors, the last program's expert counters."""
-    _refuse_mesh(mesh)
+    _refuse_mesh("kimi_k2", mesh)
     return dict(_latent_tensors(cfg, batch, cfg.max_seq),
                 **_positions(batch))
 
@@ -82,7 +67,7 @@ def kimi_k2_init_paged_cache(cfg: KimiK2Config, batch: int, *,
                              mesh=None) -> Dict[str, jnp.ndarray]:
     """Block-pool cache: (L, num_blocks, block_size, width) pools and
     per-row block tables."""
-    _refuse_mesh(mesh)
+    _refuse_mesh("kimi_k2", mesh)
     if cfg.max_seq % block_size:
         raise ValueError(f"max_seq={cfg.max_seq} must be a multiple of "
                          f"block_size={block_size}")
@@ -90,11 +75,6 @@ def kimi_k2_init_paged_cache(cfg: KimiK2Config, batch: int, *,
                 block_tables=jnp.zeros(
                     (batch, cfg.max_seq // block_size), jnp.int32),
                 **_positions(batch))
-
-
-def _block_of(cfg: KimiK2Config, n: int) -> int:
-    """``cfg.attn_block`` where it divides `n`, else `n` whole."""
-    return cfg.attn_block if n % cfg.attn_block == 0 else n
 
 
 @jax.named_scope(scopes.MLA)
@@ -222,12 +202,6 @@ def attend_paged(q, ckv_pool, rope_lanes, cache, lidx, p, fresh,
         start=cache["start"])
     return jnp.einsum("bthc,chv->bthv", o_lat[:, None],
                       p["wv_b"].astype(dt))
-
-
-def _with_counters(cache, cfg: KimiK2Config, stats):
-    with jax.named_scope(scopes.MOE_EXPERTS):
-        cache[EXPERTS] = expert_counters(cfg, stats)
-    return cache
 
 
 def kimi_k2_prefill(params, tokens: jnp.ndarray, cfg: KimiK2Config, *,
@@ -395,19 +369,7 @@ def kimi_k2_decode_step(params, cache, tokens, cfg: KimiK2Config
     return logits, _with_counters(out, cfg, stats)
 
 
-def kimi_k2_generate(params, prompt: jnp.ndarray, cfg: KimiK2Config, *,
-                     max_new_tokens: int, temperature: float = 1.0,
-                     top_k: int = 0, top_p: float = 1.0,
-                     lengths: Optional[jnp.ndarray] = None,
-                     key: Optional[jax.Array] = None,
-                     kv_layout: str = "dense",
-                     kv_block_size: int = 16) -> jnp.ndarray:
-    """Generation via the shared loop (decode_common.generate_with): one
-    dense prefill, then the decode step scanned; the serve engine's
-    parity oracle."""
-    return generate_with(kimi_k2_prefill, kimi_k2_decode_step, params,
-                         prompt, cfg, max_new_tokens=max_new_tokens,
-                         lengths=lengths, temperature=temperature,
-                         top_k=top_k, top_p=top_p, key=key,
-                         kv_layout=kv_layout,
-                         kv_block_size=kv_block_size)
+#: generation via the shared loop (decode_common.generate_with): one
+#: dense prefill, then the decode step scanned; the serve engine's
+#: parity oracle
+kimi_k2_generate = generator(kimi_k2_prefill, kimi_k2_decode_step)

@@ -409,12 +409,6 @@ def walk_layers(cfg: LagunaConfig, params, x, layer: Callable):
     return x, jnp.stack(stats) if stats else None
 
 
-def expert_counters(cfg: LagunaConfig, stats):
-    """One program's `decode_common.EXPERT_COUNTERS` from its expert
-    layers' stats (n_sparse, len(experts.STATS))."""
-    return ex.program_counters(cfg.experts, stats)
-
-
 def causal_mask(T: int, kind: str, cfg: LagunaConfig):
     """(T, T) bool: what position i of a layer of `kind` attends."""
     i = jnp.arange(T)[:, None]

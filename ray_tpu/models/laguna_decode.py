@@ -58,16 +58,16 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu._private import scopes
-from ray_tpu.models.decode_common import (EXPERT_COUNTERS, EXPERTS,
-                                          NO_SNAPSHOT, STATE_FROM_ZERO,
-                                          PagedKV, generate_with,
+from ray_tpu.models.decode_common import (NO_SNAPSHOT, STATE_FROM_ZERO,
+                                          PagedKV, _block_of, _positions,
+                                          _refuse_mesh, generator,
                                           is_paged, slot_mask)
 from ray_tpu.ops.gqa_paged_decode import (gqa_paged_decode,
                                           gqa_paged_decode_reference)
+from ray_tpu.models.experts import _with_counters
 from ray_tpu.models.laguna import (ATTN_SCOPE, FULL, WINDOW, LagunaConfig,
                                    attend_masked, block, causal_mask,
-                                   embed, expert_counters, lm_logits,
-                                   walk_layers)
+                                   embed, lm_logits, walk_layers)
 
 __all__ = ["laguna_init_cache", "laguna_init_paged_cache",
            "laguna_prefill", "laguna_paged_prefill", "laguna_decode_step",
@@ -83,25 +83,11 @@ def _tensors(cfg: LagunaConfig, batch: int, *lead: int):
             "wv": jnp.zeros(ring, cfg.dtype)}
 
 
-def _positions(batch: int):
-    return {"pos": jnp.zeros((batch,), jnp.int32),
-            "start": jnp.zeros((batch,), jnp.int32),
-            EXPERTS: jnp.zeros((len(EXPERT_COUNTERS),), jnp.float32)}
-
-
-def _refuse_mesh(mesh) -> None:
-    if mesh is not None:
-        raise ValueError(
-            "family laguna keeps a ring of K/V rows per slot beside its "
-            "K/V pool and has no sharding for it yet: mesh-sharded "
-            "caches are refused")
-
-
 def laguna_init_cache(cfg: LagunaConfig, batch: int,
                       mesh=None) -> Dict[str, jnp.ndarray]:
     """Dense cache: (n_full, B, S, kv_width) K/V, the window layers'
     rings of `batch` sequences, position vectors, expert counters."""
-    _refuse_mesh(mesh)
+    _refuse_mesh("laguna", mesh)
     return dict(_tensors(cfg, batch, batch, cfg.max_seq),
                 **_positions(batch))
 
@@ -111,7 +97,7 @@ def laguna_init_paged_cache(cfg: LagunaConfig, batch: int, *,
                             mesh=None) -> Dict[str, jnp.ndarray]:
     """Block-pool cache: the full layers' K/V pools and per-row block
     tables, the rows' rings and a snapshot pool of one entry a row."""
-    _refuse_mesh(mesh)
+    _refuse_mesh("laguna", mesh)
     if cfg.max_seq % block_size:
         raise ValueError(f"max_seq={cfg.max_seq} must be a multiple of "
                          f"block_size={block_size}")
@@ -150,11 +136,6 @@ def _rows_values(e, v, cfg: LagunaConfig):
         jnp.einsum("bgs,bsd->bgd", e[:, g * G:(g + 1) * G],
                    _head(v, g, cfg), preferred_element_type=jnp.float32)
         for g in range(cfg.n_kv_head)], axis=1)
-
-
-def _block_of(cfg: LagunaConfig, n: int) -> int:
-    """``cfg.attn_block`` where it divides `n`, else `n` whole."""
-    return cfg.attn_block if n % cfg.attn_block == 0 else n
 
 
 def attend_banded(q, k, v, first, last, cfg: LagunaConfig, scope: str):
@@ -264,12 +245,6 @@ def _attend_paged(q, pools, f: int, cache, fresh, cfg: LagunaConfig,
     return walk(q, *pools, cache["block_tables"], cache["pos"], f, fresh,
                 n_kv_head=cfg.n_kv_head,
                 scale=1.0 / math.sqrt(cfg.head_dim), start=cache["start"])
-
-
-def _with_counters(cache, cfg: LagunaConfig, stats):
-    with jax.named_scope(scopes.MOE_EXPERTS):
-        cache[EXPERTS] = expert_counters(cfg, stats)
-    return cache
 
 
 # -- the programs -------------------------------------------------------------
@@ -517,21 +492,8 @@ def laguna_decode_step(params, cache, tokens, cfg: LagunaConfig
     return logits, _with_counters(out, cfg, stats)
 
 
-def laguna_generate(params, prompt: jnp.ndarray, cfg: LagunaConfig, *,
-                    max_new_tokens: int, temperature: float = 1.0,
-                    top_k: int = 0, top_p: float = 1.0,
-                    lengths: Optional[jnp.ndarray] = None,
-                    key: Optional[jax.Array] = None,
-                    kv_layout: str = "dense",
-                    kv_block_size: int = 16) -> jnp.ndarray:
-    """Generation via the shared loop (decode_common.generate_with): one
-    dense prefill, then the decode step scanned; the serve engine's
-    parity oracle.  kv_layout="paged" re-lays the full layers' K/V into
-    blocks after the prefill (the rings are per row in both
-    layouts)."""
-    return generate_with(laguna_prefill, laguna_decode_step, params,
-                         prompt, cfg, max_new_tokens=max_new_tokens,
-                         lengths=lengths, temperature=temperature,
-                         top_k=top_k, top_p=top_p, key=key,
-                         kv_layout=kv_layout,
-                         kv_block_size=kv_block_size)
+#: generation via the shared loop (decode_common.generate_with): one
+#: dense prefill, then the decode step scanned; the serve engine's
+#: parity oracle.  kv_layout="paged" re-lays the full layers' K/V into
+#: blocks after the prefill (the rings are per row in both layouts)
+laguna_generate = generator(laguna_prefill, laguna_decode_step)

@@ -87,16 +87,6 @@ class SpecConfig:
                 f"ngram_order must be >= 1, got {self.ngram_order}")
 
 
-#: what a cache that is not plain K/V keeps, as a refusal words it
-_CACHE_HOLDS = {
-    families.RECURRENT: "one recurrent state per slot beside the K/V pool",
-    families.LATENT: "a latent pool: one latent and one rotary key a "
-                     "token, no K or V per head",
-    families.WINDOWED: "a ring of each window layer's last K/V rows per "
-                       "slot beside the full layers' K/V pool",
-}
-
-
 def _refuse_for_recurrent(family, *, spec_decode, kv_host_tier_bytes,
                           role, mesh) -> None:
     """What cannot carry a cache that is not plain K/V yet refuses,
@@ -116,7 +106,7 @@ def _refuse_for_recurrent(family, *, spec_decode, kv_host_tier_bytes,
         if on:
             raise ValueError(
                 f"family {family!r} keeps a {kind} cache "
-                f"({_CACHE_HOLDS[kind]}), "
+                f"({families.CACHE_HOLDS[kind]}), "
                 f"which {option} cannot carry yet: refused")
 
 
@@ -157,7 +147,7 @@ class EngineOptions:
     def __post_init__(self):
         if self.family not in families.FAMILIES:
             raise ValueError(f"unknown LM family {self.family!r}")
-        if families.cache_kind(self.family) in _CACHE_HOLDS:
+        if families.cache_kind(self.family) in families.CACHE_HOLDS:
             _refuse_for_recurrent(
                 self.family, spec_decode=self.spec_decode,
                 kv_host_tier_bytes=self.kv_host_tier_bytes,

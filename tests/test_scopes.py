@@ -1,13 +1,16 @@
 """Named scopes inside the jitted programs (``_private/scopes.py``).
 
 A scope is metadata on a program's instructions.  These tests read the
-lowered text of the GPT-2 decode step, paged prefill and train step at
-nano size and hold the naming to its coverage; they read the compiled
+lowered text of the decode step, paged prefill and verify step of both
+dense-K/V families (models/kv_decode.py over the GPT-2 and the llama
+block) and of GPT-2's train step at nano size and hold the naming to
+its coverage; they read the compiled
 text through ``scope_map_from_hlo`` and the program registry, which is
 how the benchmark's readers join a trace's op events to a scope; and
 they check that the Pallas flash kernels carry names of their own."""
 
 import collections
+import functools
 import re
 
 import pytest
@@ -18,62 +21,71 @@ import optax  # noqa: E402
 
 from ray_tpu._private import scopes  # noqa: E402
 from ray_tpu._private.device_stats import ProgramRegistry  # noqa: E402
-from ray_tpu.models import gpt2_config, gpt2_init, gpt2_loss  # noqa: E402
+from ray_tpu.models import families, gpt2_loss  # noqa: E402
 from ray_tpu.models.decode_common import sample_token  # noqa: E402
-from ray_tpu.models.gpt2_decode import (decode_step, init_cache,  # noqa: E402
-                                        init_paged_cache, paged_prefill,
-                                        verify_step)
 from ray_tpu.train.jax_trainer import jax_utils  # noqa: E402
 
-CFG = gpt2_config("nano", max_seq=64, use_flash=False)
 _LOC_DEF = re.compile(r'^#loc(\d+) = loc\("([^"]*)"', re.M)
 _OP = re.compile(r"(stablehlo\.[\w.]+|\bcall @\w+).*loc\(#loc(\d+)\)\s*$")
 
 
+@functools.lru_cache(maxsize=None)
+def _serving(family):
+    """(cfg, params, fresh paged cache, the programs as the engine
+    wraps them) of a family at nano size."""
+    fam = families.family(family)
+    cfg = fam.config("nano", max_seq=64, use_flash=False)
+
+    def paged_cache():
+        return fam.init_paged_cache(cfg, 2, num_blocks=8, block_size=16)
+
+    def pool_step(p, cache, toks, key):
+        logits, cache = fam.step(p, cache, toks, cfg)
+        return sample_token(logits, key, 0.0, None), cache
+
+    def prefill_sample(p, cache, toks, row_bt, key):
+        logits, cache = fam.paged_prefill(p, cache, toks, cfg,
+                                          row_bt=row_bt, prefix_len=0,
+                                          n_tail=5, slot=0)
+        return sample_token(logits[None], key, 0.0, None), cache
+
+    def verify(p, cache, block):
+        return fam.verify(p, cache, block, cfg)
+
+    return (cfg, fam.init(jax.random.PRNGKey(0), cfg), paged_cache,
+            {"pool_step": pool_step, "prefill_sample": prefill_sample,
+             "verify": verify, "init_cache": fam.init_cache})
+
+
 @pytest.fixture(scope="module")
 def params():
-    return gpt2_init(jax.random.PRNGKey(0), CFG)
-
-
-def _paged_cache():
-    return init_paged_cache(CFG, 2, num_blocks=8, block_size=16)
-
-
-def pool_step(p, cache, toks, key):
-    logits, cache = decode_step(p, cache, toks, CFG)
-    return sample_token(logits, key, 0.0, None), cache
-
-
-def prefill_sample(p, cache, toks, row_bt, key):
-    logits, cache = paged_prefill(p, cache, toks, CFG, row_bt=row_bt,
-                                  prefix_len=0, n_tail=5, slot=0)
-    return sample_token(logits[None], key, 0.0, None), cache
-
-
-def verify(p, cache, block):
-    return verify_step(p, cache, block, CFG)
+    return _serving("gpt2")[1]
 
 
 def _train_step():
     tx = optax.adamw(1e-3)
     step = jax_utils.build_train_step(
-        lambda p, b: gpt2_loss(p, b, CFG), tx, telemetry=False)
+        lambda p, b: gpt2_loss(p, b, _serving("gpt2")[0]), tx,
+        telemetry=False)
     return step, tx
 
 
-def _lowered(name, params):
+def _lowered(name, family="gpt2"):
+    cfg, params, paged_cache, fns = _serving(family)
     key = jax.random.PRNGKey(1)
     i32 = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
     if name == "decode_step":
-        return jax.jit(pool_step).lower(params, _paged_cache(), i32(2), key)
+        return jax.jit(fns["pool_step"]).lower(params, paged_cache(),
+                                               i32(2), key)
     if name == "decode_step_dense":
-        return jax.jit(pool_step).lower(params, init_cache(CFG, 2),
-                                        i32(2), key)
+        return jax.jit(fns["pool_step"]).lower(
+            params, fns["init_cache"](cfg, 2), i32(2), key)
     if name == "paged_prefill":
-        return jax.jit(prefill_sample).lower(
-            params, _paged_cache(), i32(1, 16), i32(4), key)
+        return jax.jit(fns["prefill_sample"]).lower(
+            params, paged_cache(), i32(1, 16), i32(4), key)
     if name == "verify_step":
-        return jax.jit(verify).lower(params, _paged_cache(), i32(2, 3))
+        return jax.jit(fns["verify"]).lower(params, paged_cache(),
+                                            i32(2, 3))
     step, tx = _train_step()
     return step.lower(params, tx.init(params), {"tokens": i32(4, 33)})
 
@@ -105,21 +117,23 @@ def _op_scopes(lowered):
     return out
 
 
-@pytest.mark.parametrize("program,expected", [
-    ("decode_step", {"embed", "ln", "attn", "kv_pool", "mlp", "lm_head",
-                     "sample", "layer_scan"}),
-    ("decode_step_dense", {"embed", "ln", "attn", "kv_pool", "mlp",
-                           "lm_head", "sample", "layer_scan"}),
-    ("paged_prefill", {"embed", "ln", "attn", "kv_pool", "mlp",
-                       "lm_head", "sample", "layer_scan"}),
-    ("verify_step", {"embed", "ln", "attn", "kv_pool", "mlp", "lm_head",
-                     "layer_scan"}),
-    ("train_step", {"embed", "ln", "attn", "mlp", "lm_head_ce",
-                    "loss_and_grad", "optimizer"}),
-])
-def test_at_most_a_tenth_of_a_program_is_unscoped(program, expected,
-                                                  params):
-    ops = _op_scopes(_lowered(program, params))
+#: what every serving program of a dense-K/V family names
+_SERVING_SCOPES = {"embed", "ln", "attn", "kv_pool", "mlp", "lm_head",
+                   "layer_scan"}
+
+
+@pytest.mark.parametrize("family,program,expected", [
+    (family, program, _SERVING_SCOPES | also)
+    for family in ("gpt2", "llama")
+    for program, also in (("decode_step", {"sample"}),
+                          ("decode_step_dense", {"sample"}),
+                          ("paged_prefill", {"sample"}),
+                          ("verify_step", set()))
+] + [("gpt2", "train_step", {"embed", "ln", "attn", "mlp", "lm_head_ce",
+                             "loss_and_grad", "optimizer"})])
+def test_at_most_a_tenth_of_a_program_is_unscoped(family, program,
+                                                  expected):
+    ops = _op_scopes(_lowered(program, family))
     assert len(ops) > 100
     found = collections.Counter(s for _, s in ops)
     assert set(found) - {None} == expected
@@ -154,7 +168,7 @@ def test_innermost_scope(op_name, scope):
 
 
 def test_scope_map_from_compiled_text_picks_the_innermost(params):
-    text = _lowered("train_step", params).compile().as_text()
+    text = _lowered("train_step").compile().as_text()
     assert scopes.hlo_module_name(text) == "jit_step"
     found = scopes.scope_map_from_hlo(text)
     assert {"attn", "mlp", "ln", "lm_head_ce", "optimizer",
@@ -185,6 +199,8 @@ def test_a_second_signature_brings_a_map_of_its_own(params, monkeypatch):
     scope, and a name that checks under no key finds none."""
     monkeypatch.setenv("RAYTPU_DEVICE_STATS_COST", "1")  # conftest: 0
     reg = ProgramRegistry()
+    _, _, _paged_cache, fns = _serving("gpt2")
+    prefill_sample = fns["prefill_sample"]
     fn = reg.instrument("serve.prefill", jax.jit(prefill_sample))
     key = jax.random.PRNGKey(1)
     texts = []
