@@ -59,6 +59,13 @@ MOE_EXPERTS = "moe_experts"
 #: scores, per-head gate and output projection
 ATTN_FULL = "attn_full"
 ATTN_WINDOW = "attn_window"
+#: a linear-attention layer whose state is a matrix a head
+#: (models/solar_open2.py, ops/kda.py): its projections, convolutions,
+#: gates, the delta rule, the output norm and output projection
+ATTN_LINEAR = "attn_linear"
+#: reads and writes of such layers' per-slot matrices and convolution
+#: windows and of their snapshot pool: ``ssm_state``'s twin
+LINEAR_STATE = "linear_state"
 #: the decode programs' scan over layers: what no inner scope claims is
 #: the scan's own plumbing (slicing the stacked weights, stacking the
 #: per-layer K/V it returns)
@@ -77,7 +84,8 @@ REWRITTEN = {"ragged-dot": MOE_EXPERTS}
 DEVICE_SCOPES = frozenset((EMBED, ATTN, MLP, LN, LM_HEAD_CE, LM_HEAD,
                            KV_POOL, SAMPLE, SSM, SSM_STATE, MLA,
                            MOE_ROUTER, MOE_EXPERTS, ATTN_FULL, ATTN_WINDOW,
-                           LAYER_SCAN, LOSS_AND_GRAD, OPTIMIZER))
+                           ATTN_LINEAR, LINEAR_STATE, LAYER_SCAN,
+                           LOSS_AND_GRAD, OPTIMIZER))
 
 # -- Pallas kernel names (``pallas_call(name=)`` in ops/*.py) ----------------
 FLASH_FWD, FLASH_DQ, FLASH_DKV = "flash_fwd", "flash_dq", "flash_dkv"

@@ -20,7 +20,8 @@ from typing import Any, Callable, Dict, Optional, Tuple
 #: what a family's cache holds.  KV: a slot's past is its K/V rows,
 #: which every engine feature can move (rewind by position, spill and
 #: restore by block, hand off).  RECURRENT: some layers keep one state
-#: per sequence beside the K/V (models/jamba_decode.py), and the paged
+#: per sequence beside the K/V (models/jamba_decode.py: a vector a
+#: channel; models/solar_open2_decode.py: a matrix a head), and the paged
 #: prefill takes one more argument, `state` (decode_common.py: where the
 #: slot's state starts and which snapshot it leaves); what cannot carry
 #: that state yet is refused when the engine's options are checked.
@@ -143,11 +144,28 @@ def _laguna() -> Dict[str, Any]:
         init_paged_cache=m.laguna_init_paged_cache)
 
 
+def _solar_open2() -> Dict[str, Any]:
+    from ray_tpu.models import solar_open2_decode as m
+    from ray_tpu.models.solar_open2 import (solar_open2_config,
+                                            solar_open2_init,
+                                            solar_open2_logical_axes)
+
+    return dict(
+        config=solar_open2_config, init=solar_open2_init,
+        logical_axes=solar_open2_logical_axes,
+        generate=m.solar_open2_generate, prefill=m.solar_open2_prefill,
+        paged_prefill=m.solar_open2_paged_prefill,
+        step=m.solar_open2_decode_step, verify=None,
+        init_cache=m.solar_open2_init_cache,
+        init_paged_cache=m.solar_open2_init_paged_cache)
+
+
 #: family -> (what its cache holds, loader of its programs)
 FAMILIES: Dict[str, Tuple[str, Callable[[], Dict[str, Any]]]] = {
     "gpt2": (KV, _gpt2), "llama": (KV, _llama),
     "jamba": (RECURRENT, _jamba), "kimi_k2": (LATENT, _kimi_k2),
-    "laguna": (WINDOWED, _laguna)}
+    "laguna": (WINDOWED, _laguna),
+    "solar_open2": (RECURRENT, _solar_open2)}
 
 
 def cache_kind(name: str) -> Optional[str]:

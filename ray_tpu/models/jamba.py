@@ -405,6 +405,29 @@ def ssm_scan(dt, x, A, Bm, Cm, s0, chunk: int, capture=None):
     return selective_scan_reference(dt, x, A, Bm, Cm, s0, chunk, capture)
 
 
+def conv_inputs(x, window, real=None):
+    """What a causal depthwise convolution of kernel K reads: x (B, T,
+    di), zero on its pads, behind the K - 1 inputs before it, `window`
+    (K-1, B, di).  ``ext[:, K-1 + t] = x[:, t]``, the window directly
+    before the row's first real column (`real` (B, T) bool, pads first);
+    a row of one column that holds no token keeps its window.  The
+    window after the last column is ``ext[:, T:]``.  (B, K-1 + T,
+    di)."""
+    B, T, di = x.shape
+    K1 = window.shape[0]
+    win = window.astype(x.dtype).swapaxes(0, 1)          # (B, K-1, di)
+    if real is None:
+        return jnp.concatenate([win, x], axis=1)
+    if T == 1:
+        held = jnp.concatenate([jnp.zeros_like(x), win], axis=1)
+        return jnp.where(real[..., None],
+                         jnp.concatenate([win, x], axis=1), held)
+    pads = T - jnp.sum(real, axis=1).astype(jnp.int32)
+    ext = jnp.concatenate([jnp.zeros((B, K1, di), x.dtype), x], axis=1)
+    return jax.vmap(lambda e, w, at: lax.dynamic_update_slice(
+        e, w, (at, 0)))(ext, win, pads)
+
+
 def mamba_mix(p, u, cfg: JambaConfig, window, state, real=None,
               capture=None):
     """The Mamba mixer on normalised input u (B, T, d).
@@ -426,21 +449,7 @@ def mamba_mix(p, u, cfg: JambaConfig, window, state, real=None,
         x, z = xz[..., :di], xz[..., di:]
         if real is not None:
             x = jnp.where(real[..., None], x, jnp.zeros((), x.dtype))
-        win = window.astype(x.dtype).swapaxes(0, 1)      # (B, K-1, di)
-        # ext[:, K-1 + t] = x[:, t]; the window directly before the
-        # row's first real column
-        if real is None:
-            ext = jnp.concatenate([win, x], axis=1)
-        elif T == 1:
-            held = jnp.concatenate([jnp.zeros_like(x), win], axis=1)
-            ext = jnp.where(real[..., None],
-                            jnp.concatenate([win, x], axis=1), held)
-        else:
-            pads = T - jnp.sum(real, axis=1).astype(jnp.int32)
-            ext = jnp.concatenate(
-                [jnp.zeros((B, K - 1, di), x.dtype), x], axis=1)
-            ext = jax.vmap(lambda e, w, at: lax.dynamic_update_slice(
-                e, w, (at, 0)))(ext, win, pads)
+        ext = conv_inputs(x, window, real)
         new_window = ext[:, T:].swapaxes(0, 1)
         w = p["conv_w"].astype(f32)
         conv = sum(ext[:, k:k + T].astype(f32) * w[k] for k in range(K))

@@ -343,10 +343,13 @@ def project(u, p, cfg: LagunaConfig, kind: str, positions):
 
 
 def attn_out(o, gate, p, cfg: LagunaConfig):
-    """o (..., H, hd) times its heads' gates, through ``W_o``: (...,
-    d)."""
+    """o (..., H, hd) times its gates, (..., H) one a head or (..., H,
+    hd) one a channel, through ``W_o``: (..., d)."""
     dt = cfg.dtype
-    o = (o.astype(jnp.float32) * gate[..., None]).astype(dt)
+    o = o.astype(jnp.float32)
+    if gate.ndim < o.ndim:
+        gate = gate[..., None]
+    o = (o * gate).astype(dt)
     return o.reshape(*o.shape[:-2], -1) @ p["wo"].astype(dt).reshape(
         -1, cfg.d_model)
 

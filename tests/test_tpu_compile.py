@@ -768,3 +768,56 @@ def test_kimi_k2_programs_fit_the_chip_at_the_published_widths(
                  f"bf16[{slots // 16},16,512]"):
         assert view not in text, view
     assert memory.temp_size_in_bytes < 0.55e9, memory
+
+
+@pytest.mark.parametrize("program,t_pad", [("decode", 0), ("prefill", 8192)])
+def test_solar_open2_programs_fit_the_chip_at_the_published_widths(
+        program, t_pad, monkeypatch):
+    """The cell solar-open2.serve-offline-summarize's two programs as
+    the engine builds them (benchmark/families/solar_open2.py
+    aot_serve_programs): published widths, layers 0-3, 40 of 320
+    experts held, 24,576 rows of the vocabulary, bf16 weights, 64 slots
+    over a 2.5 GiB pool of ONE layer and 1.6 GB of matrices, windows
+    and their snapshots, the 8,192-token prefill bucket.  The compiled
+    peak stays under 15 GB of the chip's 16; pool AND state are donated
+    and updated in place (no second copy of the 805 MB of matrices, no
+    copy of a layer of them).  The softmax layer's decode column is the
+    kernel ``gqa_paged_decode`` under ``attn_full`` (the program asks
+    ``jax.default_backend()``, steered here), every layer's experts ONE
+    ``grouped_swiglu`` under ``moe_experts`` at its new width of 1,280
+    and 40 groups, no ``ragged-dot`` compiled."""
+    from ray_tpu._private import scopes
+    from ray_tpu.models.solar_open2 import solar_open2_init
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, params, cache, programs, n_blocks = _serving_cell(
+        "solar-open2.serve-offline-summarize", solar_open2_init,
+        t_pad or 1024)
+    assert cache["k"].shape == (1, 40960, 16, 1024) and n_blocks == 40960
+    assert cache["ssm"].shape == (3, 64, 64, 128, 128)
+    held = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(
+        (params, cache)))
+    assert 10.9e9 < held < 11.1e9                  # 66% of the chip
+    fn, args = programs[program]
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args).compile()
+    memory = compiled.memory_analysis()
+    assert memory.peak_memory_in_bytes < 15e9, memory
+    assert memory.alias_size_in_bytes >= 4.35e9    # pool and state, in place
+    text = compiled.as_text()
+    fused, ragged = _experts_kernels(text)
+    assert not ragged and "ragged-dot" not in text, ragged
+    assert [s for _, s in fused] == [scopes.MOE_EXPERTS] * 4, fused
+    scoped = scopes.scope_map_from_hlo(text)
+    calls = {name: set(keyed.values()) for name, keyed in scoped.items()
+             if any("custom-call" in key for key in keyed)}
+    walks = [s for name, s in calls.items()
+             if name.startswith(scopes.GQA_PAGED_DECODE)]
+    assert walks == ([{scopes.ATTN_FULL}] if program == "decode" else [])
+    # the matrices: neither every layer's nor one layer's copied
+    for line in text.splitlines():
+        body = line.split(" = ", 1)[-1]
+        if body.startswith(("f32[3,64,64,128,128]", "f32[64,64,128,128]")):
+            assert " copy(" not in body and " transpose(" not in body, line
+    if program == "decode":
+        assert memory.temp_size_in_bytes < 0.3e9, memory
