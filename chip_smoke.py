@@ -41,6 +41,12 @@ Phases, one chip:
   gqa      the Laguna-XS.2 cell's decode kernel at its shapes against
            its jnp reference: the full layers' walk over the paged
            grouped-query K/V pools.
+  kda      the Solar-Open2 cell's prefill kernel at its shapes (64 heads
+           of 128, 1,024 and 8,192 columns with pads at the left, a
+           state handed in, beta up to 2, a captured column): the
+           chunked delta rule as one kernel against the jnp scan and,
+           at 1,024, against the recurrence; prints both forms'
+           milliseconds.
 Phases, --chips 4 (and no one-chip phase):
   mesh_train    the train step over data=4 and data=2 x fsdp=2 against
                 the same step on device 0.
@@ -63,7 +69,7 @@ import sys
 import time
 from typing import Any, Dict, List
 
-ONE_CHIP = ("train", "serve", "runtime", "mla", "gqa")
+ONE_CHIP = ("train", "serve", "runtime", "mla", "gqa", "kda")
 FOUR_CHIPS = ("mesh_train", "tensor_serve", "fleet")
 #: the driver allows 1200 s; leave room for the parent's own exit
 DEADLINE_S = 1100.0
@@ -137,6 +143,12 @@ class Size:
     gqa_preset: str = "laguna-xs2"
     gqa_max_seq: int = 8704
     gqa_wave: tuple = (64, 32768)
+    # kda: Solar-Open2's published widths: (heads, head size) of a KDA
+    #: layer, and (columns, pads at the left) of a prefill's delta
+    #: rule: the smallest bucket, held to the recurrence too, and the
+    #: largest
+    kda_heads: tuple = (64, 128)
+    kda_prefills: tuple = ((1024, 37), (8192, 700))
     # serve: bench.py's on-chip TrafficSpec cut to a few dozen requests
     requests: int = 32
     #: warm-up requests of the serve phase (another seed's traffic), and
@@ -1194,8 +1206,73 @@ def phase_gqa(size: Size, platform: str = "tpu") -> Dict[str, Any]:
     return {"device": device}
 
 
+def check_kda_kernels(size: Size, *, interpret: bool = False) -> None:
+    """ops/kda.py: a prefill's delta rule as one kernel (`kda_chunk`)
+    against the `jnp` chunk form it replaces on the chip, both with the
+    serving dtype's operands, and at the first length against the
+    recurrence over time in float32: a row with pads at its left (beta
+    = 0, g = 0), a state handed in, beta up to 2, a captured column."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.ops.kda import kda_chunk, kda_chunked, kda_recurrent
+
+    H, hd = size.kda_heads
+    jnp_form = jax.jit(functools.partial(kda_chunked, dtype=jnp.bfloat16))
+    kernel = functools.partial(kda_chunk, dtype=jnp.bfloat16,
+                               interpret=interpret)
+
+    def timed(f, *args, runs=3):
+        jax.block_until_ready(f(*args))
+        t0 = time.perf_counter()
+        for _ in range(runs):
+            out = f(*args)
+        jax.block_until_ready(out)
+        return out, (time.perf_counter() - t0) / runs * 1e3
+
+    for n, (T, pad) in enumerate(size.kda_prefills):
+        ks = jax.random.split(jax.random.PRNGKey(size.seed + 7 + n), 6)
+
+        def unit(key):
+            x = jax.random.normal(key, (1, T, H, hd))
+            return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+
+        real = (jnp.arange(T) >= pad)[None, :, None]
+        # a token's decay a channel log-uniform in (0.5, 0.999)
+        g = jnp.where(real[..., None], np.log(0.5) + (
+            np.log(0.999) - np.log(0.5)) * jax.random.uniform(
+                ks[3], (1, T, H, hd)), 0.0)
+        beta = jnp.where(real, 2.0 * jax.random.uniform(ks[4], (1, T, H)),
+                         0.0)
+        args = (unit(ks[0]) * hd ** -0.5, unit(ks[1]),
+                jax.random.normal(ks[2], (1, T, H, hd)), g, beta,
+                jax.random.normal(ks[5], (1, H, hd, hd)))
+        capture = jnp.int32(pad + (T - pad) // 2)
+        got, ms = timed(lambda *a: kernel(*a, capture=capture), *args)
+        want, ms_jnp = timed(lambda *a: jnp_form(*a, capture=capture), *args)
+        errs = {"o": _rel_err(got[0][:, pad:], want[0][:, pad:]),
+                "state": _rel_err(got[1], want[1]),
+                "snapshot": _rel_err(got[2], want[2])}
+        if n == 0:
+            o, state = jax.jit(kda_recurrent)(*args)
+            errs["o_recurrence"] = _rel_err(got[0][:, pad:], o[:, pad:])
+            errs["state_recurrence"] = _rel_err(got[1], state)
+        say("kda", kernel="kda_chunk", shape=[T, pad, H, hd],
+            ms=round(ms, 3), ms_jnp=round(ms_jnp, 3),
+            **{k: round(v, 6) for k, v in errs.items()})
+        assert max(errs.values()) <= KERNEL_TOL, ("kda_chunk", T, errs)
+
+
+def phase_kda(size: Size, platform: str = "tpu") -> Dict[str, Any]:
+    device = device_block(platform)
+    check_kda_kernels(size)
+    return {"device": device}
+
+
 PHASES = {"train": phase_train, "serve": phase_serve,
           "runtime": phase_runtime, "mla": phase_mla, "gqa": phase_gqa,
+          "kda": phase_kda,
           "mesh_train": phase_mesh_train,
           "tensor_serve": phase_tensor_serve, "fleet": phase_fleet}
 

@@ -112,11 +112,14 @@ GQA_PAGED_DECODE = "gqa_paged_decode"
 #: group, every touched expert's weights streamed once
 #: (ops/grouped_swiglu.py)
 GROUPED_SWIGLU = "grouped_swiglu"
+#: a prefill's chunked delta rule, one call a KDA layer: a head's state
+#: held in VMEM across its chunks (ops/kda.py)
+KDA_CHUNK = "kda_chunk"
 KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV,
            FLASH_RES_FWD, FLASH_RES_DQ, FLASH_RES_DKV,
            FLASH_TRI_FWD, FLASH_TRI_BWD, SSM_SCAN, MLA_PAGED_DECODE,
            MLA_ROTARY_LANES, MLA_FLASH_PREFILL, MOE_DISPATCH,
-           MOE_COMBINE, GQA_PAGED_DECODE, GROUPED_SWIGLU)
+           MOE_COMBINE, GQA_PAGED_DECODE, GROUPED_SWIGLU, KDA_CHUNK)
 
 # -- host phases -------------------------------------------------------------
 SPAN_PREFIX = "raytpu."

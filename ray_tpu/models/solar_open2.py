@@ -70,7 +70,7 @@ from ray_tpu.models.jamba import conv_inputs
 from ray_tpu.models.kimi_k2 import embed, lm_logits, rmsnorm
 from ray_tpu.models.laguna import attend_masked, attn_out
 from ray_tpu.models.llama import _rmsnorm
-from ray_tpu.ops.kda import kda_chunked, kda_step
+from ray_tpu.ops.kda import kda_prefill, kda_step
 from ray_tpu.parallel.sharding import (DEFAULT_RULES,
                                        with_logical_constraint)
 
@@ -387,7 +387,8 @@ def kda_mix(p, u, cfg: SolarOpen2Config, window, state, real=None,
     after them (left padding), and a pad moves neither window nor state.
     capture: a traced column index (rows all alike) after which window
     and state are also handed back, for a snapshot.  One column (a
-    decode wave) goes through `kda_step`, more through `kda_chunked`.
+    decode wave) goes through `kda_step`, more through `kda_prefill`
+    (the kernel `kda_chunk` on the chip, the `jnp` scan elsewhere).
 
     Returns (out (B, T, d), (window, state), (window, state) after
     `capture` or None)."""
@@ -424,7 +425,7 @@ def kda_mix(p, u, cfg: SolarOpen2Config, window, state, real=None,
                                 beta[:, 0], state)
         o = o[:, None]
     else:
-        o, new_state, snap_state = kda_chunked(
+        o, new_state, snap_state = kda_prefill(
             q, k, v, g, beta, state, chunk=cfg.kda_chunk, dtype=dt,
             capture=capture)
     o = _rmsnorm(o, p["o_norm"], cfg.rms_eps) \

@@ -9,7 +9,8 @@ state ``S`` (dk, dv) takes one token so:
     o_t = S_t^T q_t
 
 (arXiv 2510.26692; ``beta`` may reach 2, where ``I - beta k k^T`` has
-an eigenvalue of -1).  Three forms of it, plain ``jax.numpy`` / ``lax``:
+an eigenvalue of -1).  Four forms of it, three of them plain
+``jax.numpy`` / ``lax``:
 
   * `kda_recurrent`: the recurrence itself, a scan over time in
     float32: the oracle.
@@ -38,9 +39,37 @@ log-decays from that token to ``i`` and ``R - G_j`` those from ``j + 1``
 to it: both sums of non-positive terms, whatever the decay.  The chunks
 are walked in a `lax.scan` (they depend on each other through ``S_0``).
 
+  * `kda_chunk`: the chunk form again, as ONE ``pallas_call`` named
+    ``kda_chunk`` a layer: `_chunk`'s operations in its order and its
+    precisions (below).  XLA runs the scan as ~150 small device ops a
+    chunk, the state through HBM once a chunk, the pairwise decays
+    written out (33 MB a chunk over 64 heads), the operands re-laid
+    heads-leading first.  Here a grid step is (row, eight heads, two
+    chunks): a head is a 128-lane slice of the folded rows of q, k, v,
+    g, read where they lie; its state stays in VMEM from its first
+    chunk to its last; a diagonal block's pairwise decays are formed a
+    column against eight rows at a time and summed along the lanes
+    where they are formed; ``A``, ``B`` and ``U`` never leave the chip.
+    A chunk is a chain of dependent small steps (fifteen row steps of
+    the diagonal blocks' inverses, a dozen small products), so the
+    group's heads go through it side by side on a leading axis and
+    hide each other's latency.  ``G`` and the sub-chunks' own sums are
+    products with ones below the diagonal (float32, highest
+    precision), the solve `_solve_unit_lower`'s: the diagonal blocks
+    inverted row by row, all at once, then a sub-chunk's rows from the
+    ones above in two float32 products.  `capture`: the kernel hands
+    back the state at the START of the chunk that holds the column and
+    `_chunk` does that one chunk again in ``jnp``.
+  * `kda_prefill` is what a model calls: `kda_chunk` on the chip where
+    the shapes fit it (heads of whole lanes, more than one column, a
+    chunk of at most 128 columns in sub-chunks of whole sublane
+    tiles), `kda_chunked` everywhere else: the CPU, heads of 16.  A
+    differentiated `kda_chunk` is `kda_chunked` forward and backward
+    (its ``custom_vjp``).  `kda_chunked` is the parity oracle.
+
 A pad position is an identity step: ``beta = 0`` and ``g = 0`` (its
-``k``, ``q`` and ``v`` then move nothing), which is how `kda_chunked`
-fills a length that is no multiple of the chunk.
+``k``, ``q`` and ``v`` then move nothing), which is how both chunk
+forms fill a length that is no multiple of the chunk.
 
 Matmul operands are cast to `dtype` (bfloat16 when serving) and
 accumulate in float32; the state, ``G``, the decays and the triangular
@@ -50,17 +79,31 @@ highest precision (a TPU's default rounds float32 operands to bf16).
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
+from jax.experimental import pallas as pl
 
 from ray_tpu._private import scopes
 
-__all__ = ["kda_recurrent", "kda_step", "kda_chunked"]
+__all__ = ["kda_recurrent", "kda_step", "kda_chunked", "kda_chunk",
+           "kda_prefill"]
 
 _F32 = jnp.float32
+#: a vreg of float32: 8 sublanes of 128 lanes
+_SUBLANES, _LANES = 8, 128
+#: chunks a grid step of `kda_chunk` (an inner loop) and heads a grid
+#: step (side by side on the leading axis of every operation of a
+#: chunk).  What the chip said (my chip run, PR 50; 64 heads of 128,
+#: 8,192 columns, one layer; the `jnp` scan 17.5 ms): 1 head 18.2 ms, 2
+#: heads 12.8, 4 heads 10.0, 8 heads 9.2, 16 heads 9.0; 1, 2 or 4
+#: chunks a step alike
+_CHUNKS_A_STEP = 2
+_HEADS_A_STEP = 8
 
 
 def kda_step(q, k, v, g, beta, state):
@@ -245,3 +288,356 @@ def kda_chunked(q, k, v, g, beta, state=None, *, chunk: int = 64,
     o = jnp.moveaxis(jnp.moveaxis(o, 2, 3), 0, 1).reshape(
         B, n * chunk, H, dv)
     return o[:, :T], state, snap
+
+
+# ---------------------------------------------------------------------------
+# the kernel
+# ---------------------------------------------------------------------------
+
+def _kernel(cap_ref, q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref,
+            o_ref, st_ref, *rest, chunk: int, sub: int, dk: int, dv: int,
+            heads: int, chunks: int, dtype, snapshot: bool):
+    """One (row, group of `heads` heads, `chunks` chunks) grid step.
+    q, k, g (1, chunks x chunk, heads x dk) and v, o (.., heads x dv):
+    the heads' lane slices of the folded rows; b (1, chunks x chunk, H);
+    s0, st, snap (1, heads, dk, dv).  Scratch: the heads' states HELD
+    TRANSPOSED (heads, dv, dk), so that a chunk's decay of the state is
+    a row broadcast along the lanes and ``K+ S`` a product with a
+    transposed right side; a chunk's ``G``, its sub-chunks' own
+    cumulative sums and its keys (heads, chunk, dk each), rows of which
+    are read back one at a time.
+
+    `_chunk`'s operations on a chunk, in its order, float32 but for the
+    operands `_chunk`'s ``mm`` casts, the group's heads side by side on
+    a leading axis: a chunk is a chain of a dozen small dependent
+    products and fifteen row steps, each a few hundred cycles of
+    latency, and what hides one head's is another's beside it."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    if snapshot:
+        snap_ref, s_scr, k_scr, g_scr, in_scr = rest
+    else:
+        s_scr, k_scr, g_scr, in_scr = rest
+    group, t = pl.program_id(1), pl.program_id(2)
+    C, n, per = chunk, chunk // sub, sub // _SUBLANES
+    hi = lax.Precision.HIGHEST
+    prec = hi if dtype == _F32 else None
+    plain = (((2,), (1,)), ((0,), (0,)))                 # a[h] @ b[h]
+    nt = (((2,), (2,)), ((0,), (0,)))                    # a[h] @ b[h].T
+
+    def mm(a, b, dims=plain):
+        return lax.dot_general(a.astype(dtype), b.astype(dtype), dims,
+                               precision=prec, preferred_element_type=_F32)
+
+    def exact(a, b):
+        return lax.dot_general(a, b, plain, precision=hi,
+                               preferred_element_type=_F32)
+
+    def by_head(folded, d):     # (C, heads x d) -> (heads, C, d)
+        return jnp.stack([folded[:, h * d:(h + 1) * d]
+                          for h in range(heads)])
+
+    def across(a, width):       # (.., 128) alike along the lanes
+        return a if width == _LANES else jnp.concatenate(
+            [a] * (width // _LANES), axis=-1)
+
+    def rows_of(parts):         # along a chunk's rows
+        return jnp.concatenate(parts, axis=1)
+
+    @pl.when(t == 0)
+    def _first_chunks():
+        for h in range(heads):
+            s_scr[h] = s0_ref[0, h].T
+            if snapshot:
+                snap_ref[0, h] = s0_ref[0, h]
+
+    row = lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    col = lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    # cumulative sums as products with ones below the diagonal: over
+    # the chunk (G) and over each sub-chunk alone (G - R, module
+    # docstring), every head's at once
+    ones_both = jnp.concatenate(
+        [(col <= row).astype(_F32),
+         ((col <= row) & (row // sub == col // sub)).astype(_F32)], axis=0)
+    # ... and beta's column of a head along that head's lanes
+    H = b_ref.shape[-1]
+    to_lanes = (lax.broadcasted_iota(jnp.int32, (H, heads * _LANES), 0)
+                == group * heads + lax.broadcasted_iota(
+                    jnp.int32, (H, heads * _LANES), 1) // _LANES
+                ).astype(_F32)
+    sublane = lax.broadcasted_iota(jnp.int32, (_SUBLANES, 1), 0)
+    wide = lax.broadcasted_iota(jnp.int32, (sub, _LANES), 1)
+    deep = lax.broadcasted_iota(jnp.int32, (sub, _LANES), 0)
+    eye = ((wide % sub == deep) & (wide < C)).astype(_F32)
+    block_of = wide[:_SUBLANES] // sub
+    narrow = lax.broadcasted_iota(jnp.int32, (_SUBLANES, C), 1)
+
+    def one_chunk(c, carry):
+        if snapshot:
+            @pl.when(t * chunks + c == cap_ref[0])
+            def _the_chunk_of_capture():
+                for h in range(heads):
+                    snap_ref[0, h] = s_scr[h].T
+
+        rows = pl.ds(pl.multiple_of(c * C, C), C)
+        g_folded = g_ref[0, rows, :]
+        q, k = by_head(q_ref[0, rows, :], dk), by_head(k_ref[0, rows, :], dk)
+        v = by_head(v_ref[0, rows, :], dv)
+        beta = by_head(jnp.dot(b_ref[0, rows, :], to_lanes, precision=hi,
+                               preferred_element_type=_F32), _LANES)
+        both = jnp.dot(ones_both, g_folded, precision=hi,
+                       preferred_element_type=_F32)
+        G, inner = by_head(both[:C], dk), by_head(both[C:], dk)
+        k_scr[...], g_scr[...], in_scr[...] = k, G, inner
+        last = g_scr[:, C - 1:C, :]                               # G_C
+        grown, whole = jnp.exp(inner), jnp.exp(G)
+        kg, qg = k * grown, q * grown
+        k_beta = across(beta, dk) * k
+
+        # the diagonal blocks, pairwise: column b of sub-chunk s against
+        # the eight rows of its tile `part` (tiles wholly above the
+        # diagonal are never formed), summed along the lanes where it
+        # is formed: (heads, 8, 1), entries (part * 8 + r, b) of beta A
+        # (strictly below the diagonal) and of B
+        sums_a, sums_b = {}, {}
+        for s in range(n):
+            for b in range(sub):
+                j = s * sub + b
+                inner_b = in_scr[:, j:j + 1, :]
+                k_b = k_scr[:, j:j + 1, :]
+                for part in range(b // _SUBLANES, per):
+                    top = s * sub + part * _SUBLANES
+                    tile = slice(top, top + _SUBLANES)
+                    first = b - part * _SUBLANES   # > 0: the diagonal's tile
+                    gap = inner[:, tile] - inner_b
+                    if first > 0:
+                        gap = jnp.where(sublane >= first, gap, -jnp.inf)
+                    pair = jnp.exp(gap) * k_b
+                    below = jnp.sum(pair * k_beta[:, tile], axis=-1,
+                                    keepdims=True)
+                    sums_a[s, b, part] = below if first < 0 else jnp.where(
+                        sublane > first, below, 0.0)
+                    sums_b[s, b, part] = jnp.sum(pair * q[:, tile], axis=-1,
+                                                 keepdims=True)
+
+        def placed(sums, where, width):
+            """(heads, 8, width): `sums[i]` in the lanes where ``where
+            == i``, 0 elsewhere."""
+            tile = jnp.zeros((heads, _SUBLANES, width), _F32)
+            for i, column in sums:
+                tile = jnp.where(where == i, column, tile)
+            return tile
+
+        # the blocks below the diagonal, factored around R
+        low_off, b_off = [None], [None]
+        for s in range(1, n):
+            before = s * sub
+            r_s = g_scr[:, before - 1:before, :]
+            k_dec = rows_of([
+                k[:, :before] * jnp.exp(r_s - G[:, :before]),
+                jnp.zeros((heads, C - before, dk), _F32)])
+            at = slice(before, before + sub)
+            off = mm(rows_of([kg[:, at], qg[:, at]]), k_dec, nt)
+            low_off.append(beta[:, at, :C] * off[:, :sub])
+            b_off.append(off[:, sub:])
+
+        # (I + diag)^-1 of every sub-chunk at once, lanes (s, column):
+        # row j is final after j steps and leaves the rows below it,
+        # each by column j of its block (strictly below the diagonal)
+        # along that block's lanes
+        inv = eye
+        for j in range(sub - 1):
+            column = rows_of([placed(
+                [(s, sums_a[s, j, part]) for s in range(n)
+                 if (s, j, part) in sums_a], block_of, _LANES)
+                for part in range(per)])                # (heads, sub, 128)
+            inv = inv - column * inv[..., j:j + 1, :]
+        # (lanes from C on are zero)
+
+        state = s_scr[...]                                # (heads, dv, dk)
+        from_state = mm(rows_of([k * whole, q * whole]), state, nt)
+        rhs = across(beta, dv) * (v - from_state[:, :C])
+        out = []
+        for s in range(n):
+            r = rhs[:, s * sub:(s + 1) * sub]
+            if s:
+                r = r - exact(low_off[s], rows_of(
+                    out + [jnp.zeros((heads, C - s * sub, dv), _F32)]))
+            alone = [jnp.zeros((heads, sub, dv), _F32)] * n \
+                + [jnp.zeros((heads, _LANES - C, dv), _F32)] * (C < _LANES)
+            alone[s] = r
+            out.append(exact(jnp.where(wide // sub == s, inv, 0.0),
+                             rows_of(alone)))
+        U = rows_of(out)                                   # (heads, C, dv)
+
+        Bm = rows_of([
+            placed([(s * sub + b, sums_b[s, b, part]) for b in range(sub)
+                    if (s, b, part) in sums_b], narrow, C)
+            + (0.0 if b_off[s] is None else
+               b_off[s][:, part * _SUBLANES:(part + 1) * _SUBLANES])
+            for s in range(n) for part in range(per)])     # (heads, C, C)
+        o = from_state[:, C:] + mm(Bm, U)
+        for h in range(heads):
+            o_ref[0, rows, h * dv:(h + 1) * dv] = o[h]
+        s_scr[...] = state * jnp.exp(last) + mm(
+            jnp.stack([U[h].T for h in range(heads)]),
+            k * jnp.exp(last - G))
+        return carry
+
+    lax.fori_loop(0, chunks, one_chunk, 0)
+
+    @pl.when(t == pl.num_programs(2) - 1)
+    def _last_chunks():
+        for h in range(heads):
+            st_ref[0, h] = s_scr[h].T
+
+
+def _call(q, k, v, g, beta, state, cap_chunk, *, chunk: int, chunks: int,
+          sub: int, dtype, interpret: bool):
+    """The kernel on folded operands: q, k, g (B, T, H x dk), v (B, T,
+    H x dv), beta (B, T, H), T a multiple of `chunks` chunks; state (B,
+    H, dk, dv) float32; cap_chunk: a traced chunk index or None.
+    Returns (o (B, T, H x dv), the state after T, the state BEFORE chunk
+    `cap_chunk` or None)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, T, H = beta.shape
+    dk, dv = k.shape[-1] // H, v.shape[-1] // H
+    step = chunks * chunk
+    heads = next(n for n in range(min(_HEADS_A_STEP, H), 0, -1)
+                 if H % n == 0)
+    snapshot = cap_chunk is not None
+
+    def time_block(d):
+        return pl.BlockSpec((1, step, heads * d),
+                            lambda b, h, t, cap: (b, t, h))
+
+    state_block = pl.BlockSpec((1, heads, dk, dv),
+                               lambda b, h, t, cap: (b, h, 0, 0))
+    states = jax.ShapeDtypeStruct((B, H, dk, dv), _F32)
+    out_shape = [jax.ShapeDtypeStruct((B, T, H * dv), _F32), states]
+    out_specs = [time_block(dv), state_block]
+    if snapshot:
+        out_shape.append(states)
+        out_specs.append(state_block)
+    cap = jnp.reshape(jnp.asarray(cap_chunk if snapshot else -1, jnp.int32),
+                      (1,))
+    outs = pl.pallas_call(
+        functools.partial(_kernel, chunk=chunk, sub=sub, dk=dk, dv=dv,
+                          heads=heads, chunks=chunks, dtype=dtype,
+                          snapshot=snapshot),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, H // heads, T // step),
+            in_specs=[time_block(dk), time_block(dk), time_block(dv),
+                      time_block(dk),
+                      pl.BlockSpec((1, step, H),
+                                   lambda b, h, t, cap: (b, t, 0)),
+                      state_block],
+            out_specs=out_specs,
+            scratch_shapes=[pltpu.VMEM((heads, dv, dk), _F32)]
+            + [pltpu.VMEM((heads, chunk, dk), _F32)] * 3),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name=scopes.KDA_CHUNK,
+    )(cap, q, k, v, g, beta, state)
+    return outs[0], outs[1], (outs[2] if snapshot else None)
+
+
+def _fits_the_kernel(k, v, chunk: int, sub: int) -> bool:
+    """Whole lanes a head, more than one column, sub-chunks of whole
+    sublane tiles and a chunk whose columns fit a tile's lanes."""
+    return (k.shape[1] > 1 and k.shape[-1] % _LANES == 0
+            and v.shape[-1] % _LANES == 0 and sub % _SUBLANES == 0
+            and chunk % sub == 0 and chunk <= _LANES)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10))
+def _kernel_form(q, k, v, g, beta, state, capture, chunk, sub, dtype,
+                 interpret):
+    """`kda_chunk` on operands as `kda_chunked` takes them; state (B, H,
+    dk, dv) float32, capture int32 or None."""
+    B, T, H, dk = k.shape
+    chunks = min(_CHUNKS_A_STEP, -(-T // chunk))
+    fill = -T % (chunks * chunk)
+    q, k, v, g, beta = (a.astype(_F32) for a in (q, k, v, g, beta))
+    if fill:        # identity steps at the end, as `kda_chunked` fills
+        q, k, v, g, beta = (
+            jnp.pad(a, ((0, 0), (0, fill)) + ((0, 0),) * (a.ndim - 2))
+            for a in (q, k, v, g, beta))
+    at = None if capture is None else capture // chunk
+    with jax.named_scope(scopes.ATTN_LINEAR):
+        o, state, before = _call(
+            *(a.reshape(B, T + fill, -1) for a in (q, k, v, g)), beta,
+            state, at, chunk=chunk, chunks=chunks, sub=sub, dtype=dtype,
+            interpret=interpret)
+        o = o.reshape(B, T + fill, H, -1)[:, :T]
+        if capture is None:
+            return o, state, None
+        # the one chunk that holds `capture`, again, in `_chunk`'s own
+        # words, from the state the kernel handed back at its start
+        one = (jnp.swapaxes(lax.dynamic_slice_in_dim(
+            a, at * chunk, chunk, axis=1), 1, 2)
+            for a in (q, k, v, g, beta))
+        snap = _chunk(*one, before, sub, dtype, capture - at * chunk)[2]
+    return o, state, snap
+
+
+def _jnp_form_fwd(q, k, v, g, beta, state, capture, chunk, sub, dtype,
+              interpret):
+    # a differentiated program runs the `jnp` form, forward and backward
+    out, vjp = jax.vjp(
+        lambda *a: kda_chunked(*a, chunk=chunk, sub=sub, dtype=dtype,
+                               capture=capture), q, k, v, g, beta, state)
+    return out, (vjp, capture)
+
+
+def _jnp_form_bwd(chunk, sub, dtype, interpret, res, cts):
+    vjp, capture = res
+    no_grad = None if capture is None else np.zeros(
+        np.shape(capture), jax.dtypes.float0)
+    return (*vjp(cts), no_grad)
+
+
+_kernel_form.defvjp(_jnp_form_fwd, _jnp_form_bwd)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "sub", "dtype",
+                                             "interpret"))
+def kda_chunk(q, k, v, g, beta, state=None, *, chunk: int = 64,
+              sub: int = 16, dtype=jnp.bfloat16, capture=None,
+              interpret: bool = False
+              ) -> Tuple[jnp.ndarray, jnp.ndarray, Optional[jnp.ndarray]]:
+    """`kda_chunked`'s contract as one Pallas call named ``kda_chunk``
+    (module docstring).  Head sizes are whole lanes (multiples of 128),
+    `sub` whole sublane tiles (a multiple of 8) and `chunk` a multiple
+    of `sub`, at most 128.  ``interpret=True`` runs the kernel in
+    the Pallas interpreter (the CPU tests).  Jitted: the KDA layers of
+    one program share one trace and one lowering of the kernel.
+    Differentiated, it is `kda_chunked`, forward and backward."""
+    sub = min(sub, chunk)
+    if not _fits_the_kernel(k, v, chunk, sub):
+        raise ValueError(
+            f"kda_chunk: heads of {k.shape[-1]} x {v.shape[-1]}, chunk "
+            f"{chunk}, sub {sub} and {k.shape[1]} columns do not fit the "
+            "kernel")
+    B, _, H, dk = k.shape
+    if state is None:
+        state = jnp.zeros((B, H, dk, v.shape[-1]), _F32)
+    if capture is not None:
+        capture = jnp.asarray(capture, jnp.int32)
+    return _kernel_form(q, k, v, g, beta, state.astype(_F32), capture,
+                        chunk, sub, jnp.dtype(dtype), interpret)
+
+
+def kda_prefill(q, k, v, g, beta, state=None, *, chunk: int = 64,
+                sub: int = 16, dtype=jnp.bfloat16, capture=None):
+    """A prefill's delta rule by the form that fits what the program
+    can see: `kda_chunk` on the chip where the shapes fit the kernel,
+    `kda_chunked` everywhere else (module docstring)."""
+    form = kda_chunk if jax.default_backend() == "tpu" and _fits_the_kernel(
+        k, v, chunk, min(sub, chunk)) else kda_chunked
+    return form(q, k, v, g, beta, state, chunk=chunk, sub=sub, dtype=dtype,
+                capture=capture)

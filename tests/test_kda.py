@@ -1,20 +1,34 @@
 """ops/kda.py: the chunked delta rule and the one-token step against the
-recurrence over time, in float32 on the CPU."""
+recurrence over time, in float32 on the CPU; the chunk form twice: the
+`jnp` scan at small heads, and the kernel (`kda_chunk`, in the Pallas
+interpreter) at heads of whole lanes."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.ops.kda import (_solve_unit_lower, kda_chunked, kda_recurrent,
-                             kda_step)
+from ray_tpu.ops import kda
+from ray_tpu.ops.kda import (_solve_unit_lower, kda_chunk, kda_chunked,
+                             kda_prefill, kda_recurrent, kda_step)
 
 B, H, DK, DV = 2, 3, 16, 8
+#: the two chunk forms and the sizes each is run at: the kernel takes
+#: heads of whole lanes, one row of three heads (an odd count: a head a
+#: grid step)
+FORMS = {"jnp": (kda_chunked, (B, H, DK, DV)),
+         "kernel": (functools.partial(kda_chunk, interpret=True),
+                    (1, 3, 128, 128))}
+forms = pytest.mark.parametrize("form", sorted(FORMS))
 
 
-def _inputs(seed, T, decay=(0.5, 0.999), beta_max=2.0, state=True):
+def _inputs(seed, T, decay=(0.5, 0.999), beta_max=2.0, state=True,
+            dims=(B, H, DK, DV)):
     """Unit keys and queries, values N(0, 1), per-channel decays
     log-uniform in `decay`, beta uniform in (0, `beta_max`)."""
+    B, H, DK, DV = dims
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
 
     def unit(k):
@@ -34,92 +48,139 @@ def _close(got, want, tol=2e-5):
     assert float(jnp.max(jnp.abs(got - want))) <= tol * scale
 
 
-@pytest.mark.parametrize("chunk,sub", [(16, 16), (64, 16), (32, 8)])
-@pytest.mark.parametrize("T", [64, 83])
-def test_chunked_is_the_recurrence(chunk, sub, T):
+@pytest.mark.parametrize("form,chunk,sub,T", [
+    ("jnp", c, s, T) for c, s in [(16, 16), (64, 16), (32, 8)]
+    for T in (64, 83)] + [
+    # one chunk; no multiple of the chunk; chunks that fill a grid step
+    # (four) and one more, which is no multiple of the chunks a step
+    ("kernel", 64, 16, 64), ("kernel", 64, 16, 83),
+    ("kernel", 32, 16, 128), ("kernel", 32, 16, 160),
+    ("kernel", 16, 8, 50)])
+def test_chunked_is_the_recurrence(form, chunk, sub, T):
     """Chunks of 16 and 64, a length that is no multiple of the chunk,
     a carried-in state, beta up to 2."""
-    xs, s0 = _inputs(T + chunk, T)
+    chunked, dims = FORMS[form]
+    xs, s0 = _inputs(T + chunk, T, dims=dims)
     o, s = kda_recurrent(*xs, s0)
-    oc, sc, snap = kda_chunked(*xs, s0, chunk=chunk, sub=sub,
-                               dtype=jnp.float32)
-    assert snap is None and oc.shape == o.shape == (B, T, H, DV)
+    oc, sc, snap = chunked(*xs, s0, chunk=chunk, sub=sub,
+                           dtype=jnp.float32)
+    assert snap is None and oc.shape == o.shape == (
+        dims[0], T, dims[1], dims[3])
     _close(oc, o)
     _close(sc, s)
+    if form == "kernel":        # and the `jnp` form it stands for
+        oj, sj, _ = kda_chunked(*xs, s0, chunk=chunk, sub=sub,
+                                dtype=jnp.float32)
+        _close(oc, oj)
+        _close(sc, sj)
 
 
-def test_two_calls_are_one():
+@forms
+def test_two_calls_are_one(form):
     """The state a first call hands back carries a second: what a
     chunked admission does between its pieces."""
-    xs, s0 = _inputs(7, 90)
-    o, s = kda_chunked(*xs, s0, chunk=16, dtype=jnp.float32)[:2]
+    chunked, dims = FORMS[form]
+    xs, s0 = _inputs(7, 90, dims=dims)
+    o, s = chunked(*xs, s0, chunk=16, dtype=jnp.float32)[:2]
     cut = 37
-    o1, s1, _ = kda_chunked(*(a[:, :cut] for a in xs), s0, chunk=16,
-                            dtype=jnp.float32)
-    o2, s2, _ = kda_chunked(*(a[:, cut:] for a in xs), s1, chunk=16,
-                            dtype=jnp.float32)
+    o1, s1, _ = chunked(*(a[:, :cut] for a in xs), s0, chunk=16,
+                        dtype=jnp.float32)
+    o2, s2, _ = chunked(*(a[:, cut:] for a in xs), s1, chunk=16,
+                        dtype=jnp.float32)
     _close(jnp.concatenate([o1, o2], axis=1), o)
     _close(s2, s)
 
 
-def test_no_state_is_a_zero_state():
-    xs, _ = _inputs(3, 40, state=False)
+@forms
+def test_no_state_is_a_zero_state(form):
+    chunked, dims = FORMS[form]
+    xs, _ = _inputs(3, 40, state=False, dims=dims)
     o, s = kda_recurrent(*xs)
-    oc, sc, _ = kda_chunked(*xs, chunk=16, dtype=jnp.float32)
+    oc, sc, _ = chunked(*xs, chunk=16, dtype=jnp.float32)
     _close(oc, o)
     _close(sc, s)
 
 
-@pytest.mark.parametrize("capture", [0, 15, 16, 40, 82])
-def test_the_captured_state_is_the_state_after_that_token(capture):
-    xs, s0 = _inputs(11, 83)
+@pytest.mark.parametrize("form,capture", [
+    ("jnp", c) for c in (0, 15, 16, 40, 82)] + [
+    # a chunk's first, a middle and its last column; the first chunk's
+    # and the last's, which the length does not fill
+    ("kernel", c) for c in (0, 16, 23, 31, 82)])
+def test_the_captured_state_is_the_state_after_that_token(form, capture):
+    chunked, dims = FORMS[form]
+    xs, s0 = _inputs(11, 83, dims=dims)
     want = kda_recurrent(*(a[:, :capture + 1] for a in xs), s0)[1]
-    o, s, snap = jax.jit(lambda c: kda_chunked(
-        *xs, s0, chunk=16, sub=8, dtype=jnp.float32, capture=c))(capture)
+    o, s, snap = _captured(form)(xs, s0, capture)
     _close(snap, want)
     _close(s, kda_recurrent(*xs, s0)[1])
+    _close(o, kda_recurrent(*xs, s0)[0])
 
 
-@pytest.mark.parametrize("chunk,sub", [(64, 16), (64, 64)])
-def test_a_strong_decay_overflows_nothing(chunk, sub):
+@functools.lru_cache(maxsize=None)
+def _captured(form):
+    """One compiled program a form: the column is traced."""
+    return jax.jit(lambda xs, s0, c: FORMS[form][0](
+        *xs, s0, chunk=16, sub=8, dtype=jnp.float32, capture=c))
+
+
+@pytest.mark.parametrize("form,chunk,sub", [
+    ("jnp", 64, 16), ("jnp", 64, 64), ("kernel", 64, 16)])
+def test_a_strong_decay_overflows_nothing(form, chunk, sub):
     """Decays down to 1e-4 a token: over a chunk of 64 the cumulative
     decay reaches exp(-590), and ``exp(-G_j)`` alone would be inf."""
-    xs, s0 = _inputs(5, 128, decay=(1e-4, 0.9))
+    chunked, dims = FORMS[form]
+    xs, s0 = _inputs(5, 128, decay=(1e-4, 0.9), dims=dims)
     o, s = kda_recurrent(*xs, s0)
-    oc, sc, _ = kda_chunked(*xs, s0, chunk=chunk, sub=sub,
-                            dtype=jnp.float32)
+    oc, sc, _ = chunked(*xs, s0, chunk=chunk, sub=sub, dtype=jnp.float32)
     assert bool(jnp.all(jnp.isfinite(oc))) and bool(
         jnp.all(jnp.isfinite(sc)))
     _close(oc, o)
     _close(sc, s)
 
 
-def test_a_pad_is_an_identity_step():
+@pytest.mark.parametrize("form,pads", [("jnp", 13), ("kernel", 13),
+                                       ("kernel", 35)])
+def test_a_pad_is_an_identity_step(form, pads):
     """beta = 0 and g = 0 at a position: its q, k and v move nothing,
-    wherever the pads lie (a right-aligned tail's come first)."""
-    (q, k, v, g, beta), s0 = _inputs(9, 48)
-    real = jnp.arange(48) >= 13
+    wherever the pads lie (a right-aligned tail's come first: 13 of
+    them inside the first chunk, 35 the first two chunks whole and
+    more)."""
+    chunked, dims = FORMS[form]
+    (q, k, v, g, beta), s0 = _inputs(9, 48, dims=dims)
+    real = jnp.arange(48) >= pads
     g_p = jnp.where(real[None, :, None, None], g, 0.0)
     b_p = jnp.where(real[None, :, None], beta, 0.0)
-    o, s, _ = kda_chunked(q, k, v, g_p, b_p, s0, chunk=16,
-                          dtype=jnp.float32)
-    want_o, want_s = kda_recurrent(q[:, 13:], k[:, 13:], v[:, 13:],
-                                   g[:, 13:], beta[:, 13:], s0)
-    _close(o[:, 13:], want_o)
+    o, s, _ = chunked(q, k, v, g_p, b_p, s0, chunk=16, dtype=jnp.float32)
+    want_o, want_s = kda_recurrent(q[:, pads:], k[:, pads:], v[:, pads:],
+                                   g[:, pads:], beta[:, pads:], s0)
+    _close(o[:, pads:], want_o)
     _close(s, want_s)
 
 
-def test_equal_keys_and_beta_two_stay_bounded():
+@forms
+def test_chunks_of_pads_leave_the_state_to_the_bit(form):
+    """A bucket's left edge: whole chunks of pads in front change no
+    bit of the state handed in."""
+    chunked, dims = FORMS[form]
+    (q, k, v, g, beta), s0 = _inputs(9, 48, dims=dims)
+    s = chunked(q, k, v, jnp.zeros_like(g), jnp.zeros_like(beta), s0,
+                chunk=16, dtype=jnp.float32)[1]
+    assert bool(jnp.all(s == s0))
+
+
+@forms
+def test_equal_keys_and_beta_two_stay_bounded(form):
     """The solve is forward substitution: with every key equal and beta
     2 the transition is a reflection, the powers of ``beta A`` reach
     2^k C(64, k), and the solution stays of magnitude 2."""
     T = 64
+    chunked, (_, _, DK, DV) = FORMS[form]
     k = jnp.broadcast_to(jnp.eye(DK)[0], (1, T, 1, DK))
     v = jax.random.normal(jax.random.PRNGKey(0), (1, T, 1, DV))
     g = jnp.zeros((1, T, 1, DK))
     beta = jnp.full((1, T, 1), 2.0)
     o, s = kda_recurrent(k, k, v, g, beta)
-    oc, sc, _ = kda_chunked(k, k, v, g, beta, chunk=64, dtype=jnp.float32)
+    oc, sc, _ = chunked(k, k, v, g, beta, chunk=64, dtype=jnp.float32)
     _close(oc, o)
     _close(sc, s)
 
@@ -158,12 +219,67 @@ def test_an_idle_row_keeps_its_state_to_the_bit():
     assert bool(jnp.all(s == s0))
 
 
-def test_bf16_operands_accumulate_in_float32():
+@forms
+def test_bf16_operands_accumulate_in_float32(form):
     """The serving dtype: the state handed back is float32 and within
     bf16's rounding of the recurrence."""
-    xs, s0 = _inputs(8, 96, decay=(0.9, 0.999))
+    chunked, dims = FORMS[form]
+    xs, s0 = _inputs(8, 96, decay=(0.9, 0.999), dims=dims)
     o, s = kda_recurrent(*xs, s0)
-    oc, sc, _ = kda_chunked(*xs, s0, chunk=64, dtype=jnp.bfloat16)
+    oc, sc, _ = chunked(*xs, s0, chunk=64, dtype=jnp.bfloat16)
     assert oc.dtype == sc.dtype == jnp.float32
     _close(oc, o, tol=3e-2)
     _close(sc, s, tol=3e-2)
+
+
+def test_heads_as_lane_slices_are_heads_leading():
+    """The kernel reads a head as a lane slice of the folded row, two
+    heads a grid step: what each head alone gives (a row of one head is
+    its own folded row), the snapshot too."""
+    dims = (1, 4, 128, 128)
+    xs, s0 = _inputs(12, 40, dims=dims)
+    kernel = functools.partial(kda_chunk, chunk=16, sub=8,
+                               dtype=jnp.float32, capture=jnp.int32(21),
+                               interpret=True)
+    together = kernel(*xs, s0)
+    for h in range(dims[1]):
+        alone = kernel(*(a[:, :, h:h + 1] for a in xs), s0[:, h:h + 1])
+        _close(together[0][:, :, h:h + 1], alone[0])
+        _close(together[1][:, h:h + 1], alone[1])
+        _close(together[2][:, h:h + 1], alone[2])
+
+
+def test_the_kernel_runs_only_where_it_fits(monkeypatch):
+    """`kda_prefill` picks by what it can see: off the chip, at heads
+    that are no whole lanes or at one column it is the `jnp` form; a
+    differentiated kernel form is the `jnp` form too, forward and
+    backward."""
+    called = []
+    monkeypatch.setattr(kda, "kda_chunk", lambda *a, **k: called.append(
+        "kernel") or kda_chunk(*a, interpret=True, **k))
+    xs, s0 = _inputs(2, 24)
+    want = kda_chunked(*xs, s0, chunk=16, dtype=jnp.float32)
+    got = kda_prefill(*xs, s0, chunk=16, dtype=jnp.float32)
+    assert not called                               # the CPU
+    _close(got[0], want[0])
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    kda_prefill(*xs, s0, chunk=16, dtype=jnp.float32)
+    assert not called                               # heads of 16 x 8
+    wide, w0 = _inputs(2, 24, dims=(1, 1, 128, 128))
+    kda_prefill(*(a[:, :1] for a in wide), w0, chunk=16, dtype=jnp.float32)
+    assert not called                               # one column
+    got = kda_prefill(*wide, w0, chunk=16, dtype=jnp.float32)
+    assert called == ["kernel"]
+    _close(got[0], kda_chunked(*wide, w0, chunk=16, dtype=jnp.float32)[0])
+
+    def loss(form, v):
+        q, k, _, g, beta = wide
+        o, s, _ = form(q, k, v, g, beta, w0, chunk=16, dtype=jnp.float32)
+        return jnp.sum(o * o) + jnp.sum(s)
+
+    grad = jax.grad(functools.partial(loss, functools.partial(
+        kda_chunk, interpret=True)))(wide[2])
+    _close(grad, jax.grad(functools.partial(loss, kda_chunked))(wide[2]))
+    text = str(jax.make_jaxpr(jax.grad(functools.partial(
+        loss, functools.partial(kda_chunk, interpret=True))))(wide[2]))
+    assert "pallas_call" not in text and "scan" in text

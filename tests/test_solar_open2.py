@@ -311,7 +311,7 @@ def test_a_wrong_model_fails_the_tolerance(fault, params, want,
     if fault == "bf16_state":
         monkeypatch.setattr(so, "kda_step", _bf16_state)
     elif fault == "no_carried_state":
-        monkeypatch.setattr(so, "kda_chunked", _no_carried_state)
+        monkeypatch.setattr(so, "kda_prefill", _no_carried_state)
     elif fault == "beta_not_doubled":
         cfg = so.solar_open2_config("nano", dtype=jnp.float32,
                                     held=tuple(range(8)), neg_eigval=False)

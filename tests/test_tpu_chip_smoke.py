@@ -36,7 +36,8 @@ TOY = chip_smoke.Size(
     new_tokens=8, prefill_bucket=16, mla_preset="nano", mla_max_seq=128,
     mla_tile=16, mla_prefills=((64, 0, 51), (64, 60, 14)),
     mla_wave=(5, 24, 3), moe_rows=((64, 32), (8, 8)), moe_held=6,
-    gqa_preset="nano", gqa_max_seq=128, gqa_wave=(5, 24))
+    gqa_preset="nano", gqa_max_seq=128, gqa_wave=(5, 24),
+    kda_heads=(2, 128), kda_prefills=((96, 13), (160, 70)))
 
 
 @pytest.fixture
@@ -52,6 +53,8 @@ def interpreted(monkeypatch):
         chip_smoke.check_mla_kernels, interpret=True))
     monkeypatch.setattr(chip_smoke, "check_gqa_kernels", functools.partial(
         chip_smoke.check_gqa_kernels, interpret=True))
+    monkeypatch.setattr(chip_smoke, "check_kda_kernels", functools.partial(
+        chip_smoke.check_kda_kernels, interpret=True))
 
 
 @pytest.fixture
@@ -78,6 +81,11 @@ def test_mla_phase(interpreted):
 
 def test_gqa_phase(interpreted):
     out = chip_smoke.phase_gqa(TOY, "cpu")
+    assert out["device"]["platform"] == "cpu"
+
+
+def test_kda_phase(interpreted):
+    out = chip_smoke.phase_kda(TOY, "cpu")
     assert out["device"]["platform"] == "cpu"
 
 

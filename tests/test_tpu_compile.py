@@ -819,5 +819,20 @@ def test_solar_open2_programs_fit_the_chip_at_the_published_widths(
         body = line.split(" = ", 1)[-1]
         if body.startswith(("f32[3,64,64,128,128]", "f32[64,64,128,128]")):
             assert " copy(" not in body and " transpose(" not in body, line
+    # a prefill's delta rule: ONE ``kda_chunk`` a KDA layer under
+    # ``attn_linear`` where the chunk scan's ``while`` was; the peak is
+    # 13.53 GB where the scan's was 13.24 (PR 49): a call's operands are
+    # whole arrays, q, k, v and g in float32 (268 MB each) beside o
+    rules = [s for name, s in calls.items()
+             if name.startswith(scopes.KDA_CHUNK)]
+    assert rules == ([{scopes.ATTN_LINEAR}] * 3 if program == "prefill"
+                     else []), rules
+    assert scopes.KDA_CHUNK in scopes.KERNELS
+    loops = [name for name, keyed in scoped.items()
+             if scopes.ATTN_LINEAR in keyed.values()
+             and any(" while" in key for key in keyed)]
+    assert not loops, loops
+    if program == "prefill":
+        assert memory.peak_memory_in_bytes <= 13.6e9, memory
     if program == "decode":
         assert memory.temp_size_in_bytes < 0.3e9, memory
