@@ -41,12 +41,14 @@ Phases, one chip:
   gqa      the Laguna-XS.2 cell's decode kernel at its shapes against
            its jnp reference: the full layers' walk over the paged
            grouped-query K/V pools.
-  kda      the Solar-Open2 cell's prefill kernel at its shapes (64 heads
-           of 128, 1,024 and 8,192 columns with pads at the left, a
-           state handed in, beta up to 2, a captured column): the
-           chunked delta rule as one kernel against the jnp scan and,
-           at 1,024, against the recurrence; prints both forms'
-           milliseconds.
+  kda      the Solar-Open2 cell's two kernels at its shapes (64 heads
+           of 128).  A prefill's: 1,024 and 8,192 columns with pads at
+           the left, a state handed in, beta up to 2, a captured
+           column: the chunked delta rule as one kernel against the jnp
+           scan and, at 1,024, against the recurrence.  A decode
+           wave's: 64 rows, idle ones among them, on the middle layer of
+           a stack of three, against `kda_step` on that layer.  Prints
+           both forms' milliseconds, and the wave's GB/s.
 Phases, --chips 4 (and no one-chip phase):
   mesh_train    the train step over data=4 and data=2 x fsdp=2 against
                 the same step on device 0.
@@ -146,9 +148,10 @@ class Size:
     # kda: Solar-Open2's published widths: (heads, head size) of a KDA
     #: layer, and (columns, pads at the left) of a prefill's delta
     #: rule: the smallest bucket, held to the recurrence too, and the
-    #: largest
+    #: largest; (rows, KDA layers in the stack) of a decode wave
     kda_heads: tuple = (64, 128)
     kda_prefills: tuple = ((1024, 37), (8192, 700))
+    kda_wave: tuple = (64, 3)
     # serve: bench.py's on-chip TrafficSpec cut to a few dozen requests
     requests: int = 32
     #: warm-up requests of the serve phase (another seed's traffic), and
@@ -1211,12 +1214,17 @@ def check_kda_kernels(size: Size, *, interpret: bool = False) -> None:
     against the `jnp` chunk form it replaces on the chip, both with the
     serving dtype's operands, and at the first length against the
     recurrence over time in float32: a row with pads at its left (beta
-    = 0, g = 0), a state handed in, beta up to 2, a captured column."""
+    = 0, g = 0), a state handed in, beta up to 2, a captured column.
+    Then a decode wave's (`kda_decode`) on the middle layer of the
+    stacked state, donated, against its `jnp` form (`kda_step` on that
+    layer indexed out and set back): every fifth row idle, the other
+    layers and the idle rows back to the bit."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from ray_tpu.ops.kda import kda_chunk, kda_chunked, kda_recurrent
+    from ray_tpu.ops.kda import (_step_on_layer, kda_chunk, kda_chunked,
+                                 kda_decode, kda_recurrent)
 
     H, hd = size.kda_heads
     jnp_form = jax.jit(functools.partial(kda_chunked, dtype=jnp.bfloat16))
@@ -1262,6 +1270,51 @@ def check_kda_kernels(size: Size, *, interpret: bool = False) -> None:
             ms=round(ms, 3), ms_jnp=round(ms_jnp, 3),
             **{k: round(v, 6) for k, v in errs.items()})
         assert max(errs.values()) <= KERNEL_TOL, ("kda_chunk", T, errs)
+
+    B, layers = size.kda_wave
+    j = layers // 2
+    ks = jax.random.split(jax.random.PRNGKey(size.seed + 11), 6)
+
+    def unit(key):
+        x = jax.random.normal(key, (B, H, hd))
+        return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+
+    idle = jnp.arange(B) % 5 == 1
+    g = jnp.where(idle[:, None, None], 0.0, np.log(0.5) + (
+        np.log(0.999) - np.log(0.5)) * jax.random.uniform(ks[3], (B, H, hd)))
+    beta = jnp.where(idle[:, None], 0.0,
+                     2.0 * jax.random.uniform(ks[4], (B, H)))
+    wave = (unit(ks[0]) * hd ** -0.5, unit(ks[1]),
+            jax.random.normal(ks[2], (B, H, hd)), g, beta)
+    stacked = jax.jit(lambda: jax.random.normal(ks[5],
+                                                (layers, B, H, hd, hd)))
+    before = stacked()
+    # all that may move: layer j's matrices of the rows that decode
+    moves = ((jnp.arange(layers) == j)[:, None] & ~idle)[:, :, None, None,
+                                                         None]
+
+    def timed_in_place(form, runs=10):
+        f = jax.jit(lambda *a: form(*a, j), donate_argnums=(5,))
+        o, stack = f(*wave, stacked())
+        first = jax.device_get(
+            (o, stack[j], jnp.all(moves | (stack == before))))
+        t0 = time.perf_counter()
+        for _ in range(runs):
+            o, stack = f(*wave, stack)
+        jax.block_until_ready(stack)
+        return first, (time.perf_counter() - t0) / runs * 1e3
+
+    (o, after, kept), ms = timed_in_place(functools.partial(
+        kda_decode, interpret=interpret))
+    (want_o, want, _), ms_jnp = timed_in_place(_step_on_layer)
+    errs = {"o": _rel_err(o, want_o), "state": _rel_err(after, want)}
+    kept = bool(kept)
+    moved = 2 * B * H * hd * hd * 4
+    say("kda", kernel="kda_decode", shape=[layers, B, H, hd, hd],
+        ms=round(ms, 4), ms_jnp=round(ms_jnp, 4),
+        gb_per_s=round(moved / ms / 1e6, 1), kept_to_the_bit=kept,
+        **{k: round(v, 8) for k, v in errs.items()})
+    assert kept and max(errs.values()) <= KERNEL_TOL, ("kda_decode", errs)
 
 
 def phase_kda(size: Size, platform: str = "tpu") -> Dict[str, Any]:

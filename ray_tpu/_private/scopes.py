@@ -115,11 +115,15 @@ GROUPED_SWIGLU = "grouped_swiglu"
 #: a prefill's chunked delta rule, one call a KDA layer: a head's state
 #: held in VMEM across its chunks (ops/kda.py)
 KDA_CHUNK = "kda_chunk"
+#: a decode wave's delta rule, one call a KDA layer: a head's matrix read
+#: once and written once where it lies in the stacked state (ops/kda.py)
+KDA_DECODE = "kda_decode"
 KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV,
            FLASH_RES_FWD, FLASH_RES_DQ, FLASH_RES_DKV,
            FLASH_TRI_FWD, FLASH_TRI_BWD, SSM_SCAN, MLA_PAGED_DECODE,
            MLA_ROTARY_LANES, MLA_FLASH_PREFILL, MOE_DISPATCH,
-           MOE_COMBINE, GQA_PAGED_DECODE, GROUPED_SWIGLU, KDA_CHUNK)
+           MOE_COMBINE, GQA_PAGED_DECODE, GROUPED_SWIGLU, KDA_CHUNK,
+           KDA_DECODE)
 
 # -- host phases -------------------------------------------------------------
 SPAN_PREFIX = "raytpu."

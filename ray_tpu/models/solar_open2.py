@@ -70,7 +70,7 @@ from ray_tpu.models.jamba import conv_inputs
 from ray_tpu.models.kimi_k2 import embed, lm_logits, rmsnorm
 from ray_tpu.models.laguna import attend_masked, attn_out
 from ray_tpu.models.llama import _rmsnorm
-from ray_tpu.ops.kda import kda_prefill, kda_step
+from ray_tpu.ops.kda import kda_decode, kda_prefill
 from ray_tpu.parallel.sharding import (DEFAULT_RULES,
                                        with_logical_constraint)
 
@@ -378,20 +378,24 @@ def _unit(x, eps: float = 1e-6):
 
 @jax.named_scope(scopes.ATTN_LINEAR)
 def kda_mix(p, u, cfg: SolarOpen2Config, window, state, real=None,
-            capture=None):
+            capture=None, layer=None):
     """The KDA mixer on normalised input u (B, T, d).
 
     window (d_conv-1, B, 3 x kda_width): the convolutions' last inputs,
-    compute dtype; state (B, H, hd, hd) float32.  real (B, T) bool marks
+    compute dtype; state (B, H, hd, hd) float32 or, with `layer` (one
+    column only), the KDA layers' stack (n_kda, B, H, hd, hd) of which
+    this layer's is entry `layer`: a decode wave hands the whole stack
+    over and it is updated where it lies.  real (B, T) bool marks
     the columns that hold a token: a row's pads come first, its tokens
     after them (left padding), and a pad moves neither window nor state.
     capture: a traced column index (rows all alike) after which window
     and state are also handed back, for a snapshot.  One column (a
-    decode wave) goes through `kda_step`, more through `kda_prefill`
-    (the kernel `kda_chunk` on the chip, the `jnp` scan elsewhere).
+    decode wave) goes through `kda_decode` (the kernel of that name on
+    the chip, `kda_step` elsewhere), more through `kda_prefill` (the
+    kernel `kda_chunk` on the chip, the `jnp` scan elsewhere).
 
-    Returns (out (B, T, d), (window, state), (window, state) after
-    `capture` or None)."""
+    Returns (out (B, T, d), (window, state as it came: a layer's or the
+    stack), (window, state) after `capture` or None)."""
     B, T, _ = u.shape
     H, hd, K = cfg.kda_heads, cfg.kda_head_dim, cfg.d_conv
     dt, f32 = cfg.dtype, jnp.float32
@@ -421,9 +425,11 @@ def kda_mix(p, u, cfg: SolarOpen2Config, window, state, real=None,
         beta = jnp.where(real[..., None], beta, 0.0)
     snap_state = None
     if T == 1:
-        o, new_state = kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
-                                beta[:, 0], state)
-        o = o[:, None]
+        # a layer's state alone is a stack of one
+        stack, j = (state[None], 0) if layer is None else (state, layer)
+        o, stack = kda_decode(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                              beta[:, 0], stack, j)
+        o, new_state = o[:, None], stack[0] if layer is None else stack
     else:
         o, new_state, snap_state = kda_prefill(
             q, k, v, g, beta, state, chunk=cfg.kda_chunk, dtype=dt,
@@ -478,13 +484,13 @@ def gqa_block(x, p, cfg: SolarOpen2Config, attend: Callable, valid=None,
 
 
 def kda_block(x, p, cfg: SolarOpen2Config, window, state, real=None,
-              capture=None, tiled: bool = True):
+              capture=None, tiled: bool = True, layer=None):
     """One KDA layer on x (B, T, d) from (`window`, `state`), `kda_mix`'s
     arguments.  Returns (x, the expert layer's stats, (window, state)
     after the last column, the same after `capture` or None)."""
     out, after, snap = kda_mix(
         p["kda"], rmsnorm(x, p["ln1"]["scale"], cfg.rms_eps), cfg, window,
-        state, real, capture)
+        state, real, capture, layer)
     x, stats = ffn(x + out, p, cfg, real, tiled)
     return x, stats, after, snap
 

@@ -107,9 +107,14 @@ def _layer_state(conv, ssm, j: int):
 
 
 @jax.named_scope(scopes.LINEAR_STATE)
-def _set_layer_state(conv, ssm, j: int, window, state):
-    return (lax.dynamic_update_index_in_dim(conv, window, j, 0),
-            lax.dynamic_update_index_in_dim(ssm, state, j, 0))
+def _layer_window(conv, j: int):
+    """Every row's window of KDA layer `j`."""
+    return lax.dynamic_index_in_dim(conv, j, 0, keepdims=False)
+
+
+@jax.named_scope(scopes.LINEAR_STATE)
+def _set_layer_window(conv, j: int, window):
+    return lax.dynamic_update_index_in_dim(conv, window, j, 0)
 
 
 @jax.named_scope(scopes.LINEAR_STATE)
@@ -314,11 +319,12 @@ def solar_open2_decode_step(params, cache, tokens, cfg: SolarOpen2Config
 
     def layer(x, p, kind, j):
         if kind == KDA:
-            x, stats, after, _ = kda_block(
-                x, p, cfg, *_layer_state(held["conv"], held["ssm"], j),
-                active[:, None])
-            held["conv"], held["ssm"] = _set_layer_state(
-                held["conv"], held["ssm"], j, *after)
+            # the matrices go in and come back as the whole stack: layer
+            # j's are updated where they lie (ops/kda.py kda_decode)
+            x, stats, (window, held["ssm"]), _ = kda_block(
+                x, p, cfg, _layer_window(held["conv"], j), held["ssm"],
+                active[:, None], layer=j)
+            held["conv"] = _set_layer_window(held["conv"], j, window)
             return x, stats
 
         def attend(q, k, v):

@@ -9,12 +9,30 @@ state ``S`` (dk, dv) takes one token so:
     o_t = S_t^T q_t
 
 (arXiv 2510.26692; ``beta`` may reach 2, where ``I - beta k k^T`` has
-an eigenvalue of -1).  Four forms of it, three of them plain
+an eigenvalue of -1).  Five forms of it, three of them plain
 ``jax.numpy`` / ``lax``:
 
   * `kda_recurrent`: the recurrence itself, a scan over time in
     float32: the oracle.
   * `kda_step`: one token a row, for a decode wave.
+  * `kda_decode`: that step on one layer of the KDA layers' STACKED
+    state (n, B, H, dk, dv), which is what a decode program carries,
+    and what a model calls.  On the chip, where a head is whole lanes,
+    it is ONE ``pallas_call`` named ``kda_decode`` a layer, the stack
+    aliased to its result: a grid step fetches eight heads' matrices of
+    layer ``j`` (a scalar-prefetched index) where they lie, updates
+    them in VMEM and writes them back to the same bytes; the other
+    layers are not touched and no (B, H, dk, dv) array exists outside
+    the call.  XLA runs `kda_step` as three fusions a layer, the
+    matrices read three times and written once (``S'^T k`` must be
+    summed before the rank-one update can be formed, and ``S_t^T q``
+    needs the update); here they are read once and written once, at
+    the pace a read and a write through VMEM go at (~600 GB/s of the
+    chip's 819).  The arithmetic is `kda_step`'s, float32, in its
+    order; `_step_kernel` says how ``k``, ``q`` and ``exp(g)`` become
+    the per-sublane factors it needs without a rounding.  Everywhere
+    else (the CPU, heads of 16, a differentiated program) it is
+    `kda_step` on the layer indexed out and set back.
   * `kda_chunked`: a prefill's.  A chunk of ``C`` tokens enters with
     ``S_0``; with ``G_r = sum_{i <= r} g_i`` per channel,
 
@@ -90,8 +108,8 @@ from jax.experimental import pallas as pl
 
 from ray_tpu._private import scopes
 
-__all__ = ["kda_recurrent", "kda_step", "kda_chunked", "kda_chunk",
-           "kda_prefill"]
+__all__ = ["kda_recurrent", "kda_step", "kda_decode", "kda_chunked",
+           "kda_chunk", "kda_prefill"]
 
 _F32 = jnp.float32
 #: a vreg of float32: 8 sublanes of 128 lanes
@@ -104,6 +122,9 @@ _SUBLANES, _LANES = 8, 128
 #: chunks a step alike
 _CHUNKS_A_STEP = 2
 _HEADS_A_STEP = 8
+#: heads a grid step of `kda_decode`: a sublane tile of the folded
+#: operands; their 72 part rows fit one transposed tile (`_step_kernel`)
+_HEADS_A_WAVE_STEP = 8
 
 
 def kda_step(q, k, v, g, beta, state):
@@ -641,3 +662,170 @@ def kda_prefill(q, k, v, g, beta, state=None, *, chunk: int = 64,
         k, v, chunk, min(sub, chunk)) else kda_chunked
     return form(q, k, v, g, beta, state, chunk=chunk, sub=sub, dtype=dtype,
                 capture=capture)
+
+
+# ---------------------------------------------------------------------------
+# a decode wave's kernel
+# ---------------------------------------------------------------------------
+
+def _step_on_layer(q, k, v, g, beta, stack, j):
+    """`kda_step` on layer `j` of the stack (n, B, H, dk, dv), set back:
+    the `jnp` form of `kda_decode`."""
+    with jax.named_scope(scopes.LINEAR_STATE):
+        state = lax.dynamic_index_in_dim(stack, j, 0, keepdims=False)
+    o, new = kda_step(q, k, v, g, beta, state)
+    with jax.named_scope(scopes.LINEAR_STATE):
+        return o, lax.dynamic_update_index_in_dim(
+            stack, new.astype(stack.dtype), j, 0)
+
+
+def _step_kernel(j_ref, q_ref, k_ref, v_ref, g_ref, b_ref, s_ref,
+                 o_ref, st_ref, pick_ref):
+    """One (row, group of eight heads) grid step.  q, k, g (1, H, dk)
+    and v, o (1, H, dv): the row's heads on the sublanes, resident while
+    the row's groups go by; b (1, 1, H); s, st (1, 1, 8, dk, dv): the
+    group's matrices of layer ``j_ref[0]``, the same block of the same
+    buffer in and out.  Scratch `pick` (24, 128, dv) bfloat16: the 0/1
+    selectors below, made at the first grid step.
+
+    `kda_step`'s operations in its order, float32.  A matrix lies with
+    ``dk`` on the sublanes, so the sums over ``dk`` are vreg adds and
+    one sublane reduction each and ``delta`` is a row, spread along the
+    sublanes for free; what costs is ``k``, ``q`` and ``exp(g)`` as
+    COLUMNS spread along the lanes, 48 vregs a head (module
+    docstring).  The MXU makes them, exactly: the group's 24 rows are
+    split into three bfloat16 parts each (their sum is the float32
+    value to the bit), the 72 part rows are transposed as one (128,
+    dk) tile, and a head's column spread over ``dv`` lanes is that tile
+    times a 0/1 matrix that picks its three parts: products with 1 and
+    a float32 sum of three terms, no rounding anywhere."""
+    heads = _HEADS_A_WAVE_STEP
+    group = pl.program_id(1)
+    H, dk = q_ref.shape[1:]
+    dv = v_ref.shape[-1]
+    bf16 = jnp.bfloat16
+
+    @pl.when((pl.program_id(0) == 0) & (group == 0))
+    def _the_selectors():
+        part_row = lax.broadcasted_iota(jnp.int32, (_LANES, dv), 0)
+        for column in range(3 * heads):
+            pick_ref[column] = ((part_row % (3 * heads) == column)
+                                & (part_row < 9 * heads)).astype(bf16)
+
+    rows = pl.ds(pl.multiple_of(group * heads, heads), heads)
+    v = v_ref[0, rows, :]
+    # beta of the group's heads as a column: the row masked to one head
+    # a sublane, summed along the lanes
+    own = (lax.broadcasted_iota(jnp.int32, (heads, H), 1)
+           == group * heads + lax.broadcasted_iota(jnp.int32, (heads, H), 0))
+    beta = jnp.sum(jnp.where(own, b_ref[0], 0.0), axis=-1, keepdims=True)
+    whole = jnp.concatenate([k_ref[0, rows, :], q_ref[0, rows, :],
+                             jnp.exp(g_ref[0, rows, :])], axis=0)
+    high = whole.astype(bf16).astype(_F32)
+    middle = (whole - high).astype(bf16).astype(_F32)
+    low = (whole - high - middle).astype(bf16).astype(_F32)
+    parts = jnp.concatenate(
+        [high, middle, low,
+         jnp.zeros((_LANES - 9 * heads, dk), _F32)], axis=0).T.astype(bf16)
+
+    def spread(which, h):       # column (which, h) along dv lanes
+        return jnp.dot(parts, pick_ref[which * heads + h],
+                       preferred_element_type=_F32)
+
+    out = []
+    for h in range(heads):
+        k_h = spread(0, h)
+        decayed = s_ref[0, 0, h] * spread(2, h)
+        seen = jnp.sum(decayed * k_h, axis=0, keepdims=True)   # S'^T k
+        delta = beta[h:h + 1] * (v[h:h + 1] - seen)
+        new = decayed + k_h * delta
+        st_ref[0, 0, h] = new
+        out.append(jnp.sum(new * spread(1, h), axis=0, keepdims=True))
+    o_ref[0, rows, :] = jnp.concatenate(out, axis=0)
+
+
+def _fits_the_step_kernel(stack) -> bool:
+    """float32 matrices, whole lanes a head both ways, heads in whole
+    groups."""
+    _, _, H, dk, dv = stack.shape
+    return (stack.dtype == _F32 and dk % _LANES == 0 and dv % _LANES == 0
+            and H % _HEADS_A_WAVE_STEP == 0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def _step_kernel_form(q, k, v, g, beta, stack, j, interpret):
+    """The kernel on q, k, g (B, H, dk), v (B, H, dv), beta (B, H),
+    float32, the stack (n, B, H, dk, dv) and j int32 ()."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    _, B, H, dk, dv = stack.shape
+    heads = _HEADS_A_WAVE_STEP
+
+    def row(d):     # a row's heads: fetched once a row, not once a group
+        return pl.BlockSpec((1, H, d), lambda b, h, j: (b, 0, 0))
+
+    matrices = pl.BlockSpec((1, 1, heads, dk, dv),
+                            lambda b, h, j: (j[0], b, h, 0, 0))
+    with jax.named_scope(scopes.ATTN_LINEAR):
+        o, stack = pl.pallas_call(
+            _step_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(B, H // heads),
+                in_specs=[row(dk), row(dk), row(dv), row(dk),
+                          pl.BlockSpec((1, 1, H), lambda b, h, j: (b, 0, 0)),
+                          matrices],
+                out_specs=[row(dv), matrices],
+                scratch_shapes=[
+                    pltpu.VMEM((3 * heads, _LANES, dv), jnp.bfloat16)]),
+            out_shape=[jax.ShapeDtypeStruct((B, H, dv), _F32),
+                       jax.ShapeDtypeStruct(stack.shape, _F32)],
+            # the stack is the result: only layer j's blocks are fetched
+            # and written, the other layers' bytes are not touched
+            input_output_aliases={6: 1},
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary")),
+            interpret=interpret,
+            name=scopes.KDA_DECODE,
+        )(jnp.reshape(j, (1,)), q, k, v, g, beta.reshape(B, 1, H), stack)
+    return o, stack
+
+
+def _step_jnp_fwd(q, k, v, g, beta, stack, j, interpret):
+    # a differentiated program runs the `jnp` form, forward and backward
+    out, vjp = jax.vjp(lambda *a: _step_on_layer(*a, j),
+                       q, k, v, g, beta, stack)
+    return out, vjp
+
+
+def _step_jnp_bwd(interpret, vjp, cts):
+    return (*vjp(cts), np.zeros((), jax.dtypes.float0))
+
+
+_step_kernel_form.defvjp(_step_jnp_fwd, _step_jnp_bwd)
+
+
+#: jitted: the KDA layers of one program share one trace and one
+#: lowering of the kernel
+_step_kernel_call = jax.jit(_step_kernel_form, static_argnums=(7,))
+
+
+def kda_decode(q, k, v, g, beta, stack, j, *, interpret: bool = False):
+    """A decode wave's delta rule on layer `j` of the KDA layers'
+    stacked state.  q, k, g (B, H, dk); v (B, H, dv); beta (B, H);
+    stack (n, B, H, dk, dv) float32; j an index into its first axis.
+    Returns (o (B, H, dv) float32, the stack with layer `j` one token
+    on and every other layer as it was).
+
+    By the form that fits what the program can see: one Pallas call
+    named ``kda_decode`` on the chip where a head is whole lanes and
+    the heads whole groups of eight (module docstring), else `kda_step`
+    on the layer indexed out and set back: the CPU, heads of 16.
+    ``interpret=True`` runs the kernel in the Pallas interpreter where
+    the shapes fit it (the CPU tests).  Differentiated, it is the `jnp`
+    form, forward and backward."""
+    j = jnp.asarray(j, jnp.int32)
+    if not (_fits_the_step_kernel(stack)
+            and (interpret or jax.default_backend() == "tpu")):
+        return _step_on_layer(q, k, v, g, beta, stack, j)
+    return _step_kernel_call(*(a.astype(_F32) for a in (q, k, v, g, beta)),
+                             stack, j, interpret)

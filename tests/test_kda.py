@@ -1,7 +1,7 @@
 """ops/kda.py: the chunked delta rule and the one-token step against the
-recurrence over time, in float32 on the CPU; the chunk form twice: the
-`jnp` scan at small heads, and the kernel (`kda_chunk`, in the Pallas
-interpreter) at heads of whole lanes."""
+recurrence over time, in float32 on the CPU; each form twice: the `jnp`
+one at small heads, and the kernel (`kda_chunk`, `kda_decode`, in the
+Pallas interpreter) at heads of whole lanes."""
 
 import functools
 
@@ -12,7 +12,8 @@ import pytest
 
 from ray_tpu.ops import kda
 from ray_tpu.ops.kda import (_solve_unit_lower, kda_chunk, kda_chunked,
-                             kda_prefill, kda_recurrent, kda_step)
+                             kda_decode, kda_prefill, kda_recurrent,
+                             kda_step)
 
 B, H, DK, DV = 2, 3, 16, 8
 #: the two chunk forms and the sizes each is run at: the kernel takes
@@ -22,6 +23,19 @@ FORMS = {"jnp": (kda_chunked, (B, H, DK, DV)),
          "kernel": (functools.partial(kda_chunk, interpret=True),
                     (1, 3, 128, 128))}
 forms = pytest.mark.parametrize("form", sorted(FORMS))
+
+
+def _kernel_step(q, k, v, g, beta, state):
+    """`kda_step`'s contract through the kernel: a stack of one."""
+    o, stack = kda_decode(q, k, v, g, beta, state[None], 0, interpret=True)
+    return o, stack[0]
+
+
+#: the two step forms and the sizes each is run at: the kernel takes
+#: heads of whole lanes in groups of eight
+STEP_FORMS = {"jnp": (kda_step, (B, H, DK, DV)),
+              "kernel": (_kernel_step, (2, 8, 128, 128))}
+step_forms = pytest.mark.parametrize("form", sorted(STEP_FORMS))
 
 
 def _inputs(seed, T, decay=(0.5, 0.999), beta_max=2.0, state=True,
@@ -194,9 +208,11 @@ def test_the_solve_is_the_inverse():
     _close(_solve_unit_lower(low, rhs, 8), jnp.asarray(want, jnp.float32))
 
 
-def test_a_step_is_one_step_of_the_recurrence():
-    (q, k, v, g, beta), s0 = _inputs(4, 1)
-    o, s = kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], s0)
+@step_forms
+def test_a_step_is_one_step_of_the_recurrence(form):
+    step, dims = STEP_FORMS[form]
+    (q, k, v, g, beta), s0 = _inputs(4, 1, dims=dims)
+    o, s = step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], s0)
     want_o, want_s = kda_recurrent(q, k, v, g, beta, s0)
     _close(o, want_o[:, 0])
     _close(s, want_s)
@@ -211,12 +227,91 @@ def test_a_step_is_one_step_of_the_recurrence():
         atol=1e-5)
 
 
-def test_an_idle_row_keeps_its_state_to_the_bit():
-    """beta = 0 and g = 0 (a decode pool's row without a sequence)."""
-    (q, k, v, _, _), s0 = _inputs(6, 1)
-    _, s = kda_step(q[:, 0], k[:, 0], v[:, 0], jnp.zeros((B, H, DK)),
-                    jnp.zeros((B, H)), s0)
-    assert bool(jnp.all(s == s0))
+@step_forms
+def test_an_idle_row_keeps_its_state_to_the_bit(form):
+    """beta = 0 and g = 0 (a decode pool's row without a sequence),
+    beside a row that moves."""
+    step, dims = STEP_FORMS[form]
+    (q, k, v, g, beta), s0 = _inputs(6, 1, dims=dims)
+    idle = jnp.arange(dims[0]) == 0
+    _, s = step(q[:, 0], k[:, 0], v[:, 0],
+                jnp.where(idle[:, None, None], 0.0, g[:, 0]),
+                jnp.where(idle[:, None], 0.0, beta[:, 0]), s0)
+    assert bool(jnp.all(s[0] == s0[0]))
+    assert not bool(jnp.all(s[1] == s0[1]))
+
+
+@step_forms
+def test_steps_of_equal_keys_and_beta_two_stay_bounded(form):
+    """With every key equal and beta 2 a step is a reflection (an
+    eigenvalue of -1): twelve of them stay where the recurrence is."""
+    T = 12
+    step, (_, heads, DK, DV) = STEP_FORMS[form]
+    k = jnp.broadcast_to(jnp.eye(DK)[0], (1, T, heads, DK))
+    v = jax.random.normal(jax.random.PRNGKey(0), (1, T, heads, DV))
+    g = jnp.zeros((1, T, heads, DK))
+    beta = jnp.full((1, T, heads), 2.0)
+    want_o, want_s = kda_recurrent(k, k, v, g, beta)
+    s = jnp.zeros((1, heads, DK, DV))
+    for t in range(T):
+        o, s = step(k[:, t], k[:, t], v[:, t], g[:, t], beta[:, t], s)
+        _close(o, want_o[:, t])
+    _close(s, want_s)
+    assert float(jnp.max(jnp.abs(s))) <= 2.0 * float(
+        jnp.max(jnp.sum(jnp.abs(v), axis=1)))
+
+
+@pytest.mark.parametrize("j", range(3))
+@step_forms
+def test_a_step_on_a_stack_moves_the_layer_it_names(form, j):
+    """`kda_decode` on the KDA layers' stack: layer `j` is `kda_step` on
+    its slice, the two layers not named come back to the bit."""
+    dims = STEP_FORMS[form][1]
+    (q, k, v, g, beta), _ = _inputs(10 + j, 1, dims=dims)
+    stack = jax.random.normal(jax.random.PRNGKey(3), (3, *dims))
+    o, after = kda_decode(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
+                          stack, j, interpret=form == "kernel")
+    want_o, want = kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
+                            stack[j])
+    _close(o, want_o)
+    _close(after[j], want)
+    for other in set(range(3)) - {j}:
+        assert bool(jnp.all(after[other] == stack[other]))
+
+
+def test_the_step_kernel_runs_only_where_it_fits(monkeypatch):
+    """`kda_decode` picks by what it can see: off the chip, at heads
+    that are no whole lanes or in no whole groups of eight it is
+    `kda_step` on the layer; a differentiated kernel form is that too,
+    forward and backward."""
+    def program(dims, **kw):
+        (q, k, v, g, beta), s0 = _inputs(2, 1, dims=dims)
+        return str(jax.make_jaxpr(lambda stack: kda_decode(
+            q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], stack, 1,
+            **kw))(jnp.stack([s0, s0])))
+
+    wide = (1, 8, 128, 128)
+    assert "pallas_call" not in program(wide)              # the CPU
+    assert "pallas_call" in program(wide, interpret=True)
+    assert "pallas_call" not in program((B, H, DK, DV), interpret=True)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert "pallas_call" in program(wide)
+    assert "pallas_call" not in program((B, H, DK, DV))     # heads of 16 x 8
+    assert "pallas_call" not in program((1, 4, 128, 128))   # half a group
+    monkeypatch.undo()
+
+    (q, k, v, g, beta), s0 = _inputs(2, 1, dims=wide)
+
+    def loss(interpret, v):
+        o, stack = kda_decode(q[:, 0], k[:, 0], v, g[:, 0], beta[:, 0],
+                              s0[None], 0, interpret=interpret)
+        return jnp.sum(o * o) + jnp.sum(stack)
+
+    _close(jax.grad(functools.partial(loss, True))(v[:, 0]),
+           jax.grad(functools.partial(loss, False))(v[:, 0]))
+    text = str(jax.make_jaxpr(jax.grad(functools.partial(loss, True)))(
+        v[:, 0]))
+    assert "pallas_call" not in text
 
 
 @forms

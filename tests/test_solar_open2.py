@@ -157,6 +157,34 @@ def test_generate_equals_the_full_forward(layout, params):
         out[:, 20:], logits[:, 19:-1, :F32.vocab_size].argmax(-1))
 
 
+@pytest.mark.parametrize("layout", ["dense", "paged"])
+def test_decode_steps_through_the_kernel_answer_as_generate(layout,
+                                                            monkeypatch):
+    """Eight KDA heads of 128, where the decode wave's kernel fits:
+    with `kda_decode` held to it (interpreted: the CPU picks `kda_step`)
+    the greedy tokens of both cache layouts are `solar_open2_generate`'s
+    own, which are the `jnp` form's."""
+    import functools
+
+    cfg = so.solar_open2_config("nano", dtype=jnp.float32, kda_heads=8,
+                                kda_head_dim=128, held=tuple(range(8)))
+    wide = so.solar_open2_init(jax.random.PRNGKey(4), cfg)
+    prompt = jnp.asarray(_tokens(5, 2, 12))
+
+    def generate():     # a new program: the patched name is read traced
+        return np.asarray(jax.jit(lambda p, t: m.solar_open2_generate(
+            p, t, cfg, max_new_tokens=6, temperature=0.0, kv_layout=layout,
+            kv_block_size=BS))(wide, prompt))
+
+    want = generate()
+    called = []
+    monkeypatch.setattr(so, "kda_decode", lambda *a: called.append(
+        a[5].shape) or kda.kda_decode(*a, interpret=True))
+    np.testing.assert_array_equal(generate(), want)
+    # the whole stack goes in, once a KDA layer of the scanned step
+    assert called == [(3, 2, 8, 128, 128)] * 3, called
+
+
 def _paged(slots=3, blocks=40):
     return m.solar_open2_init_paged_cache(F32, slots, num_blocks=blocks,
                                           block_size=BS)
@@ -276,9 +304,10 @@ def test_the_shares_add_up(params):
     np.testing.assert_allclose(total, want, atol=TOL)
 
 
-def _bf16_state(q, k, v, g, beta, state):
-    o, new = kda.kda_step(q, k, v, g, beta, state)
-    return o, new.astype(jnp.bfloat16).astype(jnp.float32)
+def _bf16_state(q, k, v, g, beta, stack, j):
+    o, stack = kda.kda_decode(q, k, v, g, beta, stack, j)
+    return o, stack.at[j].set(
+        stack[j].astype(jnp.bfloat16).astype(jnp.float32))
 
 
 def _no_carried_state(*args, **kw):
@@ -309,7 +338,7 @@ def test_a_wrong_model_fails_the_tolerance(fault, params, want,
     toks, logits = want
     cfg = F32
     if fault == "bf16_state":
-        monkeypatch.setattr(so, "kda_step", _bf16_state)
+        monkeypatch.setattr(so, "kda_decode", _bf16_state)
     elif fault == "no_carried_state":
         monkeypatch.setattr(so, "kda_prefill", _no_carried_state)
     elif fault == "beta_not_doubled":

@@ -37,7 +37,8 @@ TOY = chip_smoke.Size(
     mla_tile=16, mla_prefills=((64, 0, 51), (64, 60, 14)),
     mla_wave=(5, 24, 3), moe_rows=((64, 32), (8, 8)), moe_held=6,
     gqa_preset="nano", gqa_max_seq=128, gqa_wave=(5, 24),
-    kda_heads=(2, 128), kda_prefills=((96, 13), (160, 70)))
+    kda_heads=(8, 128), kda_prefills=((96, 13), (160, 70)),
+    kda_wave=(6, 3))
 
 
 @pytest.fixture
