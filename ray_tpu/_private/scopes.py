@@ -56,7 +56,10 @@ MOE_EXPERTS = "moe_experts"
 #: a layer that attends the whole context, and one that attends a
 #: bounded window of it (models/laguna.py: the two kinds differ in
 #: head count, rotary and reach): each kind's projections, rotary,
-#: scores, per-head gate and output projection
+#: scores, per-head gate and output projection.  Laguna's rings pass
+#: under ``kv_pool``; models/phi4flash_decode.py, whose pool is one
+#: layer's that eight read, keeps its rings' writes and slices under
+#: ``attn_window``
 ATTN_FULL = "attn_full"
 ATTN_WINDOW = "attn_window"
 #: a linear-attention layer whose state is a matrix a head
@@ -66,6 +69,14 @@ ATTN_LINEAR = "attn_linear"
 #: reads and writes of such layers' per-slot matrices and convolution
 #: windows and of their snapshot pool: ``ssm_state``'s twin
 LINEAR_STATE = "linear_state"
+#: a layer that keeps no K/V of its own and attends another layer's
+#: (models/phi4flash.py: the cross-decoder reads the one full layer's
+#: pool): its query projection, its walk of the shared pool, the
+#: differential combine and norm, its output projection
+ATTN_CROSS = "attn_cross"
+#: a Gated Memory Unit (models/phi4flash.py): its two products and the
+#: gate over the memory an earlier layer left for the same token
+GMU = "gmu"
 #: the decode programs' scan over layers: what no inner scope claims is
 #: the scan's own plumbing (slicing the stacked weights, stacking the
 #: per-layer K/V it returns)
@@ -84,7 +95,8 @@ REWRITTEN = {"ragged-dot": MOE_EXPERTS}
 DEVICE_SCOPES = frozenset((EMBED, ATTN, MLP, LN, LM_HEAD_CE, LM_HEAD,
                            KV_POOL, SAMPLE, SSM, SSM_STATE, MLA,
                            MOE_ROUTER, MOE_EXPERTS, ATTN_FULL, ATTN_WINDOW,
-                           ATTN_LINEAR, LINEAR_STATE, LAYER_SCAN,
+                           ATTN_LINEAR, LINEAR_STATE, ATTN_CROSS, GMU,
+                           LAYER_SCAN,
                            LOSS_AND_GRAD, OPTIMIZER))
 
 # -- Pallas kernel names (``pallas_call(name=)`` in ops/*.py) ----------------

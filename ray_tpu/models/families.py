@@ -34,10 +34,16 @@ from typing import Any, Callable, Dict, Optional, Tuple
 #: attend everything (models/laguna_decode.py): per-slot state as a
 #: recurrent layer's is, carried by the same `state` argument and the
 #: same snapshots, and refused where that is.
+#: RECURRENT_WINDOWED: both kinds of per-slot state in one slot, a
+#: recurrent state a Mamba layer and a ring a window layer, beside a
+#: pool that ONE layer writes and several read
+#: (models/phi4flash_decode.py).  Every refusal of the two kinds holds
+#: for it, for both reasons at once.
 KV = "kv"
 RECURRENT = "kv+recurrent"
 LATENT = "latent"
 WINDOWED = "kv+window"
+RECURRENT_WINDOWED = "kv+recurrent+window"
 #: what a cache that is not plain K/V keeps, as a refusal words it
 #: (of an engine option: serve/llm.py; of a mesh: decode_common.py)
 CACHE_HOLDS = {
@@ -46,10 +52,13 @@ CACHE_HOLDS = {
             "or V per head",
     WINDOWED: "a ring of each window layer's last K/V rows per slot "
               "beside the full layers' K/V pool",
+    RECURRENT_WINDOWED: "one recurrent state and a ring of each window "
+                        "layer's last K/V rows per slot beside the K/V "
+                        "pool its layers share",
 }
 #: the kinds that keep state per SLOT beside the pool: their paged
 #: prefill takes `state`, and a prefix is reused from a snapshot of it
-PER_SLOT_STATE = frozenset((RECURRENT, WINDOWED))
+PER_SLOT_STATE = frozenset((RECURRENT, WINDOWED, RECURRENT_WINDOWED))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -160,12 +169,28 @@ def _solar_open2() -> Dict[str, Any]:
         init_paged_cache=m.solar_open2_init_paged_cache)
 
 
+def _phi4flash() -> Dict[str, Any]:
+    from ray_tpu.models import phi4flash_decode as m
+    from ray_tpu.models.phi4flash import (phi4flash_config, phi4flash_init,
+                                          phi4flash_logical_axes)
+
+    return dict(
+        config=phi4flash_config, init=phi4flash_init,
+        logical_axes=phi4flash_logical_axes,
+        generate=m.phi4flash_generate, prefill=m.phi4flash_prefill,
+        paged_prefill=m.phi4flash_paged_prefill,
+        step=m.phi4flash_decode_step, verify=None,
+        init_cache=m.phi4flash_init_cache,
+        init_paged_cache=m.phi4flash_init_paged_cache)
+
+
 #: family -> (what its cache holds, loader of its programs)
 FAMILIES: Dict[str, Tuple[str, Callable[[], Dict[str, Any]]]] = {
     "gpt2": (KV, _gpt2), "llama": (KV, _llama),
     "jamba": (RECURRENT, _jamba), "kimi_k2": (LATENT, _kimi_k2),
     "laguna": (WINDOWED, _laguna),
-    "solar_open2": (RECURRENT, _solar_open2)}
+    "solar_open2": (RECURRENT, _solar_open2),
+    "phi4flash": (RECURRENT_WINDOWED, _phi4flash)}
 
 
 def cache_kind(name: str) -> Optional[str]:

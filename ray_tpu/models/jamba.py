@@ -428,9 +428,11 @@ def conv_inputs(x, window, real=None):
         e, w, (at, 0)))(ext, win, pads)
 
 
-def mamba_mix(p, u, cfg: JambaConfig, window, state, real=None,
-              capture=None):
-    """The Mamba mixer on normalised input u (B, T, d).
+def mamba_mix(p, u, cfg, window, state, real=None, capture=None):
+    """The Mamba mixer on normalised input u (B, T, d).  `cfg` is any
+    config with this one's mixer fields (models/phi4flash.py hands its
+    own); a `p` without ``dt_norm``, ``b_norm``, ``c_norm`` is plain
+    Mamba-1: ``dt``, ``B`` and ``C`` go unnormed.
 
     window (K-1, B, di): the convolution's last inputs, compute dtype;
     state (B, N, di): the SSM state.  real (B, T) bool marks the columns
@@ -440,7 +442,8 @@ def mamba_mix(p, u, cfg: JambaConfig, window, state, real=None,
     are also handed back, for a snapshot.
 
     Returns (out (B, T, d), (window, state), (window, state) after
-    `capture` or None)."""
+    `capture` or None, y (B, T, di) float32: the scan's output before
+    its gate, with the ``D`` skip in it)."""
     B, T, _ = u.shape
     di, N, K, R = cfg.d_inner, cfg.d_state, cfg.d_conv, cfg.dt_rank
     f32 = jnp.float32
@@ -457,12 +460,15 @@ def mamba_mix(p, u, cfg: JambaConfig, window, state, real=None,
         dbc = jnp.einsum("btd,dr->btr", xc.astype(cfg.dtype),
                          p["x_proj"].astype(cfg.dtype),
                          preferred_element_type=f32)
-        dt_in = _rmsnorm(dbc[..., :R], p["dt_norm"].astype(f32),
-                         cfg.rms_eps)
-        Bm = _rmsnorm(dbc[..., R:R + N], p["b_norm"].astype(f32),
-                      cfg.rms_eps)
-        Cm = _rmsnorm(dbc[..., R + N:], p["c_norm"].astype(f32),
-                      cfg.rms_eps)
+
+        def normed(a, scale):
+            if scale not in p:
+                return a
+            return _rmsnorm(a, p[scale].astype(f32), cfg.rms_eps)
+
+        dt_in = normed(dbc[..., :R], "dt_norm")
+        Bm = normed(dbc[..., R:R + N], "b_norm")
+        Cm = normed(dbc[..., R + N:], "c_norm")
         dt = jax.nn.softplus(
             jnp.einsum("btr,rd->btd", dt_in.astype(cfg.dtype),
                        p["dt_proj"].astype(cfg.dtype),
@@ -485,7 +491,7 @@ def mamba_mix(p, u, cfg: JambaConfig, window, state, real=None,
                     snap_state.astype(state.dtype))
     return (out.astype(u.dtype),
             (new_window.astype(window.dtype),
-             new_state.astype(state.dtype)), snap)
+             new_state.astype(state.dtype)), snap, y)
 
 
 def zero_recurrent(cfg: JambaConfig, batch: int, layers: bool = True):
@@ -514,7 +520,7 @@ def jamba_hidden(params, tokens, cfg: JambaConfig, rules=DEFAULT_RULES):
 
     def mamba_layer(x, carry, m):
         p = layer_at(params["mamba"], m)
-        out, _, _ = mamba_mix(
+        out, _, _, _ = mamba_mix(
             p["mixer"], rmsnorm(x, p["ln1"]["scale"], cfg.rms_eps), cfg,
             window, state)
         x = mlp_residual(x + out, p, cfg)

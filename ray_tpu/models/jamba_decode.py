@@ -145,7 +145,7 @@ def jamba_prefill(params, tokens: jnp.ndarray, cfg: JambaConfig, *,
 
     def mamba_layer(x, rec, m):
         p = layer_at(params["mamba"], m)
-        out, (window, state), _ = mamba_mix(
+        out, (window, state), _, _ = mamba_mix(
             p["mixer"], rmsnorm(x, p["ln1"]["scale"], cfg.rms_eps), cfg,
             *_layer_state(*rec, m), real=real)
         return (mlp_residual(x + out, p, cfg),
@@ -239,7 +239,7 @@ def jamba_paged_prefill(params, cache, tokens: jnp.ndarray,
     def mamba_layer(x, carry, m):
         pools, rec, snaps = carry
         p = layer_at(params["mamba"], m)
-        out, (window, st), snap = mamba_mix(
+        out, (window, st), snap, _ = mamba_mix(
             p["mixer"], rmsnorm(x, p["ln1"]["scale"], cfg.rms_eps), cfg,
             *_layer_state(*rec, m), real=real[None], capture=capture)
         return mlp_residual(x + out, p, cfg), (
@@ -303,7 +303,7 @@ def jamba_decode_step(params, cache, tokens, cfg: JambaConfig
         pools, rec = carry
         p = layer_at(params["mamba"], m)
         u = rmsnorm(x, p["ln1"]["scale"], cfg.rms_eps)
-        out, (window, state), _ = mamba_mix(
+        out, (window, state), _, _ = mamba_mix(
             p["mixer"], u[:, None], cfg, *_layer_state(*rec, m),
             real=active)
         rec = _set_layer_state(*rec, m, window, state)

@@ -354,18 +354,21 @@ def attn_out(o, gate, p, cfg: LagunaConfig):
         -1, cfg.d_model)
 
 
-def attend_masked(q, k, v, mask, cfg: LagunaConfig):
+def attend_masked(q, k, v, mask, cfg, scale=None):
     """q (B, T, H, hd) over folded k, v (B, S, kv_width) under mask (B,
     T, S): grouped queries, no head repeated; (B, T, H, hd).  The whole
     score matrix: the full-sequence forward and the dense cache's
-    programs, small sizes."""
+    programs, small sizes.  `cfg`: any config with ``n_kv_head`` and
+    ``dtype``; `scale`: the scores' factor where it is not ``1 /
+    sqrt(hd)``."""
     B, T, H, hd = q.shape
     S, kv = k.shape[1], cfg.n_kv_head
     qg = q.reshape(B, T, kv, H // kv, hd)
     kh = k.reshape(B, S, kv, hd)
     vh = v.reshape(B, S, kv, hd)
     s = jnp.einsum("btkgd,bskd->bkgts", qg, kh).astype(jnp.float32)
-    s = jnp.where(mask[:, None, None], s / math.sqrt(hd), -1e30)
+    s = s / math.sqrt(hd) if scale is None else s * scale
+    s = jnp.where(mask[:, None, None], s, -1e30)
     probs = jax.nn.softmax(s, axis=-1).astype(cfg.dtype)
     return jnp.einsum("bkgts,bskd->btkgd", probs, vh).reshape(B, T, H, hd)
 

@@ -111,23 +111,24 @@ def laguna_init_paged_cache(cfg: LagunaConfig, batch: int, *,
 
 # -- grouped-query attention over folded K/V ---------------------------------
 
-def _head(x, g: int, cfg: LagunaConfig):
+def _head(x, g: int, cfg):
     """K/V head `g` of folded rows x (..., kv_width): a lane slice."""
     return x[..., g * cfg.head_dim:(g + 1) * cfg.head_dim]
 
 
-def _rows_scores(q, k, cfg: LagunaConfig):
+def _rows_scores(q, k, cfg, scale=None):
     """Every row against its OWN keys: q (B, H, hd), k (B, S, kv_width)
-    -> (B, H, S) float32, scaled."""
+    -> (B, H, S) float32, scaled (by ``1 / sqrt(head_dim)`` unless the
+    caller states a `scale`)."""
     G = q.shape[1] // cfg.n_kv_head
     s = jnp.concatenate([
         jnp.einsum("bgd,bsd->bgs", q[:, g * G:(g + 1) * G],
                    _head(k, g, cfg), preferred_element_type=jnp.float32)
         for g in range(cfg.n_kv_head)], axis=1)
-    return s / math.sqrt(cfg.head_dim)
+    return s / math.sqrt(cfg.head_dim) if scale is None else s * scale
 
 
-def _rows_values(e, v, cfg: LagunaConfig):
+def _rows_values(e, v, cfg):
     """Weights e (B, H, S) over each row's own values v (B, S,
     kv_width): (B, H, hd) float32."""
     G = e.shape[1] // cfg.n_kv_head
@@ -138,8 +139,11 @@ def _rows_values(e, v, cfg: LagunaConfig):
         for g in range(cfg.n_kv_head)], axis=1)
 
 
-def attend_banded(q, k, v, first, last, cfg: LagunaConfig, scope: str):
-    """One sequence's attention without its score matrix.  q (T, H,
+def attend_banded(q, k, v, first, last, cfg, scope: str, scale=None):
+    """One sequence's attention without its score matrix (`cfg`: any
+    config with ``dtype``, ``attn_block``, ``n_kv_head`` and
+    ``head_dim``; `scale`: the scores' factor where it is not ``1 /
+    sqrt(head_dim)``).  q (T, H,
     hd); k, v (S, kv_width) folded; query t attends the key INDICES
     ``first[t] <= a <= last[t]`` ((T,) int32; a query with ``last <
     first`` attends nothing and gives zeros).  A tile of queries walks
@@ -151,7 +155,8 @@ def attend_banded(q, k, v, first, last, cfg: LagunaConfig, scope: str):
     dt = cfg.dtype
     qb, kb = _block_of(cfg, T), _block_of(cfg, S)
     G = H // cfg.n_kv_head
-    scale = 1.0 / math.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
     empty = last < first
     first = jnp.where(empty, S, first)
     last = jnp.where(empty, -1, last)
@@ -221,13 +226,18 @@ def _ring_of(rows, end, window: int):
     return jnp.take(rows, (jnp.arange(window) - end) % window, axis=-2)
 
 
-@jax.named_scope(scopes.ATTN_WINDOW)
-def _attend_ring(q, ring_k, ring_v, mask, cfg: LagunaConfig):
-    """q (B, H, hd) over each row's ring (B, window, kv_width) under
-    mask (B, window): (B, H, hd) in the compute dtype."""
-    s = jnp.where(mask[:, None], _rows_scores(q, ring_k, cfg), -1e30)
+def attend_rows(q, k, v, mask, cfg, scale=None):
+    """q (B, H, hd) over each row's OWN rows k, v (B, S, kv_width)
+    under mask (B, S): (B, H, hd) in the compute dtype.  The whole
+    score row: a ring, or a small dense cache."""
+    s = jnp.where(mask[:, None], _rows_scores(q, k, cfg, scale), -1e30)
     probs = jax.nn.softmax(s, axis=-1)
-    return _rows_values(probs, ring_v, cfg).astype(cfg.dtype)
+    return _rows_values(probs, v, cfg).astype(cfg.dtype)
+
+
+#: a window layer's decode column over each row's ring (B, window,
+#: kv_width) under mask (B, window)
+_attend_ring = jax.named_scope(scopes.ATTN_WINDOW)(attend_rows)
 
 
 # -- a full layer's decode column over the pool -------------------------------

@@ -44,6 +44,9 @@ def interpreted(monkeypatch):
 # one wave each: the rows' lengths, the tables' kind, max_seq, and
 # (query heads, K/V heads, head size)
 NANO, ODD, PUBLISHED = (6, 2, 16), (9, 3, 8), (48, 8, 128)
+#: differential attention's pair-heads (models/phi4flash.py): 40 padded
+#: query sub-heads over 10 K/V pair-heads of 128 lanes, a group of 4
+PAIRS = (40, 10, 128)
 WAVES = {
     "ragged": ([1, 16, 17, 0, 100, 300, EDGE + 200], "out_of_order",
                1024, NANO),
@@ -57,6 +60,7 @@ WAVES = {
     "an_odd_grouping": ([5, 0, EDGE + 3, 77], "out_of_order", 1024, ODD),
     "published_heads": ([EDGE + 1, 0, 40], "out_of_order", 1024,
                         PUBLISHED),
+    "pair_heads": ([EDGE + 17, 0, 33, 512], "shared", 1024, PAIRS),
 }
 
 
@@ -94,7 +98,8 @@ def test_a_wave_through_the_kernel_is_the_gathered_views(wave, dtype):
     chunk's edge, past the table, rows without a sequence, each with its
     fresh row, over tables out of order and shared between rows, in the
     second layer of the pools; 6 query heads over 2 K/V heads, 9 over 3,
-    and the published 48 over 8 of 128."""
+    the published 48 over 8 of 128, and 40 over 10 of 128 (a group of
+    4: 1,280 lanes a row)."""
     args, fresh, kw = _wave(wave, dtype)
     got = np.asarray(gqa_paged_decode(*args, 1, fresh, interpret=True,
                                       **kw), np.float32)

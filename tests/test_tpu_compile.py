@@ -863,3 +863,51 @@ def test_solar_open2_programs_fit_the_chip_at_the_published_widths(
     if program == "decode":
         # 148 MB with `kda_step`'s three fusions a layer (PR 50), 146 now
         assert memory.temp_size_in_bytes < 0.15e9, memory
+
+
+@pytest.mark.parametrize("program,t_pad", [("decode", 0), ("prefill", 4096)])
+def test_phi4flash_programs_fit_the_chip_at_the_published_widths(
+        program, t_pad, monkeypatch):
+    """The cell phi4-mini-flash.serve-offline-cot's two programs as the
+    engine builds them (benchmark/families/phi4flash.py
+    aot_serve_programs): the whole model at its published widths in
+    bf16, 64 slots over a 1.62 GB pool of ONE layer (19,760 blocks of
+    1,280 lanes) and 3.1 GB of Mamba state, convolution rows, rings and
+    their snapshots, the 4,096-token prefill bucket.  Weights and cache
+    are 12.42 GB, 78% of the chip; the compiled peak stays under 13.5
+    GB; pool AND all eight state tensors are donated and updated in
+    place.  A decode step walks the pool with the kernel
+    ``gqa_paged_decode`` at 10 pair-heads of 128 lanes and a group of 4
+    (the program asks ``jax.default_backend()``, steered here): one call
+    under ``attn_full`` and one under ``attn_cross``, the scan's body
+    that the seven cross layers share.  A prefill's Mamba layers are
+    ``ssm_scan`` kernels (the pairs' scan body and the memory layer),
+    and it holds no walk."""
+    from ray_tpu._private import scopes
+    from ray_tpu.models.phi4flash import phi4flash_init
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, params, cache, programs, n_blocks = _serving_cell(
+        "phi4-mini-flash.serve-offline-cot", phi4flash_init, t_pad or 512)
+    assert cache["k"].shape == (1, 19760, 16, 1280) and n_blocks == 19760
+    assert cache["wk"].shape == (8, 64, 512, 1280)
+    assert cache["ssm"].shape == (9, 64, 16, 5120)
+    held = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(
+        (params, cache)))
+    assert 12.4e9 < held < 12.45e9                 # 78% of the chip
+    fn, args = programs[program]
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args).compile()
+    memory = compiled.memory_analysis()
+    assert memory.peak_memory_in_bytes < 13.5e9, memory
+    assert memory.alias_size_in_bytes >= 4.71e9    # pool and state, in place
+    scoped = scopes.scope_map_from_hlo(compiled.as_text())
+    calls = {name: set(keyed.values()) for name, keyed in scoped.items()
+             if any("custom-call" in key for key in keyed)}
+    walks = sorted(next(iter(s)) for name, s in calls.items()
+                   if name.startswith(scopes.GQA_PAGED_DECODE))
+    assert walks == ([scopes.ATTN_CROSS, scopes.ATTN_FULL]
+                     if program == "decode" else [])
+    scans = [s for name, s in calls.items()
+             if name.startswith(scopes.SSM_SCAN)]
+    assert scans == ([{scopes.SSM}] * 2 if program == "prefill" else [])
