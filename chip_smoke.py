@@ -49,6 +49,16 @@ Phases, one chip:
            wave's: 64 rows, idle ones among them, on the middle layer of
            a stack of three, against `kda_step` on that layer.  Prints
            both forms' milliseconds, and the wave's GB/s.
+  ring     the window layers' decode kernel at the Phi-4-mini-flash and
+           Laguna-XS.2 cells' shapes (eight stacked rings of 64 rows x
+           512 x 1,280 lanes under 10 pair-heads; three of 1,024 lanes
+           under 8 K/V heads): every row's ring read where it lies in
+           the stack against `attend_rows` over the ring sliced out, in
+           the first and last layer, rows from idle to several laps;
+           then a wave as the decode steps run it (a scan over the
+           layers, the new rows scattered into the donated stacks): the
+           compiled program aliases both stacks and holds no temporary
+           of a ring's size.  Prints ms a call and GB/s.
 Phases, --chips 4 (and no one-chip phase):
   mesh_train    the train step over data=4 and data=2 x fsdp=2 against
                 the same step on device 0.
@@ -71,7 +81,7 @@ import sys
 import time
 from typing import Any, Dict, List
 
-ONE_CHIP = ("train", "serve", "runtime", "mla", "gqa", "kda")
+ONE_CHIP = ("train", "serve", "runtime", "mla", "gqa", "kda", "ring")
 FOUR_CHIPS = ("mesh_train", "tensor_serve", "fleet")
 #: the driver allows 1200 s; leave room for the parent's own exit
 DEADLINE_S = 1100.0
@@ -152,6 +162,12 @@ class Size:
     kda_heads: tuple = (64, 128)
     kda_prefills: tuple = ((1024, 37), (8192, 700))
     kda_wave: tuple = (64, 3)
+    # ring: (window layers, rows, window, query heads, K/V heads, head
+    #: size, the scores' factor) of a decode wave over the stacked
+    #: rings: Phi-4-mini-flash's eight window layers at its pair-heads
+    #: (models/phi4flash.py `pairs`), Laguna-XS.2's three
+    ring_waves: tuple = ((8, 64, 512, 40, 10, 128, 64 ** -0.5),
+                         (3, 64, 512, 64, 8, 128, 128 ** -0.5))
     # serve: bench.py's on-chip TrafficSpec cut to a few dozen requests
     requests: int = 32
     #: warm-up requests of the serve phase (another seed's traffic), and
@@ -1323,9 +1339,101 @@ def phase_kda(size: Size, platform: str = "tpu") -> Dict[str, Any]:
     return {"device": device}
 
 
+def check_ring_kernels(size: Size, *, interpret: bool = False) -> None:
+    """ops/ring_decode.py: one decode column of every row over its ring
+    where it lies in the stacked rings, against `attend_rows` over the
+    ring sliced out, in the first and the last window layer; then a
+    whole wave as the decode steps run it (a scan over the layers: the
+    new rows scattered into the donated stacks, the kernel behind
+    them), whose compiled program must alias both stacks to its results
+    and hold no temporary of a ring's size."""
+    import types
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax import lax
+
+    from ray_tpu.models.laguna_decode import _ring_mask, attend_rows
+    from ray_tpu.ops.ring_decode import ring_decode
+
+    for n, B, W, H, n_kv, hd, scale in size.ring_waves:
+        width, dt = n_kv * hd, jnp.bfloat16
+        cfg = types.SimpleNamespace(n_kv_head=n_kv, head_dim=hd, dtype=dt)
+        ks = jax.random.split(jax.random.PRNGKey(size.seed + 13), 5)
+        stacked = jax.jit(lambda k: jax.random.normal(k, (n, B, W, width),
+                                                      dt))
+        wk, wv = stacked(ks[0]), stacked(ks[1])
+        q = jax.random.normal(ks[2], (B, H, hd), dt)
+        # rows from idle through a ring partly filled to several laps
+        pos = jnp.asarray(np.linspace(0, 5 * W, B).astype(np.int32))
+        start = jnp.zeros((B,), jnp.int32)
+        kernel = jax.jit(functools.partial(
+            ring_decode, n_kv_head=n_kv, scale=scale, interpret=interpret))
+        oracle = jax.jit(lambda q, wk, wv, j, pos, start: attend_rows(
+            q, wk[j], wv[j], _ring_mask(pos, start, W), cfg, scale))
+        moved = 2 * B * W * width * dt.dtype.itemsize
+        for j in (0, n - 1):
+            args = (q, wk, wv, jnp.int32(j), pos, start)
+            got = jax.block_until_ready(kernel(*args))
+            runs = 1 if interpret else 20
+            t0 = time.perf_counter()
+            for _ in range(runs):
+                got = kernel(*args)
+            jax.block_until_ready(got)
+            ms = (time.perf_counter() - t0) / runs * 1e3
+            err = _rel_err(got, oracle(*args))
+            say("ring", kernel="ring_decode", layer=j,
+                shape=[n, B, W, H, n_kv, hd], err=round(err, 5),
+                ms=round(ms, 4), gb_per_s=round(moved / ms / 1e6, 1))
+            assert err <= KERNEL_TOL, ("ring_decode", j, err)
+
+        def wave(q, wk, wv, new_k, new_v):
+            at = jnp.where(pos > 0, pos % W, W)   # an idle row: dropped
+
+            def layer(carry, j):
+                wk, wv = (r.at[j, jnp.arange(B), at].set(x, mode="drop")
+                          for r, x in zip(carry, (new_k, new_v)))
+                return (wk, wv), ring_decode(
+                    q, wk, wv, j, pos, start, n_kv_head=n_kv, scale=scale,
+                    interpret=interpret)
+
+            return lax.scan(layer, (wk, wv), jnp.arange(n, dtype=jnp.int32))
+
+        new = tuple(jax.random.normal(k, (B, width), dt) for k in ks[3:])
+        compiled = jax.jit(wave, donate_argnums=(1, 2)).lower(
+            q, wk, wv, *new).compile()
+        memory = compiled.memory_analysis()
+        in_place = (memory.alias_size_in_bytes >= 2 * wk.nbytes
+                    and memory.temp_size_in_bytes < wk.nbytes // (n * 2))
+        (wk, wv), outs = compiled(q, wk, wv, *new)
+        runs = 1 if interpret else 10
+        jax.block_until_ready(outs)
+        t0 = time.perf_counter()
+        for _ in range(runs):
+            (wk, wv), outs = compiled(q, wk, wv, *new)
+        jax.block_until_ready(outs)
+        ms = (time.perf_counter() - t0) / runs / n * 1e3
+        err = _rel_err(outs[n - 1], oracle(q, wk, wv, n - 1, pos, start))
+        say("ring", kernel="ring_decode", wave=[n, B, W, width],
+            ms_a_layer=round(ms, 4), gb_per_s=round(moved / ms / 1e6, 1),
+            alias_bytes=memory.alias_size_in_bytes,
+            temp_bytes=memory.temp_size_in_bytes, in_place=in_place,
+            err=round(err, 5))
+        assert err <= KERNEL_TOL, ("ring_decode in a scan", err)
+        # (the interpreter's program is no Mosaic call: nothing to alias)
+        assert in_place or interpret, memory
+
+
+def phase_ring(size: Size, platform: str = "tpu") -> Dict[str, Any]:
+    device = device_block(platform)
+    check_ring_kernels(size)
+    return {"device": device}
+
+
 PHASES = {"train": phase_train, "serve": phase_serve,
           "runtime": phase_runtime, "mla": phase_mla, "gqa": phase_gqa,
-          "kda": phase_kda,
+          "kda": phase_kda, "ring": phase_ring,
           "mesh_train": phase_mesh_train,
           "tensor_serve": phase_tensor_serve, "fleet": phase_fleet}
 

@@ -130,12 +130,15 @@ KDA_CHUNK = "kda_chunk"
 #: a decode wave's delta rule, one call a KDA layer: a head's matrix read
 #: once and written once where it lies in the stacked state (ops/kda.py)
 KDA_DECODE = "kda_decode"
+#: a decode column's grouped-query attention over each row's ring where
+#: it lies in the window layers' stacked rings (ops/ring_decode.py)
+RING_DECODE = "ring_decode"
 KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV,
            FLASH_RES_FWD, FLASH_RES_DQ, FLASH_RES_DKV,
            FLASH_TRI_FWD, FLASH_TRI_BWD, SSM_SCAN, MLA_PAGED_DECODE,
            MLA_ROTARY_LANES, MLA_FLASH_PREFILL, MOE_DISPATCH,
            MOE_COMBINE, GQA_PAGED_DECODE, GROUPED_SWIGLU, KDA_CHUNK,
-           KDA_DECODE)
+           KDA_DECODE, RING_DECODE)
 
 # -- host phases -------------------------------------------------------------
 SPAN_PREFIX = "raytpu."

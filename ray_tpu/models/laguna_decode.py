@@ -37,7 +37,11 @@ rows it has not written derive to negative slots.
     is one kernel (ops/gqa_paged_decode.py): each row's own blocks are
     read where they lie, as far as its own context reaches, under a
     running softmax; off the chip the ``jnp`` reference gathers the
-    rows' views.
+    rows' views.  A window layer on the chip is one kernel too, in
+    both layouts (ops/ring_decode.py, `_attend_stacked_ring`): each
+    row's ring is read once where it lies in the stacked rings, after
+    the row's write; off the chip the layer's rings are sliced out of
+    the stack and `attend_rows` takes the whole score row.
   * a prefill attends BANDED (`attend_banded`): a tile of queries walks
     the key tiles between its first query's window edge (or 0) and its
     own diagonal.  A window layer's keys are the slot's ring (from
@@ -64,6 +68,7 @@ from ray_tpu.models.decode_common import (NO_SNAPSHOT, STATE_FROM_ZERO,
                                           is_paged, slot_mask)
 from ray_tpu.ops.gqa_paged_decode import (gqa_paged_decode,
                                           gqa_paged_decode_reference)
+from ray_tpu.ops.ring_decode import fits_the_kernel, ring_decode
 from ray_tpu.models.experts import _with_counters
 from ray_tpu.models.laguna import (ATTN_SCOPE, FULL, WINDOW, LagunaConfig,
                                    attend_masked, block, causal_mask,
@@ -238,6 +243,28 @@ def attend_rows(q, k, v, mask, cfg, scale=None):
 #: a window layer's decode column over each row's ring (B, window,
 #: kv_width) under mask (B, window)
 _attend_ring = jax.named_scope(scopes.ATTN_WINDOW)(attend_rows)
+
+
+def _attend_stacked_ring(q, rings, j, pos, start, cfg, scale=None):
+    """A window layer's decode column over layer `j` (an index, may be
+    traced) of the stacked rings (wk, wv), each (n_window, B, window,
+    kv_width) with this column's rows written: q (B, H, hd) -> (B, H,
+    hd).  What the program can see of its input picks the path, as a
+    full layer's walk is picked: on the chip, where heads and rows are
+    whole lanes and the window whole sublane tiles, the kernel reads
+    each row's ring where it lies in the stack (ops/ring_decode.py);
+    else the layer's rings are sliced out and attended by
+    `attend_rows`, the CPU's path and the parity oracle."""
+    if jax.default_backend() == "tpu" and fits_the_kernel(q, rings[0]):
+        return ring_decode(
+            q, *rings, j, pos, start, n_kv_head=cfg.n_kv_head,
+            scale=1.0 / math.sqrt(cfg.head_dim) if scale is None else scale)
+    window = rings[0].shape[2]
+    with jax.named_scope(scopes.ATTN_WINDOW):
+        mine = tuple(lax.dynamic_index_in_dim(r, j, 0, keepdims=False)
+                     for r in rings)
+        ring_mask = _ring_mask(pos, start, window)
+    return _attend_ring(q, *mine, ring_mask, cfg, scale)
 
 
 # -- a full layer's decode column over the pool -------------------------------
@@ -448,7 +475,6 @@ def laguna_decode_step(params, cache, tokens, cfg: LagunaConfig
     active = pos > 0
     rows = jnp.arange(B)
     with jax.named_scope(scopes.ATTN_WINDOW):
-        ring_mask = _ring_mask(pos, start, W)
         # an idle row writes nowhere (row `window` is dropped)
         ring_at = jnp.where(active, pos % W, W)
     if paged:
@@ -468,8 +494,8 @@ def laguna_decode_step(params, cache, tokens, cfg: LagunaConfig
                     for n, new in (("wk", k), ("wv", v)):
                         held[n] = held[n].at[j, rows, ring_at].set(
                             new, mode="drop")
-                    ring = (held["wk"][j], held["wv"][j])
-                return _attend_ring(q, *ring, ring_mask, cfg)
+                return _attend_stacked_ring(q, (held["wk"], held["wv"]),
+                                            j, pos, start, cfg)
             if paged:
                 fresh.append((k, v))
                 return _attend_paged(q, (held["k"], held["v"]), j, cache,

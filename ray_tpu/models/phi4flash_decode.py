@@ -23,7 +23,8 @@ Gated Memory Units keep nothing: the memory they gate is this step's.
 
 Scopes: ``kv_pool`` is the ONE shared pool's (its writes, its gathered
 view, the bookkeeping).  The rings are no pool: their writes, slices
-and re-lays are the window layers' own, under ``attn_window``.
+(a prefill's, and a decode step's off the chip) and re-lays are the
+window layers' own, under ``attn_window``.
 
   * a decode step advances every ACTIVE row by one token and leaves a
     row with ``pos == 0`` exactly as it is: state, windows and rings.
@@ -32,6 +33,11 @@ and re-lays are the window layers' own, under ``attn_window``.
     (`PagedKV.commit`).  Every reader's column is grouped-query
     attention over pair-heads (phi4flash.py): on the chip the walk of
     ops/gqa_paged_decode.py, eight times a step over the same blocks.
+    A window layer's column is the same attention over the row's ring:
+    on the chip ops/ring_decode.py reads it where it lies in the
+    scan's carried stacks (the layer a traced index; no ring is sliced
+    out), elsewhere `attend_rows` over the layer's rings sliced out
+    (laguna_decode._attend_stacked_ring).
   * a prefill runs the self-decoder over every column (they owe the
     state, the rings and the pool their rows) and the CROSS-decoder
     over one column, the prompt's last: for those layers a prefill's
@@ -56,9 +62,8 @@ from ray_tpu.models.decode_common import (NO_SNAPSHOT, STATE_FROM_SLOT,
 from ray_tpu.models.jamba_decode import _layer_state, _set_layer_state
 # the rings, the banded prefill attention and a row's masked softmax
 # over folded K/V are Laguna's, at this family's pair-head geometry
-from ray_tpu.models.laguna_decode import (_attend_ring, _ring_mask,
-                                          _ring_of, attend_banded,
-                                          attend_rows)
+from ray_tpu.models.laguna_decode import (_attend_stacked_ring, _ring_of,
+                                          attend_banded, attend_rows)
 from ray_tpu.models.phi4flash import (Phi4FlashConfig, attn_layer,
                                       attend_masked, causal_mask,
                                       cross_decoder, embed, lm_logits,
@@ -399,7 +404,6 @@ def phi4flash_decode_step(params, cache, tokens, cfg: Phi4FlashConfig
     active = pos > 0
     rows = jnp.arange(B)
     with jax.named_scope(scopes.ATTN_WINDOW):
-        ring_mask = _ring_mask(pos, start, W)
         # an idle row writes nowhere (row `window` is dropped)
         ring_at = jnp.where(active, pos % W, W)
     x = embed(params, tokens, cfg)                             # (B, d)
@@ -418,10 +422,8 @@ def phi4flash_decode_step(params, cache, tokens, cfg: Phi4FlashConfig
                 for at, new in enumerate((k, v)):
                     rings[at] = rings[at].at[j, rows, ring_at].set(
                         new, mode="drop")
-                mine = tuple(lax.dynamic_index_in_dim(r, j, 0,
-                                                      keepdims=False)
-                             for r in rings)
-            return _attend_ring(q, *mine, ring_mask, pairs, pairs.scale)
+            return _attend_stacked_ring(q, rings, j, pos, start, pairs,
+                                        pairs.scale)
 
         x = attn_layer(x[:, 0], p["window"], lam_init, cfg,
                        scopes.ATTN_WINDOW, attend)

@@ -38,7 +38,9 @@ TOY = chip_smoke.Size(
     mla_wave=(5, 24, 3), moe_rows=((64, 32), (8, 8)), moe_held=6,
     gqa_preset="nano", gqa_max_seq=128, gqa_wave=(5, 24),
     kda_heads=(8, 128), kda_prefills=((96, 13), (160, 70)),
-    kda_wave=(6, 3))
+    kda_wave=(6, 3),
+    ring_waves=((3, 5, 32, 8, 2, 128, 64 ** -0.5),
+                (2, 4, 16, 16, 2, 128, 128 ** -0.5)))
 
 
 @pytest.fixture
@@ -56,6 +58,8 @@ def interpreted(monkeypatch):
         chip_smoke.check_gqa_kernels, interpret=True))
     monkeypatch.setattr(chip_smoke, "check_kda_kernels", functools.partial(
         chip_smoke.check_kda_kernels, interpret=True))
+    monkeypatch.setattr(chip_smoke, "check_ring_kernels", functools.partial(
+        chip_smoke.check_ring_kernels, interpret=True))
 
 
 @pytest.fixture
@@ -87,6 +91,11 @@ def test_gqa_phase(interpreted):
 
 def test_kda_phase(interpreted):
     out = chip_smoke.phase_kda(TOY, "cpu")
+    assert out["device"]["platform"] == "cpu"
+
+
+def test_ring_phase(interpreted):
+    out = chip_smoke.phase_ring(TOY, "cpu")
     assert out["device"]["platform"] == "cpu"
 
 

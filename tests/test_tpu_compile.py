@@ -402,6 +402,54 @@ def test_gqa_paged_decode_kernel_compiles_at_the_cells_shapes():
     assert compiled.memory_analysis().temp_size_in_bytes < 1e6
 
 
+def _no_ring_is_moved(text: str, ring: str, stack: str) -> None:
+    """No instruction of a compiled program copies, slices or
+    transposes a window layer's rings: nothing whose result is one
+    layer's rings (`ring`) or a whole stack of them (`stack`) is a
+    ``copy``, a ``dynamic-slice`` or a ``transpose``, by opcode or by
+    the name the compiler gives a fusion of one."""
+    for line in text.splitlines():
+        name, _, body = line.strip().partition(" = ")
+        if body.startswith((ring, stack)):
+            assert not re.search(r" (copy|transpose|dynamic-slice)\(",
+                                 body), line
+            assert not re.search(r"slice|copy|transpose", name), line
+
+
+@pytest.mark.parametrize("n,H,n_kv", [(8, 40, 10), (3, 64, 8)],
+                         ids=["phi4-mini-flash", "laguna-xs2"])
+def test_ring_decode_kernel_compiles_at_the_cells_shapes(n, H, n_kv):
+    """ray_tpu.ops.ring_decode at the two cells' shapes: 64 rows of
+    rings of 512 positions, Phi-4-mini-flash's eight window layers
+    under 40 query sub-heads over 10 K/V pair-heads of 128 lanes,
+    Laguna-XS.2's three under 64 query heads over 8 K/V heads; the
+    layer a traced scalar.  One Mosaic call; both stacks go in as they
+    are stored (no copy, no slice of a layer: no temporary at all)."""
+    from ray_tpu._private import scopes
+    from ray_tpu.ops.ring_decode import ring_decode
+
+    spec = _one_chip()
+    bf16 = lambda *shape: spec(shape, jnp.bfloat16)   # noqa: E731
+    i32 = lambda *shape: spec(shape, jnp.int32)   # noqa: E731
+    B, W, hd = 64, 512, 128
+
+    def attend(q, wk, wv, j, pos, start):
+        return ring_decode(q, wk, wv, j, pos, start, n_kv_head=n_kv,
+                           scale=hd ** -0.5)
+
+    stack = bf16(n, B, W, n_kv * hd)
+    compiled = jax.jit(attend).lower(
+        bf16(B, H, hd), stack, stack, i32(), i32(B), i32(B)).compile()
+    text = compiled.as_text()
+    calls = [line.split(" = ")[0].split("%")[-1]
+             for line in text.splitlines() if MOSAIC_CALL in line]
+    assert [name.split(".")[0] for name in calls] == [
+        scopes.RING_DECODE], calls
+    _no_ring_is_moved(text, f"bf16[{B},{W},{n_kv * hd}]",
+                      f"bf16[{n},{B},{W},{n_kv * hd}]")
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e6
+
+
 def test_laguna_decode_step_walks_the_pool_once_a_full_layer(monkeypatch):
     """The cell laguna-xs2.serve-offline-mixed's decode step as the
     engine builds it (benchmark/families/laguna.py aot_serve_programs:
@@ -413,7 +461,11 @@ def test_laguna_decode_step_walks_the_pool_once_a_full_layer(monkeypatch):
     rings' row writes and the commit's scatter); the pools neither
     copied nor sliced by layer; donated, they are updated in place.
     Its four expert layers are one ``grouped_swiglu`` each under
-    ``moe_experts``, and no ``ragged-dot`` is compiled."""
+    ``moe_experts``, and no ``ragged-dot`` is compiled.  Its three
+    window layers are one ``ring_decode`` each under ``attn_window``
+    (PR 53): the stacked rings are read where they lie, no ring sliced
+    or copied out of them; alias and peak no worse than the parent's
+    (5.100 GB aliased, a peak of 12.97 GB)."""
     from ray_tpu._private import scopes
     from ray_tpu.models.laguna import laguna_init
 
@@ -434,6 +486,13 @@ def test_laguna_decode_step_walks_the_pool_once_a_full_layer(monkeypatch):
     assert len(walks) == n_full == 2, calls
     assert all(set(scoped[name].values()) == {scopes.ATTN_FULL}
                for name in walks), {n: scoped.get(n) for n in walks}
+    rings = [name for name in calls
+             if name.startswith(scopes.RING_DECODE)]
+    assert len(rings) == len(cfg.layers_of("window")) == 3, calls
+    assert all(set(scoped[name].values()) == {scopes.ATTN_WINDOW}
+               for name in rings), {n: scoped.get(n) for n in rings}
+    assert cache["wk"].shape == (3, 64, 512, 1024)
+    _no_ring_is_moved(text, "bf16[64,512,1024]", "bf16[3,64,512,1024]")
     fused, ragged = _experts_kernels(text)
     assert not ragged and "ragged-dot" not in text, ragged
     assert [s for _, s in fused] == [scopes.MOE_EXPERTS] * 4, fused
@@ -449,8 +508,9 @@ def test_laguna_decode_step_walks_the_pool_once_a_full_layer(monkeypatch):
         if body.startswith((pool, layer)):
             assert " copy(" not in body and " transpose(" not in body, line
     memory = compiled.memory_analysis()
-    assert memory.alias_size_in_bytes >= 4.29e9      # the pools, in place
-    assert memory.peak_memory_in_bytes < 15e9, memory
+    # the pools and the rings, in place
+    assert memory.alias_size_in_bytes >= 5.10e9, memory
+    assert memory.peak_memory_in_bytes < 12.97e9, memory
 
 
 @pytest.mark.parametrize("T", range(2048, 8193, 1024))
@@ -623,10 +683,13 @@ def test_laguna_prefill_is_one_grouped_swiglu_a_layer(t_pad, monkeypatch):
 #: locations and with each Mosaic call's payload (which holds its
 #: source's lines) taken out, and the payloads' modules without theirs
 #: (`_mosaic_modules`), at 6291e8d (PR 46): a decode wave's experts keep
-#: what PR 46 gave them whatever the prefill's regime is taught
+#: what PR 46 gave them whatever the prefill's regime is taught.
+#: Laguna's text is re-pinned at PR 53 (its three window layers call
+#: ``ring_decode`` where they sliced a ring; the four `grouped_swiglu`
+#: modules are the pinned ones); Kimi-K2's is PR 46's
 DECODE_AT_PR46 = {
     "laguna-xs2.serve-offline-mixed": (
-        "ad6dcf978b31f2ba",
+        "79a3aae3880ee0f6",
         ["bbc5fa541589b72ff754835f9938efd788e587c7c997597a754c0e7b6f02af66"]
         * 4),
     "kimi-k2-code.serve-offline-codegen": (
@@ -880,7 +943,11 @@ def test_phi4flash_programs_fit_the_chip_at_the_published_widths(
     ``gqa_paged_decode`` at 10 pair-heads of 128 lanes and a group of 4
     (the program asks ``jax.default_backend()``, steered here): one call
     under ``attn_full`` and one under ``attn_cross``, the scan's body
-    that the seven cross layers share.  A prefill's Mamba layers are
+    that the seven cross layers share; its eight window layers read
+    their rings where they lie in the carried stacks, one
+    ``ring_decode`` under ``attn_window`` in the pairs' scan body (PR
+    53), no ring sliced or copied out, the peak under the parent's
+    12.60 GB.  A prefill's Mamba layers are
     ``ssm_scan`` kernels (the pairs' scan body and the memory layer),
     and it holds no walk."""
     from ray_tpu._private import scopes
@@ -899,7 +966,8 @@ def test_phi4flash_programs_fit_the_chip_at_the_published_widths(
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(
         params, cache, *args).compile()
     memory = compiled.memory_analysis()
-    assert memory.peak_memory_in_bytes < 13.5e9, memory
+    assert memory.peak_memory_in_bytes < (
+        12.59e9 if program == "decode" else 13.5e9), memory
     assert memory.alias_size_in_bytes >= 4.71e9    # pool and state, in place
     scoped = scopes.scope_map_from_hlo(compiled.as_text())
     calls = {name: set(keyed.values()) for name, keyed in scoped.items()
@@ -908,6 +976,12 @@ def test_phi4flash_programs_fit_the_chip_at_the_published_widths(
                    if name.startswith(scopes.GQA_PAGED_DECODE))
     assert walks == ([scopes.ATTN_CROSS, scopes.ATTN_FULL]
                      if program == "decode" else [])
+    rings = [s for name, s in calls.items()
+             if name.startswith(scopes.RING_DECODE)]
+    assert rings == ([{scopes.ATTN_WINDOW}] if program == "decode" else [])
+    if program == "decode":
+        _no_ring_is_moved(compiled.as_text(), "bf16[64,512,1280]",
+                          "bf16[8,64,512,1280]")
     scans = [s for name, s in calls.items()
              if name.startswith(scopes.SSM_SCAN)]
     assert scans == ([{scopes.SSM}] * 2 if program == "prefill" else [])
