@@ -331,6 +331,10 @@ class ProgramRegistry:
             rec = self._programs[program] = {
                 "compile_events": 0,
                 "compile_seconds": 0.0,
+                # of compile_seconds, what instrument's side compile
+                # took (its ``harvest`` set-up records, summed)
+                "harvest_seconds": 0.0,
+                # (end_ts, seconds) of each retained compile
                 "compile_times": collections.deque(maxlen=256),
                 "invokes": 0,
                 "invoke_s": collections.deque(
@@ -354,16 +358,19 @@ class ProgramRegistry:
     def record_compile(self, program: str, seconds: float,
                        cost: Optional[Dict[str, Any]] = None,
                        now: Optional[float] = None,
-                       scoped: Optional[tuple] = None) -> None:
+                       scoped: Optional[tuple] = None,
+                       harvest_seconds: float = 0.0) -> None:
         """One XLA compile of `program` taking `seconds` walltime;
         `cost` is a ``_cost_summary`` dict and `scoped` a ``(module
-        name, scope map)`` pair when the harvest ran."""
+        name, scope map)`` pair when the harvest ran, which took
+        `harvest_seconds` of `seconds`."""
         ts = self._now() if now is None else now
         with self._lock:
             rec = self._rec(program)
             rec["compile_events"] += 1
             rec["compile_seconds"] += float(seconds)
-            rec["compile_times"].append(ts)
+            rec["harvest_seconds"] += float(harvest_seconds)
+            rec["compile_times"].append((ts, float(seconds)))
             if cost:
                 rec["cost"] = dict(cost)
             if scoped:
@@ -372,7 +379,7 @@ class ProgramRegistry:
                 rec["module"] = scoped[0]
                 rec["scope_map"] = merge_scope_maps(
                     rec["scope_map"] or {}, scoped[1])
-            recent = [t for t in rec["compile_times"]
+            recent = [t for t, _ in rec["compile_times"]
                       if ts - t <= self.storm_window_s]
             storm = len(recent) >= self.storm_threshold
             fresh_storm = storm and not rec["storm_active"]
@@ -453,18 +460,13 @@ class ProgramRegistry:
     def compile_windows(self, prefix: Optional[str] = None
                         ) -> Dict[str, List[tuple]]:
         """Per-program compile windows ``{name: [(end_ts, dur_s),
-        ...]}`` — compile_times keeps end instants; durations beyond
-        the retained ring are approximated by the mean compile cost
-        (exact when a program compiled once, the common case)."""
+        ...]}``: each retained compile's end instant and its own
+        seconds (a fresh signature's whole first call, as
+        ``compile_seconds`` sums them)."""
         with self._lock:
-            out: Dict[str, List[tuple]] = {}
-            for name, rec in self._programs.items():
-                if prefix is not None and not name.startswith(prefix):
-                    continue
-                n = rec["compile_events"]
-                mean = (rec["compile_seconds"] / n) if n else 0.0
-                out[name] = [(ts, mean) for ts in rec["compile_times"]]
-            return out
+            return {name: list(rec["compile_times"])
+                    for name, rec in self._programs.items()
+                    if prefix is None or name.startswith(prefix)}
 
     # -- subscribers (e.g. EngineTelemetry.record_program_compile) ---------
 
@@ -547,6 +549,7 @@ class ProgramRegistry:
                 with seen_lock:
                     fresh = sig not in seen
                     if fresh:
+                        ordinal = len(seen)
                         seen.add(sig)
                     # claim the first signature's cost harvest under
                     # the same lock: two threads compiling fresh
@@ -559,28 +562,41 @@ class ProgramRegistry:
                         first, harvested[0] = not harvested[0], True
             if fresh:
                 cost = scoped = None
+                harvest_s = 0.0
+                # what compiles below says which program, which fresh
+                # signature and which of the two parts asked for it
+                # (the set-up records of _private/telemetry.py)
+                why = {"program": program, "signature": ordinal}
                 t0 = time.perf_counter()
                 if do_harvest:
-                    try:
-                        # side AOT compile of a fresh signature: the
-                        # first one's cost/memory analysis, and every
-                        # one's scope map (XLA names each signature's
-                        # instructions anew) — the executing call below
-                        # still goes through fn's jit cache
-                        compiled = fn.lower(*args, **kwargs).compile()
-                        if first:
-                            cost = _cost_summary(compiled)
-                        scoped = _scoped(compiled)
-                    except Exception:  # noqa: BLE001
-                        cost = None
+                    with _core.cause(part="harvest", **why):
+                        try:
+                            # side AOT compile of a fresh signature:
+                            # the first one's cost/memory analysis, and
+                            # every one's scope map (XLA names each
+                            # signature's instructions anew) — the
+                            # executing call below still goes through
+                            # fn's jit cache
+                            compiled = fn.lower(*args, **kwargs).compile()
+                            if first:
+                                cost = _cost_summary(compiled)
+                            scoped = _scoped(compiled)
+                        except Exception:  # noqa: BLE001
+                            cost = None
+                    # the whole side block, the text's print and parse
+                    # too, which no compile record holds
+                    t_harvest = time.perf_counter()
+                    harvest_s = t_harvest - t0
+                    _core.record_setup("harvest", t0, t_harvest, **why)
                 # the first call with a fresh signature IS the compile:
                 # its walltime (trace + XLA compile + run) lands in
                 # compile_seconds and stays out of the steady-state
                 # invoke window so the live MFU is not diluted
-                out = fn(*args, **kwargs)
+                with _core.cause(part="call", **why):
+                    out = fn(*args, **kwargs)
                 registry.record_compile(
                     program, time.perf_counter() - t0, cost=cost,
-                    scoped=scoped)
+                    scoped=scoped, harvest_seconds=harvest_s)
                 return out
             t0 = time.perf_counter()
             out = fn(*args, **kwargs)
@@ -614,9 +630,11 @@ class ProgramRegistry:
                  ) -> Dict[str, Dict[str, Any]]:
         """Per-program observability block:
 
-        ``{compile_events, compile_seconds, invokes, invoke_ms,
-        xla_flops, peak_hbm_bytes, alias_bytes, temp_bytes, ..., mfu,
-        recompile_storm}``.  ``alias_bytes`` is what the executable
+        ``{compile_events, compile_seconds, harvest_seconds, invokes,
+        invoke_ms, xla_flops, peak_hbm_bytes, alias_bytes, temp_bytes,
+        ..., mfu, recompile_storm}``.  ``harvest_seconds`` is the part
+        of ``compile_seconds`` that `instrument`'s side compiles took.
+        ``alias_bytes`` is what the executable
         updates in place of its (donated) arguments, ``temp_bytes``
         what it allocates beside arguments and results: a serving
         program that updates its KV pool where it lies reads the
@@ -638,6 +656,7 @@ class ProgramRegistry:
             block: Dict[str, Any] = {
                 "compile_events": rec["compile_events"],
                 "compile_seconds": round(rec["compile_seconds"], 3),
+                "harvest_seconds": round(rec["harvest_seconds"], 3),
                 "invokes": rec["invokes"],
                 "invoke_ms": _core.summarize(
                     [s * 1e3 for s in invoke_s]),

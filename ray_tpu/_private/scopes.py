@@ -152,6 +152,14 @@ ENGINE_PHASES = (
     "prefill_chunk", "spec_round", "yield")
 #: where the host only waits for the device
 ENGINE_FENCES = ("decode_fence", "prefill_fence")
+SETUP = "setup"              # layer of an engine's build
+#: leaf phases of serve/engine.py ``EngineBase.__init__``, in order:
+#: the family's configuration; the parameters (``fam.init`` or the
+#: checkpoint's load, the mesh commit, the move to the engine's
+#: device; a draft model's too); the K/V pool or cache, pager and
+#: snapshots; the jitted programs, their ``instrument`` wrappers and
+#: what the constructor compiles ahead of the first request
+SETUP_PHASES = ("config", "params", "cache", "programs")
 
 
 def span_name(layer: str, phase: str) -> str:

@@ -17,16 +17,24 @@ Three shared pieces live here:
   chrome://tracing / Perfetto view;
 * host phases (:class:`Phases`): what a loop does between device
   calls, as ``raytpu.<layer>.<phase>`` spans on the
-  profiler's own clock plus one ``{phase: [count, seconds]}`` table.
+  profiler's own clock plus one ``{phase: [count, seconds]}`` table;
+* set-up records (`record_setup`, `setup_records`): one small host
+  record for every program JAX compiled or loaded, every side compile
+  of ``device_stats.instrument`` and every phase of an engine's build,
+  each with the span that caused it (`cause`), in one ring a process.
 """
 
 from __future__ import annotations
 
+import collections
+import contextlib
+import itertools
 import json
 import math
 import sys
+import threading
 import time
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Deque, Dict, Iterator, List, Optional, Sequence
 
 from ray_tpu._private import scopes
 
@@ -265,3 +273,87 @@ class Phases:
         """``{phase: [count, seconds]}``; ``step`` counts whole steps."""
         return {name: [n, ns * 1e-9]
                 for name, (n, ns) in sorted(self._table.items())}
+
+
+# ---------------------------------------------------------------------------
+# set-up records: where a process's seconds before its first step went
+# ---------------------------------------------------------------------------
+
+#: set-up records a process keeps: a record is a small dict, and a
+#: process compiles tens to some hundreds of programs (`LAUNCH_HISTORY`
+#: of serve/telemetry.py is the same number for the same reason)
+SETUP_HISTORY = 4096
+#: what a cause may name; a key the stack does not hold reads None
+CAUSE_KEYS = ("phase", "program", "signature", "part")
+
+#: module-level, so a reader finds the records after the engine that
+#: made them is gone (`serve.telemetry.recent_launches` likewise)
+_setup_ring: Deque[Dict[str, Any]] = collections.deque(
+    maxlen=SETUP_HISTORY)
+_setup_seq = itertools.count()
+_causes = threading.local()
+
+
+def current_cause() -> Optional[Dict[str, Any]]:
+    """What this thread is inside of right now: ``{phase, program,
+    signature, part}`` as the innermost `cause` block left it, or None
+    outside every block."""
+    stack = getattr(_causes, "stack", None)
+    return dict(stack[-1]) if stack else None
+
+
+@contextlib.contextmanager
+def cause(**fields: Any) -> Iterator[None]:
+    """``with cause(phase="params"):`` -- every set-up record this
+    thread writes inside the block names it.  Blocks nest: an inner one
+    keeps the outer's fields and adds or replaces its own.  The stack
+    is per thread, as the compile that a record reports runs on the
+    thread that asked for it."""
+    stack = getattr(_causes, "stack", None)
+    if stack is None:
+        stack = _causes.stack = []
+    top = stack[-1] if stack else dict.fromkeys(CAUSE_KEYS)
+    stack.append({**top, **fields})
+    try:
+        yield
+    finally:
+        stack.pop()
+
+
+def record_setup(kind: str, t0: float, t1: float,
+                 **fields: Any) -> Dict[str, Any]:
+    """Append one set-up record: its `kind` -- ``compile``
+    (_private/compile_cache.py: one for every program JAX hands the
+    backend or loads from the persistent cache), ``harvest``
+    (device_stats.ProgramRegistry.instrument's side compile of a fresh
+    signature) or ``phase`` (`setup_phase`: a leaf of an engine's
+    build) --, its ``perf_counter`` stamps (the clock of `Phases` and of
+    the launch records), a process-wide ``seq`` (a reader sees a record
+    the ring dropped as a gap), this thread's `current_cause` and
+    `fields`."""
+    record = dict(fields, kind=kind, seq=next(_setup_seq), t0=t0, t1=t1,
+                  cause=current_cause())
+    _setup_ring.append(record)
+    return record
+
+
+def setup_records(since: Optional[float] = None) -> List[Dict[str, Any]]:
+    """This process's set-up records, oldest first, at most
+    `SETUP_HISTORY`; with `since`, those closed (``t1``) at or after
+    that ``perf_counter`` instant.  The name is where they matter most;
+    a compile inside a serving window is recorded all the same, with
+    its program and cause."""
+    records = list(_setup_ring)
+    if since is not None:
+        records = [r for r in records if r["t1"] >= since]
+    return records
+
+
+@contextlib.contextmanager
+def setup_phase(phases: Phases, name: str) -> Iterator[None]:
+    """One leaf ``raytpu.<layer>.<name>`` of a build, as `phases` books
+    it; what compiles inside names it as its cause, and on the way out
+    the leaf's own stamps become a ``phase`` record."""
+    with phases.phase(name) as leaf, cause(phase=name):
+        yield
+    record_setup("phase", leaf.t0, leaf.t1, phase=name)

@@ -26,7 +26,7 @@ from typing import Any, Dict
 import numpy as np
 
 from ray_tpu._private import scopes
-from ray_tpu._private.telemetry import Phases
+from ray_tpu._private.telemetry import Phases, setup_phase
 from ray_tpu.models import decode_common as dc
 from ray_tpu.models.decode_common import SamplingParams
 from ray_tpu.models.families import PER_SLOT_STATE, family as _family
@@ -78,31 +78,40 @@ class EngineBase:
             raise ValueError("an engine takes a mesh or one "
                              "device, not both")
         self.device = device
+        #: where this constructor's seconds go, as raytpu.setup.*
+        #: spans, the engine_stats()["setup"] table and the ``phase``
+        #: set-up records that the compiles inside name as their cause
+        #: (`_build`; the leaves are scopes.SETUP_PHASES)
+        self._setup = Phases(scopes.SETUP)
 
-        overrides = dict(opt.config_overrides or {})
-        fam = _family(opt.family)
-        self._prefill_attention = fam.prefill_attention
-        self.cfg = fam.config(opt.preset, **overrides)
-        if opt.checkpoint_path:
-            with open(opt.checkpoint_path, "rb") as f:
-                self.params = jax.tree.map(jnp.asarray, pickle.load(f))
-        else:
-            self.params = fam.init(jax.random.PRNGKey(opt.seed), self.cfg)
-        self.mesh = opt.mesh
-        if opt.mesh is not None:
-            # commit params to the mesh once at construction; the
-            # committed shardings propagate through every jitted
-            # program below, turning them SPMD without annotation
-            from ray_tpu.parallel.sharding import (DECODE_RULES,
-                                                   shard_by_shape)
-            self.params = shard_by_shape(
-                self.params, fam.logical_axes(self.cfg), opt.mesh,
-                DECODE_RULES)
-        self.params = self._to_engine(self.params)
-        # per-call PRNG threading: without it every temperature>0
-        # request would sample under the same default key and
-        # return identical "random" continuations
-        self._rng = jax.random.PRNGKey(opt.seed + 1)
+        with self._build("config"):
+            overrides = dict(opt.config_overrides or {})
+            fam = _family(opt.family)
+            self._prefill_attention = fam.prefill_attention
+            self.cfg = fam.config(opt.preset, **overrides)
+        with self._build("params"):
+            if opt.checkpoint_path:
+                with open(opt.checkpoint_path, "rb") as f:
+                    self.params = jax.tree.map(jnp.asarray,
+                                               pickle.load(f))
+            else:
+                self.params = fam.init(jax.random.PRNGKey(opt.seed),
+                                       self.cfg)
+            self.mesh = opt.mesh
+            if opt.mesh is not None:
+                # commit params to the mesh once at construction; the
+                # committed shardings propagate through every jitted
+                # program below, turning them SPMD without annotation
+                from ray_tpu.parallel.sharding import (DECODE_RULES,
+                                                       shard_by_shape)
+                self.params = shard_by_shape(
+                    self.params, fam.logical_axes(self.cfg), opt.mesh,
+                    DECODE_RULES)
+            self.params = self._to_engine(self.params)
+            # per-call PRNG threading: without it every temperature>0
+            # request would sample under the same default key and
+            # return identical "random" continuations
+            self._rng = jax.random.PRNGKey(opt.seed + 1)
         # host-side lifecycle telemetry (enqueue/admit/first-token/
         # step/finish records -> metrics + engine_stats + timeline);
         # never touches the jitted programs
@@ -130,8 +139,14 @@ class EngineBase:
 
     def _init_scheduler(self, fam) -> None:
         """The scheduler's own state and programs over family `fam`
-        (a models.families.Family)."""
+        (a models.families.Family), each part inside its `_build`
+        phase."""
         raise NotImplementedError
+
+    def _build(self, phase):
+        """``with self._build("cache"):`` -- one leaf of the
+        constructor (scopes.SETUP_PHASES)."""
+        return setup_phase(self._setup, phase)
 
     def _to_engine(self, tree):
         """Commit arrays made elsewhere (fresh inits, another
@@ -168,6 +183,9 @@ class EngineBase:
         # {phase: [count, seconds]} of the scheduler loop; "step"
         # counts iterations, the others are its leaves
         stats["phases"] = self._phases.snapshot()
+        # the same table of the constructor's leaves: what building
+        # this engine took, by scopes.SETUP_PHASES
+        stats["setup"] = self._setup.snapshot()
         if opt.admission_policy is not None:
             stats["admission_policy"] = opt.admission_policy.describe()
         # perf observatory: compiled-cost / recompile / live-MFU
@@ -243,87 +261,89 @@ class LLMEngine(EngineBase):
 
         opt = self.opt
         cfg = self.cfg
-        self._recurrent = fam.cache_kind in PER_SLOT_STATE
-        self._pager = None
-        self._kvscope_budget = None     # _compose_kv_scope's, cached
-        if opt.kv_layout == "paged":
-            from ray_tpu.serve.kv_pager import BlockPager
+        with self._build("cache"):
+            self._recurrent = fam.cache_kind in PER_SLOT_STATE
+            self._pager = None
+            self._kvscope_budget = None     # _compose_kv_scope's, cached
+            if opt.kv_layout == "paged":
+                from ray_tpu.serve.kv_pager import BlockPager
 
-            max_blk = cfg.max_seq // opt.kv_block_size
-            # default pool: every slot can hold a full sequence,
-            # plus one sequence of headroom so the prefix cache and
-            # COW forks survive a fully-occupied pool
-            n_blocks = (opt.kv_num_blocks
-                        if opt.kv_num_blocks is not None
-                        else 1 + (opt.max_slots + 1) * max_blk)
-            self._cache = fam.init_paged_cache(
-                cfg, opt.max_slots, num_blocks=n_blocks,
-                block_size=opt.kv_block_size, mesh=self.mesh)
-            # tiered host-RAM KV cache: evicted prefix blocks
-            # spill device→host and re-admit via H2D copy instead
-            # of re-prefill (serve/kv_tier.py)
-            host_tier = None
-            if opt.kv_host_tier_bytes is not None:
-                from ray_tpu.serve.kv_tier import HostKVTier
+                max_blk = cfg.max_seq // opt.kv_block_size
+                # default pool: every slot can hold a full sequence,
+                # plus one sequence of headroom so the prefix cache and
+                # COW forks survive a fully-occupied pool
+                n_blocks = (opt.kv_num_blocks
+                            if opt.kv_num_blocks is not None
+                            else 1 + (opt.max_slots + 1) * max_blk)
+                self._cache = fam.init_paged_cache(
+                    cfg, opt.max_slots, num_blocks=n_blocks,
+                    block_size=opt.kv_block_size, mesh=self.mesh)
+                # tiered host-RAM KV cache: evicted prefix blocks
+                # spill device→host and re-admit via H2D copy instead
+                # of re-prefill (serve/kv_tier.py)
+                host_tier = None
+                if opt.kv_host_tier_bytes is not None:
+                    from ray_tpu.serve.kv_tier import HostKVTier
 
-                host_tier = HostKVTier(opt.kv_host_tier_bytes)
-            self._pager = BlockPager(
-                n_blocks, opt.kv_block_size, cfg.max_seq,
-                bytes_per_block=dc.block_bytes(self._cache),
-                tensor_shards=dc.kv_shards(self._cache),
-                recorder=self._telemetry.flightrec,
-                host_tier=host_tier)
-            if host_tier is not None:
-                self._pager.set_block_saver(self._tier_save)
-            # what the cache reserves by its layers' reach (a token in
-            # the pool, a slot's windows): `_reserved_by_reach`
-            self._reach = dc.cache_reach(self._cache)
-            if self._recurrent:
-                # prefix reuse for a recurrent family: one snapshot
-                # of the state a slot, keyed as the pager keys the
-                # block at its boundary (kv_pager.StateSnapshots)
-                from ray_tpu.serve.kv_pager import StateSnapshots
+                    host_tier = HostKVTier(opt.kv_host_tier_bytes)
+                self._pager = BlockPager(
+                    n_blocks, opt.kv_block_size, cfg.max_seq,
+                    bytes_per_block=dc.block_bytes(self._cache),
+                    tensor_shards=dc.kv_shards(self._cache),
+                    recorder=self._telemetry.flightrec,
+                    host_tier=host_tier)
+                if host_tier is not None:
+                    self._pager.set_block_saver(self._tier_save)
+                # what the cache reserves by its layers' reach (a token in
+                # the pool, a slot's windows): `_reserved_by_reach`
+                self._reach = dc.cache_reach(self._cache)
+                if self._recurrent:
+                    # prefix reuse for a recurrent family: one snapshot
+                    # of the state a slot, keyed as the pager keys the
+                    # block at its boundary (kv_pager.StateSnapshots)
+                    from ray_tpu.serve.kv_pager import StateSnapshots
 
-                self._pager.set_snapshots(StateSnapshots(opt.max_slots))
-        else:
-            self._cache = fam.init_cache(cfg, opt.max_slots, mesh=self.mesh)
-        self._cache = self._to_engine(self._cache)
-        # a family with a sparse expert layer leaves its routing
-        # counters in the cache, program by program (_counters)
-        self._counted = dc.expert_counters(self._cache) is not None
-        self._cur = np.zeros((opt.max_slots,), np.int32)
-        self._slots = [None] * opt.max_slots
-        # what the chip has been given and the host has not fenced
-        # yet, oldest first: decode waves, and the prefills admitted
-        # between them (_wave, _land); when the last wave landed and
-        # what the last waves took, fence to fence
-        self._flight = collections.deque()
-        # launches made so far (a launch's `seq`), loop iterations
-        # with work in them, and when the loop last let its callers
-        # run (None: parked, or not started)
-        self._seq = 0
-        self._iteration = 0
-        self._held_from = None
-        # slot -> first token, still on the device, of each prefill
-        # in flight that the next wave takes up (join_token)
-        self._joins = {}
-        self._t_landed = 0.0
-        self._wave_s = collections.deque(maxlen=33)
-        self._queue = RequestQueue()
-        self._wake = None           # asyncio.Event, made on-loop
-        self._engine_task = None
-        self._default_sp = opt.default_sp
-        self._samplers = {}     # SamplingParams -> jitted sampler
-        # chunked streaming prefill (round 15): round-robin cursor
-        # over slots mid-prefill, plus a constant key for the
-        # discarded samples of intermediate chunks (the engine RNG
-        # splits once per admission, at the FINAL chunk — the same
-        # stream a one-shot admission sees)
-        self._chunk_rr = 0
-        # the same constant key rides with a greedy decode wave:
-        # argmax reads no key, and the eager split it replaces was
-        # 1 ms of idle device a step (`_step`)
-        self._dummy_key = jax.random.PRNGKey(0)
+                    self._pager.set_snapshots(StateSnapshots(opt.max_slots))
+            else:
+                self._cache = fam.init_cache(cfg, opt.max_slots,
+                                             mesh=self.mesh)
+            self._cache = self._to_engine(self._cache)
+            # a family with a sparse expert layer leaves its routing
+            # counters in the cache, program by program (_counters)
+            self._counted = dc.expert_counters(self._cache) is not None
+            self._cur = np.zeros((opt.max_slots,), np.int32)
+            self._slots = [None] * opt.max_slots
+            # what the chip has been given and the host has not fenced
+            # yet, oldest first: decode waves, and the prefills admitted
+            # between them (_wave, _land); when the last wave landed and
+            # what the last waves took, fence to fence
+            self._flight = collections.deque()
+            # launches made so far (a launch's `seq`), loop iterations
+            # with work in them, and when the loop last let its callers
+            # run (None: parked, or not started)
+            self._seq = 0
+            self._iteration = 0
+            self._held_from = None
+            # slot -> first token, still on the device, of each prefill
+            # in flight that the next wave takes up (join_token)
+            self._joins = {}
+            self._t_landed = 0.0
+            self._wave_s = collections.deque(maxlen=33)
+            self._queue = RequestQueue()
+            self._wake = None           # asyncio.Event, made on-loop
+            self._engine_task = None
+            self._default_sp = opt.default_sp
+            self._samplers = {}     # SamplingParams -> jitted sampler
+            # chunked streaming prefill (round 15): round-robin cursor
+            # over slots mid-prefill, plus a constant key for the
+            # discarded samples of intermediate chunks (the engine RNG
+            # splits once per admission, at the FINAL chunk — the same
+            # stream a one-shot admission sees)
+            self._chunk_rr = 0
+            # the same constant key rides with a greedy decode wave:
+            # argmax reads no key, and the eager split it replaces was
+            # 1 ms of idle device a step (`_step`)
+            self._dummy_key = jax.random.PRNGKey(0)
 
         # spec decode: (model drafts) the draft family's
         # config/params/cache pool
@@ -363,71 +383,76 @@ class LLMEngine(EngineBase):
                 d_seed = (opt.spec_decode.draft_seed
                           if opt.spec_decode.draft_seed is not None
                           else opt.seed)
-                self._draft_params = self._to_engine(d_fam.init(
-                    jax.random.PRNGKey(d_seed), d_cfg))
-                # draft pool: always dense, never mesh-sharded —
-                # the draft is small by construction and a dense
-                # row pool keeps its pos arithmetic trivial
-                self._draft_cache = self._to_engine(
-                    d_fam.init_cache(d_cfg, opt.max_slots))
+                with self._build("params"):
+                    self._draft_params = self._to_engine(d_fam.init(
+                        jax.random.PRNGKey(d_seed), d_cfg))
+                with self._build("cache"):
+                    # draft pool: always dense, never mesh-sharded —
+                    # the draft is small by construction and a dense
+                    # row pool keeps its pos arithmetic trivial
+                    self._draft_cache = self._to_engine(
+                        d_fam.init_cache(d_cfg, opt.max_slots))
                 self._draft_cfg = d_cfg
 
-        fns = _jitted_engine_fns(
-            fam, cfg, opt.default_sp, kv_layout=opt.kv_layout,
-            mesh=self.mesh, spec=opt.spec_decode, draft=d_fam,
-            draft_cfg=self._draft_cfg)
-        self._fns = fns
-        (self._prefill, self._paged_prefill, self._pool_step,
-         self._admit, self._copy_block, self._clear_row) = (
-            fns.prefill, fns.paged_prefill, fns.pool_step,
-            fns.admit, fns.copy_block, fns.clear_row)
-        # compiled here, not at the first admission that meets a
-        # decode wave in flight
-        fns.join_token(self._cur, np.int32(0), self._cur[:1])
-        if self._pager is not None and self._pager.tier is not None:
-            # pre-compile the H2D splice program with an all-pad
-            # call (every id 0 → zero rows into the null write
-            # sink): restores share ONE fixed-shape program, so
-            # the first real tier restore pays a copy inside its
-            # kv_fetch window, not a compile
-            from ray_tpu.serve.kv_tier import staging_buffers
+        with self._build("programs"):
+            fns = _jitted_engine_fns(
+                fam, cfg, opt.default_sp, kv_layout=opt.kv_layout,
+                mesh=self.mesh, spec=opt.spec_decode, draft=d_fam,
+                draft_cfg=self._draft_cfg)
+            self._fns = fns
+            (self._prefill, self._paged_prefill, self._pool_step,
+             self._admit, self._copy_block, self._clear_row) = (
+                fns.prefill, fns.paged_prefill, fns.pool_step,
+                fns.admit, fns.copy_block, fns.clear_row)
+            # compiled here, not at the first admission that meets a
+            # decode wave in flight
+            fns.join_token(self._cur, np.int32(0), self._cur[:1])
+            if self._pager is not None and self._pager.tier is not None:
+                # pre-compile the H2D splice program with an all-pad
+                # call (every id 0 → zero rows into the null write
+                # sink): restores share ONE fixed-shape program, so
+                # the first real tier restore pays a copy inside its
+                # kv_fetch window, not a compile
+                from ray_tpu.serve.kv_tier import staging_buffers
 
-            maxn = cfg.max_seq // opt.kv_block_size
-            rows = dc.block_rows(self._cache, maxn)
-            # persistent host staging buffers for the restore path
-            # (ids, k rows, v rows) — refilled in place per
-            # restore instead of re-allocating pad arrays
-            self._tier_stage = staging_buffers(maxn, rows.shape, rows.dtype)
-            zr = jnp.zeros(rows.shape, rows.dtype)
-            self._cache = fns.install_blocks(
-                self._cache, jnp.zeros((maxn,), jnp.int32),
-                zr, zr)
-            jax.block_until_ready(self._cache)
-        if self._pager is not None:
-            # handoff id staging buffer: role-split engines use it
-            # every handoff; a role="both" engine only if a caller
-            # feeds it packages via admit_prefilled directly
-            self._handoff_ids = np.zeros(
-                (cfg.max_seq // opt.kv_block_size,), np.int32)
-        if opt.role != "both":
-            # disaggregated handoff: pre-compile this role's side
-            # of the block move with an all-pad call so the first
-            # real handoff pays a copy inside its handoff window,
-            # not an XLA compile (the tier-splice precompile
-            # discipline, applied to the new programs)
-            maxn = cfg.max_seq // opt.kv_block_size
-            pad_ids = jnp.zeros((maxn,), jnp.int32)
-            if opt.role == "prefill":
-                k_rows, v_rows = fns.kv_handoff_export(self._cache, pad_ids)
-                jax.block_until_ready(k_rows)
-                del k_rows, v_rows
-            else:
+                maxn = cfg.max_seq // opt.kv_block_size
                 rows = dc.block_rows(self._cache, maxn)
+                # persistent host staging buffers for the restore path
+                # (ids, k rows, v rows) — refilled in place per
+                # restore instead of re-allocating pad arrays
+                self._tier_stage = staging_buffers(maxn, rows.shape,
+                                                   rows.dtype)
                 zr = jnp.zeros(rows.shape, rows.dtype)
-                self._cache = fns.kv_handoff_install(
-                    self._cache, pad_ids, zr, zr, np.int32(0),
-                    jnp.zeros((maxn,), jnp.int32), np.int32(0))
+                self._cache = fns.install_blocks(
+                    self._cache, jnp.zeros((maxn,), jnp.int32),
+                    zr, zr)
                 jax.block_until_ready(self._cache)
+            if self._pager is not None:
+                # handoff id staging buffer: role-split engines use it
+                # every handoff; a role="both" engine only if a caller
+                # feeds it packages via admit_prefilled directly
+                self._handoff_ids = np.zeros(
+                    (cfg.max_seq // opt.kv_block_size,), np.int32)
+            if opt.role != "both":
+                # disaggregated handoff: pre-compile this role's side
+                # of the block move with an all-pad call so the first
+                # real handoff pays a copy inside its handoff window,
+                # not an XLA compile (the tier-splice precompile
+                # discipline, applied to the new programs)
+                maxn = cfg.max_seq // opt.kv_block_size
+                pad_ids = jnp.zeros((maxn,), jnp.int32)
+                if opt.role == "prefill":
+                    k_rows, v_rows = fns.kv_handoff_export(
+                        self._cache, pad_ids)
+                    jax.block_until_ready(k_rows)
+                    del k_rows, v_rows
+                else:
+                    rows = dc.block_rows(self._cache, maxn)
+                    zr = jnp.zeros(rows.shape, rows.dtype)
+                    self._cache = fns.kv_handoff_install(
+                        self._cache, pad_ids, zr, zr, np.int32(0),
+                        jnp.zeros((maxn,), jnp.int32), np.int32(0))
+                    jax.block_until_ready(self._cache)
         # perf observatory: mirror process-wide program compile
         # events into this deployment's program-keyed recompile
         # counter (decode/sharded-decode shape churn visible, not

@@ -257,12 +257,15 @@ class BatchLLM(EngineBase):
         sampling = dict(max_new_tokens=opt.max_new_tokens,
                         temperature=opt.temperature, top_k=opt.top_k,
                         top_p=opt.top_p)
-        self._generate = jax.jit(
-            lambda p, toks, k: fam.generate(
-                p, toks, self.cfg, key=k, **sampling))
-        self._generate_ragged = jax.jit(
-            lambda p, toks, lens, k: fam.generate(
-                p, toks, self.cfg, lengths=lens, key=k, **sampling))
+        # the batch scheduler keeps no cache between calls: its build
+        # has no ``cache`` phase
+        with self._build("programs"):
+            self._generate = jax.jit(
+                lambda p, toks, k: fam.generate(
+                    p, toks, self.cfg, key=k, **sampling))
+            self._generate_ragged = jax.jit(
+                lambda p, toks, lens, k: fam.generate(
+                    p, toks, self.cfg, lengths=lens, key=k, **sampling))
         # this instance's micro-batch queue, sized by its options
         self._batched = _batch(
             max_batch_size=opt.max_batch_size,

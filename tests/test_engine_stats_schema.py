@@ -32,7 +32,7 @@ TOP_KEYS = {
     "kv_cache", "kv_scope", "kv_tier", "recurrent", "spec", "slo",
     "flightrec",
     "programs", "latency_anatomy", "prefill_chunks", "role", "handoff",
-    "health", "phases",
+    "health", "phases", "setup",
 }
 
 #: leaf phases every engine that ran a request has been through
@@ -87,7 +87,8 @@ FLIGHTREC_KEYS = {"enabled", "capacity", "recorded", "retained",
 SLO_OBJECTIVE_KEYS = {"target_ms", "samples", "violations",
                       "attainment", "burn_rate", "breached", "windows"}
 
-PROGRAM_KEYS = {"compile_events", "compile_seconds", "invokes",
+PROGRAM_KEYS = {"compile_events", "compile_seconds", "harvest_seconds",
+                "invokes",
                 "invoke_ms", "xla_flops", "bytes_accessed",
                 "arithmetic_intensity", "peak_hbm_bytes",
                 "recompile_storm", "recompile_storms_total", "mfu"}
@@ -206,6 +207,13 @@ def test_engine_stats_schema(kv_layout, spec, sharded):
     assert ("kv.reserve" in ph) == (kv_layout == "paged")
     assert ("spec_round" in ph) == (spec is not None)
     assert ("decode_fence" in ph) == (spec is None)
+
+    # setup: the same table of the constructor's leaves, each entered
+    # once (a model draft would enter "params" and "cache" twice)
+    assert set(stats["setup"]) == set(scopes.SETUP_PHASES)
+    for count, seconds in stats["setup"].values():
+        assert count == 1
+        assert isinstance(seconds, float) and seconds >= 0.0
 
     # spec block always present; counters move iff spec decoding ran
     assert set(stats["spec"]) == SPEC_KEYS
