@@ -59,6 +59,16 @@ Phases, one chip:
            layers, the new rows scattered into the donated stacks): the
            compiled program aliases both stacks and holds no temporary
            of a ring's size.  Prints ms a call and GB/s.
+  banded   a prefill's banded flash kernel (ops/banded_flash.py) at
+           the geometries of the three cells that share it (Laguna's
+           full layers, 48 query heads over 8 K/V heads, and its window
+           layers, 64; Phi-4-mini-flash's 40 over 10 pair-heads at its
+           stated scale; Solar-Open2's 64 over 8): a causal triangle
+           over the gathered view or a band of 512 over the laid ring
+           and tail, pad columns at the left, at 1,024 columns and at
+           the largest bucket, against the jnp walk `banded_walk`.
+           Prints the kernel's and the walk's milliseconds a layer
+           beside the arithmetic's at the chip's peak.
 Phases, --chips 4 (and no one-chip phase):
   mesh_train    the train step over data=4 and data=2 x fsdp=2 against
                 the same step on device 0.
@@ -81,7 +91,8 @@ import sys
 import time
 from typing import Any, Dict, List
 
-ONE_CHIP = ("train", "serve", "runtime", "mla", "gqa", "kda", "ring")
+ONE_CHIP = ("train", "serve", "runtime", "mla", "gqa", "kda", "ring",
+            "banded")
 FOUR_CHIPS = ("mesh_train", "tensor_serve", "fleet")
 #: the driver allows 1200 s; leave room for the parent's own exit
 DEADLINE_S = 1100.0
@@ -168,6 +179,19 @@ class Size:
     #: (models/phi4flash.py `pairs`), Laguna-XS.2's three
     ring_waves: tuple = ((8, 64, 512, 40, 10, 128, 64 ** -0.5),
                          (3, 64, 512, 64, 8, 128, 128 ** -0.5))
+    # banded: (query heads, K/V heads, the scores' factor, window or
+    #: None, rows of a full layer's view, the oracle's tile, buckets)
+    #: of a prefill's attention layer: Laguna-XS.2's full and window
+    #: layers, Phi-4-mini-flash's window and full layers at its
+    #: pair-heads, Solar-Open2's softmax layer
+    banded_layers: tuple = (
+        (48, 8, 128 ** -0.5, None, 8704, 512, (1024, 8192)),
+        (64, 8, 128 ** -0.5, 512, None, 512, (1024, 8192)),
+        (40, 10, 64 ** -0.5, 512, None, 256, (1024, 4096)),
+        (40, 10, 64 ** -0.5, None, 4864, 256, (1024, 4096)),
+        (64, 8, 128 ** -0.5, None, 8704, 512, (1024, 8192)))
+    #: the kernel's own tiles (None) or a toy's (block_q, block_k)
+    banded_tiles: Any = None
     # serve: bench.py's on-chip TrafficSpec cut to a few dozen requests
     requests: int = 32
     #: warm-up requests of the serve phase (another seed's traffic), and
@@ -1431,9 +1455,70 @@ def phase_ring(size: Size, platform: str = "tpu") -> Dict[str, Any]:
     return {"device": device}
 
 
+def check_banded_kernels(size: Size, *, interpret: bool = False) -> None:
+    """ops/banded_flash.py against `laguna_decode.banded_walk`, the
+    `jnp` walk it replaces on the chip: each layer geometry of
+    `size.banded_layers` at each of its buckets, a 27th of the columns
+    pads at the left, bf16 operands as the cells run them."""
+    import types
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.models.laguna_decode import banded_walk, prefill_reach
+    from ray_tpu.ops.banded_flash import banded_flash
+
+    hd, dt = 128, jnp.bfloat16
+    tiles = {} if size.banded_tiles is None else dict(zip(
+        ("block_q", "block_k"), size.banded_tiles))
+    peak = 197e12                       # v5e, bf16 (benchmark/peaks.json)
+    for H, n_kv, scale, window, rows, block, buckets in size.banded_layers:
+        cfg = types.SimpleNamespace(dtype=dt, attn_block=block,
+                                    n_kv_head=n_kv, head_dim=hd)
+        kernel = jax.jit(functools.partial(
+            banded_flash, n_kv_head=n_kv, head_dim=hd, scale=scale,
+            interpret=interpret, **tiles))
+        oracle = jax.jit(functools.partial(
+            banded_walk, cfg=cfg, scope="attn_full", scale=scale))
+        for T in buckets:
+            S = rows if window is None else window + T
+            pads = T // 27
+            ks = jax.random.split(jax.random.PRNGKey(size.seed + 17), 3)
+            q = jax.random.normal(ks[0], (T, H, hd), dt)
+            k, v = (jax.random.normal(key, (S, n_kv * hd), dt)
+                    for key in ks[1:])
+            reach = prefill_reach(T, 0, T - pads, window, xp=np)
+            args = (q, k, v, *(jnp.asarray(a) for a in reach))
+            ms, outs = {}, {}
+            for name, fn in (("kernel", kernel), ("jnp", oracle)):
+                outs[name] = jax.block_until_ready(fn(*args))
+                runs = 1 if interpret else 5
+                t0 = time.perf_counter()
+                for _ in range(runs):
+                    out = fn(*args)
+                jax.block_until_ready(out)
+                ms[name] = (time.perf_counter() - t0) / runs * 1e3
+            err = _rel_err(outs["kernel"], outs["jnp"])
+            attended = int(np.maximum(reach[1] - reach[0] + 1, 0).sum())
+            say("banded", kernel="banded_flash",
+                shape=[T, S, H, n_kv, hd], window=window,
+                ms=round(ms["kernel"], 4), ms_jnp=round(ms["jnp"], 4),
+                ms_at_peak=round(4 * hd * H * attended / peak * 1e3, 4),
+                err=round(err, 5))
+            assert err <= KERNEL_TOL, ("banded_flash", H, window, T, err)
+            assert not np.asarray(outs["kernel"][:pads], np.float32).any()
+
+
+def phase_banded(size: Size, platform: str = "tpu") -> Dict[str, Any]:
+    device = device_block(platform)
+    check_banded_kernels(size)
+    return {"device": device}
+
+
 PHASES = {"train": phase_train, "serve": phase_serve,
           "runtime": phase_runtime, "mla": phase_mla, "gqa": phase_gqa,
-          "kda": phase_kda, "ring": phase_ring,
+          "kda": phase_kda, "ring": phase_ring, "banded": phase_banded,
           "mesh_train": phase_mesh_train,
           "tensor_serve": phase_tensor_serve, "fleet": phase_fleet}
 

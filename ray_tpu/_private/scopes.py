@@ -133,12 +133,16 @@ KDA_DECODE = "kda_decode"
 #: a decode column's grouped-query attention over each row's ring where
 #: it lies in the window layers' stacked rings (ops/ring_decode.py)
 RING_DECODE = "ring_decode"
+#: a prefill's banded grouped-query attention, one sequence, one call an
+#: attention layer: the band is data, a K/V head a lane slice of the
+#: folded rows where they lie (ops/banded_flash.py)
+BANDED_FLASH = "banded_flash"
 KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV,
            FLASH_RES_FWD, FLASH_RES_DQ, FLASH_RES_DKV,
            FLASH_TRI_FWD, FLASH_TRI_BWD, SSM_SCAN, MLA_PAGED_DECODE,
            MLA_ROTARY_LANES, MLA_FLASH_PREFILL, MOE_DISPATCH,
            MOE_COMBINE, GQA_PAGED_DECODE, GROUPED_SWIGLU, KDA_CHUNK,
-           KDA_DECODE, RING_DECODE)
+           KDA_DECODE, RING_DECODE, BANDED_FLASH)
 
 # -- host phases -------------------------------------------------------------
 SPAN_PREFIX = "raytpu."

@@ -150,7 +150,8 @@ def _laguna() -> Dict[str, Any]:
         prefill=m.laguna_prefill, paged_prefill=m.laguna_paged_prefill,
         step=m.laguna_decode_step, verify=None,
         init_cache=m.laguna_init_cache,
-        init_paged_cache=m.laguna_init_paged_cache)
+        init_paged_cache=m.laguna_init_paged_cache,
+        prefill_attention=m.laguna_prefill_attention)
 
 
 def _solar_open2() -> Dict[str, Any]:
@@ -166,7 +167,8 @@ def _solar_open2() -> Dict[str, Any]:
         paged_prefill=m.solar_open2_paged_prefill,
         step=m.solar_open2_decode_step, verify=None,
         init_cache=m.solar_open2_init_cache,
-        init_paged_cache=m.solar_open2_init_paged_cache)
+        init_paged_cache=m.solar_open2_init_paged_cache,
+        prefill_attention=m.solar_open2_prefill_attention)
 
 
 def _phi4flash() -> Dict[str, Any]:
@@ -181,7 +183,8 @@ def _phi4flash() -> Dict[str, Any]:
         paged_prefill=m.phi4flash_paged_prefill,
         step=m.phi4flash_decode_step, verify=None,
         init_cache=m.phi4flash_init_cache,
-        init_paged_cache=m.phi4flash_init_paged_cache)
+        init_paged_cache=m.phi4flash_init_paged_cache,
+        prefill_attention=m.phi4flash_prefill_attention)
 
 
 #: family -> (what its cache holds, loader of its programs)

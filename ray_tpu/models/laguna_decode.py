@@ -44,9 +44,11 @@ rows it has not written derive to negative slots.
     the stack and `attend_rows` takes the whole score row.
   * a prefill attends BANDED (`attend_banded`): a tile of queries walks
     the key tiles between its first query's window edge (or 0) and its
-    own diagonal.  A window layer's keys are the slot's ring (from
-    zeros, from a snapshot or from the previous chunk, as `state`
-    says) laid before the tail's own.
+    own diagonal, on the chip as one kernel a layer
+    (ops/banded_flash.py), off it in ``jnp`` (`banded_walk`).  A window
+    layer's keys are the slot's ring (from zeros, from a snapshot or
+    from the previous chunk, as `state` says) laid before the tail's
+    own.
 
 ``cache["experts"]`` holds what the expert layers' routing did in the
 LAST program (decode_common.EXPERT_COUNTERS).
@@ -54,11 +56,13 @@ LAST program (decode_common.EXPERT_COUNTERS).
 
 from __future__ import annotations
 
+import collections
 import math
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ray_tpu._private import scopes
@@ -66,6 +70,7 @@ from ray_tpu.models.decode_common import (NO_SNAPSHOT, STATE_FROM_ZERO,
                                           PagedKV, _block_of, _positions,
                                           _refuse_mesh, generator,
                                           is_paged, slot_mask)
+from ray_tpu.ops import banded_flash as flash
 from ray_tpu.ops.gqa_paged_decode import (gqa_paged_decode,
                                           gqa_paged_decode_reference)
 from ray_tpu.ops.ring_decode import fits_the_kernel, ring_decode
@@ -76,7 +81,7 @@ from ray_tpu.models.laguna import (ATTN_SCOPE, FULL, WINDOW, LagunaConfig,
 
 __all__ = ["laguna_init_cache", "laguna_init_paged_cache",
            "laguna_prefill", "laguna_paged_prefill", "laguna_decode_step",
-           "laguna_generate"]
+           "laguna_generate", "laguna_prefill_attention"]
 
 
 def _tensors(cfg: LagunaConfig, batch: int, *lead: int):
@@ -144,6 +149,15 @@ def _rows_values(e, v, cfg):
         for g in range(cfg.n_kv_head)], axis=1)
 
 
+def _takes_kernel(T: int, S: int, H: int, cfg) -> bool:
+    """What a prefill can see of its input picks its attention: on the
+    chip, heads of whole lanes over whole tiles take the kernel
+    (ops/banded_flash.py); the CPU and any other shape keep
+    `banded_walk`, the parity oracle."""
+    return jax.default_backend() == "tpu" and flash.fits(
+        T, S, H, cfg.n_kv_head, cfg.head_dim, cfg.n_kv_head * cfg.head_dim)
+
+
 def attend_banded(q, k, v, first, last, cfg, scope: str, scale=None):
     """One sequence's attention without its score matrix (`cfg`: any
     config with ``dtype``, ``attn_block``, ``n_kv_head`` and
@@ -151,10 +165,25 @@ def attend_banded(q, k, v, first, last, cfg, scope: str, scale=None):
     sqrt(head_dim)``).  q (T, H,
     hd); k, v (S, kv_width) folded; query t attends the key INDICES
     ``first[t] <= a <= last[t]`` ((T,) int32; a query with ``last <
-    first`` attends nothing and gives zeros).  A tile of queries walks
-    the key tiles from its lowest `first` to its highest `last` with a
-    running maximum and sum, so a band costs its width and a causal
-    triangle its half.  Returns (T, H, hd) in the compute dtype."""
+    first`` attends nothing and gives zeros).  Returns (T, H, hd) in
+    the compute dtype.  Window or full is data (`first`, `last`), the
+    group size, the head count and the scale are `cfg`'s and `q`'s, and
+    `_takes_kernel` picks the path: one Pallas call under `scope`, or
+    `banded_walk`."""
+    T, H, hd = q.shape
+    if _takes_kernel(T, k.shape[0], H, cfg):
+        with jax.named_scope(scope):
+            return flash.banded_flash(
+                q, k, v, first, last, n_kv_head=cfg.n_kv_head, head_dim=hd,
+                scale=1.0 / math.sqrt(hd) if scale is None else scale)
+    return banded_walk(q, k, v, first, last, cfg, scope, scale)
+
+
+def banded_walk(q, k, v, first, last, cfg, scope: str, scale=None):
+    """`attend_banded` in ``jnp``, the CPU's path and the parity
+    oracle: a tile of queries walks the key tiles from its lowest
+    `first` to its highest `last` with a running maximum and sum, so a
+    band costs its width and a causal triangle its half."""
     T, H, hd = q.shape
     S = k.shape[0]
     dt = cfg.dtype
@@ -211,6 +240,60 @@ def attend_banded(q, k, v, first, last, cfg, scope: str, scale=None):
 
     with jax.named_scope(scope):
         return lax.map(queries, jnp.arange(T // qb)).reshape(T, H, hd)
+
+
+def prefill_reach(t_pad: int, prefix_len, n_tail, window=None, xp=jnp):
+    """(first, last) of `attend_banded` for a paged prefill's `t_pad`
+    columns, the last `n_tail` of them real, behind `prefix_len` slots
+    (a pad column's ``last`` is -1: it attends nothing).  A full layer
+    (`window` None) attends the row's gathered view, index == slot.  A
+    window layer's keys are laid so that index ``a`` holds slot ``a +
+    low``: the ring's `window` slots before the tail, then the tail's
+    own, the tail's pad columns under the ring's rows.  `xp` is
+    jax.numpy in a program, numpy for the host's count."""
+    pad = t_pad - n_tail
+    col = xp.arange(t_pad, dtype=xp.int32)
+    real = col >= pad
+    logical = prefix_len + col - pad               # position iff real
+    if window is None:
+        return xp.zeros_like(logical), xp.where(real, logical, -1)
+    low = prefix_len - pad - window
+    return (xp.maximum(logical - window + 1, 0) - low,
+            xp.where(real, logical - low, -1))
+
+
+def banded_prefill_attention(cfg, t_pad: int, prefix_len: int, n_tail: int,
+                             layers) -> Tuple[bool, int, int]:
+    """For the host's count of what a paged prefill's attention layers
+    ran (`families.Family.prefill_attention`): (whether the kernel
+    attended every one, the (query tile, key tile) pairs they walked a
+    K/V head, the pairs a walk over every key tile the sequence holds
+    would have).  `cfg`: the geometry `attend_banded` is given;
+    `layers`: (how many, query heads, rows of the view, window or None)
+    of each kind.  A `jnp` prefill counts no pairs."""
+    walked = square = 0
+    for count, H, S, window in layers:
+        if not _takes_kernel(t_pad, S, H, cfg):
+            return False, 0, 0
+        lo, hi, _, _ = flash.walk(
+            *prefill_reach(t_pad, prefix_len, n_tail, window, xp=np), S)
+        held = prefix_len if window is None else min(prefix_len, window)
+        walked += count * int((hi - lo).sum())
+        square += count * len(lo) * -(-(held + n_tail) // flash.BLOCK_K)
+    return True, walked, square
+
+
+def laguna_prefill_attention(cfg: LagunaConfig, t_pad: int, prefix_len: int,
+                             n_tail: int) -> Tuple[bool, int, int]:
+    """`banded_prefill_attention` of `laguna_paged_prefill`'s layers."""
+    views = {FULL: (cfg.max_seq, None),
+             WINDOW: (cfg.window + t_pad, cfg.window)}
+    alike = collections.Counter(
+        (H, *views[kind])
+        for kind, H in zip(cfg.layer_types, cfg.heads_per_layer))
+    return banded_prefill_attention(
+        cfg, t_pad, prefix_len, n_tail,
+        [(n, *layer) for layer, n in alike.items()])
 
 
 # -- a window layer's ring ----------------------------------------------------
@@ -374,15 +457,8 @@ def laguna_paged_prefill(params, cache, tokens: jnp.ndarray,
     pkv = PagedKV(cache, row_bt[None],
                   jnp.where(real, logical, cfg.max_seq)[None], whole=True)
     pools = pkv.pools
-    # a full layer's keys are the row's gathered view, index == slot
-    reach_full = (jnp.zeros_like(logical),
-                  jnp.where(real, logical, -1))
-    # a window layer's are laid so that index a holds slot ``a + low``:
-    # the ring's `window` slots before the tail, then the tail's own,
-    # the tail's pad columns under the ring's rows
-    low = prefix_len - pad - W
-    reach_window = (jnp.maximum(logical - W + 1, 0) - low,
-                    jnp.where(real, logical - low, -1))
+    reach_full = prefill_reach(Tt, prefix_len, n_tail)
+    reach_window = prefill_reach(Tt, prefix_len, n_tail, W)
     keep = jnp.maximum(entry, 0)
     with jax.named_scope(scopes.KV_POOL):
         def rows_of(ring, row):                  # (n_window, window, w)

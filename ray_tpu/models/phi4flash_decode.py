@@ -63,7 +63,9 @@ from ray_tpu.models.jamba_decode import _layer_state, _set_layer_state
 # the rings, the banded prefill attention and a row's masked softmax
 # over folded K/V are Laguna's, at this family's pair-head geometry
 from ray_tpu.models.laguna_decode import (_attend_stacked_ring, _ring_of,
-                                          attend_banded, attend_rows)
+                                          attend_banded, attend_rows,
+                                          banded_prefill_attention,
+                                          prefill_reach)
 from ray_tpu.models.phi4flash import (Phi4FlashConfig, attn_layer,
                                       attend_masked, causal_mask,
                                       cross_decoder, embed, lm_logits,
@@ -73,9 +75,22 @@ from ray_tpu.ops.gqa_paged_decode import (gqa_paged_decode,
 
 __all__ = ["phi4flash_init_cache", "phi4flash_init_paged_cache",
            "phi4flash_prefill", "phi4flash_paged_prefill",
-           "phi4flash_decode_step", "phi4flash_generate"]
+           "phi4flash_decode_step", "phi4flash_generate",
+           "phi4flash_prefill_attention"]
 
 _RINGS = ("wk", "wv")
+
+
+def phi4flash_prefill_attention(cfg: Phi4FlashConfig, t_pad: int,
+                                prefix_len: int, n_tail: int
+                                ) -> Tuple[bool, int, int]:
+    """`laguna_decode.banded_prefill_attention` of
+    `phi4flash_paged_prefill`'s window layers and its full layer, in
+    the pair-heads' geometry."""
+    return banded_prefill_attention(
+        cfg.pairs, t_pad, prefix_len, n_tail,
+        [(cfg.n_self, cfg.n_head, cfg.window + t_pad, cfg.window),
+         (1, cfg.n_head, cfg.max_seq, None)])
 
 
 def _tensors(cfg: Phi4FlashConfig, batch: int, *lead: int):
@@ -248,13 +263,10 @@ def phi4flash_paged_prefill(params, cache, tokens: jnp.ndarray,
     # index can alias a live prefix slot
     pkv = PagedKV(cache, row_bt[None],
                   jnp.where(real, logical, cfg.max_seq)[None], whole=True)
-    # the full layer's keys are the row's gathered view, index == slot
-    reach_full = (jnp.zeros_like(logical), jnp.where(real, logical, -1))
-    # a window layer's are laid so that index a holds slot ``a + low``
-    # (laguna_decode.laguna_paged_prefill)
-    low = prefix_len - pad - W
-    reach_window = (jnp.maximum(logical - W + 1, 0) - low,
-                    jnp.where(real, logical - low, -1))
+    # the full layer's keys are the row's gathered view, a window
+    # layer's the ring laid before the tail (laguna_decode.prefill_reach)
+    reach_full = prefill_reach(Tt, prefix_len, n_tail)
+    reach_window = prefill_reach(Tt, prefix_len, n_tail, W)
     # the column after which the state is `boundary` tokens old
     capture = jnp.clip(pad + boundary - prefix_len - 1, 0, Tt - 1)
     ring_cut = jnp.clip(boundary - prefix_len + pad, 0, Tt)

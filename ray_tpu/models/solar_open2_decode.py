@@ -49,7 +49,9 @@ from ray_tpu.models.decode_common import (NO_SNAPSHOT, STATE_FROM_SLOT,
                                           generator, is_paged, slot_mask)
 from ray_tpu.models.experts import _with_counters
 # the banded prefill attention over folded K/V is Laguna's
-from ray_tpu.models.laguna_decode import attend_banded
+from ray_tpu.models.laguna_decode import (attend_banded,
+                                          banded_prefill_attention,
+                                          prefill_reach)
 from ray_tpu.models.solar_open2 import (GQA, KDA, SolarOpen2Config,
                                         attend_masked, embed, gqa_block,
                                         kda_block, lm_logits, walk_layers,
@@ -59,7 +61,18 @@ from ray_tpu.ops.gqa_paged_decode import (gqa_paged_decode,
 
 __all__ = ["solar_open2_init_cache", "solar_open2_init_paged_cache",
            "solar_open2_prefill", "solar_open2_paged_prefill",
-           "solar_open2_decode_step", "solar_open2_generate"]
+           "solar_open2_decode_step", "solar_open2_generate",
+           "solar_open2_prefill_attention"]
+
+
+def solar_open2_prefill_attention(cfg: SolarOpen2Config, t_pad: int,
+                                  prefix_len: int, n_tail: int
+                                  ) -> Tuple[bool, int, int]:
+    """`laguna_decode.banded_prefill_attention` of
+    `solar_open2_paged_prefill`'s softmax layers."""
+    return banded_prefill_attention(
+        cfg, t_pad, prefix_len, n_tail,
+        [(len(cfg.layers_of(GQA)), cfg.n_head, cfg.max_seq, None)])
 
 
 def _kv_tensors(cfg: SolarOpen2Config, *lead: int):
@@ -228,8 +241,8 @@ def solar_open2_paged_prefill(params, cache, tokens: jnp.ndarray,
     pkv = PagedKV(cache, row_bt[None],
                   jnp.where(real, logical, cfg.max_seq)[None], whole=True)
     pools = pkv.pools
-    # a GQA layer's keys are the row's gathered view, index == slot
-    reach = (jnp.zeros_like(logical), jnp.where(real, logical, -1))
+    # a GQA layer's keys are the row's gathered view
+    reach = prefill_reach(Tt, prefix_len, n_tail)
     # the column after which the state is `boundary` tokens old
     capture = jnp.clip(pad + boundary - prefix_len - 1, 0, Tt - 1)
     keep = jnp.maximum(entry, 0)

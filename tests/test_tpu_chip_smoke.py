@@ -40,7 +40,10 @@ TOY = chip_smoke.Size(
     kda_heads=(8, 128), kda_prefills=((96, 13), (160, 70)),
     kda_wave=(6, 3),
     ring_waves=((3, 5, 32, 8, 2, 128, 64 ** -0.5),
-                (2, 4, 16, 16, 2, 128, 128 ** -0.5)))
+                (2, 4, 16, 16, 2, 128, 128 ** -0.5)),
+    banded_layers=((12, 2, 128 ** -0.5, None, 128, 32, (64,)),
+                   (8, 2, 64 ** -0.5, 32, None, 32, (64,))),
+    banded_tiles=(32, 32))
 
 
 @pytest.fixture
@@ -60,6 +63,9 @@ def interpreted(monkeypatch):
         chip_smoke.check_kda_kernels, interpret=True))
     monkeypatch.setattr(chip_smoke, "check_ring_kernels", functools.partial(
         chip_smoke.check_ring_kernels, interpret=True))
+    monkeypatch.setattr(chip_smoke, "check_banded_kernels",
+                        functools.partial(chip_smoke.check_banded_kernels,
+                                          interpret=True))
 
 
 @pytest.fixture
@@ -91,6 +97,11 @@ def test_gqa_phase(interpreted):
 
 def test_kda_phase(interpreted):
     out = chip_smoke.phase_kda(TOY, "cpu")
+    assert out["device"]["platform"] == "cpu"
+
+
+def test_banded_phase(interpreted):
+    out = chip_smoke.phase_banded(TOY, "cpu")
     assert out["device"]["platform"] == "cpu"
 
 

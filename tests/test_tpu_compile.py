@@ -15,6 +15,7 @@ Kernels and single programs only; the whole train step (~15 s) is
 compiled by the builder before a chip call, not in tier-1.
 """
 
+import functools
 import os
 import re
 
@@ -644,6 +645,79 @@ def _experts_kernels(text: str):
     return ([(n, s) for n, s in keyed
              if n.startswith(scopes.GROUPED_SWIGLU)],
             [(n, s) for n, s in keyed if "ragged-dot" in n])
+
+
+@pytest.mark.parametrize("T", [1024, 8192])
+@pytest.mark.parametrize("H,n_kv,window,rows", [
+    (48, 8, None, 8704), (64, 8, 512, None), (40, 10, 512, None),
+    (40, 10, None, 4864)],
+    ids=["laguna_full", "laguna_window_and_solar", "phi4_window",
+         "phi4_full"])
+def test_banded_flash_kernel_compiles_at_the_cells_shapes(H, n_kv, window,
+                                                          rows, T):
+    """ops/banded_flash.py at the three cells' geometries (Solar-Open2's
+    softmax layer is Laguna's window geometry over a full view), the
+    smallest 1,024 bucket and the largest: ONE Mosaic call, the band
+    being data; K and V stay whole in HBM, so the call's temporaries
+    are the walk's table and the reach, not a copy of either."""
+    from ray_tpu.ops.banded_flash import banded_flash
+
+    T = min(T, 4096) if n_kv == 10 else T
+    S = rows if window is None else window + T
+    spec = _one_chip()
+    args = (spec((T, H, 128), jnp.bfloat16),
+            spec((S, n_kv * 128), jnp.bfloat16),
+            spec((S, n_kv * 128), jnp.bfloat16),
+            spec((T,), jnp.int32), spec((T,), jnp.int32))
+    fn = functools.partial(banded_flash, n_kv_head=n_kv, head_dim=128,
+                           scale=0.125)
+    assert _kernels_in(fn, *args) == 1
+
+
+def _attention_calls(text: str):
+    """(scopes of the `banded_flash` calls, names of the ``while``
+    instructions under an attention scope) in a compiled program."""
+    from ray_tpu._private import scopes
+
+    attn = {scopes.ATTN_FULL, scopes.ATTN_WINDOW}
+    scoped = scopes.scope_map_from_hlo(text)
+    calls = sorted(scope for name, keyed in scoped.items()
+                   for key, scope in keyed.items()
+                   if name.startswith(scopes.BANDED_FLASH)
+                   and "custom-call" in key)
+    loops = [name for name, keyed in scoped.items()
+             for key, scope in keyed.items()
+             if scope in attn and key.endswith(" while")]
+    return calls, loops
+
+
+@pytest.mark.parametrize("cell,t_pad,want", [
+    ("laguna-xs2.serve-offline-mixed", 2048,
+     ["attn_full"] * 2 + ["attn_window"] * 3),
+    ("solar-open2.serve-offline-summarize", 2048, ["attn_full"]),
+    # the eight window layers share the pairs' scan body
+    ("phi4-mini-flash.serve-offline-cot", 512,
+     ["attn_full", "attn_window"])],
+    ids=["laguna", "solar_open2", "phi4flash"])
+def test_a_prefill_attends_in_one_banded_flash_a_layer(cell, t_pad, want,
+                                                       monkeypatch):
+    """The three cells' prefill programs (the program asks
+    ``jax.default_backend()``, steered here) hold ONE ``banded_flash``
+    call an attention layer under ``attn_full`` / ``attn_window`` and
+    no ``while`` of the `jnp` walk's under either."""
+    from ray_tpu.models.laguna import laguna_init
+    from ray_tpu.models.phi4flash import phi4flash_init
+    from ray_tpu.models.solar_open2 import solar_open2_init
+
+    init = {"laguna": laguna_init, "solar": solar_open2_init,
+            "phi4": phi4flash_init}[cell.split("-")[0]]
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    _, params, cache, programs, _ = _serving_cell(cell, init, t_pad)
+    fn, args = programs["prefill"]
+    calls, loops = _attention_calls(jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args).compile().as_text())
+    assert calls == want and not loops, (calls, loops)
 
 
 #: compiled peaks of the cells' 8,192 prefills at 6291e8d (PR 46), whose
