@@ -21,10 +21,11 @@ from typing import Any, Callable, Dict, Optional, Tuple
 #: which every engine feature can move (rewind by position, spill and
 #: restore by block, hand off).  RECURRENT: some layers keep one state
 #: per sequence beside the K/V (models/jamba_decode.py: a vector a
-#: channel; models/solar_open2_decode.py: a matrix a head), and the paged
-#: prefill takes one more argument, `state` (decode_common.py: where the
-#: slot's state starts and which snapshot it leaves); what cannot carry
-#: that state yet is refused when the engine's options are checked.
+#: channel; models/solar_open2_decode.py, models/olmo_hybrid_decode.py:
+#: a matrix a head), and the paged prefill takes one more argument,
+#: `state` (decode_common.py: where the slot's state starts and which
+#: snapshot it leaves); what cannot carry that state yet is refused
+#: when the engine's options are checked.
 #: LATENT: positional as KV is (a slot's past is its rows, a prefix is
 #: its blocks), but a row is one latent a token, not K and V per head
 #: (models/kimi_k2_decode.py): what moves K/V rows of one shape, rewinds
@@ -187,13 +188,31 @@ def _phi4flash() -> Dict[str, Any]:
         prefill_attention=m.phi4flash_prefill_attention)
 
 
+def _olmo_hybrid() -> Dict[str, Any]:
+    from ray_tpu.models import olmo_hybrid_decode as m
+    from ray_tpu.models.olmo_hybrid import (olmo_hybrid_config,
+                                            olmo_hybrid_init,
+                                            olmo_hybrid_logical_axes)
+
+    return dict(
+        config=olmo_hybrid_config, init=olmo_hybrid_init,
+        logical_axes=olmo_hybrid_logical_axes,
+        generate=m.olmo_hybrid_generate, prefill=m.olmo_hybrid_prefill,
+        paged_prefill=m.olmo_hybrid_paged_prefill,
+        step=m.olmo_hybrid_decode_step, verify=None,
+        init_cache=m.olmo_hybrid_init_cache,
+        init_paged_cache=m.olmo_hybrid_init_paged_cache,
+        prefill_attention=m.olmo_hybrid_prefill_attention)
+
+
 #: family -> (what its cache holds, loader of its programs)
 FAMILIES: Dict[str, Tuple[str, Callable[[], Dict[str, Any]]]] = {
     "gpt2": (KV, _gpt2), "llama": (KV, _llama),
     "jamba": (RECURRENT, _jamba), "kimi_k2": (LATENT, _kimi_k2),
     "laguna": (WINDOWED, _laguna),
     "solar_open2": (RECURRENT, _solar_open2),
-    "phi4flash": (RECURRENT_WINDOWED, _phi4flash)}
+    "phi4flash": (RECURRENT_WINDOWED, _phi4flash),
+    "olmo_hybrid": (RECURRENT, _olmo_hybrid)}
 
 
 def cache_kind(name: str) -> Optional[str]:

@@ -45,6 +45,26 @@ an eigenvalue of -1).  Five forms of it, three of them plain
     Diag(beta) (V - K+ S_0)``; then ``O = Q+ S_0 + B U`` and ``S_C =
     Diag(exp(G_C)) S_0 + K-^T U``.
 
+  * the same rule with ONE decay a head (Gated DeltaNet, arXiv
+    2412.06464): every form takes ``g`` of trailing size ``dk`` (KDA)
+    or of trailing size 1.  The recurrence and the step broadcast it.
+    The chunked form then needs no pairwise decay a channel: with
+    ``G_r`` a number a head,
+
+        A_ij = (k_i . k_j) exp(G_i - G_j)      j <  i
+        B_ri = (q_r . k_i) exp(G_r - G_i)      i <= r
+
+    are ONE product each and a (C, C) mask of non-positive exponents,
+    and `_scalar_gate_chunked` is matmuls only.  With ``T = (I +
+    Diag(beta) A)^-1``, ``U = W - Y S_0`` where ``W = T Diag(beta) V``
+    and ``Y = T Diag(beta exp(G)) K`` need no state; so ``O = B W +
+    (exp(G) Q - B Y) S_0`` and ``S_C = exp(G_C) S_0 + K-^T W - (K-^T
+    Y) S_0`` with ``K-_i = k_i exp(G_C - G_i)``: everything but the two
+    products with ``S_0`` is taken for all chunks of a prefill at once,
+    in batched products, and only ``S_0 -> S_C`` and the state's part
+    of ``O`` run in the `lax.scan`.  Neither kernel below takes a
+    scalar gate: those shapes keep the ``jnp`` forms, on the chip too.
+
 Every exponent above is <= 0, and the code keeps it so.  ``exp(-G_j)``
 is never formed alone: the factored ``(k_i e^{G_i}) . (k_j e^{-G_j})``
 overflows float32 under a strong decay.  The pairwise decays
@@ -128,8 +148,9 @@ _HEADS_A_WAVE_STEP = 8
 
 
 def kda_step(q, k, v, g, beta, state):
-    """One token a row.  q, k, g (B, H, dk); v (B, H, dv); beta (B, H);
-    state (B, H, dk, dv) float32.  Returns (o (B, H, dv) float32, the
+    """One token a row.  q, k (B, H, dk); g (B, H, dk), or (B, H, 1):
+    one decay a head; v (B, H, dv); beta (B, H); state (B, H, dk, dv)
+    float32.  Returns (o (B, H, dv) float32, the
     new state).  Multiplies and sums in float32, no matmul: a row's
     state is read once and written once, which is all a decode wave's
     delta rule costs."""
@@ -142,8 +163,9 @@ def kda_step(q, k, v, g, beta, state):
 
 
 def kda_recurrent(q, k, v, g, beta, state=None):
-    """The recurrence over time.  q, k, g (B, T, H, dk); v (B, T, H,
-    dv); beta (B, T, H); state (B, H, dk, dv) or None (zeros).  Returns
+    """The recurrence over time.  q, k (B, T, H, dk); g (B, T, H, dk)
+    or (B, T, H, 1): one decay a head; v (B, T, H, dv); beta (B, T, H);
+    state (B, H, dk, dv) or None (zeros).  Returns
     (o (B, T, H, dv) float32, the state after the last token)."""
     B, _, H, dk = k.shape
     if state is None:
@@ -257,11 +279,84 @@ def _chunk(q, k, v, g, beta, s0, sub: int, dtype, capture=None):
         None if capture is None else state_after(capture)
 
 
+def _scalar_gate_chunked(q, k, v, g, beta, state, chunk: int, sub: int,
+                         dtype, capture):
+    """`kda_chunked` for ONE decay a head, g (B, T, H, 1) (module
+    docstring): what needs no state for every chunk at once, heads
+    leading, (B, H, n, C, ...); the scan carries the state alone."""
+    B, T, H, dk = k.shape
+    dv = v.shape[-1]
+    C = chunk
+    fill = -T % C
+    n = (T + fill) // C
+    prec = lax.Precision.HIGHEST if dtype == _F32 else None
+
+    def mm(spec, a, b):
+        return jnp.einsum(spec, a.astype(dtype), b.astype(dtype),
+                          precision=prec, preferred_element_type=_F32)
+
+    def chunks(a):
+        """(B, T, H, d) -> (B, H, n, C, d) float32."""
+        a = jnp.pad(a.astype(_F32), ((0, 0), (0, fill), (0, 0), (0, 0)))
+        return jnp.moveaxis(a.reshape(B, n, C, H, -1), 3, 1)
+
+    q, k, v = chunks(q), chunks(k), chunks(v)
+    G = jnp.cumsum(chunks(g)[..., 0], axis=-1)              # (B, H, n, C)
+    beta = chunks(beta[..., None])
+    decay = jnp.exp(jnp.where(jnp.tril(jnp.ones((C, C), bool)),
+                              G[..., :, None] - G[..., None, :], -jnp.inf))
+    A = jnp.tril(mm("...id,...jd->...ij", k, k) * decay, -1)
+    Bm = mm("...id,...jd->...ij", q, k) * decay
+    whole = jnp.exp(G)[..., None]
+    solved = _solve_unit_lower(
+        beta * A, beta * jnp.concatenate([v, whole * k], axis=-1), sub)
+    W, Y = solved[..., :dv], solved[..., dv:]
+    last = G[..., -1:]                                      # G_C
+    k_end = k * jnp.exp(last - G)[..., None]
+    per_chunk = tuple(jnp.moveaxis(a, 2, 0) for a in (
+        mm("...ci,...iv->...cv", Bm, W),                    # O's own part
+        q * whole - mm("...ci,...id->...cd", Bm, Y),        # ... from S_0
+        mm("...cd,...cv->...dv", k_end, W),                 # S_C's own part
+        mm("...cd,...ce->...de", k_end, Y),                 # ... from S_0
+        jnp.exp(last)[..., None]))
+    at = None if capture is None else capture // C
+
+    # (a loop's body names its scope again: it is lowered as a function
+    # of its own, kimi_k2_decode.attend_blockwise)
+    @jax.named_scope(scopes.ATTN_LINEAR)
+    def body(carry, x):
+        s, entered = carry
+        i, o_own, q_in, s_own, s_in, keep = x
+        if capture is not None:
+            entered = jnp.where(i == at, s, entered)
+        o = o_own + mm("...cd,...dv->...cv", q_in, s)
+        s = keep * s + s_own - mm("...de,...ev->...dv", s_in, s)
+        return (s, entered), o
+
+    (state, entered), o = lax.scan(
+        body, (state, None if capture is None else state),
+        (jnp.arange(n, dtype=jnp.int32),) + per_chunk)
+    o = jnp.moveaxis(o, (0, 2), (1, 3)).reshape(B, n * C, H, dv)[:, :T]
+    if capture is None:
+        return o, state, None
+    # the state once rows ``0..row`` of chunk `at` are in, from the
+    # state that entered it
+    k_c, W_c, Y_c, G_c = (lax.dynamic_index_in_dim(a, at, 2, keepdims=False)
+                          for a in (k, W, Y, G))
+    row = capture - at * C
+    G_row = lax.dynamic_index_in_dim(G_c, row, axis=-1)         # (B, H, 1)
+    kept = jnp.exp(jnp.where(jnp.arange(C) <= row, G_row - G_c, -jnp.inf))
+    U = W_c - mm("...cd,...dv->...cv", Y_c, entered)
+    return o, state, jnp.exp(G_row)[..., None] * entered + mm(
+        "...cd,...cv->...dv", k_c * kept[..., None], U)
+
+
 def kda_chunked(q, k, v, g, beta, state=None, *, chunk: int = 64,
                 sub: int = 16, dtype=jnp.bfloat16, capture=None
                 ) -> Tuple[jnp.ndarray, jnp.ndarray, Optional[jnp.ndarray]]:
-    """The chunked form (module docstring), shapes as `kda_recurrent`.
-    `chunk` a multiple of `sub`; a length that is no multiple of
+    """The chunked form (module docstring), shapes as `kda_recurrent`;
+    a ``g`` of trailing size 1 takes the matmul form of one decay a
+    head.  `chunk` a multiple of `sub`; a length that is no multiple of
     `chunk` is filled with identity steps.  `capture`: a traced index
     of the time axis after which the state is handed back too (a
     snapshot), or None.
@@ -275,6 +370,12 @@ def kda_chunked(q, k, v, g, beta, state=None, *, chunk: int = 64,
         raise ValueError(f"chunk {chunk} must be a multiple of sub {sub}")
     if state is None:
         state = jnp.zeros((B, H, dk, dv), _F32)
+    state = state.astype(_F32)
+    if capture is not None:
+        capture = jnp.asarray(capture, jnp.int32)
+    if g.shape[-1] == 1 < dk:
+        return _scalar_gate_chunked(q, k, v, g, beta, state, chunk, sub,
+                                    dtype, capture)
     fill = -T % chunk
     n = (T + fill) // chunk
 
@@ -286,9 +387,6 @@ def kda_chunked(q, k, v, g, beta, state=None, *, chunk: int = 64,
         return jnp.moveaxis(jnp.moveaxis(a, 1, 0), 2, 3)
 
     xs = tuple(chunks(a) for a in (q, k, v, g, beta))
-    state = state.astype(_F32)
-    if capture is not None:
-        capture = jnp.asarray(capture, jnp.int32)
 
     # (a loop's body names its scope again: it is lowered as a function
     # of its own, kimi_k2_decode.attend_blockwise)
@@ -567,10 +665,12 @@ def _call(q, k, v, g, beta, state, cap_chunk, *, chunk: int, chunks: int,
     return outs[0], outs[1], (outs[2] if snapshot else None)
 
 
-def _fits_the_kernel(k, v, chunk: int, sub: int) -> bool:
-    """Whole lanes a head, more than one column, sub-chunks of whole
-    sublane tiles and a chunk whose columns fit a tile's lanes."""
-    return (k.shape[1] > 1 and k.shape[-1] % _LANES == 0
+def _fits_the_kernel(k, v, g, chunk: int, sub: int) -> bool:
+    """A decay a channel, whole lanes a head, more than one column,
+    sub-chunks of whole sublane tiles and a chunk whose columns fit a
+    tile's lanes."""
+    return (g.shape[-1] == k.shape[-1]
+            and k.shape[1] > 1 and k.shape[-1] % _LANES == 0
             and v.shape[-1] % _LANES == 0 and sub % _SUBLANES == 0
             and chunk % sub == 0 and chunk <= _LANES)
 
@@ -639,7 +739,7 @@ def kda_chunk(q, k, v, g, beta, state=None, *, chunk: int = 64,
     one program share one trace and one lowering of the kernel.
     Differentiated, it is `kda_chunked`, forward and backward."""
     sub = min(sub, chunk)
-    if not _fits_the_kernel(k, v, chunk, sub):
+    if not _fits_the_kernel(k, v, g, chunk, sub):
         raise ValueError(
             f"kda_chunk: heads of {k.shape[-1]} x {v.shape[-1]}, chunk "
             f"{chunk}, sub {sub} and {k.shape[1]} columns do not fit the "
@@ -659,7 +759,7 @@ def kda_prefill(q, k, v, g, beta, state=None, *, chunk: int = 64,
     can see: `kda_chunk` on the chip where the shapes fit the kernel,
     `kda_chunked` everywhere else (module docstring)."""
     form = kda_chunk if jax.default_backend() == "tpu" and _fits_the_kernel(
-        k, v, chunk, min(sub, chunk)) else kda_chunked
+        k, v, g, chunk, min(sub, chunk)) else kda_chunked
     return form(q, k, v, g, beta, state, chunk=chunk, sub=sub, dtype=dtype,
                 capture=capture)
 
@@ -744,11 +844,12 @@ def _step_kernel(j_ref, q_ref, k_ref, v_ref, g_ref, b_ref, s_ref,
     o_ref[0, rows, :] = jnp.concatenate(out, axis=0)
 
 
-def _fits_the_step_kernel(stack) -> bool:
-    """float32 matrices, whole lanes a head both ways, heads in whole
-    groups."""
+def _fits_the_step_kernel(stack, g) -> bool:
+    """float32 matrices, a decay a channel, whole lanes a head both
+    ways, heads in whole groups."""
     _, _, H, dk, dv = stack.shape
-    return (stack.dtype == _F32 and dk % _LANES == 0 and dv % _LANES == 0
+    return (stack.dtype == _F32 and g.shape[-1] == dk
+            and dk % _LANES == 0 and dv % _LANES == 0
             and H % _HEADS_A_WAVE_STEP == 0)
 
 
@@ -824,7 +925,7 @@ def kda_decode(q, k, v, g, beta, stack, j, *, interpret: bool = False):
     the shapes fit it (the CPU tests).  Differentiated, it is the `jnp`
     form, forward and backward."""
     j = jnp.asarray(j, jnp.int32)
-    if not (_fits_the_step_kernel(stack)
+    if not (_fits_the_step_kernel(stack, g)
             and (interpret or jax.default_backend() == "tpu")):
         return _step_on_layer(q, k, v, g, beta, stack, j)
     return _step_kernel_call(*(a.astype(_F32) for a in (q, k, v, g, beta)),

@@ -44,6 +44,9 @@ GEOMETRIES = {
     # Phi-4-mini-flash's pair-heads: 4 query heads a K/V pair-head of
     # 2 x 64 lanes, the scale the sub-heads' 64 dims give
     "pair_heads": (8, 2, 1.0 / math.sqrt(64), jnp.bfloat16),
+    # multi-head attention (models/olmo_hybrid.py): every query head
+    # its own K/V head, a tile of block_q x 1 head
+    "group_of_1": (3, 3, None, jnp.bfloat16),
 }
 
 
@@ -100,6 +103,7 @@ def test_shapes_the_tiles_do_not_divide_are_refused():
     assert flash.fits(8192, 8704, 48, 8, 128, 1024)
     assert flash.fits(4096, 4864, 40, 10, 128, 1280)
     assert flash.fits(1024, 512 + 1024, 64, 8, 128, 1024)
+    assert flash.fits(6144, 6656, 30, 30, 128, 3840)      # a group of 1
     assert not flash.fits(8192, 8704, 48, 8, 64, 512)     # half a lane row
     assert not flash.fits(8192, 8704, 48, 8, 128, 2048)   # wider rows
     assert not flash.fits(1000, 8704, 48, 8, 128, 1024)
@@ -157,9 +161,11 @@ _CELLS = {
     "laguna": (dict(max_seq=8704), [(2, None), (3, 512)]),
     "solar_open2": (dict(max_seq=8704), [(1, None)]),
     "phi4flash": (dict(max_seq=4864), [(1, None), (8, 512)]),
+    "olmo_hybrid": (dict(max_seq=6656, n_layer=8), [(2, None)]),
 }
 _PRESETS = {"laguna": "laguna-xs2", "solar_open2": "solar-open2",
-            "phi4flash": "phi4-mini-flash"}
+            "phi4flash": "phi4-mini-flash",
+            "olmo_hybrid": "olmo-hybrid-7b"}
 
 
 def _cell_config(name):

@@ -47,6 +47,10 @@ NANO, ODD, PUBLISHED = (6, 2, 16), (9, 3, 8), (48, 8, 128)
 #: differential attention's pair-heads (models/phi4flash.py): 40 padded
 #: query sub-heads over 10 K/V pair-heads of 128 lanes, a group of 4
 PAIRS = (40, 10, 128)
+#: multi-head attention (models/olmo_hybrid.py): as many K/V heads as
+#: query heads, a group of ONE padded to a sublane tile, rows of 3,840
+#: lanes
+EVERY_HEAD_ITS_OWN = (30, 30, 128)
 WAVES = {
     "ragged": ([1, 16, 17, 0, 100, 300, EDGE + 200], "out_of_order",
                1024, NANO),
@@ -61,6 +65,8 @@ WAVES = {
     "published_heads": ([EDGE + 1, 0, 40], "out_of_order", 1024,
                         PUBLISHED),
     "pair_heads": ([EDGE + 17, 0, 33, 512], "shared", 1024, PAIRS),
+    "a_group_of_one": ([EDGE + 5, 0, 37], "out_of_order", 1024,
+                       EVERY_HEAD_ITS_OWN),
 }
 
 

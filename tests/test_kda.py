@@ -1,7 +1,9 @@
 """ops/kda.py: the chunked delta rule and the one-token step against the
 recurrence over time, in float32 on the CPU; each form twice: the `jnp`
 one at small heads, and the kernel (`kda_chunk`, `kda_decode`, in the
-Pallas interpreter) at heads of whole lanes."""
+Pallas interpreter) at heads of whole lanes; and the `jnp` forms again
+with ONE decay a head (``g`` of trailing size 1), where the chunk is
+matmuls only."""
 
 import functools
 
@@ -378,3 +380,154 @@ def test_the_kernel_runs_only_where_it_fits(monkeypatch):
     text = str(jax.make_jaxpr(jax.grad(functools.partial(
         loss, functools.partial(kda_chunk, interpret=True))))(wide[2]))
     assert "pallas_call" not in text and "scan" in text
+
+
+# -- one decay a head ---------------------------------------------------------
+
+#: keys of 12 by values of 24 (not square, no whole lanes) and five
+#: heads (no multiple of 8): the shape of a model whose heads are 96 by
+#: 192 and thirty
+ONE = (1, 5, 12, 24)
+
+
+def _one_decay(seed, T, dims=ONE, **kw):
+    """`_inputs` with a decay a HEAD: g (B, T, H, 1)."""
+    (q, k, v, g, beta), s0 = _inputs(seed, T, dims=dims, **kw)
+    return (q, k, v, g[..., :1], beta), s0
+
+
+@functools.lru_cache(maxsize=None)
+def _compiled(chunk, sub=16, dtype=jnp.float32):
+    """`kda_chunked` as one program: what is taken for all chunks at
+    once lies outside the scan, and would run an operation at a time."""
+    return jax.jit(functools.partial(kda_chunked, chunk=chunk, sub=sub,
+                                     dtype=dtype))
+
+
+@pytest.mark.parametrize("dims", [ONE, (B, H, DK, DV)], ids=["5x12x24",
+                                                             "3x16x8"])
+@pytest.mark.parametrize("chunk,sub,T", [(16, 16, 64), (64, 16, 83),
+                                         (32, 8, 83)])
+def test_one_decay_a_head_chunked_is_the_recurrence(dims, chunk, sub, T):
+    """The matmul form: a carried-in state, beta up to 2, a length that
+    is no multiple of the chunk; and what a decay a channel gives when
+    every channel of a head is handed the same number."""
+    xs, s0 = _one_decay(T + chunk, T, dims)
+    o, s = kda_recurrent(*xs, s0)
+    oc, sc, snap = _compiled(chunk, sub)(*xs, s0)
+    assert snap is None and oc.shape == o.shape
+    _close(oc, o)
+    _close(sc, s)
+    q, k, v, g, beta = xs
+    ok, sk, _ = _compiled(chunk, sub)(q, k, v, jnp.broadcast_to(g, k.shape),
+                                      beta, s0)
+    _close(oc, ok)
+    _close(sc, sk)
+
+
+def test_one_decay_a_head_two_calls_are_one():
+    xs, s0 = _one_decay(7, 90)
+    o, s = _compiled(16)(*xs, s0)[:2]
+    o1, s1, _ = _compiled(16)(*(a[:, :37] for a in xs), s0)
+    o2, s2, _ = _compiled(16)(*(a[:, 37:] for a in xs), s1)
+    _close(jnp.concatenate([o1, o2], axis=1), o)
+    _close(s2, s)
+
+
+@pytest.mark.parametrize("chunk,sub", [(64, 16), (32, 32)])
+def test_one_strong_decay_a_head_overflows_nothing(chunk, sub):
+    """Decays down to 1e-4 a token: a factored ``exp(-G_j)`` would be
+    inf inside one chunk (exp(295) over 32 tokens); the (C, C) mask's
+    exponents are sums of non-positive terms."""
+    xs, s0 = _one_decay(5, 128, decay=(1e-4, 0.9))
+    o, s = kda_recurrent(*xs, s0)
+    oc, sc, _ = _compiled(chunk, sub)(*xs, s0)
+    assert bool(jnp.all(jnp.isfinite(oc))) and bool(
+        jnp.all(jnp.isfinite(sc)))
+    _close(oc, o)
+    _close(sc, s)
+
+
+@pytest.mark.parametrize("pads", [13, 35])
+def test_one_decay_a_head_a_pad_is_an_identity_step(pads):
+    """13 pads inside the first chunk, 35 the first two chunks whole
+    and more; and chunks of pads alone leave the state to the bit."""
+    (q, k, v, g, beta), s0 = _one_decay(9, 48)
+    real = jnp.arange(48) >= pads
+    g_p = jnp.where(real[None, :, None, None], g, 0.0)
+    b_p = jnp.where(real[None, :, None], beta, 0.0)
+    o, s, _ = _compiled(16)(q, k, v, g_p, b_p, s0)
+    want_o, want_s = kda_recurrent(q[:, pads:], k[:, pads:], v[:, pads:],
+                                   g[:, pads:], beta[:, pads:], s0)
+    _close(o[:, pads:], want_o)
+    _close(s, want_s)
+    idle = _compiled(16)(q, k, v, jnp.zeros_like(g), jnp.zeros_like(beta),
+                         s0)[1]
+    assert bool(jnp.all(idle == s0))
+
+
+@pytest.mark.parametrize("capture", [0, 15, 16, 40, 82])
+def test_one_decay_a_head_the_captured_state(capture):
+    """A chunk's first, a middle and its last column; the last chunk's,
+    which the length does not fill."""
+    xs, s0 = _one_decay(11, 83)
+    want = kda_recurrent(*(a[:, :capture + 1] for a in xs), s0)[1]
+    o, s, snap = _captured("jnp")(xs, s0, capture)
+    _close(snap, want)
+    _close(s, kda_recurrent(*xs, s0)[1])
+    _close(o, kda_recurrent(*xs, s0)[0])
+
+
+def test_one_decay_a_head_bf16_operands_accumulate_in_float32():
+    xs, s0 = _one_decay(8, 96, decay=(0.9, 0.999))
+    o, s = kda_recurrent(*xs, s0)
+    oc, sc, _ = _compiled(64, dtype=jnp.bfloat16)(*xs, s0)
+    assert oc.dtype == sc.dtype == jnp.float32
+    _close(oc, o, tol=3e-2)
+    _close(sc, s, tol=3e-2)
+
+
+def test_a_step_with_one_decay_a_head_is_the_recurrence():
+    (q, k, v, g, beta), s0 = _one_decay(4, 1, (2, 5, 12, 24))
+    o, s = kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], s0)
+    a = float(np.exp(g[0, 0, 0, 0])) * np.asarray(s0[0, 0], np.float64)
+    kk, vv = (np.asarray(x[0, 0, 0], np.float64) for x in (k, v))
+    new = a + float(beta[0, 0, 0]) * np.outer(kk, vv - a.T @ kk)
+    np.testing.assert_allclose(s[0, 0], new, rtol=1e-5, atol=1e-5)
+    stack = jnp.stack([s0, 2 * s0])
+    o1, after = kda_decode(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
+                           stack, 1)
+    _close(after[1], kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                              beta[:, 0], 2 * s0)[1])
+    assert bool(jnp.all(after[0] == s0))
+
+
+def test_a_decay_a_channel_never_enters_the_matmul_form(monkeypatch):
+    """KDA's results are today's to the bit because its path is
+    today's: a ``g`` of trailing size ``dk`` walks `_chunk`, only one of
+    trailing size 1 the new form; and neither kernel takes one decay a
+    head, on the chip or interpreted, whatever the heads' sizes.  (All
+    traced, nothing run.)"""
+    entered = []
+    real = kda._scalar_gate_chunked
+    monkeypatch.setattr(kda, "_scalar_gate_chunked", lambda *a: (
+        entered.append(a[3].shape) or real(*a)))
+
+    def traced(form, xs, s0):
+        return jax.eval_shape(functools.partial(
+            form, chunk=16, dtype=jnp.float32), *xs, s0)
+
+    traced(kda_chunked, *_inputs(2, 24))
+    assert not entered
+    traced(kda_chunked, *_one_decay(2, 24))
+    assert entered == [(1, 24, 5, 1)]
+    wide, s1 = _one_decay(2, 24, (1, 8, 128, 128))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(kda, "kda_chunk", None)     # would be called
+    traced(kda_prefill, wide, s1)
+    assert entered[-1] == (1, 24, 8, 1)
+    text = str(jax.make_jaxpr(lambda stack: kda_decode(
+        *(a[:, 0] for a in wide), stack, 0))(s1[None]))
+    assert "pallas_call" not in text
+    with pytest.raises(ValueError, match="do not fit the kernel"):
+        kda_chunk(*wide, s1, chunk=16, interpret=True)
