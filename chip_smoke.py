@@ -48,7 +48,12 @@ Phases, one chip:
            scan and, at 1,024, against the recurrence.  A decode
            wave's: 64 rows, idle ones among them, on the middle layer of
            a stack of three, against `kda_step` on that layer.  Prints
-           both forms' milliseconds, and the wave's GB/s.
+           both forms' milliseconds, and the wave's GB/s.  And the
+           Olmo-Hybrid cell's prefill kernel at its shape (30 heads of
+           96 x 192, ONE decay a head, 1,024 and 6,144 columns, bf16
+           operands) against the jnp matmul form, run unedited as the
+           oracle: the largest relative error, both forms'
+           milliseconds a layer and compiled temporaries.
   ring     the window layers' decode kernel at the Phi-4-mini-flash and
            Laguna-XS.2 cells' shapes (eight stacked rings of 64 rows x
            512 x 1,280 lanes under 10 pair-heads; three of 1,024 lanes
@@ -173,6 +178,12 @@ class Size:
     kda_heads: tuple = (64, 128)
     kda_prefills: tuple = ((1024, 37), (8192, 700))
     kda_wave: tuple = (64, 3)
+    #: Olmo-Hybrid-7B's published widths: (heads, keys, values) of a
+    #: Gated DeltaNet layer, ONE decay a head, and (columns, pads at the
+    #: left) of a prefill's delta rule: the cell's smallest bucket and
+    #: its largest
+    delta_heads: tuple = (30, 96, 192)
+    delta_prefills: tuple = ((1024, 37), (6144, 37))
     # ring: (window layers, rows, window, query heads, K/V heads, head
     #: size, the scores' factor) of a decode wave over the stacked
     #: rings: Phi-4-mini-flash's eight window layers at its pair-heads
@@ -1250,11 +1261,15 @@ def phase_gqa(size: Size, platform: str = "tpu") -> Dict[str, Any]:
 
 
 def check_kda_kernels(size: Size, *, interpret: bool = False) -> None:
-    """ops/kda.py: a prefill's delta rule as one kernel (`kda_chunk`)
-    against the `jnp` chunk form it replaces on the chip, both with the
-    serving dtype's operands, and at the first length against the
-    recurrence over time in float32: a row with pads at its left (beta
-    = 0, g = 0), a state handed in, beta up to 2, a captured column.
+    """ops/kda.py: a prefill's delta rule as one kernel (`kda_chunk`:
+    the call ``kda_chunk`` with a decay a channel at Solar-Open2's
+    heads, the call ``delta_chunk`` with ONE decay a head at
+    Olmo-Hybrid's) against the `jnp` chunk form it replaces on the
+    chip, both with the serving dtype's operands and compiled as one
+    program each, whose temporaries are printed, and at the smallest
+    bucket against the recurrence over time in float32: a row with pads
+    at its left (beta = 0, g = 0), a state handed in, beta up to 2, a
+    captured column.
     Then a decode wave's (`kda_decode`) on the middle layer of the
     stacked state, donated, against its `jnp` form (`kda_step` on that
     layer indexed out and set back): every fifth row idle, the other
@@ -1266,8 +1281,7 @@ def check_kda_kernels(size: Size, *, interpret: bool = False) -> None:
     from ray_tpu.ops.kda import (_step_on_layer, kda_chunk, kda_chunked,
                                  kda_decode, kda_recurrent)
 
-    H, hd = size.kda_heads
-    jnp_form = jax.jit(functools.partial(kda_chunked, dtype=jnp.bfloat16))
+    jnp_form = functools.partial(kda_chunked, dtype=jnp.bfloat16)
     kernel = functools.partial(kda_chunk, dtype=jnp.bfloat16,
                                interpret=interpret)
 
@@ -1279,37 +1293,55 @@ def check_kda_kernels(size: Size, *, interpret: bool = False) -> None:
         jax.block_until_ready(out)
         return out, (time.perf_counter() - t0) / runs * 1e3
 
-    for n, (T, pad) in enumerate(size.kda_prefills):
+    def compiled(form, *args):
+        """`form` with a captured column as one program, and the
+        temporaries it was compiled with, MB."""
+        program = jax.jit(lambda capture, *a: form(
+            *a, capture=capture)).lower(*args).compile()
+        return program, round(
+            program.memory_analysis().temp_size_in_bytes / 1e6, 1)
+
+    H, hd = size.kda_heads
+    # (heads, keys, values, a decay's trailing size, the call's name,
+    # columns, pads): KDA's a decay a channel, then ONE decay a head
+    cases = [(H, hd, hd, hd, "kda_chunk", T, pad)
+             for T, pad in size.kda_prefills] \
+        + [(*size.delta_heads, 1, "delta_chunk", T, pad)
+           for T, pad in size.delta_prefills]
+    for n, (heads, dk, dv, gate, name, T, pad) in enumerate(cases):
         ks = jax.random.split(jax.random.PRNGKey(size.seed + 7 + n), 6)
 
         def unit(key):
-            x = jax.random.normal(key, (1, T, H, hd))
+            x = jax.random.normal(key, (1, T, heads, dk))
             return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
 
         real = (jnp.arange(T) >= pad)[None, :, None]
-        # a token's decay a channel log-uniform in (0.5, 0.999)
+        # a token's decay log-uniform in (0.5, 0.999)
         g = jnp.where(real[..., None], np.log(0.5) + (
             np.log(0.999) - np.log(0.5)) * jax.random.uniform(
-                ks[3], (1, T, H, hd)), 0.0)
-        beta = jnp.where(real, 2.0 * jax.random.uniform(ks[4], (1, T, H)),
-                         0.0)
-        args = (unit(ks[0]) * hd ** -0.5, unit(ks[1]),
-                jax.random.normal(ks[2], (1, T, H, hd)), g, beta,
-                jax.random.normal(ks[5], (1, H, hd, hd)))
-        capture = jnp.int32(pad + (T - pad) // 2)
-        got, ms = timed(lambda *a: kernel(*a, capture=capture), *args)
-        want, ms_jnp = timed(lambda *a: jnp_form(*a, capture=capture), *args)
+                ks[3], (1, T, heads, gate)), 0.0)
+        beta = jnp.where(real, 2.0 * jax.random.uniform(
+            ks[4], (1, T, heads)), 0.0)
+        args = (jnp.int32(pad + (T - pad) // 2),
+                unit(ks[0]) * dk ** -0.5, unit(ks[1]),
+                jax.random.normal(ks[2], (1, T, heads, dv)), g, beta,
+                jax.random.normal(ks[5], (1, heads, dk, dv)))
+        kernel_program, temp = compiled(kernel, *args)
+        jnp_program, temp_jnp = compiled(jnp_form, *args)
+        got, ms = timed(kernel_program, *args)
+        want, ms_jnp = timed(jnp_program, *args)
         errs = {"o": _rel_err(got[0][:, pad:], want[0][:, pad:]),
                 "state": _rel_err(got[1], want[1]),
                 "snapshot": _rel_err(got[2], want[2])}
-        if n == 0:
-            o, state = jax.jit(kda_recurrent)(*args)
+        if T <= 1024:       # the smallest bucket, held to the recurrence
+            o, state = jax.jit(kda_recurrent)(*args[1:])
             errs["o_recurrence"] = _rel_err(got[0][:, pad:], o[:, pad:])
             errs["state_recurrence"] = _rel_err(got[1], state)
-        say("kda", kernel="kda_chunk", shape=[T, pad, H, hd],
-            ms=round(ms, 3), ms_jnp=round(ms_jnp, 3),
+        say("kda", kernel=name, shape=[T, pad, heads, dk, dv],
+            ms=round(ms, 3), ms_jnp=round(ms_jnp, 3), temp_mb=temp,
+            temp_mb_jnp=temp_jnp,
             **{k: round(v, 6) for k, v in errs.items()})
-        assert max(errs.values()) <= KERNEL_TOL, ("kda_chunk", T, errs)
+        assert max(errs.values()) <= KERNEL_TOL, (name, T, errs)
 
     B, layers = size.kda_wave
     j = layers // 2

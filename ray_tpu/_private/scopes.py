@@ -137,12 +137,16 @@ RING_DECODE = "ring_decode"
 #: attention layer: the band is data, a K/V head a lane slice of the
 #: folded rows where they lie (ops/banded_flash.py)
 BANDED_FLASH = "banded_flash"
+#: a prefill's chunked delta rule with ONE decay a head, one call a
+#: Gated DeltaNet layer: `kda_chunk`'s grid and solve, one (C, C) mask a
+#: head where it has pairwise decays a channel (ops/kda.py)
+DELTA_CHUNK = "delta_chunk"
 KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV,
            FLASH_RES_FWD, FLASH_RES_DQ, FLASH_RES_DKV,
            FLASH_TRI_FWD, FLASH_TRI_BWD, SSM_SCAN, MLA_PAGED_DECODE,
            MLA_ROTARY_LANES, MLA_FLASH_PREFILL, MOE_DISPATCH,
            MOE_COMBINE, GQA_PAGED_DECODE, GROUPED_SWIGLU, KDA_CHUNK,
-           KDA_DECODE, RING_DECODE, BANDED_FLASH)
+           KDA_DECODE, RING_DECODE, BANDED_FLASH, DELTA_CHUNK)
 
 # -- host phases -------------------------------------------------------------
 SPAN_PREFIX = "raytpu."

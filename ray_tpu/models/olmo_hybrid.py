@@ -372,8 +372,10 @@ def deltanet_mix(p, h, cfg: OlmoHybridConfig, window, state, real=None,
     the columns that hold a token (pads first), and a pad moves neither
     window nor state.  capture: a traced column index (rows all alike)
     after which window and state are also handed back, for a snapshot.
-    One column goes through `kda_decode`, more through `kda_prefill`:
-    with one decay a head both are their `jnp` forms, on the chip too.
+    One column goes through `kda_decode` (its `jnp` step: one decay a
+    head fits no step kernel), more through `kda_prefill` (on the chip
+    the kernel for one decay a head, ``delta_chunk``; the `jnp` matmul
+    form elsewhere).
 
     Returns (out (B, T, d), (window, state as it came: a layer's or the
     stack), (window, state) after `capture` or None)."""

@@ -62,8 +62,9 @@ an eigenvalue of -1).  Five forms of it, three of them plain
     Y) S_0`` with ``K-_i = k_i exp(G_C - G_i)``: everything but the two
     products with ``S_0`` is taken for all chunks of a prefill at once,
     in batched products, and only ``S_0 -> S_C`` and the state's part
-    of ``O`` run in the `lax.scan`.  Neither kernel below takes a
-    scalar gate: those shapes keep the ``jnp`` forms, on the chip too.
+    of ``O`` run in the `lax.scan`.  The step kernel below takes no
+    scalar gate (a decode wave keeps `kda_step`); a prefill on the chip
+    has a kernel of its own (`kda_chunk`, its second body).
 
 Every exponent above is <= 0, and the code keeps it so.  ``exp(-G_j)``
 is never formed alone: the factored ``(k_i e^{G_i}) . (k_j e^{-G_j})``
@@ -98,12 +99,29 @@ are walked in a `lax.scan` (they depend on each other through ``S_0``).
     ones above in two float32 products.  `capture`: the kernel hands
     back the state at the START of the chunk that holds the column and
     `_chunk` does that one chunk again in ``jnp``.
-  * `kda_prefill` is what a model calls: `kda_chunk` on the chip where
-    the shapes fit it (heads of whole lanes, more than one column, a
-    chunk of at most 128 columns in sub-chunks of whole sublane
-    tiles), `kda_chunked` everywhere else: the CPU, heads of 16.  A
-    differentiated `kda_chunk` is `kda_chunked` forward and backward
-    (its ``custom_vjp``).  `kda_chunked` is the parity oracle.
+    With ONE decay a head (``g`` of trailing size 1) the same grid,
+    solve and capture protocol run another body, a call named
+    ``delta_chunk`` (`_head_gate_kernel`): ``G`` is a number a token,
+    so ONE (C, C) mask ``exp(G_i - G_j)`` a head (every exponent <= 0)
+    turns the one product ``[K; Q] K^T`` into ``A`` and ``B``; there is
+    no pairwise decay a channel, no sub-chunk walk and no factoring
+    around ``R``; the state enters the solve's right-hand side
+    (`_chunk`'s algebra, width ``dv``) and stays in VMEM across the
+    head's chunks.  Heads that are no whole lanes (keys of 96, values
+    of 192) are filled with zeros to whole lanes on the way in, which
+    is exact, and cut back on the way out; the state comes and goes
+    (B, H, dk, dv).  `capture`: as above, the one chunk done again by
+    `_scalar_gate_chunked`.
+  * `kda_prefill` is what a model calls.  A prefill takes one of THREE
+    forms, picked from the backend and the shapes, never from a model's
+    name: on the chip, more than one column, a chunk of at most 128
+    columns in sub-chunks of whole sublane tiles, and then by ``g``:
+    trailing size ``dk`` over heads of whole lanes is `kda_chunk`'s
+    first body; trailing size 1 over heads that fill at least half of
+    the lanes they are padded to is its second; everything else (the
+    CPU, heads of 16, one column) is `kda_chunked`.  A differentiated
+    `kda_chunk` is `kda_chunked` forward and backward (its
+    ``custom_vjp``).  `kda_chunked` is the parity oracle.
 
 A pad position is an identity step: ``beta = 0`` and ``g = 0`` (its
 ``k``, ``q`` and ``v`` then move nothing), which is how both chunk
@@ -142,6 +160,16 @@ _SUBLANES, _LANES = 8, 128
 #: chunks a step alike
 _CHUNKS_A_STEP = 2
 _HEADS_A_STEP = 8
+#: heads a grid step of the kernel for ONE decay a head
+#: (`_head_gate_kernel`), and the VMEM it may take: a head's blocks and
+#: state are 1.7 MB at keys of 128 and values of 256 lanes, so fifteen
+#: pass the 16 MiB a kernel is given unasked.  What the chip said (my
+#: chip run, PR 57; 30 heads of 96 x 192, 6,144 columns, one layer with
+#: its fills, re-lays and captured chunk; the `jnp` form 11.93 ms): 5
+#: heads 5.13 ms, 6 heads 5.06, 10 heads 4.92, 15 heads 4.83; 1, 2 or
+#: 4 chunks a step alike
+_HEADS_A_GATE_STEP = 15
+_HEAD_GATE_VMEM = 64 * 2 ** 20
 #: heads a grid step of `kda_decode`: a sublane tile of the folded
 #: operands; their 72 part rows fit one transposed tile (`_step_kernel`)
 _HEADS_A_WAVE_STEP = 8
@@ -413,6 +441,62 @@ def kda_chunked(q, k, v, g, beta, state=None, *, chunk: int = 64,
 # the kernel
 # ---------------------------------------------------------------------------
 
+_NT = (((2,), (2,)), ((0,), (0,)))                    # a[h] @ b[h].T
+
+
+def _kernel_words(heads: int, dtype):
+    """What both kernel bodies say a chunk's operations with, the
+    group's `heads` heads on a leading axis: (mm, exact, by_head,
+    across, rows_of)."""
+    hi = lax.Precision.HIGHEST
+    prec = hi if dtype == _F32 else None
+    plain = (((2,), (1,)), ((0,), (0,)))                 # a[h] @ b[h]
+
+    def mm(a, b, dims=plain):
+        return lax.dot_general(a.astype(dtype), b.astype(dtype), dims,
+                               precision=prec, preferred_element_type=_F32)
+
+    def exact(a, b):
+        return lax.dot_general(a, b, plain, precision=hi,
+                               preferred_element_type=_F32)
+
+    def by_head(folded, d):     # (C, heads x d) -> (heads, C, d)
+        return jnp.stack([folded[:, h * d:(h + 1) * d]
+                          for h in range(heads)])
+
+    def across(a, width):       # (.., 128) alike along the lanes
+        return a if width == _LANES else jnp.concatenate(
+            [a] * (width // _LANES), axis=-1)
+
+    def rows_of(parts):         # along a chunk's rows
+        return jnp.concatenate(parts, axis=1)
+
+    return mm, exact, by_head, across, rows_of
+
+
+def _solve_in_kernel(rhs, low_off, inv, wide, sub: int, exact, rows_of):
+    """`_solve_unit_lower`'s second half on the chip: a sub-chunk's rows
+    from the ones above in two float32 products.  rhs (heads, C, dv);
+    low_off[s] (heads, sub, C): sub-chunk ``s``'s rows of the strictly
+    lower matrix, zero from its own columns on (None for the first);
+    inv (heads, sub, 128): the diagonal blocks' inverses, lanes (s,
+    column), zero from C on; wide: the lane of a (sub, 128) tile."""
+    heads, C, dv = rhs.shape
+    n = C // sub
+    out = []
+    for s in range(n):
+        r = rhs[:, s * sub:(s + 1) * sub]
+        if s:
+            r = r - exact(low_off[s], rows_of(
+                out + [jnp.zeros((heads, C - s * sub, dv), _F32)]))
+        alone = [jnp.zeros((heads, sub, dv), _F32)] * n \
+            + [jnp.zeros((heads, _LANES - C, dv), _F32)] * (C < _LANES)
+        alone[s] = r
+        out.append(exact(jnp.where(wide // sub == s, inv, 0.0),
+                         rows_of(alone)))
+    return rows_of(out)                                    # (heads, C, dv)
+
+
 def _kernel(cap_ref, q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref,
             o_ref, st_ref, *rest, chunk: int, sub: int, dk: int, dv: int,
             heads: int, chunks: int, dtype, snapshot: bool):
@@ -439,29 +523,8 @@ def _kernel(cap_ref, q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref,
         s_scr, k_scr, g_scr, in_scr = rest
     group, t = pl.program_id(1), pl.program_id(2)
     C, n, per = chunk, chunk // sub, sub // _SUBLANES
-    hi = lax.Precision.HIGHEST
-    prec = hi if dtype == _F32 else None
-    plain = (((2,), (1,)), ((0,), (0,)))                 # a[h] @ b[h]
-    nt = (((2,), (2,)), ((0,), (0,)))                    # a[h] @ b[h].T
-
-    def mm(a, b, dims=plain):
-        return lax.dot_general(a.astype(dtype), b.astype(dtype), dims,
-                               precision=prec, preferred_element_type=_F32)
-
-    def exact(a, b):
-        return lax.dot_general(a, b, plain, precision=hi,
-                               preferred_element_type=_F32)
-
-    def by_head(folded, d):     # (C, heads x d) -> (heads, C, d)
-        return jnp.stack([folded[:, h * d:(h + 1) * d]
-                          for h in range(heads)])
-
-    def across(a, width):       # (.., 128) alike along the lanes
-        return a if width == _LANES else jnp.concatenate(
-            [a] * (width // _LANES), axis=-1)
-
-    def rows_of(parts):         # along a chunk's rows
-        return jnp.concatenate(parts, axis=1)
+    hi, nt = lax.Precision.HIGHEST, _NT
+    mm, exact, by_head, across, rows_of = _kernel_words(heads, dtype)
 
     @pl.when(t == 0)
     def _first_chunks():
@@ -576,18 +639,7 @@ def _kernel(cap_ref, q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref,
         state = s_scr[...]                                # (heads, dv, dk)
         from_state = mm(rows_of([k * whole, q * whole]), state, nt)
         rhs = across(beta, dv) * (v - from_state[:, :C])
-        out = []
-        for s in range(n):
-            r = rhs[:, s * sub:(s + 1) * sub]
-            if s:
-                r = r - exact(low_off[s], rows_of(
-                    out + [jnp.zeros((heads, C - s * sub, dv), _F32)]))
-            alone = [jnp.zeros((heads, sub, dv), _F32)] * n \
-                + [jnp.zeros((heads, _LANES - C, dv), _F32)] * (C < _LANES)
-            alone[s] = r
-            out.append(exact(jnp.where(wide // sub == s, inv, 0.0),
-                             rows_of(alone)))
-        U = rows_of(out)                                   # (heads, C, dv)
+        U = _solve_in_kernel(rhs, low_off, inv, wide, sub, exact, rows_of)
 
         Bm = rows_of([
             placed([(s * sub + b, sums_b[s, b, part]) for b in range(sub)
@@ -612,25 +664,33 @@ def _kernel(cap_ref, q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref,
 
 
 def _call(q, k, v, g, beta, state, cap_chunk, *, chunk: int, chunks: int,
-          sub: int, dtype, interpret: bool):
-    """The kernel on folded operands: q, k, g (B, T, H x dk), v (B, T,
-    H x dv), beta (B, T, H), T a multiple of `chunks` chunks; state (B,
-    H, dk, dv) float32; cap_chunk: a traced chunk index or None.
-    Returns (o (B, T, H x dv), the state after T, the state BEFORE chunk
-    `cap_chunk` or None)."""
+          sub: int, dtype, interpret: bool, head_gate: bool = False):
+    """A kernel on folded operands: q, k (B, T, H x dk), v (B, T, H x
+    dv), T a multiple of `chunks` chunks; state (B, H, dk, dv) float32;
+    cap_chunk: a traced chunk index or None.  A decay a channel
+    (`_kernel`): g (B, T, H x dk), beta (B, T, H).  With `head_gate`,
+    ONE decay a head (`_head_gate_kernel`): g and beta (B, T, H'), every
+    head's, H' the heads filled to whole lanes.  Returns (o (B, T, H x
+    dv), the state after T, the state BEFORE chunk `cap_chunk` or
+    None)."""
     from jax.experimental.pallas import tpu as pltpu
 
-    B, T, H = beta.shape
-    dk, dv = k.shape[-1] // H, v.shape[-1] // H
+    B, T = beta.shape[:2]
+    _, H, dk, dv = state.shape
     step = chunks * chunk
-    heads = next(n for n in range(min(_HEADS_A_STEP, H), 0, -1)
-                 if H % n == 0)
+    kernel, name, most, vmem = (
+        (_head_gate_kernel, scopes.DELTA_CHUNK, _HEADS_A_GATE_STEP,
+         _HEAD_GATE_VMEM) if head_gate
+        else (_kernel, scopes.KDA_CHUNK, _HEADS_A_STEP, None))
+    heads = next(n for n in range(min(most, H), 0, -1) if H % n == 0)
     snapshot = cap_chunk is not None
 
     def time_block(d):
         return pl.BlockSpec((1, step, heads * d),
                             lambda b, h, t, cap: (b, t, h))
 
+    every_head = pl.BlockSpec((1, step, beta.shape[-1]),
+                              lambda b, h, t, cap: (b, t, 0))
     state_block = pl.BlockSpec((1, heads, dk, dv),
                                lambda b, h, t, cap: (b, h, 0, 0))
     states = jax.ShapeDtypeStruct((B, H, dk, dv), _F32)
@@ -642,27 +702,149 @@ def _call(q, k, v, g, beta, state, cap_chunk, *, chunk: int, chunks: int,
     cap = jnp.reshape(jnp.asarray(cap_chunk if snapshot else -1, jnp.int32),
                       (1,))
     outs = pl.pallas_call(
-        functools.partial(_kernel, chunk=chunk, sub=sub, dk=dk, dv=dv,
+        functools.partial(kernel, chunk=chunk, sub=sub, dk=dk, dv=dv,
                           heads=heads, chunks=chunks, dtype=dtype,
                           snapshot=snapshot),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B, H // heads, T // step),
             in_specs=[time_block(dk), time_block(dk), time_block(dv),
-                      time_block(dk),
-                      pl.BlockSpec((1, step, H),
-                                   lambda b, h, t, cap: (b, t, 0)),
-                      state_block],
+                      every_head if head_gate else time_block(dk),
+                      every_head, state_block],
             out_specs=out_specs,
             scratch_shapes=[pltpu.VMEM((heads, dv, dk), _F32)]
-            + [pltpu.VMEM((heads, chunk, dk), _F32)] * 3),
+            + [pltpu.VMEM((heads, chunk, dk), _F32)] * (0 if head_gate
+                                                        else 3)),
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem),
         interpret=interpret,
-        name=scopes.KDA_CHUNK,
+        name=name,
     )(cap, q, k, v, g, beta, state)
     return outs[0], outs[1], (outs[2] if snapshot else None)
+
+
+def _head_gate_kernel(cap_ref, q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref,
+                      o_ref, st_ref, *rest, chunk: int, sub: int, dk: int,
+                      dv: int, heads: int, chunks: int, dtype,
+                      snapshot: bool):
+    """`_kernel`'s grid step for ONE decay a head: q, k (1, chunks x
+    chunk, heads x dk) and v, o (.., heads x dv), heads padded to whole
+    lanes; g and b (1, chunks x chunk, H'), every head's log-decay and
+    beta a token, H' the heads padded to whole lanes; s0, st, snap (1,
+    heads, dk, dv).  Scratch: the heads' states held transposed (heads,
+    dv, dk), as `_kernel` holds them.
+
+    `_chunk`'s algebra on a chunk with `_scalar_gate_chunked`'s ``A``
+    and ``B``: ``G`` is a number a token, so it is spread along a head's
+    lanes once (a product with 0/1), its transpose gives ``G_j`` along
+    the lanes to the bit, and ONE (C, C) mask ``exp(G_i - G_j)`` a head
+    (every exponent <= 0) turns ``[K; Q] K^T``, one product, into ``A``
+    and ``B``: no pairwise decay a channel, no sub-chunk walk, no
+    factoring around ``R``.  The solve, the state's part and the
+    state's update are `_kernel`'s; the group's heads go side by side
+    on a leading axis for the same reason."""
+    s_scr, = rest[-1:]
+    snap_ref = rest[0] if snapshot else None
+    group, t = pl.program_id(1), pl.program_id(2)
+    C, n = chunk, chunk // sub
+    hi, nt = lax.Precision.HIGHEST, _NT
+    mm, exact, by_head, across, rows_of = _kernel_words(heads, dtype)
+
+    def to_a_tile(a):           # (.., C, d) -> (.., 128, d), zeros below
+        return a if C == _LANES else jnp.concatenate(
+            [a, jnp.zeros((*a.shape[:-2], _LANES - C, a.shape[-1]), _F32)],
+            axis=-2)
+
+    @pl.when(t == 0)
+    def _first_chunks():
+        for h in range(heads):
+            s_scr[h] = s0_ref[0, h].T
+            if snapshot:
+                snap_ref[0, h] = s0_ref[0, h]
+
+    # G as a product with ones below the diagonal (float32, highest
+    # precision), and a head's column of it and of beta along that
+    # head's lanes
+    ones_below = (lax.broadcasted_iota(jnp.int32, (C, C), 1)
+                  <= lax.broadcasted_iota(jnp.int32, (C, C), 0)
+                  ).astype(_F32)
+    padded = b_ref.shape[-1]
+    to_lanes = (lax.broadcasted_iota(jnp.int32, (padded, heads * _LANES), 0)
+                == group * heads + lax.broadcasted_iota(
+                    jnp.int32, (padded, heads * _LANES), 1) // _LANES
+                ).astype(_F32)
+    row = lax.broadcasted_iota(jnp.int32, (C, _LANES), 0)
+    col = lax.broadcasted_iota(jnp.int32, (C, _LANES), 1)
+    wide = lax.broadcasted_iota(jnp.int32, (sub, _LANES), 1)
+    deep = lax.broadcasted_iota(jnp.int32, (sub, _LANES), 0)
+    eye = ((wide % sub == deep) & (wide < C)).astype(_F32)
+
+    def one_chunk(c, carry):
+        if snapshot:
+            @pl.when(t * chunks + c == cap_ref[0])
+            def _the_chunk_of_capture():
+                for h in range(heads):
+                    snap_ref[0, h] = s_scr[h].T
+
+        rows = pl.ds(pl.multiple_of(c * C, C), C)
+        q, k = by_head(q_ref[0, rows, :], dk), by_head(k_ref[0, rows, :], dk)
+        v = by_head(v_ref[0, rows, :], dv)
+        spread = jnp.dot(jnp.concatenate([
+            jnp.dot(ones_below, g_ref[0, rows, :], precision=hi,
+                    preferred_element_type=_F32),
+            b_ref[0, rows, :]], axis=0), to_lanes, precision=hi,
+            preferred_element_type=_F32)
+        G, beta = by_head(spread[:C], _LANES), by_head(spread[C:], _LANES)
+        # G_j along the lanes: the same numbers, transposed
+        G_j = jnp.stack([to_a_tile(G[h]).T[:C] for h in range(heads)])
+        decay = jnp.exp(jnp.where(col <= row, G - G_j, -jnp.inf))
+        both = mm(rows_of([k, q]), to_a_tile(k), nt)   # (heads, 2C, 128)
+        low = beta * jnp.where(col < row, both[:, :C] * decay, 0.0)
+        Bm = both[:, C:] * decay
+        # lanes from C on are zero in `low`, `Bm` and `inv`
+
+        # (I + diag)^-1 of every sub-chunk at once, lanes (s, column),
+        # as `_kernel` takes it: column j of a block along that block's
+        # lanes is a masked sum along the lanes
+        diag = jnp.zeros((heads, sub, _LANES), _F32)
+        for s in range(n):
+            diag = jnp.where(wide // sub == s,
+                             low[:, s * sub:(s + 1) * sub], diag)
+        inv = eye
+        for j in range(sub - 1):
+            column = jnp.zeros((heads, sub, _LANES), _F32)
+            for s in range(n):
+                column = jnp.where(wide // sub == s, jnp.sum(
+                    jnp.where(wide == s * sub + j, diag, 0.0), axis=-1,
+                    keepdims=True), column)
+            inv = inv - column * inv[..., j:j + 1, :]
+
+        state = s_scr[...]                                # (heads, dv, dk)
+        whole = jnp.exp(G)
+        from_state = mm(rows_of([k * whole, q * whole]), state, nt)
+        rhs = across(beta, dv) * (v - from_state[:, :C])
+        U = _solve_in_kernel(rhs, [None] + [
+            jnp.where(wide < s * sub, low[:, s * sub:(s + 1) * sub],
+                      0.0)[..., :C] for s in range(1, n)],
+            inv, wide, sub, exact, rows_of)
+
+        o = from_state[:, C:] + mm(Bm, to_a_tile(U))
+        for h in range(heads):
+            o_ref[0, rows, h * dv:(h + 1) * dv] = o[h]
+        last = G[:, C - 1:C]                                      # G_C
+        s_scr[...] = state * jnp.exp(last) + mm(
+            jnp.stack([U[h].T for h in range(heads)]),
+            k * jnp.exp(last - G))
+        return carry
+
+    lax.fori_loop(0, chunks, one_chunk, 0)
+
+    @pl.when(t == pl.num_programs(2) - 1)
+    def _last_chunks():
+        for h in range(heads):
+            st_ref[0, h] = s_scr[h].T
 
 
 def _fits_the_kernel(k, v, g, chunk: int, sub: int) -> bool:
@@ -673,6 +855,23 @@ def _fits_the_kernel(k, v, g, chunk: int, sub: int) -> bool:
             and k.shape[1] > 1 and k.shape[-1] % _LANES == 0
             and v.shape[-1] % _LANES == 0 and sub % _SUBLANES == 0
             and chunk % sub == 0 and chunk <= _LANES)
+
+
+def _to_lanes(d: int) -> int:
+    """`d` filled to whole lanes."""
+    return -(-d // _LANES) * _LANES
+
+
+def _fits_the_head_gate_kernel(k, v, g, chunk: int, sub: int) -> bool:
+    """ONE decay a head, more than one column, sub-chunks of whole
+    sublane tiles, a chunk whose columns fit a tile's lanes, and heads
+    that fill at least half of the whole lanes they are padded to
+    (keys of 96 in 128, values of 192 in 256; heads of 16 do not)."""
+    dk, dv = k.shape[-1], v.shape[-1]
+    return (g.shape[-1] == 1 < dk and k.shape[1] > 1
+            and 2 * dk >= _to_lanes(dk) and 2 * dv >= _to_lanes(dv)
+            and sub % _SUBLANES == 0 and chunk % sub == 0
+            and chunk <= _LANES)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10))
@@ -725,41 +924,102 @@ def _jnp_form_bwd(chunk, sub, dtype, interpret, res, cts):
 _kernel_form.defvjp(_jnp_form_fwd, _jnp_form_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10))
+def _head_gate_form(q, k, v, g, beta, state, capture, chunk, sub, dtype,
+                    interpret):
+    """`_kernel_form` for ONE decay a head, g (B, T, H, 1): the heads
+    are filled to whole lanes on the way in (zeros: a filled channel of
+    k leaves its row of the state zero, one of q reads nothing, one of
+    v writes zeros) and cut back on the way out; the state comes and
+    goes (B, H, dk, dv) as the cache stores it."""
+    B, T, H, dk = k.shape
+    dv = v.shape[-1]
+    chunks = min(_CHUNKS_A_STEP, -(-T // chunk))
+    fill = -T % (chunks * chunk)
+    q, k, v, g, beta = (jnp.pad(     # identity steps at the end
+        a.astype(_F32), ((0, 0), (0, fill)) + ((0, 0),) * (a.ndim - 2))
+        for a in (q, k, v, g, beta))
+    at = None if capture is None else capture // chunk
+
+    def lanes(a, axis=-1):      # zeros up to whole lanes along `axis`
+        room = [(0, 0)] * a.ndim
+        room[axis] = (0, _to_lanes(a.shape[axis]) - a.shape[axis])
+        return jnp.pad(a, room)
+
+    with jax.named_scope(scopes.ATTN_LINEAR):
+        o, after, before = _call(
+            *(lanes(a).reshape(B, T + fill, -1) for a in (q, k, v)),
+            lanes(g[..., 0]), lanes(beta), lanes(lanes(state), -2), at,
+            chunk=chunk, chunks=chunks, sub=sub, dtype=dtype,
+            interpret=interpret, head_gate=True)
+        o = o.reshape(B, T + fill, H, -1)[:, :T, :, :dv]
+        after = after[:, :, :dk, :dv]
+        if capture is None:
+            return o, after, None
+        # the one chunk that holds `capture`, again, in the `jnp` form's
+        # own words, from the state the kernel handed back at its start
+        one = (lax.dynamic_slice_in_dim(a, at * chunk, chunk, axis=1)
+               for a in (q, k, v, g, beta))
+        snap = _scalar_gate_chunked(
+            *one, before[:, :, :dk, :dv], chunk, sub, dtype,
+            capture - at * chunk)[2]
+    return o, after, snap
+
+
+_head_gate_form.defvjp(_jnp_form_fwd, _jnp_form_bwd)
+
+
+def _kernel_form_for(k, v, g, chunk: int, sub: int):
+    """The kernel form whose body takes these shapes, by ``g``'s
+    trailing size, or None."""
+    if _fits_the_kernel(k, v, g, chunk, sub):
+        return _kernel_form
+    if _fits_the_head_gate_kernel(k, v, g, chunk, sub):
+        return _head_gate_form
+    return None
+
+
 @functools.partial(jax.jit, static_argnames=("chunk", "sub", "dtype",
                                              "interpret"))
 def kda_chunk(q, k, v, g, beta, state=None, *, chunk: int = 64,
               sub: int = 16, dtype=jnp.bfloat16, capture=None,
               interpret: bool = False
               ) -> Tuple[jnp.ndarray, jnp.ndarray, Optional[jnp.ndarray]]:
-    """`kda_chunked`'s contract as one Pallas call named ``kda_chunk``
-    (module docstring).  Head sizes are whole lanes (multiples of 128),
-    `sub` whole sublane tiles (a multiple of 8) and `chunk` a multiple
-    of `sub`, at most 128.  ``interpret=True`` runs the kernel in
-    the Pallas interpreter (the CPU tests).  Jitted: the KDA layers of
-    one program share one trace and one lowering of the kernel.
-    Differentiated, it is `kda_chunked`, forward and backward."""
+    """`kda_chunked`'s contract as one Pallas call (module docstring),
+    by ``g``'s trailing size: a decay a channel is the call named
+    ``kda_chunk`` (head sizes whole lanes, multiples of 128), ONE decay
+    a head the call named ``delta_chunk`` (heads that fill at least
+    half of the lanes they are padded to).  `sub` whole sublane tiles
+    (a multiple of 8) and `chunk` a multiple of `sub`, at most 128.
+    ``interpret=True`` runs the kernel in the Pallas interpreter (the
+    CPU tests).  Jitted: the layers of one program share one trace and
+    one lowering of the kernel.  Differentiated, it is `kda_chunked`,
+    forward and backward."""
     sub = min(sub, chunk)
-    if not _fits_the_kernel(k, v, g, chunk, sub):
+    form = _kernel_form_for(k, v, g, chunk, sub)
+    if form is None:
         raise ValueError(
-            f"kda_chunk: heads of {k.shape[-1]} x {v.shape[-1]}, chunk "
-            f"{chunk}, sub {sub} and {k.shape[1]} columns do not fit the "
-            "kernel")
+            f"kda_chunk: heads of {k.shape[-1]} x {v.shape[-1]}, a decay "
+            f"of {g.shape[-1]}, chunk {chunk}, sub {sub} and {k.shape[1]} "
+            "columns do not fit the kernel")
     B, _, H, dk = k.shape
     if state is None:
         state = jnp.zeros((B, H, dk, v.shape[-1]), _F32)
     if capture is not None:
         capture = jnp.asarray(capture, jnp.int32)
-    return _kernel_form(q, k, v, g, beta, state.astype(_F32), capture,
-                        chunk, sub, jnp.dtype(dtype), interpret)
+    return form(q, k, v, g, beta, state.astype(_F32), capture, chunk, sub,
+                jnp.dtype(dtype), interpret)
 
 
 def kda_prefill(q, k, v, g, beta, state=None, *, chunk: int = 64,
                 sub: int = 16, dtype=jnp.bfloat16, capture=None):
     """A prefill's delta rule by the form that fits what the program
-    can see: `kda_chunk` on the chip where the shapes fit the kernel,
-    `kda_chunked` everywhere else (module docstring)."""
-    form = kda_chunk if jax.default_backend() == "tpu" and _fits_the_kernel(
-        k, v, g, chunk, min(sub, chunk)) else kda_chunked
+    can see: on the chip `kda_chunk`, in the body ``g``'s trailing size
+    names, where the shapes fit that body; `kda_chunked` everywhere
+    else (module docstring)."""
+    on_chip = jax.default_backend() == "tpu" and _kernel_form_for(
+        k, v, g, chunk, min(sub, chunk)) is not None
+    form = kda_chunk if on_chip else kda_chunked
     return form(q, k, v, g, beta, state, chunk=chunk, sub=sub, dtype=dtype,
                 capture=capture)
 

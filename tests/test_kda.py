@@ -388,6 +388,13 @@ def test_the_kernel_runs_only_where_it_fits(monkeypatch):
 #: heads (no multiple of 8): the shape of a model whose heads are 96 by
 #: 192 and thirty
 ONE = (1, 5, 12, 24)
+#: ... and those ratios at the smallest sizes the kernel for one decay
+#: a head takes: keys three quarters of a tile of lanes, values twice
+#: the keys, five heads side by side
+GATE = (1, 5, 96, 192)
+#: the two forms of one decay a head and the sizes each is run at
+ONE_FORMS = {"jnp": ONE, "kernel": GATE}
+one_forms = pytest.mark.parametrize("form", sorted(ONE_FORMS))
 
 
 def _one_decay(seed, T, dims=ONE, **kw):
@@ -397,51 +404,65 @@ def _one_decay(seed, T, dims=ONE, **kw):
 
 
 @functools.lru_cache(maxsize=None)
-def _compiled(chunk, sub=16, dtype=jnp.float32):
-    """`kda_chunked` as one program: what is taken for all chunks at
-    once lies outside the scan, and would run an operation at a time."""
+def _compiled(chunk, sub=16, dtype=jnp.float32, form="jnp"):
+    """A chunk form as one program: what `kda_chunked` takes for all
+    chunks at once lies outside the scan, and would run an operation at
+    a time; the kernel's is `kda_chunk` in the Pallas interpreter."""
+    if form == "kernel":
+        return functools.partial(kda_chunk, chunk=chunk, sub=sub,
+                                 dtype=dtype, interpret=True)
     return jax.jit(functools.partial(kda_chunked, chunk=chunk, sub=sub,
                                      dtype=dtype))
 
 
-@pytest.mark.parametrize("dims", [ONE, (B, H, DK, DV)], ids=["5x12x24",
-                                                             "3x16x8"])
-@pytest.mark.parametrize("chunk,sub,T", [(16, 16, 64), (64, 16, 83),
-                                         (32, 8, 83)])
-def test_one_decay_a_head_chunked_is_the_recurrence(dims, chunk, sub, T):
+@pytest.mark.parametrize("form,dims,chunk,sub,T", [
+    ("jnp", dims, *c) for dims in (ONE, (B, H, DK, DV))
+    for c in ((16, 16, 64), (64, 16, 83), (32, 8, 83))] + [
+    # one chunk and a tail; chunks that fill grid steps and one more;
+    # sub-chunks of one tile
+    ("kernel", GATE, 64, 16, 83), ("kernel", GATE, 32, 16, 160),
+    ("kernel", GATE, 16, 8, 50)],
+    ids=lambda p: "x".join(map(str, p)) if isinstance(p, tuple) else None)
+def test_one_decay_a_head_chunked_is_the_recurrence(form, dims, chunk, sub,
+                                                    T):
     """The matmul form: a carried-in state, beta up to 2, a length that
     is no multiple of the chunk; and what a decay a channel gives when
-    every channel of a head is handed the same number."""
+    every channel of a head is handed the same number.  The kernel: the
+    recurrence, and the `jnp` form it stands for."""
     xs, s0 = _one_decay(T + chunk, T, dims)
     o, s = kda_recurrent(*xs, s0)
-    oc, sc, snap = _compiled(chunk, sub)(*xs, s0)
+    oc, sc, snap = _compiled(chunk, sub, form=form)(*xs, s0)
     assert snap is None and oc.shape == o.shape
     _close(oc, o)
     _close(sc, s)
     q, k, v, g, beta = xs
-    ok, sk, _ = _compiled(chunk, sub)(q, k, v, jnp.broadcast_to(g, k.shape),
-                                      beta, s0)
+    ok, sk, _ = _compiled(chunk, sub)(
+        *((q, k, v, jnp.broadcast_to(g, k.shape), beta) if form == "jnp"
+          else xs), s0)
     _close(oc, ok)
     _close(sc, sk)
 
 
-def test_one_decay_a_head_two_calls_are_one():
-    xs, s0 = _one_decay(7, 90)
-    o, s = _compiled(16)(*xs, s0)[:2]
-    o1, s1, _ = _compiled(16)(*(a[:, :37] for a in xs), s0)
-    o2, s2, _ = _compiled(16)(*(a[:, 37:] for a in xs), s1)
+@one_forms
+def test_one_decay_a_head_two_calls_are_one(form):
+    xs, s0 = _one_decay(7, 90, ONE_FORMS[form])
+    chunked = _compiled(16, form=form)
+    o, s = chunked(*xs, s0)[:2]
+    o1, s1, _ = chunked(*(a[:, :37] for a in xs), s0)
+    o2, s2, _ = chunked(*(a[:, 37:] for a in xs), s1)
     _close(jnp.concatenate([o1, o2], axis=1), o)
     _close(s2, s)
 
 
-@pytest.mark.parametrize("chunk,sub", [(64, 16), (32, 32)])
-def test_one_strong_decay_a_head_overflows_nothing(chunk, sub):
+@pytest.mark.parametrize("form,chunk,sub", [
+    ("jnp", 64, 16), ("jnp", 32, 32), ("kernel", 64, 16)])
+def test_one_strong_decay_a_head_overflows_nothing(form, chunk, sub):
     """Decays down to 1e-4 a token: a factored ``exp(-G_j)`` would be
     inf inside one chunk (exp(295) over 32 tokens); the (C, C) mask's
     exponents are sums of non-positive terms."""
-    xs, s0 = _one_decay(5, 128, decay=(1e-4, 0.9))
+    xs, s0 = _one_decay(5, 128, ONE_FORMS[form], decay=(1e-4, 0.9))
     o, s = kda_recurrent(*xs, s0)
-    oc, sc, _ = _compiled(chunk, sub)(*xs, s0)
+    oc, sc, _ = _compiled(chunk, sub, form=form)(*xs, s0)
     assert bool(jnp.all(jnp.isfinite(oc))) and bool(
         jnp.all(jnp.isfinite(sc)))
     _close(oc, o)
@@ -449,42 +470,77 @@ def test_one_strong_decay_a_head_overflows_nothing(chunk, sub):
 
 
 @pytest.mark.parametrize("pads", [13, 35])
-def test_one_decay_a_head_a_pad_is_an_identity_step(pads):
+@one_forms
+def test_one_decay_a_head_a_pad_is_an_identity_step(form, pads):
     """13 pads inside the first chunk, 35 the first two chunks whole
     and more; and chunks of pads alone leave the state to the bit."""
-    (q, k, v, g, beta), s0 = _one_decay(9, 48)
+    (q, k, v, g, beta), s0 = _one_decay(9, 48, ONE_FORMS[form])
+    chunked = _compiled(16, form=form)
     real = jnp.arange(48) >= pads
     g_p = jnp.where(real[None, :, None, None], g, 0.0)
     b_p = jnp.where(real[None, :, None], beta, 0.0)
-    o, s, _ = _compiled(16)(q, k, v, g_p, b_p, s0)
+    o, s, _ = chunked(q, k, v, g_p, b_p, s0)
     want_o, want_s = kda_recurrent(q[:, pads:], k[:, pads:], v[:, pads:],
                                    g[:, pads:], beta[:, pads:], s0)
     _close(o[:, pads:], want_o)
     _close(s, want_s)
-    idle = _compiled(16)(q, k, v, jnp.zeros_like(g), jnp.zeros_like(beta),
-                         s0)[1]
+    idle = chunked(q, k, v, jnp.zeros_like(g), jnp.zeros_like(beta), s0)[1]
     assert bool(jnp.all(idle == s0))
 
 
-@pytest.mark.parametrize("capture", [0, 15, 16, 40, 82])
-def test_one_decay_a_head_the_captured_state(capture):
-    """A chunk's first, a middle and its last column; the last chunk's,
-    which the length does not fill."""
-    xs, s0 = _one_decay(11, 83)
+@pytest.mark.parametrize("form,capture", [
+    ("jnp", c) for c in (0, 15, 16, 40, 82)] + [
+    ("kernel", c) for c in (0, 15, 40, 79, 82)])
+def test_one_decay_a_head_the_captured_state(form, capture):
+    """A chunk's first, a middle and its last column, in the first
+    chunk, a middle one and the last, which the length does not fill."""
+    xs, s0 = _one_decay(11, 83, ONE_FORMS[form])
     want = kda_recurrent(*(a[:, :capture + 1] for a in xs), s0)[1]
-    o, s, snap = _captured("jnp")(xs, s0, capture)
+    o, s, snap = _captured(form)(xs, s0, capture)
     _close(snap, want)
     _close(s, kda_recurrent(*xs, s0)[1])
     _close(o, kda_recurrent(*xs, s0)[0])
 
 
-def test_one_decay_a_head_bf16_operands_accumulate_in_float32():
-    xs, s0 = _one_decay(8, 96, decay=(0.9, 0.999))
+@one_forms
+def test_one_decay_a_head_bf16_operands_accumulate_in_float32(form):
+    xs, s0 = _one_decay(8, 96, ONE_FORMS[form], decay=(0.9, 0.999))
     o, s = kda_recurrent(*xs, s0)
-    oc, sc, _ = _compiled(64, dtype=jnp.bfloat16)(*xs, s0)
+    oc, sc, _ = _compiled(64, dtype=jnp.bfloat16, form=form)(*xs, s0)
     assert oc.dtype == sc.dtype == jnp.float32
     _close(oc, o, tol=3e-2)
     _close(sc, s, tol=3e-2)
+
+
+@one_forms
+def test_one_decay_a_head_equal_keys_and_beta_two_stay_bounded(form):
+    """`test_equal_keys_and_beta_two_stay_bounded` with one decay a
+    head: the transition is a reflection and the solve stays forward
+    substitution, in the kernel too."""
+    T = 64
+    _, heads, dk, dv = ONE_FORMS[form]
+    k = jnp.broadcast_to(jnp.eye(dk)[0], (1, T, heads, dk))
+    v = jax.random.normal(jax.random.PRNGKey(0), (1, T, heads, dv))
+    g = jnp.zeros((1, T, heads, 1))
+    beta = jnp.full((1, T, heads), 2.0)
+    o, s = kda_recurrent(k, k, v, g, beta)
+    oc, sc, _ = _compiled(64, form=form)(k, k, v, g, beta)
+    _close(oc, o)
+    _close(sc, s)
+
+
+def test_one_decay_a_head_groups_of_heads_are_heads_alone(monkeypatch):
+    """Two rows of six heads in groups of three: a grid step finds its
+    own heads' decays and betas among every head's (the row's columns
+    of ``g`` and ``beta`` are fetched whole), and a row its own state."""
+    monkeypatch.setattr(kda, "_HEADS_A_GATE_STEP", 3)
+    xs, s0 = _one_decay(3, 40, (2, 6, 96, 192))
+    oc, sc, snap = kda_chunk(*xs, s0, chunk=16, sub=8, dtype=jnp.float32,
+                             capture=jnp.int32(21), interpret=True)
+    o, s = kda_recurrent(*xs, s0)
+    _close(oc, o)
+    _close(sc, s)
+    _close(snap, kda_recurrent(*(a[:, :22] for a in xs), s0)[1])
 
 
 def test_a_step_with_one_decay_a_head_is_the_recurrence():
@@ -505,8 +561,12 @@ def test_a_step_with_one_decay_a_head_is_the_recurrence():
 def test_a_decay_a_channel_never_enters_the_matmul_form(monkeypatch):
     """KDA's results are today's to the bit because its path is
     today's: a ``g`` of trailing size ``dk`` walks `_chunk`, only one of
-    trailing size 1 the new form; and neither kernel takes one decay a
-    head, on the chip or interpreted, whatever the heads' sizes.  (All
+    trailing size 1 the matmul form, and on the chip (steered here) it
+    takes its own kernel, ``kda_chunk``, never the one for one decay a
+    head.  A fitting prefill with ONE decay a head takes that kernel,
+    ``delta_chunk``, on the chip and the matmul form off it; one column,
+    heads of 12 x 24 and `kda_decode` keep their `jnp` forms; a
+    differentiated call is `kda_chunked`, forward and backward.  (All
     traced, nothing run.)"""
     entered = []
     real = kda._scalar_gate_chunked
@@ -517,17 +577,49 @@ def test_a_decay_a_channel_never_enters_the_matmul_form(monkeypatch):
         return jax.eval_shape(functools.partial(
             form, chunk=16, dtype=jnp.float32), *xs, s0)
 
+    def calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn.params["name"]
+            for inner in jax.core.jaxprs_in_params(eqn.params):
+                yield from calls(inner)
+
+    def kernels(form, xs, s0, **kw):
+        """The names of the Pallas calls `form` traces to."""
+        return sorted(calls(jax.make_jaxpr(functools.partial(
+            form, chunk=16, dtype=jnp.float32, **kw))(*xs, s0).jaxpr))
+
     traced(kda_chunked, *_inputs(2, 24))
     assert not entered
     traced(kda_chunked, *_one_decay(2, 24))
     assert entered == [(1, 24, 5, 1)]
-    wide, s1 = _one_decay(2, 24, (1, 8, 128, 128))
+    channel = _inputs(2, 24, dims=(1, 8, 128, 128))
+    gate = _one_decay(2, 24, GATE)
+    assert kernels(kda_prefill, *gate) == []                # the CPU
+    assert entered[-1] == (1, 24, 5, 1)
+    del entered[:]
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(kda, "kda_chunk", None)     # would be called
-    traced(kda_prefill, wide, s1)
-    assert entered[-1] == (1, 24, 8, 1)
+    assert kernels(kda_prefill, *channel) == ["kda_chunk"]
+    assert kernels(kda_chunk, *channel, interpret=True) == ["kda_chunk"]
+    assert not entered
+    assert kernels(kda_prefill, *gate) == ["delta_chunk"]
+    assert not entered      # no column is captured: nothing done again
+    xs, s0 = gate
+    assert kernels(kda_prefill, tuple(a[:, :1] for a in xs), s0) == []
+    small = _one_decay(2, 24)
+    assert kernels(kda_prefill, *small) == []               # 12 x 24
+    assert [shape[1:] for shape in entered] == [(1, 5, 1), (24, 5, 1)]
     text = str(jax.make_jaxpr(lambda stack: kda_decode(
-        *(a[:, 0] for a in wide), stack, 0))(s1[None]))
+        *(a[:, 0] for a in xs), stack, 0))(s0[None]))
     assert "pallas_call" not in text
     with pytest.raises(ValueError, match="do not fit the kernel"):
-        kda_chunk(*wide, s1, chunk=16, interpret=True)
+        kda_chunk(*small[0], small[1], chunk=16, interpret=True)
+
+    def loss(form, v):
+        q, k, _, g, beta = xs
+        o, s, _ = form(q, k, v, g, beta, s0, chunk=16, dtype=jnp.float32)
+        return jnp.sum(o * o) + jnp.sum(s)
+
+    text = str(jax.make_jaxpr(jax.grad(functools.partial(
+        loss, kda_prefill)))(xs[2]))
+    assert "pallas_call" not in text and "scan" in text

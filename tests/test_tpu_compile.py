@@ -1002,6 +1002,121 @@ def test_solar_open2_programs_fit_the_chip_at_the_published_widths(
         assert memory.temp_size_in_bytes < 0.15e9, memory
 
 
+#: `kda_chunk` at the Solar-Open2 cell's shapes (64 heads of 128, the
+#: 8,192 bucket, a captured column), as PR 50 wrote it: ops/kda.py has
+#: two kernel bodies since PR 57, and the cell's is this one, operation
+#: for operation (its three calls in the compiled prefill are this
+#: module too); whoever changes it on purpose states the new module
+#: here, with the cell's numbers beside it
+KDA_CHUNK_MODULE = (
+    "a702ef8c76f6f000a5f2b677dc505077054139c694bbce6094cb2d1782f51552")
+
+
+def test_kda_chunk_is_the_module_the_solar_open2_cell_runs():
+    from ray_tpu._private import scopes
+    from ray_tpu.ops.kda import kda_chunk
+
+    f32 = functools.partial(_one_chip(), dtype=jnp.float32)
+    H, d, T = 64, 128, 8192
+    args = [f32((1, T, H, d))] * 4 + [f32((1, T, H)), f32((1, H, d, d)),
+                                     _one_chip()((), jnp.int32)]
+    text = jax.jit(lambda *a: kda_chunk(
+        *a[:6], chunk=64, dtype=jnp.bfloat16,
+        capture=a[6])).lower(*args).compile().as_text()
+    assert _mosaic_modules(text, scopes.KDA_CHUNK) == [KDA_CHUNK_MODULE]
+
+
+@pytest.mark.parametrize("capture", [False, True],
+                         ids=["no_capture", "capture"])
+@pytest.mark.parametrize("T", [1024, 6144])
+def test_delta_chunk_kernel_compiles_at_the_cells_shapes(T, capture):
+    """ops/kda.py's kernel for ONE decay a head at the Olmo-Hybrid
+    cell's shapes: one row, 30 heads of 96 x 192 (filled to 128 x 256
+    lanes on the way in), bf16 operands, the smallest and the largest
+    prefill bucket, with and without a captured column: one Mosaic
+    call, and no loop around it (the captured chunk's `jnp` form is a
+    scan of one step, which the compiler inlines)."""
+    from ray_tpu.ops.kda import kda_chunk
+
+    f32 = functools.partial(_one_chip(), dtype=jnp.float32)
+    H, dk, dv = 30, 96, 192
+    args = [f32((1, T, H, dk)), f32((1, T, H, dk)), f32((1, T, H, dv)),
+            f32((1, T, H, 1)), f32((1, T, H)), f32((1, H, dk, dv))]
+    if capture:
+        args.append(_one_chip()((), jnp.int32))
+    compiled = jax.jit(lambda *a: kda_chunk(
+        *a[:6], chunk=64, dtype=jnp.bfloat16,
+        capture=a[6] if capture else None)).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count(MOSAIC_CALL) == 1 and " while(" not in text
+
+
+#: the Olmo-Hybrid cell's decode step as PR 56 wrote it: its delta rule
+#: is `kda_step` on the layer indexed out and set back, and a PR that
+#: gives a PREFILL its kernel leaves this text as it was
+OLMO_DECODE_AT_PR56 = "78857abc294f40d9"
+
+
+@pytest.mark.parametrize("program,t_pad", [("decode", 0), ("prefill", 6144)])
+def test_olmo_hybrid_programs_fit_the_chip_at_the_published_widths(
+        program, t_pad, monkeypatch):
+    """The cell olmo-hybrid-7b.serve-offline-docqa's two programs as
+    the engine builds them (benchmark/families/olmo_hybrid.py
+    aot_serve_programs): published widths, layers 0-7, bf16 weights, 32
+    slots over a 6.75 GB pool of the TWO full layers and 0.88 GB of
+    matrices, windows and their snapshots, the 6,144-token prefill
+    bucket.  Weights and cache are 12.5 GB, 78% of the chip; pool AND
+    state are donated and updated in place.  A prefill's delta rule is
+    ONE ``delta_chunk`` a Gated DeltaNet layer under ``attn_linear``
+    (the program asks ``jax.default_backend()``, steered here) where the
+    matmul chunk form's batched products, solve and chunk scan were: no
+    ``while`` under the scope, and a compiled peak of 13.04 GiB where
+    the `jnp` form's 0.48 GB of float32 temporaries a thousand columns
+    put it at 14.80 of the chip's 15.75 (PR 56).  The decode step lowers
+    to the text it lowered to: its rule keeps the `jnp` step."""
+    import hashlib
+
+    from ray_tpu._private import scopes
+    from ray_tpu.models.olmo_hybrid import olmo_hybrid_init
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, params, cache, programs, n_blocks = _serving_cell(
+        "olmo-hybrid-7b.serve-offline-docqa", olmo_hybrid_init,
+        t_pad or 1024)
+    assert cache["k"].shape == (2, 13728, 16, 3840) and n_blocks == 13728
+    assert cache["ssm"].shape == (6, 32, 30, 96, 192)
+    held = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(
+        (params, cache)))
+    assert 12.4e9 < held < 12.8e9                  # 78-80% of the chip
+    fn, args = programs[program]
+    lowered = jax.jit(fn, donate_argnums=(1,)).lower(params, cache, *args)
+    if program == "decode":
+        text = lowered.compiler_ir(dialect="stablehlo").operation.get_asm(
+            enable_debug_info=False)
+        text = re.sub(r'backend_config = "(?:[^"\\]|\\.)*"',
+                      'backend_config = ""', text)
+        assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+            == OLMO_DECODE_AT_PR56
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 7.9e9     # pool and state, in place
+    scoped = scopes.scope_map_from_hlo(compiled.as_text())
+    calls = {name: set(keyed.values()) for name, keyed in scoped.items()
+             if any("custom-call" in key for key in keyed)}
+    rules = [s for name, s in calls.items()
+             if name.startswith(scopes.DELTA_CHUNK)]
+    assert rules == ([{scopes.ATTN_LINEAR}] * 6 if program == "prefill"
+                     else []), rules
+    assert scopes.DELTA_CHUNK in scopes.KERNELS
+    loops = [name for name, keyed in scoped.items()
+             if scopes.ATTN_LINEAR in keyed.values()
+             and any(" while" in key for key in keyed)]
+    assert not loops, loops
+    # at least 1 GiB under the 14.80 GiB of the `jnp` form's prefill
+    assert memory.peak_memory_in_bytes < (
+        12.1 if program == "decode" else 13.8) * 2 ** 30, memory
+
+
 @pytest.mark.parametrize("program,t_pad", [("decode", 0), ("prefill", 4096)])
 def test_phi4flash_programs_fit_the_chip_at_the_published_widths(
         program, t_pad, monkeypatch):
