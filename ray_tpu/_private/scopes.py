@@ -46,6 +46,13 @@ SSM_STATE = "ssm_state"
 #: queries and of the cached latent, RoPE, and both attention paths
 #: (expanded for a prefill, absorbed for a decode step)
 MLA = "mla"
+#: a learned indexer that picks what latent attention may read
+#: (models/glm_dsa.py, ops/dsa.py): its query, key and head-weight
+#: projections, the key's LayerNorm and rotary, the index scores, the
+#: top-k, and the mask or the gather's indices built from it.  The
+#: attention over the selected rows keeps ``mla``; reads and writes of
+#: the index keys' pool and the gather of selected latents ``kv_pool``
+ATTN_INDEX = "attn_index"
 #: a sparse expert layer (models/experts.py): scores and top-k ...
 MOE_ROUTER = "moe_router"
 #: ... and the held experts' part: which choices are local and their
@@ -96,7 +103,7 @@ DEVICE_SCOPES = frozenset((EMBED, ATTN, MLP, LN, LM_HEAD_CE, LM_HEAD,
                            KV_POOL, SAMPLE, SSM, SSM_STATE, MLA,
                            MOE_ROUTER, MOE_EXPERTS, ATTN_FULL, ATTN_WINDOW,
                            ATTN_LINEAR, LINEAR_STATE, ATTN_CROSS, GMU,
-                           LAYER_SCAN,
+                           ATTN_INDEX, LAYER_SCAN,
                            LOSS_AND_GRAD, OPTIMIZER))
 
 # -- Pallas kernel names (``pallas_call(name=)`` in ops/*.py) ----------------

@@ -56,8 +56,11 @@ and layer no K and V per head but ONE latent ``ckv`` (kv_lora_rank
 wide, after its norm) and ONE rotary key ``kpe`` shared by every head,
 (L, B, S, width) dense and (L, num_blocks, block_size, width) paged.
 They are positional as K and V are, so everything below that walks
-"the K/V" walks `positional(cache)`: whichever of the two pairs the
-cache holds.  Two tensors and not one 576 wide: compiled for the chip,
+"the K/V" walks `positional(cache)`: whichever of the pairs the cache
+holds (or the triple: a family whose attention reads what a learned
+indexer selects keeps the indexer's key a token, ``kidx``, as a third
+per-position tensor in the same blocks, models/glm_dsa_decode.py).
+Two tensors and not one 576 wide: compiled for the chip,
 the 512-wide one keeps its rows whole in the tiles and is gathered and
 scattered where it lies, where one of 576 (4.5 lane tiles) is stored
 block-minor and re-laid a layer at a time, as the 64-wide one still is
@@ -151,16 +154,23 @@ def is_paged(cache) -> bool:
 
 
 #: what a cache keeps PER POSITION: K and V per head, or latent
-#: attention's latent and rotary key (one of each a token, no heads)
+#: attention's latent and rotary key (one of each a token, no heads),
+#: or those two and the key a learned indexer scores the position by
+#: (models/glm_dsa_decode.py: written by every program, shared by a
+#: prefix's blocks as the other two are, never read by the attention
+#: itself)
 _KV = ("k", "v")
 _LATENT = ("ckv", "kpe")
+_LATENT_INDEXED = (*_LATENT, "kidx")
 
 
 def positional(cache):
     """The names of the cache's per-position tensors, in the order the
-    family's programs hand their new rows over: ``("k", "v")`` or
-    ``("ckv", "kpe")``."""
-    return _LATENT if _LATENT[0] in cache else _KV
+    family's programs hand their new rows over: ``("k", "v")``,
+    ``("ckv", "kpe")`` or ``("ckv", "kpe", "kidx")``."""
+    if _LATENT[0] not in cache:
+        return _KV
+    return _LATENT_INDEXED if _LATENT_INDEXED[-1] in cache else _LATENT
 
 
 @jax.named_scope(scopes.KV_POOL)
@@ -341,7 +351,7 @@ _HEADS = (None, None, None, "heads", "head_dim")
 #: (over `tensor`).  A state tensor's snapshot pool is "snap_" + name,
 #: same axes, one entry a slot.
 _TENSORS = {"k": (1, _HEADS), "v": (1, _HEADS),
-            "ckv": (1, None), "kpe": (1, None),
+            "ckv": (1, None), "kpe": (1, None), "kidx": (1, None),
             "ssm": (1, None), "conv": (2, None),
             "wk": (1, None), "wv": (1, None)}
 #: what a cache may keep per SLOT beside its per-position tensors: a
@@ -542,7 +552,7 @@ def kv_shards(cache) -> int:
     devices the cache lives on: 1 on one device, and where the head
     count does not divide the mesh's tensor degree (`cache_shardings`
     replicates it then); 1 for a latent cache, which has no heads."""
-    if positional(cache) is _LATENT:
+    if positional(cache) is not _KV:
         return 1
     k = cache["k"]
     return k.shape[3] // k.sharding.shard_shape(k.shape)[3]
@@ -581,7 +591,7 @@ def cache_reach(cache) -> dict:
 #: this key what the routing of its LAST program did on this chip, a
 #: float32 vector of `EXPERT_COUNTERS`: every program overwrites it, and
 #: the engine's fused programs hand it out beside their tokens
-#: (`expert_counters`), so it lands at the fence the tokens land at
+#: (`program_counters`), so it lands at the fence the tokens land at
 EXPERTS = "experts"
 #: experts held by this chip, of how many routed; (token, expert)
 #: assignments that fell on held experts, summed over the layers; held
@@ -596,10 +606,23 @@ EXPERT_COUNTERS = ("held", "of", "assignments_local",
                    "row_tiles_per_touched")
 
 
-def expert_counters(cache):
-    """The last program's `EXPERT_COUNTERS`, or None for a family
-    without expert layers."""
-    return cache.get(EXPERTS)
+#: a family whose attention reads only what a learned indexer selects
+#: (models/glm_dsa_decode.py) keeps under this key what the selection
+#: did in its LAST program, a float32 vector of `INDEX_COUNTERS`,
+#: overwritten and handed out as the experts' is
+INDEX = "index"
+#: positions the program's queries attended, summed over its rows (a
+#: prefill's real columns) and layers; positions they could have
+#: reached (every earlier one and their own): what attention over the
+#: whole context would have read
+INDEX_COUNTERS = ("index_selected", "index_reachable")
+
+
+def program_counters(cache):
+    """Every counter vector the last program left in the cache, by its
+    key (`EXPERTS`, `INDEX`), or None for a family that keeps none."""
+    return {key: cache[key] for key in (EXPERTS, INDEX)
+            if key in cache} or None
 
 
 def _positions(batch: int):

@@ -28,8 +28,10 @@ from typing import Any, Callable, Dict, Optional, Tuple
 #: when the engine's options are checked.
 #: LATENT: positional as KV is (a slot's past is its rows, a prefix is
 #: its blocks), but a row is one latent a token, not K and V per head
-#: (models/kimi_k2_decode.py): what moves K/V rows of one shape, rewinds
-#: through a verify program, or splits a heads axis is refused for it.
+#: (models/kimi_k2_decode.py; models/glm_dsa_decode.py keeps a third
+#: tensor a token beside them, the key a learned indexer scores the
+#: position by): what moves K/V rows of one shape, rewinds through a
+#: verify program, or splits a heads axis is refused for it.
 #: WINDOWED: some layers attend a bounded window and keep, per slot, a
 #: ring of their last K/V rows beside the pool of the layers that
 #: attend everything (models/laguna_decode.py): per-slot state as a
@@ -205,6 +207,20 @@ def _olmo_hybrid() -> Dict[str, Any]:
         prefill_attention=m.olmo_hybrid_prefill_attention)
 
 
+def _glm_dsa() -> Dict[str, Any]:
+    from ray_tpu.models import glm_dsa_decode as m
+    from ray_tpu.models.glm_dsa import (glm_dsa_config, glm_dsa_init,
+                                        glm_dsa_logical_axes)
+
+    return dict(
+        config=glm_dsa_config, init=glm_dsa_init,
+        logical_axes=glm_dsa_logical_axes, generate=m.glm_dsa_generate,
+        prefill=m.glm_dsa_prefill, paged_prefill=m.glm_dsa_paged_prefill,
+        step=m.glm_dsa_decode_step, verify=None,
+        init_cache=m.glm_dsa_init_cache,
+        init_paged_cache=m.glm_dsa_init_paged_cache)
+
+
 #: family -> (what its cache holds, loader of its programs)
 FAMILIES: Dict[str, Tuple[str, Callable[[], Dict[str, Any]]]] = {
     "gpt2": (KV, _gpt2), "llama": (KV, _llama),
@@ -212,7 +228,8 @@ FAMILIES: Dict[str, Tuple[str, Callable[[], Dict[str, Any]]]] = {
     "laguna": (WINDOWED, _laguna),
     "solar_open2": (RECURRENT, _solar_open2),
     "phi4flash": (RECURRENT_WINDOWED, _phi4flash),
-    "olmo_hybrid": (RECURRENT, _olmo_hybrid)}
+    "olmo_hybrid": (RECURRENT, _olmo_hybrid),
+    "glm_dsa": (LATENT, _glm_dsa)}
 
 
 def cache_kind(name: str) -> Optional[str]:

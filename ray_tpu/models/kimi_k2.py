@@ -333,11 +333,13 @@ def rotate(x, cos, sin):
 # ---------------------------------------------------------------------------
 
 @jax.named_scope(scopes.MLA)
-def mla_project(u, p, cfg: KimiK2Config, cos, sin):
+def mla_project(u, p, cfg: KimiK2Config, cos, sin, latent: bool = False):
     """u (B, T, d) normed input; cos, sin (B or 1, T, rope/2) ->
     q (B, T, H, nope + rope) with its rotary part rotated, and what the
     cache keeps: ckv (B, T, kv_lora_rank) normed, kpe (B, T, rope)
-    rotated."""
+    rotated.  With `latent` the query latent ``c_q`` (B, T,
+    q_lora_rank) is handed out as a fourth (models/glm_dsa.py: an
+    indexer projects its own queries from it)."""
     dt = cfg.dtype
     B, T, _ = u.shape
     H, n = cfg.n_head, cfg.qk_nope_dim
@@ -351,7 +353,7 @@ def mla_project(u, p, cfg: KimiK2Config, cos, sin):
     kv = u @ p["wkv_a"].astype(dt)
     ckv = _rmsnorm(kv[..., :cfg.kv_lora_rank], p["kv_norm"], cfg.rms_eps)
     kpe = rotate(kv[..., cfg.kv_lora_rank:], cos, sin)
-    return q, ckv, kpe
+    return (q, ckv, kpe, cq) if latent else (q, ckv, kpe)
 
 
 @jax.named_scope(scopes.MLA)
@@ -442,19 +444,29 @@ def swiglu(x, p, cfg: KimiK2Config):
 
 
 def block(x, p, cfg: KimiK2Config, positions, attend: Callable,
-          valid=None, tiled: bool = True):
+          valid=None, tiled: bool = True,
+          indexer: Optional[Callable] = None):
     """One layer on x (B, T, d) at int `positions` (B or 1, T).
     ``attend(q, ckv, kpe) -> o (B, T, H, v)`` is the caller's: it owns
     the cache (and sees this layer's new latent rows).  `valid` (B, T)
     marks the rows that hold a token (experts.routed_experts).  A layer
     of ``p`` with a ``"moe"`` entry is an expert layer.
 
+    A family whose attention reads only what a learned indexer selects
+    (models/glm_dsa.py) gives ``indexer(u, c_q, cos, sin) -> tuple``:
+    what it returns from the layer's normed input and query latent is
+    handed to `attend` after the three.
+
     Returns (x, per-layer experts.STATS or None)."""
     cos, sin = rope_tables(positions, cfg)
-    q, ckv, kpe = mla_project(
-        rmsnorm(x, p["ln1"]["scale"], cfg.rms_eps), p["attn"], cfg, cos,
-        sin)
-    x = x + mla_out(attend(q, ckv, kpe), p["attn"], cfg).astype(x.dtype)
+    u = rmsnorm(x, p["ln1"]["scale"], cfg.rms_eps)
+    if indexer is None:
+        o = attend(*mla_project(u, p["attn"], cfg, cos, sin))
+    else:
+        q, ckv, kpe, cq = mla_project(u, p["attn"], cfg, cos, sin,
+                                      latent=True)
+        o = attend(q, ckv, kpe, *indexer(u, cq, cos, sin))
+    x = x + mla_out(o, p["attn"], cfg).astype(x.dtype)
     if "moe" not in p:
         return x + swiglu(rmsnorm(x, p["ln2"]["scale"], cfg.rms_eps),
                           p["mlp"], cfg), None
@@ -546,19 +558,20 @@ def kimi_k2_forward(params, tokens, cfg: KimiK2Config,
                                    ("batch", "seq", "vocab"), rules)
 
 
-def kimi_k2_loss(params, batch, cfg: KimiK2Config,
-                 rules=DEFAULT_RULES) -> jnp.ndarray:
+def kimi_k2_loss(params, batch, cfg: KimiK2Config, rules=DEFAULT_RULES,
+                 forward: Callable = kimi_k2_forward) -> jnp.ndarray:
     """Next-token cross-entropy; batch = {"tokens": (B, T+1)} or
     {"inputs", "targets"}, optionally {"mask"} (the NLL shared with the
     other families).  No auxiliary balance loss: the source balances by
     its selection bias (``noaux_tc``), which training would update
-    outside the gradient and nothing here trains."""
+    outside the gradient and nothing here trains.  `forward` is the
+    family's own where another shares this loss (models/glm_dsa.py)."""
     if "tokens" in batch:
         inputs, targets = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
     else:
         inputs, targets = batch["inputs"], batch["targets"]
-    nll = nll_from_logits(kimi_k2_forward(params, inputs, cfg, rules),
-                          targets, cfg.vocab_size, cfg.padded_vocab)
+    nll = nll_from_logits(forward(params, inputs, cfg, rules), targets,
+                          cfg.vocab_size, cfg.padded_vocab)
     mask = batch.get("mask")
     if mask is not None:
         m = mask.astype(jnp.float32)

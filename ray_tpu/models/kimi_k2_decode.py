@@ -78,7 +78,8 @@ def kimi_k2_init_paged_cache(cfg: KimiK2Config, batch: int, *,
 
 
 @jax.named_scope(scopes.MLA)
-def attend_blockwise(q, ckv, kpe, p, logical, real, cfg: KimiK2Config):
+def attend_blockwise(q, ckv, kpe, p, logical, real, cfg: KimiK2Config,
+                     selected=None):
     """One sequence's expanded attention without its score matrix.
 
     q (T, H, qk) at positions `logical` (T,), `real` (T,) False on pad
@@ -86,7 +87,10 @@ def attend_blockwise(q, ckv, kpe, p, logical, real, cfg: KimiK2Config):
     own new rows among them; position t attends slots <= logical[t].
     The latents are up-projected a block at a time as far as the last
     real query reaches; each block of queries then walks the key blocks
-    up to its own diagonal with a running maximum and sum.  Returns
+    up to its own diagonal with a running maximum and sum.  `selected`
+    (T, S) bool, where given, is a second mask beside the causal one:
+    position t attends slot s only where ``selected[t, s]`` too (a
+    learned indexer's choice, models/glm_dsa_decode.py).  Returns
     (T, H, v); a pad's row is zeros."""
     T, H, _ = q.shape
     S = ckv.shape[0]
@@ -116,6 +120,8 @@ def attend_blockwise(q, ckv, kpe, p, logical, real, cfg: KimiK2Config):
     def queries(i):
         qi = lax.dynamic_slice_in_dim(q, i * qb, qb)
         at = lax.dynamic_slice_in_dim(reach, i * qb, qb)
+        pick = None if selected is None \
+            else lax.dynamic_slice_in_dim(selected, i * qb, qb)
 
         @jax.named_scope(scopes.MLA)
         def over(j, carry):
@@ -124,6 +130,8 @@ def attend_blockwise(q, ckv, kpe, p, logical, real, cfg: KimiK2Config):
             vj = lax.dynamic_slice_in_dim(values, j * kb, kb)
             s = jnp.einsum("qhd,khd->hqk", qi, kj).astype(jnp.float32)
             ok = (j * kb + jnp.arange(kb))[None, :] <= at[:, None]
+            if pick is not None:
+                ok &= lax.dynamic_slice_in_dim(pick, j * kb, kb, axis=1)
             s = jnp.where(ok[None], s * scale, -1e30)
             m_new = jnp.maximum(m, jnp.max(s, axis=-1))
             # a row with nothing to attend yet has m_new == -1e30 and

@@ -309,8 +309,10 @@ class LLMEngine(EngineBase):
                                              mesh=self.mesh)
             self._cache = self._to_engine(self._cache)
             # a family with a sparse expert layer leaves its routing
-            # counters in the cache, program by program (_counters)
-            self._counted = dc.expert_counters(self._cache) is not None
+            # counters in the cache, program by program, and one whose
+            # attention reads what an indexer selects its selection's
+            # (_counters)
+            self._counted = dc.program_counters(self._cache) is not None
             self._cur = np.zeros((opt.max_slots,), np.int32)
             self._slots = [None] * opt.max_slots
             # what the chip has been given and the host has not fenced
@@ -516,17 +518,19 @@ class LLMEngine(EngineBase):
         return self._telemetry.launch_records()
 
     def _counters(self):
-        """The expert counters the program just dispatched leaves,
-        still on the device (None for a family without expert
-        layers): a copy queued behind the program, which its fence
+        """The counter vectors the program just dispatched leaves, by
+        their cache key and still on the device (None for a family that
+        keeps none): a copy queued behind the program, which its fence
         reads beside its tokens (`_book_counters`)."""
         if not self._counted:
             return None
-        return self._fns.take_counters(dc.expert_counters(self._cache))
+        return self._fns.take_counters(dc.program_counters(self._cache))
 
     def _book_counters(self, program, counters) -> None:
-        if counters is not None:
-            self._telemetry.record_experts(program, np.asarray(counters))
+        book = {dc.EXPERTS: self._telemetry.record_experts,
+                dc.INDEX: self._telemetry.record_index}
+        for key, vector in (counters or {}).items():
+            book[key](program, np.asarray(vector))
 
     def _sampler_for(self, sp):
         """Per-SamplingParams jitted full-batch sampler for

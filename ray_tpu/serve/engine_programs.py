@@ -49,9 +49,9 @@ def _jitted_engine_fns(family, cfg, sampling, kv_layout="dense",
       prefill_raw / paged_prefill_raw / pool_logits — logits-returning
           twins for requests overriding SamplingParams (compiled only
           if such a request arrives)
-      take_counters                        — a copy of the expert
-          counters a program left in the cache
-          (decode_common.expert_counters), queued behind that program:
+      take_counters                        — a copy of the counter
+          vectors a program left in the cache
+          (decode_common.program_counters), queued behind that program:
           the copy outlives the cache's next donation and is read at
           the fence the program's tokens are read at
       admit / copy_block / clear_row / restore_state / install_blocks
@@ -153,7 +153,7 @@ def _jitted_engine_fns(family, cfg, sampling, kv_layout="dense",
 
     def take_counters(counters):
         # a copy that outlives the cache's next donation
-        return counters + 0
+        return jax.tree.map(lambda c: c + 0, counters)
 
     def fork_block(cache, src, dst):
         return pinned(dc.copy_block(cache, src, dst))

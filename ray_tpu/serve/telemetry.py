@@ -242,6 +242,22 @@ def _engine_metrics() -> Dict[str, Any]:
                     "per program: row tiles the experts' rows fill "
                     "over the experts touched, over all layers; summed",
                     tag_keys=tags + ("program",)),
+                # an indexer's selection (a family whose attention reads
+                # only what a learned indexer picks), summed over the
+                # fused programs of one kind
+                "index_programs": Counter(
+                    "serve_index_programs_total",
+                    "fused programs whose selection counters landed",
+                    tag_keys=tags + ("program",)),
+                "index_selected": Counter(
+                    "serve_index_selected_total",
+                    "positions the programs' queries attended, over "
+                    "rows and layers", tag_keys=tags + ("program",)),
+                "index_reachable": Counter(
+                    "serve_index_reachable_total",
+                    "positions those queries could have attended: "
+                    "every earlier one and their own",
+                    tag_keys=tags + ("program",)),
                 "expert_held": Gauge(
                     "serve_expert_held",
                     "routed experts this chip holds", tag_keys=tags),
@@ -632,6 +648,10 @@ class EngineTelemetry:
         #: {program kind: sums of decode_common.EXPERT_COUNTERS} of a
         #: family with a sparse expert layer; empty for the others
         self._experts: Dict[str, Dict[str, float]] = {}
+        #: {program kind: [programs, sums of decode_common
+        #: .INDEX_COUNTERS]} of a family whose attention reads what an
+        #: indexer selects; empty for the others
+        self._index: Dict[str, List[float]] = {}
         #: paged decode waves landed; the blocks their rows' positions
         #: fill; the entries of those rows' tables
         self._kv_walk = [0, 0, 0]
@@ -1237,6 +1257,22 @@ class EngineTelemetry:
         self._m["expert_held"].set(held, tags=self._tags)
         self._m["expert_of"].set(of, tags=self._tags)
 
+    def record_index(self, program: str, counters) -> None:
+        """One fused program's selection counters (decode_common
+        .INDEX_COUNTERS, in that order), landed with its tokens: summed
+        under `program` into ``engine_stats()["index"]`` and the
+        ``serve_index_*`` metrics."""
+        selected, reachable = (float(v) for v in counters)
+        with self._lock:
+            acc = self._index.setdefault(program, [0, 0.0, 0.0])
+            acc[0] += 1
+            acc[1] += selected
+            acc[2] += reachable
+        tags = dict(self._tags, program=program)
+        self._m["index_programs"].inc(tags=tags)
+        self._m["index_selected"].inc(selected, tags=tags)
+        self._m["index_reachable"].inc(reachable, tags=tags)
+
     def record_kv_walk(self, walked: int, tabled: int) -> None:
         """One paged decode wave, landed with its tokens: `walked`
         blocks hold its rows' positions (the sum of ceil(pos /
@@ -1560,6 +1596,7 @@ class EngineTelemetry:
             kv_tier = self._kv_tier
             recurrent = self._recurrent
             experts = {k: dict(v) for k, v in self._experts.items()}
+            index = {k: list(v) for k, v in self._index.items()}
             walk_waves, walked, tabled = self._kv_walk
             reach_waves, in_pool, in_window, at_full = self._kv_reach
             attn_kernel, attn_jnp, pairs, square = self._prefill_attn
@@ -1656,6 +1693,16 @@ class EngineTelemetry:
                            acc["row_tiles_per_touched"]
                            / acc["programs"], 4)}
                 for kind, acc in sorted(experts.items())},
+            # an indexer's selection, by program kind (empty for a
+            # family without one): positions attended of those the
+            # queries could have reached, over rows and layers
+            "index": {
+                kind: {"programs": n, "selected": int(selected),
+                       "reachable": int(reachable),
+                       "selected_share": round(selected / reachable, 4)
+                       if reachable else 0.0}
+                for kind, (n, selected, reachable)
+                in sorted(index.items())},
             # paged decode waves: the blocks that hold their rows'
             # positions over the entries of those rows' block tables
             # (zeros for a dense cache)

@@ -112,10 +112,12 @@ def test_no_drop_when_every_token_chooses_one_expert(whole):
     assert float(stats[2]) >= 200 / (200 * K / E) - 1e-6
 
 
-@pytest.mark.parametrize("shares", [4, 2])
+@pytest.mark.parametrize("shares", [4, 2, 16])
 def test_the_shares_add_up_to_the_uncut_layer(whole, shares):
-    """16 experts over `shares` chips: the routed parts of all shares
-    plus the shared expert ONCE equal the uncut layer."""
+    """16 experts over `shares` chips (16: EP16 with one expert a chip,
+    the deployment models/glm_dsa.py's cell is a sixteenth of): the
+    routed parts of all shares plus the shared expert ONCE equal the
+    uncut layer."""
     cfg, p = whole
     x = _x(96, seed=5)
     uncut, _ = ex.moe_layer(p, x, cfg)

@@ -50,6 +50,20 @@ def tiny():
     return cfg, K.kimi_k2_init(jax.random.PRNGKey(0), cfg)
 
 
+@pytest.fixture(scope="module")
+def wide_values():
+    """Values WIDER than the keys' position-free part, in the published
+    ratio of models/glm_dsa.py (192 + 64 against 256): 24 + 8 against
+    32."""
+    cfg = K.kimi_k2_config("nano", qk_nope_dim=24, v_head_dim=32, **_OVR)
+    return cfg, K.kimi_k2_init(jax.random.PRNGKey(0), cfg)
+
+
+@pytest.fixture(params=["nano", "values_wider_than_keys"])
+def heads(request, tiny, wide_values):
+    return tiny if request.param == "nano" else wide_values
+
+
 def _stated(cfg):
     return dict(held=cfg.experts.held_ids, top_k=cfg.top_k,
                 qk_nope_dim=cfg.qk_nope_dim, qk_rope_dim=cfg.qk_rope_dim,
@@ -109,8 +123,8 @@ def _attention_inputs(cfg, params, B=2, T=24):
     return p, K.mla_project(u, p, cfg, cos, sin)
 
 
-def test_absorbed_equals_expanded(tiny):
-    cfg, params = tiny
+def test_absorbed_equals_expanded(heads):
+    cfg, params = heads
     p, (q, ckv, kpe) = _attention_inputs(cfg, params)
     mask = jnp.tril(jnp.ones((24, 24), bool))[None]
     a = K.attend_expanded(q, ckv, kpe, p, mask, cfg)
@@ -132,8 +146,8 @@ def test_a_fresh_row_beside_the_view_equals_the_row_in_it(tiny):
 
 
 @pytest.mark.parametrize("pad,prefix", [(0, 0), (5, 0), (9, 32)])
-def test_blockwise_equals_expanded(tiny, pad, prefix):
-    cfg, params = tiny
+def test_blockwise_equals_expanded(heads, pad, prefix):
+    cfg, params = heads
     T, S = 64, cfg.max_seq
     p, (q, ckv, kpe) = _attention_inputs(cfg, params, B=1, T=S)
     col = jnp.arange(T)
@@ -143,9 +157,19 @@ def test_blockwise_equals_expanded(tiny, pad, prefix):
             & (jnp.arange(S)[None, :] <= logical[:, None]))[None]
     want = K.attend_expanded(q[:, :T], ckv, kpe, p, mask, cfg)[0]
     got = attend_blockwise(q[0, :T], ckv[0], kpe[0], p, logical, real, cfg)
+    assert got.shape == (T, cfg.n_head, cfg.v_head_dim)
     np.testing.assert_allclose(np.asarray(got[pad:]),
                                np.asarray(want[pad:]), atol=3e-6)
     assert float(jnp.abs(got[:pad]).max()) == 0.0 if pad else True
+    # a second mask beside the causal one (an indexer's selection)
+    pick = jax.random.bernoulli(jax.random.PRNGKey(pad), 0.4, (T, S)) \
+        | jnp.eye(T, S, k=prefix - pad, dtype=bool)
+    want = K.attend_expanded(q[:, :T], ckv, kpe, p, mask & pick[None],
+                             cfg)[0]
+    got = attend_blockwise(q[0, :T], ckv[0], kpe[0], p, logical, real, cfg,
+                           selected=pick)
+    np.testing.assert_allclose(np.asarray(got[pad:]),
+                               np.asarray(want[pad:]), atol=3e-6)
 
 
 def _paged_prefill(params, cfg, prompt, bucket, prefix_blocks=(), slot=1,

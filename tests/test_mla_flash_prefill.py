@@ -33,11 +33,8 @@ PLACES = {
 }
 
 
-@pytest.fixture(scope="module", params=[jnp.float32, jnp.bfloat16],
-                ids=["f32", "bf16"])
-def problem(request):
-    dtype = request.param
-    cfg = kimi_k2_config("nano", dtype=dtype, max_seq=S)
+def _problem(dtype, **dims):
+    cfg = kimi_k2_config("nano", dtype=dtype, max_seq=S, **dims)
     H, c = cfg.n_head, cfg.kv_lora_rank
     ks = jax.random.split(jax.random.PRNGKey(7), 5)
     p = {"wk_b": jax.random.normal(ks[0], (c, H, cfg.qk_nope_dim)) * 0.3,
@@ -53,6 +50,27 @@ def problem(request):
             block_q=TILE, block_k=TILE, strip=STRIP, interpret=True)
 
     return cfg, p, q, ckv, kpe, kernel
+
+
+@pytest.fixture(scope="module", params=[jnp.float32, jnp.bfloat16],
+                ids=["f32", "bf16"])
+def problem(request):
+    return _problem(request.param)
+
+
+def test_values_wider_than_the_keys_free_part(monkeypatch):
+    """models/glm_dsa.py's head shape, 192 + 64 against 256, in
+    miniature (24 + 8 against 32): keys and values of one width, the
+    keys' position-free part narrower than the values."""
+    cfg, p, q, ckv, kpe, kernel = _problem(jnp.float32, qk_nope_dim=24,
+                                           v_head_dim=32)
+    prefix_len, pad = PLACES["short_tail_behind_a_prefix"]
+    col = jnp.arange(T)
+    out = kernel(prefix_len, pad)
+    assert out.shape == (T, cfg.n_head, 32) and cfg.qk_head_dim == 32
+    walk = attend_blockwise(q, ckv, kpe, p, prefix_len + col - pad,
+                            col >= pad, cfg)
+    np.testing.assert_allclose(out, walk, atol=1e-5, rtol=1e-5)
 
 
 @pytest.mark.parametrize("place", PLACES)
