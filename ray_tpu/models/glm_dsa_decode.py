@@ -14,19 +14,29 @@ its LayerNorm and rotary, ONE for all index heads):
 (1,408 B a token a layer in bf16 at the published widths), through
 `PagedKV` as every family's per-position tensors go: every program
 writes all three, a prefix's blocks share all three, and the attention
-itself never reads ``kidx``.  Every path below is ``jnp``:
+itself never reads ``kidx``.  The paths:
 
   * a decode step scores the row's WHOLE context from ``kidx`` (a
-    gathered view of the index pool), takes the top ``index_topk``
-    (`dsa.select_top`), gathers those latents and rotary keys by (block
-    table, offset) and attends ABSORBED over the gathered rows alone
-    (kimi_k2.attend_absorbed): what the attention reads stops growing
-    with the context, what the indexer reads does not;
+    gathered view of the index pool) and attends ABSORBED over the
+    ``index_topk`` positions of highest score alone.  On the chip a
+    paged cache is attended where it lies (since PR 59): the selection
+    is a mask (`dsa.select_mask`), and ops/mla_paged_decode.py's walk
+    of the row's block table, one Pallas call a layer
+    (kimi_k2_decode.attend_paged), takes it beside its causal mask,
+    the rotary keys re-laid once a step (``rotary_lanes``): every block
+    of the row crosses HBM once, which is what a gather of 2,048 rows
+    out of 8k spread evenly touches anyway, at ten times a gather's
+    rate.  Off the chip, and over the dense cache, the step is ``jnp``
+    and the oracle the kernel is held to: `dsa.select_top`'s positions,
+    those latents and rotary keys gathered by (block table, offset),
+    kimi_k2.attend_absorbed over the gathered rows.  Either way what
+    the attention WEIGHS stops growing with the context, what the
+    indexer reads does not;
   * a prefill scores blocks of queries against the keys they reach,
     finds each query's selection as a mask (`dsa.select_prefill`) and
     attends EXPANDED blockwise under it (kimi_k2_decode
-    .attend_blockwise): every (query, key) pair is scored and masked,
-    none is skipped.
+    .attend_blockwise), in ``jnp``: every (query, key) pair is scored
+    and masked, none is skipped.
 
 A context of at most ``index_topk`` positions selects everything, and
 the same paths then give dense latent attention's result.
@@ -53,8 +63,9 @@ from ray_tpu.models.experts import _with_counters
 from ray_tpu.models.glm_dsa import GlmDsaConfig, block, selection
 from ray_tpu.models.kimi_k2 import (attend_absorbed, attend_expanded, embed,
                                     lm_logits, walk_layers)
-from ray_tpu.models.kimi_k2_decode import attend_blockwise
+from ray_tpu.models.kimi_k2_decode import attend_blockwise, attend_paged
 from ray_tpu.ops import dsa
+from ray_tpu.ops.mla_paged_decode import rotary_lanes
 
 __all__ = ["glm_dsa_init_cache", "glm_dsa_init_paged_cache",
            "glm_dsa_prefill", "glm_dsa_paged_prefill",
@@ -223,6 +234,11 @@ def glm_dsa_decode_step(params, cache, tokens, cfg: GlmDsaConfig
     Returns (logits (B, padded_vocab) float32, updated cache)."""
     B = tokens.shape[0]
     paged = is_paged(cache)
+    # what the program can see of its input picks the path, as
+    # kimi_k2_decode_step's: a paged cache on the chip is walked where
+    # it lies under the selection's mask; the CPU gathers the selected
+    # rows and keeps the jnp path, the parity oracle
+    in_place = paged and jax.default_backend() == "tpu"
     pos, start = cache["pos"], cache["start"]
     rows = jnp.arange(B)
     with jax.named_scope(scopes.ATTN_INDEX):
@@ -230,8 +246,12 @@ def glm_dsa_decode_step(params, cache, tokens, cfg: GlmDsaConfig
         ok = slot_mask(start, pos + 1, cfg.max_seq)             # (B, S)
     pkv = PagedKV(cache, cache["block_tables"], pos[:, None],
                   whole=True) if paged else None
-    if paged:
-        # once for all layers: the pools are read-only in the scan
+    if in_place:
+        with jax.named_scope(scopes.KV_POOL):
+            # once for all layers: the pools are read-only in the scan
+            rope = rotary_lanes(cache["kpe"])
+    elif paged:
+        # once for all layers too
         rope_rows = dsa.pool_rows(cache["kpe"])
     x = embed(params, tokens, cfg)[:, None]                     # (B,1,d)
 
@@ -244,10 +264,15 @@ def glm_dsa_decode_step(params, cache, tokens, cfg: GlmDsaConfig
                 bt = cache["block_tables"]
                 # the pools are read-only in the scan: the step's rows
                 # land after it (PagedKV.commit)
-                idx, valid = dsa.select_top(
-                    dsa.index_scores_step(qi[:, 0], w[:, 0], pools[2],
-                                          lidx, bt, pos, kidx[:, 0]),
-                    ok, cfg.index_topk)
+                scores = dsa.index_scores_step(qi[:, 0], w[:, 0], pools[2],
+                                               lidx, bt, pos, kidx[:, 0])
+            if in_place:
+                return attend_paged(
+                    q, pools[0], rope, cache, lidx, p["attn"], (ckv, kpe),
+                    cfg, selected=dsa.select_mask(scores, ok,
+                                                  cfg.index_topk))
+            elif paged:
+                idx, valid = dsa.select_top(scores, ok, cfg.index_topk)
                 with jax.named_scope(scopes.ATTN_INDEX):
                     own = idx == pos[:, None]
                 picked = (

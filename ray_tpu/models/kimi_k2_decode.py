@@ -193,21 +193,24 @@ def kimi_k2_prefill_attention(cfg: KimiK2Config, t_pad: int,
 
 @jax.named_scope(scopes.MLA)
 def attend_paged(q, ckv_pool, rope_lanes, cache, lidx, p, fresh,
-                 cfg: KimiK2Config):
+                 cfg: KimiK2Config, selected=None):
     """`attend_absorbed` for one decode column of every row of a paged
     cache, over the latent pool where it lies: q (B, 1, H, qk); the
     whole latent pool and ``rotary_lanes`` of the rotary pool; `fresh`
     = this column's (ckv (B, 1, c), kpe (B, 1, r)).  The walk over each
     row's blocks, the running softmax and the weighted sum are one
     kernel (ops/mla_paged_decode.py); ``W_uk`` and ``W_uv`` stay the
-    einsums they are."""
+    einsums they are.  `selected` (B, max_seq) bool, where given, is
+    the slots of its table a row attends and no others (a learned
+    indexer's choice, models/glm_dsa_decode.py), its own new position
+    at slot ``pos`` among them or not."""
     dt, n = cfg.dtype, cfg.qk_nope_dim
     q_lat = jnp.einsum("bthn,chn->bthc", q[..., :n], p["wk_b"].astype(dt))
     o_lat = mla_paged_decode(
         q_lat[:, 0], q[:, 0, :, n:], ckv_pool, rope_lanes,
         cache["block_tables"], cache["pos"], lidx,
         (fresh[0][:, 0], fresh[1][:, 0]), scale=softmax_scale(cfg),
-        start=cache["start"])
+        start=cache["start"], selected=selected)
     return jnp.einsum("bthc,chv->bthv", o_lat[:, None],
                       p["wv_b"].astype(dt))
 

@@ -23,13 +23,15 @@ positions, and a tie at the last place goes to the LOWER position in
 both, as the source's ``topk`` breaks it (a score of exactly 0, every
 head's ReLU shut, is common where the heads are few):
 
-* `select_top` (a decode step, which needs the positions to gather):
-  ``lax.top_k``, which is stable.
-* `select_mask` (a prefill, which needs a mask and no index): each
-  query's ``count``-th highest score, found by a bitwise search over
-  float32's order (32 passes of compare-and-count, no sort); everything
-  above it, and of the positions AT it the first so many that the count
-  is met (a second search of the same kind, over the slot's bits).
+* `select_top` (a decode step off the chip or over a dense cache,
+  which needs the positions to gather): ``lax.top_k``, which is stable.
+* `select_mask` (a prefill, and a decode step whose attention walks
+  the paged pool under a mask, ops/mla_paged_decode.py: both need a
+  mask and no index): each query's ``count``-th highest score, found
+  by a bitwise search over float32's order (32 passes of
+  compare-and-count, no sort); everything above it, and of the
+  positions AT it the first so many that the count is met (a second
+  search of the same kind, over the slot's bits).
   tests/test_dsa.py holds the two forms equal, tied scores among them.
 
 No (T, S) score matrix is ever whole: `select_prefill` walks blocks of
@@ -183,16 +185,19 @@ def select_prefill(qi, w, k, reach, topk: int, qb: int, kb: int):
 @jax.named_scope(scopes.KV_POOL)
 def pool_rows(pool):
     """A paged pool (L, blocks, bs, width) as rows (L, blocks * bs,
-    width), for `gather_selected`.  A pool whose rows are whole lane
+    width), for `gather_selected`: the ``jnp`` decode step's (since PR
+    59 the chip's step walks the pool and gathers no row:
+    models/glm_dsa_decode.py).  A pool whose rows are whole lane
     tiles is read where it lies and this is no operation.  A narrower
     one (a 64-wide rotary key) is stored block-minor on the chip, and a
     gather of rows has the compiler re-lay ALL of it first
     (decode_common.PagedKV has why): asked for ONCE a decode step for
     all layers (the pool is read-only in the step's layer scan), that
-    copy is 1.48 ms a step for the cell's 0.29 GB, where a layer sliced
-    out in every layer was 1.48 + 1.79.  The copy is the compiler's and
-    carries no name (`unscoped_time_share`); written down as a `pad` to
-    whole lane tiles it was 1.4 ms slower and the copy stayed, and as
+    copy was 1.48 ms a step for the GLM-5 cell's 0.29 GB while the chip
+    ran this path, where a layer sliced out in every layer was 1.48 +
+    1.79.  The copy is the compiler's and carries no name
+    (`unscoped_time_share`); written down as a `pad` to whole lane
+    tiles it was 1.4 ms slower and the copy stayed, and as
     `mla_paged_decode.rotary_lanes_reference`'s layers side by side 4.7
     ms slower (my chip runs, PR 58)."""
     L, blocks, bs, width = pool.shape

@@ -12,7 +12,9 @@ attention is
 
 over the slots ``start[b] <= t < pos[b]`` and the row's own new
 position (`fresh`: not in the pool yet, `PagedKV.commit` lands it after
-the layer scan).  Two bodies, one mathematics:
+the layer scan); where a learned indexer has picked which of them a
+row attends (`selected`: models/glm_dsa_decode.py), over those alone,
+the own position among them or not.  Two bodies, one mathematics:
 
   * `mla_paged_decode_reference` -- pure ``jnp``: every row's blocks
     gathered to the dense-equivalent ``(B, max_blk * bs, width)`` views,
@@ -37,6 +39,10 @@ pool's dtype before the weighted sum (as `attend_absorbed` casts them).
 The running softmax starts from the fresh key (maximum = its score,
 sum = 1), so no row is ever empty: a row with ``pos == 0`` walks
 nothing and returns its own new latent, as the masked path does.
+Under a selection that leaves the own position out the row starts with
+no maximum instead, and the first selected slot's shrinks what stood
+in the sums before it to exactly 0 (a selection is never empty: a row
+reaches its own position at least, and takes one of what it reaches).
 
 The rotary keys cannot be read where they lie: a 64-wide pool is stored
 block-minor by the chip's compiler (PERF.md, PR 32), a block's keys are
@@ -53,6 +59,21 @@ step (690 GB/s), the arithmetic alone 3.2, together 4.8; a copy costs
 chunks beat 32 (5.1 against 5.6 ms) though a row's last chunk is
 fetched whole, and 128 lose (5.9); two buffers or four do what three
 do; the rotary keys' re-lay 1.56 ms a step.
+
+And of the walk under a selection (my chip runs, PR 59; GLM-5's cell:
+32 rows of 4,600 to 12,700 positions, 2,048 selected a row, 64 heads,
+5 layers a step over a pool of 28,597 blocks): 2.94 ms a step with the
+mask and 2.93 without (0.59 a layer; 585 GB/s of the 1.72 GB it reads:
+the copies set the pace and the mask's row and its AND hide beside
+them), where XLA's gathers of the 2,048 selected latents and rotary
+keys a row, the block-table lookups of their slots, the 64-wide pool's
+re-laying for them and the attention over the gathered rows took 12.7
+(a gather moves 67 GB/s).  Walking every block to weigh a quarter of
+the positions is the cheaper read while the selected are spread so
+that nearly every block of 16 holds one; skipping the blocks and
+chunks that hold none is for contexts where they do not (ROADMAP.md
+B3a).  The same 13 ns a copy, 64-block chunks and ring of 3 were kept
+as they stood: the step's walk reads what the unmasked one reads alone.
 """
 
 from __future__ import annotations
@@ -75,12 +96,24 @@ _LANES = 128
 _MASKED = -1e30
 
 
+def _own(selected, pos):
+    """Whether each row's own new position, slot ``pos`` of its table,
+    is among `selected` (B, slots): (B,) bool."""
+    at = jnp.minimum(pos, selected.shape[1] - 1)[:, None]
+    return jnp.take_along_axis(selected, at, axis=1)[:, 0] \
+        & (pos < selected.shape[1])
+
+
 def mla_paged_decode_reference(q_lat, q_rope, ckv, kpe, block_tables, pos,
-                               lidx, fresh, *, scale, start=None):
+                               lidx, fresh, *, scale, start=None,
+                               selected=None):
     """q_lat (B, H, c), q_rope (B, H, r); ckv (L, blocks, bs, c) and
     kpe (L, blocks, bs, r) the whole pools, of which layer `lidx`;
     block_tables (B, max_blk), pos (B,), start (B,) or None (zeros);
-    fresh = (ckv_new (B, c), kpe_new (B, r)) -> o_lat (B, H, c)."""
+    fresh = (ckv_new (B, c), kpe_new (B, r)); selected (B, max_blk *
+    bs) bool or None: the slots of the row's table a learned indexer
+    picked, slot ``pos`` (the own new position) among them or not; the
+    others are not attended -> o_lat (B, H, c)."""
     B, nb = block_tables.shape
     bs = ckv.shape[2]
     dt = ckv.dtype
@@ -89,6 +122,9 @@ def mla_paged_decode_reference(q_lat, q_rope, ckv, kpe, block_tables, pos,
     slot = jnp.arange(nb * bs)[None]
     lo = 0 if start is None else start[:, None]
     ok = (slot >= lo) & (slot < pos[:, None])
+    mine = jnp.ones((B, 1), bool)
+    if selected is not None:
+        ok, mine = ok & selected, _own(selected, pos)[:, None]
 
     def scores(c, r):                      # (B, S, width) -> (B, H, S)
         return (jnp.einsum("bhc,bsc->bhs", q_lat, c.astype(dt),
@@ -98,7 +134,9 @@ def mla_paged_decode_reference(q_lat, q_rope, ckv, kpe, block_tables, pos,
 
     s = jnp.concatenate(
         [jnp.where(ok[:, None], scores(cview, rview), _MASKED),
-         scores(fresh[0][:, None], fresh[1][:, None])], axis=-1)
+         jnp.where(mine[:, None], scores(fresh[0][:, None],
+                                         fresh[1][:, None]), _MASKED)],
+        axis=-1)
     probs = jax.nn.softmax(s, axis=-1).astype(dt)
     keys = jnp.concatenate([cview, fresh[0][:, None].astype(dt)], axis=1)
     return jnp.einsum("bhs,bsc->bhc", probs, keys,
@@ -112,7 +150,7 @@ def mla_paged_decode_reference(q_lat, q_rope, ckv, kpe, block_tables, pos,
 def _kernel(base_ref, tab_ref, pos_ref, start_ref, chunks_ref, next_ref,
             qlat_ref, qrope_ref, cnew_ref, rnew_ref, ckv_hbm, kpe_hbm,
             o_ref, cbuf, rbuf, sems, acc_ref, ring_ref, *, nb: int,
-            scale: float):
+            scale: float, own_ref=None, sel_ref=None):
     """One row.  Prefetched scalars: the layer's first block in the
     latent pool and first lane in the rotary rows (2,); the block
     tables, flat, a row of null blocks after the last ((B + 1) * nb,);
@@ -123,7 +161,10 @@ def _kernel(base_ref, tab_ref, pos_ref, start_ref, chunks_ref, next_ref,
     chunk, bs, c) and rotary keys (ring, chunk, bs, r), their DMA
     semaphores (2, ring), the weighted sum (H, c) float32, and the
     ring's state (3,) int32: chunks attended since the call began, the
-    row and chunk the next start is for."""
+    row and chunk the next start is for.  With a selection
+    (`_selected_kernel`): own_ref (B,) int32 prefetched, whether the
+    row's own new position is selected, and sel_ref (1, chunks, chunk *
+    bs) int32 in VMEM, which slots of the row's table are."""
     from jax.experimental.pallas import tpu as pltpu
 
     b, rows = pl.program_id(0), pl.num_programs(0)
@@ -186,6 +227,13 @@ def _kernel(base_ref, tab_ref, pos_ref, start_ref, chunks_ref, next_ref,
                     keepdims=True)) * scale            # (H, 1)
     acc_ref[...] = jnp.broadcast_to(cn.astype(dt).astype(f32),
                                     acc_ref.shape)
+    if sel_ref is not None:
+        # ... where it is selected.  Else the row opens with no maximum
+        # yet, and what stands in the sums before its first selected
+        # slot (the opening's own 1 and latent, exp(0) a masked slot of
+        # the chunks before it) the first real maximum shrinks to
+        # exactly 0: exp(_MASKED - m) is 0.0 in float32
+        m0 = jnp.where(own_ref[b] > 0, m0, _MASKED)
     nt = (((1,), (1,)), ((), ()))                      # a @ b.T
 
     def attend(buf, i, m, l):
@@ -200,7 +248,10 @@ def _kernel(base_ref, tab_ref, pos_ref, start_ref, chunks_ref, next_ref,
              + lax.dot_general(qr, kr, nt, preferred_element_type=f32)
              ) * scale                                 # (H, span)
         slot = i * span + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where((slot >= lo) & (slot < n), s, _MASKED)
+        ok = (slot >= lo) & (slot < n)
+        if sel_ref is not None:
+            ok &= sel_ref[0, pl.ds(i, 1), :] > 0       # (1, span)
+        s = jnp.where(ok, s, _MASKED)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         # m_new >= the fresh key's score: a masked slot's exp is 0
         p = jnp.exp(s - m_new)
@@ -228,6 +279,16 @@ def _kernel(base_ref, tab_ref, pos_ref, start_ref, chunks_ref, next_ref,
             wait(ring_ref[DONE] % ring)
 
 
+def _selected_kernel(base_ref, tab_ref, pos_ref, start_ref, chunks_ref,
+                     next_ref, own_ref, qlat_ref, qrope_ref, cnew_ref,
+                     rnew_ref, sel_ref, *rest, **static):
+    """`_kernel` under a selection: one prefetched vector and one VMEM
+    block more, where a `pallas_call` puts them."""
+    _kernel(base_ref, tab_ref, pos_ref, start_ref, chunks_ref, next_ref,
+            qlat_ref, qrope_ref, cnew_ref, rnew_ref, *rest,
+            own_ref=own_ref, sel_ref=sel_ref, **static)
+
+
 def _check_width(r: int) -> None:
     if _LANES % r and r % _LANES:
         raise ValueError(
@@ -247,10 +308,15 @@ def rotary_lanes_reference(kpe):
 
 def _lanes_kernel(x_ref, o_ref):
     """x (L, bs, r, tile): a tile of blocks as the chip stores the
-    rotary pool, blocks minor-most; o (tile, bs, L * r)."""
-    L, bs = x_ref.shape[:2]
+    rotary pool, blocks minor-most; o (tile, bs, lanes): L * r, and
+    zeros as far as the last lane tile's end where the layers leave it
+    part empty."""
+    L, bs, r, tile = x_ref.shape
+    empty = o_ref.shape[-1] - L * r
+    spare = [jnp.zeros((empty, tile), x_ref.dtype)] if empty else []
     o_ref[...] = jnp.swapaxes(jnp.stack([
-        jnp.concatenate([x_ref[layer, t] for layer in range(L)], axis=0).T
+        jnp.concatenate([x_ref[layer, t] for layer in range(L)] + spare,
+                        axis=0).T
         for t in range(bs)]), 0, 1)
 
 
@@ -264,18 +330,24 @@ def rotary_lanes(kpe, *, interpret: bool = False):
     at a time: one pass, 1.56 ms for the cell's 0.48 GB, where XLA's
     own transposes take two passes and two buffers (3.0 ms; a layer
     sliced out in every layer three passes, 5.2 ms a step; my chip
-    run, PR 33).  Widths its tiles do not fit (a toy configuration)
-    take the reference."""
+    run, PR 33).  Layers that leave the last lane tile part empty (five
+    of 64) get zeros there from the kernel, with no padded copy of the
+    pool before it: 1.06 ms for a five-layer pool of 0.29 GB, where the
+    reference's two passes and pad took 3.02 and a sixth layer of zeros
+    padded on first 2.13 (my chip run, PR 59).  Keys narrower than half
+    a lane tile (a toy configuration) take the reference."""
     L, blocks, bs, r = kpe.shape
-    if (L * r) % _LANES:
+    _check_width(r)
+    if (2 * r) % _LANES:
         return rotary_lanes_reference(kpe)
     tile = min(_LANES, blocks)
+    lanes = -(-L * r // _LANES) * _LANES
     return pl.pallas_call(
         _lanes_kernel,
         grid=(pl.cdiv(blocks, tile),),
         in_specs=[pl.BlockSpec((L, bs, r, tile), lambda i: (0, 0, 0, i))],
-        out_specs=pl.BlockSpec((tile, bs, L * r), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((blocks, bs, L * r), kpe.dtype),
+        out_specs=pl.BlockSpec((tile, bs, lanes), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((blocks, bs, lanes), kpe.dtype),
         interpret=interpret,
         name=scopes.MLA_ROTARY_LANES,
     )(kpe.transpose(0, 2, 3, 1))
@@ -284,10 +356,12 @@ def rotary_lanes(kpe, *, interpret: bool = False):
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def mla_paged_decode(q_lat, q_rope, ckv, kpe_lanes, block_tables, pos,
                      lidx, fresh, *, scale: float, start=None,
-                     interpret: bool = False):
+                     selected=None, interpret: bool = False):
     """`mla_paged_decode_reference`'s contract as one Pallas call, but
     for the rotary keys: `kpe_lanes` is ``rotary_lanes(kpe)``.  `lidx`
-    may be traced (the call sits in a scan over layers).
+    may be traced (the call sits in a scan over layers).  With
+    `selected` the walk is the same walk, every block of the row, under
+    one more mask; without, the program holds the kernel as it was.
     ``interpret=True`` runs the kernel in the Pallas interpreter (the
     CPU tests)."""
     from jax.experimental.pallas import tpu as pltpu
@@ -333,14 +407,30 @@ def mla_paged_decode(q_lat, q_rope, ckv, kpe_lanes, block_tables, pos,
     def new(width):
         return pl.BlockSpec((1, 1, width), lambda b, *_: (b, 0, 0))
 
+    kernel, scalars = _kernel, [jnp.stack([lidx * blocks, lane0]), tables,
+                                pos, start, n_chunks, following]
+    blocked = [(q_lat.astype(dt), row(c)), (tile(q_rope), row(r)),
+               (fresh[0].astype(dt)[:, None], new(c)),
+               (tile(fresh[1])[:, None], new(r))]
+    if selected is not None:
+        # a chunk's slots a row of the block: the walk takes chunk i's
+        # by its index; the own position's flag beside the scalars
+        kernel = _selected_kernel
+        span = chunk * bs
+        picked = jnp.pad(selected, ((0, 0), (0, (wide - nb) * bs)))
+        scalars.append(_own(selected, pos).astype(i32))
+        blocked.append((picked.astype(i32).reshape(B, wide // chunk, span),
+                        pl.BlockSpec((1, wide // chunk, span),
+                                     lambda b, *_: (b, 0, 0))))
+
     return pl.pallas_call(
-        functools.partial(_kernel, nb=wide, scale=scale),
+        functools.partial(kernel, nb=wide, scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=6,
+            num_scalar_prefetch=len(scalars),
             grid=(B,),
-            in_specs=[row(c), row(r), new(c), new(r),
-                      pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
+            in_specs=[spec for _, spec in blocked] + [
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=row(c),
             scratch_shapes=[pltpu.VMEM((_RING, chunk, bs, c), dt),
                             pltpu.VMEM((_RING, chunk, bs, r),
@@ -353,9 +443,7 @@ def mla_paged_decode(q_lat, q_rope, ckv, kpe_lanes, block_tables, pos,
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name=scopes.MLA_PAGED_DECODE,
-    )(jnp.stack([lidx * blocks, lane0]), tables, pos, start, n_chunks,
-      following, q_lat.astype(dt), tile(q_rope),
-      fresh[0].astype(dt)[:, None], tile(fresh[1])[:, None],
+    )(*scalars, *(a for a, _ in blocked),
       ckv.reshape(L * blocks, bs, c), kpe_lanes)
 
 

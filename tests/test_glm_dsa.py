@@ -8,6 +8,7 @@ engine."""
 
 import asyncio
 import collections
+import functools
 import importlib.util
 import os
 
@@ -18,15 +19,18 @@ import pytest
 
 from ray_tpu._private import scopes
 from ray_tpu.models import decode_common as dc
+from ray_tpu.models import experts
 from ray_tpu.models import families
 from ray_tpu.models import glm_dsa as G
 from ray_tpu.models import kimi_k2 as K
+from ray_tpu.models import kimi_k2_decode
 from ray_tpu.models.glm_dsa_decode import (glm_dsa_decode_step,
                                            glm_dsa_generate,
                                            glm_dsa_init_paged_cache,
                                            glm_dsa_paged_prefill,
                                            glm_dsa_prefill)
 from ray_tpu.ops import dsa
+from ray_tpu.ops.mla_paged_decode import mla_paged_decode
 from ray_tpu.serve.llm import SpecConfig, build_llm_deployment
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -199,13 +203,38 @@ def programs(tiny):
             slot=s)))
 
 
+@pytest.fixture(params=["jnp", "kernel"])
+def path(request, monkeypatch):
+    """The paged decode step by its ``jnp`` path (the CPU's), and once
+    more as the chip takes it: the backend test says "tpu" and the walk
+    under the selection's mask (ops/mla_paged_decode.py) runs in the
+    Pallas interpreter, the experts' kernels too."""
+    walked = []
+
+    def walk(*args, selected, **kw):
+        walked.append(selected.shape)
+        return mla_paged_decode(*args, selected=selected, interpret=True,
+                                **kw)
+
+    if request.param == "kernel":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(kimi_k2_decode, "mla_paged_decode", walk)
+        for kernel in ("moe_dispatch", "moe_combine", "_fused"):
+            monkeypatch.setattr(experts, kernel, functools.partial(
+                getattr(experts, kernel), interpret=True))
+    yield request.param
+    assert bool(walked) == (request.param == "kernel")
+
+
 def test_prefill_then_decode_is_the_full_forward_dense_and_paged(
-        tiny, programs):
+        tiny, programs, path):
     """Through the dense cache, and through a pool the dense cache was
     re-laid into: both the full forward's logits at every step, the
     selection's counters equal."""
     cfg, params = tiny
     step, _ = programs
+    if path == "kernel":        # a trace of its own, under the fixture
+        step = jax.jit(lambda c, t: glm_dsa_decode_step(params, c, t, cfg))
     toks = _tokens(5, 2, 72)
     want = _forward(cfg, params, toks)
     lg, dense = glm_dsa_prefill(params, jnp.asarray(toks[:, :40]), cfg)
@@ -283,14 +312,17 @@ def test_generate_is_greedy_under_the_reference(reference, tiny, layout):
     assert np.array_equal(lg[:, 39:].argmax(-1), out[:, 40:])
 
 
-def test_ragged_rows_decode_as_they_would_alone(tiny):
+def test_ragged_rows_decode_as_they_would_alone(tiny, path):
+    """(Through the kernel the batch decodes from a pool: a row's first
+    slot is then past 0, which no engine's row is today.)"""
     cfg, params = tiny
     a, b = _tokens(9, 1, 40)[0], _tokens(10, 1, 29)[0]
     batch = np.zeros((2, 40), np.int32)
     batch[0], batch[1, 11:] = a, b
     both = np.asarray(glm_dsa_generate(
         params, jnp.asarray(batch), cfg, max_new_tokens=5, temperature=0.0,
-        lengths=jnp.asarray([40, 29])))
+        lengths=jnp.asarray([40, 29]),
+        kv_layout="paged" if path == "kernel" else "dense"))
     alone = np.asarray(glm_dsa_generate(
         params, jnp.asarray(b[None]), cfg, max_new_tokens=5,
         temperature=0.0))

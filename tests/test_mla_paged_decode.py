@@ -14,6 +14,8 @@ from ray_tpu.models import decode_common as dc
 from ray_tpu.models import experts
 from ray_tpu.models import kimi_k2 as K
 from ray_tpu.models import kimi_k2_decode as D
+from ray_tpu.models.glm_dsa_decode import _selected_rows
+from ray_tpu.ops import dsa
 from ray_tpu.ops.mla_paged_decode import (mla_paged_decode,
                                           mla_paged_decode_reference,
                                           rotary_lanes,
@@ -168,21 +170,136 @@ def test_the_kernel_honours_a_first_slot():
     assert np.abs(np.asarray(moved) - np.asarray(want)).max() > 1e-3
 
 
+# -- the walk under a selection ------------------------------------------------
+
+#: a pool of 8 blocks makes a chunk 8 blocks (128 slots) and a table of
+#: 20 two chunks and a half: the last is partly past the table.  Rows:
+#: (pos, start); the scores are a case's own
+CHUNK_SLOTS, TABLE = 8 * BS, 20
+SELECTIONS = {
+    # the own position scores lowest in rows 0 and 2, highest in row 1
+    "the_own_position_left_out": ([(200, 0), (300, 0), (130, 0)], 24),
+    # nothing of a row's first chunk (rows 0, 1) or first two (row 2)
+    # is selected, nor its own position: the sums open on exp(0) terms
+    "a_first_chunk_with_no_selected_slot": (
+        [(200, 0), (319, 0), (300, 0)], 24),
+    # the mask is every reachable slot: the walk without a mask
+    "a_context_within_topk": ([(23, 0), (9, 0), (1, 0)], 24),
+    # scores of four values: the last place is shared by dozens
+    "tied_at_the_last_place": ([(200, 0), (300, 0), (77, 0)], 24),
+    "an_idle_row_and_a_first_slot": ([(0, 0), (250, 37), (140, 130)], 24),
+    # rows that reach the table's end, whose last chunk is half null
+    "a_last_chunk_partly_past_the_table": (
+        [(320, 0), (319, 0), (257, 0)], 40),
+}
+
+
+def _scores(case, rows, S, rng):
+    scores = rng.standard_normal((len(rows), S)).astype(np.float32)
+    at = np.arange(len(rows)), [pos for pos, _ in rows]
+    if case == "the_own_position_left_out":
+        scores[at] = [-9.0, 9.0, -9.0]
+    elif case == "a_first_chunk_with_no_selected_slot":
+        scores[:, :CHUNK_SLOTS] = -9.0
+        scores[2, :2 * CHUNK_SLOTS] = -9.0
+        scores[at] = -9.0
+    elif case == "tied_at_the_last_place":
+        scores = rng.integers(0, 4, scores.shape).astype(np.float32)
+    return jnp.asarray(scores)
+
+
+@pytest.fixture(scope="module")
+def pools():
+    """Two layers' pools of 8 blocks, three rows' queries and new rows,
+    a layer's two absorbed products."""
+    cfg = K.kimi_k2_config("nano", dtype=jnp.float32, max_seq=TABLE * BS,
+                           **_OVR)
+    c, r, H = cfg.kv_lora_rank, cfg.qk_rope_dim, cfg.n_head
+    ks = jax.random.split(jax.random.PRNGKey(5), 7)
+    draw = lambda k, *shape: jax.random.normal(k, shape)  # noqa: E731
+    return (cfg, draw(ks[0], 2, 8, BS, c), draw(ks[1], 2, 8, BS, r),
+            draw(ks[2], 3, 1, H, cfg.qk_head_dim),
+            (draw(ks[3], 3, 1, c), draw(ks[4], 3, 1, r)),
+            {"wk_b": draw(ks[5], c, H, cfg.qk_nope_dim) * 0.2,
+             "wv_b": draw(ks[6], c, H, cfg.v_head_dim) * 0.2})
+
+
+@pytest.mark.parametrize("case", SELECTIONS)
+def test_the_walk_under_a_selection_is_the_gathered_selection(case, pools,
+                                                              interpreted):
+    """`mla_paged_decode` with a mask (`dsa.select_mask`) against the
+    ``jnp`` path of a GLM-5 decode step, `dsa.select_top`'s positions
+    gathered by (block table, offset) and attended absorbed, on the
+    same pools and scores: the two forms select the same set, and a
+    slot not selected weighs exactly nothing."""
+    rows, topk = SELECTIONS[case]
+    cfg, ckv, kpe, q, fresh, p = pools
+    rng = np.random.default_rng(5)
+    B, S, lidx = len(rows), TABLE * BS, 1
+    cache = {"block_tables": jnp.asarray(_tables(rng, "in_order", B, TABLE,
+                                                 8), jnp.int32),
+             "pos": jnp.asarray([pos for pos, _ in rows], jnp.int32),
+             "start": jnp.asarray([lo for _, lo in rows], jnp.int32)}
+    scores = _scores(case, rows, S, rng)
+
+    @jax.jit
+    def gathered(cache, scores):
+        ok = dc.slot_mask(cache["start"], cache["pos"] + 1, S)
+        idx, valid = dsa.select_top(scores, ok, topk)
+        own = idx == cache["pos"][:, None]
+        picked = (_selected_rows(dsa.pool_rows(pool), lidx,
+                                 cache["block_tables"], idx, BS, own, new)
+                  for pool, new in zip((ckv, kpe), fresh))
+        return K.attend_absorbed(q, *picked, p, valid[:, None], cfg), \
+            dsa.select_mask(scores, ok, topk), idx, valid, own & valid
+
+    want, mask, idx, valid, own = gathered(cache, scores)
+    # the selected sets of the two forms are one set
+    picked = np.zeros((B, S), bool)
+    np.put_along_axis(picked, np.asarray(idx), np.asarray(valid), axis=1)
+    np.testing.assert_array_equal(np.asarray(mask), picked)
+    if case == "a_first_chunk_with_no_selected_slot":
+        assert not picked[:, :CHUNK_SLOTS].any() and not own.any()
+    walk = functools.partial(D.attend_paged, q, ckv, rotary_lanes(kpe),
+                             cache, lidx, p, fresh, cfg)
+    got = walk(selected=mask)
+    if case == "a_context_within_topk":
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(walk()))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=F32_ATOL)
+    held = mla_paged_decode_reference(
+        jnp.einsum("bhn,chn->bhc", q[:, 0, :, :cfg.qk_nope_dim], p["wk_b"]),
+        q[:, 0, :, cfg.qk_nope_dim:], ckv, kpe, cache["block_tables"],
+        cache["pos"], lidx, (fresh[0][:, 0], fresh[1][:, 0]),
+        scale=K.softmax_scale(cfg), start=cache["start"], selected=mask)
+    np.testing.assert_allclose(
+        np.asarray(jnp.einsum("bhc,chv->bhv", held, p["wv_b"])),
+        np.asarray(want)[:, 0], atol=F32_ATOL)
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 @pytest.mark.parametrize("r,L,blocks,lanes", [
-    (8, 3, 20, 128), (64, 6, 20, 384), (64, 2, 300, 128), (256, 3, 5, 768)],
-    ids=["nano", "published", "a_ragged_last_tile", "whole_tiles"])
+    (8, 3, 20, 128), (64, 6, 20, 384), (64, 2, 300, 128), (256, 3, 5, 768),
+    (64, 5, 20, 384), (64, 1, 130, 128)],
+    ids=["nano", "published", "a_ragged_last_tile", "whole_tiles",
+         "five_layers_leave_half_a_tile", "one_layer_leaves_half_a_tile"])
 def test_rotary_lanes_lays_the_layers_side_by_side(r, L, blocks, lanes,
-                                                   dtype):
+                                                   dtype, monkeypatch):
     """All layers' keys of a position along the lanes, whole lane
-    tiles: the kernel (interpreted) where the widths fit its tiles, 128
-    blocks a grid step and the last tile ragged; the ``jnp`` transposes
-    where they do not.  A width that neither divides a tile nor fills
-    whole ones is refused: no layer's keys would lie in one tile."""
+    tiles, zeros where the layers leave the last one part empty: the
+    kernel (interpreted) where a layer's keys are half a tile or whole
+    ones, 128 blocks a grid step and the last tile ragged; the ``jnp``
+    transposes where they are narrower.  A width that neither divides a
+    tile nor fills whole ones is refused: no layer's keys would lie in
+    one tile."""
     kpe = jax.random.normal(jax.random.PRNGKey(0), (L, blocks, BS, r)
                             ).astype(dtype)
+    if r >= 64:         # through the kernel, not the fallback
+        from ray_tpu.ops import mla_paged_decode as module
+        monkeypatch.setattr(module, "rotary_lanes_reference", None)
     out = rotary_lanes(kpe, interpret=True)
+    monkeypatch.undo()
     assert out.shape == (blocks, BS, lanes) and out.dtype == dtype
     np.testing.assert_array_equal(np.asarray(out, np.float32), np.asarray(
         rotary_lanes_reference(kpe), np.float32))

@@ -99,6 +99,15 @@ def _mosaic_modules(hlo_text: str, kernel: str):
 #: states the new module here, with the cell's numbers beside it
 MLA_PAGED_DECODE_MODULE = (
     "cd1c6ff8e23a570e4c641f494b781e916c786236b02b6b174366774eb0e91eae")
+#: ... and the same body under a selection's mask, in the GLM-5 cell's
+#: decode step since PR 59 (32 rows over tables of 800 blocks, chunks
+#: of 64, a (1, 13, 1,024) int32 block of the mask a row and the own
+#: position's flag prefetched).  The cell with it (my chip runs, PR 59):
+#: 873.2 and 877.3 tokens/s beside the parent's 653.1 and 647.3 on the
+#: same seeds, `decode_step_p50_ms.offline` 16.56, the walk 2.98 ms of
+#: a wave's 13.4 on the device (2.93 alone without the mask)
+MLA_SELECTED_DECODE_MODULE = (
+    "2744aad94193c6b7f95632180738889a35bd1c0470b67d1989b93b80faca3d90")
 
 
 def _on(sharding):
@@ -905,6 +914,70 @@ def test_kimi_k2_programs_fit_the_chip_at_the_published_widths(
                  f"bf16[{slots // 16},16,512]"):
         assert view not in text, view
     assert memory.temp_size_in_bytes < 0.55e9, memory
+
+
+def test_glm5_decode_step_walks_the_pool_under_the_selections_mask(
+        monkeypatch):
+    """The cell glm-5.serve-offline-longdoc's decode step as the engine
+    builds it (benchmark/families/glm_dsa.py aot_serve_programs):
+    published widths, 1 + 4 layers, bf16 weights, 32 slots over a 3 GiB
+    pool of 28,597 blocks, contexts of 12,800.  On the chip (the program
+    asks ``jax.default_backend()``, steered here) its attention is
+    ``mla_paged_decode`` under ``mla``, one call in each scan over
+    layers, with the selection as a mask: the module
+    `MLA_SELECTED_DECODE_MODULE`, not Kimi-K2's.  The rotary keys reach
+    it through ``mla_rotary_lanes`` once a step under ``kv_pool``, five
+    layers and half a lane tile of zeros.  Nothing is gathered by
+    selected position (no result of 32 x 2,048 rows, latents or rotary
+    keys), the selection is the bitwise search (no ``sort`` under
+    ``attn_index``: what ``lax.top_k`` compiles to), and the index
+    scores keep their gathered view of the index keys under
+    ``kv_pool``.  Off the chip the step keeps the gathers and the
+    sort."""
+    from ray_tpu._private import scopes
+    from ray_tpu.models.glm_dsa import glm_dsa_init
+
+    def compiled_text(backend):
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        _, params, cache, programs, n_blocks = _serving_cell(
+            "glm-5.serve-offline-longdoc", glm_dsa_init, 1024)
+        assert n_blocks == 28597 and cache["kpe"].shape == (
+            5, 28597, 16, 64) and cache["block_tables"].shape == (32, 800)
+        fn, args = programs["decode"]
+        compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+            params, cache, *args).compile()
+        return compiled.as_text(), compiled.memory_analysis()
+
+    def moved(text):
+        """(gathers by selected position, sorts under ``attn_index``)"""
+        lines = text.splitlines()
+        return ([ln for ln in lines if re.search(
+                    r"= bf16\[32,2048,(512|64)\]\S* gather\(", ln)],
+                [ln for ln in lines if " sort(" in ln
+                 and f"/{scopes.ATTN_INDEX}/" in ln])
+
+    text, memory = compiled_text("tpu")
+    assert memory.peak_memory_in_bytes < 12.5e9, memory
+    assert memory.alias_size_in_bytes >= 3.22e9      # the pool, in place
+    scoped = scopes.scope_map_from_hlo(text)
+    calls = {name: set(keyed.values()) for name, keyed in scoped.items()
+             if any("custom-call" in key for key in keyed)}
+    walks = [s for name, s in calls.items()
+             if name.startswith(scopes.MLA_PAGED_DECODE)]
+    assert walks == [{scopes.MLA}] * 2, walks
+    assert _mosaic_modules(text, scopes.MLA_PAGED_DECODE) == [
+        MLA_SELECTED_DECODE_MODULE] * 2
+    lanes = [s for name, s in calls.items()
+             if name.startswith(scopes.MLA_ROTARY_LANES)]
+    assert lanes == [{scopes.KV_POOL}], lanes
+    assert "bf16[28597,16,384]" in text              # 5 x 64 and zeros
+    assert moved(text) == ([], [])
+    views = [ln for ln in text.splitlines() if re.search(
+        r"= bf16\[32,800,16,128\]\S* gather\(", ln)]
+    assert len(views) == 2 and all(
+        f"/{scopes.KV_POOL}/" in ln for ln in views), views
+    gathers, sorts = moved(compiled_text("cpu")[0])
+    assert len(gathers) == 4 and len(sorts) == 2, (gathers, sorts)
 
 
 @pytest.mark.parametrize("program,t_pad", [("decode", 0), ("prefill", 8192)])
