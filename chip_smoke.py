@@ -381,7 +381,7 @@ def check_kernels(size: Size, *, interpret: bool = False) -> None:
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.models.gpt2 import nll_from_logits
+    from ray_tpu.models.layers import nll_from_logits
     from ray_tpu.ops.fused_ce import fused_lm_ce
 
     for shape in size.attn_shapes:
@@ -1410,7 +1410,7 @@ def check_ring_kernels(size: Size, *, interpret: bool = False) -> None:
     import numpy as np
     from jax import lax
 
-    from ray_tpu.models.laguna_decode import _ring_mask, attend_rows
+    from ray_tpu.models.banded_attention import _ring_mask, attend_rows
     from ray_tpu.ops.ring_decode import ring_decode
 
     for n, B, W, H, n_kv, hd, scale in size.ring_waves:
@@ -1488,7 +1488,7 @@ def phase_ring(size: Size, platform: str = "tpu") -> Dict[str, Any]:
 
 
 def check_banded_kernels(size: Size, *, interpret: bool = False) -> None:
-    """ops/banded_flash.py against `laguna_decode.banded_walk`, the
+    """ops/banded_flash.py against `banded_attention.banded_walk`, the
     `jnp` walk it replaces on the chip: each layer geometry of
     `size.banded_layers` at each of its buckets, a 27th of the columns
     pads at the left, bf16 operands as the cells run them."""
@@ -1498,7 +1498,7 @@ def check_banded_kernels(size: Size, *, interpret: bool = False) -> None:
     import jax.numpy as jnp
     import numpy as np
 
-    from ray_tpu.models.laguna_decode import banded_walk, prefill_reach
+    from ray_tpu.models.banded_attention import banded_walk, prefill_reach
     from ray_tpu.ops.banded_flash import banded_flash
 
     hd, dt = 128, jnp.bfloat16

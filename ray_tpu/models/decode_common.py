@@ -111,6 +111,103 @@ STATE_FROM_ZERO, STATE_FROM_SLOT = -1, -2
 NO_SNAPSHOT = -1
 
 
+# -- a recurrent family's per-slot state, a layer or a slot of it at a time --
+#
+# ``conv`` (layers, K-1, B, width) and ``ssm`` (layers, B, ...): the
+# layer is axis 0 of both, the slot axis 2 of ``conv`` and axis 1 of
+# ``ssm``; the snapshot pool has the same axes, an entry where the slot
+# is.  `scope` is the family's name for its state in a profile
+# (scopes.SSM_STATE, scopes.LINEAR_STATE): every accessor runs under it.
+
+def layer_state(scope: str, conv, ssm, j):
+    """Every row's (window, state) of recurrent layer `j`."""
+    with jax.named_scope(scope):
+        return (lax.dynamic_index_in_dim(conv, j, 0, keepdims=False),
+                lax.dynamic_index_in_dim(ssm, j, 0, keepdims=False))
+
+
+def set_layer_state(scope: str, conv, ssm, j, window, state):
+    with jax.named_scope(scope):
+        return (lax.dynamic_update_index_in_dim(conv, window, j, 0),
+                lax.dynamic_update_index_in_dim(ssm, state, j, 0))
+
+
+def layer_window(scope: str, conv, j):
+    """Every row's window of recurrent layer `j`."""
+    with jax.named_scope(scope):
+        return lax.dynamic_index_in_dim(conv, j, 0, keepdims=False)
+
+
+def set_layer_window(scope: str, conv, j, window):
+    with jax.named_scope(scope):
+        return lax.dynamic_update_index_in_dim(conv, window, j, 0)
+
+
+def slot_rows(scope: str, conv, ssm, row):
+    """Row `row`'s (windows, states) of every recurrent layer: (layers,
+    K-1, 1, width), (layers, 1, ...)."""
+    with jax.named_scope(scope):
+        return (lax.dynamic_slice_in_dim(conv, row, 1, axis=2),
+                lax.dynamic_slice_in_dim(ssm, row, 1, axis=1))
+
+
+def land_rows(scope: str, conv, ssm, row, windows, states):
+    with jax.named_scope(scope):
+        return (lax.dynamic_update_slice_in_dim(conv, windows, row, 2),
+                lax.dynamic_update_slice_in_dim(ssm, states, row, 1))
+
+
+def stacked(pairs):
+    """A list [(window, state) a layer] -> (windows, states) stacked
+    over the layers; a TUPLE is a pair of stacks already and is
+    itself (a family whose walk carries the stacks)."""
+    if isinstance(pairs, tuple):
+        return pairs
+    return tuple(jnp.stack(part) for part in zip(*pairs))
+
+
+def begin_rows(scope: str, cache, slot, source):
+    """The (windows, states) a paged prefill's slot starts from, every
+    recurrent layer's, by ``state[0]`` = `source`: zeros
+    (`STATE_FROM_ZERO`), the slot's own rows (`STATE_FROM_SLOT`: the
+    previous chunk of this prompt left them) or snapshot entry ``source
+    >= 0``.  The slot's rows leave the big state ONCE, here, before the
+    walk, and go back once after it (`leave_rows`), as `PagedKV` lands a
+    decode step's rows: the walk carries the slot's own (layers, ...)
+    rows and the snapshot's, a few MB.  A row written into the carried
+    (layers, slots, ...) state layer by layer made the compiler copy
+    the whole state every layer (1.4 ms each on the chip; PERF.md, PR
+    28)."""
+    own = slot_rows(scope, cache["conv"], cache["ssm"], slot)
+    held = slot_rows(scope, cache[_SNAP + "conv"], cache[_SNAP + "ssm"],
+                     jnp.maximum(source, 0))
+    with jax.named_scope(scope):
+        return tuple(
+            jnp.where(source >= 0, h,
+                      jnp.where(source == STATE_FROM_SLOT, o,
+                                jnp.zeros_like(o)))
+            for o, h in zip(own, held))
+
+
+def leave_rows(scope: str, cache, slot, ends, entry, keep, snaps):
+    """What a paged prefill leaves of the recurrent state, as the four
+    cache entries it writes: `ends` in row `slot`, and `snaps` in
+    snapshot entry `keep` (``max(entry, 0)``) where ``state[1]`` =
+    `entry` names one; without a snapshot to leave, entry `keep` gets
+    back what it has.  `ends`, `snaps`: as `stacked` takes them."""
+    out = {}
+    out["conv"], out["ssm"] = land_rows(
+        scope, cache["conv"], cache["ssm"], slot, *stacked(ends))
+    pool = cache[_SNAP + "conv"], cache[_SNAP + "ssm"]
+    kept = slot_rows(scope, *pool, keep)
+    with jax.named_scope(scope):
+        left = tuple(jnp.where(entry >= 0, new, old)
+                     for new, old in zip(stacked(snaps), kept))
+    out[_SNAP + "conv"], out[_SNAP + "ssm"] = land_rows(
+        scope, *pool, keep, *left)
+    return out
+
+
 @dataclasses.dataclass(frozen=True)
 class SamplingParams:
     """Jit-static sampling knobs (round 11).
@@ -625,12 +722,15 @@ def program_counters(cache):
             if key in cache} or None
 
 
-def _positions(batch: int):
-    """What a fresh cache of a family with expert layers holds beside
-    its tensors: the position vectors and the counters, all zeros."""
-    return {"pos": jnp.zeros((batch,), jnp.int32),
-            "start": jnp.zeros((batch,), jnp.int32),
-            EXPERTS: jnp.zeros((len(EXPERT_COUNTERS),), jnp.float32)}
+def _positions(batch: int, experts: bool = True):
+    """What a fresh cache holds beside its tensors, all zeros: the
+    position vectors and, for a family with expert layers, the
+    counters."""
+    held = {"pos": jnp.zeros((batch,), jnp.int32),
+            "start": jnp.zeros((batch,), jnp.int32)}
+    if experts:
+        held[EXPERTS] = jnp.zeros((len(EXPERT_COUNTERS),), jnp.float32)
+    return held
 
 
 def _refuse_mesh(family: str, mesh) -> None:

@@ -1,9 +1,29 @@
 """The decoder families a serving engine can be built over: one row each.
 
 A family is `models/<family>.py` (config, init, forward), its decode
-programs in `models/<family>_decode.py` (for a family whose cache is
-plain K/V: its `kv_decode.Block` and kv_decode.py's programs bound to
-it), and a row of `FAMILIES` below.
+programs in `models/<family>_decode.py`, and a row of `FAMILIES` below.
+The decode file is a BLOCK and the bindings of a shared decoder's
+programs to it, for a family whose cache is plain K/V (`kv_decode.Block`
+over kv_decode.py: gpt2, llama) or K/V beside a matrix state a head
+(`delta_decode.Block` over delta_decode.py: solar_open2, olmo_hybrid);
+or the family's own five programs (jamba, kimi_k2, laguna, phi4flash,
+glm_dsa).  A family's two files import each other and the shared
+modules, never another family's files (tests/test_engine_seam.py; the
+one edge left is glm_dsa's on kimi_k2, until latent attention has a
+module of its own).  What two families call lives in a module named
+for what it is:
+
+  layers.py            norms, embedding and head, gated MLP, rotary
+                       pairs and YaRN, the forward's cross-entropy
+  mamba.py             the Mamba mixer and the convolutions' inputs
+  banded_attention.py  grouped-query attention over folded K/V: the
+                       whole score matrix, a prefill's banded walk, a
+                       window layer's ring, a decode column over the
+                       paged pool (and the one choice of its kernel)
+  experts.py           the expert layer and its counters
+  decode_common.py     the cache's format and operations, per-slot
+                       state by layer and by slot, sampling, `generator`
+
 The serving layer (serve/llm.py, serve/engine.py) asks `family(name)`
 for the programs and `cache_kind(name)` for what the cache holds, and
 names no family itself.
@@ -21,7 +41,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 #: which every engine feature can move (rewind by position, spill and
 #: restore by block, hand off).  RECURRENT: some layers keep one state
 #: per sequence beside the K/V (models/jamba_decode.py: a vector a
-#: channel; models/solar_open2_decode.py, models/olmo_hybrid_decode.py:
+#: channel; models/delta_decode.py, for solar_open2 and olmo_hybrid:
 #: a matrix a head), and the paged prefill takes one more argument,
 #: `state` (decode_common.py: where the slot's state starts and which
 #: snapshot it leaves); what cannot carry that state yet is refused
@@ -34,7 +54,8 @@ from typing import Any, Callable, Dict, Optional, Tuple
 #: verify program, or splits a heads axis is refused for it.
 #: WINDOWED: some layers attend a bounded window and keep, per slot, a
 #: ring of their last K/V rows beside the pool of the layers that
-#: attend everything (models/laguna_decode.py): per-slot state as a
+#: attend everything (models/laguna_decode.py, over
+#: models/banded_attention.py): per-slot state as a
 #: recurrent layer's is, carried by the same `state` argument and the
 #: same snapshots, and refused where that is.
 #: RECURRENT_WINDOWED: both kinds of per-slot state in one slot, a

@@ -76,7 +76,7 @@ from jax import lax
 
 from ray_tpu._private import scopes
 from ray_tpu.models import kimi_k2 as K
-from ray_tpu.models.gpt2 import _layernorm
+from ray_tpu.models.layers import embed, layernorm, lm_logits, rotate
 from ray_tpu.ops import dsa
 from ray_tpu.parallel.sharding import (DEFAULT_RULES,
                                        with_logical_constraint)
@@ -206,12 +206,12 @@ def index_project(u, cq, p, cfg: GlmDsaConfig, cos, sin):
     u = u.astype(dt)
 
     def rotated(x, c, s):
-        return jnp.concatenate([K.rotate(x[..., :r], c, s), x[..., r:]],
+        return jnp.concatenate([rotate(x[..., :r], c, s), x[..., r:]],
                                axis=-1)
 
     qi = rotated((cq.astype(dt) @ p["wq"].astype(dt)).reshape(B, T, J, D),
                  cos[:, :, None], sin[:, :, None])
-    kidx = rotated(_layernorm(u @ p["wk"].astype(dt), p["k_norm"]["scale"],
+    kidx = rotated(layernorm(u @ p["wk"].astype(dt), p["k_norm"]["scale"],
                               p["k_norm"]["bias"], cfg.index_eps), cos, sin)
     w = jnp.einsum("btd,dj->btj", u.astype(jnp.float32),
                    p["ww"].astype(jnp.float32),
@@ -246,7 +246,7 @@ def glm_dsa_hidden(params, tokens, cfg: GlmDsaConfig, rules=DEFAULT_RULES):
     positions = jnp.arange(T, dtype=jnp.int32)[None]
     causal = jnp.broadcast_to(jnp.tril(jnp.ones((T, T), bool))[None],
                               (B, T, T))
-    x = with_logical_constraint(K.embed(params, tokens, cfg),
+    x = with_logical_constraint(embed(params, tokens, cfg),
                                 ("batch", "seq", "embed"), rules)
 
     def layer(x, carry, p, lidx):
@@ -267,7 +267,7 @@ def glm_dsa_forward(params, tokens, cfg: GlmDsaConfig,
                     rules=DEFAULT_RULES) -> jnp.ndarray:
     """tokens (B, T) int32 -> logits (B, T, padded_vocab) float32."""
     hidden, _ = glm_dsa_hidden(params, tokens, cfg, rules)
-    return with_logical_constraint(K.lm_logits(hidden, params, cfg),
+    return with_logical_constraint(lm_logits(hidden, params, cfg),
                                    ("batch", "seq", "vocab"), rules)
 
 

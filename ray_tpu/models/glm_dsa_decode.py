@@ -61,9 +61,10 @@ from ray_tpu.models.decode_common import (INDEX, INDEX_COUNTERS, PagedKV,
                                           generator, is_paged, slot_mask)
 from ray_tpu.models.experts import _with_counters
 from ray_tpu.models.glm_dsa import GlmDsaConfig, block, selection
-from ray_tpu.models.kimi_k2 import (attend_absorbed, attend_expanded, embed,
-                                    lm_logits, walk_layers)
+from ray_tpu.models.kimi_k2 import (attend_absorbed, attend_expanded,
+                                    walk_layers)
 from ray_tpu.models.kimi_k2_decode import attend_blockwise, attend_paged
+from ray_tpu.models.layers import embed, lm_logits
 from ray_tpu.ops import dsa
 from ray_tpu.ops.mla_paged_decode import rotary_lanes
 

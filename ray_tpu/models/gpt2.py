@@ -31,43 +31,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu._private import scopes
+from ray_tpu.models.layers import (ce_config_problems, layernorm,
+                                   lm_head_nll, nll_from_logits)
 from ray_tpu.parallel.sharding import DEFAULT_RULES, with_logical_constraint
-
-#: The three lm-head + cross-entropy implementations (GPT2Config.ce_impl):
-#: "dense" materializes f32 (B,T,V) logits; "streaming_xla" is the
-#: lax.scan vocab-tile path (ops/vocab_ce.py); "pallas" is the fused
-#: MXU-streamed kernel (ops/fused_ce.py) — no (B,T,V) buffer in either
-#: pass.  Which wins on the chip is ROADMAP.md A2 (measure once, keep one).
-CE_IMPLS = ("dense", "streaming_xla", "pallas")
-FLASH_RESIDENT_MODES = ("auto", "on", "off")
-
-
-def ce_config_problems(ce_impl: str, flash_resident: str, *,
-                       loss_chunks: int = 1,
-                       seq_parallel: bool = False) -> list:
-    """Validation shared by GPT2Config/LlamaConfig: returns a list of
-    human-readable problems with the CE/attention knob combination (empty
-    when valid).  Callers join the list into ONE coherent ValueError so
-    an invalid config reports every conflict at once instead of the
-    first scattered check to trip."""
-    problems = []
-    if ce_impl not in CE_IMPLS:
-        problems.append(f"ce_impl must be one of {CE_IMPLS} "
-                        f"(got {ce_impl!r})")
-    else:
-        if ce_impl != "dense" and loss_chunks > 1:
-            problems.append(
-                f"loss_chunks={loss_chunks} requires ce_impl='dense' "
-                f"(both bound the logits footprint; pick one)")
-        if ce_impl != "dense" and seq_parallel:
-            problems.append(
-                f"ce_impl={ce_impl!r} needs an unsharded seq axis (the "
-                f"(B,T)->(B*T) flatten would reshard under seq "
-                f"parallelism)")
-    if flash_resident not in FLASH_RESIDENT_MODES:
-        problems.append(f"flash_resident must be one of "
-                        f"{FLASH_RESIDENT_MODES} (got {flash_resident!r})")
-    return problems
 
 
 @dataclasses.dataclass(frozen=True)
@@ -280,15 +246,6 @@ def gpt2_init(key, cfg: GPT2Config) -> Dict[str, Any]:
 # Forward
 # ---------------------------------------------------------------------------
 
-@jax.named_scope(scopes.LN)
-def _layernorm(x, scale, bias, eps=1e-5):
-    # LN in float32 for stability, cast back to compute dtype.
-    xf = x.astype(jnp.float32)
-    mu = jnp.mean(xf, axis=-1, keepdims=True)
-    var = jnp.mean(jnp.square(xf - mu), axis=-1, keepdims=True)
-    y = (xf - mu) * lax.rsqrt(var + eps)
-    return (y * scale + bias).astype(x.dtype)
-
 
 def _heads_axis_sharded(rules) -> bool:
     """True when the active mesh shards the "heads" logical axis (tensor
@@ -398,9 +355,9 @@ def _block(x, layer_params, cfg: GPT2Config, rules):
     """Returns (x, moe_aux_loss) — aux is 0.0 for dense blocks."""
     p = layer_params
     x = x + _attention(
-        _layernorm(x, p["ln1"]["scale"], p["ln1"]["bias"]), p["attn"], cfg,
+        layernorm(x, p["ln1"]["scale"], p["ln1"]["bias"]), p["attn"], cfg,
         rules)
-    xm = _layernorm(x, p["ln2"]["scale"], p["ln2"]["bias"])
+    xm = layernorm(x, p["ln2"]["scale"], p["ln2"]["bias"])
     if cfg.n_experts:
         from ray_tpu.models.moe import moe_apply
 
@@ -465,14 +422,14 @@ def gpt2_hidden(params, tokens, cfg: GPT2Config,
         # the MLP, dots-level speed for attention.
         def attn_half(x, p):
             return x + _attention(
-                _layernorm(x, p["ln1"]["scale"], p["ln1"]["bias"]),
+                layernorm(x, p["ln1"]["scale"], p["ln1"]["bias"]),
                 p["attn"], cfg, rules)
 
         @partial(jax.checkpoint,
                  policy=jax.checkpoint_policies.nothing_saveable)
         def mlp_half(x, p):
             return x + _mlp(
-                _layernorm(x, p["ln2"]["scale"], p["ln2"]["bias"]),
+                layernorm(x, p["ln2"]["scale"], p["ln2"]["bias"]),
                 p["mlp"], cfg, rules)
 
         def scan_body(carry, layer_params):
@@ -484,7 +441,7 @@ def gpt2_hidden(params, tokens, cfg: GPT2Config,
 
         x, _ = lax.scan(scan_body, x, params["blocks"],
                         unroll=cfg.scan_unroll)
-        out = _layernorm(x, params["ln_f"]["scale"],
+        out = layernorm(x, params["ln_f"]["scale"],
                          params["ln_f"]["bias"])
         return (out, jnp.float32(0.0)) if return_aux else out
 
@@ -508,7 +465,7 @@ def gpt2_hidden(params, tokens, cfg: GPT2Config,
 
     x, auxes = lax.scan(scan_body, x, params["blocks"],
                         unroll=cfg.scan_unroll)
-    out = _layernorm(x, params["ln_f"]["scale"], params["ln_f"]["bias"])
+    out = layernorm(x, params["ln_f"]["scale"], params["ln_f"]["bias"])
     return (out, jnp.sum(auxes)) if return_aux else out
 
 
@@ -529,30 +486,6 @@ def gpt2_forward(params, tokens, cfg: GPT2Config,
     """tokens (B, T) int32 → logits (B, T, padded_vocab) float32."""
     x = gpt2_hidden(params, tokens, cfg, rules)
     return _tied_logits(x, params["wte"], cfg, rules)
-
-
-@jax.named_scope(scopes.LM_HEAD_CE)
-def nll_from_logits(logits, targets, vocab_size: int,
-                    padded_vocab: int):
-    """Per-token negative log likelihood with the padded-vocab tail masked.
-
-    Gather-free formulation: ``nll = logsumexp(logits) - logits[target]``
-    with the target pick as a masked reduction over an iota comparison.
-    A ``take_along_axis`` gather along a TENSOR-SHARDED vocab axis makes
-    the SPMD partitioner replicate the full (B,T,V) float32 logits; the
-    where/iota form partitions cleanly (local reduce + cross-shard sum),
-    and XLA fuses the comparison into the reduction so nothing V-sized
-    materializes beyond the logits themselves."""
-    vocab_iota = lax.broadcasted_iota(jnp.int32, logits.shape,
-                                      logits.ndim - 1)
-    if padded_vocab != vocab_size:
-        logits = jnp.where(vocab_iota < vocab_size, logits,
-                           jnp.asarray(-1e9, logits.dtype))
-    lse = jax.scipy.special.logsumexp(logits, axis=-1)
-    target_logit = jnp.sum(
-        jnp.where(vocab_iota == targets[..., None], logits, 0),
-        axis=-1)
-    return lse - target_logit
 
 
 def _nll_from_logits(logits, targets, cfg):
@@ -588,42 +521,6 @@ def _chunked_ce(hidden, wte, targets, mask, cfg: GPT2Config):
     (total, count), _ = lax.scan(
         body, (jnp.float32(0.0), jnp.float32(0.0)), (hs, ts, ms))
     return total / jnp.maximum(count, 1.0)
-
-
-@jax.named_scope(scopes.LM_HEAD_CE)
-def lm_head_nll(hidden, w_vocab_major, targets, cfg) -> jnp.ndarray:
-    """Per-token nll via the non-dense CE impls, shared by gpt2 and
-    llama.  hidden (B, T, D); w_vocab_major (V, D) — tied wte, or a
-    transposed lm_head for untied models; targets (B, T) int32.  cfg is
-    any config carrying ce_impl / vocab_size / vocab_tile / ce_block_n /
-    ce_block_v / dtype / padded_vocab.  Returns (B, T) float32."""
-    B, T = targets.shape
-    h2 = hidden.reshape(B * T, -1)
-    t1 = targets.reshape(-1).astype(jnp.int32)
-    if cfg.ce_impl == "pallas":
-        from ray_tpu.ops.fused_ce import fused_lm_ce
-        from ray_tpu.parallel.mesh import active_mesh
-
-        mesh = active_mesh()
-        if mesh is not None and mesh.size > 1:
-            # GSPMD cannot partition a Mosaic kernel, and the fused CE
-            # has no shard_map form (its vocab stream would have to
-            # cross the tensor axis): refuse rather than degrade.
-            raise NotImplementedError(
-                f"ce_impl='pallas' runs on one device; under a "
-                f"{mesh.size}-device mesh use ce_impl='dense' or "
-                f"'streaming_xla'")
-        nll = fused_lm_ce(h2, w_vocab_major, t1, cfg.vocab_size,
-                          block_n=cfg.ce_block_n,
-                          block_v=min(cfg.ce_block_v, cfg.padded_vocab),
-                          compute_dtype=cfg.dtype)
-    else:
-        from ray_tpu.ops.vocab_ce import streaming_ce
-
-        nll = streaming_ce(h2, w_vocab_major, t1, cfg.vocab_size,
-                           min(cfg.vocab_tile, cfg.padded_vocab),
-                           cfg.dtype)
-    return nll.reshape(B, T)
 
 
 def gpt2_loss(params, batch, cfg: GPT2Config,

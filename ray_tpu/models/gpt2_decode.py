@@ -21,7 +21,8 @@ import jax.numpy as jnp
 from ray_tpu._private import scopes
 from ray_tpu.models import kv_decode
 from ray_tpu.models.decode_common import generator
-from ray_tpu.models.gpt2 import GPT2Config, _layernorm
+from ray_tpu.models.gpt2 import GPT2Config
+from ray_tpu.models.layers import layernorm
 
 __all__ = ["init_cache", "init_paged_cache", "prefill", "paged_prefill",
            "decode_step", "verify_step", "generate"]
@@ -45,7 +46,7 @@ def _place(x, params, pos_ids, cfg: GPT2Config):
 
 def _qkv(x, p, cfg: GPT2Config, positions):
     d, h, hd = cfg.d_model, cfg.n_head, cfg.head_dim
-    xa = _layernorm(x, p["ln1"]["scale"], p["ln1"]["bias"])
+    xa = layernorm(x, p["ln1"]["scale"], p["ln1"]["bias"])
     with jax.named_scope(scopes.ATTN):
         w = p["attn"]["qkv_w"].astype(cfg.dtype).reshape(d, 3 * h * hd)
         qkv = (xa @ w).reshape(*x.shape[:-1], 3, h, hd) \
@@ -80,12 +81,12 @@ def _mix(x, o, p, cfg: GPT2Config):
         wo = p["attn"]["o_w"].astype(cfg.dtype).reshape(h * hd, d)
         x = x + (o.reshape(*x.shape[:-1], h * hd) @ wo
                  + p["attn"]["o_b"].astype(cfg.dtype))
-    return x + _mlp(_layernorm(x, p["ln2"]["scale"], p["ln2"]["bias"]),
+    return x + _mlp(layernorm(x, p["ln2"]["scale"], p["ln2"]["bias"]),
                     p["mlp"], cfg)
 
 
 def _norm_f(x, params, cfg: GPT2Config):
-    return _layernorm(x, params["ln_f"]["scale"], params["ln_f"]["bias"])
+    return layernorm(x, params["ln_f"]["scale"], params["ln_f"]["bias"])
 
 
 @jax.named_scope(scopes.LM_HEAD)

@@ -28,12 +28,13 @@ What "a row's past" means for a recurrent layer:
   * a prefill sets its slot's state from what its `state` argument
     names (zeros, a snapshot entry, or the slot's own state after the
     previous chunk), never from the previous tenant's, and walks it
-    through the real columns only (jamba.mamba_mix: a pad moves
+    through the real columns only (mamba.mamba_mix: a pad moves
     nothing).
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Dict, Optional, Tuple
 
@@ -42,13 +43,15 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu._private import scopes
-from ray_tpu.models.decode_common import (NO_SNAPSHOT, STATE_FROM_SLOT,
-                                          STATE_FROM_ZERO, PagedKV,
-                                          _refuse_mesh, dense_layer_kv,
-                                          generator, is_paged, slot_mask)
+from ray_tpu.models.decode_common import (NO_SNAPSHOT, STATE_FROM_ZERO,
+                                          PagedKV, _refuse_mesh, begin_rows,
+                                          dense_layer_kv, generator,
+                                          is_paged, layer_state, leave_rows,
+                                          set_layer_state, slot_mask)
 from ray_tpu.models.jamba import (JambaConfig, attn_out, embed, layer_at,
-                                  lm_logits, mamba_mix, mlp_residual, qkv,
+                                  lm_logits, mlp_residual, qkv,
                                   rmsnorm, walk_layers, zero_recurrent)
+from ray_tpu.models.mamba import mamba_mix
 
 __all__ = ["jamba_init_cache", "jamba_init_paged_cache", "jamba_prefill",
            "jamba_paged_prefill", "jamba_decode_step", "jamba_generate"]
@@ -93,17 +96,10 @@ def jamba_init_paged_cache(cfg: JambaConfig, batch: int, *,
 
 # -- the recurrent state, one layer of it at a time --------------------------
 
-@jax.named_scope(scopes.SSM_STATE)
-def _layer_state(conv, ssm, m):
-    """Every row's (window, state) of Mamba layer `m`."""
-    return (lax.dynamic_index_in_dim(conv, m, 0, keepdims=False),
-            lax.dynamic_index_in_dim(ssm, m, 0, keepdims=False))
-
-
-@jax.named_scope(scopes.SSM_STATE)
-def _set_layer_state(conv, ssm, m, window, state):
-    return (lax.dynamic_update_index_in_dim(conv, window, m, 0),
-            lax.dynamic_update_index_in_dim(ssm, state, m, 0))
+#: every row's (window, state) of a Mamba layer, read and written
+#: (decode_common.py has the cache's axes), under this family's scope
+_layer_state = functools.partial(layer_state, scopes.SSM_STATE)
+_set_layer_state = functools.partial(set_layer_state, scopes.SSM_STATE)
 
 
 # -- attention over a cache view --------------------------------------------
@@ -216,25 +212,9 @@ def jamba_paged_prefill(params, cache, tokens: jnp.ndarray,
     keep = jnp.maximum(entry, 0)
     x = embed(params, tokens, cfg)
 
-    # The slot's rows leave the big state ONCE, before the walk, and go
-    # back once after it (as PagedKV lands a decode step's rows): the
-    # walk carries the slot's own (n_mamba, ...) rows and the snapshot's,
-    # a few MB.  A row written into the carried (n_mamba, slots, ...)
-    # state layer by layer made the compiler copy the whole state every
-    # layer (1.4 ms each on the chip; PERF.md, PR 28).
-    with jax.named_scope(scopes.SSM_STATE):
-        def rows(conv, ssm, row):
-            return (lax.dynamic_slice_in_dim(conv, row, 1, axis=2),
-                    lax.dynamic_slice_in_dim(ssm, row, 1, axis=1))
-
-        own = rows(cache["conv"], cache["ssm"], slot)
-        held = rows(cache["snap_conv"], cache["snap_ssm"],
-                    jnp.maximum(source, 0))
-        begin = tuple(
-            jnp.where(source >= 0, h,
-                      jnp.where(source == STATE_FROM_SLOT, o,
-                                jnp.zeros_like(o)))
-            for o, h in zip(own, held))
+    # the slot's rows leave the big state once, before the walk, and go
+    # back once after it (decode_common.begin_rows has why)
+    begin = begin_rows(scopes.SSM_STATE, cache, slot, source)
 
     def mamba_layer(x, carry, m):
         pools, rec, snaps = carry
@@ -260,19 +240,8 @@ def jamba_paged_prefill(params, cache, tokens: jnp.ndarray,
         cfg, x, (pkv.pools, begin, begin), mamba_layer, attn_layer)
     logits = lm_logits(x[0, -1], params, cfg)   # right-aligned: last real
     out = pkv.commit(pools, new_k, new_v)
-    with jax.named_scope(scopes.SSM_STATE):
-        def land(conv, ssm, row, window, state):
-            return (lax.dynamic_update_slice_in_dim(conv, window, row, 2),
-                    lax.dynamic_update_slice_in_dim(ssm, state, row, 1))
-
-        out["conv"], out["ssm"] = land(cache["conv"], cache["ssm"], slot,
-                                       *rec)
-        # without a snapshot to leave, entry `keep` gets back what it has
-        kept = rows(cache["snap_conv"], cache["snap_ssm"], keep)
-        out["snap_conv"], out["snap_ssm"] = land(
-            cache["snap_conv"], cache["snap_ssm"], keep,
-            *(jnp.where(entry >= 0, new, old)
-              for new, old in zip(snaps, kept)))
+    out.update(leave_rows(scopes.SSM_STATE, cache, slot, rec, entry, keep,
+                          snaps))
     out["block_tables"] = cache["block_tables"].at[slot].set(row_bt)
     out["pos"] = cache["pos"].at[slot].set(prefix_len + n_tail)
     out["start"] = cache["start"].at[slot].set(0)

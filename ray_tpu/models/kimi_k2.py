@@ -45,13 +45,13 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 
 from ray_tpu._private import scopes
 from ray_tpu.models import experts as ex
-from ray_tpu.models.gpt2 import nll_from_logits
-from ray_tpu.models.llama import _rmsnorm
+from ray_tpu.models.layers import (embed, lm_logits, nll_from_logits,
+                                   plain_rmsnorm, rmsnorm, rotate, swiglu,
+                                   yarn_inv_freq)
 from ray_tpu.parallel.sharding import (DEFAULT_RULES,
                                        with_logical_constraint)
 
@@ -272,36 +272,6 @@ def _mscale(factor: float, m: float) -> float:
     return 0.1 * m * math.log(factor) + 1.0 if factor > 1 else 1.0
 
 
-def yarn_correction_range(cfg: KimiK2Config) -> Tuple[int, int]:
-    """DeepSeek-V3's ``yarn_find_correction_range``: the rotary pairs
-    between which the frequencies pass from kept to divided by
-    ``rope_factor``; [8, 20] for the published numbers."""
-    dim = cfg.qk_rope_dim
-
-    def pair_of(rotations):
-        return dim * math.log(cfg.rope_orig_max
-                              / (rotations * 2 * math.pi)) \
-            / (2 * math.log(cfg.rope_theta))
-
-    low = max(math.floor(pair_of(cfg.beta_fast)), 0)
-    high = min(math.ceil(pair_of(cfg.beta_slow)), dim - 1)
-    return low, high
-
-
-def yarn_inv_freq(cfg: KimiK2Config) -> np.ndarray:
-    """(qk_rope_dim / 2,) float32 inverse frequencies."""
-    dim = cfg.qk_rope_dim
-    f = cfg.rope_theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
-    if cfg.rope_factor <= 1:
-        return f.astype(np.float32)
-    low, high = yarn_correction_range(cfg)
-    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
-                   / max(high - low, 1e-3), 0.0, 1.0)
-    keep = 1.0 - ramp
-    return (f / cfg.rope_factor * (1.0 - keep) + f * keep
-            ).astype(np.float32)
-
-
 def softmax_scale(cfg: KimiK2Config) -> float:
     """``qk_head_dim^-1/2 * mscale(factor, mscale_all_dim)^2``: 0.14468
     for the published numbers."""
@@ -317,15 +287,6 @@ def rope_tables(positions, cfg: KimiK2Config):
     gain = _mscale(cfg.rope_factor, cfg.mscale) \
         / _mscale(cfg.rope_factor, cfg.mscale_all_dim)
     return jnp.cos(ang) * gain, jnp.sin(ang) * gain
-
-
-def rotate(x, cos, sin):
-    """x (..., qk_rope_dim) with cos, sin broadcastable to (...,
-    qk_rope_dim / 2): pairs (x_2i, x_2i+1) rotate, in float32."""
-    pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], -1, 2)
-    x1, x2 = pairs[..., 0], pairs[..., 1]
-    out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
-    return out.reshape(x.shape).astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -344,14 +305,14 @@ def mla_project(u, p, cfg: KimiK2Config, cos, sin, latent: bool = False):
     B, T, _ = u.shape
     H, n = cfg.n_head, cfg.qk_nope_dim
     u = u.astype(dt)
-    cq = _rmsnorm(u @ p["wq_a"].astype(dt), p["q_norm"], cfg.rms_eps)
+    cq = plain_rmsnorm(u @ p["wq_a"].astype(dt), p["q_norm"], cfg.rms_eps)
     q = (cq @ p["wq_b"].astype(dt).reshape(cfg.q_lora_rank, -1)
          ).reshape(B, T, H, cfg.qk_head_dim)
     q = jnp.concatenate(
         [q[..., :n], rotate(q[..., n:], cos[:, :, None], sin[:, :, None])],
         axis=-1)
     kv = u @ p["wkv_a"].astype(dt)
-    ckv = _rmsnorm(kv[..., :cfg.kv_lora_rank], p["kv_norm"], cfg.rms_eps)
+    ckv = plain_rmsnorm(kv[..., :cfg.kv_lora_rank], p["kv_norm"], cfg.rms_eps)
     kpe = rotate(kv[..., cfg.kv_lora_rank:], cos, sin)
     return (q, ckv, kpe, cq) if latent else (q, ckv, kpe)
 
@@ -429,19 +390,6 @@ def mla_out(o, p, cfg: KimiK2Config):
 # the block and the walk over layers of two kinds
 # ---------------------------------------------------------------------------
 
-@jax.named_scope(scopes.LN)
-def rmsnorm(x, scale, eps):
-    return _rmsnorm(x, scale, eps)
-
-
-@jax.named_scope(scopes.MLP)
-def swiglu(x, p, cfg: KimiK2Config):
-    xc = x.astype(cfg.dtype)
-    gate = xc @ p["w_gate"].astype(cfg.dtype)
-    up = xc @ p["w_up"].astype(cfg.dtype)
-    return ((jax.nn.silu(gate) * up)
-            @ p["w_down"].astype(cfg.dtype)).astype(x.dtype)
-
 
 def block(x, p, cfg: KimiK2Config, positions, attend: Callable,
           valid=None, tiled: bool = True,
@@ -511,20 +459,6 @@ def walk_layers(cfg: KimiK2Config, params, x, carry, layer: Callable):
     ys = jax.tree.map(lambda a, b: jnp.concatenate([a, b], axis=0),
                       ys, ys_m)
     return x, carry, ys, stats
-
-
-@jax.named_scope(scopes.EMBED)
-def embed(params, tokens, cfg: KimiK2Config):
-    return params["wte"].astype(cfg.dtype)[tokens]
-
-
-@jax.named_scope(scopes.LM_HEAD)
-def lm_logits(x, params, cfg: KimiK2Config):
-    """Float32 logits of ``RMSNorm(x)`` through the untied head."""
-    x = _rmsnorm(x, params["ln_f"]["scale"], cfg.rms_eps)
-    return jnp.einsum("...d,vd->...v", x.astype(cfg.dtype),
-                      params["head"].astype(cfg.dtype),
-                      preferred_element_type=jnp.float32)
 
 
 def kimi_k2_hidden(params, tokens, cfg: KimiK2Config, rules=DEFAULT_RULES):

@@ -34,9 +34,10 @@ from ray_tpu.models.decode_common import (PagedKV, _block_of, _positions,
                                           generator, is_paged, slot_mask)
 from ray_tpu.models.experts import _with_counters
 from ray_tpu.models.kimi_k2 import (KimiK2Config, attend_absorbed,
-                                    attend_expanded, block, embed,
-                                    expand_keys, expand_latents, lm_logits,
-                                    softmax_scale, walk_layers)
+                                    attend_expanded, block, expand_keys,
+                                    expand_latents, softmax_scale,
+                                    walk_layers)
+from ray_tpu.models.layers import embed, lm_logits
 from ray_tpu.ops import mla_flash_prefill as flash
 from ray_tpu.ops.mla_paged_decode import mla_paged_decode, rotary_lanes
 

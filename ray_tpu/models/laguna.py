@@ -22,9 +22,9 @@ The layer equations, ``u = RMSNorm(h)``, no bias anywhere:
     cache row is whole lane tiles, and a K/V head is a lane slice of
     it).
   * RoPE, pairs ``(2i, 2i+1)`` as `models/llama.py apply_rope` pairs
-    them (`kimi_k2.rotate`).  A full layer rotates the first
+    them (`layers.rotate`).  A full layer rotates the first
     ``full_rotary_dim`` dims of each head with YaRN's frequencies
-    (`kimi_k2.yarn_inv_freq`'s arithmetic) and multiplies cos and sin
+    (`layers.yarn_inv_freq`'s arithmetic) and multiplies cos and sin
     by ``attention_factor``; the other dims pass.  A window layer
     rotates the whole head at base ``window_rope_theta``, unscaled.
   * ``score = q.k / sqrt(head_dim)`` over ``j <= i`` (full) or ``i -
@@ -51,12 +51,11 @@ import numpy as np
 
 from ray_tpu._private import scopes
 from ray_tpu.models import experts as ex
-from ray_tpu.models.gpt2 import nll_from_logits
+from ray_tpu.models.banded_attention import attend_masked, attn_out
 # the norm, the SwiGLU MLP, the embedding lookup and the untied head
-# are the Kimi-K2 block's, scopes and all: they read `dtype` and
-# `rms_eps` off whichever config they are handed
-from ray_tpu.models.kimi_k2 import (embed, lm_logits, rmsnorm, rotate,
-                                    swiglu, yarn_inv_freq)
+# read `dtype` and `rms_eps` off whichever config they are handed
+from ray_tpu.models.layers import (embed, lm_logits, nll_from_logits,
+                                   rmsnorm, rotate, swiglu, yarn_inv_freq)
 from ray_tpu.parallel.sharding import (DEFAULT_RULES,
                                        with_logical_constraint)
 
@@ -287,7 +286,7 @@ def laguna_init(key, cfg: LagunaConfig) -> Dict[str, Any]:
 
 def _full_inv_freq(cfg: LagunaConfig) -> np.ndarray:
     """YaRN's frequencies of a full layer's rotated dims
-    (`kimi_k2.yarn_inv_freq`, which reads these names)."""
+    (`layers.yarn_inv_freq`, which reads these names)."""
     return yarn_inv_freq(types.SimpleNamespace(
         qk_rope_dim=cfg.full_rotary_dim, rope_theta=cfg.full_rope_theta,
         rope_factor=cfg.rope_factor, rope_orig_max=cfg.rope_orig_max,
@@ -340,37 +339,6 @@ def project(u, p, cfg: LagunaConfig, kind: str, positions):
     v = u @ p["wv"].astype(dt)
     gate = jax.nn.sigmoid((u @ p["wg"].astype(dt)).astype(jnp.float32))
     return q, k, v, gate
-
-
-def attn_out(o, gate, p, cfg: LagunaConfig):
-    """o (..., H, hd) times its gates, (..., H) one a head or (..., H,
-    hd) one a channel, through ``W_o``: (..., d)."""
-    dt = cfg.dtype
-    o = o.astype(jnp.float32)
-    if gate.ndim < o.ndim:
-        gate = gate[..., None]
-    o = (o * gate).astype(dt)
-    return o.reshape(*o.shape[:-2], -1) @ p["wo"].astype(dt).reshape(
-        -1, cfg.d_model)
-
-
-def attend_masked(q, k, v, mask, cfg, scale=None):
-    """q (B, T, H, hd) over folded k, v (B, S, kv_width) under mask (B,
-    T, S): grouped queries, no head repeated; (B, T, H, hd).  The whole
-    score matrix: the full-sequence forward and the dense cache's
-    programs, small sizes.  `cfg`: any config with ``n_kv_head`` and
-    ``dtype``; `scale`: the scores' factor where it is not ``1 /
-    sqrt(hd)``."""
-    B, T, H, hd = q.shape
-    S, kv = k.shape[1], cfg.n_kv_head
-    qg = q.reshape(B, T, kv, H // kv, hd)
-    kh = k.reshape(B, S, kv, hd)
-    vh = v.reshape(B, S, kv, hd)
-    s = jnp.einsum("btkgd,bskd->bkgts", qg, kh).astype(jnp.float32)
-    s = s / math.sqrt(hd) if scale is None else s * scale
-    s = jnp.where(mask[:, None, None], s, -1e30)
-    probs = jax.nn.softmax(s, axis=-1).astype(cfg.dtype)
-    return jnp.einsum("bkgts,bskd->btkgd", probs, vh).reshape(B, T, H, hd)
 
 
 def block(x, p, cfg: LagunaConfig, kind: str, positions,

@@ -28,8 +28,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models.gpt2 import (ce_config_problems, lm_head_nll,
-                                 nll_from_logits)
+from ray_tpu.models.layers import (ce_config_problems, lm_head_nll,
+                                   nll_from_logits, plain_rmsnorm)
 from ray_tpu.parallel.sharding import (DEFAULT_RULES,
                                        with_logical_constraint)
 
@@ -51,7 +51,7 @@ class LlamaConfig:
     scan_unroll: int = 1
     use_flash: Optional[bool] = None    # None = auto (flash on TPU)
     vocab_pad_to: int = 128
-    #: lm-head + CE implementation (gpt2.CE_IMPLS); the non-dense impls
+    #: lm-head + CE implementation (layers.CE_IMPLS); the non-dense impls
     #: run against the TRANSPOSED (V, D) view of lm_head so one kernel
     #: serves tied and untied heads (the transpose+cast fuses into the
     #: bf16 tile staging — cheap next to the (B,T,V) logits it removes).
@@ -59,7 +59,7 @@ class LlamaConfig:
     vocab_tile: int = 8192
     ce_block_n: int = 256
     ce_block_v: int = 1024
-    #: resident-kv flash dispatch knob (gpt2.FLASH_RESIDENT_MODES);
+    #: resident-kv flash dispatch knob (layers.FLASH_RESIDENT_MODES);
     #: RAYTPU_FLASH_RESIDENT overrides per-process.
     flash_resident: str = "auto"
 
@@ -172,13 +172,6 @@ def llama_init(key, cfg: LlamaConfig) -> Dict[str, Any]:
     }
 
 
-def _rmsnorm(x, scale, eps):
-    xf = x.astype(jnp.float32)
-    y = xf * lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1,
-                                keepdims=True) + eps)
-    return (y * scale).astype(x.dtype)
-
-
 def rope_frequencies(T: int, head_dim: int, theta: float):
     """(T, head_dim/2) cos/sin tables."""
     inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2,
@@ -240,9 +233,9 @@ def _mlp(x, p, cfg: LlamaConfig, rules):
 
 
 def _block(x, p, cos, sin, cfg: LlamaConfig, rules):
-    x = x + _attention(_rmsnorm(x, p["ln1"]["scale"], cfg.rms_eps),
+    x = x + _attention(plain_rmsnorm(x, p["ln1"]["scale"], cfg.rms_eps),
                        p["attn"], cos, sin, cfg, rules)
-    x = x + _mlp(_rmsnorm(x, p["ln2"]["scale"], cfg.rms_eps),
+    x = x + _mlp(plain_rmsnorm(x, p["ln2"]["scale"], cfg.rms_eps),
                  p["mlp"], cfg, rules)
     return with_logical_constraint(x, ("batch", "seq", "embed"),
                                    rules), None
@@ -267,7 +260,7 @@ def llama_hidden(params, tokens, cfg: LlamaConfig,
 
     x, _ = lax.scan(scan_body, x, params["blocks"],
                     unroll=cfg.scan_unroll)
-    return _rmsnorm(x, params["ln_f"]["scale"], cfg.rms_eps)
+    return plain_rmsnorm(x, params["ln_f"]["scale"], cfg.rms_eps)
 
 
 def llama_forward(params, tokens, cfg: LlamaConfig,

@@ -18,8 +18,8 @@ import jax.numpy as jnp
 from ray_tpu._private import scopes
 from ray_tpu.models import kv_decode
 from ray_tpu.models.decode_common import generator
-from ray_tpu.models.llama import (LlamaConfig, _rmsnorm,
-                                  rope_frequencies)
+from ray_tpu.models.layers import plain_rmsnorm
+from ray_tpu.models.llama import LlamaConfig, rope_frequencies
 
 __all__ = ["llama_init_cache", "llama_init_paged_cache",
            "llama_prefill", "llama_paged_prefill", "llama_decode_step",
@@ -28,7 +28,7 @@ __all__ = ["llama_init_cache", "llama_init_paged_cache",
 
 @jax.named_scope(scopes.LN)
 def _norm(x, scale, cfg: LlamaConfig):
-    return _rmsnorm(x, scale, cfg.rms_eps)
+    return plain_rmsnorm(x, scale, cfg.rms_eps)
 
 
 def _rope(x, cos, sin):

@@ -41,7 +41,7 @@ a linear layer by its recurrence.
     published sizes), no rotary; ``score = q.k / sqrt(head_dim)`` over
     ``j <= t``, float32 softmax; ``out = concat_h(o_h) W_o``.  K (after
     its norm) and V of a token folded into one row of ``kv_width``
-    lanes, as laguna.py folds them.
+    lanes, as banded_attention.py reads them.
 
 A pad is an identity step of the recurrence (``beta = 0``, ``g = 0``)
 and leaves the window alone, as solar_open2.py's pads do
@@ -59,18 +59,16 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu._private import scopes
-from ray_tpu.models.gpt2 import nll_from_logits
-from ray_tpu.models.jamba import conv_inputs
-# the embedding lookup, the untied head and the dense SwiGLU are the
-# Kimi-K2 block's, the attention over folded K/V Laguna's, scopes and
-# all: they read `dtype`, `rms_eps` and `n_kv_head` off whichever
-# config they are handed.  The seeded draw's constants and the unit
-# vectors are Solar-Open2's, whose linear layers this family's follow
-from ray_tpu.models.kimi_k2 import embed, lm_logits, swiglu
-from ray_tpu.models.laguna import attend_masked
-from ray_tpu.models.llama import _rmsnorm
-from ray_tpu.models.solar_open2 import (DECAY_SPAN, EMBED_STD, SILU_IN,
-                                        _unit)
+# the attention over folded K/V, the embedding lookup, the untied head
+# and the dense SwiGLU read `dtype`, `rms_eps` and `n_kv_head` off
+# whichever config they are handed.  The seeded draw's constants are
+# the ones Solar-Open2's linear layers are drawn by, which this
+# family's follow
+from ray_tpu.models.banded_attention import attend_masked
+from ray_tpu.models.layers import (DECAY_SPAN, EMBED_STD, SILU_IN, embed,
+                                   lm_logits, nll_from_logits,
+                                   plain_rmsnorm, swiglu, unit)
+from ray_tpu.models.mamba import conv_inputs
 from ray_tpu.ops.kda import kda_decode, kda_prefill
 from ray_tpu.parallel.sharding import (DEFAULT_RULES,
                                        with_logical_constraint)
@@ -339,9 +337,9 @@ def full_project(h, p, cfg: OlmoHybridConfig):
     rotary."""
     dt = cfg.dtype
     h = h.astype(dt)
-    q = _rmsnorm(h @ p["wq"].astype(dt).reshape(cfg.d_model, -1),
+    q = plain_rmsnorm(h @ p["wq"].astype(dt).reshape(cfg.d_model, -1),
                  p["q_norm"], cfg.rms_eps)
-    k = _rmsnorm(h @ p["wk"].astype(dt), p["k_norm"], cfg.rms_eps)
+    k = plain_rmsnorm(h @ p["wk"].astype(dt), p["k_norm"], cfg.rms_eps)
     return (q.reshape(*h.shape[:-1], cfg.n_head, cfg.head_dim), k,
             h @ p["wv"].astype(dt))
 
@@ -399,8 +397,8 @@ def deltanet_mix(p, h, cfg: OlmoHybridConfig, window, state, real=None,
                          for name in ("conv_q", "conv_k", "conv_v")], axis=-1)
     qkv = jax.nn.silu(sum(ext[:, i:i + T].astype(f32) * w[i]
                           for i in range(K)))
-    q = _unit(qkv[..., :cfg.key_width].reshape(B, T, H, dk)) * dk ** -0.5
-    k = _unit(qkv[..., cfg.key_width:2 * cfg.key_width].reshape(
+    q = unit(qkv[..., :cfg.key_width].reshape(B, T, H, dk)) * dk ** -0.5
+    k = unit(qkv[..., cfg.key_width:2 * cfg.key_width].reshape(
         B, T, H, dk))
     v = qkv[..., 2 * cfg.key_width:].reshape(B, T, H, dv)
     g = -jnp.exp(p["A_log"].astype(f32)) * jax.nn.softplus(
@@ -424,7 +422,7 @@ def deltanet_mix(p, h, cfg: OlmoHybridConfig, window, state, real=None,
             q, k, v, g, beta, state, chunk=cfg.rule_chunk, dtype=dt,
             capture=capture)
     gate = (h @ p["wg"].astype(dt).reshape(cfg.d_model, -1)).astype(f32)
-    o = _rmsnorm(o, p["o_norm"], cfg.rms_eps) \
+    o = plain_rmsnorm(o, p["o_norm"], cfg.rms_eps) \
         * jax.nn.silu(gate).reshape(B, T, H, dv)
     out = o.astype(dt).reshape(B, T, -1) @ p["wo"].astype(dt).reshape(
         -1, cfg.d_model)
@@ -445,7 +443,7 @@ def _close(x, y, scale, cfg: OlmoHybridConfig, scope: str):
     """``x + RMSNorm(y)``: a sublayer's output joins the stream normed,
     under the scope of the sublayer it closes."""
     with jax.named_scope(scope):
-        return x + _rmsnorm(y, scale, cfg.rms_eps).astype(x.dtype)
+        return x + plain_rmsnorm(y, scale, cfg.rms_eps).astype(x.dtype)
 
 
 def mlp_half(x, p, cfg: OlmoHybridConfig):

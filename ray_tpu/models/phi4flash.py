@@ -31,7 +31,7 @@ table.  Logits ``= LN_f(h) E^T``, tied.  By index ``i``:
                  ``half + 1``'s K, V
   ============  ===============================================  =====
 
-* Mamba (Mamba-1; jamba.mamba_mix WITHOUT Jamba's norms on dt, B, C):
+* Mamba (Mamba-1; mamba.mamba_mix WITHOUT Jamba's norms on dt, B, C):
   ``[x, z] = u W_in``; ``x <- silu(conv4(x) + b_c)``; ``[dt_r, B, C] = x
   W_x``; ``dt = softplus(dt_r W_dt + b_dt)``; ``A = -exp(A_log)``;
   ``s_t = exp(dt_t A) s_{t-1} + (dt_t x_t) (x) B_t`` (float32); ``y_t =
@@ -60,7 +60,7 @@ row: adjacent heads (2r, 2r+1) are then ONE pair-head of 2 hd lanes,
 output the 2 hd-wide ``o^s``.  That is grouped-query attention with
 ``n_kv_head / 2`` K/V heads of ``2 hd``, ``n_head`` query heads and a
 scale of ``1 / sqrt(hd)`` (`Phi4FlashConfig.pairs` is that geometry):
-the walks the tree has (laguna_decode.attend_banded and attend_rows,
+the walks the tree has (banded_attention.attend_banded and attend_rows,
 ops/gqa_paged_decode.py) compute it as they are, at twice the score
 products.  The combine, the norm and ``W_o`` follow outside
 (`diff_out`).
@@ -85,9 +85,9 @@ import numpy as np
 from jax import lax
 
 from ray_tpu._private import scopes
-from ray_tpu.models.gpt2 import _layernorm, nll_from_logits
-from ray_tpu.models.jamba import mamba_mix
-from ray_tpu.models.llama import _rmsnorm
+from ray_tpu.models import banded_attention, layers
+from ray_tpu.models.layers import nll_from_logits, plain_rmsnorm
+from ray_tpu.models.mamba import mamba_mix
 from ray_tpu.parallel.sharding import (DEFAULT_RULES,
                                        with_logical_constraint)
 
@@ -388,8 +388,8 @@ def embed(params, tokens, cfg: Phi4FlashConfig):
 
 @jax.named_scope(scopes.LN)
 def layernorm(x, p, cfg: Phi4FlashConfig):
-    return _layernorm(x, p["scale"].astype(jnp.float32),
-                      p["bias"].astype(jnp.float32), cfg.ln_eps)
+    return layers.layernorm(x, p["scale"].astype(jnp.float32),
+                            p["bias"].astype(jnp.float32), cfg.ln_eps)
 
 
 @jax.named_scope(scopes.MLP)
@@ -408,8 +408,9 @@ def mlp_residual(x, p, cfg: Phi4FlashConfig):
 @jax.named_scope(scopes.LM_HEAD)
 def lm_logits(x, params, cfg: Phi4FlashConfig):
     """Float32 logits of ``LN_f(x)`` through the tied embedding."""
-    x = _layernorm(x, params["ln_f"]["scale"].astype(jnp.float32),
-                   params["ln_f"]["bias"].astype(jnp.float32), cfg.ln_eps)
+    x = layers.layernorm(x, params["ln_f"]["scale"].astype(jnp.float32),
+                         params["ln_f"]["bias"].astype(jnp.float32),
+                         cfg.ln_eps)
     return jnp.einsum("...d,vd->...v", x.astype(cfg.dtype),
                       params["wte"].astype(cfg.dtype),
                       preferred_element_type=jnp.float32)
@@ -454,7 +455,7 @@ def diff_out(o, p, lam_init, cfg: Phi4FlashConfig):
            - jnp.exp(jnp.sum(p["lq2"].astype(f32) * p["lk2"].astype(f32)))
            + lam_init)
     o = o.astype(f32).reshape(*o.shape[:-2], cfg.n_head // 2, 2, -1)
-    mixed = _rmsnorm(o[..., 0, :] - lam * o[..., 1, :],
+    mixed = plain_rmsnorm(o[..., 0, :] - lam * o[..., 1, :],
                      p["subln"].astype(f32), cfg.ln_eps) * (1.0 - lam_init)
     mixed = mixed.reshape(*mixed.shape[:-2], cfg.d_model).astype(cfg.dtype)
     return mixed @ p["wo"].astype(cfg.dtype) + p["bo"].astype(cfg.dtype)
@@ -462,18 +463,18 @@ def diff_out(o, p, lam_init, cfg: Phi4FlashConfig):
 
 def attend_masked(q, k, v, mask, cfg: Phi4FlashConfig):
     """Padded queries q (B, T, n_head, 2 hd) over folded k, v (B, S,
-    kv_width) under mask (B, T, S): Laguna's whole-score-matrix
-    grouped-query attention at the pair-head geometry.  The
-    full-sequence forward and the dense cache's prefill, small sizes.
+    kv_width) under mask (B, T, S): banded_attention.py's
+    whole-score-matrix grouped-query attention at the pair-head
+    geometry.  The full-sequence forward and the dense cache's prefill,
+    small sizes.
     (B, T, n_head, 2 hd)."""
-    from ray_tpu.models.laguna import attend_masked as grouped
-
-    return grouped(q, k, v, mask, cfg.pairs, cfg.pairs.scale)
+    return banded_attention.attend_masked(q, k, v, mask, cfg.pairs,
+                                          cfg.pairs.scale)
 
 
 def mamba_layer(x, p, cfg: Phi4FlashConfig, window, state, real=None,
                 capture=None):
-    """One Mamba layer with its MLP on x (B, T, d): jamba.mamba_mix's
+    """One Mamba layer with its MLP on x (B, T, d): mamba.mamba_mix's
     contract.  Returns (x, (window, state), snapshot or None, m): what
     the layer would hand out as the memory (B, T, d_inner), in the
     compute dtype; only the last Mamba layer's is used."""

@@ -6,14 +6,14 @@ The cache contract of decode_common with everything a cache may hold
 but a latent (models/phi4flash.py has the architecture):
 
   k, v   : (1, B, S, kv_width) dense, (1, blocks, bs, kv_width) paged:
-           the FULL layer's K/V, folded (laguna_decode.py), the model's
-           only positional cache.  The full layer writes it; the full
+           the FULL layer's K/V, folded (banded_attention.py), the
+           model's only positional cache.  The full layer writes it; the full
            layer and every cross layer read it.  A pool of ONE layer:
            a token weighs ``2 * kv_width`` elements whatever the depth.
   conv   : (n_mamba, d_conv - 1, B, d_inner)  the Mamba layers'
   ssm    : (n_mamba, B, d_state, d_inner)     state (jamba_decode.py)
   wk, wv : (n_self, B, window, kv_width)      the window layers' rings
-           (laguna_decode.py: the row of cache slot s is ``s mod
+           (banded_attention.py: the row of cache slot s is ``s mod
            window``)
 
 and, in the paged layout, a snapshot pool of all four (``snap_*``; one
@@ -37,7 +37,7 @@ window layers' own, under ``attn_window``.
     on the chip ops/ring_decode.py reads it where it lies in the
     scan's carried stacks (the layer a traced index; no ring is sliced
     out), elsewhere `attend_rows` over the layer's rings sliced out
-    (laguna_decode._attend_stacked_ring).
+    (banded_attention.attend_stacked_ring).
   * a prefill runs the self-decoder over every column (they owe the
     state, the rings and the pool their rows) and the CROSS-decoder
     over one column, the prompt's last: for those layers a prefill's
@@ -55,23 +55,23 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu._private import scopes
+# the rings, the banded prefill attention, a row's masked softmax over
+# folded K/V and the walk of the paged pool, at this family's pair-head
+# geometry
+from ray_tpu.models.banded_attention import (attend_banded, attend_paged,
+                                             attend_rows,
+                                             attend_stacked_ring,
+                                             banded_prefill_attention,
+                                             prefill_reach, ring_after)
 from ray_tpu.models.decode_common import (NO_SNAPSHOT, STATE_FROM_SLOT,
                                           STATE_FROM_ZERO, PagedKV,
                                           _refuse_mesh, generator, is_paged,
+                                          layer_state, set_layer_state,
                                           slot_mask)
-from ray_tpu.models.jamba_decode import _layer_state, _set_layer_state
-# the rings, the banded prefill attention and a row's masked softmax
-# over folded K/V are Laguna's, at this family's pair-head geometry
-from ray_tpu.models.laguna_decode import (_attend_stacked_ring, _ring_of,
-                                          attend_banded, attend_rows,
-                                          banded_prefill_attention,
-                                          prefill_reach)
 from ray_tpu.models.phi4flash import (Phi4FlashConfig, attn_layer,
                                       attend_masked, causal_mask,
                                       cross_decoder, embed, lm_logits,
                                       mamba_layer, zero_recurrent)
-from ray_tpu.ops.gqa_paged_decode import (gqa_paged_decode,
-                                          gqa_paged_decode_reference)
 
 __all__ = ["phi4flash_init_cache", "phi4flash_init_paged_cache",
            "phi4flash_prefill", "phi4flash_paged_prefill",
@@ -84,7 +84,7 @@ _RINGS = ("wk", "wv")
 def phi4flash_prefill_attention(cfg: Phi4FlashConfig, t_pad: int,
                                 prefix_len: int, n_tail: int
                                 ) -> Tuple[bool, int, int]:
-    """`laguna_decode.banded_prefill_attention` of
+    """`banded_attention.banded_prefill_attention` of
     `phi4flash_paged_prefill`'s window layers and its full layer, in
     the pair-heads' geometry."""
     return banded_prefill_attention(
@@ -144,7 +144,7 @@ def _last_ring(rows, end, window: int):
     of zeros that derive to slots below 0."""
     lead = [(0, 0)] * (rows.ndim - 2)
     rows = jnp.pad(rows, lead + [(window, 0), (0, 0)])[..., -window:, :]
-    return _ring_of(rows, end, window)
+    return ring_after(rows, end, window)
 
 
 # -- the programs -------------------------------------------------------------
@@ -264,7 +264,7 @@ def phi4flash_paged_prefill(params, cache, tokens: jnp.ndarray,
     pkv = PagedKV(cache, row_bt[None],
                   jnp.where(real, logical, cfg.max_seq)[None], whole=True)
     # the full layer's keys are the row's gathered view, a window
-    # layer's the ring laid before the tail (laguna_decode.prefill_reach)
+    # layer's the ring laid before the tail (banded_attention.prefill_reach)
     reach_full = prefill_reach(Tt, prefix_len, n_tail)
     reach_window = prefill_reach(Tt, prefix_len, n_tail, W)
     # the column after which the state is `boundary` tokens old
@@ -313,10 +313,10 @@ def phi4flash_paged_prefill(params, cache, tokens: jnp.ndarray,
                     for ring, new in zip(old, (k, v)))
                 # the rings after the tail, and after `boundary` tokens
                 left.extend(
-                    _ring_of(lax.dynamic_slice_in_dim(a, Tt, W),
+                    ring_after(lax.dynamic_slice_in_dim(a, Tt, W),
                              prefix_len + n_tail, W) for a in laid)
                 left.extend(
-                    _ring_of(lax.dynamic_slice_in_dim(a, ring_cut, W),
+                    ring_after(lax.dynamic_slice_in_dim(a, ring_cut, W),
                              boundary, W) for a in laid)
             return attend_banded(q[0], *laid, *reach_window, pairs,
                                  scopes.ATTN_WINDOW, pairs.scale)[None]
@@ -406,12 +406,6 @@ def phi4flash_decode_step(params, cache, tokens, cfg: Phi4FlashConfig
     B = tokens.shape[0]
     W, pairs = cfg.window, cfg.pairs
     paged = is_paged(cache)
-    # what the program can see of its input picks the path (a paged
-    # cache, the chip): the kernel walks the pool's blocks where they
-    # lie; the CPU gathers the views and keeps the jnp path, the parity
-    # oracle (laguna_decode.laguna_decode_step)
-    walk = gqa_paged_decode if jax.default_backend() == "tpu" \
-        else gqa_paged_decode_reference
     pos, start = cache["pos"], cache["start"]
     active = pos > 0
     rows = jnp.arange(B)
@@ -424,9 +418,11 @@ def phi4flash_decode_step(params, cache, tokens, cfg: Phi4FlashConfig
         x, conv, ssm, wk, wv = carry
         p, lam_init, j = xs
         x, state, _, _ = mamba_layer(
-            x[:, None], p["mamba"], cfg, *_layer_state(conv, ssm, j),
+            x[:, None], p["mamba"], cfg,
+            *layer_state(scopes.SSM_STATE, conv, ssm, j),
             real=active[:, None])
-        conv, ssm = _set_layer_state(conv, ssm, j, *state)
+        conv, ssm = set_layer_state(scopes.SSM_STATE, conv, ssm, j,
+                                    *state)
         rings = [wk, wv]
 
         def attend(q, k, v):
@@ -434,7 +430,7 @@ def phi4flash_decode_step(params, cache, tokens, cfg: Phi4FlashConfig
                 for at, new in enumerate((k, v)):
                     rings[at] = rings[at].at[j, rows, ring_at].set(
                         new, mode="drop")
-            return _attend_stacked_ring(q, rings, j, pos, start, pairs,
+            return attend_stacked_ring(q, rings, j, pos, start, pairs,
                                         pairs.scale)
 
         x = attn_layer(x[:, 0], p["window"], lam_init, cfg,
@@ -450,9 +446,9 @@ def phi4flash_decode_step(params, cache, tokens, cfg: Phi4FlashConfig
              jnp.arange(n, dtype=jnp.int32)))
     # the memory is THIS step's: the Gated Memory Units keep nothing
     x, state, _, m = mamba_layer(
-        x[:, None], params["memory"], cfg, *_layer_state(conv, ssm, n),
-        real=active[:, None])
-    conv, ssm = _set_layer_state(conv, ssm, n, *state)
+        x[:, None], params["memory"], cfg,
+        *layer_state(scopes.SSM_STATE, conv, ssm, n), real=active[:, None])
+    conv, ssm = set_layer_state(scopes.SSM_STATE, conv, ssm, n, *state)
     x, m = x[:, 0], m[:, 0]
     # the full layer's new row: attended beside the pool by the full
     # layer and by every cross layer, landed once after them
@@ -460,10 +456,10 @@ def phi4flash_decode_step(params, cache, tokens, cfg: Phi4FlashConfig
 
     def read_pool(q):
         if paged:
-            return walk(q, cache["k"], cache["v"], cache["block_tables"],
-                        pos, 0, (fresh["k"], fresh["v"]),
-                        n_kv_head=pairs.n_kv_head, scale=pairs.scale,
-                        start=start)
+            # the kernel on the chip, the gathered views off it
+            return attend_paged(q, (cache["k"], cache["v"]), 0, cache,
+                                (fresh["k"], fresh["v"]), pairs,
+                                pairs.scale)
         return attend_rows(q, fresh["k"], fresh["v"], fresh["mask"], pairs,
                            pairs.scale)
 

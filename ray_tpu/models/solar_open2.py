@@ -18,11 +18,11 @@ alone, a KDA layer by its recurrence.
   * GQA.  ``q = u W_q`` (``n_head`` heads of ``head_dim``), ``k = u
     W_k``, ``v = u W_v`` (``n_kv_head`` heads; query head ``h`` reads
     K/V head ``h // (n_head / n_kv_head)``), K and V of a token folded
-    into one row of ``kv_width`` lanes as laguna.py folds them.
+    into one row of ``kv_width`` lanes as banded_attention.py reads them.
     ``score = q.k / sqrt(head_dim)`` over ``j <= i``, float32 softmax;
     ``a = concat_h(g_h * o_h) W_o`` with ``g = sigmoid(u W_g)``, one
-    value a CHANNEL of each head (`laguna.attn_out`, whose gate there is
-    one a head).
+    value a CHANNEL of each head (`banded_attention.attn_out`, whose
+    gate may also be one a head).
   * KDA, ``kda_heads`` heads with keys and values of ``kda_head_dim``
     (ops/kda.py has the recurrence and its chunked form):
     ``[q~ | k~ | v] = SiLU(conv(u W_qkv))``, three causal depthwise
@@ -60,16 +60,15 @@ import jax.numpy as jnp
 
 from ray_tpu._private import scopes
 from ray_tpu.models import experts as ex
-from ray_tpu.models.gpt2 import nll_from_logits
-from ray_tpu.models.jamba import conv_inputs
-# the norm, the embedding lookup and the untied head are the Kimi-K2
-# block's, the grouped-query attention over folded K/V and the gated
-# output projection Laguna's, scopes and all: they read `dtype`,
-# `rms_eps`, `n_kv_head` and `d_model` off whichever config they are
-# handed
-from ray_tpu.models.kimi_k2 import embed, lm_logits, rmsnorm
-from ray_tpu.models.laguna import attend_masked, attn_out
-from ray_tpu.models.llama import _rmsnorm
+# the grouped-query attention over folded K/V and the gated output
+# projection, the norm, the embedding lookup and the untied head read
+# `dtype`, `rms_eps`, `n_kv_head` and `d_model` off whichever config
+# they are handed
+from ray_tpu.models.banded_attention import attend_masked, attn_out
+from ray_tpu.models.layers import (DECAY_SPAN, EMBED_STD, SILU_IN, embed,
+                                   lm_logits, nll_from_logits,
+                                   plain_rmsnorm, rmsnorm, unit)
+from ray_tpu.models.mamba import conv_inputs
 from ray_tpu.ops.kda import kda_decode, kda_prefill
 from ray_tpu.parallel.sharding import (DEFAULT_RULES,
                                        with_logical_constraint)
@@ -249,28 +248,6 @@ def solar_open2_logical_axes(cfg: SolarOpen2Config) -> Dict[str, Any]:
             "layers": [layer(t) for t in cfg.layer_types]}
 
 
-#: the per-token decay a seeded KDA channel is drawn to: exp(g) spans
-#: about this range (`solar_open2_init`)
-DECAY_SPAN = (0.9, 0.999)
-#: the deviation at which a seeded layer's q~, k~ and v enter their SiLU,
-#: on its linear part: the taps are N(0, SILU_IN / (0.02 sqrt(d_model
-#: d_conv))), 0.049 at the published width.  At unit scale (taps N(0,
-#: 0.5) there) a SiLU's output has a mean of 0.3 of its deviation,
-#: every head's read-out carries that mean, the per-head RMSNorm makes
-#: it a token-independent vector of the stream larger than the
-#: embedding (a quarter of the mixer's output), and every later router
-#: scores it: the fullest held expert took 5-8 times the mean of a
-#: prefill and a decode wave touched 0.46-0.49 of the held experts
-#: where an even load touches 0.56, by the seed (PERF.md section 6,
-#: PR 49)
-SILU_IN = 0.125
-#: a seeded embedding row's deviation, five times a projection's: the
-#: first layer's router reads the token over what its softmax layer
-#: adds, which without positions is a mean over the context that every
-#: later token of a sequence shares
-EMBED_STD = 0.1
-
-
 @functools.partial(jax.jit, static_argnames=("shape", "std", "dtype"))
 def _normal(key, shape, std, dtype):
     return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
@@ -371,11 +348,6 @@ def zero_recurrent(cfg: SolarOpen2Config, batch: int, layers: bool = True):
                               cfg.kda_head_dim), jnp.float32))
 
 
-def _unit(x, eps: float = 1e-6):
-    """x (..., hd) float32 over its L2 norm."""
-    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
-
-
 @jax.named_scope(scopes.ATTN_LINEAR)
 def kda_mix(p, u, cfg: SolarOpen2Config, window, state, real=None,
             capture=None, layer=None):
@@ -412,8 +384,8 @@ def kda_mix(p, u, cfg: SolarOpen2Config, window, state, real=None,
     w = p["conv_w"].astype(f32).reshape(K, -1)
     qkv = jax.nn.silu(sum(ext[:, i:i + T].astype(f32) * w[i]
                           for i in range(K))).reshape(B, T, 3, H, hd)
-    q = _unit(qkv[:, :, 0]) * hd ** -0.5
-    k, v = _unit(qkv[:, :, 1]), qkv[:, :, 2]
+    q = unit(qkv[:, :, 0]) * hd ** -0.5
+    k, v = unit(qkv[:, :, 1]), qkv[:, :, 2]
     g = -jnp.exp(p["A_log"].astype(f32))[:, None] * jax.nn.softplus(
         low_rank("wf_a", "wf_b") + p["dt_bias"].astype(f32))
     beta = jax.nn.sigmoid(jnp.einsum(
@@ -434,7 +406,7 @@ def kda_mix(p, u, cfg: SolarOpen2Config, window, state, real=None,
         o, new_state, snap_state = kda_prefill(
             q, k, v, g, beta, state, chunk=cfg.kda_chunk, dtype=dt,
             capture=capture)
-    o = _rmsnorm(o, p["o_norm"], cfg.rms_eps) \
+    o = plain_rmsnorm(o, p["o_norm"], cfg.rms_eps) \
         * jax.nn.sigmoid(low_rank("wg_a", "wg_b"))
     out = o.astype(dt).reshape(B, T, -1) @ p["wo"].astype(dt).reshape(
         -1, cfg.d_model)

@@ -2,382 +2,51 @@
 softmax layer in four, a matrix a head for the three KDA layers, in one
 cache.
 
-The cache contract of decode_common with the second kind of state, as
-models/jamba_decode.py keeps it, at another size.  The K/V tensors hold
-the GQA layers only (``n_gqa`` of them), folded as models/laguna.py
-folds them (``kv_width`` = n_kv_head * head_dim lanes a row), and go
-through the pool as every family's do.  Beside them, per sequence and
-not per token, under the names the recurrent state already has
-(decode_common ``_STATE``: its axes are per name, not per shape):
+The programs are `delta_decode.py`'s, the decoder of every family that
+keeps a matrix a head beside K/V (the cache in both layouts, the
+`state` argument of the paged prefill, the snapshot pool: read them
+there).  This module is the Solar-Open2 block they run over, and their
+binding under the family's public names:
 
   conv : (n_kda, d_conv - 1, B, 3 * kda_width)   the three convolutions'
          window, compute dtype
   ssm  : (n_kda, B, heads, head_dim, head_dim)   the delta rule's state,
          float32: 4.19 MB a layer a slot at the published sizes
 
-and, in the paged layout the serve engine uses, a snapshot pool of the
-same two shapes (``snap_conv``, ``snap_ssm``; one entry a slot): the
-state after a block boundary of some prompt, so that a later prompt
-with that prefix resident starts from it (serve/kv_pager.py
-``StateSnapshots``).  All four are donated with the pool and updated
-where they lie.
-
-What "a row's past" means for a KDA layer is what it means for a Mamba
-layer (jamba_decode.py): a decode step advances every ACTIVE row by one
-token and leaves a row with ``pos == 0`` exactly as it is, window and
-state; a prefill sets its slot's state from what its `state` argument
-names and walks it through the real columns only (solar_open2.kda_mix:
-a pad moves nothing).
-
-``cache["experts"]`` holds what the expert layers' routing did in the
-LAST program (decode_common.EXPERT_COUNTERS).
+Every layer ends in an expert layer: ``cache["experts"]`` holds what
+their routing did in the LAST program (decode_common.EXPERT_COUNTERS),
+and a pad or an idle row is routed to no expert (`gqa_block`'s and
+`kda_block`'s `valid`).
 """
 
 from __future__ import annotations
 
-import math
-from typing import Dict, Optional, Tuple
+from functools import partial
 
-import jax
-import jax.numpy as jnp
-from jax import lax
-
-from ray_tpu._private import scopes
-from ray_tpu.models.decode_common import (NO_SNAPSHOT, STATE_FROM_SLOT,
-                                          STATE_FROM_ZERO, PagedKV,
-                                          _positions, _refuse_mesh,
-                                          generator, is_paged, slot_mask)
-from ray_tpu.models.experts import _with_counters
-# the banded prefill attention over folded K/V is Laguna's
-from ray_tpu.models.laguna_decode import (attend_banded,
-                                          banded_prefill_attention,
-                                          prefill_reach)
-from ray_tpu.models.solar_open2 import (GQA, KDA, SolarOpen2Config,
-                                        attend_masked, embed, gqa_block,
-                                        kda_block, lm_logits, walk_layers,
+from ray_tpu.models import delta_decode
+from ray_tpu.models.decode_common import generator
+from ray_tpu.models.solar_open2 import (GQA, embed, gqa_block, kda_block,
+                                        lm_logits, walk_layers,
                                         zero_recurrent)
-from ray_tpu.ops.gqa_paged_decode import (gqa_paged_decode,
-                                          gqa_paged_decode_reference)
 
 __all__ = ["solar_open2_init_cache", "solar_open2_init_paged_cache",
            "solar_open2_prefill", "solar_open2_paged_prefill",
            "solar_open2_decode_step", "solar_open2_generate",
            "solar_open2_prefill_attention"]
 
+BLOCK = delta_decode.Block(
+    family="solar_open2", attn=GQA, zero_recurrent=zero_recurrent,
+    embed=embed, attn_block=gqa_block, rule_block=kda_block,
+    walk_layers=walk_layers, lm_logits=lm_logits)
 
-def solar_open2_prefill_attention(cfg: SolarOpen2Config, t_pad: int,
-                                  prefix_len: int, n_tail: int
-                                  ) -> Tuple[bool, int, int]:
-    """`laguna_decode.banded_prefill_attention` of
-    `solar_open2_paged_prefill`'s softmax layers."""
-    return banded_prefill_attention(
-        cfg, t_pad, prefix_len, n_tail,
-        [(len(cfg.layers_of(GQA)), cfg.n_head, cfg.max_seq, None)])
-
-
-def _kv_tensors(cfg: SolarOpen2Config, *lead: int):
-    shape = (len(cfg.layers_of(GQA)), *lead, cfg.kv_width)
-    return {"k": jnp.zeros(shape, cfg.dtype),
-            "v": jnp.zeros(shape, cfg.dtype)}
-
-
-def solar_open2_init_cache(cfg: SolarOpen2Config, batch: int,
-                           mesh=None) -> Dict[str, jnp.ndarray]:
-    """Dense cache: (n_gqa, B, S, kv_width) K/V, the recurrent state of
-    `batch` sequences, position vectors, expert counters."""
-    _refuse_mesh("solar_open2", mesh)
-    conv, ssm = zero_recurrent(cfg, batch)
-    return dict(_kv_tensors(cfg, batch, cfg.max_seq), conv=conv, ssm=ssm,
-                **_positions(batch))
-
-
-def solar_open2_init_paged_cache(cfg: SolarOpen2Config, batch: int, *,
-                                 num_blocks: int, block_size: int,
-                                 mesh=None) -> Dict[str, jnp.ndarray]:
-    """Block-pool cache: K/V pools of the GQA layers, per-row block
-    tables, the rows' recurrent state and a snapshot pool of one entry
-    a row."""
-    _refuse_mesh("solar_open2", mesh)
-    if cfg.max_seq % block_size:
-        raise ValueError(f"max_seq={cfg.max_seq} must be a multiple of "
-                         f"block_size={block_size}")
-    conv, ssm = zero_recurrent(cfg, batch)
-    return dict(_kv_tensors(cfg, num_blocks, block_size), conv=conv,
-                ssm=ssm, snap_conv=jnp.zeros_like(conv),
-                snap_ssm=jnp.zeros_like(ssm),
-                block_tables=jnp.zeros(
-                    (batch, cfg.max_seq // block_size), jnp.int32),
-                **_positions(batch))
-
-
-# -- the recurrent state, one layer or one slot of it at a time --------------
-
-@jax.named_scope(scopes.LINEAR_STATE)
-def _layer_state(conv, ssm, j: int):
-    """Every row's (window, state) of KDA layer `j`."""
-    return (lax.dynamic_index_in_dim(conv, j, 0, keepdims=False),
-            lax.dynamic_index_in_dim(ssm, j, 0, keepdims=False))
-
-
-@jax.named_scope(scopes.LINEAR_STATE)
-def _layer_window(conv, j: int):
-    """Every row's window of KDA layer `j`."""
-    return lax.dynamic_index_in_dim(conv, j, 0, keepdims=False)
-
-
-@jax.named_scope(scopes.LINEAR_STATE)
-def _set_layer_window(conv, j: int, window):
-    return lax.dynamic_update_index_in_dim(conv, window, j, 0)
-
-
-@jax.named_scope(scopes.LINEAR_STATE)
-def _slot_rows(conv, ssm, row):
-    """Row `row`'s (windows, states) of every KDA layer: (n_kda, K-1, 1,
-    width), (n_kda, 1, H, hd, hd)."""
-    return (lax.dynamic_slice_in_dim(conv, row, 1, axis=2),
-            lax.dynamic_slice_in_dim(ssm, row, 1, axis=1))
-
-
-@jax.named_scope(scopes.LINEAR_STATE)
-def _land_rows(conv, ssm, row, windows, states):
-    return (lax.dynamic_update_slice_in_dim(conv, windows, row, 2),
-            lax.dynamic_update_slice_in_dim(ssm, states, row, 1))
-
-
-def _stacked(pairs):
-    """[(window, state) a KDA layer] -> (windows, states) stacked over
-    the layers."""
-    return tuple(jnp.stack(part) for part in zip(*pairs))
-
-
-# -- the programs -------------------------------------------------------------
-
-def solar_open2_prefill(params, tokens: jnp.ndarray, cfg: SolarOpen2Config,
-                        *, lengths: Optional[jnp.ndarray] = None
-                        ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
-    """Single-dispatch prompt ingestion into a fresh DENSE cache: tokens
-    (B, T0) int32 -> (last_logits (B, padded_vocab) float32, cache).
-    Ragged rows are LEFT-padded with `lengths` (B,): the GQA layers mask
-    the pads' keys, the KDA layers step over the pads, and a pad is
-    routed to no expert.  The whole score matrix of each GQA layer: the
-    parity oracle, small sizes."""
-    B, T0 = tokens.shape
-    cache = solar_open2_init_cache(cfg, B)
-    col = jnp.arange(T0, dtype=jnp.int32)
-    if lengths is None:
-        start, real = jnp.zeros((B,), jnp.int32), None
-        mask = (col[None, :] <= col[:, None])[None]
-    else:
-        start = (T0 - jnp.asarray(lengths, jnp.int32)).astype(jnp.int32)
-        real = col[None, :] >= start[:, None]                   # (B, T0)
-        mask = (col[None, :] <= col[:, None])[None] & real[:, None, :]
-    x = embed(params, tokens, cfg)
-    new_kv, after = [], []
-
-    def layer(x, p, kind, j):
-        if kind == GQA:
-            def attend(q, k, v):
-                new_kv.append((k, v))
-                with jax.named_scope(scopes.ATTN_FULL):
-                    return attend_masked(q, k, v, mask, cfg)
-
-            return gqa_block(x, p, cfg, attend, valid=real)
-        x, stats, state, _ = kda_block(
-            x, p, cfg, *_layer_state(cache["conv"], cache["ssm"], j), real)
-        after.append(state)
-        return x, stats
-
-    x, stats = walk_layers(cfg, params, x, layer)
-    with jax.named_scope(scopes.KV_POOL):
-        for name, at in (("k", 0), ("v", 1)):
-            if new_kv:
-                cache[name] = lax.dynamic_update_slice(
-                    cache[name], jnp.stack([kv[at] for kv in new_kv]),
-                    (0, 0, 0, 0))
-    if after:
-        with jax.named_scope(scopes.LINEAR_STATE):
-            cache["conv"], cache["ssm"] = _stacked(after)
-    cache.update(start=start, pos=jnp.full((B,), T0, jnp.int32))
-    return lm_logits(x[:, -1], params, cfg), \
-        _with_counters(cache, cfg, stats)
-
-
-def solar_open2_paged_prefill(params, cache, tokens: jnp.ndarray,
-                              cfg: SolarOpen2Config, *,
-                              row_bt: jnp.ndarray, prefix_len, n_tail,
-                              slot, state=None
-                              ) -> Tuple[jnp.ndarray,
-                                         Dict[str, jnp.ndarray]]:
-    """Prompt-tail ingestion for ONE sequence against the block pool
-    (gpt2_decode.paged_prefill has the K/V half of the contract): tokens
-    (1, Tt) RIGHT-aligned tail of `n_tail` real columns after
-    `prefix_len` tokens whose GQA-layer K/V are resident in `row_bt`'s
-    blocks.
-
-    The recurrent half is jamba_decode.jamba_paged_prefill's: `state` is
-    int32 (3,) ``[source, snapshot entry, snapshot boundary]``.  The
-    slot's state starts from zeros (``STATE_FROM_ZERO``), from its own
-    rows (``STATE_FROM_SLOT``: the previous chunk of this prompt left
-    them) or from snapshot entry ``source >= 0``, which has to be the
-    state after exactly `prefix_len` tokens.  It ends as the state after
-    ``prefix_len + n_tail`` tokens, in row `slot`.  With ``snapshot
-    entry >= 0`` the state after ``snapshot boundary`` tokens
-    (``prefix_len < boundary <= prefix_len + n_tail``) is also written
-    into that entry of the snapshot pool.  None is a whole prompt from
-    zeros, no snapshot."""
-    _, Tt = tokens.shape
-    prefix_len = jnp.asarray(prefix_len, jnp.int32)
-    n_tail = jnp.asarray(n_tail, jnp.int32)
-    slot = jnp.asarray(slot, jnp.int32)
-    if state is None:
-        state = jnp.asarray([STATE_FROM_ZERO, NO_SNAPSHOT, 0], jnp.int32)
-    source, entry, boundary = state[0], state[1], state[2]
-    pad = Tt - n_tail
-    col = jnp.arange(Tt, dtype=jnp.int32)
-    real = col >= pad                          # (Tt,), False on pads
-    logical = prefix_len + col - pad           # position iff real
-    # pad columns MUST be masked writes (slot max_seq): their logical
-    # index can alias a live prefix slot
-    pkv = PagedKV(cache, row_bt[None],
-                  jnp.where(real, logical, cfg.max_seq)[None], whole=True)
-    pools = pkv.pools
-    # a GQA layer's keys are the row's gathered view
-    reach = prefill_reach(Tt, prefix_len, n_tail)
-    # the column after which the state is `boundary` tokens old
-    capture = jnp.clip(pad + boundary - prefix_len - 1, 0, Tt - 1)
-    keep = jnp.maximum(entry, 0)
-    # the slot's rows leave the big state ONCE, before the walk, and go
-    # back once after it (jamba_decode.jamba_paged_prefill)
-    own = _slot_rows(cache["conv"], cache["ssm"], slot)
-    held = _slot_rows(cache["snap_conv"], cache["snap_ssm"],
-                      jnp.maximum(source, 0))
-    with jax.named_scope(scopes.LINEAR_STATE):
-        begin = tuple(
-            jnp.where(source >= 0, h,
-                      jnp.where(source == STATE_FROM_SLOT, o,
-                                jnp.zeros_like(o)))
-            for o, h in zip(own, held))
-    x = embed(params, tokens, cfg)                             # (1, Tt, d)
-    ends, snaps = [], []
-
-    def layer(x, p, kind, j):
-        if kind == GQA:
-            def attend(q, k, v):
-                nonlocal pools
-                pools, (kview, vview) = pkv.attend(j, pools, k, v)
-                return attend_banded(q[0], kview[0], vview[0], *reach, cfg,
-                                     scopes.ATTN_FULL)[None]
-
-            return gqa_block(x, p, cfg, attend, valid=real[None])
-        x, stats, after, snap = kda_block(
-            x, p, cfg, *_layer_state(*begin, j), real[None], capture)
-        ends.append(after)
-        snaps.append(snap)
-        return x, stats
-
-    x, stats = walk_layers(cfg, params, x, layer)
-    # right-aligned: the last column is the last real one.  As eight
-    # equal rows: the product of one row is compiled as a float32
-    # multiply and sum over the whole head upcast
-    # (kimi_k2_decode.kimi_k2_paged_prefill)
-    logits = lm_logits(jnp.broadcast_to(x[0, -1], (8, cfg.d_model)),
-                       params, cfg)[0]
-    out = pkv.commit(pools)
-    if ends:
-        out["conv"], out["ssm"] = _land_rows(
-            cache["conv"], cache["ssm"], slot, *_stacked(ends))
-        # without a snapshot to leave, entry `keep` gets back what it has
-        kept = _slot_rows(cache["snap_conv"], cache["snap_ssm"], keep)
-        with jax.named_scope(scopes.LINEAR_STATE):
-            left = tuple(jnp.where(entry >= 0, new, old)
-                         for new, old in zip(_stacked(snaps), kept))
-        out["snap_conv"], out["snap_ssm"] = _land_rows(
-            cache["snap_conv"], cache["snap_ssm"], keep, *left)
-    out["block_tables"] = cache["block_tables"].at[slot].set(row_bt)
-    out["pos"] = cache["pos"].at[slot].set(prefix_len + n_tail)
-    out["start"] = cache["start"].at[slot].set(0)
-    return logits, _with_counters(out, cfg, stats)
-
-
-def solar_open2_decode_step(params, cache, tokens, cfg: SolarOpen2Config
-                            ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
-    """One token per sequence: tokens (B,) int32, row b at cache slot
-    ``cache["pos"][b]``.  Both cache layouts (decode_common.is_paged).
-    A row with ``pos == 0`` holds no sequence that decodes (module
-    docstring): it is routed to no expert, its recurrent state is left
-    as it is and it stays at ``pos == 0``; what it computes is the
-    masked garbage every family's idle rows produce.
-
-    Returns (logits (B, padded_vocab) float32, updated cache)."""
-    B = tokens.shape[0]
-    paged = is_paged(cache)
-    # what the program can see of its input picks the path (a paged
-    # cache, the chip): the kernel walks the pool's blocks where they
-    # lie; the CPU gathers the views and keeps the jnp path, the parity
-    # oracle (laguna_decode.laguna_decode_step)
-    walk = gqa_paged_decode if jax.default_backend() == "tpu" \
-        else gqa_paged_decode_reference
-    pos, start = cache["pos"], cache["start"]
-    active = pos > 0
-    rows = jnp.arange(B)
-    if paged:
-        pkv = PagedKV(cache, cache["block_tables"], pos[:, None],
-                      whole=True)
-    else:
-        with jax.named_scope(scopes.ATTN_FULL):
-            mask = slot_mask(start, pos + 1, cfg.max_seq)[:, None]
-    held = {n: cache[n] for n in ("k", "v", "conv", "ssm")}
-    fresh = []
-    x = embed(params, tokens, cfg)[:, None]                    # (B, 1, d)
-
-    def layer(x, p, kind, j):
-        if kind == KDA:
-            # the matrices go in and come back as the whole stack: layer
-            # j's are updated where they lie (ops/kda.py kda_decode)
-            x, stats, (window, held["ssm"]), _ = kda_block(
-                x, p, cfg, _layer_window(held["conv"], j), held["ssm"],
-                active[:, None], layer=j)
-            held["conv"] = _set_layer_window(held["conv"], j, window)
-            return x, stats
-
-        def attend(q, k, v):
-            q, k, v = q[:, 0], k[:, 0], v[:, 0]
-            if paged:
-                fresh.append((k, v))
-                with jax.named_scope(scopes.ATTN_FULL):
-                    return walk(
-                        q, held["k"], held["v"], cache["block_tables"],
-                        pos, j, (k, v), n_kv_head=cfg.n_kv_head,
-                        scale=1.0 / math.sqrt(cfg.head_dim),
-                        start=start)[:, None]
-            with jax.named_scope(scopes.KV_POOL):
-                for n, new in (("k", k), ("v", v)):
-                    held[n] = held[n].at[j, rows, pos].set(new)
-                view = (held["k"][j], held["v"][j])
-            with jax.named_scope(scopes.ATTN_FULL):
-                return attend_masked(q[:, None], *view, mask, cfg)
-
-        return gqa_block(x, p, cfg, attend, valid=active[:, None])
-
-    x, stats = walk_layers(cfg, params, x, layer)
-    logits = lm_logits(x[:, 0], params, cfg)
-    if paged:
-        # the pools were read-only in the walk: the rows land now, every
-        # GQA layer at once (PagedKV.commit)
-        out = pkv.commit(
-            (held["k"], held["v"]),
-            *(jnp.stack([kv[at] for kv in fresh])[:, :, None]
-              for at in (0, 1))) if fresh else dict(cache)
-    else:
-        out = dict(cache, k=held["k"], v=held["v"])
-    out.update(conv=held["conv"], ssm=held["ssm"])
-    with jax.named_scope(scopes.KV_POOL):
-        # a row without a sequence stays one: were its pos to count the
-        # steps it idled through, the next step would route it
-        out["pos"] = jnp.where(active, pos + 1, 0)
-    return logits, _with_counters(out, cfg, stats)
-
-
+# delta_decode's programs over the block (each documented there)
+solar_open2_init_cache = partial(delta_decode.init_cache, BLOCK)
+solar_open2_init_paged_cache = partial(delta_decode.init_paged_cache, BLOCK)
+solar_open2_prefill = partial(delta_decode.prefill, BLOCK)
+solar_open2_paged_prefill = partial(delta_decode.paged_prefill, BLOCK)
+solar_open2_decode_step = partial(delta_decode.decode_step, BLOCK)
+solar_open2_prefill_attention = partial(delta_decode.prefill_attention,
+                                        BLOCK)
 #: generation via the shared loop (decode_common.generate_with): one
 #: dense prefill, then the decode step scanned; the serve engine's
 #: parity oracle.  kv_layout="paged" re-lays the GQA layers' K/V into

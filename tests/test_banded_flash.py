@@ -1,6 +1,6 @@
 """ops/banded_flash.py: a prefill's banded flash kernel, in the Pallas
 interpreter, held to the `jnp` walk it replaces on the chip
-(models/laguna_decode.banded_walk); the choice between the two paths;
+(models/banded_attention.banded_walk); the choice between the two paths;
 and what the three families that share it count of it for the engine.
 
 Heads of 128 lanes (the kernel takes no other) at toy lengths: tiles
@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import laguna_decode as m
+from ray_tpu.models import banded_attention as m
 from ray_tpu.models.families import family
 from ray_tpu.ops import banded_flash as flash
 from ray_tpu.serve.llm import build_llm_deployment

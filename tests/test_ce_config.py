@@ -5,9 +5,9 @@ ValueErrors the old use_streaming_ce path raised at loss time)."""
 
 import pytest
 
-from ray_tpu.models.gpt2 import (CE_IMPLS, FLASH_RESIDENT_MODES,
-                                 GPT2Config, ce_config_problems,
-                                 gpt2_config)
+from ray_tpu.models.gpt2 import GPT2Config, gpt2_config
+from ray_tpu.models.layers import (CE_IMPLS, FLASH_RESIDENT_MODES,
+                                   ce_config_problems)
 from ray_tpu.models.llama import llama_config
 
 pytestmark = pytest.mark.fast

@@ -5,10 +5,14 @@
   ray_tpu/serve/;
 * `EngineOptions` checks the options against one another where they are
   made, without building a deployment;
-* the engine classes are module-level: no method reads a closure."""
+* the engine classes are module-level: no method reads a closure;
+* a family's two files import the shared modules and each other, never
+  another family's files."""
 
+import ast
 import asyncio
 import inspect
+import pathlib
 
 import numpy as np
 import pytest
@@ -151,3 +155,35 @@ def test_the_engine_is_a_module_level_class(scheduler, cls):
     assert cls.opt is None
     assert inspect.iscoroutinefunction(deployed.__call__)
     assert getattr(inspect.getmodule(cls), cls.__name__) is cls
+
+
+#: the one family that still builds on another's files, until latent
+#: attention has a module of its own (ROADMAP.md C2c, "latent attention
+#: to models/mla.py"): GLM-5's config subclasses Kimi-K2's and its
+#: programs call Kimi-K2's attention
+_BORROWS = {("glm_dsa", "kimi_k2")}
+
+
+def test_a_familys_files_name_no_other_family():
+    """An import under ray_tpu/models/ that reaches a family's files
+    (`<family>.py`, `<family>_decode.py`) comes from that family's own
+    pair, from the table of families, or from the package's
+    ``__init__``: what two families call lives in a module named for
+    what it is."""
+    root = pathlib.Path(families.__file__).parent
+    owner = {stem: name for name in families.FAMILIES
+             for stem in (name, name + "_decode")}
+    found = set()
+    for path in sorted(root.glob("*.py")):
+        if path.stem in ("families", "__init__"):
+            continue
+        for node in ast.walk(ast.parse(path.read_text())):
+            if not isinstance(node, ast.ImportFrom) or not (
+                    node.module or "").startswith("ray_tpu.models"):
+                continue
+            stems = [node.module.rsplit(".", 1)[-1]] \
+                + [alias.name for alias in node.names]
+            found |= {(owner.get(path.stem, path.stem), owner[stem])
+                      for stem in stems if stem in owner}
+    crossing = {(me, other) for me, other in found if me != other}
+    assert crossing == _BORROWS, sorted(crossing ^ _BORROWS)

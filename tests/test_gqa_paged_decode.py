@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from ray_tpu._private import scopes
+from ray_tpu.models import banded_attention
 from ray_tpu.models import experts
 from ray_tpu.models import laguna_decode as D
 from ray_tpu.models.laguna import laguna_config, laguna_init
@@ -27,7 +28,7 @@ def _steer(monkeypatch):
     """The decode step takes the kernel's path (the backend test says
     "tpu") and every kernel on it runs in the interpreter."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(D, "gqa_paged_decode", functools.partial(
+    monkeypatch.setattr(banded_attention, "gqa_paged_decode", functools.partial(
         gqa_paged_decode, interpret=True))
     # the chip's step moves the experts' rows by kernels too, and
     # multiplies them by one where they are few an expert

@@ -211,7 +211,7 @@ def test_pads_leave_the_recurrence_bit_equal(pads):
     chunks' edges fall elsewhere.  (What a bucket also changes is the
     row count of the projections' matmuls, which is the backend's: the
     next test.)"""
-    from ray_tpu.models.jamba import ssm_scan
+    from ray_tpu.models.mamba import ssm_scan
 
     rng = np.random.RandomState(pads)
     T, di, N = 21, 32, 4

@@ -22,7 +22,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import decode_common as dc
-from ray_tpu.models import families, laguna
+from ray_tpu.models import banded_attention, families, laguna
 from ray_tpu.models import laguna_decode as m
 from ray_tpu.models.laguna import laguna_config, laguna_init
 from ray_tpu.serve.llm import SpecConfig, build_llm_deployment
@@ -279,7 +279,7 @@ def _wrong_error(monkeypatch, params, want, cfg, patch):
 
 
 def _stale_rows_attended(mp):
-    mp.setattr(m, "_ring_mask",
+    mp.setattr(banded_attention, "_ring_mask",
                lambda pos, start, window: jnp.ones((pos.shape[0], window),
                                                    bool))
 

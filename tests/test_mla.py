@@ -13,6 +13,7 @@ import pytest
 
 from ray_tpu.models import decode_common as dc
 from ray_tpu.models import kimi_k2 as K
+from ray_tpu.models import layers
 from ray_tpu.models.kimi_k2_decode import (attend_blockwise,
                                            kimi_k2_decode_step,
                                            kimi_k2_generate,
@@ -257,8 +258,8 @@ def test_ragged_rows_decode_as_they_would_alone(tiny):
 
 def test_the_yarn_table_and_scale_are_the_published_models():
     cfg = K.kimi_k2_config("kimi-k2-code")
-    assert K.yarn_correction_range(cfg) == (8, 20)
-    inv = K.yarn_inv_freq(cfg)
+    assert layers.yarn_correction_range(cfg) == (8, 20)
+    inv = layers.yarn_inv_freq(cfg)
     f = 50000.0 ** (-np.arange(32) * 2 / 64)
     np.testing.assert_allclose(inv[:9], f[:9], rtol=1e-6)      # kept
     np.testing.assert_allclose(inv[20:], f[20:] / 64, rtol=1e-6)

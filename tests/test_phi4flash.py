@@ -341,7 +341,7 @@ def test_a_wrong_model_fails_the_tolerance(fault, params, want,
         cfg = ph.phi4flash_config("nano", dtype=jnp.float32,
                                   state_dtype=jnp.bfloat16)
     elif fault == "no_lambda_term":
-        real = ph._rmsnorm
+        real = ph.plain_rmsnorm
         monkeypatch.setattr(
             ph, "diff_out", lambda o, p, lam_init, c: _no_lambda(
                 o, p, lam_init, c, real))

@@ -14,6 +14,7 @@ import pytest
 from jax import lax
 
 from ray_tpu._private import scopes
+from ray_tpu.models import banded_attention as BA
 from ray_tpu.models import experts
 from ray_tpu.models import laguna_decode as D
 from ray_tpu.models import phi4flash_decode as P
@@ -73,8 +74,8 @@ def _kernel(q, wk, wv, j, pos, start, cfg, scale):
 
 
 def _oracle(q, wk, wv, j, pos, start, cfg, scale):
-    return D.attend_rows(q, wk[j], wv[j],
-                         D._ring_mask(pos, start, wk.shape[2]), cfg, scale)
+    return BA.attend_rows(q, wk[j], wv[j],
+                         BA._ring_mask(pos, start, wk.shape[2]), cfg, scale)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
@@ -97,7 +98,7 @@ def test_a_wave_through_the_kernel_is_attend_rows_over_the_ring_mask(
         np.testing.assert_allclose(got, want, atol=F32_ATOL)
         # what the mask hides is never attended: huge keys and values
         # there change nothing
-        hidden = ~D._ring_mask(pos, start, W)[None, :, :, None]
+        hidden = ~BA._ring_mask(pos, start, W)[None, :, :, None]
         layer = (jnp.arange(3) == 1)[:, None, None, None]
         got_again = _kernel(q, jnp.where(hidden & layer, 1e4, wk),
                             jnp.where(hidden & layer, -1e4, wv), 1, pos,
@@ -178,11 +179,10 @@ def _steer(monkeypatch):
     from ray_tpu.ops.gqa_paged_decode import gqa_paged_decode
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(D, "ring_decode", functools.partial(
+    monkeypatch.setattr(BA, "ring_decode", functools.partial(
         ring_decode, interpret=True))
-    for module in (D, P):
-        monkeypatch.setattr(module, "gqa_paged_decode", functools.partial(
-            gqa_paged_decode, interpret=True))
+    monkeypatch.setattr(BA, "gqa_paged_decode", functools.partial(
+        gqa_paged_decode, interpret=True))
     for kernel in ("moe_dispatch", "moe_combine", "_fused"):
         monkeypatch.setattr(experts, kernel, functools.partial(
             getattr(experts, kernel), interpret=True))

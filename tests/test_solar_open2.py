@@ -361,9 +361,9 @@ def test_a_wrong_model_fails_the_tolerance(fault, params, want,
 
 
 def _ungated(o, gate, p, c):
-    from ray_tpu.models import laguna
+    from ray_tpu.models import banded_attention
 
-    return laguna.attn_out(o, jnp.ones_like(gate), p, c)
+    return banded_attention.attn_out(o, jnp.ones_like(gate), p, c)
 
 
 # -- what the programs call their parts ---------------------------------------
