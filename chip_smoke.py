@@ -53,7 +53,9 @@ Phases, one chip:
            96 x 192, ONE decay a head, 1,024 and 6,144 columns, bf16
            operands) against the jnp matmul form, run unedited as the
            oracle: the largest relative error, both forms'
-           milliseconds a layer and compiled temporaries.
+           milliseconds a layer and compiled temporaries; and its
+           decode wave's kernel (32 rows, the middle layer of a stack
+           of three, float32) against `kda_step` on that layer.
   ring     the window layers' decode kernel at the Phi-4-mini-flash and
            Laguna-XS.2 cells' shapes (eight stacked rings of 64 rows x
            512 x 1,280 lanes under 10 pair-heads; three of 1,024 lanes
@@ -184,6 +186,8 @@ class Size:
     #: its largest
     delta_heads: tuple = (30, 96, 192)
     delta_prefills: tuple = ((1024, 37), (6144, 37))
+    #: (rows, linear layers in the stack) of its decode wave
+    delta_wave: tuple = (32, 3)
     # ring: (window layers, rows, window, query heads, K/V heads, head
     #: size, the scores' factor) of a decode wave over the stacked
     #: rings: Phi-4-mini-flash's eight window layers at its pair-heads
@@ -1270,10 +1274,12 @@ def check_kda_kernels(size: Size, *, interpret: bool = False) -> None:
     bucket against the recurrence over time in float32: a row with pads
     at its left (beta = 0, g = 0), a state handed in, beta up to 2, a
     captured column.
-    Then a decode wave's (`kda_decode`) on the middle layer of the
-    stacked state, donated, against its `jnp` form (`kda_step` on that
-    layer indexed out and set back): every fifth row idle, the other
-    layers and the idle rows back to the bit."""
+    Then a decode wave's (`kda_decode`: the call ``kda_decode`` at
+    Solar-Open2's heads, the call ``delta_decode`` with ONE decay a head
+    at Olmo-Hybrid's) on the middle layer of the stacked state, donated,
+    against its `jnp` form (`kda_step` on that layer indexed out and set
+    back): every fifth row idle, the other layers and the idle rows
+    back to the bit, the largest error printed."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -1343,50 +1349,55 @@ def check_kda_kernels(size: Size, *, interpret: bool = False) -> None:
             **{k: round(v, 6) for k, v in errs.items()})
         assert max(errs.values()) <= KERNEL_TOL, (name, T, errs)
 
-    B, layers = size.kda_wave
-    j = layers // 2
-    ks = jax.random.split(jax.random.PRNGKey(size.seed + 11), 6)
+    # (rows, layers, heads, keys, values, a decay's trailing size, the
+    # call's name): KDA's a decay a channel, then ONE decay a head
+    waves = [(*size.kda_wave, H, hd, hd, hd, "kda_decode"),
+             (*size.delta_wave, *size.delta_heads, 1, "delta_decode")]
+    for n, (B, layers, heads, dk, dv, gate, name) in enumerate(waves):
+        j = layers // 2
+        ks = jax.random.split(jax.random.PRNGKey(size.seed + 11 + n), 6)
 
-    def unit(key):
-        x = jax.random.normal(key, (B, H, hd))
-        return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+        def unit(key):
+            x = jax.random.normal(key, (B, heads, dk))
+            return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
 
-    idle = jnp.arange(B) % 5 == 1
-    g = jnp.where(idle[:, None, None], 0.0, np.log(0.5) + (
-        np.log(0.999) - np.log(0.5)) * jax.random.uniform(ks[3], (B, H, hd)))
-    beta = jnp.where(idle[:, None], 0.0,
-                     2.0 * jax.random.uniform(ks[4], (B, H)))
-    wave = (unit(ks[0]) * hd ** -0.5, unit(ks[1]),
-            jax.random.normal(ks[2], (B, H, hd)), g, beta)
-    stacked = jax.jit(lambda: jax.random.normal(ks[5],
-                                                (layers, B, H, hd, hd)))
-    before = stacked()
-    # all that may move: layer j's matrices of the rows that decode
-    moves = ((jnp.arange(layers) == j)[:, None] & ~idle)[:, :, None, None,
-                                                         None]
+        idle = jnp.arange(B) % 5 == 1
+        g = jnp.where(idle[:, None, None], 0.0, np.log(0.5) + (
+            np.log(0.999) - np.log(0.5)) * jax.random.uniform(
+                ks[3], (B, heads, gate)))
+        beta = jnp.where(idle[:, None], 0.0,
+                         2.0 * jax.random.uniform(ks[4], (B, heads)))
+        wave = (unit(ks[0]) * dk ** -0.5, unit(ks[1]),
+                jax.random.normal(ks[2], (B, heads, dv)), g, beta)
+        stacked = jax.jit(lambda: jax.random.normal(
+            ks[5], (layers, B, heads, dk, dv)))
+        before = stacked()
+        # all that may move: layer j's matrices of the rows that decode
+        moves = ((jnp.arange(layers) == j)[:, None]
+                 & ~idle)[:, :, None, None, None]
 
-    def timed_in_place(form, runs=10):
-        f = jax.jit(lambda *a: form(*a, j), donate_argnums=(5,))
-        o, stack = f(*wave, stacked())
-        first = jax.device_get(
-            (o, stack[j], jnp.all(moves | (stack == before))))
-        t0 = time.perf_counter()
-        for _ in range(runs):
-            o, stack = f(*wave, stack)
-        jax.block_until_ready(stack)
-        return first, (time.perf_counter() - t0) / runs * 1e3
+        def timed_in_place(form, runs=10):
+            f = jax.jit(lambda *a: form(*a, j), donate_argnums=(5,))
+            o, stack = f(*wave, stacked())
+            first = jax.device_get(
+                (o, stack[j], jnp.all(moves | (stack == before))))
+            t0 = time.perf_counter()
+            for _ in range(runs):
+                o, stack = f(*wave, stack)
+            jax.block_until_ready(stack)
+            return first, (time.perf_counter() - t0) / runs * 1e3
 
-    (o, after, kept), ms = timed_in_place(functools.partial(
-        kda_decode, interpret=interpret))
-    (want_o, want, _), ms_jnp = timed_in_place(_step_on_layer)
-    errs = {"o": _rel_err(o, want_o), "state": _rel_err(after, want)}
-    kept = bool(kept)
-    moved = 2 * B * H * hd * hd * 4
-    say("kda", kernel="kda_decode", shape=[layers, B, H, hd, hd],
-        ms=round(ms, 4), ms_jnp=round(ms_jnp, 4),
-        gb_per_s=round(moved / ms / 1e6, 1), kept_to_the_bit=kept,
-        **{k: round(v, 8) for k, v in errs.items()})
-    assert kept and max(errs.values()) <= KERNEL_TOL, ("kda_decode", errs)
+        (o, after, kept), ms = timed_in_place(functools.partial(
+            kda_decode, interpret=interpret))
+        (want_o, want, _), ms_jnp = timed_in_place(_step_on_layer)
+        errs = {"o": _rel_err(o, want_o), "state": _rel_err(after, want)}
+        kept = bool(kept)
+        moved = 2 * B * heads * dk * dv * 4
+        say("kda", kernel=name, shape=[layers, B, heads, dk, dv],
+            ms=round(ms, 4), ms_jnp=round(ms_jnp, 4),
+            gb_per_s=round(moved / ms / 1e6, 1), kept_to_the_bit=kept,
+            **{k: round(v, 8) for k, v in errs.items()})
+        assert kept and max(errs.values()) <= KERNEL_TOL, (name, errs)
 
 
 def phase_kda(size: Size, platform: str = "tpu") -> Dict[str, Any]:

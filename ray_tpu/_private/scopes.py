@@ -148,12 +148,17 @@ BANDED_FLASH = "banded_flash"
 #: Gated DeltaNet layer: `kda_chunk`'s grid and solve, one (C, C) mask a
 #: head where it has pairwise decays a channel (ops/kda.py)
 DELTA_CHUNK = "delta_chunk"
+#: a decode wave's delta rule with ONE decay a head, one call a Gated
+#: DeltaNet layer: a row's matrices (no whole lanes) read once and
+#: written once where they lie in the stacked state (ops/kda.py)
+DELTA_DECODE = "delta_decode"
 KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV,
            FLASH_RES_FWD, FLASH_RES_DQ, FLASH_RES_DKV,
            FLASH_TRI_FWD, FLASH_TRI_BWD, SSM_SCAN, MLA_PAGED_DECODE,
            MLA_ROTARY_LANES, MLA_FLASH_PREFILL, MOE_DISPATCH,
            MOE_COMBINE, GQA_PAGED_DECODE, GROUPED_SWIGLU, KDA_CHUNK,
-           KDA_DECODE, RING_DECODE, BANDED_FLASH, DELTA_CHUNK)
+           KDA_DECODE, RING_DECODE, BANDED_FLASH, DELTA_CHUNK,
+           DELTA_DECODE)
 
 # -- host phases -------------------------------------------------------------
 SPAN_PREFIX = "raytpu."

@@ -370,8 +370,9 @@ def deltanet_mix(p, h, cfg: OlmoHybridConfig, window, state, real=None,
     the columns that hold a token (pads first), and a pad moves neither
     window nor state.  capture: a traced column index (rows all alike)
     after which window and state are also handed back, for a snapshot.
-    One column goes through `kda_decode` (its `jnp` step: one decay a
-    head fits no step kernel), more through `kda_prefill` (on the chip
+    One column goes through `kda_decode` (on the chip the step kernel
+    for one decay a head, ``delta_decode``, on the stack where it lies;
+    the `jnp` step elsewhere), more through `kda_prefill` (on the chip
     the kernel for one decay a head, ``delta_chunk``; the `jnp` matmul
     form elsewhere).
 

@@ -30,9 +30,9 @@ an eigenvalue of -1).  Five forms of it, three of them plain
     the pace a read and a write through VMEM go at (~600 GB/s of the
     chip's 819).  The arithmetic is `kda_step`'s, float32, in its
     order; `_step_kernel` says how ``k``, ``q`` and ``exp(g)`` become
-    the per-sublane factors it needs without a rounding.  Everywhere
-    else (the CPU, heads of 16, a differentiated program) it is
-    `kda_step` on the layer indexed out and set back.
+    the per-sublane factors it needs without a rounding.  ONE decay a
+    head has a body of its own, below.  Everywhere else (the CPU, heads
+    of 16, a differentiated program) it is `kda_step` on the layer.
   * `kda_chunked`: a prefill's.  A chunk of ``C`` tokens enters with
     ``S_0``; with ``G_r = sum_{i <= r} g_i`` per channel,
 
@@ -62,9 +62,9 @@ an eigenvalue of -1).  Five forms of it, three of them plain
     Y) S_0`` with ``K-_i = k_i exp(G_C - G_i)``: everything but the two
     products with ``S_0`` is taken for all chunks of a prefill at once,
     in batched products, and only ``S_0 -> S_C`` and the state's part
-    of ``O`` run in the `lax.scan`.  The step kernel below takes no
-    scalar gate (a decode wave keeps `kda_step`); a prefill on the chip
-    has a kernel of its own (`kda_chunk`, its second body).
+    of ``O`` run in the `lax.scan`.  On the chip a decode wave's step is
+    the call ``delta_decode`` (`_head_gate_step_kernel`: a row's heads a
+    grid step), a prefill's `kda_chunk`'s second body, ``delta_chunk``.
 
 Every exponent above is <= 0, and the code keeps it so.  ``exp(-G_j)``
 is never formed alone: the factored ``(k_i e^{G_i}) . (k_j e^{-G_j})``
@@ -1170,23 +1170,158 @@ _step_kernel_form.defvjp(_step_jnp_fwd, _step_jnp_bwd)
 _step_kernel_call = jax.jit(_step_kernel_form, static_argnums=(7,))
 
 
+# ... with ONE decay a head
+
+#: a grid step's matrices at most, in bytes as they lie (a row's thirty
+#: of 96 x 192 are 96 x 256 lanes each, 2.95 MB: one step a row, and
+#: the wave's own operands go in as they are), and the VMEM the kernel
+#: may take: the block in and out, each buffered twice.  What the chip
+#: said (my chip runs, PR 61; 32 rows of 30 heads, one layer of six,
+#: donated; `kda_step` on the layer 0.576 ms, a kernel that only copies
+#: the blocks 0.326-0.335): 0.321-0.339 ms a call whether a step holds
+#: 5, 6, 10, 15 or 30 heads, and with a head's arithmetic done four
+#: times over still 0.327: the copies are all it costs
+_GATE_WAVE_BLOCK = 3 * 2 ** 20
+_GATE_WAVE_VMEM = 32 * 2 ** 20
+
+
+def _head_gate_step_kernel(j_ref, q_ref, k_ref, v_ref, g_ref, b_ref, s_ref,
+                           o_ref, st_ref, rows_ref):
+    """One (row, group of heads) grid step for ONE decay a head.  q, k
+    (1, 1, heads, dk), v, o (1, 1, heads, dv), g, b (1, 1, heads, 1):
+    the group's heads on the sublanes; s, st (1, 1, heads, dk, dv): the
+    group's matrices of layer ``j_ref[0]``, the same block of the same
+    buffer in and out.  Scratch `rows` (2, heads, dv): a head's decay
+    and its beta along the lanes.
+
+    `kda_step`'s operations in its order, float32.  The decay is a
+    number a head, so it multiplies a matrix as a row spread along the
+    sublanes, as ``delta`` does.  ``k`` and ``q`` are needed as COLUMNS:
+    the group's rows are transposed on the MXU (the identity times
+    their transpose at the highest precision: a float32 value's three
+    bfloat16 parts times 1, summed to the value to the bit), and a
+    head's column spread along the lanes is twelve lane broadcasts,
+    hidden under the matrices' copies.  A matrix of 192 lanes lies as a
+    tile and a half, the second tile's upper half masked in every
+    operation."""
+    heads, dk = k_ref.shape[2:]
+    dv = v_ref.shape[-1]
+    eye = (lax.broadcasted_iota(jnp.int32, (dk, dk), 0)
+           == lax.broadcasted_iota(jnp.int32, (dk, dk), 1)).astype(_F32)
+
+    def columns(rows):          # (heads, dk) -> (dk, heads)
+        return lax.dot_general(eye, rows, (((1,), (1,)), ((), ())),
+                               precision=lax.Precision.HIGHEST,
+                               preferred_element_type=_F32)
+
+    k, q, v = columns(k_ref[0, 0]), columns(q_ref[0, 0]), v_ref[0, 0]
+    # (through VMEM: a number spread along lanes AND sublanes at once is
+    # a broadcast the compiler does not have)
+    rows_ref[0] = jnp.broadcast_to(jnp.exp(g_ref[0, 0]), (heads, dv))
+    rows_ref[1] = jnp.broadcast_to(b_ref[0, 0], (heads, dv))
+    out = []
+    for h in range(heads):
+        k_h = k[:, h:h + 1]
+        decayed = s_ref[0, 0, h] * rows_ref[0, h:h + 1, :]
+        seen = jnp.sum(decayed * k_h, axis=0, keepdims=True)   # S'^T k
+        delta = rows_ref[1, h:h + 1, :] * (v[h:h + 1] - seen)
+        new = decayed + k_h * delta
+        st_ref[0, 0, h] = new
+        out.append(jnp.sum(new * q[:, h:h + 1], axis=0, keepdims=True))
+    o_ref[0, 0] = jnp.concatenate(out, axis=0)
+
+
+def _fits_the_head_gate_step_kernel(stack, g) -> bool:
+    """float32 matrices, ONE decay a head, keys of whole sublane tiles
+    and values that fill at least half of the lanes they lie in (96 x
+    192: twelve tiles by 192 of 256 lanes; heads of 12 x 24 do not)."""
+    _, _, _, dk, dv = stack.shape
+    return (stack.dtype == _F32 and g.shape[-1] == 1 < dk
+            and dk % _SUBLANES == 0 and 2 * dv >= _to_lanes(dv))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def _head_gate_step_form(q, k, v, g, beta, stack, j, interpret):
+    """`_step_kernel_form` for ONE decay a head, g (B, H, 1).  The
+    heads go in the largest groups whose matrices fit a grid step's
+    bytes, a whole row where thirty do: the matrices' head axis is a
+    leading axis of the stack, so a group is a block index there, and
+    the wave's own operands are handed in a group a block (as they are,
+    where a group is a row): nothing is sliced at sublanes that are no
+    tile's first."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    _, B, H, dk, dv = stack.shape
+    most = max(1, _GATE_WAVE_BLOCK // (dk * _to_lanes(dv) * 4))
+    heads = next(n for n in range(min(most, H), 0, -1) if H % n == 0)
+
+    def grouped(a):             # (B, H, d) -> (B, groups, heads, d)
+        return a.reshape(B, H // heads, heads, a.shape[-1])
+
+    def group(d):               # a (row, group)'s own, whole
+        return pl.BlockSpec((1, 1, heads, d), lambda b, h, j: (b, h, 0, 0))
+
+    matrices = pl.BlockSpec((1, 1, heads, dk, dv),
+                            lambda b, h, j: (j[0], b, h, 0, 0))
+    with jax.named_scope(scopes.ATTN_LINEAR):
+        o, stack = pl.pallas_call(
+            _head_gate_step_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(B, H // heads),
+                in_specs=[group(dk), group(dk), group(dv), group(1),
+                          group(1), matrices],
+                out_specs=[group(dv), matrices],
+                scratch_shapes=[pltpu.VMEM((2, heads, dv), _F32)]),
+            out_shape=[jax.ShapeDtypeStruct((B, H // heads, heads, dv), _F32),
+                       jax.ShapeDtypeStruct(stack.shape, _F32)],
+            # the stack is the result, as `_step_kernel_form`'s is
+            input_output_aliases={6: 1},
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel"),
+                vmem_limit_bytes=_GATE_WAVE_VMEM),
+            interpret=interpret,
+            name=scopes.DELTA_DECODE,
+        )(jnp.reshape(j, (1,)), *(grouped(a) for a in (
+            q, k, v, g, beta[..., None])), stack)
+    return o.reshape(B, H, dv), stack
+
+
+_head_gate_step_form.defvjp(_step_jnp_fwd, _step_jnp_bwd)
+_head_gate_step_call = jax.jit(_head_gate_step_form, static_argnums=(7,))
+
+
+def _step_call_for(stack, g):
+    """The jitted step kernel whose body takes these shapes, by ``g``'s
+    trailing size, or None."""
+    if _fits_the_step_kernel(stack, g):
+        return _step_kernel_call
+    if _fits_the_head_gate_step_kernel(stack, g):
+        return _head_gate_step_call
+    return None
+
+
 def kda_decode(q, k, v, g, beta, stack, j, *, interpret: bool = False):
     """A decode wave's delta rule on layer `j` of the KDA layers'
-    stacked state.  q, k, g (B, H, dk); v (B, H, dv); beta (B, H);
-    stack (n, B, H, dk, dv) float32; j an index into its first axis.
-    Returns (o (B, H, dv) float32, the stack with layer `j` one token
-    on and every other layer as it was).
+    stacked state.  q, k (B, H, dk); g (B, H, dk) or (B, H, 1): one
+    decay a head; v (B, H, dv); beta (B, H); stack (n, B, H, dk, dv)
+    float32; j an index into its first axis.  Returns (o (B, H, dv)
+    float32, the stack with layer `j` one token on and every other
+    layer as it was).
 
-    By the form that fits what the program can see: one Pallas call
-    named ``kda_decode`` on the chip where a head is whole lanes and
-    the heads whole groups of eight (module docstring), else `kda_step`
-    on the layer indexed out and set back: the CPU, heads of 16.
-    ``interpret=True`` runs the kernel in the Pallas interpreter where
-    the shapes fit it (the CPU tests).  Differentiated, it is the `jnp`
-    form, forward and backward."""
+    By the form that fits what the program can see, ONE Pallas call on
+    the chip either way (module docstring): with a decay a channel,
+    where a head is whole lanes and the heads whole groups of eight,
+    the call named ``kda_decode``; with ONE decay a head (``g`` of
+    trailing size 1), keys of whole sublane tiles and values that fill
+    half their lanes, the call named ``delta_decode``; else `kda_step`
+    on the layer indexed out and set back: the CPU, heads of 16, a
+    state that is not float32.  ``interpret=True`` runs the kernel in
+    the Pallas interpreter where the shapes fit one (the CPU tests).
+    Differentiated, it is the `jnp` form, forward and backward."""
     j = jnp.asarray(j, jnp.int32)
-    if not (_fits_the_step_kernel(stack, g)
-            and (interpret or jax.default_backend() == "tpu")):
+    call = _step_call_for(stack, g) if (
+        interpret or jax.default_backend() == "tpu") else None
+    if call is None:
         return _step_on_layer(q, k, v, g, beta, stack, j)
-    return _step_kernel_call(*(a.astype(_F32) for a in (q, k, v, g, beta)),
-                             stack, j, interpret)
+    return call(*(a.astype(_F32) for a in (q, k, v, g, beta)), stack, j,
+                interpret)

@@ -1,9 +1,10 @@
 """ops/kda.py: the chunked delta rule and the one-token step against the
 recurrence over time, in float32 on the CPU; each form twice: the `jnp`
 one at small heads, and the kernel (`kda_chunk`, `kda_decode`, in the
-Pallas interpreter) at heads of whole lanes; and the `jnp` forms again
-with ONE decay a head (``g`` of trailing size 1), where the chunk is
-matmuls only."""
+Pallas interpreter) at heads of whole lanes; and both again with ONE
+decay a head (``g`` of trailing size 1), where the `jnp` chunk is
+matmuls only and the kernels (``delta_chunk``, ``delta_decode``) take
+heads of 96 x 192."""
 
 import functools
 
@@ -18,10 +19,14 @@ from ray_tpu.ops.kda import (_solve_unit_lower, kda_chunk, kda_chunked,
                              kda_step)
 
 B, H, DK, DV = 2, 3, 16, 8
+#: the oracle and the `jnp` chunk form as programs the module's cases
+#: share: a scan run an operation at a time is compiled anew a call
+_recurrent = jax.jit(kda_recurrent)
+_chunked = jax.jit(kda_chunked, static_argnames=("chunk", "sub", "dtype"))
 #: the two chunk forms and the sizes each is run at: the kernel takes
 #: heads of whole lanes, one row of three heads (an odd count: a head a
 #: grid step)
-FORMS = {"jnp": (kda_chunked, (B, H, DK, DV)),
+FORMS = {"jnp": (_chunked, (B, H, DK, DV)),
          "kernel": (functools.partial(kda_chunk, interpret=True),
                     (1, 3, 128, 128))}
 forms = pytest.mark.parametrize("form", sorted(FORMS))
@@ -64,6 +69,13 @@ def _close(got, want, tol=2e-5):
     assert float(jnp.max(jnp.abs(got - want))) <= tol * scale
 
 
+def _traced_grad(loss, x):
+    """`loss`'s gradient at `x` traced ONCE: (the jaxpr's text, the
+    gradient)."""
+    traced = jax.jit(jax.grad(loss)).trace(x)
+    return str(traced.jaxpr), traced.lower().compile()(x)
+
+
 @pytest.mark.parametrize("form,chunk,sub,T", [
     ("jnp", c, s, T) for c, s in [(16, 16), (64, 16), (32, 8)]
     for T in (64, 83)] + [
@@ -77,7 +89,7 @@ def test_chunked_is_the_recurrence(form, chunk, sub, T):
     a carried-in state, beta up to 2."""
     chunked, dims = FORMS[form]
     xs, s0 = _inputs(T + chunk, T, dims=dims)
-    o, s = kda_recurrent(*xs, s0)
+    o, s = _recurrent(*xs, s0)
     oc, sc, snap = chunked(*xs, s0, chunk=chunk, sub=sub,
                            dtype=jnp.float32)
     assert snap is None and oc.shape == o.shape == (
@@ -85,7 +97,7 @@ def test_chunked_is_the_recurrence(form, chunk, sub, T):
     _close(oc, o)
     _close(sc, s)
     if form == "kernel":        # and the `jnp` form it stands for
-        oj, sj, _ = kda_chunked(*xs, s0, chunk=chunk, sub=sub,
+        oj, sj, _ = _chunked(*xs, s0, chunk=chunk, sub=sub,
                                 dtype=jnp.float32)
         _close(oc, oj)
         _close(sc, sj)
@@ -111,7 +123,7 @@ def test_two_calls_are_one(form):
 def test_no_state_is_a_zero_state(form):
     chunked, dims = FORMS[form]
     xs, _ = _inputs(3, 40, state=False, dims=dims)
-    o, s = kda_recurrent(*xs)
+    o, s = _recurrent(*xs)
     oc, sc, _ = chunked(*xs, chunk=16, dtype=jnp.float32)
     _close(oc, o)
     _close(sc, s)
@@ -125,11 +137,11 @@ def test_no_state_is_a_zero_state(form):
 def test_the_captured_state_is_the_state_after_that_token(form, capture):
     chunked, dims = FORMS[form]
     xs, s0 = _inputs(11, 83, dims=dims)
-    want = kda_recurrent(*(a[:, :capture + 1] for a in xs), s0)[1]
+    want = _recurrent(*(a[:, :capture + 1] for a in xs), s0)[1]
     o, s, snap = _captured(form)(xs, s0, capture)
     _close(snap, want)
-    _close(s, kda_recurrent(*xs, s0)[1])
-    _close(o, kda_recurrent(*xs, s0)[0])
+    _close(s, _recurrent(*xs, s0)[1])
+    _close(o, _recurrent(*xs, s0)[0])
 
 
 @functools.lru_cache(maxsize=None)
@@ -146,7 +158,7 @@ def test_a_strong_decay_overflows_nothing(form, chunk, sub):
     decay reaches exp(-590), and ``exp(-G_j)`` alone would be inf."""
     chunked, dims = FORMS[form]
     xs, s0 = _inputs(5, 128, decay=(1e-4, 0.9), dims=dims)
-    o, s = kda_recurrent(*xs, s0)
+    o, s = _recurrent(*xs, s0)
     oc, sc, _ = chunked(*xs, s0, chunk=chunk, sub=sub, dtype=jnp.float32)
     assert bool(jnp.all(jnp.isfinite(oc))) and bool(
         jnp.all(jnp.isfinite(sc)))
@@ -167,7 +179,7 @@ def test_a_pad_is_an_identity_step(form, pads):
     g_p = jnp.where(real[None, :, None, None], g, 0.0)
     b_p = jnp.where(real[None, :, None], beta, 0.0)
     o, s, _ = chunked(q, k, v, g_p, b_p, s0, chunk=16, dtype=jnp.float32)
-    want_o, want_s = kda_recurrent(q[:, pads:], k[:, pads:], v[:, pads:],
+    want_o, want_s = _recurrent(q[:, pads:], k[:, pads:], v[:, pads:],
                                    g[:, pads:], beta[:, pads:], s0)
     _close(o[:, pads:], want_o)
     _close(s, want_s)
@@ -195,7 +207,7 @@ def test_equal_keys_and_beta_two_stay_bounded(form):
     v = jax.random.normal(jax.random.PRNGKey(0), (1, T, 1, DV))
     g = jnp.zeros((1, T, 1, DK))
     beta = jnp.full((1, T, 1), 2.0)
-    o, s = kda_recurrent(k, k, v, g, beta)
+    o, s = _recurrent(k, k, v, g, beta)
     oc, sc, _ = chunked(k, k, v, g, beta, chunk=64, dtype=jnp.float32)
     _close(oc, o)
     _close(sc, s)
@@ -215,7 +227,7 @@ def test_a_step_is_one_step_of_the_recurrence(form):
     step, dims = STEP_FORMS[form]
     (q, k, v, g, beta), s0 = _inputs(4, 1, dims=dims)
     o, s = step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], s0)
-    want_o, want_s = kda_recurrent(q, k, v, g, beta, s0)
+    want_o, want_s = _recurrent(q, k, v, g, beta, s0)
     _close(o, want_o[:, 0])
     _close(s, want_s)
     # by the definition, written out for one head of one row
@@ -253,7 +265,7 @@ def test_steps_of_equal_keys_and_beta_two_stay_bounded(form):
     v = jax.random.normal(jax.random.PRNGKey(0), (1, T, heads, DV))
     g = jnp.zeros((1, T, heads, DK))
     beta = jnp.full((1, T, heads), 2.0)
-    want_o, want_s = kda_recurrent(k, k, v, g, beta)
+    want_o, want_s = _recurrent(k, k, v, g, beta)
     s = jnp.zeros((1, heads, DK, DV))
     for t in range(T):
         o, s = step(k[:, t], k[:, t], v[:, t], g[:, t], beta[:, t], s)
@@ -309,10 +321,8 @@ def test_the_step_kernel_runs_only_where_it_fits(monkeypatch):
                               s0[None], 0, interpret=interpret)
         return jnp.sum(o * o) + jnp.sum(stack)
 
-    _close(jax.grad(functools.partial(loss, True))(v[:, 0]),
-           jax.grad(functools.partial(loss, False))(v[:, 0]))
-    text = str(jax.make_jaxpr(jax.grad(functools.partial(loss, True)))(
-        v[:, 0]))
+    text, grad = _traced_grad(functools.partial(loss, True), v[:, 0])
+    _close(grad, _traced_grad(functools.partial(loss, False), v[:, 0])[1])
     assert "pallas_call" not in text
 
 
@@ -322,7 +332,7 @@ def test_bf16_operands_accumulate_in_float32(form):
     bf16's rounding of the recurrence."""
     chunked, dims = FORMS[form]
     xs, s0 = _inputs(8, 96, decay=(0.9, 0.999), dims=dims)
-    o, s = kda_recurrent(*xs, s0)
+    o, s = _recurrent(*xs, s0)
     oc, sc, _ = chunked(*xs, s0, chunk=64, dtype=jnp.bfloat16)
     assert oc.dtype == sc.dtype == jnp.float32
     _close(oc, o, tol=3e-2)
@@ -355,7 +365,7 @@ def test_the_kernel_runs_only_where_it_fits(monkeypatch):
     monkeypatch.setattr(kda, "kda_chunk", lambda *a, **k: called.append(
         "kernel") or kda_chunk(*a, interpret=True, **k))
     xs, s0 = _inputs(2, 24)
-    want = kda_chunked(*xs, s0, chunk=16, dtype=jnp.float32)
+    want = _chunked(*xs, s0, chunk=16, dtype=jnp.float32)
     got = kda_prefill(*xs, s0, chunk=16, dtype=jnp.float32)
     assert not called                               # the CPU
     _close(got[0], want[0])
@@ -367,18 +377,17 @@ def test_the_kernel_runs_only_where_it_fits(monkeypatch):
     assert not called                               # one column
     got = kda_prefill(*wide, w0, chunk=16, dtype=jnp.float32)
     assert called == ["kernel"]
-    _close(got[0], kda_chunked(*wide, w0, chunk=16, dtype=jnp.float32)[0])
+    _close(got[0], _chunked(*wide, w0, chunk=16, dtype=jnp.float32)[0])
 
     def loss(form, v):
         q, k, _, g, beta = wide
         o, s, _ = form(q, k, v, g, beta, w0, chunk=16, dtype=jnp.float32)
         return jnp.sum(o * o) + jnp.sum(s)
 
-    grad = jax.grad(functools.partial(loss, functools.partial(
-        kda_chunk, interpret=True)))(wide[2])
-    _close(grad, jax.grad(functools.partial(loss, kda_chunked))(wide[2]))
-    text = str(jax.make_jaxpr(jax.grad(functools.partial(
-        loss, functools.partial(kda_chunk, interpret=True))))(wide[2]))
+    text, grad = _traced_grad(functools.partial(loss, functools.partial(
+        kda_chunk, interpret=True)), wide[2])
+    _close(grad, _traced_grad(functools.partial(loss, kda_chunked),
+                              wide[2])[1])
     assert "pallas_call" not in text and "scan" in text
 
 
@@ -411,8 +420,7 @@ def _compiled(chunk, sub=16, dtype=jnp.float32, form="jnp"):
     if form == "kernel":
         return functools.partial(kda_chunk, chunk=chunk, sub=sub,
                                  dtype=dtype, interpret=True)
-    return jax.jit(functools.partial(kda_chunked, chunk=chunk, sub=sub,
-                                     dtype=dtype))
+    return functools.partial(_chunked, chunk=chunk, sub=sub, dtype=dtype)
 
 
 @pytest.mark.parametrize("form,dims,chunk,sub,T", [
@@ -430,7 +438,7 @@ def test_one_decay_a_head_chunked_is_the_recurrence(form, dims, chunk, sub,
     every channel of a head is handed the same number.  The kernel: the
     recurrence, and the `jnp` form it stands for."""
     xs, s0 = _one_decay(T + chunk, T, dims)
-    o, s = kda_recurrent(*xs, s0)
+    o, s = _recurrent(*xs, s0)
     oc, sc, snap = _compiled(chunk, sub, form=form)(*xs, s0)
     assert snap is None and oc.shape == o.shape
     _close(oc, o)
@@ -461,7 +469,7 @@ def test_one_strong_decay_a_head_overflows_nothing(form, chunk, sub):
     inf inside one chunk (exp(295) over 32 tokens); the (C, C) mask's
     exponents are sums of non-positive terms."""
     xs, s0 = _one_decay(5, 128, ONE_FORMS[form], decay=(1e-4, 0.9))
-    o, s = kda_recurrent(*xs, s0)
+    o, s = _recurrent(*xs, s0)
     oc, sc, _ = _compiled(chunk, sub, form=form)(*xs, s0)
     assert bool(jnp.all(jnp.isfinite(oc))) and bool(
         jnp.all(jnp.isfinite(sc)))
@@ -480,7 +488,7 @@ def test_one_decay_a_head_a_pad_is_an_identity_step(form, pads):
     g_p = jnp.where(real[None, :, None, None], g, 0.0)
     b_p = jnp.where(real[None, :, None], beta, 0.0)
     o, s, _ = chunked(q, k, v, g_p, b_p, s0)
-    want_o, want_s = kda_recurrent(q[:, pads:], k[:, pads:], v[:, pads:],
+    want_o, want_s = _recurrent(q[:, pads:], k[:, pads:], v[:, pads:],
                                    g[:, pads:], beta[:, pads:], s0)
     _close(o[:, pads:], want_o)
     _close(s, want_s)
@@ -495,17 +503,17 @@ def test_one_decay_a_head_the_captured_state(form, capture):
     """A chunk's first, a middle and its last column, in the first
     chunk, a middle one and the last, which the length does not fill."""
     xs, s0 = _one_decay(11, 83, ONE_FORMS[form])
-    want = kda_recurrent(*(a[:, :capture + 1] for a in xs), s0)[1]
+    want = _recurrent(*(a[:, :capture + 1] for a in xs), s0)[1]
     o, s, snap = _captured(form)(xs, s0, capture)
     _close(snap, want)
-    _close(s, kda_recurrent(*xs, s0)[1])
-    _close(o, kda_recurrent(*xs, s0)[0])
+    _close(s, _recurrent(*xs, s0)[1])
+    _close(o, _recurrent(*xs, s0)[0])
 
 
 @one_forms
 def test_one_decay_a_head_bf16_operands_accumulate_in_float32(form):
     xs, s0 = _one_decay(8, 96, ONE_FORMS[form], decay=(0.9, 0.999))
-    o, s = kda_recurrent(*xs, s0)
+    o, s = _recurrent(*xs, s0)
     oc, sc, _ = _compiled(64, dtype=jnp.bfloat16, form=form)(*xs, s0)
     assert oc.dtype == sc.dtype == jnp.float32
     _close(oc, o, tol=3e-2)
@@ -523,7 +531,7 @@ def test_one_decay_a_head_equal_keys_and_beta_two_stay_bounded(form):
     v = jax.random.normal(jax.random.PRNGKey(0), (1, T, heads, dv))
     g = jnp.zeros((1, T, heads, 1))
     beta = jnp.full((1, T, heads), 2.0)
-    o, s = kda_recurrent(k, k, v, g, beta)
+    o, s = _recurrent(k, k, v, g, beta)
     oc, sc, _ = _compiled(64, form=form)(k, k, v, g, beta)
     _close(oc, o)
     _close(sc, s)
@@ -537,25 +545,124 @@ def test_one_decay_a_head_groups_of_heads_are_heads_alone(monkeypatch):
     xs, s0 = _one_decay(3, 40, (2, 6, 96, 192))
     oc, sc, snap = kda_chunk(*xs, s0, chunk=16, sub=8, dtype=jnp.float32,
                              capture=jnp.int32(21), interpret=True)
-    o, s = kda_recurrent(*xs, s0)
+    o, s = _recurrent(*xs, s0)
     _close(oc, o)
     _close(sc, s)
-    _close(snap, kda_recurrent(*(a[:, :22] for a in xs), s0)[1])
+    _close(snap, _recurrent(*(a[:, :22] for a in xs), s0)[1])
 
 
-def test_a_step_with_one_decay_a_head_is_the_recurrence():
-    (q, k, v, g, beta), s0 = _one_decay(4, 1, (2, 5, 12, 24))
+#: the published head of a Gated DeltaNet layer: two rows of thirty
+#: heads of 96 x 192, a row a grid step
+PUBLISHED = (2, 30, 96, 192)
+#: the two step forms of one decay a head and the sizes each is run
+#: at: the kernel takes keys of whole sublane tiles and values that
+#: fill half their lanes, and its cases share one trace of the jitted
+#: call (the published head, a stack of three)
+ONE_STEP_FORMS = {"jnp": (2, 5, 12, 24), "kernel": PUBLISHED}
+
+
+@pytest.mark.parametrize("form", sorted(ONE_STEP_FORMS))
+def test_a_step_with_one_decay_a_head_is_the_recurrence(form):
+    (q, k, v, g, beta), s0 = _one_decay(4, 1, ONE_STEP_FORMS[form])
     o, s = kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], s0)
     a = float(np.exp(g[0, 0, 0, 0])) * np.asarray(s0[0, 0], np.float64)
     kk, vv = (np.asarray(x[0, 0, 0], np.float64) for x in (k, v))
     new = a + float(beta[0, 0, 0]) * np.outer(kk, vv - a.T @ kk)
     np.testing.assert_allclose(s[0, 0], new, rtol=1e-5, atol=1e-5)
-    stack = jnp.stack([s0, 2 * s0])
+    stack = jnp.stack([s0, 2 * s0, -s0])
     o1, after = kda_decode(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
-                           stack, 1)
-    _close(after[1], kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
-                              beta[:, 0], 2 * s0)[1])
-    assert bool(jnp.all(after[0] == s0))
+                           stack, 1, interpret=form == "kernel")
+    want_o, want = kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
+                            2 * s0)
+    _close(o1, want_o)
+    _close(after[1], want)
+    assert bool(jnp.all(after[0] == s0)) and bool(jnp.all(after[2] == -s0))
+
+
+@pytest.fixture(scope="module")
+def published_wave():
+    """One wave at the published head through the kernel for ONE decay
+    a head (interpreted, run once for the module's cases): the middle
+    layer of a stack of three, row 0 a pad (``g`` = 0, ``beta`` = 0).
+    (the wave's operands, the stack, o, the stack after)."""
+    (q, k, v, g, beta), _ = _one_decay(21, 1, PUBLISHED)
+    pad = jnp.arange(PUBLISHED[0]) == 0
+    wave = (q[:, 0], k[:, 0], v[:, 0],
+            jnp.where(pad[:, None, None], 0.0, g[:, 0]),
+            jnp.where(pad[:, None], 0.0, beta[:, 0]))
+    stack = jax.random.normal(jax.random.PRNGKey(3), (3, *PUBLISHED))
+    return wave, stack, *kda_decode(*wave, stack, 1, interpret=True)
+
+
+def test_the_head_gate_step_kernel_is_kda_step_at_the_published_head(
+        published_wave):
+    """Thirty heads of 96 x 192, a row a grid step: the layer it names
+    is `kda_step` on its slice, the two others come back TO THE BIT."""
+    wave, stack, o, after = published_wave
+    want_o, want = kda_step(*wave, stack[1])
+    assert o.shape == want_o.shape and after.shape == stack.shape
+    assert o.dtype == after.dtype == jnp.float32
+    _close(o, want_o)
+    _close(after[1], want)
+    for other in (0, 2):
+        assert bool(jnp.all(after[other] == stack[other]))
+
+
+def test_the_head_gate_step_kernel_leaves_a_padded_row_to_the_bit(
+        published_wave):
+    """``g`` = 0 and ``beta`` = 0 (a decode pool's row without a
+    sequence), beside a row that moves."""
+    _, stack, _, after = published_wave
+    assert bool(jnp.all(after[1, 0] == stack[1, 0]))
+    assert not bool(jnp.all(after[1, 1] == stack[1, 1]))
+
+
+@pytest.mark.parametrize("dims,channel,dtype", [
+    ((1, 5, 96, 192), False, jnp.bfloat16),     # a state that is no float32
+    ((1, 5, 96, 192), True, jnp.float32),       # a decay a channel
+    ((1, 5, 12, 64), False, jnp.float32),       # keys of a tile and a half
+    ((1, 5, 96, 24), False, jnp.float32),       # values of 24 in 128 lanes
+], ids=["bf16_state", "a_decay_a_channel", "keys_of_12", "values_of_24"])
+def test_the_head_gate_step_kernel_runs_only_where_it_fits(dims, channel,
+                                                           dtype):
+    """One shape a branch of `_fits_the_head_gate_step_kernel`: each
+    falls back to `kda_step` on the layer indexed out and set back,
+    asked for interpreted or not, and gives what that gives."""
+    (q, k, v, g, beta), s0 = _inputs(2, 1, dims=dims)
+    wave = (q[:, 0], k[:, 0], v[:, 0], g[:, 0, :, :None if channel else 1],
+            beta[:, 0])
+    stack = jnp.stack([s0, 2 * s0]).astype(dtype)
+    assert not kda._fits_the_head_gate_step_kernel(stack, wave[3])
+    assert kda._fits_the_head_gate_step_kernel(
+        jnp.zeros((2, 1, 5, 96, 192)), jnp.zeros((1, 5, 1)))
+    assert kda._step_call_for(stack, wave[3]) is None
+    program = jax.jit(lambda stack: (
+        kda_decode(*wave, stack, 1, interpret=True),
+        kda_step(*wave, stack[1]))).trace(stack)
+    assert "pallas_call" not in str(program.jaxpr)
+    (o, after), (want_o, want) = program.lower().compile()(stack)
+    assert after.dtype == dtype
+    _close(o, want_o)
+    _close(after[1].astype(jnp.float32), want.astype(dtype).astype(
+        jnp.float32))
+    assert bool(jnp.all(after[0] == stack[0]))
+
+
+def test_the_head_gate_step_kernel_groups_of_heads_are_heads_alone(
+        monkeypatch):
+    """Two rows of six heads where a grid step's bytes hold four: groups
+    of three, and a grid step finds its own group's rows and
+    matrices."""
+    monkeypatch.setattr(kda, "_GATE_WAVE_BLOCK", 4 * 8 * 128 * 4)
+    (q, k, v, g, beta), s0 = _one_decay(6, 1, (2, 6, 8, 64))
+    wave = (q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
+    # (the form jitted anew: the module's jitted call keeps the traces
+    # it has made, a row a step)
+    o, after = jax.jit(kda._head_gate_step_form, static_argnums=(7,))(
+        *wave, s0[None], jnp.int32(0), True)
+    want_o, want = kda_step(*wave, s0)
+    _close(o, want_o)
+    _close(after[0], want)
 
 
 def test_a_decay_a_channel_never_enters_the_matmul_form(monkeypatch):
@@ -564,10 +671,12 @@ def test_a_decay_a_channel_never_enters_the_matmul_form(monkeypatch):
     trailing size 1 the matmul form, and on the chip (steered here) it
     takes its own kernel, ``kda_chunk``, never the one for one decay a
     head.  A fitting prefill with ONE decay a head takes that kernel,
-    ``delta_chunk``, on the chip and the matmul form off it; one column,
-    heads of 12 x 24 and `kda_decode` keep their `jnp` forms; a
-    differentiated call is `kda_chunked`, forward and backward.  (All
-    traced, nothing run.)"""
+    ``delta_chunk``, on the chip and the matmul form off it; one column
+    and heads of 12 x 24 keep their `jnp` forms.  A decode wave picks
+    among three the same way: off the chip and differentiated the `jnp`
+    step, a decay a channel the call ``kda_decode``, ONE decay a head
+    the call ``delta_decode``.  A differentiated call is `kda_chunked`,
+    forward and backward.  (All traced, nothing run.)"""
     entered = []
     real = kda._scalar_gate_chunked
     monkeypatch.setattr(kda, "_scalar_gate_chunked", lambda *a: (
@@ -589,6 +698,12 @@ def test_a_decay_a_channel_never_enters_the_matmul_form(monkeypatch):
         return sorted(calls(jax.make_jaxpr(functools.partial(
             form, chunk=16, dtype=jnp.float32, **kw))(*xs, s0).jaxpr))
 
+    def steps(xs, s0):
+        """The names of the Pallas calls a decode wave's step traces
+        to."""
+        return sorted(calls(jax.make_jaxpr(lambda stack: kda_decode(
+            *(a[:, 0] for a in xs), stack, 0))(s0[None]).jaxpr))
+
     traced(kda_chunked, *_inputs(2, 24))
     assert not entered
     traced(kda_chunked, *_one_decay(2, 24))
@@ -596,6 +711,7 @@ def test_a_decay_a_channel_never_enters_the_matmul_form(monkeypatch):
     channel = _inputs(2, 24, dims=(1, 8, 128, 128))
     gate = _one_decay(2, 24, GATE)
     assert kernels(kda_prefill, *gate) == []                # the CPU
+    assert steps(*gate) == steps(*channel) == []
     assert entered[-1] == (1, 24, 5, 1)
     del entered[:]
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -609,9 +725,12 @@ def test_a_decay_a_channel_never_enters_the_matmul_form(monkeypatch):
     small = _one_decay(2, 24)
     assert kernels(kda_prefill, *small) == []               # 12 x 24
     assert [shape[1:] for shape in entered] == [(1, 5, 1), (24, 5, 1)]
-    text = str(jax.make_jaxpr(lambda stack: kda_decode(
-        *(a[:, 0] for a in xs), stack, 0))(s0[None]))
-    assert "pallas_call" not in text
+    # a decode wave picks among three: a decay a channel the call
+    # ``kda_decode``, ONE decay a head the call ``delta_decode``, heads
+    # of 12 x 24 the `jnp` step
+    assert steps(*channel) == ["kda_decode"]
+    assert steps(*gate) == ["delta_decode"]
+    assert steps(*small) == []
     with pytest.raises(ValueError, match="do not fit the kernel"):
         kda_chunk(*small[0], small[1], chunk=16, interpret=True)
 
@@ -623,3 +742,11 @@ def test_a_decay_a_channel_never_enters_the_matmul_form(monkeypatch):
     text = str(jax.make_jaxpr(jax.grad(functools.partial(
         loss, kda_prefill)))(xs[2]))
     assert "pallas_call" not in text and "scan" in text
+
+    def step_loss(v):
+        q, k, _, g, beta = (a[:, 0] for a in xs)
+        o, stack = kda_decode(q, k, v, g, beta, s0[None], 0)
+        return jnp.sum(o * o) + jnp.sum(stack)
+
+    assert list(calls(jax.make_jaxpr(jax.grad(step_loss))(
+        xs[2][:, 0]).jaxpr)) == []

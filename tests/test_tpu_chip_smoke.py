@@ -39,6 +39,7 @@ TOY = chip_smoke.Size(
     gqa_preset="nano", gqa_max_seq=128, gqa_wave=(5, 24),
     kda_heads=(8, 128), kda_prefills=((96, 13), (160, 70)),
     kda_wave=(6, 3), delta_heads=(5, 96, 192), delta_prefills=((96, 13),),
+    delta_wave=(2, 3),
     ring_waves=((3, 5, 32, 8, 2, 128, 64 ** -0.5),
                 (2, 4, 16, 16, 2, 128, 128 ** -0.5)),
     banded_layers=((12, 2, 128 ** -0.5, None, 128, 32, (64,)),
